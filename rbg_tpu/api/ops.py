@@ -261,7 +261,8 @@ ADMIN_OPS: Dict[str, OpSpec] = {
 
 ENGINE_OPS: Dict[str, OpSpec] = {
     OP_HEALTH: _spec(OP_HEALTH, PLANE_ENGINE, False, {},
-                     {"ok": ("ok", "mode", "draining", "draining_for_s")}),
+                     {"ok": ("ok", "mode", "draining", "draining_for_s",
+                             "device")}),
     OP_WARMUP: _spec(OP_WARMUP, PLANE_ENGINE, True,
                      {"input_len": "int?"},
                      {"ok": ("ok", "elapsed_s")}),

@@ -19,9 +19,6 @@ def main(argv=None) -> int:
     if argv and argv[0] == "stress":
         from rbg_tpu.stress.harness import main as stress_main
         return stress_main(argv[1:])
-    if argv and argv[0] == "tpu-check":
-        from rbg_tpu.cli.tpucheck import run as tpucheck_run
-        return tpucheck_run(argv[1:])
     if argv and argv[0] == "deploy-manifests":
         from rbg_tpu.cli.deploygen import run as deploygen_run
         return deploygen_run(argv[1:])

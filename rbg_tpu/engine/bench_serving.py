@@ -53,7 +53,9 @@ def _drive_inprocess(args, prompts, arrivals):
     """Submit through an EngineService; per-token timing via step events."""
     from rbg_tpu.engine.config import EngineConfig, SamplingParams
     from rbg_tpu.engine.service import EngineService
+    from rbg_tpu.utils import chipenv
 
+    chipenv.configure_compile_cache()
     svc = EngineService(EngineConfig(
         model=args.model, page_size=args.page_size, num_pages=args.num_pages,
         max_seq_len=args.max_seq_len, max_batch=args.max_batch,

@@ -4,8 +4,9 @@ BASELINE.md north star: PD-disagg throughput >= 50% of co-located, p50
 TTFT < 200 ms (on TPU v5e-64 for Llama-3-70B). On this machine the suite
 runs the same topology as a CPU proxy (tiny model, real processes, real
 wire) so the ratio is a *tracked number* across rounds rather than an
-aspiration; the identical command reruns on TPU hardware when the chip is
-reachable (docs/tpu-runbook.md).
+aspiration; ``--platform tpu`` runs the identical command on a TPU host,
+each engine process pinned to a chip of its own (a chip belongs to one
+process; ``utils/chipenv.chip_env``) and everything else kept on the CPU.
 
 Topologies (all real subprocesses over the wire protocol):
 
@@ -64,22 +65,27 @@ def _wait_ready(port: int, timeout: float = 240.0) -> None:
 
 
 class _Topology:
-    """Spawn + tear down one serving topology (scrubbed CPU env unless the
-    caller passes a TPU-ready env)."""
+    """Spawn + tear down one serving topology. ``env`` is the CPU
+    environment every process without an engine gets; with ``on_tpu`` the
+    topology's engine servers each get one chip of this host instead."""
 
     def __init__(self, kind: str, engine_args: List[str], env: dict,
-                 max_batch: int, decode_replicas: int = 1):
+                 max_batch: int, decode_replicas: int = 1,
+                 on_tpu: bool = False):
         self.kind = kind
         self.procs: List[subprocess.Popen] = []
         self.max_batch = max_batch
         self.engine_ports: List[int] = []
+        self._on_tpu = on_tpu
+        self._engines = 1 if kind == "unified" else 1 + decode_replicas
+        self._engines_started = 0
         ports: Dict[str, int] = {}
         try:
             if kind == "unified":
                 ports["front"] = _free_port()
-                self._spawn(["-m", "rbg_tpu.engine.server",
-                             "--mode", "unified",
-                             "--port", str(ports["front"])] + engine_args, env)
+                self._spawn_engine(["--mode", "unified",
+                                    "--port", str(ports["front"])]
+                                   + engine_args, env)
                 _wait_ready(ports["front"])
                 self.engine_ports = [ports["front"]]
             elif kind == "pd":
@@ -90,15 +96,14 @@ class _Topology:
                 self._spawn(["-m", "rbg_tpu.engine.kvpool",
                              "--port", str(ports["pool"]),
                              "--page-size", page], env)
-                self._spawn(["-m", "rbg_tpu.engine.server",
-                             "--mode", "prefill",
-                             "--port", str(ports["prefill"]),
-                             "--kv-pool", f"127.0.0.1:{ports['pool']}"]
-                            + engine_args, env)
+                self._spawn_engine(["--mode", "prefill",
+                                    "--port", str(ports["prefill"]),
+                                    "--kv-pool",
+                                    f"127.0.0.1:{ports['pool']}"]
+                                   + engine_args, env)
                 for dp in decode_ports:
-                    self._spawn(["-m", "rbg_tpu.engine.server",
-                                 "--mode", "decode",
-                                 "--port", str(dp)] + engine_args, env)
+                    self._spawn_engine(["--mode", "decode",
+                                        "--port", str(dp)] + engine_args, env)
                 backends = {"prefill": [f"127.0.0.1:{ports['prefill']}"],
                             "decode": [f"127.0.0.1:{dp}"
                                        for dp in decode_ports]}
@@ -117,6 +122,15 @@ class _Topology:
 
     def _spawn(self, argv: List[str], env: dict) -> None:
         self.procs.append(subprocess.Popen([sys.executable] + argv, env=env))
+
+    def _spawn_engine(self, argv: List[str], env: dict) -> None:
+        """An engine server: on a TPU host, engine i of n on chip i."""
+        if self._on_tpu:
+            from rbg_tpu.utils.chipenv import chip_env
+            env = chip_env(self._engines_started, self._engines,
+                           {**env, "JAX_PLATFORMS": "tpu"})
+        self._engines_started += 1
+        self._spawn(["-m", "rbg_tpu.engine.server"] + argv, env)
 
     def warmup(self, input_len: int) -> None:
         """Compile every jit bucket variant on every engine in the
@@ -180,7 +194,8 @@ def measure(kind: str, rates: List[float], args, env) -> List[dict]:
                    "--prefill-chunk", str(args.prefill_chunk),
                    "--use-pallas", args.use_pallas]
     topo = _Topology(kind, engine_args, env, args.max_batch,
-                     decode_replicas=args.pd_decode_replicas)
+                     decode_replicas=args.pd_decode_replicas,
+                     on_tpu=args.platform == "tpu")
     rows = []
     try:
         topo.warmup(args.input_len)
@@ -248,24 +263,19 @@ def main(argv=None) -> int:
                          "least-loads across them) — the knob the "
                          "saturation ratio scales with")
     ap.add_argument("--platform", default="cpu", choices=["cpu", "tpu"],
-                    help="cpu = scrubbed CPU-proxy subprocesses (default); "
-                         "tpu = inherit the TPU environment (one engine "
-                         "process at a time touches the chip — unified and "
-                         "pd runs are sequential, but a pd TOPOLOGY is "
-                         "multi-process: only run it on real multi-chip "
-                         "hosts, per docs/tpu-runbook.md)")
+                    help="cpu = CPU-proxy subprocesses (default); tpu = "
+                         "each engine server holds one chip of this host "
+                         "(unified needs one chip, pd one per engine), the "
+                         "router and kv-pool stay on the CPU")
     args = ap.parse_args(argv)
     rates = [float(r) for r in args.rates.split(",") if r]
 
     # The executor's env contract (RBG_SERVE_PORT & co) must not leak into
     # spawned topologies — it would override every --port with ONE value.
-    drop = {"RBG_SERVE_PORT": None, "RBG_PORT_SERVE": None,
-            "RBG_KV_POOL_ADDR": None}
-    if args.platform == "cpu":
-        from rbg_tpu.utils import scrubbed_cpu_env
-        env = scrubbed_cpu_env(extra=drop)
-    else:
-        env = {k: v for k, v in os.environ.items() if k not in drop}
+    from rbg_tpu.utils import scrubbed_cpu_env
+    env = scrubbed_cpu_env(extra={"RBG_SERVE_PORT": None,
+                                  "RBG_PORT_SERVE": None,
+                                  "RBG_KV_POOL_ADDR": None})
 
     results: Dict[str, List[dict]] = {}
     for kind in args.setups.split(","):

@@ -30,6 +30,7 @@ from rbg_tpu.engine.protocol import (CODE_DRAINING, DeadlineExceeded,
                                      bundle_to_wire, recv_msg, send_msg)
 from rbg_tpu.obs import names, trace
 from rbg_tpu.obs.metrics import REGISTRY
+from rbg_tpu.utils import chipenv
 from rbg_tpu.utils.locktrace import named_lock
 
 
@@ -168,7 +169,8 @@ class Handler(socketserver.BaseRequestHandler):
         op = obj.get("op")
         if op == "health":
             ready = srv.service is not None or srv.prefill is not None or srv.decode is not None
-            resp = {"ok": ready, "mode": srv.mode, "draining": srv.draining}
+            resp = {"ok": ready, "mode": srv.mode, "draining": srv.draining,
+                    "device": srv.device}
             if srv.draining:
                 resp["draining_for_s"] = round(
                     time.monotonic() - srv.drain_started, 3)
@@ -290,6 +292,11 @@ class Handler(socketserver.BaseRequestHandler):
                 stats["device_tier_pages"] = (
                     eng.radix.cached_pages if eng.radix is not None else 0)
             stats["draining"] = srv.draining
+            # Set-up cost and device memory, so a client on the wire can
+            # tell compile time from serving time and see what the pool
+            # and the step programs really hold.
+            stats["compile"] = srv.compile_counter.snapshot()
+            stats["device_memory"] = chipenv.memory_summary()
             send_msg(self.request, {"metrics": stats, "mode": srv.mode})
             return
         if op == "generate_text" and srv.service is not None:
@@ -706,6 +713,9 @@ def serve(args) -> None:
     server = EngineServer(("127.0.0.1", port), Handler)
     server.mode = cfg.mode
     server.service = server.prefill = server.decode = None
+    server.device = None           # set by init_engine, reported by health
+    chipenv.configure_compile_cache()
+    server.compile_counter = chipenv.CompileCounter().install()
     server.auth_token = (args.auth_token
                          or os.environ.get("RBG_DATA_TOKEN") or None)
     server.pd_lock = named_lock("engine.server_pd")
@@ -735,6 +745,11 @@ def serve(args) -> None:
     # tokenizer in the background — a slow HF load must not stall accepts.
     def init_engine():
         try:
+            # One line a deployment's log can be searched for: which
+            # device this process took (a chip belongs to one process).
+            server.device = chipenv.device_summary()
+            print("engine device platform={platform} kind={kind!r} id={id} "
+                  "count={count}".format(**server.device), flush=True)
             if args.tokenizer_path:
                 from rbg_tpu.engine.tokenizer import load_tokenizer
                 server.tokenizer = load_tokenizer(args.tokenizer_path)

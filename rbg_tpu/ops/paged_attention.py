@@ -104,17 +104,12 @@ def write_kv_pages(k_pages, v_pages, k_new, v_new, page_table, positions,
 
 def dispatch_pallas(use_pallas: str, kernel_name: str, xla_fn, args):
     """The ONE kernel-vs-XLA dispatch policy (GQA and MLA both use it):
-    'always' imports the kernel and fails loudly if unavailable; 'auto'
-    takes the kernel on TPU, swallowing only ImportError; anything else
-    (or a non-TPU backend) runs the XLA fallback."""
-    if use_pallas == "always":
+    'always' takes the kernel everywhere, 'auto' takes it on a TPU and
+    the XLA path on any other backend, 'never' the XLA path. A kernel
+    that cannot be imported is an error, never a reason to run XLA."""
+    if use_pallas == "always" or (use_pallas == "auto"
+                                  and jax.default_backend() == "tpu"):
         from rbg_tpu.ops.pallas import paged_attention_kernel as K
-        return getattr(K, kernel_name)(*args)
-    if use_pallas == "auto" and jax.default_backend() == "tpu":
-        try:
-            from rbg_tpu.ops.pallas import paged_attention_kernel as K
-        except ImportError:
-            return xla_fn(*args)
         return getattr(K, kernel_name)(*args)
     return xla_fn(*args)
 

@@ -32,12 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x;
-# resolve whichever this image ships so the kernels (and their interpret-
-# mode tests) run on both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _NEG_INF = -1e30
 
 
@@ -155,7 +149,7 @@ def _decode_call(q, k_pages, v_pages, page_table, kv_lens, interpret=False):
         _decode_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -250,7 +244,7 @@ def _decode_call_q(q, k_pages, v_pages, k_scales, v_scales, page_table,
         _decode_kernel_q,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -402,7 +396,7 @@ def _mla_decode_call(q_lat, q_pe, c_pages, pe_pages, page_table, kv_lens,
         functools.partial(_mla_decode_kernel, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dc), q_lat.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -480,7 +474,7 @@ def _mla_decode_call_q(q_lat, q_pe, c_pages, pe_pages, c_scales, pe_scales,
         functools.partial(_mla_decode_kernel_q, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dc), q_lat.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

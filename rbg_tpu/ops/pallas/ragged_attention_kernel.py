@@ -12,8 +12,12 @@ Block-ragged grid = (T/TILE, TILE, TILE·P inner steps collapsed to
 
 * the packed token axis is padded to a multiple of ``Q_TILE`` (pad tokens
   carry ``q_position == -1`` — the SAME pad contract as the pack itself)
-  and the q/out BlockSpecs move one ``[TILE, KV, G, hd]`` tile per outer
-  step;
+  and the wrapper folds each tile's tokens into the query-row axis in
+  XLA, so the q/out BlockSpecs move one ``[KV, TILE·G, hd]`` (MLA:
+  ``[TILE·H, dc]``) tile per outer step and the kernel body holds no
+  shape cast — Mosaic (v5e, jax 0.9) refuses to merge a G-row axis
+  smaller than a sublane tile, and has no layout for a rank-1 vector of
+  per-token limits, so those are built against a 2-D iota instead;
 * inner step ``(r, p)`` nominates packed token ``t = tile·TILE + r`` and
   logical page ``p`` of ``row_ids[t]``. The kernel computes FIRST-
   OCCURRENCE leadership from the scalar-prefetched ``row_ids``: only the
@@ -56,12 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Same jax 0.4.x/0.5.x rename compat as paged_attention_kernel (resolved
-# here rather than imported from it: that module re-exports THESE kernels
-# for dispatch_pallas, so importing back would be circular).
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _NEG_INF = -1e30
 
 # Query-tile length of the block-ragged grid. 8 packed tokens per tile
@@ -96,6 +94,26 @@ def _tile_leadership(row_ids_ref, kv_lens_ref, q_pos_ref, t0, r_off, row,
         (jnp.zeros((), jnp.bool_), jnp.zeros((), jnp.int32)))
 
 
+def _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row, tile, group):
+    """Per-query-row causal limits of one tile as a ``[TILE·group, 1]``
+    column (query row ``j`` belongs to tile token ``j // group``). Tokens
+    of OTHER rows get limit 0 (fully masked), so every tile token rides
+    the same softmax update and only ``row``'s tokens accumulate.
+
+    Built from SMEM scalars by ``tile`` selects against a 2-D iota: Mosaic
+    has no layout for a rank-1 vector of stacked scalars, nor for the
+    shape casts that would broadcast one."""
+    j = jax.lax.broadcasted_iota(jnp.int32, (tile * group, 1), 0)
+    limits = jnp.zeros((tile * group, 1), jnp.int32)
+    for k in range(tile):
+        rk = row_ids_ref[t0 + k]
+        lim_k = jnp.where(
+            rk == row,
+            jnp.minimum(kv_lens_ref[rk], q_pos_ref[t0 + k] + 1), 0)
+        limits = jnp.where(j >= k * group, lim_k, limits)
+    return limits
+
+
 def _block_ragged_kernel(
     # scalar prefetch
     page_table_ref,   # [R, P] int32 (SMEM)
@@ -103,15 +121,17 @@ def _block_ragged_kernel(
     row_ids_ref,      # [Tp] int32 (SMEM) — Tp padded to a Q_TILE multiple
     q_pos_ref,        # [Tp] int32 (SMEM)
     # blocks
-    q_ref,            # [TILE, KV, G, hd] (VMEM) — one query tile
+    q_ref,            # [1, KV, TILE·G, hd] (VMEM) — one query tile, the
+                      # tile's tokens already folded into the query-row axis
     k_ref,            # [1, page, KV, hd] — the page picked by index_map
     v_ref,
-    out_ref,          # [TILE, KV, G, hd]
+    out_ref,          # [1, KV, TILE·G, hd]
     # scratch — online softmax state for the WHOLE tile
     m_ref,            # [KV, TILE·G, 1] running max
     l_ref,            # [KV, TILE·G, 1] running denom
     acc_ref,          # [KV, TILE·G, hd] running numerator
     *,
+    tile: int,
     ks_ref=None,      # int8 pools: [1, page, KV] f32 scales
     vs_ref=None,
 ):
@@ -121,7 +141,6 @@ def _block_ragged_kernel(
     num_r = pl.num_programs(1)
     num_p = pl.num_programs(2)
     page = k_ref.shape[1]
-    tile = q_ref.shape[0]
     quantized = ks_ref is not None
 
     @pl.when((r_off == 0) & (p == 0))
@@ -140,26 +159,17 @@ def _block_ragged_kernel(
     # skip (their DMAs are elided by the clamped index_map).
     @pl.when(jnp.logical_not(dup) & (p * page < row_limit))
     def _attend():
-        KV, G, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-        # Per-token causal limits for the tile — tokens of OTHER rows get
-        # limit 0 (fully masked), so every tile token rides the same
-        # softmax update and only this row's tokens accumulate.
-        rows_t = jnp.stack([row_ids_ref[t0 + k] for k in range(tile)])
-        pos_t = jnp.stack([q_pos_ref[t0 + k] for k in range(tile)])
-        lens_t = jnp.stack([kv_lens_ref[row_ids_ref[t0 + k]]
-                            for k in range(tile)])
-        limit_t = jnp.where(rows_t == row,
-                            jnp.minimum(lens_t, pos_t + 1), 0)   # [TILE]
+        rows_q, hd = q_ref.shape[2], q_ref.shape[3]
+        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row,
+                              tile, rows_q // tile)         # [TILE·G, 1]
 
-        q = q_ref[...].astype(jnp.float32)                  # [TILE,KV,G,hd]
+        # The tile's whole query block rides ONE batched dot per page.
+        qm = q_ref[0].astype(jnp.float32)                   # [KV,TILE·G,hd]
         k = k_ref[0].astype(jnp.float32)                    # [page, KV, hd]
         v = v_ref[0].astype(jnp.float32)
 
         k_t = jnp.transpose(k, (1, 0, 2))                   # [KV, page, hd]
         v_t = jnp.transpose(v, (1, 0, 2))
-        # Fold TILE into the query-row axis: [KV, TILE·G, hd] — the tile's
-        # whole query block rides ONE batched dot per page.
-        qm = jnp.transpose(q, (1, 0, 2, 3)).reshape(KV, tile * G, hd)
         scores = jax.lax.dot_general(
             qm, k_t,
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
@@ -170,10 +180,9 @@ def _block_ragged_kernel(
             scores = scores * ks_t[:, None, :]
 
         token_idx = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (KV, tile, G, page), dimension=3)
-        mask = token_idx < limit_t[None, :, None, None]
-        scores = jnp.where(mask.reshape(KV, tile * G, page), scores,
-                           _NEG_INF)
+            jnp.int32, (rows_q, page), dimension=1)
+        mask = token_idx < limits                           # [TILE·G, page]
+        scores = jnp.where(mask[None], scores, _NEG_INF)
 
         m_prev = m_ref[:]                                   # [KV, TILE·G, 1]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -196,10 +205,8 @@ def _block_ragged_kernel(
 
     @pl.when((r_off == num_r - 1) & (p == num_p - 1))
     def _finalize():
-        KV, G, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
         denom = jnp.maximum(l_ref[:], 1e-30)                # guard pad rows
-        o = (acc_ref[:] / denom).reshape(KV, tile, G, hd)
-        out_ref[...] = jnp.transpose(o, (1, 0, 2, 3)).astype(out_ref.dtype)
+        out_ref[0] = (acc_ref[:] / denom).astype(out_ref.dtype)
 
 
 def _kv_page_index(i, r, p, table, lens, rows, *, tile, page):
@@ -220,46 +227,77 @@ def _kv_page_index(i, r, p, table, lens, rows, *, tile, page):
     return jnp.where(lead, jnp.minimum(p, last), last), row
 
 
+def _fold_tile(qg):
+    """[Tp, KV, G, hd] packed → [Tp/TILE, KV, TILE·G, hd]: each tile's
+    tokens folded into the query-row axis (row ``k·G + g``). Done here,
+    in XLA, because Mosaic cannot merge a G-row axis smaller than a
+    sublane tile inside the kernel."""
+    Tp, KV, G, hd = qg.shape
+    q5 = qg.reshape(Tp // Q_TILE, Q_TILE, KV, G, hd)
+    return jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(
+        Tp // Q_TILE, KV, Q_TILE * G, hd)
+
+
+def _unfold_tile(out, G):
+    """Inverse of ``_fold_tile``: [Tp/TILE, KV, TILE·G, hd] → [Tp, KV·G, hd]."""
+    NT, KV, _, hd = out.shape
+    o5 = out.reshape(NT, KV, Q_TILE, G, hd)
+    return jnp.transpose(o5, (0, 2, 1, 3, 4)).reshape(
+        NT * Q_TILE, KV * G, hd)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _block_ragged_call(q, k_pages, v_pages, page_table, kv_lens, row_ids,
-                       q_pos, interpret=False):
-    """q: [Tp, KV, G, hd] packed (Tp a Q_TILE multiple); pages:
-    [NP, page, KV, hd]. Returns [Tp, KV, G, hd]."""
-    Tp, KV, G, hd = q.shape
+def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
+                       kv_lens, row_ids, q_pos, interpret=False):
+    """q: [Tp/TILE, KV, TILE·G, hd] folded tiles; pages: [NP, page, KV,
+    hd]; scales (int8 pools) [NP, page, KV] f32 or None. Returns q's
+    shape."""
+    NT, KV, rows_q, hd = q.shape
     _, page, _, _ = k_pages.shape
     P = page_table.shape[1]
     tile = Q_TILE
 
-    def pick(i, r, p, table, lens, rows, qpos):
+    def pick4(i, r, p, table, lens, rows, qpos):
         pidx, row = _kv_page_index(i, r, p, table, lens, rows,
                                    tile=tile, page=page)
         return (table[row, pidx], 0, 0, 0)
 
+    def pick3(i, r, p, table, lens, rows, qpos):
+        return pick4(i, r, p, table, lens, rows, qpos)[:3]
+
     fixed = lambda i, r, p, table, lens, rows, qpos: (i, 0, 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, KV, rows_q, hd), fixed),
+        pl.BlockSpec((1, page, KV, hd), pick4),
+        pl.BlockSpec((1, page, KV, hd), pick4),
+    ]
+    args = (page_table, kv_lens, row_ids, q_pos, q, k_pages, v_pages)
+    kernel = _block_ragged_kernel
+    if k_scales is not None:
+        kernel = _block_ragged_kernel_q
+        in_specs += [pl.BlockSpec((1, page, KV), pick3),
+                     pl.BlockSpec((1, page, KV), pick3)]
+        args += (k_scales, v_scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(Tp // tile, tile, P),
-        in_specs=[
-            pl.BlockSpec((tile, KV, G, hd), fixed),
-            pl.BlockSpec((1, page, KV, hd), pick),
-            pl.BlockSpec((1, page, KV, hd), pick),
-        ],
-        out_specs=pl.BlockSpec((tile, KV, G, hd), fixed),
+        grid=(NT, tile, P),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, KV, rows_q, hd), fixed),
         scratch_shapes=[
-            pltpu.VMEM((KV, tile * G, 1), jnp.float32),
-            pltpu.VMEM((KV, tile * G, 1), jnp.float32),
-            pltpu.VMEM((KV, tile * G, hd), jnp.float32),
+            pltpu.VMEM((KV, rows_q, 1), jnp.float32),
+            pltpu.VMEM((KV, rows_q, 1), jnp.float32),
+            pltpu.VMEM((KV, rows_q, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        _block_ragged_kernel,
+        functools.partial(kernel, tile=tile),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tp, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(page_table, kv_lens, row_ids, q_pos, q, k_pages, v_pages)
+    )(*args)
 
 
 def _pad_pack(qg, rows, qpos):
@@ -278,22 +316,29 @@ def _pad_pack(qg, rows, qpos):
     return qg, rows, qpos
 
 
-def ragged_paged_attention_pallas(q, k_pages, v_pages, page_table,
-                                  q_positions, kv_lens, row_ids,
-                                  interpret: bool = False):
-    """Drop-in for ``ragged_paged_attention_xla`` (q packed [1, T, H, hd]),
-    block-ragged grid."""
+def _block_ragged(q, k_pages, v_pages, k_scales, v_scales, page_table,
+                  q_positions, kv_lens, row_ids, interpret):
     _, T, H, hd = q.shape
     KV = k_pages.shape[2]
     G = H // KV
     qg, rows, qpos = _pad_pack(q.reshape(T, KV, G, hd),
                                row_ids.astype(jnp.int32),
                                q_positions.reshape(T).astype(jnp.int32))
-    out = _block_ragged_call(qg, k_pages, v_pages,
+    out = _block_ragged_call(_fold_tile(qg), k_pages, v_pages,
+                             k_scales, v_scales,
                              page_table.astype(jnp.int32),
                              kv_lens.astype(jnp.int32),
                              rows, qpos, interpret=interpret)
-    return out[:T].reshape(1, T, H, hd)
+    return _unfold_tile(out, G)[:T].reshape(1, T, H, hd)
+
+
+def ragged_paged_attention_pallas(q, k_pages, v_pages, page_table,
+                                  q_positions, kv_lens, row_ids,
+                                  interpret: bool = False):
+    """Drop-in for ``ragged_paged_attention_xla`` (q packed [1, T, H, hd]),
+    block-ragged grid."""
+    return _block_ragged(q, k_pages, v_pages, None, None, page_table,
+                         q_positions, kv_lens, row_ids, interpret)
 
 
 # ---- int8 (quantized pool) variant ------------------------------------------
@@ -309,60 +354,13 @@ def _block_ragged_kernel_q(
     out_ref,
     # scratch
     m_ref, l_ref, acc_ref,
+    *,
+    tile: int,
 ):
     _block_ragged_kernel(page_table_ref, kv_lens_ref, row_ids_ref,
                          q_pos_ref, q_ref, k_ref, v_ref, out_ref,
-                         m_ref, l_ref, acc_ref,
+                         m_ref, l_ref, acc_ref, tile=tile,
                          ks_ref=ks_ref, vs_ref=vs_ref)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _block_ragged_call_q(q, k_pages, v_pages, k_scales, v_scales,
-                         page_table, kv_lens, row_ids, q_pos,
-                         interpret=False):
-    Tp, KV, G, hd = q.shape
-    _, page, _, _ = k_pages.shape
-    P = page_table.shape[1]
-    tile = Q_TILE
-
-    def pick4(i, r, p, table, lens, rows, qpos):
-        pidx, row = _kv_page_index(i, r, p, table, lens, rows,
-                                   tile=tile, page=page)
-        return (table[row, pidx], 0, 0, 0)
-
-    def pick3(i, r, p, table, lens, rows, qpos):
-        pidx, row = _kv_page_index(i, r, p, table, lens, rows,
-                                   tile=tile, page=page)
-        return (table[row, pidx], 0, 0)
-
-    fixed = lambda i, r, p, table, lens, rows, qpos: (i, 0, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(Tp // tile, tile, P),
-        in_specs=[
-            pl.BlockSpec((tile, KV, G, hd), fixed),
-            pl.BlockSpec((1, page, KV, hd), pick4),
-            pl.BlockSpec((1, page, KV, hd), pick4),
-            pl.BlockSpec((1, page, KV), pick3),
-            pl.BlockSpec((1, page, KV), pick3),
-        ],
-        out_specs=pl.BlockSpec((tile, KV, G, hd), fixed),
-        scratch_shapes=[
-            pltpu.VMEM((KV, tile * G, 1), jnp.float32),
-            pltpu.VMEM((KV, tile * G, 1), jnp.float32),
-            pltpu.VMEM((KV, tile * G, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        _block_ragged_kernel_q,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tp, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(page_table, kv_lens, row_ids, q_pos, q, k_pages, v_pages,
-      k_scales, v_scales)
 
 
 def ragged_paged_attention_pallas_q(q, k_pages, v_pages, page_table,
@@ -371,18 +369,9 @@ def ragged_paged_attention_pallas_q(q, k_pages, v_pages, page_table,
                                     interpret: bool = False):
     """Quantized-pool drop-in: scales arrive [NP, page, KV, 1] (the pool
     layout) and are squeezed for the kernel."""
-    _, T, H, hd = q.shape
-    KV = k_pages.shape[2]
-    G = H // KV
-    qg, rows, qpos = _pad_pack(q.reshape(T, KV, G, hd),
-                               row_ids.astype(jnp.int32),
-                               q_positions.reshape(T).astype(jnp.int32))
-    out = _block_ragged_call_q(qg, k_pages, v_pages,
-                               k_scales[..., 0], v_scales[..., 0],
-                               page_table.astype(jnp.int32),
-                               kv_lens.astype(jnp.int32),
-                               rows, qpos, interpret=interpret)
-    return out[:T].reshape(1, T, H, hd)
+    return _block_ragged(q, k_pages, v_pages,
+                         k_scales[..., 0], v_scales[..., 0], page_table,
+                         q_positions, kv_lens, row_ids, interpret)
 
 
 # ---- MLA (latent) block-ragged kernels --------------------------------------
@@ -399,18 +388,19 @@ def ragged_paged_attention_pallas_q(q, k_pages, v_pages, page_table,
 def _block_ragged_mla_kernel(
     # scalar prefetch
     page_table_ref, kv_lens_ref, row_ids_ref, q_pos_ref,
-    # blocks
-    ql_ref,           # [TILE, H, dc]
-    qp_ref,           # [TILE, H, dr]
+    # blocks — the tile's tokens are folded into the query-row axis
+    ql_ref,           # [TILE·H, dc]
+    qp_ref,           # [TILE·H, dr]
     c_ref,            # [1, page, 1, dc]
     pe_ref,           # [1, page, 1, dr]
-    out_ref,          # [TILE, H, dc]
+    out_ref,          # [TILE·H, dc]
     # scratch
     m_ref,            # [TILE·H, 1]
     l_ref,            # [TILE·H, 1]
     acc_ref,          # [TILE·H, dc]
     *,
     scale: float,
+    tile: int,
     cs_ref=None,      # int8 pools: [1, page, 1] f32 scales
     ps_ref=None,
 ):
@@ -420,7 +410,6 @@ def _block_ragged_mla_kernel(
     num_r = pl.num_programs(1)
     num_p = pl.num_programs(2)
     page = c_ref.shape[1]
-    tile = ql_ref.shape[0]
     quantized = cs_ref is not None
 
     @pl.when((r_off == 0) & (p == 0))
@@ -436,16 +425,12 @@ def _block_ragged_mla_kernel(
 
     @pl.when(jnp.logical_not(dup) & (p * page < row_limit))
     def _attend():
-        H, dc = ql_ref.shape[1], ql_ref.shape[2]
-        rows_t = jnp.stack([row_ids_ref[t0 + k] for k in range(tile)])
-        pos_t = jnp.stack([q_pos_ref[t0 + k] for k in range(tile)])
-        lens_t = jnp.stack([kv_lens_ref[row_ids_ref[t0 + k]]
-                            for k in range(tile)])
-        limit_t = jnp.where(rows_t == row,
-                            jnp.minimum(lens_t, pos_t + 1), 0)   # [TILE]
+        rows_q = ql_ref.shape[0]
+        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row,
+                              tile, rows_q // tile)         # [TILE·H, 1]
 
-        ql = ql_ref[...].astype(jnp.float32).reshape(tile * H, dc)
-        qp = qp_ref[...].astype(jnp.float32).reshape(tile * H, -1)
+        ql = ql_ref[...].astype(jnp.float32)                # [TILE·H, dc]
+        qp = qp_ref[...].astype(jnp.float32)                # [TILE·H, dr]
         c = c_ref[0, :, 0, :].astype(jnp.float32)           # [page, dc]
         pe = pe_ref[0, :, 0, :].astype(jnp.float32)         # [page, dr]
 
@@ -459,9 +444,8 @@ def _block_ragged_mla_kernel(
         scores = (s_c + s_pe) * scale                       # [TILE·H, page]
 
         token_idx = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (tile, H, page), dimension=2)
-        mask = token_idx < limit_t[:, None, None]
-        scores = jnp.where(mask.reshape(tile * H, page), scores, _NEG_INF)
+            jnp.int32, (rows_q, page), dimension=1)
+        scores = jnp.where(token_idx < limits, scores, _NEG_INF)
 
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -480,10 +464,8 @@ def _block_ragged_mla_kernel(
 
     @pl.when((r_off == num_r - 1) & (p == num_p - 1))
     def _finalize():
-        H, dc = ql_ref.shape[1], ql_ref.shape[2]
         denom = jnp.maximum(l_ref[:], 1e-30)
-        out_ref[...] = (acc_ref[:] / denom).reshape(tile, H, dc).astype(
-            out_ref.dtype)
+        out_ref[...] = (acc_ref[:] / denom).astype(out_ref.dtype)
 
 
 def _block_ragged_mla_kernel_q(
@@ -495,22 +477,26 @@ def _block_ragged_mla_kernel_q(
     m_ref, l_ref, acc_ref,
     *,
     scale: float,
+    tile: int,
 ):
     _block_ragged_mla_kernel(page_table_ref, kv_lens_ref, row_ids_ref,
                              q_pos_ref, ql_ref, qp_ref, c_ref, pe_ref,
                              out_ref, m_ref, l_ref, acc_ref,
-                             scale=scale, cs_ref=cs_ref, ps_ref=ps_ref)
+                             scale=scale, tile=tile,
+                             cs_ref=cs_ref, ps_ref=ps_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "quantized"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _block_ragged_mla_call(ql, qp, c_pages, pe_pages, c_scales, pe_scales,
                            page_table, kv_lens, row_ids, q_pos, scale,
-                           quantized=False, interpret=False):
-    """ql: [Tp, H, dc], qp: [Tp, H, dr] packed (Tp a Q_TILE multiple);
-    pages: [NP, page, 1, d]. Returns [Tp, H, dc]."""
-    Tp, H, dc = ql.shape
-    dr = qp.shape[-1]
+                           interpret=False):
+    """ql: [Tp·H, dc], qp: [Tp·H, dr] packed (Tp a Q_TILE multiple, H a
+    static divisor of the block); pages: [NP, page, 1, d]; scales (int8
+    pools) [NP, page, 1] f32 or None. Returns [Tp·H, dc]."""
+    dc = ql.shape[1]
+    dr = qp.shape[1]
+    Tp = row_ids.shape[0]
+    rows_q = ql.shape[0] // Tp * Q_TILE                     # TILE·H
     _, page, _, _ = c_pages.shape
     P = page_table.shape[1]
     tile = Q_TILE
@@ -521,49 +507,47 @@ def _block_ragged_mla_call(ql, qp, c_pages, pe_pages, c_scales, pe_scales,
         return (table[row, pidx], 0, 0, 0)
 
     def pick3(i, r, p, table, lens, rows, qpos):
-        pidx, row = _kv_page_index(i, r, p, table, lens, rows,
-                                   tile=tile, page=page)
-        return (table[row, pidx], 0, 0)
+        return pick4(i, r, p, table, lens, rows, qpos)[:3]
 
-    fixed = lambda i, r, p, table, lens, rows, qpos: (i, 0, 0)
+    fixed = lambda i, r, p, table, lens, rows, qpos: (i, 0)
     in_specs = [
-        pl.BlockSpec((tile, H, dc), fixed),
-        pl.BlockSpec((tile, H, dr), fixed),
+        pl.BlockSpec((rows_q, dc), fixed),
+        pl.BlockSpec((rows_q, dr), fixed),
         pl.BlockSpec((1, page, 1, dc), pick4),
         pl.BlockSpec((1, page, 1, dr), pick4),
     ]
     args = (page_table, kv_lens, row_ids, q_pos, ql, qp, c_pages, pe_pages)
-    if quantized:
-        kernel = functools.partial(_block_ragged_mla_kernel_q, scale=scale)
+    kernel = _block_ragged_mla_kernel
+    if c_scales is not None:
+        kernel = _block_ragged_mla_kernel_q
         in_specs += [pl.BlockSpec((1, page, 1), pick3),
                      pl.BlockSpec((1, page, 1), pick3)]
         args += (c_scales, pe_scales)
-    else:
-        kernel = functools.partial(_block_ragged_mla_kernel, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(Tp // tile, tile, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tile, H, dc), fixed),
+        out_specs=pl.BlockSpec((rows_q, dc), fixed),
         scratch_shapes=[
-            pltpu.VMEM((tile * H, 1), jnp.float32),
-            pltpu.VMEM((tile * H, 1), jnp.float32),
-            pltpu.VMEM((tile * H, dc), jnp.float32),
+            pltpu.VMEM((rows_q, 1), jnp.float32),
+            pltpu.VMEM((rows_q, 1), jnp.float32),
+            pltpu.VMEM((rows_q, dc), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(kernel, scale=scale, tile=tile),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tp, H, dc), ql.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct(ql.shape, ql.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(*args)
 
 
-def _ragged_mla_prep(q_lat, q_pe, row_ids, q_positions):
-    """Shared pack-padding for the MLA ragged entries."""
+def _block_ragged_mla(q_lat, q_pe, c_pages, pe_pages, c_scales, pe_scales,
+                      page_table, q_positions, kv_lens, row_ids, scale,
+                      interpret):
     _, T, H, dc = q_lat.shape
     ql, rows, qpos = _pad_pack(q_lat.reshape(T, H, dc),
                                row_ids.astype(jnp.int32),
@@ -573,7 +557,15 @@ def _ragged_mla_prep(q_lat, q_pe, row_ids, q_positions):
     if Tp != T:
         qp = jnp.concatenate(
             [qp, jnp.zeros((Tp - T,) + qp.shape[1:], qp.dtype)])
-    return ql, qp, rows, qpos, T
+    # Row-major [Tp, H, d] → [Tp·H, d] is free in XLA; inside the kernel
+    # it would be a shape cast Mosaic has to find a layout for.
+    out = _block_ragged_mla_call(ql.reshape(Tp * H, dc),
+                                 qp.reshape(Tp * H, -1),
+                                 c_pages, pe_pages, c_scales, pe_scales,
+                                 page_table.astype(jnp.int32),
+                                 kv_lens.astype(jnp.int32), rows, qpos,
+                                 scale=float(scale), interpret=interpret)
+    return out.reshape(Tp, H, dc)[:T].reshape(1, T, H, dc)
 
 
 def ragged_paged_mla_attention_pallas(q_lat, q_pe, c_pages, pe_pages,
@@ -582,14 +574,9 @@ def ragged_paged_mla_attention_pallas(q_lat, q_pe, c_pages, pe_pages,
                                       interpret: bool = False):
     """Drop-in for ``ragged_paged_mla_attention_xla`` (q_lat packed
     [1, T, H, dc]), block-ragged grid over the latent pools."""
-    _, T, H, dc = q_lat.shape
-    ql, qp, rows, qpos, T = _ragged_mla_prep(q_lat, q_pe, row_ids,
-                                             q_positions)
-    out = _block_ragged_mla_call(ql, qp, c_pages, pe_pages, None, None,
-                                 page_table.astype(jnp.int32),
-                                 kv_lens.astype(jnp.int32), rows, qpos,
-                                 scale=float(scale), interpret=interpret)
-    return out[:T].reshape(1, T, H, dc)
+    return _block_ragged_mla(q_lat, q_pe, c_pages, pe_pages, None, None,
+                             page_table, q_positions, kv_lens, row_ids,
+                             scale, interpret)
 
 
 def ragged_paged_mla_attention_pallas_q(q_lat, q_pe, c_pages, pe_pages,
@@ -598,16 +585,10 @@ def ragged_paged_mla_attention_pallas_q(q_lat, q_pe, c_pages, pe_pages,
                                         interpret: bool = False):
     """Quantized-latent-pool drop-in: scales arrive [NP, page, 1, 1] (the
     pool layout) and are squeezed for the kernel."""
-    _, T, H, dc = q_lat.shape
-    ql, qp, rows, qpos, T = _ragged_mla_prep(q_lat, q_pe, row_ids,
-                                             q_positions)
-    out = _block_ragged_mla_call(ql, qp, c_pages, pe_pages,
-                                 c_scales[..., 0], pe_scales[..., 0],
-                                 page_table.astype(jnp.int32),
-                                 kv_lens.astype(jnp.int32), rows, qpos,
-                                 scale=float(scale), quantized=True,
-                                 interpret=interpret)
-    return out[:T].reshape(1, T, H, dc)
+    return _block_ragged_mla(q_lat, q_pe, c_pages, pe_pages,
+                             c_scales[..., 0], pe_scales[..., 0],
+                             page_table, q_positions, kv_lens, row_ids,
+                             scale, interpret)
 
 
 # ---- PR-7 token-grid kernels (retained baseline) ----------------------------
@@ -731,7 +712,7 @@ def _ragged_call(q, k_pages, v_pages, page_table, kv_lens, row_ids, q_pos,
         _ragged_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

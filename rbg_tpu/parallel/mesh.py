@@ -26,20 +26,6 @@ from jax.sharding import Mesh
 AXES = ("dp", "sp", "ep", "tp")
 
 
-def shard_map_compat(body, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the top-level alias (and its
-    ``check_vma`` kwarg) only exists on newer jax; older images ship
-    ``jax.experimental.shard_map`` with the same semantics under
-    ``check_rep``. Replication checking is disabled either way — the
-    callers' collectives confuse it."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def make_mesh(
     dp: int = 1,
     tp: int = 1,

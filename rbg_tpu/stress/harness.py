@@ -3714,7 +3714,7 @@ def _attach_locktrace(report: dict, args) -> None:
 def _attach_jitwatch(report: dict, args) -> None:
     """Fold the compile-sentry verdict into the report when --jitwatch
     ran: the counters, every post-warmup compile of a cataloged program
-    (with shape signature + origin stack), and the
+    (with its origin stack), and the
     zero_unwarmed_compiles invariant so one fails the drill red."""
     if not getattr(args, "jitwatch", False):
         return

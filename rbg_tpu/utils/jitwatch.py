@@ -6,10 +6,14 @@ sync introduced behind a dynamic dispatch).
 
 Two probes share one arming matrix (mirroring locktrace/racetrace):
 
-* **Compile sentry** — ``arm()`` hooks JAX's compile seam
-  (``jax._src.compiler.backend_compile`` on this jax-0.4.37 image — a
-  monkeypatch, restored by ``disarm()``) and records every XLA compile as
-  (program name, shape signature, origin stack). Compiles recorded before
+* **Compile sentry** — ``arm()`` listens to JAX's public compile signal
+  (``jax.monitoring``'s ``backend_compile_duration`` scalar event, fired
+  as a program enters the backend compiler; unregistered by
+  ``disarm()``) and records every XLA compile as (program name, origin
+  stack). ``arm()`` then compiles a throwaway ``jit`` and raises
+  :class:`JitWatchBlindError` unless the listener saw it: a JAX that
+  moved the signal must fail the gate, not pass it vacuously. Compiles
+  recorded before
   :func:`warmup_complete` are the warmup set; after it the gate is armed
   and any compile of a *cataloged* program (``obs.names.PROGRAMS`` — the
   catalog warmers and sentry agree on) raises :class:`JitCompileError`
@@ -44,6 +48,8 @@ import threading
 import traceback
 from typing import Dict, List, Optional
 
+from rbg_tpu.utils.chipenv import COMPILE_EVENT
+
 log = logging.getLogger("rbg_tpu.jitwatch")
 
 ENV_VAR = "RBG_JITWATCH"
@@ -58,6 +64,10 @@ class JitCompileError(RuntimeError):
 
 class HostSyncError(RuntimeError):
     """A device→host sync fired inside a strict hot_section()."""
+
+
+class JitWatchBlindError(RuntimeError):
+    """arm() compiled a program and the compile listener saw nothing."""
 
 
 def mode() -> str:
@@ -90,10 +100,11 @@ _syncs = [0]
 # ---- arming ----
 
 def arm(strict: Optional[bool] = None) -> bool:
-    """Install the compile hook + sync wrappers (idempotent). ``strict``
-    overrides the env mode (True = raise, False = warn). Call BEFORE
-    warmup so the warmup compile set is recorded. Returns True once
-    installed."""
+    """Install the compile listener + sync wrappers (idempotent), then
+    prove the listener sees a compile. ``strict`` overrides the env mode
+    (True = raise, False = warn). Call BEFORE warmup so the warmup compile
+    set is recorded. Returns True once installed; raises
+    :class:`JitWatchBlindError` when a compile goes unseen."""
     m = mode() or "raise"
     if strict is not None:
         m = "raise" if strict else "warn"
@@ -103,16 +114,47 @@ def arm(strict: Optional[bool] = None) -> bool:
     _install_compile_hook()
     _install_sync_wrappers()
     _installed[0] = True
+    try:
+        _self_test()
+    except JitWatchBlindError:
+        disarm()
+        raise
     return True
+
+
+_SELF_TEST_PROGRAM = "rbg_jitwatch_self_test"
+
+
+def _self_test() -> None:
+    """Compile a throwaway program and require its record. The program is
+    new each time (a fresh function object), so jit's own cache never
+    answers for it; the record is dropped again so reports count only the
+    caller's compiles."""
+    import jax
+    import jax.numpy as jnp
+
+    def probe(x):
+        return x + 1
+    probe.__name__ = _SELF_TEST_PROGRAM
+    jax.jit(probe)(jnp.zeros((), jnp.int32))
+    with _state:
+        seen = any(r["program"] == _SELF_TEST_PROGRAM for r in _records)
+        _records[:] = [r for r in _records
+                       if r["program"] != _SELF_TEST_PROGRAM]
+        _warmed.discard(_SELF_TEST_PROGRAM)
+    if not seen:
+        raise JitWatchBlindError(
+            f"jitwatch armed but blind: a jit compile fired no "
+            f"{COMPILE_EVENT!r} event on jax {jax.__version__} — every "
+            f"zero-unwarmed-compiles verdict would be vacuous")
 
 
 def disarm() -> None:
     """Remove every patch and reset all state (test isolation)."""
     import jax
-    from jax._src import compiler as _compiler
     for key, (obj_kind, attr, had, value) in list(_saved.items()):
-        if obj_kind == "compiler":
-            setattr(_compiler, attr, value)
+        if obj_kind == "monitoring":
+            jax.monitoring.unregister_scalar_listener(value)
         elif obj_kind == "arrayimpl":
             from jax._src.array import ArrayImpl
             if had:
@@ -196,26 +238,13 @@ def counters() -> Dict[str, float]:
 
 # ---- compile hook ----
 
-def _program_name(module) -> str:
-    """The jitted callable's name as XLA sees it — ``sym_name`` minus the
-    ``jit_`` prefix, so it matches the ``obs.names.PROGRAMS`` catalog."""
-    try:
-        attr = module.operation.attributes["sym_name"]
-        name = getattr(attr, "value", None)
-        if name is None:
-            name = str(attr).strip('"')
-        if name.startswith("jit_"):
-            name = name[len("jit_"):]
-        return name
-    except Exception:
-        return "unknown"
-
-
-def _shape_signature(module) -> str:
-    try:
-        return str(module.body.operations[0].type)
-    except Exception:
-        return ""
+def _program_name(fun_name: str) -> str:
+    """The jitted callable's name out of the event's ``fun_name`` tag
+    (``jit(<name>)``, ``pmap(<name>)``), so it matches the
+    ``obs.names.PROGRAMS`` catalog."""
+    if fun_name.endswith(")") and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name or "unknown"
 
 
 def _origin() -> List[str]:
@@ -226,15 +255,13 @@ def _origin() -> List[str]:
     return frames[-STACK_FRAMES:]
 
 
-def _record_compile(module) -> None:
+def _record_compile(prog: str) -> None:
     from rbg_tpu.obs import names
-    prog = _program_name(module)
     cataloged = prog in names.PROGRAMS
     desc = None
     with _state:
         rec = {
             "program": prog,
-            "signature": _shape_signature(module),
             "origin": _origin(),
             "post_warmup": _gate[0],
             "violation": bool(_gate[0] and cataloged),
@@ -247,8 +274,7 @@ def _record_compile(module) -> None:
         if not cataloged:
             return
         _unwarmed_counts[prog] = _unwarmed_counts.get(prog, 0) + 1
-        desc = (f"unwarmed compile of {prog} {rec['signature']} "
-                f"after warmup_complete() at "
+        desc = (f"unwarmed compile of {prog} after warmup_complete() at "
                 f"{' <- '.join(reversed(rec['origin'])) or '<no rbg frame>'}")
         if len(_violations) < MAX_RECORDS:
             _violations.append(desc)
@@ -263,17 +289,19 @@ def _record_compile(module) -> None:
     log.warning("%s", desc)
 
 
+def _on_compile_event(event: str, _value, fun_name: str = "",
+                      **_kw) -> None:
+    # Fired as the program ENTERS the compiler: a strict-mode raise here
+    # surfaces from the jit call itself, before the compile is paid.
+    if event == COMPILE_EVENT:
+        _record_compile(_program_name(fun_name))
+
+
 def _install_compile_hook() -> None:
-    from jax._src import compiler as _compiler
-    orig = _compiler.backend_compile
-
-    def traced_backend_compile(backend, module, *args, **kwargs):
-        _record_compile(module)
-        return orig(backend, module, *args, **kwargs)
-
-    _saved["compiler.backend_compile"] = (
-        "compiler", "backend_compile", True, orig)
-    _compiler.backend_compile = traced_backend_compile
+    import jax
+    jax.monitoring.register_scalar_listener(_on_compile_event)
+    _saved["monitoring.compile"] = (
+        "monitoring", "scalar_listener", True, _on_compile_event)
 
 
 # ---- host-sync probe ----

@@ -1,0 +1,183 @@
+"""Every Pallas kernel the serving path can reach, compiled by the TPU's
+own compiler for a described (not attached) v5e at published widths.
+
+Interpret mode checks a kernel's arithmetic and nothing about whether
+Mosaic can lay it out: the block-ragged kernels passed every interpret
+test and were refused on first contact. These cases cost no chip time
+and guard each later PR. Nothing runs here, so nothing below is a
+statement about results or speed — ``chip_smoke.py`` is the run.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs in /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from rbg_tpu.engine import EngineConfig
+from rbg_tpu.ops.pallas import paged_attention_kernel as K
+
+BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+
+# The shapes of one mixed serving step: 8 rows over a 4096-page pool of
+# 16-token pages, up to 128 pages a row, 256 packed tokens.
+NP, PAGE, R, P, T = 4096, 16, 8, 128, 256
+
+# heads / kv heads / head dim as published.
+GQA_WIDTHS = {"llama3-1b": (32, 8, 64), "llama3-8b": (32, 8, 128),
+              "qwen2-0.5b": (14, 2, 64)}
+# deepseek-v2-lite latent dims: heads, kv_lora_rank, qk_rope_head_dim.
+MLA_H, MLA_DC, MLA_DR = 16, 512, 64
+MLA_SCALE = (128 + MLA_DR) ** -0.5
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one chip of a described v5e 2x2 host."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    # A compile for a described chip is written to JAX's persistent cache
+    # but cannot be read back without one: keep the cache out of it.
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(chip, tree):
+    """Shapes of ``tree``'s leaves, placed on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=chip),
+        tree)
+
+
+def _compiles_with_kernel(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _gqa_args(chip, width, quantized, ragged):
+    H, KV, hd = GQA_WIDTHS[width]
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    pages = S((NP, PAGE, KV, hd), I8 if quantized else BF16)
+    if ragged:
+        args = [S((1, T, H, hd), BF16), pages, pages, S((R, P), I32),
+                S((1, T), I32), S((R,), I32), S((T,), I32)]
+    else:
+        args = [S((R, 1, H, hd), BF16), pages, pages, S((R, P), I32),
+                S((R, 1), I32), S((R,), I32)]
+    if quantized:
+        args += [S((NP, PAGE, KV, 1), F32)] * 2
+    return args
+
+
+@pytest.mark.parametrize("width", sorted(GQA_WIDTHS))
+@pytest.mark.parametrize("kernel", [
+    "paged_attention_pallas", "paged_attention_pallas_q",
+    "ragged_paged_attention_pallas", "ragged_paged_attention_pallas_q",
+])
+def test_gqa_kernel_compiles_for_v5e(chip, kernel, width):
+    args = _gqa_args(chip, width, quantized=kernel.endswith("_q"),
+                     ragged=kernel.startswith("ragged"))
+    _compiles_with_kernel(getattr(K, kernel), *args)
+
+
+def test_tokengrid_kernel_compiles_for_v5e(chip):
+    args = _gqa_args(chip, "llama3-8b", quantized=False, ragged=True)
+    _compiles_with_kernel(K.ragged_paged_attention_pallas_tokengrid, *args)
+
+
+@pytest.mark.parametrize("kernel", [
+    "paged_mla_attention_pallas", "paged_mla_attention_pallas_q",
+    "ragged_paged_mla_attention_pallas",
+    "ragged_paged_mla_attention_pallas_q",
+])
+def test_mla_kernel_compiles_for_v5e(chip, kernel):
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    quantized, ragged = kernel.endswith("_q"), kernel.startswith("ragged")
+    dt = I8 if quantized else BF16
+    pools = [S((NP, PAGE, 1, MLA_DC), dt), S((NP, PAGE, 1, MLA_DR), dt)]
+    scales = [S((NP, PAGE, 1, 1), F32)] * 2 if quantized else []
+    kern = getattr(K, kernel)
+    if ragged:
+        fn = lambda ql, qp, c, pe, tab, pos, lens, rows, *sc: kern(
+            ql, qp, c, pe, tab, pos, lens, rows, MLA_SCALE, *sc)
+        args = [S((1, T, MLA_H, MLA_DC), BF16), S((1, T, MLA_H, MLA_DR), BF16),
+                *pools, S((R, P), I32), S((1, T), I32), S((R,), I32),
+                S((T,), I32), *scales]
+    else:
+        fn = lambda ql, qp, c, pe, tab, pos, lens, *sc: kern(
+            ql, qp, c, pe, tab, pos, lens, MLA_SCALE, *sc)
+        args = [S((R, 1, MLA_H, MLA_DC), BF16), S((R, 1, MLA_H, MLA_DR), BF16),
+                *pools, S((R, P), I32), S((R, 1), I32), S((R,), I32), *scales]
+    _compiles_with_kernel(fn, *args)
+
+
+# ---- the engine's own step programs, whole, at chip_smoke's serving size ----
+
+
+@pytest.fixture()
+def abstract_engine(chip, monkeypatch):
+    """An ``Engine`` of llama3-1b whose parameters and KV pool are shapes
+    on the described chip: its jitted step programs are the ones a server
+    on the chip compiles, pool and program together against 16 GB. The
+    engine picks the kernel from ``jax.default_backend()``, which is the
+    CPU here, so the test asks for it outright."""
+    import chip_smoke
+    from rbg_tpu.engine import engine as E
+
+    init, create = E.init_params, E.PagedKVCache.create
+    monkeypatch.setattr(
+        E, "init_params",
+        lambda mcfg, key: _on(chip, jax.eval_shape(lambda: init(mcfg, key))))
+    monkeypatch.setattr(
+        E.PagedKVCache, "create",
+        lambda *a, **kw: _on(chip, jax.eval_shape(lambda: create(*a, **kw))))
+    cfg = EngineConfig(use_pallas="always", **chip_smoke.SERVE_CONFIG)
+    return E.Engine(cfg)
+
+
+def test_unified_step_of_llama3_1b_fits_and_holds_the_kernel(
+        chip, abstract_engine):
+    eng = abstract_engine
+    Rb, Tb = eng.cfg.max_batch, eng.cfg.max_batch * eng.cfg.prefill_chunk
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    compiled = eng._get_ragged_fn(Rb, Tb).lower(
+        eng.params, S((1, Tb), I32), S((1, Tb), I32), S((1, Tb), bool),
+        S((Tb,), I32), S((Rb,), I32), S((Rb, eng.cfg.max_pages_per_seq), I32),
+        eng.cache.k_pages, eng.cache.v_pages, None, None).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# Half a minute of compile, so outside tier-1; chip_smoke.py's reference
+# phase lowers the same program on the chip in every run.
+@pytest.mark.slow
+def test_fused_decode_of_llama3_1b_fits_and_holds_the_kernel(
+        chip, abstract_engine):
+    from rbg_tpu.engine.sampler import row_keys
+    eng = abstract_engine
+    B, Pm, Kw = eng.cfg.max_batch, eng.cfg.max_pages_per_seq, eng.cfg.multi_step
+    temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
+    small = _on(chip, (
+        jnp.zeros(B, I32), jnp.zeros(B, I32), jnp.zeros(B, I32),
+        jnp.zeros((B, Pm), I32), jnp.zeros((B, Kw), bool), jnp.zeros(B, I32)))
+    tail = _on(chip, (row_keys(seeds, eng._sample_base, rids),
+                      jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
+                      jnp.asarray(mps)))
+    compiled = eng._get_decode_fn(B, False, False, False, False, False).lower(
+        eng.params, *small, eng.cache.k_pages, eng.cache.v_pages, None, None,
+        *tail).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -42,12 +42,18 @@ def test_arming_matrix(monkeypatch, value, expect):
     assert jitwatch.enabled() == bool(expect)
 
 
+def _listening() -> bool:
+    """Does a compile reach jitwatch's records right now?"""
+    before = len(jitwatch.compiles())
+    _compile_cataloged("listening_probe")
+    return len(jitwatch.compiles()) > before
+
+
 def test_off_by_default_nothing_patched(monkeypatch):
     monkeypatch.delenv("RBG_JITWATCH", raising=False)
     jitwatch.disarm()
-    from jax._src import compiler
     from jax._src.array import ArrayImpl
-    assert compiler.backend_compile.__name__ != "traced_backend_compile"
+    assert not _listening()
     item = getattr(ArrayImpl, "item", None)
     assert item is None or not item.__name__.startswith("jitwatch_")
     assert jax.device_get.__name__ != "traced_device_get"
@@ -57,15 +63,28 @@ def test_off_by_default_nothing_patched(monkeypatch):
 
 
 def test_disarm_restores_all_seams(watch):
-    from jax._src import compiler
-    orig_compile = compiler.backend_compile
     orig_get = jax.device_get
     watch.arm()
-    assert compiler.backend_compile is not orig_compile
+    assert _listening()
     assert jax.device_get is not orig_get
     watch.disarm()
-    assert compiler.backend_compile is orig_compile
+    assert not _listening()
     assert jax.device_get is orig_get
+
+
+def test_blind_hook_makes_arm_raise(watch, monkeypatch):
+    """A JAX that renames or drops the compile event must fail the gate
+    loudly: arm() compiles a throwaway program and insists on seeing it."""
+    monkeypatch.setattr(jitwatch, "COMPILE_EVENT", "/jax/no/such/event")
+    with pytest.raises(watch.JitWatchBlindError):
+        watch.arm()
+    # A failed arm leaves nothing installed behind.
+    assert jax.device_get.__name__ != "traced_device_get"
+
+
+def test_self_test_leaves_no_record(watch):
+    watch.arm()
+    assert watch.compiles() == [] and watch.warmed_programs() == set()
 
 
 # ---- warmup_complete gating ----
@@ -146,8 +165,7 @@ def test_reset_clears_records_but_keeps_hooks(watch):
     watch.reset()
     assert not watch.gate_armed()
     assert watch.compiles() == [] and watch.warmed_programs() == set()
-    from jax._src import compiler
-    assert compiler.backend_compile.__name__ == "traced_backend_compile"
+    assert _listening()
 
 
 def test_warmup_complete_without_arm_is_harmless(monkeypatch):

@@ -6,7 +6,8 @@ Flags, at tracer call sites:
 
 * names not in the catalog — at calls on the trace module itself
   (``trace.start_trace`` / ``trace.ingress_span`` / ``trace.child`` /
-  ``trace.from_wire``, resolved through this file's imports) and at
+  ``trace.from_wire`` / ``trace.annotation``, resolved through this
+  file's imports) and at
   ``<span>.child(...)`` method calls whose first argument is a
   dotted-lowercase span literal or a catalog constant;
 * names that break the ``component.phase`` naming contract (lowercase
@@ -32,7 +33,7 @@ TRACE_MODULE = "rbg_tpu.obs.trace"
 
 # Functions on the trace module that take a span name, and where it sits.
 TRACE_FUNCS = {"child": 0, "start_trace": 0, "ingress_span": 0,
-               "from_wire": 1}
+               "from_wire": 1, "annotation": 0}
 
 # Naming contract: lowercase dotted component.phase (underscores allowed).
 SPAN_NAME_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)+$")

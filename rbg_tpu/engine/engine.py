@@ -43,7 +43,56 @@ from rbg_tpu.obs.names import (PROGRAM_FUSED_DECODE, PROGRAM_PAGED_FWD,
                                PROGRAM_SPEC_VERIFY)
 from rbg_tpu.models.llama import forward_paged, forward_ragged, init_params
 from rbg_tpu.obs import names as obs_names
+from rbg_tpu.obs import trace
 from rbg_tpu.obs.metrics import REGISTRY
+
+# Step records kept for the ``traces`` op (about a minute of 14 ms steps).
+STEP_RING = 4096
+
+
+class _Phase:
+    """One phase of a step: its ``jax.profiler`` annotation, the stamp of
+    when it last began (for the step record), and its wall time added to
+    a cumulative clock in ``Engine.metrics``. A phase entered inside
+    another suspends the outer one's clock (a drain of the pending decode
+    window inside ``engine.pack`` is sync and emit time, not pack time),
+    so the clocks never overlap and sum to no more than the step."""
+
+    __slots__ = ("eng", "idx", "ann", "outer", "t0")
+    SPANS = (obs_names.SPAN_ENGINE_ADMIT, obs_names.SPAN_ENGINE_PACK,
+             obs_names.SPAN_ENGINE_DISPATCH, obs_names.SPAN_ENGINE_SYNC,
+             obs_names.SPAN_ENGINE_EMIT)
+    CLOCKS = ("t_admit_s", "t_pack_s", "t_dispatch_s", "t_sync_s",
+              "t_emit_s")
+
+    def __init__(self, eng: "Engine", idx: int):
+        self.eng = eng
+        self.idx = idx
+        self.ann = trace.annotation(self.SPANS[idx])
+
+    def __enter__(self):
+        eng = self.eng
+        self.ann.__enter__()
+        now = self.t0 = eng._marks[self.idx] = time.monotonic()
+        outer = self.outer = eng._phase_now
+        if outer is not None:
+            outer._stop(now)
+        eng._phase_now = self
+        return self
+
+    def __exit__(self, *exc):
+        now = time.monotonic()
+        self._stop(now)
+        outer = self.eng._phase_now = self.outer
+        if outer is not None:
+            outer.t0 = now
+        self.ann.__exit__(*exc)
+
+    def _stop(self, now: float) -> None:
+        self.eng.metrics[self.CLOCKS[self.idx]] += now - self.t0
+
+
+_ADMIT, _PACK, _DISPATCH, _SYNC, _EMIT = range(5)
 
 
 @dataclasses.dataclass
@@ -156,10 +205,12 @@ class Engine:
         # window later. Loop-thread-confined (single-writer, like all
         # engine state); cleared at the end of every step.
         self.join_hint = False
-        # Seconds each admitted request waited between entering the engine
-        # queue and joining the running batch — drained by the service
-        # loop into rbg_serving_join_latency_seconds.
-        self.last_join_waits: List[float] = []
+        # ``(seconds, request id, time.perf_counter())`` of each admission:
+        # how long the request waited between entering the engine queue
+        # and joining the running batch, and when it joined — drained by
+        # the service loop into rbg_serving_join_latency_seconds and its
+        # own queue-wait clock.
+        self.last_join_waits: List[Tuple[float, int, float]] = []
         self.grammar = None     # TokenGrammar — enable_json_grammar()
         self._token_bytes = None
         self._grammar_eos = None
@@ -182,7 +233,31 @@ class Engine:
                         "preemptions": 0,
                         "spec_drafted": 0, "spec_accepted": 0,
                         "spec_steps": 0, "unified_steps": 0, "joins": 0,
-                        "join_wait_steps_max": 0, "join_excess_steps_max": 0}
+                        "join_wait_steps_max": 0, "join_excess_steps_max": 0,
+                        # The step timeline (docs/observability.md):
+                        # cumulative seconds and counts, never reset.
+                        # ``steps`` counts every turn of step();
+                        # ``steps_run`` those that dispatched a program.
+                        "t_step_s": 0.0, "t_admit_s": 0.0, "t_pack_s": 0.0,
+                        "t_dispatch_s": 0.0, "t_sync_s": 0.0,
+                        "t_emit_s": 0.0, "steps_run": 0,
+                        "t_unified_s": 0.0, "unified_steps_run": 0,
+                        "t_decode_s": 0.0, "decode_steps_run": 0,
+                        "kv_live_token_steps": 0, "kv_held_slot_steps": 0}
+        # The step being run: when each phase last began and which one
+        # is running (``_Phase``), and what the step first dispatched
+        # (``_note_dispatch``).
+        self._marks: List[Optional[float]] = [None] * 5
+        self._phase_now: Optional[_Phase] = None
+        self._dispatched: Optional[tuple] = None
+        # One record per run step, ``(t0, t_pack, t_dispatch, t_sync,
+        # t_emit, t_end, kind, rows, q_tokens, bucket, step_num)`` on
+        # time.monotonic(); appended by the loop thread, read by the
+        # ``traces`` op through steps_since().
+        self.step_ring: collections.deque = collections.deque(
+            maxlen=STEP_RING)
+        self._ring_dropped = 0
+        self._ring_dropped_t0 = 0.0
 
     def _shard_state(self, mesh):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -593,16 +668,93 @@ class Engine:
         if self._deferred_events:
             events.extend(self._deferred_events)
             self._deferred_events = []
-        self.metrics["steps"] += 1
-        self._admit()
-        if self._unified_eligible():
-            self.metrics["unified_steps"] += 1
-            events.extend(self._unified_step())
-        else:
-            events.extend(self._prefill_step())
-            events.extend(self._decode_step())
-        self.join_hint = False
+        m = self.metrics
+        m["steps"] += 1
+        self._marks = [None] * 5
+        self._dispatched = None
+        t0 = time.monotonic()
+        with trace.annotation(obs_names.SPAN_ENGINE_STEP,
+                              step_num=m["steps_run"]) as ann:
+            with _Phase(self, _ADMIT):
+                self._admit()
+            if self._unified_eligible():
+                m["unified_steps"] += 1
+                events.extend(self._unified_step())
+            else:
+                events.extend(self._prefill_step())
+                events.extend(self._decode_step())
+            self.join_hint = False
+            t_end = time.monotonic()
+            m["t_step_s"] += t_end - t0
+            if self._dispatched is not None:
+                self._record_step(t0, t_end, ann)
         return events
+
+    def _note_dispatch(self, kind: str, rows: int, q_tokens: int,
+                       row_bucket: int, token_bucket: int) -> None:
+        """What this step ran, noted where it dispatches its first device
+        program; that one names the step's kind (a step of the split path
+        that prefills and decodes is a ``prefill`` step)."""
+        if self._dispatched is None:
+            self._dispatched = (kind, rows, q_tokens,
+                                (row_bucket, token_bucket))
+
+    def _record_step(self, t0: float, t_end: float, ann) -> None:
+        """Account one step that dispatched: its wall time by kind, the
+        cache it held against the cache it used, and its record in the
+        ring (stamps of phases that did not run, or ran only before an
+        earlier phase, read as the stamp that follows them)."""
+        m = self.metrics
+        kind, rows, q_tokens, bucket = self._dispatched
+        ann.set_metadata(kind=kind, rows=rows, q_tokens=q_tokens,
+                         row_bucket=bucket[0], token_bucket=bucket[1])
+        step_num = m["steps_run"]
+        m["steps_run"] = step_num + 1
+        if kind == "unified":
+            m["t_unified_s"] += t_end - t0
+            m["unified_steps_run"] += 1
+        elif kind == "decode":
+            m["t_decode_s"] += t_end - t0
+            m["decode_steps_run"] += 1
+        live = held = 0
+        for r in self.running:
+            live += r.seq_len
+            held += len(r.pages)
+        m["kv_live_token_steps"] += live
+        m["kv_held_slot_steps"] += held * self.cfg.page_size
+        marks = self._marks
+        prev = marks[_PACK]
+        for i in (_DISPATCH, _SYNC, _EMIT):
+            if marks[i] is not None:
+                if marks[i] < prev:
+                    marks[i] = None
+                else:
+                    prev = marks[i]
+        nxt = t_end
+        for i in (_EMIT, _SYNC, _DISPATCH, _PACK):
+            if marks[i] is None:
+                marks[i] = nxt
+            nxt = marks[i]
+        ring = self.step_ring
+        if len(ring) == ring.maxlen:
+            self._ring_dropped += 1
+            self._ring_dropped_t0 = ring[0][0]
+        ring.append((t0, marks[_PACK], marks[_DISPATCH], marks[_SYNC],
+                     marks[_EMIT], t_end, kind, rows, q_tokens, bucket,
+                     step_num))
+
+    def steps_since(self, since: float = 0.0) -> dict:
+        """The ``traces`` op's view of the ring: the records that began
+        after ``since`` (time.monotonic() seconds), oldest first, and
+        ``steps_dropped``: 0 when the ring still held every such record,
+        else how many it has overwritten so far (an upper bound on those
+        missed; ``step_num``, the last field, counts them exactly)."""
+        # Called from a connection thread while the loop thread appends:
+        # the copy is one C call under the interpreter lock.
+        records = list(self.step_ring)
+        dropped = self._ring_dropped if self._ring_dropped_t0 > since else 0
+        return {"steps": [r for r in records if r[0] > since],
+                "steps_dropped": dropped}
 
     def generate(self, prompts: List[List[int]],
                  sampling: Optional[SamplingParams] = None) -> List[List[int]]:
@@ -658,7 +810,8 @@ class Engine:
                 self.metrics["join_wait_steps_max"], wait)
             self.metrics["join_excess_steps_max"] = max(
                 self.metrics["join_excess_steps_max"], excess)
-            self.last_join_waits.append(time.perf_counter() - req.t_enqueue)
+            now = time.perf_counter()
+            self.last_join_waits.append((now - req.t_enqueue, req.id, now))
             # Bounded: only the service loop drains this (PD workers and
             # generate() step the engine directly) — cap so an undrained
             # engine never leaks; the loop drains every step, so real
@@ -1034,6 +1187,58 @@ class Engine:
         rows on top of an undrained window would double-write KV slots
         and corrupt the stream."""
         events: List[StepEvent] = list(self._drain_decode())
+        with _Phase(self, _PACK):
+            packed = self._pack_unified()
+        if packed is None:
+            return events
+        entries, sample_rows, Ttot, Rb, Tb, dev = packed
+
+        with _Phase(self, _DISPATCH):
+            self._note_dispatch("unified", len(entries), Ttot, Rb, Tb)
+            fn = self._get_ragged_fn(Rb, Tb)
+            logits, kp, vp, ksc, vsc = fn(
+                self.params, *dev, self.cache.k_pages, self.cache.v_pages,
+                self.cache.k_scales, self.cache.v_scales)
+            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
+                                      k_scales=ksc, v_scales=vsc)
+
+            # Host bookkeeping for prefill rows (before emission, matching
+            # the legacy order: seq_len is advanced, then the finish token
+            # emits).
+            for req, start, end in entries:
+                if end > start:
+                    req.prefill_pos = end
+                    req.seq_len = end
+                    self.metrics["prefill_tokens"] += end - start
+            if not sample_rows:
+                return events
+            toks, lps = self._sample_unified(logits, sample_rows)
+        with _Phase(self, _SYNC):
+            # One batched fetch instead of two sequential np.asarray syncs
+            # (device_get resolves both leaves in a single transfer; a
+            # None lps leaf passes through untouched).
+            # lint: allow[jit-hygiene] the step's one intrinsic emission fetch — sampled tokens must reach the host to stream
+            toks, lps = jax.device_get((toks, lps))
+        with _Phase(self, _EMIT):
+            for n, (req, _, _, is_decode) in enumerate(sample_rows):
+                lpv = (float(lps[n])
+                       if lps is not None and req.sampling.logprobs else None)
+                if is_decode:
+                    req.seq_len += 1
+                    self.metrics["decode_tokens"] += 1
+                else:
+                    req.state = "running"
+                    req.t_first = time.perf_counter()
+                events.append(self._emit(req, int(toks[n]), lpv))
+        return events
+
+    # hot_path
+    def _pack_unified(self):
+        """The unified step's host side: this step's rows, their packed
+        arrays in numpy, and the uploads. Returns None when no row has
+        work, else (entries, sample_rows, packed tokens, row bucket, token
+        bucket, the six device arrays in the ragged program's argument
+        order)."""
         decode = [r for r in self.running if r.state == "running"]
         self._grow_decode_pages(decode)
 
@@ -1046,7 +1251,7 @@ class Engine:
             elif r.state == "running":
                 entries.append((r, r.seq_len, r.seq_len))
         if not entries:
-            return events
+            return None
 
         P = self.cfg.max_pages_per_seq
         Rb = self._bucket(len(entries))
@@ -1084,29 +1289,15 @@ class Engine:
             row_ids[off:off + n] = i
             table[i, :len(req.pages)] = req.pages
             off += n
+        dev = [jnp.asarray(a) for a in (tok, pos, tmask, row_ids, kvl, table)]
+        return entries, sample_rows, Ttot, Rb, Tb, dev
 
-        fn = self._get_ragged_fn(Rb, Tb)
-        logits, kp, vp, ksc, vsc = fn(
-            self.params, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(tmask), jnp.asarray(row_ids), jnp.asarray(kvl),
-            jnp.asarray(table), self.cache.k_pages, self.cache.v_pages,
-            self.cache.k_scales, self.cache.v_scales)
-        self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                  k_scales=ksc, v_scales=vsc)
-
-        # Host bookkeeping for prefill rows (before emission, matching the
-        # legacy order: seq_len is advanced, then the finish token emits).
-        for req, start, end in entries:
-            if end > start:
-                req.prefill_pos = end
-                req.seq_len = end
-                self.metrics["prefill_tokens"] += end - start
-        if not sample_rows:
-            return events
-
-        # One batched sampler dispatch for every sampling row — decode
-        # steps and finishing prefills together (the _prefill_step /
-        # fused-scan sampler, so outputs stay bit-identical).
+    # hot_path
+    def _sample_unified(self, logits, sample_rows):
+        """One batched sampler dispatch for every sampling row — decode
+        steps and finishing prefills together (the _prefill_step /
+        fused-scan sampler, so outputs stay bit-identical). Returns the
+        device arrays (tokens, logprobs or None)."""
         reqs = [r for r, _, _, _ in sample_rows]
         Bs = self._bucket(len(sample_rows))
         pad = Bs - len(sample_rows)
@@ -1137,23 +1328,7 @@ class Engine:
             for n, req in enumerate(reqs):
                 np.add.at(oc[n], np.asarray(req.output, np.int64), 1)
             args += [pmask, jnp.asarray(oc), rep, pres, freq]
-        toks, lps = self._get_sampler(pen, lp, tpmp)(*args)
-        # One batched fetch instead of two sequential np.asarray syncs
-        # (device_get resolves both leaves in a single transfer; a None
-        # lps leaf passes through untouched).
-        # lint: allow[jit-hygiene] the step's one intrinsic emission fetch — sampled tokens must reach the host to stream
-        toks, lps = jax.device_get((toks, lps))
-        for n, (req, _, _, is_decode) in enumerate(sample_rows):
-            lpv = (float(lps[n]) if lps is not None and req.sampling.logprobs
-                   else None)
-            if is_decode:
-                req.seq_len += 1
-                self.metrics["decode_tokens"] += 1
-            else:
-                req.state = "running"
-                req.t_first = time.perf_counter()
-            events.append(self._emit(req, int(toks[n]), lpv))
-        return events
+        return self._get_sampler(pen, lp, tpmp)(*args)
 
     # ---- prefill ----
 
@@ -1173,6 +1348,8 @@ class Engine:
             rows.append((req, start, end))
 
         B = self._bucket(len(batch))
+        self._note_dispatch("prefill", len(rows),
+                            sum(e - s for _, s, e in rows), B, B * chunk)
         logits = self._run(
             tokens=[req.prompt[s:e] for req, s, e in rows],
             positions=[list(range(s, e)) for _, s, e in rows],
@@ -1194,6 +1371,27 @@ class Engine:
 
         # One batched sample for every finishing row — a single gather +
         # sampler dispatch + host transfer (mirrors the decode path).
+        with _Phase(self, _DISPATCH):
+            toks, lps, reqs = self._sample_finishing(logits, finishing)
+        with _Phase(self, _SYNC):
+            # One batched fetch — same single-transfer emission as the
+            # unified step.
+            toks, lps = jax.device_get((toks, lps))
+        events = []
+        with _Phase(self, _EMIT):
+            for n, req in enumerate(reqs):
+                req.state = "running"
+                req.t_first = time.perf_counter()
+                events.append(self._emit(
+                    req, int(toks[n]),
+                    float(lps[n]) if lps is not None and req.sampling.logprobs
+                    else None))
+        return events
+
+    def _sample_finishing(self, logits, finishing):
+        """The split prefill path's sampler dispatch for the rows whose
+        prompt ended in this chunk: (tokens, logprobs or None) on the
+        device, and the requests in row order."""
         Bs = self._bucket(len(finishing))
         pad = Bs - len(finishing)
         row_idx = np.asarray([i for i, _, _ in finishing] + [0] * pad, np.int32)
@@ -1224,18 +1422,7 @@ class Engine:
             pmask, oc_base, rep, pres, freq = self._penalty_rows(reqs, Bs)
             args += [pmask, jnp.asarray(oc_base), rep, pres, freq]
         toks, lps = self._get_sampler(pen, lp, tpmp)(*args)
-        # One batched fetch — same single-transfer emission as the
-        # unified step.
-        toks, lps = jax.device_get((toks, lps))
-        events = []
-        for n, req in enumerate(reqs):
-            req.state = "running"
-            req.t_first = time.perf_counter()
-            events.append(self._emit(
-                req, int(toks[n]),
-                float(lps[n]) if lps is not None and req.sampling.logprobs
-                else None))
-        return events
+        return toks, lps, reqs
 
     def _sampling_rows(self, reqs, B: int):
         """Per-row sampling arrays + static variant flags for a batch —
@@ -1347,17 +1534,19 @@ class Engine:
 
     def _emit_pending(self, pending) -> List[StepEvent]:
         rows, toks_dev, lp_dev, valid = pending
-        vals = np.asarray(toks_dev)          # [K, B] — the one host sync
-        lpv = np.asarray(lp_dev) if lp_dev is not None else None
+        with _Phase(self, _SYNC):
+            vals = np.asarray(toks_dev)      # [K, B] — the one host sync
+            lpv = np.asarray(lp_dev) if lp_dev is not None else None
         events = []
-        for i, req in enumerate(rows):
-            for k in range(valid[i]):
-                if req.state != "running":
-                    break                    # stop token cut the window short
-                self.metrics["decode_tokens"] += 1
-                lp = (float(lpv[k, i])
-                      if lpv is not None and req.sampling.logprobs else None)
-                events.append(self._emit(req, int(vals[k, i]), lp))
+        with _Phase(self, _EMIT):
+            for i, req in enumerate(rows):
+                for k in range(valid[i]):
+                    if req.state != "running":
+                        break                # stop token cut the window short
+                    self.metrics["decode_tokens"] += 1
+                    lp = (float(lpv[k, i]) if lpv is not None
+                          and req.sampling.logprobs else None)
+                    events.append(self._emit(req, int(vals[k, i]), lp))
         return events
 
     def _drain_decode(self) -> List[StepEvent]:
@@ -1569,6 +1758,57 @@ class Engine:
     # hot_path
     def _fused_decode_step(self) -> List[StepEvent]:
         events: List[StepEvent] = []
+        with _Phase(self, _PACK):
+            packed = self._pack_decode(events)
+        if packed is None:
+            return events
+        st, batch, K = packed
+
+        with _Phase(self, _DISPATCH):
+            self._note_dispatch("decode", len(batch), len(batch) * K,
+                                st["B"], st["B"] * K)
+            fn = self._get_decode_fn(st["B"], st["pen"], st["lp"],
+                                     st["tpmp"], st["lids"] is not None,
+                                     st["gr"], K=K)
+            kw = {}
+            if st["pen"]:
+                kw.update(pmask=st["pmask"], ocounts=st["ocounts"],
+                          rep=st["rep"], pres=st["pres"], freq=st["freq"])
+            if st["lids"] is not None:
+                kw.update(lora=self.lora_stack, lids=st["lids"])
+            if st["gr"]:
+                kw.update(gnext=st["gnext"], glegal=st["glegal"],
+                          gstate=st["gstate"], gactive=st["gactive"])
+            toks_seq, lp_seq, tok, pos, kvl, kp, vp, ksc, vsc, oc, gs = fn(
+                self.params, st["tok"], st["pos"], st["kvl"], st["table"],
+                st["mask"], st["limit"], self.cache.k_pages,
+                self.cache.v_pages, self.cache.k_scales, self.cache.v_scales,
+                st["keys"], st["temps"], st["ks"], st["tps"], st["mps"], **kw)
+            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
+                                      k_scales=ksc, v_scales=vsc)
+            st["tok"], st["pos"], st["kvl"] = tok, pos, kvl
+            if st["pen"]:
+                st["ocounts"] = oc
+            if st["gr"]:
+                st["gstate"] = gs
+            valid = []
+            for req in batch:
+                valid.append(min(K, req.max_len() - req.seq_len))
+                req.seq_len = min(req.seq_len + K, req.max_len())
+
+            prev, st["pending"] = st["pending"], (list(batch), toks_seq,
+                                                  lp_seq, valid)
+        if prev is not None:
+            events.extend(self._emit_pending(prev))
+        return events
+
+    # hot_path
+    def _pack_decode(self, events: List[StepEvent]):
+        """The decode step's host side: the batch, pages for its window
+        (preempting on exhaustion), and the device state, built anew when
+        the batch changed and patched when only its pages did. Whatever a
+        drain emits on the way is appended to ``events``. Returns None
+        when no row is left to dispatch, else (state, batch, window)."""
         batch = self._decode_batch()
         st = self._dec
         if st is not None and st["rows"] != batch:
@@ -1577,7 +1817,7 @@ class Engine:
             batch = self._decode_batch()
         if not batch:
             events.extend(self._drain_decode())
-            return events
+            return None
 
         # Ensure pages exist for the whole decode window; preempt the
         # youngest requests on exhaustion. Oldest-first so old requests
@@ -1629,7 +1869,7 @@ class Engine:
                 st = None
             batch = batch2
         if not batch:
-            return events
+            return None
 
         if st is None:
             st = self._dec = self._build_decode_state(batch)
@@ -1639,41 +1879,7 @@ class Engine:
                 row[:len(r.pages)] = r.pages
                 row[len(r.pages):] = 0
             st["table"] = jnp.asarray(st["table_np"])
-
-        fn = self._get_decode_fn(st["B"], st["pen"], st["lp"],
-                                 st["tpmp"], st["lids"] is not None,
-                                 st["gr"], K=K)
-        kw = {}
-        if st["pen"]:
-            kw.update(pmask=st["pmask"], ocounts=st["ocounts"],
-                      rep=st["rep"], pres=st["pres"], freq=st["freq"])
-        if st["lids"] is not None:
-            kw.update(lora=self.lora_stack, lids=st["lids"])
-        if st["gr"]:
-            kw.update(gnext=st["gnext"], glegal=st["glegal"],
-                      gstate=st["gstate"], gactive=st["gactive"])
-        toks_seq, lp_seq, tok, pos, kvl, kp, vp, ksc, vsc, oc, gs = fn(
-            self.params, st["tok"], st["pos"], st["kvl"], st["table"],
-            st["mask"], st["limit"], self.cache.k_pages, self.cache.v_pages,
-            self.cache.k_scales, self.cache.v_scales,
-            st["keys"], st["temps"], st["ks"], st["tps"], st["mps"], **kw)
-        self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                  k_scales=ksc, v_scales=vsc)
-        st["tok"], st["pos"], st["kvl"] = tok, pos, kvl
-        if st["pen"]:
-            st["ocounts"] = oc
-        if st["gr"]:
-            st["gstate"] = gs
-        valid = []
-        for req in batch:
-            valid.append(min(K, req.max_len() - req.seq_len))
-            req.seq_len = min(req.seq_len + K, req.max_len())
-
-        prev, st["pending"] = st["pending"], (list(batch), toks_seq, lp_seq,
-                                              valid)
-        if prev is not None:
-            events.extend(self._emit_pending(prev))
-        return events
+        return st, batch, K
 
     # ---- speculative decode (prompt-lookup drafting) ----
 
@@ -1747,128 +1953,134 @@ class Engine:
                  and len(r.output) < r.sampling.max_new_tokens]
         if not batch:
             return events
-        K = self.cfg.spec_k if self.cfg.speculative == "ngram" else 0
-        ps = self.cfg.page_size
-        drafts: Dict[int, List[int]] = {}
-        gmask_rows: Dict[int, list] = {}
-        # Draft + grow pages, oldest-first (preempt youngest on exhaustion;
-        # a row sheds its drafts before anyone gets preempted for them).
-        # Penalized rows never draft (their counts are sequential); grammar
-        # rows draft along the automaton — masks are computed assuming the
-        # draft prefix is accepted, which holds for every accepted prefix.
-        for req in sorted(batch, key=lambda r: r.t_submit):
-            if req.state != "running":
-                continue
-            cap = min(K, req.sampling.max_new_tokens - len(req.output) - 1,
-                      self.cfg.max_seq_len - req.seq_len - 1)
-            if cap > 0 and not req.sampling.needs_penalties():
-                self._ensure_ngram(req)
-                d = req.ngram.draft(cap)
-            else:
-                d = []
-            if req.gstate is not None:
-                g = req.grammar
-                s = req.gstate
-                masks = [self._gmask(g, s)]
-                kept = []
-                for dt in d:
-                    ns = g.advance_token(s, dt)
-                    if ns is None:
-                        break           # draft leaves the grammar — cut here
-                    kept.append(dt)
-                    masks.append(self._gmask(g, ns))
-                    s = ns
-                d = kept
-                gmask_rows[id(req)] = masks
-            while True:
-                need = (pages_for_tokens(req.seq_len + 1 + len(d), ps)
-                        - len(req.pages))
-                if need <= 0:
-                    break
-                extra = self._alloc(need)
-                if extra is not None:
-                    req.pages.extend(extra)
-                    break
-                if d:
-                    d = []          # shed drafts before preempting others
-                    continue
-                if self._preempt_youngest(exclude=req) is None:
-                    self._preempt(req)
-                    break
-            if req.state == "running":
-                drafts[id(req)] = d
-        batch = [r for r in batch if r.state == "running"]
-        if not batch:
-            return events
-
-        B = self._bucket(len(batch))
-        T = K + 1
-        P = self.cfg.max_pages_per_seq
-        tok = np.zeros((B, T), np.int32)
-        pos = np.zeros((B, T), np.int32)
-        mask = np.zeros((B, T), bool)
-        kvl = np.zeros(B, np.int32)
-        table = np.zeros((B, P), np.int32)
-        temps, ks, tps, mps, seeds, rids, pen, lp, tpmp = \
-            self._sampling_rows(batch, B)
-        gr = any(r.gstate is not None for r in batch)
-        gmasks = (np.ones((B, T, self.mcfg.vocab_size), bool)
-                  if gr else None)
-        for i, r in enumerate(batch):
-            d = drafts[id(r)]
-            tok[i, 0] = r.last_token
-            tok[i, 1:1 + len(d)] = d
-            pos[i, :] = r.seq_len + np.arange(T)
-            mask[i, :1 + len(d)] = True
-            kvl[i] = r.seq_len + 1 + len(d)
-            table[i, :len(r.pages)] = r.pages
-            if gr and id(r) in gmask_rows:
-                for t, m in enumerate(gmask_rows[id(r)]):
-                    gmasks[i, t] = m
-        kw = {}
-        if pen:
-            pmask, oc, rep, pres, freq = self._penalty_rows(batch, B)
-            for i, r in enumerate(batch):
-                np.add.at(oc[i], np.asarray(r.output, np.int64), 1)
-            kw.update(pmask=pmask, ocounts=jnp.asarray(oc), rep=rep,
-                      pres=pres, freq=freq)
-        if gr:
-            kw["gmasks"] = jnp.asarray(gmasks)
-        lids = self._lora_rows(batch, B)
-        if lids is not None:
-            kw.update(lora=self.lora_stack, lids=lids)
-        fn = self._get_spec_fn(B, lp, tpmp, pen, gr, lids is not None)
-        toks_out, lps_out, kp, vp, ksc, vsc = fn(
-            self.params, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(mask), jnp.asarray(kvl), jnp.asarray(table),
-            self.cache.k_pages, self.cache.v_pages,
-            self.cache.k_scales, self.cache.v_scales,
-            row_keys(seeds, self._sample_base, rids),
-            jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
-            jnp.asarray(mps), **kw)
-        self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                  k_scales=ksc, v_scales=vsc)
-        vals = np.asarray(toks_out)                       # [T, B]
-        lpv = np.asarray(lps_out) if lps_out is not None else None
-        self.metrics["spec_steps"] += 1
-        for i, req in enumerate(batch):
-            d = drafts[id(req)]
-            m = 0
-            while m < len(d) and int(vals[m, i]) == d[m]:
-                m += 1
-            # d_0..d_{m-1} verified; vals[m] is the true next token at the
-            # first mismatch (or the bonus token when every draft held).
-            self.metrics["spec_drafted"] += len(d)
-            self.metrics["spec_accepted"] += m
-            emit_n = m + 1
-            req.seq_len += emit_n   # KV valid through the last GOOD input
-            for t in range(emit_n):
+        with _Phase(self, _PACK):
+            K = self.cfg.spec_k if self.cfg.speculative == "ngram" else 0
+            ps = self.cfg.page_size
+            drafts: Dict[int, List[int]] = {}
+            gmask_rows: Dict[int, list] = {}
+            # Draft + grow pages, oldest-first (preempt youngest on exhaustion;
+            # a row sheds its drafts before anyone gets preempted for them).
+            # Penalized rows never draft (their counts are sequential); grammar
+            # rows draft along the automaton — masks are computed assuming the
+            # draft prefix is accepted, which holds for every accepted prefix.
+            for req in sorted(batch, key=lambda r: r.t_submit):
                 if req.state != "running":
-                    break           # stop token cut the window short
-                self.metrics["decode_tokens"] += 1
-                lpt = (float(lpv[t, i])
-                       if lpv is not None and req.sampling.logprobs else None)
-                events.append(self._emit(req, int(vals[t, i]), lpt))
+                    continue
+                cap = min(K, req.sampling.max_new_tokens - len(req.output) - 1,
+                          self.cfg.max_seq_len - req.seq_len - 1)
+                if cap > 0 and not req.sampling.needs_penalties():
+                    self._ensure_ngram(req)
+                    d = req.ngram.draft(cap)
+                else:
+                    d = []
+                if req.gstate is not None:
+                    g = req.grammar
+                    s = req.gstate
+                    masks = [self._gmask(g, s)]
+                    kept = []
+                    for dt in d:
+                        ns = g.advance_token(s, dt)
+                        if ns is None:
+                            break           # draft leaves the grammar — cut here
+                        kept.append(dt)
+                        masks.append(self._gmask(g, ns))
+                        s = ns
+                    d = kept
+                    gmask_rows[id(req)] = masks
+                while True:
+                    need = (pages_for_tokens(req.seq_len + 1 + len(d), ps)
+                            - len(req.pages))
+                    if need <= 0:
+                        break
+                    extra = self._alloc(need)
+                    if extra is not None:
+                        req.pages.extend(extra)
+                        break
+                    if d:
+                        d = []          # shed drafts before preempting others
+                        continue
+                    if self._preempt_youngest(exclude=req) is None:
+                        self._preempt(req)
+                        break
+                if req.state == "running":
+                    drafts[id(req)] = d
+            batch = [r for r in batch if r.state == "running"]
+            if not batch:
+                return events
+
+            B = self._bucket(len(batch))
+            T = K + 1
+            P = self.cfg.max_pages_per_seq
+            tok = np.zeros((B, T), np.int32)
+            pos = np.zeros((B, T), np.int32)
+            mask = np.zeros((B, T), bool)
+            kvl = np.zeros(B, np.int32)
+            table = np.zeros((B, P), np.int32)
+            temps, ks, tps, mps, seeds, rids, pen, lp, tpmp = \
+                self._sampling_rows(batch, B)
+            gr = any(r.gstate is not None for r in batch)
+            gmasks = (np.ones((B, T, self.mcfg.vocab_size), bool)
+                      if gr else None)
+            for i, r in enumerate(batch):
+                d = drafts[id(r)]
+                tok[i, 0] = r.last_token
+                tok[i, 1:1 + len(d)] = d
+                pos[i, :] = r.seq_len + np.arange(T)
+                mask[i, :1 + len(d)] = True
+                kvl[i] = r.seq_len + 1 + len(d)
+                table[i, :len(r.pages)] = r.pages
+                if gr and id(r) in gmask_rows:
+                    for t, m in enumerate(gmask_rows[id(r)]):
+                        gmasks[i, t] = m
+            kw = {}
+            if pen:
+                pmask, oc, rep, pres, freq = self._penalty_rows(batch, B)
+                for i, r in enumerate(batch):
+                    np.add.at(oc[i], np.asarray(r.output, np.int64), 1)
+                kw.update(pmask=pmask, ocounts=jnp.asarray(oc), rep=rep,
+                          pres=pres, freq=freq)
+            if gr:
+                kw["gmasks"] = jnp.asarray(gmasks)
+            lids = self._lora_rows(batch, B)
+            if lids is not None:
+                kw.update(lora=self.lora_stack, lids=lids)
+        with _Phase(self, _DISPATCH):
+            self._note_dispatch("spec", len(batch),
+                                int(mask.sum()), B, B * T)
+            fn = self._get_spec_fn(B, lp, tpmp, pen, gr, lids is not None)
+            toks_out, lps_out, kp, vp, ksc, vsc = fn(
+                self.params, jnp.asarray(tok), jnp.asarray(pos),
+                jnp.asarray(mask), jnp.asarray(kvl), jnp.asarray(table),
+                self.cache.k_pages, self.cache.v_pages,
+                self.cache.k_scales, self.cache.v_scales,
+                row_keys(seeds, self._sample_base, rids),
+                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
+                jnp.asarray(mps), **kw)
+            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
+                                      k_scales=ksc, v_scales=vsc)
+        with _Phase(self, _SYNC):
+            vals = np.asarray(toks_out)                       # [T, B]
+            lpv = np.asarray(lps_out) if lps_out is not None else None
+        with _Phase(self, _EMIT):
+            self.metrics["spec_steps"] += 1
+            for i, req in enumerate(batch):
+                d = drafts[id(req)]
+                m = 0
+                while m < len(d) and int(vals[m, i]) == d[m]:
+                    m += 1
+                # d_0..d_{m-1} verified; vals[m] is the true next token at the
+                # first mismatch (or the bonus token when every draft held).
+                self.metrics["spec_drafted"] += len(d)
+                self.metrics["spec_accepted"] += m
+                emit_n = m + 1
+                req.seq_len += emit_n   # KV valid through the last GOOD input
+                for t in range(emit_n):
+                    if req.state != "running":
+                        break           # stop token cut the window short
+                    self.metrics["decode_tokens"] += 1
+                    lpt = (float(lpv[t, i])
+                           if lpv is not None and req.sampling.logprobs else None)
+                    events.append(self._emit(req, int(vals[t, i]), lpt))
         return events
 
     def _emit(self, req: Request, tok: int,
@@ -2004,27 +2216,28 @@ class Engine:
         B = B_bucket or 1
         T = T_bucket
         P = self.cfg.max_pages_per_seq
-        tok = np.zeros((B, T), np.int32)
-        pos = np.zeros((B, T), np.int32)
-        mask = np.zeros((B, T), bool)
-        kvl = np.zeros((B,), np.int32)
-        table = np.zeros((B, P), np.int32)
-        for i, (ts, ps_, ln, pg) in enumerate(zip(tokens, positions, lens, pages)):
-            tok[i, :len(ts)] = ts
-            pos[i, :len(ps_)] = ps_
-            mask[i, :len(ts)] = True
-            kvl[i] = ln
-            table[i, :len(pg)] = pg
-        lids = self._lora_rows(reqs, B) if reqs is not None else None
-        kw = ({"lora": self.lora_stack, "lids": lids}
-              if lids is not None else {})
-        fn = self._get_fwd(B, T, lids is not None)
-        logits, k_pages, v_pages, k_scales, v_scales = fn(
-            self.params, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(mask),
-            jnp.asarray(kvl), jnp.asarray(table),
-            self.cache.k_pages, self.cache.v_pages,
-            self.cache.k_scales, self.cache.v_scales, **kw,
-        )
-        self.cache = PagedKVCache(k_pages=k_pages, v_pages=v_pages,
-                                  k_scales=k_scales, v_scales=v_scales)
+        with _Phase(self, _PACK):
+            tok = np.zeros((B, T), np.int32)
+            pos = np.zeros((B, T), np.int32)
+            mask = np.zeros((B, T), bool)
+            kvl = np.zeros((B,), np.int32)
+            table = np.zeros((B, P), np.int32)
+            for i, (ts, ps_, ln, pg) in enumerate(zip(tokens, positions,
+                                                      lens, pages)):
+                tok[i, :len(ts)] = ts
+                pos[i, :len(ps_)] = ps_
+                mask[i, :len(ts)] = True
+                kvl[i] = ln
+                table[i, :len(pg)] = pg
+            lids = self._lora_rows(reqs, B) if reqs is not None else None
+            kw = ({"lora": self.lora_stack, "lids": lids}
+                  if lids is not None else {})
+            dev = [jnp.asarray(a) for a in (tok, pos, mask, kvl, table)]
+        with _Phase(self, _DISPATCH):
+            fn = self._get_fwd(B, T, lids is not None)
+            logits, k_pages, v_pages, k_scales, v_scales = fn(
+                self.params, *dev, self.cache.k_pages, self.cache.v_pages,
+                self.cache.k_scales, self.cache.v_scales, **kw)
+            self.cache = PagedKVCache(k_pages=k_pages, v_pages=v_pages,
+                                      k_scales=k_scales, v_scales=v_scales)
         return logits  # device array; callers slice what they need

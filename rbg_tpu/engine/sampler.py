@@ -83,6 +83,7 @@ def _mask_top_p_min_p(scaled: jnp.ndarray, top_p: jnp.ndarray,
     return scaled
 
 
+@jax.named_scope("sampler")   # metadata only: names the ops in a profile
 def sample(
     logits: jnp.ndarray,        # [B, V] f32
     keys: jax.Array,            # [B] typed PRNG keys — per-row streams

@@ -233,7 +233,20 @@ class Handler(socketserver.BaseRequestHandler):
             # slowest request's waterfall, and the histogram exemplars
             # linking a bad quantile to a trace_id.
             from rbg_tpu.obs.trace import traces_response
-            send_msg(self.request, traces_response(obj.get("n", 10)))
+            resp = traces_response(obj.get("n", 10))
+            if "steps_since" in obj:
+                # The step timeline's ring (docs/observability.md): the
+                # engine's step records later than the caller's cursor.
+                svc = srv.service or srv.decode
+                eng = svc.engine if svc is not None else (
+                    srv.prefill.engine if srv.prefill is not None else None)
+                try:
+                    since = float(obj["steps_since"] or 0.0)
+                except (TypeError, ValueError):
+                    since = 0.0
+                resp.update(eng.steps_since(since) if eng is not None
+                            else {"steps": [], "steps_dropped": 0})
+            send_msg(self.request, resp)
             return
         if op in self._DATA_OPS:
             span = trace.from_wire(obj.get("trace"), names.SPAN_ENGINE_OP,

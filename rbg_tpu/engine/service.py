@@ -31,15 +31,18 @@ from rbg_tpu.utils.racetrace import guard as _race_guard
 
 
 class _Pending:
-    __slots__ = ("tokens", "logprobs", "done", "t_submit", "t_first", "error",
-                 "code", "deadline", "span_parent", "span_queue", "span_scan",
-                 "stream_rx")
+    __slots__ = ("tokens", "logprobs", "done", "t_submit", "t_admit",
+                 "t_first", "error", "code", "deadline", "span_parent",
+                 "span_queue", "span_scan", "stream_rx")
 
     def __init__(self, deadline: Optional[float] = None):
         self.tokens: List[int] = []
         self.logprobs: List[float] = []   # 1:1 with tokens when requested
         self.done = threading.Event()
         self.t_submit = time.perf_counter()
+        # When the engine first gave the request a batch row (None until
+        # then; a preempted request's later admissions do not move it).
+        self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None
         self.error: Optional[str] = None
         self.code: Optional[str] = None   # structured rejection code
@@ -198,6 +201,15 @@ class _BatchService:
         self._pf_t = time.monotonic()
         # Loop-thread-confined (admitted rows); deliberately NOT guarded.
         self._pending: Dict[int, _Pending] = {}
+        # The loop thread's share of the step timeline
+        # (docs/observability.md): cumulative seconds and counts, never
+        # reset, written by the loop thread only and copied whole by
+        # service_stats(). t_loop_s is the thread's wall time; intake,
+        # the engine's t_step_s, deliver and idle tile it.
+        self.timeline = {"t_loop_s": 0.0, "t_intake_s": 0.0,
+                         "t_deliver_s": 0.0, "t_idle_s": 0.0,
+                         "queue_wait_s": 0.0, "queue_waited": 0,
+                         "ttft_s": 0.0, "first_tokens": 0}
         self._lock = named_lock("engine.service_queue")
         self._wake = threading.Event()
         self._stopped = False
@@ -393,26 +405,35 @@ class _BatchService:
         cache). One wave per bucket size, largest first, through the
         normal submit path. Returns elapsed seconds."""
         t0 = time.monotonic()
+        took = {}
+
+        def timed(name, warmer):
+            t = time.monotonic()
+            warmer()
+            took[name + "_s"] = round(time.monotonic() - t, 3)
+
         # Ragged unified shapes first (all-pad dispatches, cache
         # untouched) — the prompt waves below only hit the packed-token
         # buckets their own composition happens to produce.
-        self.engine.warm_ragged()
+        timed("ragged", self.engine.warm_ragged)
+        t_waves = time.monotonic()
         for B in self._bucket_sizes():
             items = [(self._warm_item(input_len, B, i),
                       SamplingParams(max_new_tokens=out_len))
                      for i in range(B)]
             for p in self.submit_wave(items):
                 self.wait(p, 600.0)
+        took["waves_s"] = round(time.monotonic() - t_waves, 3)
         # The waves only compiled the fused-decode and sampler variants
         # their own composition hit (default sampling, wave-sized
         # buckets); warm_decode/warm_samplers cover the full plain
         # bucket × top-p grid — the gap the jitwatch sentry surfaced.
-        self.engine.warm_decode()
+        timed("decode", self.engine.warm_decode)
         # The waves compiled the K=multi_step fused programs; the K=1
         # early-exit twins (_decode_window's join shortening) would
         # otherwise first compile MID-SERVING, on the join-latency path.
-        self.engine.warm_join_windows()
-        self.engine.warm_samplers()
+        timed("join_windows", self.engine.warm_join_windows)
+        timed("samplers", self.engine.warm_samplers)
         # Arm the jitwatch gate (no-op unless RBG_JITWATCH armed the
         # hooks): everything compiled above is the blessed warmup set;
         # any cataloged program compiling after this is a violation.
@@ -424,7 +445,13 @@ class _BatchService:
         self._prefill_rate = None
         self._pf_tokens = self.engine.metrics.get("prefill_tokens", 0)
         self._pf_t = time.monotonic()
-        return time.monotonic() - t0
+        elapsed = time.monotonic() - t0
+        # Wall time of the last warm-up, whole and by warmer, on the wire
+        # (metrics op: ``warmup_s``, ``warmup.<warmer>_s``).
+        with self._lock:
+            self.counters["warmup_s"] = round(elapsed, 3)
+            self.counters["warmup"] = took
+        return elapsed
 
     def _warm_item(self, input_len: int, wave: int, row: int):
         raise NotImplementedError
@@ -464,6 +491,7 @@ class _BatchService:
         with self._lock:
             depth = len(self._queue)
             out = dict(self.counters)
+        out.update(self.timeline)
         est = self.estimated_wait_s(depth)
         out["queue_depth"] = depth
         out["max_queue"] = self.max_queue
@@ -548,80 +576,18 @@ class _BatchService:
             REGISTRY.inc(names.SERVING_TOKENS_TOTAL, float(n), service=svc)
 
     def _loop(self):
+        """One turn: intake, then either an idle wait or one engine step
+        and the delivery of what it emitted. Each part is a phase of the
+        step timeline: a profiler annotation and a cumulative clock."""
         eng = self.engine
+        tl = self.timeline
+        t_start = time.monotonic()
         while not self._stopped:
             now = time.monotonic()
-            with self._lock:
-                cancels = self._cancels
-                self._cancels = []
-                expired = self._expire_queue_locked(now)
-                # Admission control: never exceed the engine's batch ceiling —
-                # excess items stay queued for later rounds.
-                budget = max(0, eng.cfg.max_batch
-                             - len(eng.running) - len(eng.waiting))
-                newly = self._queue[:budget]
-                self._queue = self._queue[budget:]
-                # Continuous batching: submissions still queued beyond this
-                # step's budget shorten the engine's fused decode window so
-                # the next free slot absorbs them at step granularity.
-                eng.join_hint = bool(self._queue)
-            if expired:
-                with self._lock:
-                    self.counters["deadline_queue_drops"] += len(expired)
-            for pending in expired:
-                REGISTRY.inc(names.SERVING_DEADLINE_EXCEEDED_TOTAL,
-                             stage="queue")
-                pending.error = "deadline expired before admission"
-                pending.code = CODE_DEADLINE
-                pending.span_queue.end(outcome="deadline_dropped")
-                pending.done.set()
-            for item, sampling, pending in newly:
-                pending.span_queue.end(outcome="admitted")
-                scan = pending.span_scan = pending.span_parent.child(
-                    names.SPAN_SERVICE_SCAN)
-                try:
-                    if pending.span_parent:
-                        # Ambient span so hop internals (e.g. the decode
-                        # bundle KV-import in pd.py) attach their own
-                        # children without signature plumbing.
-                        with trace.use_span(pending.span_parent):
-                            rid = self._admit(item, sampling)
-                    else:
-                        rid = self._admit(item, sampling)
-                except Exception as e:
-                    # A bad request must fail ITSELF, never the loop thread.
-                    scan.end(outcome="admit_error")
-                    pending.error = str(e)
-                    # Structured failure classes (e.g. a dead KV stream's
-                    # kv_stream_failed) keep their wire code so the router
-                    # can recognize and recover instead of passing a raw
-                    # error to the client.
-                    pending.code = getattr(e, "wire_code", None)
-                    pending.done.set()
-                    continue
-                if rid is None:
-                    scan.end(outcome="done_at_admit")
-                    self._judge_finished(pending, time.perf_counter())
-                    pending.done.set()  # completed at admission
-                    self._done_times.append(time.monotonic())
-                    continue
-                self._pending[rid] = pending
-            self._abort_expired_running(now)
-            self._pump()
-            for pending in cancels:
-                rid = next((r for r, p in self._pending.items() if p is pending),
-                           None)
-                if rid is not None:
-                    eng.cancel_request(rid)
-                    del self._pending[rid]
-                    pending.span_scan.end(outcome="cancelled")
-                    pending.done.set()
-                else:
-                    # Still queued (never admitted) — drop it from the queue.
-                    with self._lock:
-                        self._queue = [q for q in self._queue if q[2] is not pending]
-                    pending.span_queue.end(outcome="cancelled")
-                    pending.done.set()
+            with trace.annotation(names.SPAN_SERVICE_INTAKE):
+                self._intake(now)
+            t = time.monotonic()
+            tl["t_intake_s"] += t - now
             if not eng.has_work():
                 with self._lock:
                     empty = not self._queue and not self._cancels
@@ -633,51 +599,153 @@ class _BatchService:
                     # rate — shedding the whole next burst.
                     self._pf_t = time.monotonic()
                     self._pf_tokens = eng.metrics.get("prefill_tokens", 0)
-                    self._wake.wait(0.01)
-                    self._wake.clear()
+                    with trace.annotation(names.SPAN_SERVICE_IDLE):
+                        self._wake.wait(0.01)
+                        self._wake.clear()
+                    tl["t_idle_s"] += time.monotonic() - t
+                tl["t_loop_s"] = time.monotonic() - t_start
                 continue
             events = eng.step()
-            self._note_prefill_progress()
-            # Batch-occupancy / join-latency observability (one occupancy
-            # sample per step; join waits are recorded by the engine at
-            # admission and drained here — both loop-thread-confined).
-            REGISTRY.observe(names.SERVING_BATCH_OCCUPANCY,
-                             len(eng.running) / max(1, eng.cfg.max_batch),
-                             service=type(self).__name__.lower())
-            if eng.last_join_waits:
-                for w in eng.last_join_waits:
-                    REGISTRY.observe(names.SERVING_JOIN_LATENCY_SECONDS, w,
-                                     service=type(self).__name__.lower())
-                eng.last_join_waits.clear()
-            for ev in events:
-                pending = self._pending.get(ev.request_id)
-                if pending is None:
-                    continue
-                if pending.t_first is None:
-                    pending.t_first = time.perf_counter()
-                    if pending.stream_rx is not None \
-                            and pending.stream_rx.t_first_step is None:
-                        # First DECODE step of a streamed row — the
-                        # kv_stream_overlap invariant compares this
-                        # against the stream's FIN arrival.
-                        pending.stream_rx.t_first_step = time.monotonic()
-                pending.tokens.append(ev.token)
-                if ev.logprob is not None:
-                    pending.logprobs.append(ev.logprob)
-                if ev.finished:
-                    pending.span_scan.end(outcome="ok",
-                                          tokens=len(pending.tokens))
-                    t_done = time.perf_counter()
-                    REGISTRY.observe(
-                        names.SERVING_REQUEST_DURATION_SECONDS,
-                        t_done - pending.t_submit,
-                        exemplar=pending.span_scan.trace_id or None,
-                        service=type(self).__name__.lower())
-                    self._judge_finished(pending, t_done)
-                    pending.done.set()
-                    del self._pending[ev.request_id]
-                    # Completion history feeds the estimated-wait gate.
-                    self._done_times.append(time.monotonic())
+            t = time.monotonic()
+            with trace.annotation(names.SPAN_SERVICE_DELIVER):
+                self._deliver(events)
+            t_end = time.monotonic()
+            tl["t_deliver_s"] += t_end - t
+            tl["t_loop_s"] = t_end - t_start
+
+    def _intake(self, now: float) -> None:
+        """The turn's bookkeeping before the engine runs: expired and
+        cancelled requests leave, new submissions enter the engine's
+        queue (up to its batch ceiling), inbound KV streams are pumped."""
+        eng = self.engine
+        with self._lock:
+            cancels = self._cancels
+            self._cancels = []
+            expired = self._expire_queue_locked(now)
+            # Admission control: never exceed the engine's batch ceiling —
+            # excess items stay queued for later rounds.
+            budget = max(0, eng.cfg.max_batch
+                         - len(eng.running) - len(eng.waiting))
+            newly = self._queue[:budget]
+            self._queue = self._queue[budget:]
+            # Continuous batching: submissions still queued beyond this
+            # step's budget shorten the engine's fused decode window so
+            # the next free slot absorbs them at step granularity.
+            eng.join_hint = bool(self._queue)
+        if expired:
+            with self._lock:
+                self.counters["deadline_queue_drops"] += len(expired)
+        for pending in expired:
+            REGISTRY.inc(names.SERVING_DEADLINE_EXCEEDED_TOTAL,
+                         stage="queue")
+            pending.error = "deadline expired before admission"
+            pending.code = CODE_DEADLINE
+            pending.span_queue.end(outcome="deadline_dropped")
+            pending.done.set()
+        for item, sampling, pending in newly:
+            pending.span_queue.end(outcome="admitted")
+            scan = pending.span_scan = pending.span_parent.child(
+                names.SPAN_SERVICE_SCAN)
+            try:
+                if pending.span_parent:
+                    # Ambient span so hop internals (e.g. the decode
+                    # bundle KV-import in pd.py) attach their own
+                    # children without signature plumbing.
+                    with trace.use_span(pending.span_parent):
+                        rid = self._admit(item, sampling)
+                else:
+                    rid = self._admit(item, sampling)
+            except Exception as e:
+                # A bad request must fail ITSELF, never the loop thread.
+                scan.end(outcome="admit_error")
+                pending.error = str(e)
+                # Structured failure classes (e.g. a dead KV stream's
+                # kv_stream_failed) keep their wire code so the router
+                # can recognize and recover instead of passing a raw
+                # error to the client.
+                pending.code = getattr(e, "wire_code", None)
+                pending.done.set()
+                continue
+            if rid is None:
+                scan.end(outcome="done_at_admit")
+                self._judge_finished(pending, time.perf_counter())
+                pending.done.set()  # completed at admission
+                self._done_times.append(time.monotonic())
+                continue
+            self._pending[rid] = pending
+        self._abort_expired_running(now)
+        self._pump()
+        for pending in cancels:
+            rid = next((r for r, p in self._pending.items() if p is pending),
+                       None)
+            if rid is not None:
+                eng.cancel_request(rid)
+                del self._pending[rid]
+                pending.span_scan.end(outcome="cancelled")
+                pending.done.set()
+            else:
+                # Still queued (never admitted) — drop it from the queue.
+                with self._lock:
+                    self._queue = [q for q in self._queue if q[2] is not pending]
+                pending.span_queue.end(outcome="cancelled")
+                pending.done.set()
+
+    def _deliver(self, events) -> None:
+        """What one engine step produced, handed on: admissions and the
+        step's occupancy observed, tokens to their ``_Pending``s, finished
+        requests judged and released."""
+        eng = self.engine
+        tl = self.timeline
+        self._note_prefill_progress()
+        # Batch-occupancy / join-latency observability (one occupancy
+        # sample per step; join waits are recorded by the engine at
+        # admission and drained here — both loop-thread-confined).
+        REGISTRY.observe(names.SERVING_BATCH_OCCUPANCY,
+                         len(eng.running) / max(1, eng.cfg.max_batch),
+                         service=type(self).__name__.lower())
+        if eng.last_join_waits:
+            for wait, rid, t_admit in eng.last_join_waits:
+                REGISTRY.observe(names.SERVING_JOIN_LATENCY_SECONDS, wait,
+                                 service=type(self).__name__.lower())
+                pending = self._pending.get(rid)
+                if pending is not None and pending.t_admit is None:
+                    # Queue wait as the request felt it: both queues, from
+                    # submit_async to its batch row.
+                    pending.t_admit = t_admit
+                    tl["queue_wait_s"] += t_admit - pending.t_submit
+                    tl["queue_waited"] += 1
+            eng.last_join_waits.clear()
+        for ev in events:
+            pending = self._pending.get(ev.request_id)
+            if pending is None:
+                continue
+            if pending.t_first is None:
+                pending.t_first = time.perf_counter()
+                tl["ttft_s"] += pending.t_first - pending.t_submit
+                tl["first_tokens"] += 1
+                if pending.stream_rx is not None \
+                        and pending.stream_rx.t_first_step is None:
+                    # First DECODE step of a streamed row — the
+                    # kv_stream_overlap invariant compares this
+                    # against the stream's FIN arrival.
+                    pending.stream_rx.t_first_step = time.monotonic()
+            pending.tokens.append(ev.token)
+            if ev.logprob is not None:
+                pending.logprobs.append(ev.logprob)
+            if ev.finished:
+                pending.span_scan.end(outcome="ok",
+                                      tokens=len(pending.tokens))
+                t_done = time.perf_counter()
+                REGISTRY.observe(
+                    names.SERVING_REQUEST_DURATION_SECONDS,
+                    t_done - pending.t_submit,
+                    exemplar=pending.span_scan.trace_id or None,
+                    service=type(self).__name__.lower())
+                self._judge_finished(pending, t_done)
+                pending.done.set()
+                del self._pending[ev.request_id]
+                # Completion history feeds the estimated-wait gate.
+                self._done_times.append(time.monotonic())
 
 
 class EngineService(_BatchService):

@@ -206,10 +206,12 @@ def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
                     lora_ids=None):
     """Shared post-attention math: residual → norm → MLP/MoE → residual."""
     B, T, _ = x.shape
-    x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
-                       lora_ids)
-    xm = rms_norm(x, blk["mlp_norm"], cfg.rms_norm_eps)
-    return x + _mlp(cfg, blk, xm, lora, lora_ids)
+    with jax.named_scope("attention"):
+        x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
+                           lora_ids)
+    with jax.named_scope("moe" if cfg.num_experts else "mlp"):
+        xm = rms_norm(x, blk["mlp_norm"], cfg.rms_norm_eps)
+        return x + _mlp(cfg, blk, xm, lora, lora_ids)
 
 
 def _mlp(cfg: ModelConfig, blk, xm, lora=None, lora_ids=None):
@@ -253,11 +255,12 @@ def _moe_mlp(cfg: ModelConfig, blk, xm):
 
 def _head(params, cfg: ModelConfig, x) -> jnp.ndarray:
     """Shared epilogue: final norm + (tied) LM head, f32 logits."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    return (x @ head.astype(cfg.jax_dtype)).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed"].T
+        return (x @ head.astype(cfg.jax_dtype)).astype(jnp.float32)
 
 
 def _block(cfg: ModelConfig, x, blk, k_cache, v_cache, positions, kv_valid):
@@ -288,14 +291,17 @@ def _block(cfg: ModelConfig, x, blk, k_cache, v_cache, positions, kv_valid):
                                      _mla_scale(cfg))
         attn = _mla_out(cfg, blk, attn_lat)
         return _post_attention(cfg, blk, x, attn), k_cache, v_cache
-    q, k, vv = _qkv(cfg, blk, x, positions)
-    if k_cache is not None:
-        # Write new K/V at their absolute positions (scatter per batch row).
-        k_cache = k_cache.at[b_idx, positions].set(k.astype(k_cache.dtype), mode="drop")
-        v_cache = v_cache.at[b_idx, positions].set(vv.astype(v_cache.dtype), mode="drop")
-        attn = gqa_attention(q, k_cache, v_cache, positions, kv_valid)
-    else:
-        attn = gqa_attention(q, k, vv, positions, kv_valid)
+    with jax.named_scope("attention"):
+        q, k, vv = _qkv(cfg, blk, x, positions)
+        if k_cache is not None:
+            # Write new K/V at their absolute positions (scatter per row).
+            k_cache = k_cache.at[b_idx, positions].set(
+                k.astype(k_cache.dtype), mode="drop")
+            v_cache = v_cache.at[b_idx, positions].set(
+                vv.astype(v_cache.dtype), mode="drop")
+            attn = gqa_attention(q, k_cache, v_cache, positions, kv_valid)
+        else:
+            attn = gqa_attention(q, k, vv, positions, kv_valid)
     return _post_attention(cfg, blk, x, attn), k_cache, v_cache
 
 
@@ -399,27 +405,28 @@ def forward_paged(
             blk, li = xs
             lr = None
         table = page_table + li * NP
-        if cfg.mla:
-            from rbg_tpu.ops.mla_attention import paged_mla_attention
-            q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions,
-                                            lr, lora_ids)
-            kpf, vpf, ksf, vsf = write_kv_pages(
-                kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
-                positions, token_mask, ksf, vsf)
-            attn_lat = paged_mla_attention(q_lat, q_pe, kpf, vpf, table,
-                                           positions, kv_lens,
-                                           _mla_scale(cfg),
-                                           use_pallas=use_pallas,
-                                           c_scales=ksf, pe_scales=vsf)
-            attn = _mla_out(cfg, blk, attn_lat)
-        else:
-            q, k, vv = _qkv(cfg, blk, hcur, positions, lr, lora_ids)
-            kpf, vpf, ksf, vsf = write_kv_pages(kpf, vpf, k, vv, table,
-                                                positions, token_mask,
-                                                ksf, vsf)
-            attn = paged_attention(q, kpf, vpf, table, positions, kv_lens,
-                                   use_pallas=use_pallas, k_scales=ksf,
-                                   v_scales=vsf)
+        with jax.named_scope("attention"):
+            if cfg.mla:
+                from rbg_tpu.ops.mla_attention import paged_mla_attention
+                q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions,
+                                                lr, lora_ids)
+                kpf, vpf, ksf, vsf = write_kv_pages(
+                    kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
+                    positions, token_mask, ksf, vsf)
+                attn_lat = paged_mla_attention(q_lat, q_pe, kpf, vpf, table,
+                                               positions, kv_lens,
+                                               _mla_scale(cfg),
+                                               use_pallas=use_pallas,
+                                               c_scales=ksf, pe_scales=vsf)
+                attn = _mla_out(cfg, blk, attn_lat)
+            else:
+                q, k, vv = _qkv(cfg, blk, hcur, positions, lr, lora_ids)
+                kpf, vpf, ksf, vsf = write_kv_pages(kpf, vpf, k, vv, table,
+                                                    positions, token_mask,
+                                                    ksf, vsf)
+                attn = paged_attention(q, kpf, vpf, table, positions, kv_lens,
+                                       use_pallas=use_pallas, k_scales=ksf,
+                                       v_scales=vsf)
         out = _post_attention(cfg, blk, hcur, attn, lr, lora_ids)
         return (out, kpf, vpf, ksf, vsf), None
 
@@ -477,26 +484,27 @@ def forward_paged_window(
         hcur, kpf, vpf, ksf, vsf = carry
         blk, li = xs
         table = page_table + li * NP
-        if cfg.mla:
-            from rbg_tpu.ops.mla_attention import paged_mla_attention
-            q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions)
-            kpf, vpf, ksf, vsf = write_kv_pages(
-                kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
-                positions, token_mask, ksf, vsf)
-            attn_lat = paged_mla_attention(q_lat, q_pe, kpf, vpf, table,
-                                           positions, kv_lens,
-                                           _mla_scale(cfg),
-                                           use_pallas=use_pallas,
-                                           c_scales=ksf, pe_scales=vsf)
-            attn = _mla_out(cfg, blk, attn_lat)
-        else:
-            q, k, vv = _qkv(cfg, blk, hcur, positions)
-            kpf, vpf, ksf, vsf = write_kv_pages(kpf, vpf, k, vv, table,
-                                                positions, token_mask,
-                                                ksf, vsf)
-            attn = paged_attention(q, kpf, vpf, table, positions, kv_lens,
-                                   use_pallas=use_pallas, k_scales=ksf,
-                                   v_scales=vsf)
+        with jax.named_scope("attention"):
+            if cfg.mla:
+                from rbg_tpu.ops.mla_attention import paged_mla_attention
+                q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions)
+                kpf, vpf, ksf, vsf = write_kv_pages(
+                    kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
+                    positions, token_mask, ksf, vsf)
+                attn_lat = paged_mla_attention(q_lat, q_pe, kpf, vpf, table,
+                                               positions, kv_lens,
+                                               _mla_scale(cfg),
+                                               use_pallas=use_pallas,
+                                               c_scales=ksf, pe_scales=vsf)
+                attn = _mla_out(cfg, blk, attn_lat)
+            else:
+                q, k, vv = _qkv(cfg, blk, hcur, positions)
+                kpf, vpf, ksf, vsf = write_kv_pages(kpf, vpf, k, vv, table,
+                                                    positions, token_mask,
+                                                    ksf, vsf)
+                attn = paged_attention(q, kpf, vpf, table, positions, kv_lens,
+                                       use_pallas=use_pallas, k_scales=ksf,
+                                       v_scales=vsf)
         out = _post_attention(cfg, blk, hcur, attn)
         return (out, kpf, vpf, ksf, vsf), None
 
@@ -563,26 +571,27 @@ def forward_ragged(
         hcur, kpf, vpf, ksf, vsf = carry
         blk, li = xs
         table = page_table + li * NP
-        if cfg.mla:
-            q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions)
-            kpf, vpf, ksf, vsf = write_kv_pages_ragged(
-                kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
-                row_ids, positions, token_mask, ksf, vsf)
-            attn_lat = ragged_paged_mla_attention(
-                q_lat, q_pe, kpf, vpf, table, positions, kv_lens, row_ids,
-                _mla_scale(cfg), use_pallas=use_pallas, c_scales=ksf,
-                pe_scales=vsf, max_q_len=max_q_len)
-            attn = _mla_out(cfg, blk, attn_lat)
-        else:
-            q, k, vv = _qkv(cfg, blk, hcur, positions)
-            kpf, vpf, ksf, vsf = write_kv_pages_ragged(
-                kpf, vpf, k, vv, table, row_ids, positions, token_mask,
-                ksf, vsf)
-            attn = ragged_paged_attention(q, kpf, vpf, table, positions,
-                                          kv_lens, row_ids,
-                                          use_pallas=use_pallas,
-                                          k_scales=ksf, v_scales=vsf,
-                                          max_q_len=max_q_len)
+        with jax.named_scope("attention"):
+            if cfg.mla:
+                q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions)
+                kpf, vpf, ksf, vsf = write_kv_pages_ragged(
+                    kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
+                    row_ids, positions, token_mask, ksf, vsf)
+                attn_lat = ragged_paged_mla_attention(
+                    q_lat, q_pe, kpf, vpf, table, positions, kv_lens, row_ids,
+                    _mla_scale(cfg), use_pallas=use_pallas, c_scales=ksf,
+                    pe_scales=vsf, max_q_len=max_q_len)
+                attn = _mla_out(cfg, blk, attn_lat)
+            else:
+                q, k, vv = _qkv(cfg, blk, hcur, positions)
+                kpf, vpf, ksf, vsf = write_kv_pages_ragged(
+                    kpf, vpf, k, vv, table, row_ids, positions, token_mask,
+                    ksf, vsf)
+                attn = ragged_paged_attention(q, kpf, vpf, table, positions,
+                                              kv_lens, row_ids,
+                                              use_pallas=use_pallas,
+                                              k_scales=ksf, v_scales=vsf,
+                                              max_q_len=max_q_len)
         out = _post_attention(cfg, blk, hcur, attn)
         return (out, kpf, vpf, ksf, vsf), None
 

@@ -37,9 +37,7 @@ DISRUPTION_MIGRATIONS_MISSED_DEADLINE_TOTAL = (
 DISRUPTION_SLICES_RELEASED_TOTAL = "rbg_disruption_slices_released_total"
 DISRUPTION_SPARES_CONSUMED_TOTAL = "rbg_disruption_spares_consumed_total"
 LOCKTRACE_INVERSIONS_TOTAL = "rbg_locktrace_inversions_total"
-RACE_CHECKED_TOTAL = "rbg_race_checked_total"
 RACE_VIOLATIONS_TOTAL = "rbg_race_violations_total"
-JIT_COMPILES_TOTAL = "rbg_jit_compiles_total"
 JIT_UNWARMED_COMPILES_TOTAL = "rbg_jit_unwarmed_compiles_total"
 JIT_HOST_SYNCS_TOTAL = "rbg_jit_host_syncs_total"
 WIRE_CONTRACT_VIOLATIONS_TOTAL = "rbg_wire_contract_violations_total"
@@ -167,9 +165,7 @@ COUNTERS = frozenset({
     DISRUPTION_SLICES_RELEASED_TOTAL,
     DISRUPTION_SPARES_CONSUMED_TOTAL,
     LOCKTRACE_INVERSIONS_TOTAL,
-    RACE_CHECKED_TOTAL,
     RACE_VIOLATIONS_TOTAL,
-    JIT_COMPILES_TOTAL,
     JIT_UNWARMED_COMPILES_TOTAL,
     JIT_HOST_SYNCS_TOTAL,
     WIRE_CONTRACT_VIOLATIONS_TOTAL,
@@ -301,9 +297,7 @@ HELP = {
     DISRUPTION_SLICES_RELEASED_TOTAL: "Slices released to maintenance",
     DISRUPTION_SPARES_CONSUMED_TOTAL: "Warm spare slices granted",
     LOCKTRACE_INVERSIONS_TOTAL: "Lock acquisition-order inversions observed",
-    RACE_CHECKED_TOTAL: "Guarded-field accesses checked by racetrace",
     RACE_VIOLATIONS_TOTAL: "Guarded-field accesses without the owning lock",
-    JIT_COMPILES_TOTAL: "XLA compiles recorded while jitwatch is armed",
     JIT_UNWARMED_COMPILES_TOTAL:
         "Cataloged programs compiled after warmup_complete(), per program",
     JIT_HOST_SYNCS_TOTAL:
@@ -577,6 +571,18 @@ SPAN_TOPOLOGY_CUTOVER = "topology.cutover"
 SPAN_TOPOLOGY_DRAIN = "topology.drain"
 SPAN_PLANE_TAKEOVER = "plane.takeover"
 SPAN_ROUTER_RESHARD = "router.reshard"
+# The step timeline (docs/observability.md "Step timeline"): phases of one
+# turn of the serving loop, emitted as ``jax.profiler`` annotations by
+# ``trace.annotation`` on the loop thread, never as per-request spans.
+SPAN_SERVICE_INTAKE = "service.intake"
+SPAN_SERVICE_DELIVER = "service.deliver"
+SPAN_SERVICE_IDLE = "service.idle"
+SPAN_ENGINE_STEP = "engine.step"
+SPAN_ENGINE_ADMIT = "engine.admit"
+SPAN_ENGINE_PACK = "engine.pack"
+SPAN_ENGINE_DISPATCH = "engine.dispatch"
+SPAN_ENGINE_SYNC = "engine.sync"
+SPAN_ENGINE_EMIT = "engine.emit"
 
 # ---- jitted program catalog (jitwatch sentry + warmers) ----
 #
@@ -647,4 +653,13 @@ SPANS = frozenset({
     SPAN_TOPOLOGY_DRAIN,
     SPAN_PLANE_TAKEOVER,
     SPAN_ROUTER_RESHARD,
+    SPAN_SERVICE_INTAKE,
+    SPAN_SERVICE_DELIVER,
+    SPAN_SERVICE_IDLE,
+    SPAN_ENGINE_STEP,
+    SPAN_ENGINE_ADMIT,
+    SPAN_ENGINE_PACK,
+    SPAN_ENGINE_DISPATCH,
+    SPAN_ENGINE_SYNC,
+    SPAN_ENGINE_EMIT,
 })

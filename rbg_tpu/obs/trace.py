@@ -32,6 +32,12 @@ Mooncake / "Taming the Chaos" trace-driven-analysis analog):
 ``RBG_TRACE_STRICT=1`` is the runtime complement of the
 ``span-name-registry`` lint rule: a span name missing from the
 ``obs/names.py`` catalog raises at creation time.
+
+:func:`annotation` is the bridge to the *device's* clock: the phases of
+the serving loop (``engine.*`` / ``service.*`` in the catalog) are not
+per-request spans but ``jax.profiler`` annotations, which cost under a
+microsecond without a profiler session and land in the ``.xplane.pb``
+beside the device ops with one (docs/observability.md "Step timeline").
 """
 
 from __future__ import annotations
@@ -89,6 +95,27 @@ def _check_name(name: str) -> None:
         raise ValueError(
             f"span name {name!r} is not cataloged in rbg_tpu/obs/names.py "
             f"SPANS (RBG_TRACE_STRICT is set)")
+
+
+_profiler = None    # jax.profiler, once annotation() has been called
+
+
+def annotation(name: str, **attrs):
+    """The ``jax.profiler`` annotation (a context manager) for one phase
+    of the serving loop, always on: without a profiler session entering
+    it is a flag test. With ``step_num`` among the attributes it is a
+    ``StepTraceAnnotation``, which the profile viewer lays out as one
+    step. Attributes known only later go in through the annotation's own
+    ``set_metadata(**attrs)`` before it exits. jax is imported at the
+    first call, not at module load: the control plane traces requests
+    without it."""
+    global _profiler
+    _check_name(name)
+    if _profiler is None:
+        from jax import profiler as _profiler
+    if "step_num" in attrs:
+        return _profiler.StepTraceAnnotation(name, **attrs)
+    return _profiler.TraceAnnotation(name, **attrs)
 
 
 def new_trace_id() -> str:

@@ -13,9 +13,11 @@ chip-holding children must stay off the backend itself.
 
 from __future__ import annotations
 
+import collections
 import os
 import re
 import threading
+import time
 from typing import Optional
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -123,13 +125,19 @@ def memory_summary() -> dict:
 class CompileCounter:
     """Programs this process compiled (or loaded from the persistent
     cache), the seconds that took, and how many were cache hits — set-up
-    cost, read from ``jax.monitoring``'s public compile events."""
+    cost, read from ``jax.monitoring``'s public compile events. The last
+    ``RECENT`` of them are kept by name, ``(time.monotonic(), fun_name,
+    seconds)``, so that a compile in the middle of serving can be named
+    from the wire."""
+
+    RECENT = 64
 
     def __init__(self):
         self._lock = threading.Lock()
         self.programs = 0
         self.seconds = 0.0
         self.cache_hits = 0
+        self.recent = collections.deque(maxlen=self.RECENT)
 
     def install(self) -> "CompileCounter":
         import jax.monitoring as monitoring
@@ -137,11 +145,13 @@ class CompileCounter:
         monitoring.register_event_listener(self._on_event)
         return self
 
-    def _on_duration(self, event: str, seconds: float, **_kw) -> None:
+    def _on_duration(self, event: str, seconds: float, **kw) -> None:
         if event == COMPILE_EVENT:
             with self._lock:
                 self.programs += 1
                 self.seconds += seconds
+                self.recent.append((time.monotonic(),
+                                    str(kw.get("fun_name")), seconds))
 
     def _on_event(self, event: str, **_kw) -> None:
         if event == CACHE_HIT_EVENT:
@@ -152,4 +162,5 @@ class CompileCounter:
         with self._lock:
             return {"programs": self.programs,
                     "seconds": round(self.seconds, 3),
-                    "cache_hits": self.cache_hits}
+                    "cache_hits": self.cache_hits,
+                    "recent": [list(r) for r in self.recent]}

@@ -1,0 +1,389 @@
+"""The step timeline (docs/observability.md "Step timeline"): the phase
+clocks and counts the engine and the service loop keep, the ring of step
+records behind the ``traces`` op, the profiler annotations' names, and the
+benchmark's metric files that read the clocks off the wire."""
+
+import collections
+import glob
+import json
+import math
+import os
+import sys
+import time
+
+import jax
+import pytest
+
+from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
+from rbg_tpu.engine import engine as engine_mod
+from rbg_tpu.engine.protocol import request_once
+from rbg_tpu.engine.service import EngineService
+from rbg_tpu.models import get_config, init_params
+from rbg_tpu.obs import names, trace
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+ENGINE_CLOCKS = ("t_step_s", "t_admit_s", "t_pack_s", "t_dispatch_s",
+                 "t_sync_s", "t_emit_s", "t_unified_s", "t_decode_s")
+PHASE_CLOCKS = ("t_admit_s", "t_pack_s", "t_dispatch_s", "t_sync_s",
+                "t_emit_s")
+LOOP_CLOCKS = ("t_loop_s", "t_intake_s", "t_deliver_s", "t_idle_s",
+               "queue_wait_s", "ttft_s")
+COUNTS = ("steps", "steps_run", "unified_steps_run", "decode_steps_run",
+          "queue_waited", "first_tokens", "kv_live_token_steps",
+          "kv_held_slot_steps")
+
+# The eleven metric files this timeline brought to the benchmark.
+NEW_METRICS = (
+    "engine.decode_step_ms", "engine.unified_step_ms",
+    "engine.prefill_time_share", "engine.host_ms_per_step",
+    "engine.sync_wait_share", "service.queue_wait_mean_ms",
+    "service.ttft_server_mean_ms", "service.loop_idle_share",
+    "kv.page_fill_share", "setup.compile_s", "setup.warmup_s")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(get_config("tiny"), jax.random.key(0))
+
+
+def engine_config(**kw):
+    base = dict(model="tiny", page_size=8, num_pages=64, max_batch=2,
+                max_seq_len=128, prefill_chunk=16, use_pallas="never",
+                enable_radix_cache=False, decode_buckets=(2,))
+    base.update(kw)
+    return EngineConfig(**base)
+
+
+@pytest.fixture
+def svc(params):
+    s = EngineService(engine_config(), params=params)
+    try:
+        yield s
+    finally:
+        s.stop()
+
+
+def wait_for_turn(svc):
+    """Let the loop thread finish the turn that completed a request (the
+    caller is woken from inside ``service.deliver``)."""
+    seen = svc.timeline["t_loop_s"]
+    deadline = time.monotonic() + 5.0
+    while svc.timeline["t_loop_s"] == seen and time.monotonic() < deadline:
+        time.sleep(0.002)
+
+
+# ---- (1) clocks --------------------------------------------------------
+
+
+def test_clocks_are_monotone_and_phases_fit_inside_the_step(svc):
+    seen = []
+    for n in (3, 6, 4):
+        svc.submit([1, 2, 3, 4, 5], SamplingParams(max_new_tokens=n))
+        wait_for_turn(svc)
+        seen.append(svc.stats())
+    for name in ENGINE_CLOCKS + LOOP_CLOCKS + COUNTS:
+        vals = [s[name] for s in seen]
+        assert all(v >= 0 for v in vals), (name, vals)
+        assert vals == sorted(vals), (name, vals)
+        assert vals[-1] > 0 or name == "t_idle_s", (name, vals)
+    last = seen[-1]
+    # The phase clocks never overlap, so they sum to no more than the
+    # steps' wall time; steps by kind are some of all steps.
+    assert sum(last[c] for c in PHASE_CLOCKS) <= last["t_step_s"] + 1e-9
+    assert last["t_unified_s"] + last["t_decode_s"] <= last["t_step_s"] + 1e-9
+
+
+def test_loop_phases_tile_the_loop_threads_wall_time(svc):
+    svc.submit([9, 8, 7], SamplingParams(max_new_tokens=5))
+    time.sleep(0.05)                       # a few idle turns
+    wait_for_turn(svc)
+    st = svc.stats()
+    parts = (st["t_intake_s"] + st["t_step_s"] + st["t_deliver_s"]
+             + st["t_idle_s"])
+    assert st["t_idle_s"] > 0
+    assert parts <= st["t_loop_s"] + 1e-6
+    # What the four parts leave out is a few clock reads a turn.
+    assert st["t_loop_s"] - parts < 0.05 * st["t_loop_s"] + 0.01
+
+
+# ---- (2) counts --------------------------------------------------------
+
+
+def test_steps_run_counts_only_steps_that_dispatched(params):
+    eng = Engine(engine_config(), params=params)
+    eng.step()                             # nothing to run
+    eng.step()
+    m = eng.metrics
+    assert (m["steps"], m["steps_run"], m["unified_steps"]) == (2, 0, 0)
+    assert len(eng.step_ring) == 0 and m["t_step_s"] > 0
+
+    rid = eng.add_request(list(range(1, 21)), SamplingParams(max_new_tokens=4))
+    turns = unified = 0
+    while eng.has_work():
+        was = m["unified_steps"]
+        eng.step()
+        turns += 1
+        unified += m["unified_steps"] - was
+    assert rid not in eng.requests
+    # `steps` and `unified_steps` read as they always did: every turn of
+    # step(), and every turn that took the unified path.
+    assert m["steps"] == 2 + turns and m["unified_steps"] == unified == 2
+    # 20 prompt tokens in chunks of 16: two unified steps; the first
+    # token comes with the second, the other three from decode steps.
+    assert m["unified_steps_run"] == 2
+    assert m["decode_steps_run"] >= 3
+    assert m["steps_run"] == m["unified_steps_run"] + m["decode_steps_run"]
+    assert m["steps_run"] <= turns
+    assert [r[6] for r in eng.step_ring][:2] == ["unified", "unified"]
+    assert {r[6] for r in eng.step_ring} == {"unified", "decode"}
+    # Rows, query tokens and buckets of the first step: one row, 16 tokens.
+    first = eng.step_ring[0]
+    assert first[7:10] == (1, 16, (2, 16)) and first[10] == 0
+
+
+def test_split_path_steps_are_prefill_and_decode(params):
+    eng = Engine(engine_config(ragged="off"), params=params)
+    eng.add_request(list(range(1, 21)), SamplingParams(max_new_tokens=3))
+    while eng.has_work():
+        eng.step()
+    m = eng.metrics
+    kinds = [r[6] for r in eng.step_ring]
+    assert kinds[:2] == ["prefill", "prefill"] and "decode" in kinds
+    assert m["unified_steps_run"] == 0
+    assert m["steps_run"] == len(kinds) > m["decode_steps_run"] > 0
+
+
+# ---- (3) queue wait ----------------------------------------------------
+
+
+def test_queue_wait_shows_a_delay_before_admission(svc):
+    delay = 0.08
+    real_pump = svc._pump
+    svc._pump = lambda: (time.sleep(delay), real_pump())   # loop thread,
+    try:                                   # between submit and the batch row
+        svc.submit([5, 6, 7, 8], SamplingParams(max_new_tokens=2))
+    finally:
+        svc._pump = real_pump
+    wait_for_turn(svc)
+    st = svc.stats()
+    assert st["queue_waited"] == 1 and st["first_tokens"] == 1
+    assert st["queue_wait_s"] / st["queue_waited"] >= delay
+    # The first token came after the wait, and the server's own TTFT
+    # holds both.
+    assert st["ttft_s"] / st["first_tokens"] > st["queue_wait_s"]
+
+
+# ---- (4) the ring ------------------------------------------------------
+
+
+def test_ring_is_bounded_and_ordered(params):
+    eng = Engine(engine_config(), params=params)
+    assert eng.step_ring.maxlen == engine_mod.STEP_RING
+    eng.step_ring = collections.deque(maxlen=6)
+    eng.add_request([3, 1, 4, 1, 5], SamplingParams(max_new_tokens=12))
+    while eng.has_work():
+        eng.step()
+    ring = list(eng.step_ring)
+    assert len(ring) == 6 and eng.metrics["steps_run"] > 6
+    for rec in ring:
+        stamps = rec[:6]
+        assert list(stamps) == sorted(stamps), rec
+    assert [r[0] for r in ring] == sorted(r[0] for r in ring)
+    nums = [r[10] for r in ring]
+    assert nums == list(range(nums[0], nums[0] + 6))
+    assert nums[-1] == eng.metrics["steps_run"] - 1
+
+    everything = eng.steps_since(0.0)
+    assert [tuple(r) for r in everything["steps"]] == ring
+    assert everything["steps_dropped"] == eng.metrics["steps_run"] - 6
+    # A cursor inside the ring: only later records, nothing missed.
+    later = eng.steps_since(ring[2][0])
+    assert [r[10] for r in later["steps"]] == nums[3:]
+    assert later["steps_dropped"] == 0
+    assert eng.steps_since(ring[-1][0]) == {"steps": [], "steps_dropped": 0}
+
+
+# ---- (5) cache held against cache used ----------------------------------
+
+
+def test_live_tokens_never_exceed_held_slots(svc):
+    ps = [svc.submit_async(list(range(1, n)), SamplingParams(max_new_tokens=9))
+          for n in (8, 30)]
+    for p in ps:
+        svc.wait(p, 60.0)
+    m = svc.engine.metrics
+    assert 0 < m["kv_live_token_steps"] <= m["kv_held_slot_steps"]
+    # Pages are reserved for the prompt and one token, then one at a time:
+    # what is held is never a page and a prompt's chunk more than is used.
+    assert m["kv_held_slot_steps"] < 2 * m["kv_live_token_steps"]
+
+
+# ---- (6) annotation names ----------------------------------------------
+
+
+def test_every_phase_annotation_is_cataloged():
+    phases = set(engine_mod._Phase.SPANS)
+    assert len(phases) == len(engine_mod._Phase.CLOCKS) == 5
+    loop = {names.SPAN_SERVICE_INTAKE, names.SPAN_SERVICE_DELIVER,
+            names.SPAN_SERVICE_IDLE, names.SPAN_ENGINE_STEP}
+    assert phases | loop <= names.SPANS
+    assert {n for n in names.SPANS
+            if n.startswith("engine.") and n != names.SPAN_ENGINE_OP} \
+        == phases | {names.SPAN_ENGINE_STEP}
+
+
+def test_strict_mode_rejects_an_uncataloged_annotation():
+    was = trace._CFG.strict
+    trace.configure(strict=True)
+    try:
+        with trace.annotation(names.SPAN_ENGINE_PACK):
+            pass
+        with trace.annotation(names.SPAN_ENGINE_STEP, step_num=3) as ann:
+            ann.set_metadata(kind="decode", rows=2)
+        with pytest.raises(ValueError, match="not cataloged"):
+            trace.annotation("engine.pak")  # lint: allow[span-name-registry] strict-mode negative test needs an uncataloged literal
+    finally:
+        trace.configure(strict=was)
+
+
+def test_strict_mode_serves_a_request(params):
+    """Every annotation the serving loop makes passes the strict check."""
+    was = trace._CFG.strict
+    trace.configure(strict=True)
+    s = EngineService(engine_config(), params=params)
+    try:
+        toks, _ = s.submit([2, 4, 6], SamplingParams(max_new_tokens=3))
+        assert len(toks) == 3
+    finally:
+        s.stop()
+        trace.configure(strict=was)
+
+
+def test_span_lint_checks_annotation_call_sites(tmp_path):
+    from rbg_tpu.analysis.core import run_lint
+    from rbg_tpu.analysis.rules import make_rules
+    src = tmp_path / "phases.py"
+    src.write_text(
+        "from rbg_tpu.obs import names, trace\n"
+        "def good():\n"
+        "    with trace.annotation(names.SPAN_ENGINE_SYNC):\n"
+        "        pass\n"
+        "def bad():\n"
+        "    with trace.annotation('engine.sink'):\n"
+        "        pass\n")
+    found = [f for f in run_lint([str(src)],
+                                 make_rules(["span-name-registry"]),
+                                 skip_fixture_dirs=False)
+             if f.rule == "span-name-registry"]
+    assert [f.line for f in found] == [6], [f.render() for f in found]
+
+
+# ---- (4, 7, 8) over the wire -------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def wire():
+    """A served tiny engine: the ``metrics`` reply before and after some
+    traffic, as the benchmark reads a window, and the server itself."""
+    from conftest import SpawnedEngineServer
+    srv = SpawnedEngineServer(
+        "--model", "tiny", "--page-size", "8", "--num-pages", "128",
+        "--max-seq-len", "256", "--max-batch", "2", "--prefill-chunk", "16",
+        "--use-pallas", "never")
+    with srv:
+        def ask(obj, timeout=120):
+            reply, _, _ = request_once(srv.addr, obj, timeout=timeout)
+            assert reply is not None and "error" not in reply, reply
+            return reply
+
+        ask({"op": "warmup"}, 600)
+        t_mid = time.monotonic()
+        before = ask({"op": "metrics"})["metrics"]
+        for i in range(3):
+            ask({"op": "generate", "prompt": list(range(1, 20 + i)),
+                 "max_new_tokens": 6})
+        # A shape the warm-up never met: a program compiled after the
+        # first snapshot (the embedding program of a 3-row batch).
+        ask({"op": "embed", "prompts": [[1, 2, 3]] * 3})
+        after = ask({"op": "metrics"})["metrics"]
+        yield {"ask": ask, "before": before, "after": after, "t_mid": t_mid}
+
+
+def test_traces_op_returns_the_steps_after_the_cursor(wire):
+    ask = wire["ask"]
+    plain = ask({"op": "traces"})
+    assert "steps" not in plain and "recent" in plain
+    every = ask({"op": "traces", "steps_since": 0})
+    steps = every["steps"]
+    assert every["steps_dropped"] == 0
+    assert len(steps) == wire["after"]["steps_run"] > 6
+    assert all(len(r) == 11 and r[6] in ("unified", "decode") for r in steps)
+    # The traffic's steps lie after the warm-up's; a cursor between the
+    # two gets exactly those.
+    late = ask({"op": "traces", "steps_since": wire["t_mid"]})["steps"]
+    assert 0 < len(late) < len(steps)
+    assert late == steps[-len(late):]
+    assert all(r[0] > wire["t_mid"] for r in late)
+    assert len(late) == (wire["after"]["steps_run"]
+                         - wire["before"]["steps_run"])
+    assert ask({"op": "traces", "steps_since": steps[-1][0]})["steps"] == []
+
+
+def window_ctx(before, after):
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    try:
+        from harness import window
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmark"))
+    o, c = window.flatten(before), window.flatten(after)
+    scalars = {k: c[k] - o.get(k, 0) for k in c}
+    scalars.update({"open." + k: v for k, v in o.items()})
+    return window, {"scalars": scalars, "series": {}, "per_server": [],
+                    "seconds": 1.0, "trace": None}
+
+
+@pytest.mark.parametrize("metric", NEW_METRICS)
+def test_metric_file_reads_a_finite_value_off_the_wire(wire, metric):
+    """A misspelt counter fails here, on the CPU, and not on the chip."""
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    entry = [m for m in bench["per_layer"] if m["name"] == metric]
+    assert len(entry) == 1 and entry[0]["source"] == "program_counter"
+    spec = json.load(open(os.path.join(
+        ROOT, "benchmark", "layer_metrics", metric + ".json")))
+    window, ctx = window_ctx(wire["before"], wire["after"])
+    value = window.read_metric(spec, ctx)
+    assert value is not None and math.isfinite(value) and value >= 0, value
+    if entry[0]["unit"] == "%":
+        assert value <= 100.0 + 1e-9, value
+    # Every name the file reads is a numeric leaf of the reply.
+    for key in ("num", "den"):
+        for name in spec.get(key, []):
+            assert name in ctx["scalars"], name
+
+
+def test_the_new_metric_files_are_the_eleven():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    listed = [m["name"] for m in bench["per_layer"]]
+    assert listed[-len(NEW_METRICS):] == list(NEW_METRICS)
+    on_disk = {os.path.basename(p)[:-5] for p in glob.glob(os.path.join(
+        ROOT, "benchmark", "layer_metrics", "*.json"))}
+    assert on_disk == set(listed)
+
+
+def test_compile_recent_names_a_program_compiled_in_the_window(wire):
+    before, after = wire["before"]["compile"], wire["after"]["compile"]
+    assert after["programs"] > before["programs"]
+    assert 0 < len(after["recent"]) <= 64
+    new = [r for r in after["recent"] if r[0] > wire["t_mid"]]
+    assert new, after["recent"][-3:]
+    assert all(isinstance(r[1], str) and r[2] >= 0 for r in new)
+    assert any("rbg_embed_pooled" in r[1] for r in new), new
+
+
+def test_warmup_times_are_on_the_wire(wire):
+    m = wire["after"]
+    assert m["warmup_s"] > 0
+    assert set(m["warmup"]) == {"ragged_s", "waves_s", "decode_s",
+                                "join_windows_s", "samplers_s"}
+    assert sum(m["warmup"].values()) <= m["warmup_s"] + 0.01

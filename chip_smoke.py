@@ -146,8 +146,30 @@ def _mixed_pack(rng, num_rows, pages_per_row, page, total_tokens):
             np.asarray(pos, np.int32)[None], table.astype(np.int32), real)
 
 
+def _long_table_pack(rng, page, pages_per_row, short, long_, total_tokens):
+    """Two short rows under a table ``pages_per_row`` wide, as a server
+    with a large ``max_seq_len`` sees them: a decode token on a cache of
+    ``short`` slots and an eight-token chunk that ends at ``long_``. The
+    table's dead entries all name the last page of the pool."""
+    import numpy as np
+    kv_lens = [short, long_]
+    row_ids = [0] + [1] * 8
+    pos = [short - 1] + list(range(long_ - 8, long_))
+    real = len(pos)
+    row_ids += [0] * (total_tokens - real)
+    pos += [-1] * (total_tokens - real)
+    table = np.full((2, pages_per_row), 2 * pages_per_row, np.int64)
+    live = rng.permutation(2 * pages_per_row) + 1
+    for r, n in enumerate(kv_lens):
+        n_pages = -(-n // page)
+        table[r, :n_pages] = live[r * pages_per_row:][:n_pages]
+    return (np.asarray(kv_lens, np.int32), np.asarray(row_ids, np.int32),
+            np.asarray(pos, np.int32)[None], table.astype(np.int32), real)
+
+
 def child_kernels(args) -> None:
-    """Each kernel family once on the chip against its XLA reference."""
+    """Each kernel family on the chip against its XLA reference: on one
+    mixed step's rows, and on two short rows under a long table."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -165,72 +187,83 @@ def child_kernels(args) -> None:
     if args.rehearse:
         gqa, mla = {"tiny": (4, 2, 32)}, (4, 64, 16)
         R, P, page, T = 4, 4, 16, 32
+        long_table = (16, 17, 40)       # table width, the two rows' lengths
     else:
         gqa, mla = GQA_WIDTHS, MLA_WIDTH
         R, P, page, T = 8, 64, 16, 256
-    NP = R * P + 1
+        long_table = (512, 17, 900)
     rng = np.random.default_rng(args.seed)
-    kv_lens, row_ids, qpos, table, real = _mixed_pack(rng, R, P, page, T)
-    dec_pos = (kv_lens - 1)[:, None]
-    worst = 0.0
+    packs = {"": _mixed_pack(rng, R, P, page, T),
+             f" P={long_table[0]}": _long_table_pack(rng, page, *long_table,
+                                                     total_tokens=16)}
+    worst = {label: 0.0 for label in packs}
 
     def normal(shape):
         return jnp.asarray(rng.standard_normal(shape, np.float32),
                            jnp.bfloat16)
 
-    def check(kernel, reference, label, args, rows):
+    def check(kernel, reference, label, case, args, rows):
         """One kernel as the engine calls it, against its XLA reference
         on the same arguments. The reference runs at full matmul
         precision: XLA's default on a TPU rounds float32 operands to
         bf16, which would be the larger error of the two."""
-        nonlocal worst
-        name = f"{kernel.__name__}{label}"
+        name = f"{kernel.__name__}{label}{case}"
         got = np.asarray(kernel(*args, interpret=interpret), np.float32)
         with jax.default_matmul_precision("highest"):
             want = np.asarray(reference(*args), np.float32)
         if not np.isfinite(got[rows]).all():
             raise SystemExit(f"kernel {name}: non-finite output")
         err = float(np.max(np.abs(got[rows] - want[rows])))
-        worst = max(worst, err)
+        worst[case] = max(worst[case], err)
         say(f"kernel {name}: max_abs_err={err:.4g} (atol {KERNEL_ATOL:.4g})")
         if err > KERNEL_ATOL:
             raise SystemExit(f"kernel {name}: error {err} over {KERNEL_ATOL}")
 
-    packed, every = (0, slice(0, real)), slice(None)   # rows compared
-    for width, (H, KV, hd) in gqa.items():
-        q_rag, q_dec = normal((1, T, H, hd)), normal((R, 1, H, hd))
-        k, v = normal((NP, page, KV, hd)), normal((NP, page, KV, hd))
-        (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
-        rag = (table, qpos, kv_lens, row_ids)
-        dec = (table, dec_pos, kv_lens)
-        check(K.ragged_paged_attention_pallas, ragged_paged_attention_xla,
-              f"[{width}]", (q_rag, k, v, *rag), packed)
-        check(K.ragged_paged_attention_pallas_q, ragged_paged_attention_xla,
-              f"[{width}]", (q_rag, kq, vq, *rag, ks, vs), packed)
-        check(K.paged_attention_pallas, paged_attention_xla,
-              f"[{width}]", (q_dec, k, v, *dec), every)
-        check(K.paged_attention_pallas_q, paged_attention_xla,
-              f"[{width}]", (q_dec, kq, vq, *dec, ks, vs), every)
+    for case, (kv_lens, row_ids, qpos, table, real) in packs.items():
+        rows, tokens = kv_lens.shape[0], row_ids.shape[0]
+        NP = int(table.max()) + 1
+        dec_pos = (kv_lens - 1)[:, None]
+        packed, every = (0, slice(0, real)), slice(None)   # rows compared
+        for width, (H, KV, hd) in gqa.items():
+            q_rag, q_dec = normal((1, tokens, H, hd)), normal((rows, 1, H, hd))
+            k, v = normal((NP, page, KV, hd)), normal((NP, page, KV, hd))
+            (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
+            rag = (table, qpos, kv_lens, row_ids)
+            dec = (table, dec_pos, kv_lens)
+            label = f"[{width}]"
+            check(K.ragged_paged_attention_pallas, ragged_paged_attention_xla,
+                  label, case, (q_rag, k, v, *rag), packed)
+            check(K.ragged_paged_attention_pallas_q,
+                  ragged_paged_attention_xla,
+                  label, case, (q_rag, kq, vq, *rag, ks, vs), packed)
+            check(K.paged_attention_pallas, paged_attention_xla,
+                  label, case, (q_dec, k, v, *dec), every)
+            check(K.paged_attention_pallas_q, paged_attention_xla,
+                  label, case, (q_dec, kq, vq, *dec, ks, vs), every)
 
-    H, dc, dr = mla
-    scale = (dc // 4 + dr) ** -0.5    # deepseek: 128 nope + 64 rope
-    ql_rag, qp_rag = normal((1, T, H, dc)), normal((1, T, H, dr))
-    ql_dec, qp_dec = normal((R, 1, H, dc)), normal((R, 1, H, dr))
-    c, pe = normal((NP, page, 1, dc)), normal((NP, page, 1, dr))
-    (cq, cs), (pq, ps) = quantize_kv(c), quantize_kv(pe)
-    rag = (table, qpos, kv_lens, row_ids, scale)
-    dec = (table, dec_pos, kv_lens, scale)
-    check(K.ragged_paged_mla_attention_pallas, ragged_paged_mla_attention_xla,
-          "", (ql_rag, qp_rag, c, pe, *rag), packed)
-    check(K.ragged_paged_mla_attention_pallas_q,
-          ragged_paged_mla_attention_xla,
-          "", (ql_rag, qp_rag, cq, pq, *rag, cs, ps), packed)
-    check(K.paged_mla_attention_pallas, paged_mla_attention_xla,
-          "", (ql_dec, qp_dec, c, pe, *dec), every)
-    check(K.paged_mla_attention_pallas_q, paged_mla_attention_xla,
-          "", (ql_dec, qp_dec, cq, pq, *dec, cs, ps), every)
+        H, dc, dr = mla
+        scale = (dc // 4 + dr) ** -0.5    # deepseek: 128 nope + 64 rope
+        ql_rag, qp_rag = normal((1, tokens, H, dc)), normal((1, tokens, H, dr))
+        ql_dec, qp_dec = normal((rows, 1, H, dc)), normal((rows, 1, H, dr))
+        c, pe = normal((NP, page, 1, dc)), normal((NP, page, 1, dr))
+        (cq, cs), (pq, ps) = quantize_kv(c), quantize_kv(pe)
+        rag = (table, qpos, kv_lens, row_ids, scale)
+        dec = (table, dec_pos, kv_lens, scale)
+        check(K.ragged_paged_mla_attention_pallas,
+              ragged_paged_mla_attention_xla,
+              "", case, (ql_rag, qp_rag, c, pe, *rag), packed)
+        check(K.ragged_paged_mla_attention_pallas_q,
+              ragged_paged_mla_attention_xla,
+              "", case, (ql_rag, qp_rag, cq, pq, *rag, cs, ps), packed)
+        check(K.paged_mla_attention_pallas, paged_mla_attention_xla,
+              "", case, (ql_dec, qp_dec, c, pe, *dec), every)
+        check(K.paged_mla_attention_pallas_q, paged_mla_attention_xla,
+              "", case, (ql_dec, qp_dec, cq, pq, *dec, cs, ps), every)
 
-    _finish_child(args.result, counter, worst_abs_err=worst,
+    say("kernels: largest error " + ", ".join(
+        f"{err:.4g} ({case.strip() or 'mixed step'})"
+        for case, err in worst.items()))
+    _finish_child(args.result, counter, worst_abs_err=max(worst.values()),
                   cache_dir=cache_dir, cache_files_before=files0,
                   cache_files_after=chipenv.cache_files(cache_dir))
 

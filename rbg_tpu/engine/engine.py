@@ -965,9 +965,9 @@ class Engine:
     def _get_ragged_fn(self, R: int, T: int):
         """One jitted ragged forward per (row bucket, packed-token
         bucket). The cache key carries the kernel's grid revision so a
-        cache warmed for one grid (PR-7 token grid vs the round-16
-        block-ragged tile grid) can never alias programs compiled for
-        the other."""
+        cache warmed for one grid (the PR-7 token grid, the block-ragged
+        tiles over the whole table, the tiles over live pages) can never
+        alias programs compiled for another."""
         from rbg_tpu.ops.pallas.ragged_attention_kernel import \
             RAGGED_GRID_REV
         fn = self._ragged_fn_cache.get((R, T, RAGGED_GRID_REV))

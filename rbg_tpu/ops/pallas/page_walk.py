@@ -1,0 +1,220 @@
+"""The page walk every paged-attention kernel here shares.
+
+A row's K/V lives in ``ceil(len / page)`` pages of a pool, named by its
+line of the page table; the table is as wide as ``max_seq_len`` allows and
+almost all of it is dead. The kernels used to make the table's width a
+grid axis and skip the dead steps one by one: a grid step that does
+nothing still costs its index maps and the pipeline's bookkeeping, and at
+512 pages a row those steps were nearly all of a call.
+
+Here the walk is a flat list of LIVE work items. A kernel's work falls
+into *segments* (a decode row; in the ragged kernels a row's first token
+in a query tile), each of which attends its row's pages in ascending
+order, a *block* of ``pages_per_block`` consecutive pages an item.
+``live_block_starts`` turns the segments' live token counts into
+cumulative block counts in XLA; the grid is one axis whose DYNAMIC length
+is their total, and ``find_item`` maps grid step ``w`` back to (segment,
+block of the segment) by bisecting the scalar-prefetched starts. Nothing
+a kernel does depends on the table's width any more.
+
+A block's pages are scattered over the pool, so each pool is handed to
+the kernel once per page of a block (``block_specs``): the Pallas
+pipeline fetches the pages side by side, double-buffered as before, and
+``load_blocks`` joins them in VMEM. (Copies issued by the kernel itself
+into one buffer would be the plainer way; Mosaic refuses them for every
+pool whose trailing dims are not whole tiles: hd 64, KV 2, the scales,
+the latents.) A per-step cost is paid once for ``pages_per_block·page``
+slots.
+
+The two softmax updates (``gqa_attend``, ``mla_attend``) are the ones the
+grid kernels had, over a block instead of a page: same masks, same
+ascending order, same int8 scale folding.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+
+NEG_INF = -1e30
+_I32 = np.int32
+
+# Slots of one block. A per-step cost (0.5 us on a v5e) is paid once a
+# block, so wider is faster per page: 0.78, 0.51, 0.39, 0.33, 0.30 us at
+# 1, 2, 4, 8, 16 pages of 16 slots (PERF.md, PR 25). But every page of a
+# block is one more operand per pool, whose index map is traced and
+# lowered in each step program an engine warms: at 128 slots warm-up was
+# 5 s longer than at 64, for 0.4 % of a decode step.
+_BLOCK_SLOTS = 64
+
+
+def pages_per_block(page: int) -> int:
+    """Pages one work item attends (4 pages of 16 slots)."""
+    return max(1, _BLOCK_SLOTS // page)
+
+
+def live_block_starts(live_tokens, page: int, at_least_one):
+    """Cumulative live blocks of the segments, ``[S + 1]`` int32 (XLA).
+
+    ``live_tokens [S]`` is how many slots of its row each segment
+    attends (<= 0: none). A segment where ``at_least_one`` holds gets one
+    item even then, which attends nothing: the kernels initialise and
+    write an output block in the items of its first and last segment, so
+    every output block needs one. ``starts[-1]`` is the grid's length."""
+    block = pages_per_block(page) * page
+    blocks = jnp.maximum(-(-live_tokens // block), 0)
+    blocks = jnp.where(at_least_one, jnp.maximum(blocks, 1), blocks)
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(blocks, dtype=jnp.int32)])
+
+
+def find_item(starts_ref, w, n_segments: int):
+    """(segment, block of the segment) of work item ``w``: the LAST
+    segment whose start is <= w (segments without items share their
+    successor's start and are never found). A fixed-trip bisection over
+    SMEM scalars, so it can run inside an index map. An item past the
+    grid's end, which a pipeline may ask for ahead of time, resolves to
+    the last segment.
+
+    Written in ``lax`` primitives, here and in ``page_of_block``: every
+    index map of every page of a block traces and lowers this code, in
+    each of the dozens of step programs an engine warms, and a ``jnp``
+    call costs a nested ``jit`` each time (half a minute of set-up).
+    Unrolled: as a loop the scalar core pays a branch a trip in every
+    index map of every step (`_decode_call` 0.135 -> 0.26 ms on the chip)."""
+    lo, hi = _I32(0), _I32(n_segments)
+    for _ in range(max(n_segments - 1, 1).bit_length()):
+        mid = lax.shift_right_arithmetic(lax.add(lo, hi), _I32(1))
+        left = lax.le(starts_ref[mid], w)
+        lo, hi = lax.select(left, mid, lo), lax.select(left, hi, mid)
+    return lo, lax.sub(w, starts_ref[lo])
+
+
+def page_of_block(table_ref, row, block, j: int, live_tokens, page: int):
+    """Physical page of page ``j`` of ``row``'s block ``block``. Past the
+    row's last live page it is that last page again (its slots are masked:
+    they lie beyond ``live_tokens``), so no copy follows a table entry
+    beyond the live pages, whatever it names."""
+    last = lax.sub(lax.div(lax.add(live_tokens, _I32(page - 1)), _I32(page)),
+                   _I32(1))
+    p = lax.add(lax.mul(block, _I32(pages_per_block(page))), _I32(j))
+    p = lax.clamp(_I32(0), lax.min(p, last), _I32(table_ref.shape[1] - 1))
+    return table_ref[row, p]
+
+
+def block_specs(pools, page_id):
+    """Block specs and operands that hand each of ``pools`` (arrays
+    ``[NP, page, ...]``) to a kernel once per page of a block:
+    ``page_id(j, *grid_and_prefetch)`` is the physical page of the item's
+    ``j``-th page. The kernel receives, pool by pool, ``pages_per_block``
+    refs ``[1, page, ...]``."""
+    n = pages_per_block(pools[0].shape[1])
+    specs, operands = [], []
+    for pool in pools:
+        tail = (0,) * (pool.ndim - 1)
+        for j in range(n):
+            specs.append(pl.BlockSpec(
+                (1,) + pool.shape[1:],
+                lambda *a, j=j, tail=tail: (page_id(j, *a),) + tail))
+            operands.append(pool)
+    return specs, operands
+
+
+def load_blocks(page_refs):
+    """The item's block of each pool, ``[n·page, ...]``, from the page refs
+    a kernel received (``block_specs``' order: pool by pool)."""
+    n = pages_per_block(page_refs[0].shape[1])
+    return [jnp.concatenate([r[0] for r in page_refs[i:i + n]], axis=0)
+            for i in range(0, len(page_refs), n)]
+
+
+def init_softmax(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def finalize_softmax(l_ref, acc_ref, dtype):
+    """acc / l, with rows that attended nothing (pads, empty rows)
+    finalizing to zero through the denominator's guard."""
+    return (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(dtype)
+
+
+def _update(scores, mask, m_ref, l_ref, acc_ref, values):
+    """One online-softmax step; ``values(probs)`` is the block's
+    probability-weighted value sum."""
+    scores = jnp.where(mask, scores, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # A fully masked query row: m_new == m_prev → alpha 1, probs 0 → its
+    # state is untouched by this block (no special casing).
+    probs = jnp.exp(scores - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + values(probs)
+
+
+def gqa_attend(q, k, v, ks, vs, token0, limit, m_ref, l_ref, acc_ref):
+    """Attend query rows ``q [KV, rows, hd]`` to one block ``k, v
+    [S, KV, hd]`` whose first slot is token ``token0`` of the row; query
+    row ``j`` sees slots ``< limit`` (a scalar, or ``[rows, 1]``).
+
+    int8 pools hand their per-(slot, head) scales ``ks, vs [S, KV]``:
+    they factor out of both dots (scores ·= ks, pv = (probs·vs)·v), so
+    the pages are never multiplied elementwise."""
+    q = q.astype(jnp.float32)
+    rows, hd = q.shape[1], q.shape[2]
+    k_t = jnp.transpose(k.astype(jnp.float32), (1, 0, 2))   # [KV, S, hd]
+    v_t = jnp.transpose(v.astype(jnp.float32), (1, 0, 2))
+    scores = jax.lax.dot_general(
+        q, k_t, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * (1.0 / (hd ** 0.5))
+    if ks is not None:
+        scores = scores * jnp.transpose(ks, (1, 0))[:, None, :]
+    token_idx = token0 + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, k.shape[0]), dimension=1)
+    mask = (token_idx < limit)[None]                        # [1, rows, S]
+
+    def values(probs):
+        if vs is not None:
+            probs = probs * jnp.transpose(vs, (1, 0))[:, None, :]
+        return jax.lax.dot_general(
+            probs, v_t, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)             # [KV, rows, hd]
+
+    _update(scores, mask, m_ref, l_ref, acc_ref, values)
+
+
+def mla_attend(ql, qp, c, pe, cs, ps, token0, limit, scale,
+               m_ref, l_ref, acc_ref):
+    """The latent twin of ``gqa_attend``: ``ql [rows, dc]``, ``qp
+    [rows, dr]`` against one block ``c [S, dc]``, ``pe [S, dr]``; the
+    values are the latents. int8 pools hand per-slot scales ``cs, ps
+    [S]``: the latent scale multiplies the latent score term and the
+    probabilities before the value dot, the RoPE scale the RoPE term."""
+    ql = ql.astype(jnp.float32)
+    qp = qp.astype(jnp.float32)
+    c = c.astype(jnp.float32)
+    pe = pe.astype(jnp.float32)
+    s_c = jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    s_pe = jax.lax.dot_general(qp, pe, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    if cs is not None:
+        s_c = s_c * cs[None, :]
+        s_pe = s_pe * ps[None, :]
+    scores = (s_c + s_pe) * scale                           # [rows, S]
+    token_idx = token0 + jax.lax.broadcasted_iota(
+        jnp.int32, scores.shape, dimension=1)
+
+    def values(probs):
+        if cs is not None:
+            probs = probs * cs[None, :]
+        return jax.lax.dot_general(probs, c, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    _update(scores, token_idx < limit, m_ref, l_ref, acc_ref, values)

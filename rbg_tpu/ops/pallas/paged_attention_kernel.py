@@ -4,23 +4,29 @@ Why a kernel: the XLA fallback (`paged_attention_xla`) materializes the
 gathered per-sequence KV view ``[B, S, KV, hd]`` in HBM before attending —
 every decode step pays ~3× the pool's live-token traffic (gather write +
 attention read, plus the pool read). Decode attention is pure HBM bandwidth,
-so this kernel streams each page HBM→VMEM exactly once and keeps the
+so this kernel streams each live page HBM→VMEM exactly once and keeps the
 flash-style online softmax state in VMEM scratch.
 
-Design (see /opt/skills/guides/pallas_guide.md):
-* grid = (B, P): one sequence per outer step, its pages inner ("arbitrary"
-  semantics — scratch accumulators persist across the page walk).
-* page_table + kv_lens are scalar-prefetch args: the k/v BlockSpec index_map
-  dereferences the page table, so the pipeline DMAs the RIGHT physical page
-  ahead of compute (double-buffered by the Pallas pipeline itself).
-* GQA via one batched dot per page: [KV, G, hd] × [KV, page, hd].
-* Out-of-range pages (beyond a sequence's kv_len) still prefetch page 0 (the
-  reserved null page) and are masked in-softmax — no divergent control flow.
+Design (see /opt/skills/guides/pallas_guide.md and ``page_walk.py``):
+* the grid is ONE axis of dynamic length: the rows' live blocks of pages,
+  row after row ("arbitrary" semantics — scratch accumulators persist
+  across a row's walk). The grid used to be (B, P), every page of
+  ``max_seq_len`` for every row; a row of 900 tokens under a table 512
+  wide spent eight steps in nine stepping over nothing.
+* page_table, kv_lens and the rows' cumulative block counts are
+  scalar-prefetch args: the k/v BlockSpec index_maps find the step's row
+  and block by bisection and dereference the page table, so the pipeline
+  DMAs the RIGHT physical pages ahead of compute (double-buffered by the
+  Pallas pipeline itself). Entries past a row's live pages are never
+  followed.
+* GQA via one batched dot per block: [KV, G, hd] × [KV, n·page, hd].
+* A row of length 0 (a free slot of the batch) keeps one step that
+  attends nothing and writes zeros.
 
 Reference context: this is the TPU analog of the ragged/paged attention
-kernels the PAPERS.md "Ragged Paged Attention" paper describes; the engine
-only uses it for decode (T == 1); prefill chunks stay on the dense XLA path
-(MXU-bound, already optimal).
+kernels the PAPERS.md "Ragged Paged Attention" paper describes. The engine
+uses it for pure-decode batches (T == 1); steps that hold a prefill chunk
+run the block-ragged kernel of ragged_attention_kernel.py.
 """
 
 from __future__ import annotations
@@ -32,113 +38,74 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NEG_INF = -1e30
+from rbg_tpu.ops.pallas import page_walk as W
+
+
+def _page_id(j, w, table, lens, starts, *, page):
+    """Physical page of page ``j`` of decode work item ``w`` (every row
+    is a segment)."""
+    b, block = W.find_item(starts, w, lens.shape[0])
+    return W.page_of_block(table, b, block, j, lens[b], page)
+
+
+def _row_of(rank):
+    def index_map(w, table, lens, starts):
+        return (W.find_item(starts, w, lens.shape[0])[0],) + (0,) * (rank - 1)
+    return index_map
 
 
 def _decode_kernel(
     # scalar prefetch
     page_table_ref,   # [B, P] int32 (SMEM)
     kv_lens_ref,      # [B] int32 (SMEM)
+    starts_ref,       # [B + 1] int32 (SMEM) — cumulative live blocks
     # blocks
     q_ref,            # [1, KV, G, hd] (VMEM)
-    k_ref,            # [1, page, KV, hd] — the page picked by index_map
-    v_ref,
-    out_ref,          # [1, KV, G, hd]
-    # scratch
-    m_ref,            # [KV, G, 1] running max
-    l_ref,            # [KV, G, 1] running denom
-    acc_ref,          # [KV, G, hd] running numerator
-    *,
-    # int8 pools (the _decode_kernel_q entry): per-(slot, head) absmax
-    # scales [1, page, KV]. Folded ALGEBRAICALLY — scales factor out of
-    # both dot products, so the int8 page tensors feed the MXU directly.
-    ks_ref=None,
-    vs_ref=None,
+    *refs,            # the item's pages, picked by index_map: n k refs and
+                      # n v refs [1, page, KV, hd] (int8 pools: then n + n
+                      # scale refs [1, page, KV] f32); out_ref [1, KV, G,
+                      # hd]; scratch: m [KV, G, 1] running max, l [KV, G, 1]
+                      # running denom, acc [KV, G, hd] running numerator
 ):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    num_p = pl.num_programs(1)
-    page = k_ref.shape[1]
-    quantized = ks_ref is not None
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    *pages, out_ref, m_ref, l_ref, acc_ref = refs
+    w = pl.program_id(0)
+    b, block = W.find_item(starts_ref, w, kv_lens_ref.shape[0])
     kv_len = kv_lens_ref[b]
+    page = pages[0].shape[1]
+    token0 = block * (W.pages_per_block(page) * page)   # the block's first slot
 
-    # Skip pages entirely past the sequence (still DMA'd, never read).
-    @pl.when(p * page < kv_len)
+    @pl.when(block == 0)
+    def _init():
+        W.init_softmax(m_ref, l_ref, acc_ref)
+
+    # False only in the one item of an empty row.
+    @pl.when(token0 < kv_len)
     def _attend():
-        q = q_ref[0].astype(jnp.float32)                    # [KV, G, hd]
-        k = k_ref[0].astype(jnp.float32)                    # [page, KV, hd]
-        v = v_ref[0].astype(jnp.float32)
-        hd = q.shape[-1]
+        # int8 pools: per-(slot, head) absmax scales, folded
+        # ALGEBRAICALLY, so the int8 pages feed the MXU directly.
+        k, v, *scales = W.load_blocks(pages)
+        ks, vs = scales or (None, None)
+        W.gqa_attend(q_ref[0], k, v, ks, vs, token0, kv_len,
+                     m_ref, l_ref, acc_ref)
 
-        k_t = jnp.transpose(k, (1, 0, 2))                   # [KV, page, hd]
-        v_t = jnp.transpose(v, (1, 0, 2))
-        # scores[kv, g, t] = q[kv, g, :] · k[kv, t, :]
-        scores = jax.lax.dot_general(
-            q, k_t,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * (1.0 / (hd ** 0.5))                             # [KV, G, page]
-        if quantized:
-            # scores ·= ks[t, kv] (k's scale factors out of the dot).
-            ks_t = jnp.transpose(ks_ref[0], (1, 0))         # [KV, page]
-            scores = scores * ks_t[:, None, :]
-
-        token_idx = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, dimension=2)
-        scores = jnp.where(token_idx < kv_len, scores, _NEG_INF)
-
-        m_prev = m_ref[:]                                   # [KV, G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)                     # [KV, G, 1]
-        probs = jnp.exp(scores - m_new)                     # [KV, G, page]
-
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        # acc[kv, g, :] += probs[kv, g, t] * v[kv, t, :]; for int8 v the
-        # scale folds into probs BEFORE the dot (pv = (probs·vs)·v_int8).
-        pmat = probs
-        if quantized:
-            vs_t = jnp.transpose(vs_ref[0], (1, 0))         # [KV, page]
-            pmat = probs * vs_t[:, None, :]
-        pv = jax.lax.dot_general(
-            pmat, v_t,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                                   # [KV, G, hd]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-
-    @pl.when(p == num_p - 1)
+    @pl.when(w + 1 == starts_ref[b + 1])
     def _finalize():
-        denom = jnp.maximum(l_ref[:], 1e-30)                # guard empty rows
-        out_ref[0] = (acc_ref[:] / denom).astype(out_ref.dtype)
+        out_ref[0] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _decode_call(q, k_pages, v_pages, page_table, kv_lens, interpret=False):
-    """q: [B, KV, G, hd]; pages: [NP, page, KV, hd]. Returns [B, KV, G, hd]."""
+def _decode(q, pools, page_table, kv_lens, interpret):
+    """q: [B, KV, G, hd]; pools: k, v pages [NP, page, KV, hd], and for
+    int8 pools their scales [NP, page, KV] f32. Returns q's shape."""
     B, KV, G, hd = q.shape
-    NP, page, _, _ = k_pages.shape
-    P = page_table.shape[1]
-
+    page = pools[0].shape[1]
+    starts = W.live_block_starts(kv_lens, page, True)
+    page_specs, page_operands = W.block_specs(
+        pools, functools.partial(_page_id, page=page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda b, p, table, lens: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page, KV, hd),
-                         lambda b, p, table, lens: (table[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, page, KV, hd),
-                         lambda b, p, table, lens: (table[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, KV, G, hd),
-                               lambda b, p, table, lens: (b, 0, 0, 0)),
+        num_scalar_prefetch=3,
+        grid=(starts[B],),
+        in_specs=[pl.BlockSpec((1, KV, G, hd), _row_of(4))] + page_specs,
+        out_specs=pl.BlockSpec((1, KV, G, hd), _row_of(4)),
         scratch_shapes=[
             pltpu.VMEM((KV, G, 1), jnp.float32),
             pltpu.VMEM((KV, G, 1), jnp.float32),
@@ -148,18 +115,23 @@ def _decode_call(q, k_pages, v_pages, page_table, kv_lens, interpret=False):
     return pl.pallas_call(
         _decode_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, kv_lens, q, k_pages, v_pages)
+    )(page_table, kv_lens, starts, q, *page_operands)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _decode_call(q, k_pages, v_pages, page_table, kv_lens, interpret=False):
+    return _decode(q, (k_pages, v_pages), page_table, kv_lens, interpret)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, q_positions,
                            kv_lens, interpret: bool = False):
     """Drop-in for ``paged_attention_xla``. Decode (T == 1) runs the kernel;
-    other shapes fall back to the XLA path (prefill is MXU-bound there)."""
+    other shapes fall back to the XLA path (the engine sends prefill
+    through the ragged kernel, not through here)."""
     B, T, H, hd = q.shape
     KV = k_pages.shape[2]
     if T != 1:
@@ -176,79 +148,26 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, q_positions,
 
 # ---- int8 (quantized pool) decode ------------------------------------------
 #
-# The SAME kernel body handles quantized pools via a static ``quantized``
-# flag: pages arrive int8 with per-(slot, head) absmax scales alongside
-# (ops/paged_attention.quantize_kv). Scales are folded ALGEBRAICALLY —
-# they factor out of both dot products (scores[kv,g,t] = (q·k_int8)·ks[t]
-# and pv = (probs·vs)·v_int8) — so the [page, KV, hd] page tensors are
-# never multiplied elementwise and the MXU consumes the int8 pages'
-# values directly after cast.
+# The SAME kernel body handles quantized pools: pages arrive int8 with
+# per-(slot, head) absmax scales alongside (ops/paged_attention.quantize_kv),
+# walked as two more pools. Scales are folded ALGEBRAICALLY — they factor
+# out of both dot products (scores[kv,g,t] = (q·k_int8)·ks[t] and
+# pv = (probs·vs)·v_int8) — so the [S, KV, hd] blocks are never multiplied
+# elementwise and the MXU consumes the int8 pages' values directly after
+# cast.
 #
-# Byte accounting (honest): int8 halves the k/v page DMA, but the f32
-# scale blocks are (1, page, KV) — the KV lane dim pads to 128 on real
-# hardware, so each scale block moves ~page*128*4 B. At page=16/KV=8/
-# hd=128 that is k+v 64 KB (bf16) → 32 KB (int8) + ~16 KB padded scales
-# ≈ a 25% net walk saving, not 50%. Packing scales lane-major across
-# pages is the documented follow-up seam.
-
-
-def _decode_kernel_q(
-    # scalar prefetch
-    page_table_ref,   # [B, P] int32 (SMEM)
-    kv_lens_ref,      # [B] int32 (SMEM)
-    # blocks
-    q_ref,            # [1, KV, G, hd] (VMEM)
-    k_ref,            # [1, page, KV, hd] int8 — the page picked by index_map
-    v_ref,
-    ks_ref,           # [1, page, KV] f32 scales
-    vs_ref,
-    out_ref,          # [1, KV, G, hd]
-    # scratch
-    m_ref, l_ref, acc_ref,
-):
-    _decode_kernel(page_table_ref, kv_lens_ref, q_ref, k_ref, v_ref,
-                   out_ref, m_ref, l_ref, acc_ref,
-                   ks_ref=ks_ref, vs_ref=vs_ref)
+# Byte accounting (honest): int8 halves the k/v page DMA, but a page's f32
+# scales are (page, KV) — the KV lane dim pads to 128 on real hardware, so
+# each moves ~page*128*4 B. At page=16/KV=8/hd=128 that is k+v 64 KB (bf16)
+# → 32 KB (int8) + ~16 KB padded scales ≈ a 25% net walk saving, not 50%.
+# Packing scales lane-major across pages is the documented follow-up seam.
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _decode_call_q(q, k_pages, v_pages, k_scales, v_scales, page_table,
                    kv_lens, interpret=False):
-    """int8 variant: pages int8, scales f32 [NP, page, KV]. Returns
-    [B, KV, G, hd]."""
-    B, KV, G, hd = q.shape
-    _, page, _, _ = k_pages.shape
-    P = page_table.shape[1]
-
-    pick4 = lambda b, p, table, lens: (table[b, p], 0, 0, 0)
-    pick3 = lambda b, p, table, lens: (table[b, p], 0, 0)
-    fixed = lambda b, p, table, lens: (b, 0, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, KV, G, hd), fixed),
-            pl.BlockSpec((1, page, KV, hd), pick4),
-            pl.BlockSpec((1, page, KV, hd), pick4),
-            pl.BlockSpec((1, page, KV), pick3),
-            pl.BlockSpec((1, page, KV), pick3),
-        ],
-        out_specs=pl.BlockSpec((1, KV, G, hd), fixed),
-        scratch_shapes=[
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        _decode_kernel_q,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(page_table, kv_lens, q, k_pages, v_pages, k_scales, v_scales)
+    return _decode(q, (k_pages, v_pages, k_scales, v_scales), page_table,
+                   kv_lens, interpret)
 
 
 def paged_attention_pallas_q(q, k_pages, v_pages, page_table, q_positions,
@@ -282,110 +201,64 @@ def paged_attention_pallas_q(q, k_pages, v_pages, page_table, q_positions,
 # heads against it. The XLA fallback instead gathers the rows' pages into
 # a [B, S, dc] view in HBM every step — at long context that gather (plus
 # its attention re-read) is ~3× the live-latent traffic, same argument as
-# the GQA kernel above.
+# the GQA kernel above. int8 latent pools walk their per-slot scales
+# [NP, page, 1] as two more pools.
 
 
 def _mla_decode_kernel(
     # scalar prefetch
     page_table_ref,   # [B, P] int32 (SMEM)
     kv_lens_ref,      # [B] int32 (SMEM)
+    starts_ref,       # [B + 1] int32 (SMEM) — cumulative live blocks
     # blocks
     ql_ref,           # [1, H, dc] (VMEM) — q_nope absorbed through W_uk
     qp_ref,           # [1, H, dr] — RoPE'd query part
-    c_ref,            # [1, page, 1, dc] — the page picked by index_map
-    pe_ref,           # [1, page, 1, dr]
-    out_ref,          # [1, H, dc] — latent attention output
-    # scratch
-    m_ref,            # [H, 1] running max
-    l_ref,            # [H, 1] running denom
-    acc_ref,          # [H, dc] running numerator
-    *,
+    *refs,            # the item's pages, picked by index_map: n c refs
+                      # [1, page, 1, dc] and n pe refs [1, page, 1, dr]
+                      # (int8 pools: then n + n scale refs [1, page, 1]
+                      # f32); out_ref [1, H, dc] — latent attention output;
+                      # scratch: m [H, 1], l [H, 1], acc [H, dc]
     scale: float,
-    cs_ref=None,      # int8 pools: [1, page, 1] f32 scales
-    ps_ref=None,
 ):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    num_p = pl.num_programs(1)
-    page = c_ref.shape[1]
-    quantized = cs_ref is not None
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    *pages, out_ref, m_ref, l_ref, acc_ref = refs
+    w = pl.program_id(0)
+    b, block = W.find_item(starts_ref, w, kv_lens_ref.shape[0])
     kv_len = kv_lens_ref[b]
+    page = pages[0].shape[1]
+    token0 = block * (W.pages_per_block(page) * page)   # the block's first slot
 
-    @pl.when(p * page < kv_len)
+    @pl.when(block == 0)
+    def _init():
+        W.init_softmax(m_ref, l_ref, acc_ref)
+
+    @pl.when(token0 < kv_len)
     def _attend():
-        ql = ql_ref[0].astype(jnp.float32)              # [H, dc]
-        qp = qp_ref[0].astype(jnp.float32)              # [H, dr]
-        c = c_ref[0, :, 0, :].astype(jnp.float32)       # [page, dc]
-        pe = pe_ref[0, :, 0, :].astype(jnp.float32)     # [page, dr]
+        c, pe, *scales = (blk[:, 0] for blk in W.load_blocks(pages))
+        cs, ps = scales or (None, None)
+        W.mla_attend(ql_ref[0], qp_ref[0], c, pe, cs, ps, token0, kv_len,
+                     scale, m_ref, l_ref, acc_ref)
 
-        s_c = jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        s_pe = jax.lax.dot_general(qp, pe, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        if quantized:
-            # int8 latent pool: fold the per-slot scales ALGEBRAICALLY —
-            # the latent scale multiplies the latent score term, the RoPE
-            # scale the RoPE term; the pages feed the MXU as int8.
-            s_c = s_c * cs_ref[0, :, 0][None, :]
-            s_pe = s_pe * ps_ref[0, :, 0][None, :]
-        scores = (s_c + s_pe) * scale                   # [H, page]
-
-        token_idx = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, dimension=1)
-        scores = jnp.where(token_idx < kv_len, scores, _NEG_INF)
-
-        m_prev = m_ref[:]                               # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)                 # [H, page]
-
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        pmat = probs
-        if quantized:
-            # Values ARE the latents: their scale folds into the probs
-            # before the value dot (same algebra as the GQA v-scale fold).
-            pmat = probs * cs_ref[0, :, 0][None, :]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pmat, c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [H, dc]
-
-    @pl.when(p == num_p - 1)
+    @pl.when(w + 1 == starts_ref[b + 1])
     def _finalize():
-        denom = jnp.maximum(l_ref[:], 1e-30)
-        out_ref[0] = (acc_ref[:] / denom).astype(out_ref.dtype)
+        out_ref[0] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _mla_decode_call(q_lat, q_pe, c_pages, pe_pages, page_table, kv_lens,
-                     scale, interpret=False):
-    """q_lat: [B, H, dc], q_pe: [B, H, dr]; pages: [NP, page, 1, d].
+def _mla_decode(q_lat, q_pe, pools, page_table, kv_lens, scale, interpret):
+    """q_lat: [B, H, dc], q_pe: [B, H, dr]; pools: c, pe pages
+    [NP, page, 1, d], and for int8 pools their scales [NP, page, 1] f32.
     Returns the latent attention output [B, H, dc]."""
     B, H, dc = q_lat.shape
     dr = q_pe.shape[-1]
-    _, page, _, _ = c_pages.shape
-    P = page_table.shape[1]
-
+    page = pools[0].shape[1]
+    starts = W.live_block_starts(kv_lens, page, True)
+    page_specs, page_operands = W.block_specs(
+        pools, functools.partial(_page_id, page=page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, H, dc), lambda b, p, table, lens: (b, 0, 0)),
-            pl.BlockSpec((1, H, dr), lambda b, p, table, lens: (b, 0, 0)),
-            pl.BlockSpec((1, page, 1, dc),
-                         lambda b, p, table, lens: (table[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, page, 1, dr),
-                         lambda b, p, table, lens: (table[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, dc),
-                               lambda b, p, table, lens: (b, 0, 0)),
+        num_scalar_prefetch=3,
+        grid=(starts[B],),
+        in_specs=[pl.BlockSpec((1, H, dc), _row_of(3)),
+                  pl.BlockSpec((1, H, dr), _row_of(3))] + page_specs,
+        out_specs=pl.BlockSpec((1, H, dc), _row_of(3)),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -395,12 +268,18 @@ def _mla_decode_call(q_lat, q_pe, c_pages, pe_pages, page_table, kv_lens,
     return pl.pallas_call(
         functools.partial(_mla_decode_kernel, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, dc), q_lat.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_lat.shape, q_lat.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, kv_lens, q_lat, q_pe, c_pages, pe_pages)
+    )(page_table, kv_lens, starts, q_lat, q_pe, *page_operands)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _mla_decode_call(q_lat, q_pe, c_pages, pe_pages, page_table, kv_lens,
+                     scale, interpret=False):
+    return _mla_decode(q_lat, q_pe, (c_pages, pe_pages), page_table,
+                       kv_lens, scale, interpret)
 
 
 def paged_mla_attention_pallas(q_lat, q_pe, c_pages, pe_pages, page_table,
@@ -421,65 +300,11 @@ def paged_mla_attention_pallas(q_lat, q_pe, c_pages, pe_pages, page_table,
     return out[:, None]
 
 
-def _mla_decode_kernel_q(
-    # scalar prefetch
-    page_table_ref, kv_lens_ref,
-    # blocks
-    ql_ref, qp_ref, c_ref, pe_ref,
-    cs_ref,           # [1, page, 1] f32 scales
-    ps_ref,
-    out_ref,
-    # scratch
-    m_ref, l_ref, acc_ref,
-    *,
-    scale: float,
-):
-    _mla_decode_kernel(page_table_ref, kv_lens_ref, ql_ref, qp_ref,
-                       c_ref, pe_ref, out_ref, m_ref, l_ref, acc_ref,
-                       scale=scale, cs_ref=cs_ref, ps_ref=ps_ref)
-
-
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _mla_decode_call_q(q_lat, q_pe, c_pages, pe_pages, c_scales, pe_scales,
                        page_table, kv_lens, scale, interpret=False):
-    """int8-latent-pool twin of ``_mla_decode_call``: scales ride two
-    extra [NP, page, 1] operands blocked alongside their pages."""
-    B, H, dc = q_lat.shape
-    dr = q_pe.shape[-1]
-    _, page, _, _ = c_pages.shape
-    P = page_table.shape[1]
-
-    pick4 = lambda b, p, table, lens: (table[b, p], 0, 0, 0)
-    pick3 = lambda b, p, table, lens: (table[b, p], 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, H, dc), lambda b, p, table, lens: (b, 0, 0)),
-            pl.BlockSpec((1, H, dr), lambda b, p, table, lens: (b, 0, 0)),
-            pl.BlockSpec((1, page, 1, dc), pick4),
-            pl.BlockSpec((1, page, 1, dr), pick4),
-            pl.BlockSpec((1, page, 1), pick3),
-            pl.BlockSpec((1, page, 1), pick3),
-        ],
-        out_specs=pl.BlockSpec((1, H, dc),
-                               lambda b, p, table, lens: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, dc), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_mla_decode_kernel_q, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, dc), q_lat.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(page_table, kv_lens, q_lat, q_pe, c_pages, pe_pages,
-      c_scales, pe_scales)
+    return _mla_decode(q_lat, q_pe, (c_pages, pe_pages, c_scales, pe_scales),
+                       page_table, kv_lens, scale, interpret)
 
 
 def paged_mla_attention_pallas_q(q_lat, q_pe, c_pages, pe_pages, page_table,

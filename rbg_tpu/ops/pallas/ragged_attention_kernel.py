@@ -5,10 +5,11 @@ per outer step — which made the structural win (ONE dispatch serves an
 arbitrary prefill/decode mix) but paid a bandwidth tax: a prefill row's
 pages were streamed HBM→VMEM once PER TOKEN of the chunk. Round 2 is the
 block-ragged tiling of the RPA paper (PAPERS.md): query TILES that span
-row boundaries, so each KV page a tile needs streams once per tile.
-
-Block-ragged grid = (T/TILE, TILE, TILE·P inner steps collapsed to
-(row-in-tile, page)):
+row boundaries, so each KV page a tile needs streams once per tile. Its
+grid was (T/TILE, TILE, P): every page of ``max_seq_len`` for every
+row-slot of every tile, live or not, which at a table 512 wide was 99 %
+of a call's steps. Round 3 (this file) keeps the tiling and walks LIVE
+pages only (``page_walk.py``):
 
 * the packed token axis is padded to a multiple of ``Q_TILE`` (pad tokens
   carry ``q_position == -1`` — the SAME pad contract as the pack itself)
@@ -18,18 +19,18 @@ Block-ragged grid = (T/TILE, TILE, TILE·P inner steps collapsed to
   shape cast — Mosaic (v5e, jax 0.9) refuses to merge a G-row axis
   smaller than a sublane tile, and has no layout for a rank-1 vector of
   per-token limits, so those are built against a 2-D iota instead;
-* inner step ``(r, p)`` nominates packed token ``t = tile·TILE + r`` and
-  logical page ``p`` of ``row_ids[t]``. The kernel computes FIRST-
-  OCCURRENCE leadership from the scalar-prefetched ``row_ids``: only the
-  first token of each distinct row in the tile activates its row's page
-  walk, and an active step attends EVERY tile token of that row at once
-  (per-token causal limits masked in-softmax). A row with a C-token chunk
-  in the tile therefore streams its pages once, not C times;
-* the k/v index_map clamps followers and past-limit pages to the
-  previously streamed page index — consecutive grid steps with an equal
-  block index make the Pallas pipeline SKIP the copy, so duplicate-row
-  and past-limit steps cost loop overhead only, no HBM traffic (the
-  token-grid kernel DMA'd dead pages; this one doesn't);
+* FIRST-OCCURRENCE leadership is computed in XLA (``_tile_segments``):
+  only the first token of each distinct row in a tile leads that row's
+  page walk, up to the row's largest causal limit over the tile's tokens,
+  and an item attends EVERY tile token of that row at once (per-token
+  causal limits masked in-softmax). A row with a C-token chunk in the
+  tile therefore streams its pages once, not C times;
+* the grid is ONE axis of dynamic length: the live (leader, block of
+  pages) items in tile order, their count the cumulative sum the kernel
+  bisects to find its item. Followers, pads and pages past a limit have
+  no grid step at all; an all-pad tile keeps one item, which attends
+  nothing and writes the tile's zeros. The table's width appears in no
+  grid, block or loop bound;
 * causal masking is unchanged: token ``t`` attends slots
   ``< min(kv_lens[row_ids[t]], q_positions[t] + 1)``; pad tokens
   (position −1) have limit ≤ 0 → always masked → zero accumulators
@@ -57,8 +58,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from rbg_tpu.ops.pallas import page_walk as W
 
 _NEG_INF = -1e30
 
@@ -68,30 +72,50 @@ _NEG_INF = -1e30
 Q_TILE = 8
 
 # Grid revision — part of the engine's ragged program-cache key
-# (warm_ragged): a cache warmed for the PR-7 token grid must not alias
-# programs compiled for the block-ragged grid.
-RAGGED_GRID_REV = 2
+# (warm_ragged): a cache warmed for one grid must not alias programs
+# compiled for another. 1: the PR-7 token grid; 2: block-ragged tiles over
+# every page of the table; 3: block-ragged tiles over live pages only.
+RAGGED_GRID_REV = 3
 
 
-def _tile_leadership(row_ids_ref, kv_lens_ref, q_pos_ref, t0, r_off, row,
-                     tile):
-    """Scalar scan over one tile: is token ``t0 + r_off`` the FIRST
-    occurrence of ``row`` in the tile, and what is the row's max causal
-    limit across its tile tokens? Returns (dup, row_limit) — ``dup`` True
-    means a smaller r_off already leads this row (this step skips), and
-    ``row_limit`` bounds the page walk (≤ 0 for all-pad rows: their
-    positions are −1, so no page ever activates)."""
-    def body(k, carry):
-        dup, lim = carry
-        rk = row_ids_ref[t0 + k]
-        same = rk == row
-        dup = dup | (same & (k < r_off))
-        tok_lim = jnp.minimum(kv_lens_ref[row], q_pos_ref[t0 + k] + 1)
-        lim = jnp.maximum(lim, jnp.where(same, tok_lim, 0))
-        return dup, lim
-    return jax.lax.fori_loop(
-        0, tile, body,
-        (jnp.zeros((), jnp.bool_), jnp.zeros((), jnp.int32)))
+def _tile_segments(row_ids, q_pos, kv_lens, page):
+    """The ragged kernels' work, in XLA: per packed token (a segment of
+    the walk) how many slots of its row it leads the tile through, and
+    the cumulative live blocks of that (``page_walk.live_block_starts``).
+
+    A token LEADS its row in its tile if it is the row's first occurrence
+    there; it walks up to the row's largest causal limit over the tile's
+    tokens (``min(kv_lens[row], q_position + 1)``), and every tile token
+    of that row is attended in its items. Followers, and rows whose limit
+    is <= 0 (pads carry position −1), lead nothing: they get no items, so
+    their grid steps do not exist. Each tile's first token keeps one
+    item regardless, which writes the tile's output block.
+
+    Returns (lead_tokens [Tp] int32, starts [Tp + 1] int32)."""
+    rows = row_ids.reshape(-1, Q_TILE)
+    lims = jnp.minimum(kv_lens[row_ids], q_pos + 1).reshape(-1, Q_TILE)
+    same = rows[:, :, None] == rows[:, None, :]             # [NT, r, k]
+    k_lt_r = jnp.tri(Q_TILE, k=-1, dtype=jnp.bool_)         # k < r
+    dup = jnp.any(same & k_lt_r, axis=2)
+    row_limit = jnp.max(jnp.where(same, lims[:, None, :], 0), axis=2)
+    lead = jnp.where(dup, 0, row_limit).astype(jnp.int32).reshape(-1)
+    first_of_tile = jnp.arange(lead.shape[0]) % Q_TILE == 0
+    return lead, W.live_block_starts(lead, page, first_of_tile)
+
+
+def _page_id(j, w, table, lens, rows, qpos, lead, starts, *, page):
+    """Physical page of page ``j`` of ragged work item ``w`` (every
+    packed token is a segment; only leaders have items)."""
+    t, block = W.find_item(starts, w, rows.shape[0])
+    return W.page_of_block(table, rows[t], block, j, lead[t], page)
+
+
+def _tile_of(rank):
+    """Index map of the query/output tile of an item."""
+    def index_map(w, table, lens, rows, qpos, lead, starts):
+        t, _ = W.find_item(starts, w, rows.shape[0])
+        return (jax.lax.div(t, np.int32(Q_TILE)),) + (0,) * (rank - 1)
+    return index_map
 
 
 def _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row, tile, group):
@@ -120,111 +144,47 @@ def _block_ragged_kernel(
     kv_lens_ref,      # [R] int32 (SMEM)
     row_ids_ref,      # [Tp] int32 (SMEM) — Tp padded to a Q_TILE multiple
     q_pos_ref,        # [Tp] int32 (SMEM)
+    lead_ref,         # [Tp] int32 (SMEM) — slots each token leads (0: none)
+    starts_ref,       # [Tp + 1] int32 (SMEM) — cumulative live blocks
     # blocks
     q_ref,            # [1, KV, TILE·G, hd] (VMEM) — one query tile, the
                       # tile's tokens already folded into the query-row axis
-    k_ref,            # [1, page, KV, hd] — the page picked by index_map
-    v_ref,
-    out_ref,          # [1, KV, TILE·G, hd]
-    # scratch — online softmax state for the WHOLE tile
-    m_ref,            # [KV, TILE·G, 1] running max
-    l_ref,            # [KV, TILE·G, 1] running denom
-    acc_ref,          # [KV, TILE·G, hd] running numerator
-    *,
+    *refs,            # the item's pages, picked by index_map: n k refs and
+                      # n v refs [1, page, KV, hd] (int8 pools: then n + n
+                      # scale refs [1, page, KV] f32); out_ref [1, KV,
+                      # TILE·G, hd]; scratch — online softmax state for the
+                      # WHOLE tile: m, l [KV, TILE·G, 1], acc [KV, TILE·G, hd]
     tile: int,
-    ks_ref=None,      # int8 pools: [1, page, KV] f32 scales
-    vs_ref=None,
 ):
-    i = pl.program_id(0)          # tile
-    r_off = pl.program_id(1)      # row-slot within the tile
-    p = pl.program_id(2)          # logical page of that slot's row
-    num_r = pl.num_programs(1)
-    num_p = pl.num_programs(2)
-    page = k_ref.shape[1]
-    quantized = ks_ref is not None
+    *pages, out_ref, m_ref, l_ref, acc_ref = refs
+    w = pl.program_id(0)
+    t, block = W.find_item(starts_ref, w, row_ids_ref.shape[0])
+    t0 = t // tile * tile
+    page = pages[0].shape[1]
+    token0 = block * (W.pages_per_block(page) * page)   # the block's first slot
 
-    @pl.when((r_off == 0) & (p == 0))
+    # A tile's first token always has an item, so the tile's first item
+    # is that token's first.
+    @pl.when((t == t0) & (block == 0))
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        W.init_softmax(m_ref, l_ref, acc_ref)
 
-    t0 = i * tile
-    row = row_ids_ref[t0 + r_off]
-    dup, row_limit = _tile_leadership(row_ids_ref, kv_lens_ref, q_pos_ref,
-                                      t0, r_off, row, tile)
-
-    # One active step per (row-in-tile, live page): the row's first tile
-    # occurrence walks its causal pages; duplicates and past-limit pages
-    # skip (their DMAs are elided by the clamped index_map).
-    @pl.when(jnp.logical_not(dup) & (p * page < row_limit))
+    # False only in the one item of a tile's first token that leads
+    # nothing (a pad, or an empty row).
+    @pl.when(token0 < lead_ref[t])
     def _attend():
-        rows_q, hd = q_ref.shape[2], q_ref.shape[3]
-        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row,
-                              tile, rows_q // tile)         # [TILE·G, 1]
+        rows_q = q_ref.shape[2]
+        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0,
+                              row_ids_ref[t], tile, rows_q // tile)
+        k, v, *scales = W.load_blocks(pages)
+        ks, vs = scales or (None, None)
+        # The tile's whole query block rides ONE batched dot per block.
+        W.gqa_attend(q_ref[0], k, v, ks, vs, token0, limits,
+                     m_ref, l_ref, acc_ref)
 
-        # The tile's whole query block rides ONE batched dot per page.
-        qm = q_ref[0].astype(jnp.float32)                   # [KV,TILE·G,hd]
-        k = k_ref[0].astype(jnp.float32)                    # [page, KV, hd]
-        v = v_ref[0].astype(jnp.float32)
-
-        k_t = jnp.transpose(k, (1, 0, 2))                   # [KV, page, hd]
-        v_t = jnp.transpose(v, (1, 0, 2))
-        scores = jax.lax.dot_general(
-            qm, k_t,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * (1.0 / (hd ** 0.5))                             # [KV,TILE·G,page]
-        if quantized:
-            ks_t = jnp.transpose(ks_ref[0], (1, 0))         # [KV, page]
-            scores = scores * ks_t[:, None, :]
-
-        token_idx = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (rows_q, page), dimension=1)
-        mask = token_idx < limits                           # [TILE·G, page]
-        scores = jnp.where(mask[None], scores, _NEG_INF)
-
-        m_prev = m_ref[:]                                   # [KV, TILE·G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)                     # fully-masked
-        # tokens: m_new == m_prev → alpha 1, probs 0 → their state is a
-        # no-op this step (no special casing).
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        pmat = probs
-        if quantized:
-            vs_t = jnp.transpose(vs_ref[0], (1, 0))         # [KV, page]
-            pmat = probs * vs_t[:, None, :]
-        pv = jax.lax.dot_general(
-            pmat, v_t,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                                   # [KV, TILE·G, hd]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-
-    @pl.when((r_off == num_r - 1) & (p == num_p - 1))
+    @pl.when(w + 1 == starts_ref[t0 + tile])
     def _finalize():
-        denom = jnp.maximum(l_ref[:], 1e-30)                # guard pad rows
-        out_ref[0] = (acc_ref[:] / denom).astype(out_ref.dtype)
-
-
-def _kv_page_index(i, r, p, table, lens, rows, *, tile, page):
-    """Block index for the k/v (and scale) specs at inner step (r, p).
-
-    RUN-leaders (first token of a consecutive same-row run — a superset
-    of the kernel's first-occurrence leaders, so every active step gets
-    its real page) stream page ``min(p, last-live-page)``; followers and
-    past-limit steps repeat the PREVIOUS step's index, which makes the
-    Pallas pipeline elide their copies entirely. A same-row run's last
-    leader step and all its follower steps resolve to the same
-    ``table[row, last]``, so the chain of equal indices is unbroken."""
-    t = i * tile + r
-    row = rows[t]
-    prev_row = rows[jnp.maximum(t - 1, 0)]
-    lead = (r == 0) | (prev_row != row)
-    last = jnp.maximum((lens[row] - 1) // page, 0)
-    return jnp.where(lead, jnp.minimum(p, last), last), row
+        out_ref[0] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
 def _fold_tile(qg):
@@ -253,36 +213,18 @@ def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
     hd]; scales (int8 pools) [NP, page, KV] f32 or None. Returns q's
     shape."""
     NT, KV, rows_q, hd = q.shape
-    _, page, _, _ = k_pages.shape
-    P = page_table.shape[1]
-    tile = Q_TILE
-
-    def pick4(i, r, p, table, lens, rows, qpos):
-        pidx, row = _kv_page_index(i, r, p, table, lens, rows,
-                                   tile=tile, page=page)
-        return (table[row, pidx], 0, 0, 0)
-
-    def pick3(i, r, p, table, lens, rows, qpos):
-        return pick4(i, r, p, table, lens, rows, qpos)[:3]
-
-    fixed = lambda i, r, p, table, lens, rows, qpos: (i, 0, 0, 0)
-    in_specs = [
-        pl.BlockSpec((1, KV, rows_q, hd), fixed),
-        pl.BlockSpec((1, page, KV, hd), pick4),
-        pl.BlockSpec((1, page, KV, hd), pick4),
-    ]
-    args = (page_table, kv_lens, row_ids, q_pos, q, k_pages, v_pages)
-    kernel = _block_ragged_kernel
+    page = k_pages.shape[1]
+    lead, starts = _tile_segments(row_ids, q_pos, kv_lens, page)
+    pools = (k_pages, v_pages)
     if k_scales is not None:
-        kernel = _block_ragged_kernel_q
-        in_specs += [pl.BlockSpec((1, page, KV), pick3),
-                     pl.BlockSpec((1, page, KV), pick3)]
-        args += (k_scales, v_scales)
+        pools += (k_scales, v_scales)
+    page_specs, page_operands = W.block_specs(
+        pools, functools.partial(_page_id, page=page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(NT, tile, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, KV, rows_q, hd), fixed),
+        num_scalar_prefetch=6,
+        grid=(starts[NT * Q_TILE],),
+        in_specs=[pl.BlockSpec((1, KV, rows_q, hd), _tile_of(4))] + page_specs,
+        out_specs=pl.BlockSpec((1, KV, rows_q, hd), _tile_of(4)),
         scratch_shapes=[
             pltpu.VMEM((KV, rows_q, 1), jnp.float32),
             pltpu.VMEM((KV, rows_q, 1), jnp.float32),
@@ -290,14 +232,13 @@ def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
         ],
     )
     return pl.pallas_call(
-        functools.partial(kernel, tile=tile),
+        functools.partial(_block_ragged_kernel, tile=Q_TILE),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*args)
+    )(page_table, kv_lens, row_ids, q_pos, lead, starts, q, *page_operands)
 
 
 def _pad_pack(qg, rows, qpos):
@@ -344,25 +285,6 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_table,
 # ---- int8 (quantized pool) variant ------------------------------------------
 
 
-def _block_ragged_kernel_q(
-    # scalar prefetch
-    page_table_ref, kv_lens_ref, row_ids_ref, q_pos_ref,
-    # blocks
-    q_ref, k_ref, v_ref,
-    ks_ref,           # [1, page, KV] f32 scales
-    vs_ref,
-    out_ref,
-    # scratch
-    m_ref, l_ref, acc_ref,
-    *,
-    tile: int,
-):
-    _block_ragged_kernel(page_table_ref, kv_lens_ref, row_ids_ref,
-                         q_pos_ref, q_ref, k_ref, v_ref, out_ref,
-                         m_ref, l_ref, acc_ref, tile=tile,
-                         ks_ref=ks_ref, vs_ref=vs_ref)
-
-
 def ragged_paged_attention_pallas_q(q, k_pages, v_pages, page_table,
                                     q_positions, kv_lens, row_ids,
                                     k_scales, v_scales,
@@ -387,103 +309,42 @@ def ragged_paged_attention_pallas_q(q, k_pages, v_pages, page_table,
 
 def _block_ragged_mla_kernel(
     # scalar prefetch
-    page_table_ref, kv_lens_ref, row_ids_ref, q_pos_ref,
+    page_table_ref, kv_lens_ref, row_ids_ref, q_pos_ref, lead_ref,
+    starts_ref,
     # blocks — the tile's tokens are folded into the query-row axis
     ql_ref,           # [TILE·H, dc]
     qp_ref,           # [TILE·H, dr]
-    c_ref,            # [1, page, 1, dc]
-    pe_ref,           # [1, page, 1, dr]
-    out_ref,          # [TILE·H, dc]
-    # scratch
-    m_ref,            # [TILE·H, 1]
-    l_ref,            # [TILE·H, 1]
-    acc_ref,          # [TILE·H, dc]
-    *,
+    *refs,            # the item's pages: n c refs [1, page, 1, dc] and n
+                      # pe refs [1, page, 1, dr] (int8 pools: then n + n
+                      # scale refs [1, page, 1] f32); out_ref [TILE·H, dc];
+                      # scratch: m, l [TILE·H, 1], acc [TILE·H, dc]
     scale: float,
     tile: int,
-    cs_ref=None,      # int8 pools: [1, page, 1] f32 scales
-    ps_ref=None,
 ):
-    i = pl.program_id(0)
-    r_off = pl.program_id(1)
-    p = pl.program_id(2)
-    num_r = pl.num_programs(1)
-    num_p = pl.num_programs(2)
-    page = c_ref.shape[1]
-    quantized = cs_ref is not None
+    *pages, out_ref, m_ref, l_ref, acc_ref = refs
+    w = pl.program_id(0)
+    t, block = W.find_item(starts_ref, w, row_ids_ref.shape[0])
+    t0 = t // tile * tile
+    page = pages[0].shape[1]
+    token0 = block * (W.pages_per_block(page) * page)   # the block's first slot
 
-    @pl.when((r_off == 0) & (p == 0))
+    @pl.when((t == t0) & (block == 0))
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        W.init_softmax(m_ref, l_ref, acc_ref)
 
-    t0 = i * tile
-    row = row_ids_ref[t0 + r_off]
-    dup, row_limit = _tile_leadership(row_ids_ref, kv_lens_ref, q_pos_ref,
-                                      t0, r_off, row, tile)
-
-    @pl.when(jnp.logical_not(dup) & (p * page < row_limit))
+    @pl.when(token0 < lead_ref[t])
     def _attend():
         rows_q = ql_ref.shape[0]
-        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row,
-                              tile, rows_q // tile)         # [TILE·H, 1]
+        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0,
+                              row_ids_ref[t], tile, rows_q // tile)
+        c, pe, *scales = (blk[:, 0] for blk in W.load_blocks(pages))
+        cs, ps = scales or (None, None)
+        W.mla_attend(ql_ref[...], qp_ref[...], c, pe, cs, ps, token0,
+                     limits, scale, m_ref, l_ref, acc_ref)
 
-        ql = ql_ref[...].astype(jnp.float32)                # [TILE·H, dc]
-        qp = qp_ref[...].astype(jnp.float32)                # [TILE·H, dr]
-        c = c_ref[0, :, 0, :].astype(jnp.float32)           # [page, dc]
-        pe = pe_ref[0, :, 0, :].astype(jnp.float32)         # [page, dr]
-
-        s_c = jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        s_pe = jax.lax.dot_general(qp, pe, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        if quantized:
-            s_c = s_c * cs_ref[0, :, 0][None, :]
-            s_pe = s_pe * ps_ref[0, :, 0][None, :]
-        scores = (s_c + s_pe) * scale                       # [TILE·H, page]
-
-        token_idx = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (rows_q, page), dimension=1)
-        scores = jnp.where(token_idx < limits, scores, _NEG_INF)
-
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        pmat = probs
-        if quantized:
-            # Values are the latents: the c scale folds into probs BEFORE
-            # the value dot, same algebra as the GQA v-scale fold.
-            pmat = probs * cs_ref[0, :, 0][None, :]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pmat, c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [TILE·H, dc]
-
-    @pl.when((r_off == num_r - 1) & (p == num_p - 1))
+    @pl.when(w + 1 == starts_ref[t0 + tile])
     def _finalize():
-        denom = jnp.maximum(l_ref[:], 1e-30)
-        out_ref[...] = (acc_ref[:] / denom).astype(out_ref.dtype)
-
-
-def _block_ragged_mla_kernel_q(
-    page_table_ref, kv_lens_ref, row_ids_ref, q_pos_ref,
-    ql_ref, qp_ref, c_ref, pe_ref,
-    cs_ref,           # [1, page, 1] f32 scales
-    ps_ref,
-    out_ref,
-    m_ref, l_ref, acc_ref,
-    *,
-    scale: float,
-    tile: int,
-):
-    _block_ragged_mla_kernel(page_table_ref, kv_lens_ref, row_ids_ref,
-                             q_pos_ref, ql_ref, qp_ref, c_ref, pe_ref,
-                             out_ref, m_ref, l_ref, acc_ref,
-                             scale=scale, tile=tile,
-                             cs_ref=cs_ref, ps_ref=ps_ref)
+        out_ref[...] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -497,37 +358,19 @@ def _block_ragged_mla_call(ql, qp, c_pages, pe_pages, c_scales, pe_scales,
     dr = qp.shape[1]
     Tp = row_ids.shape[0]
     rows_q = ql.shape[0] // Tp * Q_TILE                     # TILE·H
-    _, page, _, _ = c_pages.shape
-    P = page_table.shape[1]
-    tile = Q_TILE
-
-    def pick4(i, r, p, table, lens, rows, qpos):
-        pidx, row = _kv_page_index(i, r, p, table, lens, rows,
-                                   tile=tile, page=page)
-        return (table[row, pidx], 0, 0, 0)
-
-    def pick3(i, r, p, table, lens, rows, qpos):
-        return pick4(i, r, p, table, lens, rows, qpos)[:3]
-
-    fixed = lambda i, r, p, table, lens, rows, qpos: (i, 0)
-    in_specs = [
-        pl.BlockSpec((rows_q, dc), fixed),
-        pl.BlockSpec((rows_q, dr), fixed),
-        pl.BlockSpec((1, page, 1, dc), pick4),
-        pl.BlockSpec((1, page, 1, dr), pick4),
-    ]
-    args = (page_table, kv_lens, row_ids, q_pos, ql, qp, c_pages, pe_pages)
-    kernel = _block_ragged_mla_kernel
+    page = c_pages.shape[1]
+    lead, starts = _tile_segments(row_ids, q_pos, kv_lens, page)
+    pools = (c_pages, pe_pages)
     if c_scales is not None:
-        kernel = _block_ragged_mla_kernel_q
-        in_specs += [pl.BlockSpec((1, page, 1), pick3),
-                     pl.BlockSpec((1, page, 1), pick3)]
-        args += (c_scales, pe_scales)
+        pools += (c_scales, pe_scales)
+    page_specs, page_operands = W.block_specs(
+        pools, functools.partial(_page_id, page=page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(Tp // tile, tile, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows_q, dc), fixed),
+        num_scalar_prefetch=6,
+        grid=(starts[Tp],),
+        in_specs=[pl.BlockSpec((rows_q, dc), _tile_of(2)),
+                  pl.BlockSpec((rows_q, dr), _tile_of(2))] + page_specs,
+        out_specs=pl.BlockSpec((rows_q, dc), _tile_of(2)),
         scratch_shapes=[
             pltpu.VMEM((rows_q, 1), jnp.float32),
             pltpu.VMEM((rows_q, 1), jnp.float32),
@@ -535,14 +378,14 @@ def _block_ragged_mla_call(ql, qp, c_pages, pe_pages, c_scales, pe_scales,
         ],
     )
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale, tile=tile),
+        functools.partial(_block_ragged_mla_kernel, scale=scale, tile=Q_TILE),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(ql.shape, ql.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*args)
+    )(page_table, kv_lens, row_ids, q_pos, lead, starts, ql, qp,
+      *page_operands)
 
 
 def _block_ragged_mla(q_lat, q_pe, c_pages, pe_pages, c_scales, pe_scales,

@@ -242,3 +242,157 @@ def test_mla_ragged_dispatcher_never_matches_xla():
     got = ragged_paged_mla_attention(ql, qp, c, pe, table, q_pos, lens,
                                      rows, scale, use_pallas="never")
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+
+
+# ---- the page walk is over live pages only (PR 25) ----
+
+_WALK_P, _WALK_NP = 4, 64
+# Pages of 4 slots: a leader's walk is one work item (a block holds 16
+# such pages, more than the narrow table). Pages of 64: a block is one
+# page, so the walks below take one to four items.
+_WALK_PAGES = [4, 64]
+
+
+def _walk_specs(page):
+    """(q_len, kv_len): a chunk straddling tiles on a row that fills every
+    page of the narrow table, a one-token row, a row of exactly two pages,
+    one slot more; the pack then runs on into an all-pad tile."""
+    return [(Q_TILE + 2, _WALK_P * page), (1, 1), (2, 2 * page),
+            (1, 2 * page + 1)]
+
+
+_RAGGED_KERNELS = ["gqa", "gqa_q", "mla", "mla_q"]
+
+
+def _widen(table, width, poison):
+    R, P = table.shape
+    return jnp.concatenate(
+        [table, jnp.full((R, width - P), poison, jnp.int32)], axis=1)
+
+
+def _pad_to_tiles(n_tiles, q_pos, row_ids, *qs):
+    """Pad a pack to ``n_tiles`` whole tiles with the pack's own pad
+    contract (row 0, position −1, arbitrary queries)."""
+    T = row_ids.shape[0]
+    pad = n_tiles * Q_TILE - T
+    rng = np.random.RandomState(99)
+    qs = [jnp.concatenate([q, jnp.asarray(
+        rng.randn(1, pad, *q.shape[2:]) * 0.1, jnp.float32)], axis=1)
+        for q in qs]
+    return (jnp.concatenate([q_pos, jnp.full((1, pad), -1, jnp.int32)],
+                            axis=1),
+            jnp.concatenate([row_ids, jnp.zeros(pad, jnp.int32)]), *qs)
+
+
+def _ragged_case(kernel, page):
+    """(call(table) -> real tokens' output, narrow table, XLA reference)
+    for one of the four block-ragged kernels; the pool's last page is all
+    NaN (int8 pools: its scales)."""
+    rng = np.random.RandomState(30)
+    specs = _walk_specs(page)
+    T = sum(ql for ql, _ in specs)
+    n_tiles = -(-T // Q_TILE) + 1                       # one all-pad tile
+    nan_last = lambda a: a.at[_WALK_NP - 1].set(jnp.nan)
+    if kernel.startswith("mla"):
+        ql, qp, c, pe, table, q_pos, lens, rows, scale = _mla_pack(
+            rng, specs, page=page, NP=_WALK_NP, P=_WALK_P)
+        table = jnp.minimum(table, _WALK_NP - 2)
+        pos_p, rows_p, ql_p, qp_p = _pad_to_tiles(n_tiles, q_pos, rows,
+                                                  ql, qp)
+        if kernel == "mla_q":
+            cq, cs = quantize_kv(c)
+            peq, pes = quantize_kv(pe)
+            cs, pes = nan_last(cs), nan_last(pes)
+            ref = ragged_paged_mla_attention_xla(
+                ql, qp, cq, peq, table, q_pos, lens, rows, scale,
+                c_scales=cs, pe_scales=pes)
+            return (lambda t: ragged_paged_mla_attention_pallas_q(
+                ql_p, qp_p, cq, peq, t, pos_p, lens, rows_p, scale, cs, pes,
+                interpret=True)[:, :T]), table, ref
+        c, pe = nan_last(c), nan_last(pe)
+        ref = ragged_paged_mla_attention_xla(ql, qp, c, pe, table, q_pos,
+                                             lens, rows, scale)
+        return (lambda t: ragged_paged_mla_attention_pallas(
+            ql_p, qp_p, c, pe, t, pos_p, lens, rows_p, scale,
+            interpret=True)[:, :T]), table, ref
+    k, v = _pool(rng, NP=_WALK_NP, page=page)
+    q, table, q_pos, lens, rows = _pack(rng, specs, P=_WALK_P, NP=_WALK_NP)
+    table = jnp.minimum(table, _WALK_NP - 2)
+    pos_p, rows_p, q_p = _pad_to_tiles(n_tiles, q_pos, rows, q)
+    if kernel == "gqa_q":
+        kq, ks = quantize_kv(k)
+        vq, vs = quantize_kv(v)
+        ks, vs = nan_last(ks), nan_last(vs)
+        ref = ragged_paged_attention_xla(q, kq, vq, table, q_pos, lens, rows,
+                                         k_scales=ks, v_scales=vs)
+        return (lambda t: ragged_paged_attention_pallas_q(
+            q_p, kq, vq, t, pos_p, lens, rows_p, ks, vs,
+            interpret=True)[:, :T]), table, ref
+    k, v = nan_last(k), nan_last(v)
+    ref = ragged_paged_attention_xla(q, k, v, table, q_pos, lens, rows)
+    return (lambda t: ragged_paged_attention_pallas(
+        q_p, k, v, t, pos_p, lens, rows_p, interpret=True)[:, :T]), table, ref
+
+
+@pytest.mark.parametrize("page", _WALK_PAGES)
+@pytest.mark.parametrize("kernel", _RAGGED_KERNELS)
+def test_ragged_output_is_the_same_under_a_wide_table(kernel, page):
+    """The same live rows under a table of width 4 and of width 512 whose
+    dead entries name a page of NaNs: the same finite output, equal to
+    the XLA reference — with a row that fills the narrow table, a row of
+    one token, one of exactly two pages, and an all-pad tile."""
+    call, table, ref = _ragged_case(kernel, page)
+    narrow = np.asarray(call(table))
+    wide = np.asarray(call(_widen(table, 512, poison=_WALK_NP - 1)))
+    assert np.isfinite(wide).all()
+    np.testing.assert_array_equal(narrow, wide)
+    np.testing.assert_allclose(narrow, np.asarray(ref), rtol=1e-4, atol=1e-4)
+
+
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` in ``fn``'s jaxpr."""
+    import jax
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("kernel", _RAGGED_KERNELS)
+def test_ragged_grid_does_not_hold_the_table_width(kernel):
+    call, table, _ = _ragged_case(kernel, 4)
+    g24, g96 = (_pallas_grids(call, _widen(table, w, poison=0))
+                for w in (24, 96))
+    assert len(g24) == 1 and g24 == g96
+    assert 24 not in g24[0] and 96 not in g96[0]
+
+
+def test_tile_segments_count_live_blocks_only():
+    """The work list behind the grid: a leader per distinct row of a
+    tile walks that row's slots up to the tile's largest causal limit, a
+    block of pages an item; followers and pads have no items, except that
+    each tile's first token keeps one."""
+    from rbg_tpu.ops.pallas.page_walk import pages_per_block
+    from rbg_tpu.ops.pallas.ragged_attention_kernel import _tile_segments
+    page = 16
+    block = pages_per_block(page) * page
+    # tile 0: row 0 x3 (the last three positions of 300), row 1 x1 (kv
+    # 128), row 0 again (a second run: still a follower), pads; tile 1:
+    # all pads.
+    row_ids = jnp.asarray([0, 0, 0, 1, 0, 0, 0, 0] + [0] * 8, jnp.int32)
+    q_pos = jnp.asarray([297, 298, 299, 127, 3, -1, -1, -1] + [-1] * 8,
+                        jnp.int32)
+    kv_lens = jnp.asarray([300, 128], jnp.int32)
+    lead, starts = _tile_segments(row_ids, q_pos, kv_lens, page)
+    assert lead.tolist() == [300, 0, 0, 128] + [0] * 12
+    # the two leaders' blocks; the pad tile's first token: 1 item.
+    assert np.diff(np.asarray(starts)).tolist() == (
+        [-(-300 // block), 0, 0, -(-128 // block), 0, 0, 0, 0]
+        + [1] + [0] * 7)

@@ -27,6 +27,9 @@ BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
 # The shapes of one mixed serving step: 8 rows over a 4096-page pool of
 # 16-token pages, up to 128 pages a row, 256 packed tokens.
 NP, PAGE, R, P, T = 4096, 16, 8, 128, 256
+# The benchmark's mixtral.longgen cell: max_seq_len 8192 makes the table
+# 512 wide over a pool of 8192 pages; its heads are llama3-8b's.
+CELL_NP, CELL_P = 8192, 512
 
 # heads / kv heads / head dim as published.
 GQA_WIDTHS = {"llama3-1b": (32, 8, 64), "llama3-8b": (32, 8, 128),
@@ -69,29 +72,43 @@ def _compiles_with_kernel(fn, *args):
     return compiled
 
 
-def _gqa_args(chip, width, quantized, ragged):
+def _gqa_args(chip, width, quantized, ragged, num_pages=NP, table_width=P):
     H, KV, hd = GQA_WIDTHS[width]
     S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
-    pages = S((NP, PAGE, KV, hd), I8 if quantized else BF16)
+    pages = S((num_pages, PAGE, KV, hd), I8 if quantized else BF16)
+    table = S((R, table_width), I32)
     if ragged:
-        args = [S((1, T, H, hd), BF16), pages, pages, S((R, P), I32),
+        args = [S((1, T, H, hd), BF16), pages, pages, table,
                 S((1, T), I32), S((R,), I32), S((T,), I32)]
     else:
-        args = [S((R, 1, H, hd), BF16), pages, pages, S((R, P), I32),
+        args = [S((R, 1, H, hd), BF16), pages, pages, table,
                 S((R, 1), I32), S((R,), I32)]
     if quantized:
-        args += [S((NP, PAGE, KV, 1), F32)] * 2
+        args += [S((num_pages, PAGE, KV, 1), F32)] * 2
     return args
 
 
-@pytest.mark.parametrize("width", sorted(GQA_WIDTHS))
-@pytest.mark.parametrize("kernel", [
+GQA_KERNELS = [
     "paged_attention_pallas", "paged_attention_pallas_q",
     "ragged_paged_attention_pallas", "ragged_paged_attention_pallas_q",
-])
+]
+
+
+@pytest.mark.parametrize("width", sorted(GQA_WIDTHS))
+@pytest.mark.parametrize("kernel", GQA_KERNELS)
 def test_gqa_kernel_compiles_for_v5e(chip, kernel, width):
     args = _gqa_args(chip, width, quantized=kernel.endswith("_q"),
                      ragged=kernel.startswith("ragged"))
+    _compiles_with_kernel(getattr(K, kernel), *args)
+
+
+@pytest.mark.parametrize("kernel", GQA_KERNELS)
+def test_gqa_kernel_compiles_at_the_cells_table_width(chip, kernel):
+    """The table's width reaches a kernel only as the shape of a
+    scalar-prefetched array; Mosaic must take it at the benchmark's own."""
+    args = _gqa_args(chip, "llama3-8b", quantized=kernel.endswith("_q"),
+                     ragged=kernel.startswith("ragged"),
+                     num_pages=CELL_NP, table_width=CELL_P)
     _compiles_with_kernel(getattr(K, kernel), *args)
 
 

@@ -247,3 +247,130 @@ def test_int8_dispatch_routes_to_quantized_kernel(monkeypatch):
     ref = paged_attention_xla(q, kq, vq, table, q_pos, lens, ks, vs)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---- the page walk is over live pages only (PR 25) ----
+#
+# The table is as wide as max_seq_len allows; a row's live pages are a
+# small prefix of its line. The kernels' grids used to hold the table's
+# width; now a call must neither read past the live pages nor grow with
+# the width.
+
+from rbg_tpu.ops.pallas.paged_attention_kernel import (
+    paged_mla_attention_pallas_q)
+
+_WALK_P = 4
+# Pages of 4 slots: a row is one work item (a block holds 16 such pages,
+# more than the narrow table). Pages of 64: a block is one page, so the
+# rows below take one to four items.
+_WALK_PAGES = [4, 64]
+
+
+def _walk_lens(page):
+    """One token, exactly k pages, one slot more, every page of the
+    narrow table."""
+    return [1, 2 * page, 2 * page + 1, _WALK_P * page]
+
+
+def _widen(table, width, poison):
+    """The same live pages under a table ``width`` wide whose other
+    entries all name the ``poison`` page."""
+    B, P = table.shape
+    return jnp.concatenate(
+        [table, jnp.full((B, width - P), poison, jnp.int32)], axis=1)
+
+
+def _decode_case(kernel, page):
+    """(call(table) -> output, narrow table, XLA reference) for one of
+    the four decode kernels, with the pool's last page all NaN (int8
+    pools: its scales)."""
+    lens = jnp.asarray(_walk_lens(page), jnp.int32)
+    B, NP = lens.shape[0], 32
+    q_pos = (lens - 1)[:, None]
+    nan_last = lambda a: a.at[NP - 1].set(jnp.nan)
+    if kernel.startswith("mla"):
+        ql, qp, c, pe, table, _, _, scale = _mla_setup(
+            B=B, H=4, dc=128, dr=32, page=page, NP=NP, P=_WALK_P,
+            seed=21)
+        table = jnp.minimum(table, NP - 2)
+        if kernel == "mla_q":
+            cq, cs = quantize_kv(c)
+            peq, pes = quantize_kv(pe)
+            cs, pes = nan_last(cs), nan_last(pes)
+            ref = paged_mla_attention_xla(ql, qp, cq, peq, table, q_pos, lens,
+                                          scale, c_scales=cs, pe_scales=pes)
+            return (lambda t: paged_mla_attention_pallas_q(
+                ql, qp, cq, peq, t, q_pos, lens, scale, cs, pes,
+                interpret=True)), table, ref
+        c, pe = nan_last(c), nan_last(pe)
+        ref = paged_mla_attention_xla(ql, qp, c, pe, table, q_pos, lens,
+                                      scale)
+        return (lambda t: paged_mla_attention_pallas(
+            ql, qp, c, pe, t, q_pos, lens, scale, interpret=True)), table, ref
+    q, k, v, table, _, _ = _setup(B=B, H=4, KV=2, hd=16, page=page,
+                                  NP=NP, P=_WALK_P, seed=20)
+    table = jnp.minimum(table, NP - 2)
+    if kernel == "gqa_q":
+        kq, vq, ks, vs = _quantize_pages(k, v)
+        ks, vs = nan_last(ks), nan_last(vs)
+        ref = paged_attention_xla(q, kq, vq, table, q_pos, lens, ks, vs)
+        return (lambda t: paged_attention_pallas_q(
+            q, kq, vq, t, q_pos, lens, ks, vs, interpret=True)), table, ref
+    k, v = nan_last(k), nan_last(v)
+    ref = paged_attention_xla(q, k, v, table, q_pos, lens)
+    return (lambda t: paged_attention_pallas(
+        q, k, v, t, q_pos, lens, interpret=True)), table, ref
+
+
+_DECODE_KERNELS = ["gqa", "gqa_q", "mla", "mla_q"]
+
+
+@pytest.mark.parametrize("page", _WALK_PAGES)
+@pytest.mark.parametrize("kernel", _DECODE_KERNELS)
+def test_decode_output_is_the_same_under_a_wide_table(kernel, page):
+    """Lengths of 1, of exactly k pages, and one that fills the narrow
+    table: a table of width 4 and one of width 512, whose dead entries
+    name a page of NaNs, give the same finite output."""
+    call, table, ref = _decode_case(kernel, page)
+    narrow = np.asarray(call(table))
+    wide = np.asarray(call(_widen(table, 512, poison=31)))
+    assert np.isfinite(wide).all()
+    np.testing.assert_array_equal(narrow, wide)
+    np.testing.assert_allclose(narrow, np.asarray(ref), rtol=1e-4, atol=1e-4)
+
+
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` in ``fn``'s jaxpr."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("kernel", _DECODE_KERNELS)
+def test_decode_grid_does_not_hold_the_table_width(kernel):
+    call, table, _ = _decode_case(kernel, 4)
+    g24, g96 = (_pallas_grids(call, _widen(table, w, poison=0))
+                for w in (24, 96))
+    assert len(g24) == 1 and g24 == g96
+    assert 24 not in g24[0] and 96 not in g96[0]
+
+
+def test_decode_empty_row_finalizes_to_zero():
+    """A row of length 0 (a free slot of the batch) keeps one work item
+    that attends nothing; its output is zero, its neighbours' untouched."""
+    q, k, v, table, _, _ = _setup(B=3, seed=22)
+    lens = jnp.asarray([9, 0, 20], jnp.int32)
+    q_pos = jnp.maximum(lens - 1, 0)[:, None]
+    got = np.asarray(paged_attention_pallas(q, k, v, table, q_pos, lens,
+                                            interpret=True))
+    ref = np.asarray(paged_attention_xla(q, k, v, table, q_pos, lens))
+    assert np.all(got[1] == 0)
+    np.testing.assert_allclose(got[[0, 2]], ref[[0, 2]], rtol=1e-5, atol=1e-5)

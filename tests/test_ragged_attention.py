@@ -175,3 +175,51 @@ def test_write_kv_pages_ragged_matches_dense_scatter():
     np.testing.assert_allclose(kp[2, 1], np.asarray(k_new[0, 3]))  # pos 5
     np.testing.assert_allclose(kp[4, 3], np.asarray(k_new[0, 4]))  # row 1
     assert np.all(kp[0] == 0) and np.all(kp[5:] == 0)  # pad dropped
+
+
+# ---- the page walk is over live pages only (PR 25) ----
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("q_specs", [
+    [(1, 1)],                               # one token, one slot
+    [(1, 12), (4, 8)],                      # exactly k pages
+    [(1, 24), (3, 24)],                     # rows that fill all P pages
+    [(1, 9), (1, 21)],                      # a pad tile follows
+], ids=["len1", "k_pages", "full_table", "pad_tile"])
+def test_ragged_pallas_walks_live_pages(q_specs, quantized):
+    """Edge lengths of the live-page walk, each under the narrow table
+    and under one 512 wide whose dead entries name a NaN page; the pack
+    is padded by a whole all-pad tile."""
+    rng = np.random.RandomState(7)
+    page, P, NP = 4, 6, 64
+    k, v = _pool(rng, NP=NP, page=page)
+    q, table, q_pos, kv_lens, row_ids = _pack(rng, q_specs, P=P, NP=NP)
+    table = jnp.minimum(table, NP - 2)
+    T = q.shape[1]
+    pad = 2 * 8 - T                          # Q_TILE 8: tile 1 is all pads
+    q_p = jnp.concatenate([q, jnp.zeros((1, pad) + q.shape[2:])], axis=1)
+    pos_p = jnp.concatenate([q_pos, jnp.full((1, pad), -1, jnp.int32)],
+                            axis=1)
+    rows_p = jnp.concatenate([row_ids, jnp.zeros(pad, jnp.int32)])
+    wide = jnp.concatenate(
+        [table, jnp.full((len(q_specs), 512 - P), NP - 1, jnp.int32)], axis=1)
+    if quantized:
+        k, ks = quantize_kv(k)
+        v, vs = quantize_kv(v)
+        ks, vs = ks.at[NP - 1].set(jnp.nan), vs.at[NP - 1].set(jnp.nan)
+        ref = ragged_paged_attention_xla(q, k, v, table, q_pos, kv_lens,
+                                         row_ids, k_scales=ks, v_scales=vs)
+        call = lambda t: ragged_paged_attention_pallas_q(
+            q_p, k, v, t, pos_p, kv_lens, rows_p, ks, vs, interpret=True)
+    else:
+        k, v = k.at[NP - 1].set(jnp.nan), v.at[NP - 1].set(jnp.nan)
+        ref = ragged_paged_attention_xla(q, k, v, table, q_pos, kv_lens,
+                                         row_ids)
+        call = lambda t: ragged_paged_attention_pallas(
+            q_p, k, v, t, pos_p, kv_lens, rows_p, interpret=True)
+    narrow, got = np.asarray(call(table)), np.asarray(call(wide))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(narrow, got)
+    np.testing.assert_allclose(got[:, :T], np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)

@@ -5,14 +5,23 @@ do for the rows a step really ran: never the grid, never ``max_seq_len``,
 never padding. ``least_seconds`` turns them into the least time the chip
 could take (the larger of operations over peak FLOP/s and bytes over peak
 bytes/s), which a roofline share divides by the traced time.
+
+A kernel's count is a file: every public function of every
+``benchmark/opsbytes/*.py`` is a model, ``(cfg, rows) -> (flops, bytes)``
+with ``cfg`` the configuration file and ``rows`` the ``(q, kv)`` of one
+recorded step, under the function's own name. ``paged_attention`` below
+is the one that was here first.
 """
 
 from __future__ import annotations
 
+import glob
+import importlib.util
 import json
 import os
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
+MODELS_DIR = os.path.join(os.path.dirname(_HERE), "opsbytes")
 
 
 def peak_for(device_kind: str) -> dict:
@@ -43,10 +52,35 @@ def paged_attention(cfg: dict, rows: list) -> tuple:
     return layers * flops, layers * (kv_bytes + qo_bytes)
 
 
-MODELS = {"paged_attention": paged_attention}
+def models(extra_dirs=()) -> dict:
+    """``{name: function}``: ``paged_attention`` and the public functions
+    of the files in ``benchmark/opsbytes`` and in ``extra_dirs`` (the
+    tests' own). A name defined twice is an error."""
+    out = {"paged_attention": paged_attention}
+    where = {"paged_attention": __file__}
+    for d in (MODELS_DIR, *extra_dirs):
+        for path in sorted(glob.glob(os.path.join(d, "*.py"))):
+            stem = os.path.splitext(os.path.basename(path))[0]
+            spec = importlib.util.spec_from_file_location(
+                f"bench_opsbytes_{stem}", path)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            for name, fn in vars(mod).items():
+                if (name.startswith("_") or not callable(fn)
+                        or getattr(fn, "__module__", None) != mod.__name__):
+                    continue
+                if name in out:
+                    raise ValueError(f"opsbytes model {name!r} is defined in "
+                                     f"{where[name]} and in {path}")
+                out[name], where[name] = fn, path
+    return out
 
 
-def least_seconds(model: str, cfg: dict, rows: list, peak: dict) -> float:
-    flops, nbytes = MODELS[model](cfg, rows)
+MODELS = models()
+
+
+def least_seconds(model, cfg: dict, rows: list, peak: dict) -> float:
+    """``model`` is one of the functions of ``models()``."""
+    flops, nbytes = model(cfg, rows)
     return max(flops / peak["bf16_flops_per_s"],
                nbytes / peak["hbm_bytes_per_s"])

@@ -24,6 +24,13 @@ to serve (``serve.py``), so neither side reads anything the other made.
 The weight layout (stacked over layers, ``[in, out]`` matrices) is the one
 ``rbg_tpu.models.llama`` reads; that is the single thing taken from the
 program, and it is a layout, not a value.
+
+This module is the default of a configuration that names no ``reference``
+of its own (``serve.py``, ``README.md``: the contract is ``make_params``,
+``chosen_logprobs`` and ``CONTROLS``). Another architecture's reference is
+a module of its own under ``benchmark/references/`` and may import what
+is general here: ``random_params``, ``_mm``, ``_fake_quant``, ``_rms_norm``,
+``_rope``, ``_attention``, ``_swiglu``, ``_moe``.
 """
 
 from __future__ import annotations
@@ -35,6 +42,9 @@ import jax
 import jax.numpy as jnp
 
 S_IN = 0.02
+
+# The values of ``quant`` that ``chosen_logprobs`` knows: the controls.
+CONTROLS = ("int8", "fp8", "bf16", "kv_int8", "kv_fp8", "kv_bf16")
 
 
 def sizes(cfg: dict) -> dict:
@@ -88,38 +98,49 @@ def _random_leaf(key, shape, scale, dtype):
     return out.reshape(shape)
 
 
-def make_params(cfg: dict, seed: int):
-    """Every weight of the configuration from ``seed``, on the default
-    device, in one jitted program."""
-    dtype = jnp.dtype(cfg.get("torch_dtype", "bfloat16"))
-    z = sizes(cfg)
-    shapes = param_shapes(cfg)
+def random_params(random: dict, ones: dict, dtype, seed: int):
+    """One jitted program that makes every leaf on the default device in
+    ``dtype``: ``random`` is ``{path: (shape, scale)}`` (normal, drawn matrix
+    by matrix), ``ones`` is ``{path: shape}`` (the norms); a path is a tuple
+    of one or two keys into the nested dict that comes back. The key of a
+    leaf is its rank among the random paths (one-key paths first, each
+    group sorted), so a set of paths always draws the same weights."""
+    order = sorted(random, key=lambda path: (len(path), path))
 
     @jax.jit
     def build(key):
-        flat = [("embed",), ("lm_head",)] + [("blocks", n)
-                                            for n in sorted(shapes["blocks"])]
-        keys = jax.random.split(key, len(flat))
-        out = {"blocks": {
-            "attn_norm": jnp.ones((z["L"], z["d"]), dtype),
-            "mlp_norm": jnp.ones((z["L"], z["d"]), dtype)},
-            "final_norm": jnp.ones((z["d"],), dtype)}
-        for k, path in zip(keys, flat):
-            node = shapes
-            for p in path:
-                node = node[p]
-            shape, scale = node
-            leaf = _random_leaf(k, shape, scale, dtype)
-            if len(path) == 1:
-                out[path[0]] = leaf
-            else:
-                out["blocks"][path[1]] = leaf
+        out = {}
+
+        def put(path, leaf):
+            node = out
+            for p in path[:-1]:
+                node = node.setdefault(p, {})
+            node[path[-1]] = leaf
+
+        for path, shape in ones.items():
+            put(path, jnp.ones(shape, dtype))
+        for k, path in zip(jax.random.split(key, len(order)), order):
+            put(path, _random_leaf(k, *random[path], dtype))
         return out
 
     # Key data is 2 x uint32: fold a seed of any size into it.
     seed = int(seed)
     key = jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF), seed >> 31)
     return build(key)
+
+
+def make_params(cfg: dict, seed: int):
+    """Every weight of the configuration from ``seed``, on the default
+    device, in one jitted program."""
+    z = sizes(cfg)
+    shapes = param_shapes(cfg)
+    random = {("embed",): shapes["embed"], ("lm_head",): shapes["lm_head"]}
+    random.update({("blocks", n): v for n, v in shapes["blocks"].items()})
+    ones = {("blocks", "attn_norm"): (z["L"], z["d"]),
+            ("blocks", "mlp_norm"): (z["L"], z["d"]),
+            ("final_norm",): (z["d"],)}
+    return random_params(random, ones,
+                         jnp.dtype(cfg.get("torch_dtype", "bfloat16")), seed)
 
 
 # ---------------------------------------------------------------------------

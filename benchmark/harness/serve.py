@@ -10,9 +10,10 @@ scheduler, cache and kernels. This launcher does three things around it,
 all from outside the program:
 
 * registers the configuration file's sizes as a model preset
-  (``rbg_tpu.models.config._PRESETS``) and hands the engine its weights:
-  ``reference.make_params`` from ``--seed``, one jitted call, instead of the
-  program's leaf-by-leaf eager initialiser;
+  (``rbg_tpu.models.config._PRESETS``: the published keys, then the file's
+  ``preset`` by field name) and hands the engine its weights: the
+  ``make_params`` of the configuration's reference module from ``--seed``,
+  one jitted call, instead of the program's leaf-by-leaf eager initialiser;
 * answers a second, benchmark-only port (``--ctl-port``): the plain
   reference on those same weights (a chip belongs to one process, so the
   reference has to run here), the profiler's start and stop with the
@@ -27,6 +28,8 @@ all from outside the program:
 from __future__ import annotations
 
 import argparse
+import importlib.util
+import inspect
 import json
 import os
 import socketserver
@@ -40,10 +43,10 @@ for p in (ROOT, os.path.dirname(HERE)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from harness import reference  # noqa: E402
+DEFAULT_REFERENCE = "benchmark/harness/reference.py"
 
-STATE = {"cfg": None, "params": None, "steps": [], "recording": False,
-         "tracing": False, "compiles": []}
+STATE = {"cfg": None, "reference": None, "params": None, "steps": [],
+         "recording": False, "tracing": False, "compiles": []}
 
 
 def log_compiles() -> None:
@@ -61,31 +64,118 @@ def log_compiles() -> None:
     monitoring.register_event_duration_secs_listener(on_duration)
 
 
+# ---------------------------------------------------------------------------
+# what a configuration file names: its reference module, its preset
+# ---------------------------------------------------------------------------
+
+
+def reference_path(cfg: dict) -> str:
+    """The configuration's reference module: ``reference`` in its file, a
+    path from the repo's root; the default where the file names none."""
+    path = os.path.join(ROOT, cfg.get("reference", DEFAULT_REFERENCE))
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"the configuration names the reference module "
+            f"{cfg.get('reference', DEFAULT_REFERENCE)!r}: no such file "
+            f"under {ROOT}")
+    return path
+
+
+# name -> the parameters it is called with, in this order
+REFERENCE_CONTRACT = {
+    "make_params": ("cfg", "seed"),
+    "chosen_logprobs": ("cfg", "params", "prompt", "served", "quant"),
+}
+
+
+def load_reference(cfg: dict):
+    """The reference module, held to its contract (``README.md``):
+    ``make_params(cfg, seed)``, ``chosen_logprobs(cfg, params, prompt,
+    served, quant=None)`` and ``CONTROLS``, the values of ``quant`` it
+    knows. Anything missing is an error that names it."""
+    path = reference_path(cfg)
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(f"bench_reference_{stem}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    for name, want in REFERENCE_CONTRACT.items():
+        fn = getattr(mod, name, None)
+        if not callable(fn):
+            raise TypeError(f"{path} defines no function {name!r}")
+        have = tuple(inspect.signature(fn).parameters)
+        if have[:len(want)] != want:
+            raise TypeError(f"{path}: {name}{have} does not start with the "
+                            f"contract's parameters {want}")
+    controls = getattr(mod, "CONTROLS", None)
+    if (not isinstance(controls, (tuple, list)) or not controls
+            or not all(isinstance(c, str) for c in controls)):
+        raise TypeError(f"{path} lists no CONTROLS (the values of `quant` "
+                        f"its chosen_logprobs knows)")
+    return mod
+
+
+# ModelConfig field -> (published key, default where a published config may
+# leave the key out; REQUIRED where it may not)
+REQUIRED = object()
+PUBLISHED = {
+    "vocab_size": ("vocab_size", REQUIRED),
+    "hidden_size": ("hidden_size", REQUIRED),
+    "intermediate_size": ("intermediate_size", REQUIRED),
+    "num_layers": ("num_hidden_layers", REQUIRED),
+    "num_heads": ("num_attention_heads", REQUIRED),
+    "num_kv_heads": ("num_key_value_heads", REQUIRED),
+    "head_dim": ("head_dim", None),
+    "rope_theta": ("rope_theta", REQUIRED),
+    "rms_norm_eps": ("rms_norm_eps", REQUIRED),
+    "max_seq_len": ("max_position_embeddings", REQUIRED),
+    "tie_word_embeddings": ("tie_word_embeddings", False),
+    "dtype": ("torch_dtype", "bfloat16"),
+    "num_experts": ("num_local_experts", 0),
+    "experts_per_token": ("num_experts_per_tok", 2),
+}
+
+
 def model_config(cfg: dict, name: str):
-    """The configuration file's published keys as the program's preset."""
+    """The program's preset of a configuration file: its published keys
+    (``PUBLISHED``), then its ``preset``, ``{ModelConfig field: value}``,
+    on top of them. A ``preset`` name that is no field of the program's
+    ``ModelConfig`` is an error that names it, and so is a published key
+    that the file leaves out and the ``preset`` does not give."""
+    import dataclasses
     from rbg_tpu.models.config import ModelConfig
-    return ModelConfig(
-        name=name, vocab_size=cfg["vocab_size"],
-        hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim"),
-        rope_theta=float(cfg["rope_theta"]),
-        rms_norm_eps=float(cfg["rms_norm_eps"]),
-        max_seq_len=cfg["max_position_embeddings"],
-        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        dtype=cfg.get("torch_dtype", "bfloat16"),
-        num_experts=cfg.get("num_local_experts", 0),
-        experts_per_token=cfg.get("num_experts_per_tok", 2))
+    known = {f.name for f in dataclasses.fields(ModelConfig)}
+    preset = cfg.get("preset", {})
+    unknown = sorted(set(preset) - known)
+    if unknown:
+        raise ValueError(
+            f"preset names {unknown}: no field of rbg_tpu.models.config."
+            f"ModelConfig, which has {sorted(known)}")
+    fields = {"name": name}
+    for field, (key, default) in PUBLISHED.items():
+        if key in cfg:
+            fields[field] = cfg[key]
+        elif default is not REQUIRED:
+            fields[field] = default
+        elif field not in preset:
+            raise KeyError(f"the configuration has no {key!r} and its preset "
+                           f"no {field!r}")
+    fields.update(preset)
+    for field in ("rope_theta", "rms_norm_eps"):
+        fields[field] = float(fields[field])
+    fields["tie_word_embeddings"] = bool(fields["tie_word_embeddings"])
+    return ModelConfig(**fields)
 
 
 def install(cfg: dict, name: str, seed: int) -> None:
-    """Preset and weights, before the server builds its engine."""
+    """Preset and weights, before the server builds its engine. Replacing
+    ``rbg_tpu.engine.engine.init_params`` is the one seam by which the
+    benchmark's weights reach the engine: whatever family's model code the
+    engine imports, it has to ask that name for them."""
     from rbg_tpu.engine import engine as engine_mod
     from rbg_tpu.models import config as model_presets
     model_presets._PRESETS[name] = model_config(cfg, name)
+    reference = STATE["reference"] = load_reference(cfg)
 
     def benchmark_params(_mcfg, _key):
         import jax
@@ -188,9 +278,9 @@ def _ctl(obj: dict) -> dict:
     if op == "reference":
         if STATE["params"] is None:
             return {"error": "no weights yet"}
-        lp = reference.chosen_logprobs(STATE["cfg"], STATE["params"],
-                                       obj["prompt"], obj["served"],
-                                       obj.get("quant"))
+        lp = STATE["reference"].chosen_logprobs(
+            STATE["cfg"], STATE["params"], obj["prompt"], obj["served"],
+            obj.get("quant"))
         return {"logprobs": [float(x) for x in np.asarray(lp)]}
     if op == "trace_start":
         opts = jax.profiler.ProfileOptions()

@@ -4,17 +4,21 @@
 
 prints one JSON object. Each ``<trace_dir>`` is what
 ``jax.profiler.start_trace`` was given by one server process; its
-``.xplane.pb`` is read with ``jax.profiler.ProfileData`` (nothing but JAX),
-in a process that holds no chip (run it with ``JAX_PLATFORMS=cpu``).
+``.xplane.pb`` is read by ``harness/xplane.py`` (``google.protobuf``, no
+JAX), in a process that holds no chip.
 
-The reduction itself (``reduce_events``) works on plain tuples, so that a
-hand-built event list checks it:
+The reduction itself (``reduce_events``) works on plain tuples
+``(start_ns, end_ns, name[, scope])``, so that a hand-built event list
+checks it:
 
 * busy       - the union of the intervals in which an operation ran on the
                device; idle is the traced window minus that;
 * ops_total  - seconds per operation name, every event whole;
 * ops_self   - the same less the time of the operations nested inside
                (a ``while`` holds its body), so the column sums to busy;
+* scopes_self - that self time by the ``jax.named_scope`` path the
+               profiler recorded for the operation (``""`` where it
+               recorded none), so this column sums to busy too;
 * gaps       - the longest idle gaps, each named by the innermost host
                events (Python frames, ``bench.*`` annotations) that cover
                its middle.
@@ -27,6 +31,10 @@ import json
 import os
 import re
 import sys
+
+_BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _BENCH not in sys.path:          # run as a script: ``harness`` is a sibling
+    sys.path.insert(0, _BENCH)
 
 DEVICE_PREFIX = "/device:TPU"
 OPS_LINE = "XLA Ops"
@@ -46,22 +54,23 @@ def merge(intervals: list) -> list:
     return out
 
 
-def self_times(events: list) -> dict:
-    """Seconds per name, nested events' time taken out of their parents.
-    ``events`` are ``(start_ns, end_ns, name)`` of one line (they nest)."""
+def self_times(events: list, key: int = 2) -> dict:
+    """Seconds per ``event[key]`` (2 the name, 3 the scope), nested
+    events' time taken out of their parents. ``events`` are ``(start_ns,
+    end_ns, name[, scope])`` of one line (they nest)."""
     out = {}
-    stack = []                                # [end, name, child_ns]
+    stack = []                                # [end, label, child_ns, start]
 
     def close(upto):
         while stack and stack[-1][0] <= upto:
-            end, name, child, start = stack.pop()
-            out[name] = out.get(name, 0.0) + (end - start - child) / 1e9
+            end, label, child, start = stack.pop()
+            out[label] = out.get(label, 0.0) + (end - start - child) / 1e9
             if stack:
                 stack[-1][2] += end - start
 
-    for s, e, name in sorted(events, key=lambda x: (x[0], -x[1])):
-        close(s)
-        stack.append([e, name, 0, s])
+    for ev in sorted(events, key=lambda x: (x[0], -x[1])):
+        close(ev[0])
+        stack.append([ev[1], ev[key] if key < len(ev) else "", 0, ev[0]])
     close(float("inf"))
     return out
 
@@ -91,10 +100,11 @@ def name_gaps(gaps: list, host_events: list) -> list:
 
 
 def reduce_events(device_events: list, host_events: list) -> dict:
-    """One device's reduction. Both lists hold ``(start_ns, end_ns, name)``."""
-    busy = merge([(s, e) for s, e, _ in device_events])
+    """One device's reduction. Both lists hold ``(start_ns, end_ns,
+    name)``; a device event may carry its scope as a fourth entry."""
+    busy = merge([ev[:2] for ev in device_events])
     totals = {}
-    for s, e, name in device_events:
+    for s, e, name, *_ in device_events:
         totals[name] = totals.get(name, 0.0) + (e - s) / 1e9
     gaps = [(a[1], b[0]) for a, b in zip(busy, busy[1:])
             if b[0] - a[1] >= MIN_GAP_NS]
@@ -105,6 +115,7 @@ def reduce_events(device_events: list, host_events: list) -> dict:
         "events": len(device_events),
         "ops_total": totals,
         "ops_self": self_times(device_events),
+        "scopes_self": self_times(device_events, key=3),
         "gaps": name_gaps(gaps, host_events),
     }
 
@@ -127,29 +138,25 @@ def op_name(name: str) -> str:
     return m.group(1) + (" " + m.group(2) if m.group(2) else "")
 
 
-def _line_events(line, rename=None) -> list:
-    return [(int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
-             rename(ev.name) if rename else ev.name)
-            for ev in line.events]
-
-
 def read_xplane(path: str) -> tuple:
-    """(device planes' op events by plane name, host events, structure)."""
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(path)
+    """(device planes' op events by plane name, host events, structure).
+    A device event is ``(start_ns, end_ns, op_name(name), scope)``, a host
+    event ``(start_ns, end_ns, name)``."""
+    from harness import xplane
     devices, host, structure = {}, [], []
-    for plane in data.planes:
-        lines = list(plane.lines)
-        structure.append({"plane": plane.name, "lines": [
-            {"name": ln.name, "events": sum(1 for _ in ln.events)}
+    for plane in xplane.read_planes(path):
+        name, lines = plane["name"], plane["lines"]
+        structure.append({"plane": name, "lines": [
+            {"name": ln["name"], "events": len(ln["events"])}
             for ln in lines]})
-        if plane.name.startswith(DEVICE_PREFIX):
+        if name.startswith(DEVICE_PREFIX):
             for ln in lines:
-                if ln.name == OPS_LINE:
-                    devices[plane.name] = _line_events(ln, op_name)
-        elif plane.name.startswith("/host:"):
+                if ln["name"] == OPS_LINE:
+                    devices[name] = [(s, e, op_name(n), scope)
+                                     for s, e, n, scope in ln["events"]]
+        elif name.startswith("/host:"):
             for ln in lines:
-                host += _line_events(ln)
+                host += [ev[:3] for ev in ln["events"]]
     return devices, host, structure
 
 

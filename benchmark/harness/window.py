@@ -18,6 +18,8 @@ Readings (``ctx``):
   ``client.ttft_ms.<class>``, ``client.itl_ms``, ``client.lateness_ms``,
   ``samples.<counter>`` (polled over the wire in a traced run).
 * ``trace``    - ``trace_reduce``'s output, or ``None`` without a trace.
+* ``models``   - ``opsbytes.models()`` with the run's directories (absent:
+  ``opsbytes.MODELS``), for ``trace_roofline``.
 """
 
 from __future__ import annotations
@@ -163,6 +165,26 @@ def r_trace_op_share(m, ctx):
     return 100.0 * s / ctx["trace"]["busy_s"]
 
 
+def r_trace_scope_share(m, ctx):
+    """Device self time of the operations whose ``jax.named_scope`` path
+    matches, over busy time. Nothing where no path matches: never 0 for a
+    block the program does not have."""
+    s = _op_seconds(ctx, m["scopes"], "scopes_self")
+    if not s or not ctx["trace"]["busy_s"]:
+        return None
+    return 100.0 * s / ctx["trace"]["busy_s"]
+
+
+def traced_stretch(host_window_s: float, devices: list) -> float:
+    """Seconds the profiler covered. It goes on recording while it is being
+    stopped, so that is the host's window or the devices' own span of
+    operations (their mean), whichever is longer: busy time, which lies
+    inside the span, never exceeds it."""
+    spans = [(d["last_ns"] - d["first_ns"]) / 1e9 for d in devices
+             if d["first_ns"] is not None]
+    return max(host_window_s, sum(spans) / len(spans) if spans else 0.0)
+
+
 def r_trace_idle(m, ctx):
     tr = ctx.get("trace")
     if not tr or not tr["window_s"]:
@@ -178,10 +200,11 @@ def r_trace_roofline(m, ctx):
     steps = (ctx.get("trace") or {}).get("steps")
     if not s or not steps:
         return None
+    model = ctx.get("models", opsbytes.MODELS)[m["model"]]
     least = 0.0
     for server_steps in steps:
         for _t0, _t1, _kind, rows in server_steps:
-            least += opsbytes.least_seconds(m["model"], ctx["cfg"], rows,
+            least += opsbytes.least_seconds(model, ctx["cfg"], rows,
                                             ctx["peak"])
     least /= len(steps)
     share = 100.0 * least / s
@@ -206,15 +229,26 @@ READERS = {
     "series_mean": r_series_mean,
     "server_max_over_mean": r_server_max_over_mean,
     "trace_op_share": r_trace_op_share,
+    "trace_scope_share": r_trace_scope_share,
     "trace_idle": r_trace_idle,
     "trace_roofline": r_trace_roofline,
     "memory_peak": r_memory_peak,
 }
 
 
-def read_metric(spec: dict, ctx: dict):
-    kind = spec["kind"]
+def check_spec(spec: dict, models: dict) -> None:
+    """Refuse at start what would fail at read: a reader kind that is not
+    one of ``READERS``, a roofline whose ``model`` no file defines."""
+    kind = spec.get("kind")
     if kind not in READERS:
         raise ValueError(f"unknown reader kind {kind!r}; have "
                          f"{sorted(READERS)}")
-    return READERS[kind](spec, ctx)
+    if kind == "trace_roofline" and spec.get("model") not in models:
+        raise ValueError(
+            f"roofline model {spec.get('model')!r} is defined by no file of "
+            f"benchmark/opsbytes; have {sorted(models)}")
+
+
+def read_metric(spec: dict, ctx: dict):
+    check_spec(spec, ctx.get("models", opsbytes.MODELS))
+    return READERS[spec["kind"]](spec, ctx)

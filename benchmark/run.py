@@ -42,7 +42,7 @@ sys.path[:0] = [HERE, ROOT]
 
 import numpy as np  # noqa: E402
 
-from harness import client, opsbytes, window  # noqa: E402
+from harness import client, opsbytes, serve, window  # noqa: E402
 
 REHEARSAL_SEED = 20260927      # token contents of the rehearsal passes
 LEAD_S = 3.0                   # loadgen start-up before the ramp is due
@@ -52,6 +52,16 @@ SAMPLED = ("waiting", "queue_depth")   # wire counters polled in a traced run
 
 def say(msg: str) -> None:
     print(msg, flush=True)
+
+
+COMPARED = []      # what `correct` compared, each number beside its limit
+
+
+def say_compared(msg: str) -> None:
+    """A line of the reference check: said now, and again as the run's
+    last lines on standard error."""
+    COMPARED.append(msg)
+    say(msg)
 
 
 class Children:
@@ -243,8 +253,8 @@ def check_correct(server: dict, cfg: dict, seed: int) -> bool:
     for key in prompts:
         toks, lps = got[key]["tokens"], got[key].get("logprobs") or []
         if len(toks) != new or len(lps) != new:
-            say(f"correct[{key}]: {len(toks)} tokens and {len(lps)} "
-                f"logprobs of {new} asked: truncated")
+            say_compared(f"correct[{key}]: {len(toks)} tokens and "
+                         f"{len(lps)} logprobs of {new} asked: truncated")
             ok = False
             continue
         ref = client.checked(server["ctl"], {
@@ -253,13 +263,15 @@ def check_correct(server: dict, cfg: dict, seed: int) -> bool:
         d = [abs(x - y) for x, y in zip(lps, ref)]
         pooled += d
         med = statistics.median(d)
-        say(f"correct[{key}]: median |served - reference| logprob over "
+        say_compared(
+            f"correct[{key}]: median |served - reference| logprob over "
             f"{new} positions = {med:.5f} (limit {spec['limit_request']}); "
             f"max = {max(d):.5f}")
         ok = ok and med <= spec["limit_request"]
     if pooled:
         med, p75 = statistics.median(pooled), window.percentile(pooled, 75)
-        say(f"correct: over {len(pooled)} positions median = {med:.5f} "
+        say_compared(
+            f"correct: over {len(pooled)} positions median = {med:.5f} "
             f"(limit {spec['limit']}), 75th percentile = {p75:.5f} (limit "
             f"{spec['limit_p75']}); 90th = "
             f"{window.percentile(pooled, 90):.5f}, not compared; served in "
@@ -392,7 +404,8 @@ def reduce_traces(traced: dict) -> dict:
     if r.returncode != 0:
         raise RuntimeError(f"trace reduction failed:\n{r.stderr[-3000:]}")
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    out["window_s"] = traced["window_s"]
+    out["window_s"] = window.traced_stretch(traced["window_s"],
+                                            out["devices"])
     out["steps"] = traced["steps"]
     with open(os.path.join(os.path.dirname(traced["dirs"][0]),
                            "trace_reduced.json"), "w") as f:
@@ -405,9 +418,12 @@ def reduce_traces(traced: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def metric_specs(bench: dict, group: str, directories: list, cell: str) -> list:
+def metric_specs(bench: dict, group: str, directories: list, cell: str,
+                 models: dict) -> list:
     """(entry, metric file) of the cell's metrics of one group; a metric's
-    file is looked for in each of ``directories`` in turn."""
+    file is looked for in each of ``directories`` in turn. What a file
+    names and nothing defines (a reader kind, a roofline's model) stops
+    the run here, before any server starts."""
     out = []
     for m in bench[group]:
         if "workloads" in m and cell not in m["workloads"]:
@@ -417,7 +433,12 @@ def metric_specs(bench: dict, group: str, directories: list, cell: str) -> list:
         found = next((p for p in paths if os.path.exists(p)), None)
         if found is None:
             raise SystemExit(f"metric {m['name']!r} has no file: {paths}")
-        out.append((m, load_json(found)))
+        spec = load_json(found)
+        try:
+            window.check_spec(spec, models)
+        except ValueError as e:
+            raise SystemExit(f"metric {m['name']!r} ({found}): {e}")
+        out.append((m, spec))
     return out
 
 
@@ -429,9 +450,16 @@ def run(args, ch: Children) -> dict:
         raise SystemExit(f"no workload {args.workload!r} in {args.benchmark}")
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = load_json(cfg_entry["file"])
+    try:
+        serve.reference_path(cfg)
+    except FileNotFoundError as e:
+        raise SystemExit(f"configuration {cfg_entry['name']!r} "
+                         f"({cfg_entry['file']}): {e}")
     # ``dirs`` is the tests' own: their cells file names directories that
     # are searched before the benchmark's.
     dirs = bench.get("dirs", {})
+    models = opsbytes.models([os.path.join(ROOT, d)
+                              for d in dirs.get("opsbytes", [])])
     traffic_path = os.path.join(
         ROOT, dirs.get("traffic", "benchmark/traffic"),
         cell["traffic"] + ".json")
@@ -442,7 +470,7 @@ def run(args, ch: Children) -> dict:
         bench, group, dirs.get(group, []) + [
             {"per_layer": "benchmark/layer_metrics",
              "end_to_end": "benchmark/end_to_end_metrics"}[group]],
-        cell["name"])
+        cell["name"], models)
 
     # -- servers, warm-up ---------------------------------------------------
     servers = start_servers(ch, cell, cfg_entry, args.seed, trace,
@@ -518,6 +546,7 @@ def run(args, ch: Children) -> dict:
                       for m in after), default=0)
     ctx = {"scalars": scalars, "series": series, "per_server": per_server,
            "seconds": args.seconds, "cfg": cfg, "trace": None,
+           "models": models,
            "cap_ms": 1000.0 * (ramp + args.seconds + drain),
            "memory_peak_bytes": peak_bytes}
     device = {"platform": devices[0]["platform"], "kind": devices[0]["kind"],
@@ -566,7 +595,8 @@ def run(args, ch: Children) -> dict:
             f"over a tenth of the median token gap ({itl:.2f} ms)")
     truncated = [r for r in records if (r["error"] or "").startswith("trunc")]
     if truncated:
-        say(f"correct: {len(truncated)} request(s) came back truncated")
+        say_compared(f"correct: {len(truncated)} request(s) of the window "
+                     f"came back truncated (limit 0)")
         correct = False
     say(f"window: {scalars['client.attempted']} requests due, "
         f"{scalars['client.failed']} failed, "
@@ -615,6 +645,7 @@ def main(argv=None) -> int:
         return 1
     finally:
         ch.stop_all()
+    sys.stderr.write("\n".join(COMPARED) + "\n")
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

@@ -43,10 +43,17 @@ def _served(cfg, name, params, prompt, new, **engine_kw):
     return toks, lps
 
 
-@pytest.mark.parametrize("name", ["tiny-dense", "tiny-moe"])
-def test_program_agrees_with_reference_and_controls_do_not(name):
+# The latent cache alone in int8 moves this toy by less than its limit
+# (one normalised latent of 32 numbers a token); every full control fails.
+@pytest.mark.parametrize("name,controls", [
+    ("tiny-dense", ("bf16", "int8", "fp8", "kv_int8")),
+    ("tiny-moe", ("bf16", "int8", "fp8", "kv_int8")),
+    ("tiny-latent-shared", ("bf16", "int8", "fp8"))])
+def test_program_agrees_with_reference_and_controls_do_not(name, controls):
     cfg = _cfg(name)
     limit = cfg["correct"]["limit"]
+    # the module the configuration names (the default for the first two)
+    reference = serve.load_reference(cfg)
     params = reference.make_params(cfg, 3000000019)
     prompt = np.random.default_rng(1).integers(
         1, cfg["vocab_size"], 80).tolist()
@@ -56,7 +63,8 @@ def test_program_agrees_with_reference_and_controls_do_not(name):
     assert _rms(lps, ref) <= limit
     # the reference one precision step down, in the program's place
     # float32 here: bf16 is the step; kv_int8 rounds the cached K, V alone
-    for quant in ("bf16", "int8", "fp8", "kv_int8"):
+    assert set(controls) <= set(reference.CONTROLS)
+    for quant in controls:
         ctl = reference.chosen_logprobs(cfg, params, prompt, toks, quant)
         assert _rms(ctl, ref) > 3 * limit, quant
 

@@ -117,7 +117,7 @@ def test_roofline_share_and_its_refusal_above_100():
     spec = {"kind": "trace_roofline", "ops": ["_decode_call"],
             "model": "paged_attention"}
     rows = [(1, 4000)] * 8
-    least = opsbytes.least_seconds("paged_attention", CFG, rows,
+    least = opsbytes.least_seconds(opsbytes.paged_attention, CFG, rows,
                                    opsbytes.peak_for("TPU v5 lite"))
     share = window.read_metric(spec, _trace_ctx(least * 4, rows))
     assert share == pytest.approx(25.0)

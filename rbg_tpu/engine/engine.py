@@ -184,6 +184,9 @@ class Engine:
 
         if mesh is not None:
             self._shard_state(mesh)
+        # A decode step may read hit experts only where every device holds
+        # every expert (parallel/sharding.py splits them over ``ep``).
+        self._experts_whole = mesh is None or mesh.shape.get("ep", 1) == 1
 
         self.waiting: List[Request] = []
         self.running: List[Request] = []
@@ -243,7 +246,11 @@ class Engine:
                         "t_emit_s": 0.0, "steps_run": 0,
                         "t_unified_s": 0.0, "unified_steps_run": 0,
                         "t_decode_s": 0.0, "decode_steps_run": 0,
-                        "kv_live_token_steps": 0, "kv_held_slot_steps": 0}
+                        "kv_live_token_steps": 0, "kv_held_slot_steps": 0,
+                        # Decode steps that visited hit experts only
+                        # (llama._moe_mlp_hit): experts x layers a step,
+                        # and how many of them the step read.
+                        "moe_expert_slots": 0, "moe_experts_visited": 0}
         # The step being run: when each phase last began and which one
         # is running (``_Phase``), and what the step first dispatched
         # (``_note_dispatch``).
@@ -1060,7 +1067,7 @@ class Engine:
             # is written and pos/kvl never advance — the donated pool
             # buffers round-trip unchanged (tok/pos/kvl/limit are
             # separate arrays: pos and kvl are donated, tok is not).
-            _, _, _, _, _, kp, vp, ksc, vsc, _, _ = fn(
+            *_, kp, vp, ksc, vsc, _, _ = fn(
                 self.params, jnp.zeros(B, jnp.int32),
                 jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
                 jnp.zeros((B, P), jnp.int32), jnp.zeros((B, 1), bool),
@@ -1106,7 +1113,7 @@ class Engine:
                 # mask all-False: no KV slot is written and pos/kvl never
                 # advance — the donated pool buffers round-trip unchanged
                 # (see warm_join_windows).
-                _, _, _, _, _, kp, vp, ksc, vsc, _, _ = fn(
+                *_, kp, vp, ksc, vsc, _, _ = fn(
                     self.params, jnp.zeros(B, jnp.int32),
                     jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
                     jnp.zeros((B, P), jnp.int32), jnp.zeros((B, K), bool),
@@ -1508,7 +1515,7 @@ class Engine:
         """id(req) → number of un-emitted tokens awaiting fetch."""
         if self._dec is None or self._dec["pending"] is None:
             return {}
-        rows, _, _, valid = self._dec["pending"]
+        rows, *_, valid = self._dec["pending"]
         return {id(r): v for r, v in zip(rows, valid)}
 
     def _decode_batch(self) -> List[Request]:
@@ -1533,12 +1540,16 @@ class Engine:
         return out
 
     def _emit_pending(self, pending) -> List[StepEvent]:
-        rows, toks_dev, lp_dev, valid = pending
+        rows, toks_dev, lp_dev, visited_dev, valid = pending
         with _Phase(self, _SYNC):
             vals = np.asarray(toks_dev)      # [K, B] — the one host sync
             lpv = np.asarray(lp_dev) if lp_dev is not None else None
         events = []
         with _Phase(self, _EMIT):
+            if visited_dev is not None:      # copied since dispatch
+                self.metrics["moe_experts_visited"] += int(visited_dev)
+                self.metrics["moe_expert_slots"] += (
+                    len(vals) * self.mcfg.num_layers * self.mcfg.num_experts)
             for i, req in enumerate(rows):
                 for k in range(valid[i]):
                     if req.state != "running":
@@ -1611,7 +1622,8 @@ class Engine:
             return fn
         import functools
         base = functools.partial(forward_paged, cfg=self.mcfg,
-                                 use_pallas=self.cfg.use_pallas)
+                                 use_pallas=self.cfg.use_pallas,
+                                 experts_whole=self._experts_whole)
 
         def fused(params, tok, pos, kvl, table, mask, limit, k_pages,
                   v_pages, k_scales, v_scales, keys, temps, ks, tps, mps,
@@ -1624,11 +1636,14 @@ class Engine:
                 # writing KV and stop advancing — their sampled values are
                 # discarded host-side via the per-row valid count.
                 write_ok = mask & (pos < limit)[:, None]    # [B, 1]
-                logits, kp, vp, ksc, vsc = base(
+                logits, kp, vp, ksc, vsc, *visited = base(
                     params, tokens=tok[:, None], positions=pos[:, None],
                     token_mask=write_ok, kv_lens=kvl, page_table=table,
                     k_pages=kp, v_pages=vp, k_scales=ksc, v_scales=vsc,
                     lora=lora, lora_ids=lids)
+                # The experts a hit-only step visited; none to count where
+                # the experts are sharded or the step is dense.
+                visited = visited[0] if visited else None
                 pkw = (dict(prompt_mask=pmask, out_counts=oc, rep=rep,
                             pres=pres, freq=freq) if pen else {})
                 lg = logits[:, 0, :]
@@ -1654,8 +1669,8 @@ class Engine:
                 pos = jnp.where(active, pos + 1, pos)
                 kvl = jnp.where(active, kvl + 1, kvl)
                 tok = jnp.where(active, toks, tok)
-                ys = (toks, lps) if lp else toks
-                return (tok, pos, kvl, kp, vp, ksc, vsc, oc, gs), ys
+                return (tok, pos, kvl, kp, vp, ksc, vsc, oc, gs), (
+                    toks, lps if lp else None, visited)
 
             oc0 = ocounts if pen else jnp.zeros((), jnp.int32)
             gs0 = gstate if gr else jnp.zeros((), jnp.int32)
@@ -1663,9 +1678,11 @@ class Engine:
                 body, (tok, pos, kvl, k_pages, v_pages, k_scales, v_scales,
                        oc0, gs0), None, length=K)
             tok, pos, kvl, kp, vp, ksc, vsc, oc, gs = carry
-            toks_seq, lp_seq = ys if lp else (ys, None)
-            return (toks_seq, lp_seq, tok, pos, kvl, kp, vp, ksc, vsc, oc,
-                    gs)
+            toks_seq, lp_seq, visited = ys
+            if visited is not None:          # hit experts only: the window's
+                visited = visited.sum()      # count rides out beside the tokens
+            return (toks_seq, lp_seq, visited, tok, pos, kvl, kp, vp, ksc,
+                    vsc, oc, gs)
 
         # tok is NOT donated: the pending fetch reads last window's output
         # after it has been fed back as this window's input. keys is reused
@@ -1779,13 +1796,18 @@ class Engine:
             if st["gr"]:
                 kw.update(gnext=st["gnext"], glegal=st["glegal"],
                           gstate=st["gstate"], gactive=st["gactive"])
-            toks_seq, lp_seq, tok, pos, kvl, kp, vp, ksc, vsc, oc, gs = fn(
+            (toks_seq, lp_seq, visited, tok, pos, kvl, kp, vp, ksc, vsc, oc,
+             gs) = fn(
                 self.params, st["tok"], st["pos"], st["kvl"], st["table"],
                 st["mask"], st["limit"], self.cache.k_pages,
                 self.cache.v_pages, self.cache.k_scales, self.cache.v_scales,
                 st["keys"], st["temps"], st["ks"], st["tps"], st["mps"], **kw)
             self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
                                       k_scales=ksc, v_scales=vsc)
+            if visited is not None:
+                # On the host by the time the lagged fetch reads it: a
+                # second blocking read a step would cost 0.3 ms of emit.
+                visited.copy_to_host_async()
             st["tok"], st["pos"], st["kvl"] = tok, pos, kvl
             if st["pen"]:
                 st["ocounts"] = oc
@@ -1797,7 +1819,7 @@ class Engine:
                 req.seq_len = min(req.seq_len + K, req.max_len())
 
             prev, st["pending"] = st["pending"], (list(batch), toks_seq,
-                                                  lp_seq, valid)
+                                                  lp_seq, visited, valid)
         if prev is not None:
             events.extend(self._emit_pending(prev))
         return events

@@ -19,6 +19,7 @@ DeepSeek via SGLang) map onto the presets in ``rbg_tpu.models.config``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -203,14 +204,19 @@ def _mla_scale(cfg: ModelConfig) -> float:
 
 
 def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
-                    lora_ids=None):
-    """Shared post-attention math: residual → norm → MLP/MoE → residual."""
+                    lora_ids=None, hit_experts=None):
+    """Shared post-attention math: residual → norm → MLP/MoE → residual.
+    With ``hit_experts`` (``_moe_mlp_hit``'s stacks, layer and live rows)
+    the experts are the hit ones only, and their count is returned too."""
     B, T, _ = x.shape
     with jax.named_scope("attention"):
         x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
                            lora_ids)
     with jax.named_scope("moe" if cfg.num_experts else "mlp"):
         xm = rms_norm(x, blk["mlp_norm"], cfg.rms_norm_eps)
+        if hit_experts is not None:
+            out, visited = _moe_mlp_hit(cfg, blk, xm, *hit_experts)
+            return x + out, visited
         return x + _mlp(cfg, blk, xm, lora, lora_ids)
 
 
@@ -223,34 +229,97 @@ def _mlp(cfg: ModelConfig, blk, xm, lora=None, lora_ids=None):
     return _lora_proj(gate * up, blk["w_down"], "w_down", lora, lora_ids)
 
 
+def _route(cfg: ModelConfig, blk, xm):
+    """Combine weights ``[B, T, E]``: the top-k routing probabilities,
+    renormalised, and exact zeros for every other expert."""
+    logits = (xm @ blk["router"]).astype(jnp.float32)          # [B, T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_vals, _ = jax.lax.top_k(probs, cfg.experts_per_token)  # [B, T, K]
+    threshold = top_vals[..., -1:]                              # k-th largest
+    weights = jnp.where(probs >= threshold, probs, 0.0)
+    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    return weights.astype(xm.dtype)
+
+
+def _shared_expert(blk, xm):
+    gate = jax.nn.silu(xm @ blk["w_gate"])
+    return (gate * (xm @ blk["w_up"])) @ blk["w_down"]
+
+
 def _moe_mlp(cfg: ModelConfig, blk, xm):
     """Top-k sparse MoE (DeepSeek/Mixtral-style) in the dense-dispatch
     formulation: every expert is evaluated and combined with its (mostly
     zero) routing weight. TPU-first rationale: the expert dim shards over
     the ``ep`` mesh axis (each device computes only its experts; XLA psums
     the weighted combine over ep), shapes stay static, and no sort/dispatch
-    scalar code enters the graph. A capacity-based dispatch kernel is a
-    later optimization; routing math is exact either way."""
-    B, T, D = xm.shape
-    E, K = cfg.num_experts, cfg.experts_per_token
-
-    logits = (xm @ blk["router"]).astype(jnp.float32)          # [B, T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_vals, _ = jax.lax.top_k(probs, K)                      # [B, T, K]
-    threshold = top_vals[..., -1:]                              # k-th largest
-    weights = jnp.where(probs >= threshold, probs, 0.0)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
-    weights = weights.astype(xm.dtype)
-
+    scalar code enters the graph. Right wherever a step's tokens hit every
+    expert anyway (prefill, training); a step of few rows takes
+    ``_moe_mlp_hit``. Routing math is exact either way."""
+    weights = _route(cfg, blk, xm)
     hg = jnp.einsum("btd,edf->btef", xm, blk["moe_gate"])
     hu = jnp.einsum("btd,edf->btef", xm, blk["moe_up"])
     h = jax.nn.silu(hg) * hu
     out = jnp.einsum("bte,btef,efd->btd", weights, h, blk["moe_down"])
 
     if cfg.moe_shared_expert:
-        gate = jax.nn.silu(xm @ blk["w_gate"])
-        out = out + (gate * (xm @ blk["w_up"])) @ blk["w_down"]
+        out = out + _shared_expert(blk, xm)
     return out
+
+
+_EXPERT_STACKS = ("moe_gate", "moe_up", "moe_down")
+
+
+def hit_experts_pay(cfg: ModelConfig, rows: int) -> bool:
+    """Whether a step of ``rows`` tokens should visit hit experts only.
+    The expected share of experts hit is 1 - (1 - K/E)^rows; at rows·K =
+    2·E it is 0.87-0.90 (Mixtral: 8 rows), and a step with every expert
+    hit costs 1.4 % over the dense dispatch (PERF.md, PR 29). Above that
+    there is nothing to skip."""
+    return bool(cfg.num_experts) and (
+        rows * cfg.experts_per_token <= 2 * cfg.num_experts)
+
+
+def _moe_mlp_hit(cfg: ModelConfig, blk, xm, stacks, layer, live):
+    """``_moe_mlp`` for a step of few rows: only the experts some LIVE
+    row routed to are evaluated, so only their weights are read. Decode is
+    bound by expert bytes, and 8 rows x top-2 of 8 experts hit 7.2 of them
+    on average (4 / 2 / 1 rows: 5.5 / 3.5 / 2). Every term left out is a
+    product with a combine weight of exactly 0. Weights stay in their
+    dtype; gate, up and the combine accumulate in float32.
+
+    ``stacks`` are the STACKED expert weights ``[L, E, D, F]`` /
+    ``[L, E, F, D]`` and ``layer`` the scan's index: each visit slices
+    ``(layer, expert)`` at once, which XLA fuses into the dot. The scan's
+    own slice ``blk["moe_gate"] [E, D, F]`` would be an operand of the
+    inner loop, and so a copy of 0.94 GB per matrix per layer.
+    ``live [B, T]`` marks the real rows: a padded or finished row routes
+    nowhere, so it makes no expert live and only the shared expert adds
+    to it. Returns (out ``[B, T, D]``, the number of experts visited)."""
+    B, T, D = xm.shape
+    E = cfg.num_experts
+    x = xm.reshape(B * T, D)
+    w = jnp.where(live.reshape(B * T, 1),
+                  _route(cfg, blk, xm).reshape(B * T, E), 0)
+    w = w.astype(jnp.float32)
+    hit = jnp.any(w > 0, axis=0)                                # [E]
+    visited = jnp.sum(hit, dtype=jnp.int32)
+    ids = jnp.nonzero(hit, size=E, fill_value=0)[0].astype(jnp.int32)
+
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+
+    def visit(i, acc):
+        e = ids[i]
+        h = jax.nn.silu(dot(x, stacks["moe_gate"][layer, e])) * dot(
+            x, stacks["moe_up"][layer, e])
+        y = dot(h.astype(x.dtype), stacks["moe_down"][layer, e])
+        return acc + jax.lax.dynamic_slice_in_dim(w, e, 1, axis=1) * y
+
+    out = jax.lax.fori_loop(0, visited, visit,
+                            jnp.zeros((B * T, D), jnp.float32))
+    out = out.astype(xm.dtype).reshape(B, T, D)
+    if cfg.moe_shared_expert:
+        out = out + _shared_expert(blk, xm)
+    return out, visited
 
 
 def _head(params, cfg: ModelConfig, x) -> jnp.ndarray:
@@ -373,12 +442,19 @@ def forward_paged(
     lora: Optional[dict] = None,    # {w: (A [L,n,d,r], B [L,n,r,o])} —
                                     # multi-LoRA stack, alpha/r folded into B
     lora_ids: Optional[jnp.ndarray] = None,  # [B] int32 adapter slot per row
+    experts_whole: bool = False,    # no mesh axis shards the expert dim
 ):
     """Serving forward over the paged KV pool (prefill chunks and decode steps
     share this one traced program per (B, T) bucket). With scales, the pool
     is int8-quantized (per-vector absmax) — half the KV HBM.
 
     Returns (logits [B, T, V] f32, k_pages, v_pages, k_scales, v_scales).
+
+    A caller whose experts are whole on every device says so with
+    ``experts_whole`` and gets a sixth value: where the step is small enough
+    to pay (``hit_experts_pay``), the experts run as ``_moe_mlp_hit`` and
+    the value is the number of experts visited, summed over the layers;
+    otherwise it is None and the program is the dense one.
     """
     from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
 
@@ -396,6 +472,15 @@ def forward_paged(
     kpf, vpf = flat(k_pages), flat(v_pages)
     ksf = flat(k_scales) if quantized else None
     vsf = flat(v_scales) if quantized else None
+
+    # The hit-experts form takes the stacked expert weights as the scan's
+    # invariants, addressed by (layer, expert) like the pool above: as
+    # scanned inputs each layer's [E, D, F] slice would be copied whole.
+    blocks = params["blocks"]
+    hit_only = experts_whole and hit_experts_pay(cfg, x.shape[0] * x.shape[1])
+    if hit_only:
+        stacks = {k: blocks[k] for k in _EXPERT_STACKS}
+        blocks = {k: v for k, v in blocks.items() if k not in stacks}
 
     def step(carry, xs):
         hcur, kpf, vpf, ksf, vsf = carry
@@ -427,19 +512,27 @@ def forward_paged(
                 attn = paged_attention(q, kpf, vpf, table, positions, kv_lens,
                                        use_pallas=use_pallas, k_scales=ksf,
                                        v_scales=vsf)
+        if hit_only:
+            out, visited = _post_attention(
+                cfg, blk, hcur, attn, lr, lora_ids,
+                hit_experts=(stacks, li, token_mask))
+            return (out, kpf, vpf, ksf, vsf), visited
         out = _post_attention(cfg, blk, hcur, attn, lr, lora_ids)
         return (out, kpf, vpf, ksf, vsf), None
 
-    xs_in = (params["blocks"], jnp.arange(L_, dtype=jnp.int32))
+    xs_in = (blocks, jnp.arange(L_, dtype=jnp.int32))
     if lora is not None:
         xs_in = xs_in + (lora,)             # A/B carry leading L → scan-sliced
-    (x, kpf, vpf, ksf, vsf), _ = jax.lax.scan(
+    (x, kpf, vpf, ksf, vsf), visited = jax.lax.scan(
         step, (x, kpf, vpf, ksf, vsf), xs_in)
     k_pages, v_pages = kpf.reshape(k_pages.shape), vpf.reshape(v_pages.shape)
     if quantized:
         k_scales = ksf.reshape(k_scales.shape)
         v_scales = vsf.reshape(v_scales.shape)
-    return _head(params, cfg, x), k_pages, v_pages, k_scales, v_scales
+    out = (_head(params, cfg, x), k_pages, v_pages, k_scales, v_scales)
+    if experts_whole:
+        out += (visited.sum() if hit_only else None,)
+    return out
 
 
 def forward_paged_window(

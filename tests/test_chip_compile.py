@@ -198,3 +198,36 @@ def test_fused_decode_of_llama3_1b_fits_and_holds_the_kernel(
         eng.params, *small, eng.cache.k_pages, eng.cache.v_pages, None, None,
         *tail).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---- the hit-experts form of a decode step, at Mixtral's widths -------------
+
+
+def test_hit_experts_read_the_stacked_weights_in_place(chip):
+    """``_moe_mlp_hit`` inside a layer scan at Mixtral-8x7B's widths: the
+    slice of one expert out of ``[L, E, D, F]`` has to fuse into its dot.
+    An expert matrix copied first (117 MB; a layer's ``[E, D, F]`` 0.94 GB)
+    is what handing the scan's own slice to the inner loop costs, and it
+    shows as temporary memory."""
+    import dataclasses
+    from rbg_tpu.models import get_config
+    from rbg_tpu.models.llama import _moe_mlp_hit
+    cfg = dataclasses.replace(get_config("mixtral-8x7b"), num_layers=3)
+    L, E, D, F = cfg.num_layers, cfg.num_experts, cfg.hidden_size, cfg.moe_f
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+
+    def experts(x, live, router, gate, up, down):
+        stacks = {"moe_gate": gate, "moe_up": up, "moe_down": down}
+
+        def layer(h, xs):
+            li, r = xs
+            out, visited = _moe_mlp_hit(cfg, {"router": r}, h, stacks, li,
+                                        live)
+            return h + out, visited
+        return jax.lax.scan(layer, x, (jnp.arange(L, dtype=I32), router))
+
+    compiled = jax.jit(experts).lower(
+        S((R, 1, D), BF16), S((R, 1), bool), S((L, D, E), BF16),
+        S((L, E, D, F), BF16), S((L, E, D, F), BF16),
+        S((L, E, F, D), BF16)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20

@@ -96,3 +96,142 @@ def test_moe_serving_engine(moe_setup):
                               use_pallas="never"), params=params)
     got = eng.generate([prompt], SamplingParams(max_new_tokens=6))[0]
     assert got == expect
+
+
+# ---- a step of few rows visits hit experts only (llama._moe_mlp_hit) ----
+
+
+def _toy(E, K, shared):
+    import dataclasses
+    return dataclasses.replace(
+        get_config("tiny-moe"), name=f"toy-e{E}k{K}", hidden_size=64,
+        num_heads=2, num_kv_heads=1, num_experts=E, experts_per_token=K,
+        moe_intermediate_size=32, moe_shared_expert=shared,
+        intermediate_size=48)
+
+
+def _routed(cfg, params, picks, seed=0):
+    """A layer-1 block whose router sends row r to experts ``picks[r]``
+    (a spike on each picked expert's logit), and the rows' activations."""
+    E, D = cfg.num_experts, cfg.hidden_size
+    blk = jax.tree_util.tree_map(lambda a: a[1], params["blocks"])
+    x = 0.1 * np.asarray(jax.random.normal(
+        jax.random.key(seed), (len(picks), D), jnp.float32))
+    if picks[0] is not None:
+        blk = dict(blk, router=50.0 * jnp.eye(D, E, dtype=jnp.float32))
+        x[:, :E] = 0.0
+        for r, experts in enumerate(picks):
+            for j, e in enumerate(experts):
+                x[r, e] = 1.0 - 0.1 * j
+    return blk, jnp.asarray(x)[:, None, :]                    # [rows, 1, D]
+
+
+HIT_CASES = {
+    # name: (E, K, shared expert, picks per row (None: as the weights fall),
+    #        live rows (None: all), experts visited (None: whatever is hit))
+    "e8k2-rows1": (8, 2, False, [None], None, 2),
+    "e8k2-rows2": (8, 2, False, [None] * 2, None, None),
+    "e8k2-rows8": (8, 2, False, [None] * 8, None, None),
+    "e8k2-every-expert-hit": (
+        8, 2, False, [(2 * r % 8, (2 * r + 1) % 8) for r in range(8)],
+        None, 8),
+    "e8k2-all-rows-agree": (8, 2, False, [(5, 3)] * 8, None, 2),
+    "e8k1-one-expert-hit": (8, 1, False, [(6,)] * 8, None, 1),
+    "e64k6-shared-rows1": (64, 6, True, [None], None, 6),
+    "e64k6-shared-rows2": (64, 6, True, [None] * 2, None, None),
+    "e64k6-shared-rows8": (64, 6, True, [None] * 8, None, None),
+    # The dead rows route to experts 6 and 7, which no live row wants.
+    "e8k2-dead-rows-make-no-expert-live": (
+        8, 2, False, [(0, 1), (1, 2), (0, 2), (2, 1)] + [(6, 7)] * 4,
+        [True] * 4 + [False] * 4, 3),
+    "e8k2-no-row-live": (8, 2, False, [(0, 1)] * 2, [False] * 2, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HIT_CASES))
+def test_hit_experts_form_equals_the_dense_dispatch(case):
+    from rbg_tpu.models.llama import (_EXPERT_STACKS, _moe_mlp,
+                                      _moe_mlp_hit, _route, hit_experts_pay)
+    E, K, shared, picks, live, want_visited = HIT_CASES[case]
+    cfg = _toy(E, K, shared)
+    assert hit_experts_pay(cfg, len(picks))
+    params = init_params(cfg, jax.random.key(5))
+    blk, xm = _routed(cfg, params, picks)
+    live = jnp.asarray([True] * len(picks) if live is None else live)
+    stacks = {k: params["blocks"][k] for k in _EXPERT_STACKS}
+
+    dense = np.asarray(_moe_mlp(cfg, blk, xm))
+    got, visited = jax.jit(
+        lambda b, x, s, m: _moe_mlp_hit(cfg, b, x, s, jnp.int32(1), m[:, None])
+    )(blk, xm, stacks, live)
+
+    rows = np.asarray(live)
+    if rows.any():
+        # Against the output's own size: an expert left out is a tenth of it.
+        size = np.abs(dense[rows]).max()
+        assert size > 0
+        np.testing.assert_allclose(np.asarray(got)[rows], dense[rows],
+                                   rtol=1e-4, atol=1e-5 * size)
+    hit = (np.asarray(_route(cfg, blk, xm))[rows] > 0).any(axis=(0, 1))
+    assert int(visited) == hit.sum()
+    if want_visited is not None:
+        assert int(visited) == want_visited
+    # A dead row gets the shared expert and nothing else.
+    if not rows.all() and not shared:
+        assert not np.asarray(got)[~rows].any()
+
+
+def _paged_step(cfg, params, rows, **kw):
+    from rbg_tpu.models.llama import forward_paged
+    L, KV, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
+    pool = jnp.zeros((L, 2 * rows, 8, KV, hd), cfg.jax_dtype)
+    tokens = (jnp.arange(rows, dtype=jnp.int32) * 7 + 3)[:, None]
+    return lambda p: forward_paged(
+        p, cfg, tokens=tokens, positions=jnp.zeros((rows, 1), jnp.int32),
+        token_mask=jnp.ones((rows, 1), bool),
+        kv_lens=jnp.ones((rows,), jnp.int32),
+        page_table=jnp.arange(2 * rows, dtype=jnp.int32).reshape(rows, 2),
+        k_pages=pool, v_pages=pool, use_pallas="never", **kw)
+
+
+def test_paged_step_of_few_rows_visits_hit_experts_only(moe_setup):
+    cfg, params = moe_setup                     # E 4, K 2: up to 4 rows
+    dense = jax.jit(_paged_step(cfg, params, 3))(params)
+    hit = jax.jit(_paged_step(cfg, params, 3, experts_whole=True))(params)
+    assert len(dense) == 5 and len(hit) == 6
+    np.testing.assert_allclose(np.asarray(hit[0]), np.asarray(dense[0]),
+                               rtol=1e-5, atol=1e-5)
+    assert cfg.num_layers * cfg.experts_per_token <= int(hit[5]) \
+        <= cfg.num_layers * cfg.num_experts
+
+
+def test_paged_step_above_the_threshold_is_the_dense_program(moe_setup):
+    """Rows x K > 2 x E: nothing to skip, and the program is the one a
+    caller gets who never said its experts are whole."""
+    cfg, params = moe_setup
+    from rbg_tpu.models.llama import hit_experts_pay
+    assert hit_experts_pay(cfg, 4) and not hit_experts_pay(cfg, 5)
+
+    def program(**kw):
+        def step(p):
+            out = _paged_step(cfg, params, 5, **kw)(p)
+            assert len(out) == 5 or out[5] is None
+            return out[:5]
+        return jax.jit(step).lower(params).as_text()
+
+    texts = [program(), program(experts_whole=True)]
+    assert texts[0] == texts[1]
+    assert "while" in texts[0]
+
+
+def test_hit_experts_form_under_a_tp_mesh(moe_setup):
+    """tp splits every expert's F, not the experts: each device visits the
+    same hit experts and reads its own columns of them."""
+    cfg, params = moe_setup
+    mesh = make_mesh(dp=1, sp=1, ep=1, tp=2)
+    want = jax.jit(_paged_step(cfg, params, 2, experts_whole=True))(params)
+    p_sh = shard_pytree(params, param_specs(cfg), mesh)
+    got = jax.jit(_paged_step(cfg, params, 2, experts_whole=True))(p_sh)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-4, atol=1e-4)
+    assert int(got[5]) == int(want[5])

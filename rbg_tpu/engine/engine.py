@@ -1110,13 +1110,15 @@ class Engine:
                     self._sampling_rows([], B)
                 fn = self._get_decode_fn(B, False, False, tpmp, False,
                                          False, K=K)
-                # mask all-False: no KV slot is written and pos/kvl never
+                # mask all-False, [B, 1] as _build_decode_state makes it (any
+                # other shape is another program, and this one would compile
+                # mid-serving): no KV slot is written and pos/kvl never
                 # advance — the donated pool buffers round-trip unchanged
                 # (see warm_join_windows).
                 *_, kp, vp, ksc, vsc, _, _ = fn(
                     self.params, jnp.zeros(B, jnp.int32),
                     jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
-                    jnp.zeros((B, P), jnp.int32), jnp.zeros((B, K), bool),
+                    jnp.zeros((B, P), jnp.int32), jnp.zeros((B, 1), bool),
                     jnp.zeros(B, jnp.int32),
                     self.cache.k_pages, self.cache.v_pages,
                     self.cache.k_scales, self.cache.v_scales,

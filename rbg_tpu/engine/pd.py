@@ -456,7 +456,7 @@ class DecodeWorker:
         # release its pages instead of holding KV capacity forever.
         self._stream_commits: Dict[str, _StreamCommit] = {}
         self.stream_ttl_s = 120.0
-        # Layer-sliced admission: jitted forward_paged_window programs
+        # Layer-sliced admission: jitted layer-window (paged_layers) programs
         # keyed (layer_lo, layer_hi, B) and the per-bucket LM head.
         self._window_fns: Dict = {}
         self._head_fns: Dict = {}
@@ -737,16 +737,17 @@ class DecodeWorker:
         if fn is None:
             import functools
 
-            from rbg_tpu.models.llama import forward_paged_window
+            from rbg_tpu.models.llama import PoolAddr, paged_layers
             eng = self.engine
-            base = functools.partial(forward_paged_window, eng.params,
-                                     eng.mcfg, lo, hi,
+            base = functools.partial(paged_layers, eng.params, eng.mcfg,
+                                     layers=(lo, hi),
                                      use_pallas=eng.cfg.use_pallas)
 
             def window(x, pos, mask, kvl, table, k_pages, v_pages,
                        k_scales, v_scales):
-                return base(x, pos, mask, kvl, table, k_pages, v_pages,
-                            k_scales=k_scales, v_scales=v_scales)
+                x, pool, _ = base(x, (k_pages, v_pages, k_scales, v_scales),
+                                  PoolAddr(pos, mask, kvl, table))
+                return (x, *pool)
 
             window.__name__ = obs_names.PROGRAM_PD_WINDOW   # jitwatch catalog
             donate = (5, 6, 7, 8) if eng.cache.quantized else (5, 6)

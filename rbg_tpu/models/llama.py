@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -426,6 +426,109 @@ def forward(
     return logits, KVCache(k=k_new, v=v_new, length=new_length)
 
 
+class PoolAddr(NamedTuple):
+    """How a step's tokens address the paged KV pool: a ``[B, T]`` batch has
+    a table line per row; a packed ``[1, T]`` step (``forward_ragged``) names
+    each token's line with ``row_ids``."""
+    positions: jnp.ndarray      # [B, T] int32 absolute positions
+    token_mask: jnp.ndarray     # [B, T] bool — real (non-pad) tokens
+    kv_lens: jnp.ndarray        # [R] int32 — cache length AFTER this step
+    page_table: jnp.ndarray     # [R, P] int32 physical page ids
+    row_ids: Optional[jnp.ndarray] = None   # [T] int32 token → row
+    max_q_len: Optional[int] = None         # static, packed steps only
+
+
+def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
+                    use_pallas, lora=None, lora_ids=None):
+    """The attention half of one layer over the flat pool: q/k/v (or the
+    MLA latents, which ride the pool as the (c, k_pe) pair), the write of
+    this step's slots, the attend, by the row or the packed operations as
+    the input says (``row_ids``). ``table`` is the layer's own. Returns
+    (attn ``[B, T, h, dv]``, pool)."""
+    from rbg_tpu.ops.mla_attention import (paged_mla_attention,
+                                            ragged_paged_mla_attention)
+    from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
+    from rbg_tpu.ops.ragged_paged_attention import (ragged_paged_attention,
+                                                    write_kv_pages_ragged)
+
+    positions, token_mask, kv_lens, _, row_ids, max_q_len = addr
+    if row_ids is None:
+        write, attend, attend_mla = (write_kv_pages, paged_attention,
+                                     paged_mla_attention)
+        rows, bound = (), {}
+    else:
+        write, attend, attend_mla = (write_kv_pages_ragged,
+                                     ragged_paged_attention,
+                                     ragged_paged_mla_attention)
+        rows, bound = (row_ids,), {"max_q_len": max_q_len}
+    if cfg.mla:
+        *q, c, k_pe = _mla_qkv(cfg, blk, x, positions, lora, lora_ids)
+        k, v = c[:, :, None, :], k_pe[:, :, None, :]
+    else:
+        q, k, v = _qkv(cfg, blk, x, positions, lora, lora_ids)
+    kpf, vpf, ksf, vsf = pool = write(*pool[:2], k, v, table, *rows,
+                                      positions, token_mask, *pool[2:])
+    where = (kpf, vpf, table, positions, kv_lens, *rows)
+    if cfg.mla:
+        return _mla_out(cfg, blk, attend_mla(
+            *q, *where, _mla_scale(cfg), use_pallas=use_pallas, c_scales=ksf,
+            pe_scales=vsf, **bound)), pool
+    return attend(q, *where, use_pallas=use_pallas, k_scales=ksf,
+                  v_scales=vsf, **bound), pool
+
+
+def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
+                 layers: Tuple[int, int], use_pallas: str = "auto", lora=None,
+                 lora_ids=None, experts_whole: bool = False):
+    """The one walk over cache-bearing layers: layers ``[lo, hi)`` (static)
+    over the hidden states ``x [B, T, D]`` entering layer ``lo``, writing and
+    attending those layers' pages of the FULL pool, the tuple ``(k_pages,
+    v_pages, k_scales, v_scales)``, ``[L, NP, page, KV, hd]`` each (scales
+    None unless int8). A chain of windows covering every layer is the whole
+    walk (``tests/test_layer_walk.py``): ``engine/pd.py`` chains them so that
+    a first decode step starts when the leading layers' KV has arrived.
+    Returns (x, pool, visited): ``visited [hi - lo]`` counts the experts each
+    layer visited where the hit-experts form ran (``experts_whole`` and
+    ``hit_experts_pay``), else None."""
+    lo, hi = layers
+    # The pool rides the layer scan as CARRY over a [L·NP, …] flat view,
+    # with each layer addressing its pages as ``layer·NP + page_table``. As
+    # a per-layer scan INPUT/OUTPUT (stacked ys) the entire pool would be
+    # copied every step, though only [B·T] slots changed; the in-place carry
+    # scatter keeps a step's KV traffic at the written slots.
+    L_, NP = pool[0].shape[:2]
+    flat = jax.tree_util.tree_map(
+        lambda p: p.reshape((L_ * NP,) + p.shape[2:]), pool)
+
+    # The hit-experts form takes the stacked expert weights as the scan's
+    # invariants, addressed by (layer, expert) like the pool above: as
+    # scanned inputs each layer's [E, D, F] slice would be copied whole.
+    blocks = params["blocks"]
+    hit_only = experts_whole and hit_experts_pay(cfg, x.shape[0] * x.shape[1])
+    if hit_only:
+        stacks = {k: blocks[k] for k in _EXPERT_STACKS}
+        blocks = {k: v for k, v in blocks.items() if k not in stacks}
+
+    def step(carry, xs):
+        hcur, flat = carry
+        blk, li, lr = xs
+        table = addr.page_table + li * NP
+        with jax.named_scope("attention"):
+            attn, flat = _pool_attention(cfg, blk, hcur, flat, table, addr,
+                                         use_pallas, lr, lora_ids)
+        hit = (stacks, li, addr.token_mask) if hit_only else None
+        out = _post_attention(cfg, blk, hcur, attn, lr, lora_ids, hit)
+        out, visited = out if hit_only else (out, None)
+        return (out, flat), visited
+
+    # Block weights and LoRA A/B carry a leading L: scan-sliced per layer.
+    blocks, lora = jax.tree_util.tree_map(lambda a: a[lo:hi], (blocks, lora))
+    (x, flat), visited = jax.lax.scan(
+        step, (x, flat), (blocks, jnp.arange(lo, hi, dtype=jnp.int32), lora))
+    return x, jax.tree_util.tree_map(lambda f, p: f.reshape(p.shape), flat,
+                                     pool), visited
+
+
 def forward_paged(
     params: dict,
     cfg: ModelConfig,
@@ -439,178 +542,28 @@ def forward_paged(
     use_pallas: str = "auto",
     k_scales: Optional[jnp.ndarray] = None,  # [L, NP, page, KV, 1] (int8 KV)
     v_scales: Optional[jnp.ndarray] = None,
-    lora: Optional[dict] = None,    # {w: (A [L,n,d,r], B [L,n,r,o])} —
-                                    # multi-LoRA stack, alpha/r folded into B
+    lora: Optional[dict] = None,    # {w: (A [L,n,d,r], B [L,n,r,o]·alpha/r)}
     lora_ids: Optional[jnp.ndarray] = None,  # [B] int32 adapter slot per row
     experts_whole: bool = False,    # no mesh axis shards the expert dim
 ):
     """Serving forward over the paged KV pool (prefill chunks and decode steps
     share this one traced program per (B, T) bucket). With scales, the pool
     is int8-quantized (per-vector absmax) — half the KV HBM.
-
     Returns (logits [B, T, V] f32, k_pages, v_pages, k_scales, v_scales).
-
     A caller whose experts are whole on every device says so with
-    ``experts_whole`` and gets a sixth value: where the step is small enough
-    to pay (``hit_experts_pay``), the experts run as ``_moe_mlp_hit`` and
-    the value is the number of experts visited, summed over the layers;
-    otherwise it is None and the program is the dense one.
-    """
-    from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
-
+    ``experts_whole`` and gets a sixth value: the experts visited, summed
+    over the layers, where the step ran as ``_moe_mlp_hit`` (small enough
+    for ``hit_experts_pay``); else None, and the program is the dense one."""
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
-    quantized = k_scales is not None
-
-    # The pool rides the layer scan as CARRY over a [L·NP, …] flat view,
-    # with each layer addressing its pages as ``layer·NP + page_table``.
-    # Making the pool a per-layer scan INPUT/OUTPUT instead (stacked ys)
-    # would copy the entire pool every step — the layer-slice stacking is a
-    # full-pool write even though only [B·T] slots changed. In-place carry
-    # scatter keeps the per-step KV traffic at the written slots only.
-    L_, NP = k_pages.shape[0], k_pages.shape[1]
-    flat = lambda p: p.reshape((L_ * NP,) + p.shape[2:])
-    kpf, vpf = flat(k_pages), flat(v_pages)
-    ksf = flat(k_scales) if quantized else None
-    vsf = flat(v_scales) if quantized else None
-
-    # The hit-experts form takes the stacked expert weights as the scan's
-    # invariants, addressed by (layer, expert) like the pool above: as
-    # scanned inputs each layer's [E, D, F] slice would be copied whole.
-    blocks = params["blocks"]
-    hit_only = experts_whole and hit_experts_pay(cfg, x.shape[0] * x.shape[1])
-    if hit_only:
-        stacks = {k: blocks[k] for k in _EXPERT_STACKS}
-        blocks = {k: v for k, v in blocks.items() if k not in stacks}
-
-    def step(carry, xs):
-        hcur, kpf, vpf, ksf, vsf = carry
-        if lora is not None:
-            blk, li, lr = xs
-        else:
-            blk, li = xs
-            lr = None
-        table = page_table + li * NP
-        with jax.named_scope("attention"):
-            if cfg.mla:
-                from rbg_tpu.ops.mla_attention import paged_mla_attention
-                q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions,
-                                                lr, lora_ids)
-                kpf, vpf, ksf, vsf = write_kv_pages(
-                    kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
-                    positions, token_mask, ksf, vsf)
-                attn_lat = paged_mla_attention(q_lat, q_pe, kpf, vpf, table,
-                                               positions, kv_lens,
-                                               _mla_scale(cfg),
-                                               use_pallas=use_pallas,
-                                               c_scales=ksf, pe_scales=vsf)
-                attn = _mla_out(cfg, blk, attn_lat)
-            else:
-                q, k, vv = _qkv(cfg, blk, hcur, positions, lr, lora_ids)
-                kpf, vpf, ksf, vsf = write_kv_pages(kpf, vpf, k, vv, table,
-                                                    positions, token_mask,
-                                                    ksf, vsf)
-                attn = paged_attention(q, kpf, vpf, table, positions, kv_lens,
-                                       use_pallas=use_pallas, k_scales=ksf,
-                                       v_scales=vsf)
-        if hit_only:
-            out, visited = _post_attention(
-                cfg, blk, hcur, attn, lr, lora_ids,
-                hit_experts=(stacks, li, token_mask))
-            return (out, kpf, vpf, ksf, vsf), visited
-        out = _post_attention(cfg, blk, hcur, attn, lr, lora_ids)
-        return (out, kpf, vpf, ksf, vsf), None
-
-    xs_in = (blocks, jnp.arange(L_, dtype=jnp.int32))
-    if lora is not None:
-        xs_in = xs_in + (lora,)             # A/B carry leading L → scan-sliced
-    (x, kpf, vpf, ksf, vsf), visited = jax.lax.scan(
-        step, (x, kpf, vpf, ksf, vsf), xs_in)
-    k_pages, v_pages = kpf.reshape(k_pages.shape), vpf.reshape(v_pages.shape)
-    if quantized:
-        k_scales = ksf.reshape(k_scales.shape)
-        v_scales = vsf.reshape(v_scales.shape)
-    out = (_head(params, cfg, x), k_pages, v_pages, k_scales, v_scales)
+    x, pool, visited = paged_layers(
+        params, cfg, x, (k_pages, v_pages, k_scales, v_scales),
+        PoolAddr(positions, token_mask, kv_lens, page_table),
+        layers=(0, cfg.num_layers), use_pallas=use_pallas, lora=lora,
+        lora_ids=lora_ids, experts_whole=experts_whole)
+    out = (_head(params, cfg, x), *pool)
     if experts_whole:
-        out += (visited.sum() if hit_only else None,)
+        out += (None if visited is None else visited.sum(),)
     return out
-
-
-def forward_paged_window(
-    params: dict,
-    cfg: ModelConfig,
-    layer_lo: int,              # static — first layer of the window
-    layer_hi: int,              # static — one past the last layer
-    x: jnp.ndarray,             # [B, T, D] hidden states ENTERING layer_lo
-    positions: jnp.ndarray,     # [B, T] int32 absolute positions
-    token_mask: jnp.ndarray,    # [B, T] bool — real (non-pad) tokens
-    kv_lens: jnp.ndarray,       # [B] int32 — cache length AFTER this step
-    page_table: jnp.ndarray,    # [B, P] int32 physical page ids
-    k_pages: jnp.ndarray,       # [L, NP, page, KV, hd] — FULL pool
-    v_pages: jnp.ndarray,
-    use_pallas: str = "auto",
-    k_scales: Optional[jnp.ndarray] = None,
-    v_scales: Optional[jnp.ndarray] = None,
-):
-    """One LAYER WINDOW of ``forward_paged``: run layers
-    ``[layer_lo, layer_hi)`` over hidden states, writing/attending only
-    those layers' pages. The layer-sliced decode admission path
-    (kvtransfer) chains these windows so the first decode step can start
-    as soon as the leading layers' KV has arrived, overlapping compute
-    with the transfer tail; the caller embeds tokens before window 0 and
-    applies ``_head`` after the last window.
-
-    Same per-layer math as ``forward_paged``'s scan body (the window of
-    size L is exactly the full forward), so a chain covering every layer
-    reproduces the unified step's numerics. Returns
-    (x, k_pages, v_pages, k_scales, v_scales) with the FULL pool
-    (untouched layers pass through)."""
-    from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
-
-    quantized = k_scales is not None
-    L_, NP = k_pages.shape[0], k_pages.shape[1]
-    flat = lambda p: p.reshape((L_ * NP,) + p.shape[2:])
-    kpf, vpf = flat(k_pages), flat(v_pages)
-    ksf = flat(k_scales) if quantized else None
-    vsf = flat(v_scales) if quantized else None
-
-    def step(carry, xs):
-        hcur, kpf, vpf, ksf, vsf = carry
-        blk, li = xs
-        table = page_table + li * NP
-        with jax.named_scope("attention"):
-            if cfg.mla:
-                from rbg_tpu.ops.mla_attention import paged_mla_attention
-                q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions)
-                kpf, vpf, ksf, vsf = write_kv_pages(
-                    kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
-                    positions, token_mask, ksf, vsf)
-                attn_lat = paged_mla_attention(q_lat, q_pe, kpf, vpf, table,
-                                               positions, kv_lens,
-                                               _mla_scale(cfg),
-                                               use_pallas=use_pallas,
-                                               c_scales=ksf, pe_scales=vsf)
-                attn = _mla_out(cfg, blk, attn_lat)
-            else:
-                q, k, vv = _qkv(cfg, blk, hcur, positions)
-                kpf, vpf, ksf, vsf = write_kv_pages(kpf, vpf, k, vv, table,
-                                                    positions, token_mask,
-                                                    ksf, vsf)
-                attn = paged_attention(q, kpf, vpf, table, positions, kv_lens,
-                                       use_pallas=use_pallas, k_scales=ksf,
-                                       v_scales=vsf)
-        out = _post_attention(cfg, blk, hcur, attn)
-        return (out, kpf, vpf, ksf, vsf), None
-
-    window = jax.tree_util.tree_map(lambda a: a[layer_lo:layer_hi],
-                                    params["blocks"])
-    (x, kpf, vpf, ksf, vsf), _ = jax.lax.scan(
-        step, (x, kpf, vpf, ksf, vsf),
-        (window, jnp.arange(layer_lo, layer_hi, dtype=jnp.int32)))
-    k_pages, v_pages = kpf.reshape(k_pages.shape), vpf.reshape(v_pages.shape)
-    if quantized:
-        k_scales = ksf.reshape(k_scales.shape)
-        v_scales = vsf.reshape(v_scales.shape)
-    return x, k_pages, v_pages, k_scales, v_scales
 
 
 def forward_ragged(
@@ -627,75 +580,22 @@ def forward_ragged(
     use_pallas: str = "auto",
     k_scales: Optional[jnp.ndarray] = None,
     v_scales: Optional[jnp.ndarray] = None,
-    max_q_len: Optional[int] = None,  # static bound on per-row query len
-                                      # (engine: prefill_chunk)
-):
+    max_q_len: Optional[int] = None,  # static bound on a row's query len
+):                                    # (engine: prefill_chunk)
     """Serving forward over a RAGGED packed batch: prefill chunks and decode
-    steps of different rows ride ONE dispatch (tokens packed row-major on
-    the flat token axis, per-token ``row_ids`` naming each token's page
-    table line / kv length). Everything token-pointwise (norms, projections,
-    RoPE, MLP, head) is shape-agnostic and reuses the ``forward_paged``
-    building blocks verbatim — only the KV scatter and the attention need
-    the ragged metadata. MLA rides the same pack: the latent write reuses
-    ``write_kv_pages_ragged`` on the (c, k_pe) pair and the attention goes
-    through ``ragged_paged_mla_attention`` (round 16 — MLA configs get the
-    continuous-batching wins). Multi-LoRA rows stay gated out by the engine
-    (``lora_delta`` gathers adapters per batch ROW, and the packed batch
-    axis is 1).
-
-    Returns (logits [1, T, V] f32, k_pages, v_pages, k_scales, v_scales).
-    """
-    from rbg_tpu.ops.mla_attention import ragged_paged_mla_attention
-    from rbg_tpu.ops.ragged_paged_attention import (ragged_paged_attention,
-                                                    write_kv_pages_ragged)
-
+    steps of different rows ride ONE dispatch (tokens packed row-major on the
+    flat token axis, ``row_ids`` naming each token's page table line and kv
+    length). Only the KV scatter and the attention read the ragged metadata
+    (``_pool_attention``). No LoRA: ``lora_delta`` gathers adapters per batch
+    ROW and the packed batch axis is 1, so the engine gates such rows out.
+    Returns (logits [1, T, V] f32, k_pages, v_pages, k_scales, v_scales)."""
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
-    quantized = k_scales is not None
-
-    # Same flat-pool carry trick as forward_paged (see the comment there):
-    # each layer addresses its pages as ``layer·NP + table``.
-    L_, NP = k_pages.shape[0], k_pages.shape[1]
-    flat = lambda p: p.reshape((L_ * NP,) + p.shape[2:])
-    kpf, vpf = flat(k_pages), flat(v_pages)
-    ksf = flat(k_scales) if quantized else None
-    vsf = flat(v_scales) if quantized else None
-
-    def step(carry, xs):
-        hcur, kpf, vpf, ksf, vsf = carry
-        blk, li = xs
-        table = page_table + li * NP
-        with jax.named_scope("attention"):
-            if cfg.mla:
-                q_lat, q_pe, c, k_pe = _mla_qkv(cfg, blk, hcur, positions)
-                kpf, vpf, ksf, vsf = write_kv_pages_ragged(
-                    kpf, vpf, c[:, :, None, :], k_pe[:, :, None, :], table,
-                    row_ids, positions, token_mask, ksf, vsf)
-                attn_lat = ragged_paged_mla_attention(
-                    q_lat, q_pe, kpf, vpf, table, positions, kv_lens, row_ids,
-                    _mla_scale(cfg), use_pallas=use_pallas, c_scales=ksf,
-                    pe_scales=vsf, max_q_len=max_q_len)
-                attn = _mla_out(cfg, blk, attn_lat)
-            else:
-                q, k, vv = _qkv(cfg, blk, hcur, positions)
-                kpf, vpf, ksf, vsf = write_kv_pages_ragged(
-                    kpf, vpf, k, vv, table, row_ids, positions, token_mask,
-                    ksf, vsf)
-                attn = ragged_paged_attention(q, kpf, vpf, table, positions,
-                                              kv_lens, row_ids,
-                                              use_pallas=use_pallas,
-                                              k_scales=ksf, v_scales=vsf,
-                                              max_q_len=max_q_len)
-        out = _post_attention(cfg, blk, hcur, attn)
-        return (out, kpf, vpf, ksf, vsf), None
-
-    (x, kpf, vpf, ksf, vsf), _ = jax.lax.scan(
-        step, (x, kpf, vpf, ksf, vsf),
-        (params["blocks"], jnp.arange(L_, dtype=jnp.int32)))
-    k_pages, v_pages = kpf.reshape(k_pages.shape), vpf.reshape(v_pages.shape)
-    if quantized:
-        k_scales = ksf.reshape(k_scales.shape)
-        v_scales = vsf.reshape(v_scales.shape)
-    return _head(params, cfg, x), k_pages, v_pages, k_scales, v_scales
+    x, pool, _ = paged_layers(
+        params, cfg, x, (k_pages, v_pages, k_scales, v_scales),
+        PoolAddr(positions, token_mask, kv_lens, page_table, row_ids,
+                 max_q_len),
+        layers=(0, cfg.num_layers), use_pallas=use_pallas)
+    return (_head(params, cfg, x), *pool)
 
 
 def forward_train(

@@ -333,13 +333,11 @@ def paged_mla_attention_pallas_q(q_lat, q_pe, c_pages, pe_pages, page_table,
 #
 # Re-exported here because ``dispatch_pallas`` resolves every kernel name
 # against this module; the implementations live in
-# ragged_attention_kernel.py (block-ragged tile grid; the PR-7 token-grid
-# variants stay exported as the bench A/B baseline).
+# ragged_attention_kernel.py (block-ragged tile grid).
 
 from rbg_tpu.ops.pallas.ragged_attention_kernel import (  # noqa: E402,F401
     ragged_paged_attention_pallas,
     ragged_paged_attention_pallas_q,
-    ragged_paged_attention_pallas_tokengrid,
     ragged_paged_mla_attention_pallas,
     ragged_paged_mla_attention_pallas_q,
 )

@@ -42,10 +42,6 @@ masked MXU lanes (underfilled at small G anyway) for the page-streaming
 win, exactly the RPA paper's trade. Pure-decode batches never reach this
 kernel (the engine's fused multi-step path owns them).
 
-The PR-7 token-grid kernel is kept as ``*_tokengrid`` — it is the bench
-A/B baseline (``bench.py mixed`` re-runs old-grid vs block-ragged) and a
-second correctness reference for the tile math.
-
 Same family of int8 variants as the decode kernel: scales fold
 algebraically into scores/probs, pages feed the MXU as int8. The MLA
 (latent) ragged kernels live here too — same tiling over the ``c/pe``
@@ -432,149 +428,3 @@ def ragged_paged_mla_attention_pallas_q(q_lat, q_pe, c_pages, pe_pages,
                              c_scales[..., 0], pe_scales[..., 0],
                              page_table, q_positions, kv_lens, row_ids,
                              scale, interpret)
-
-
-# ---- PR-7 token-grid kernels (retained baseline) ----------------------------
-#
-# The round-1 grid: (T, P), one packed token per outer step — a prefill
-# row's pages stream once per token. Kept (not dispatched) as the bench
-# A/B baseline for the block-ragged grid and as a second correctness
-# reference; ``bench.py mixed`` interleaves it against the tile grid.
-
-
-def _ragged_kernel(
-    # scalar prefetch
-    page_table_ref,   # [R, P] int32 (SMEM)
-    kv_lens_ref,      # [R] int32 (SMEM)
-    row_ids_ref,      # [T] int32 (SMEM)
-    q_pos_ref,        # [T] int32 (SMEM)
-    # blocks
-    q_ref,            # [1, KV, G, hd] (VMEM) — the packed token t
-    k_ref,            # [1, page, KV, hd] — the page picked by index_map
-    v_ref,
-    out_ref,          # [1, KV, G, hd]
-    # scratch
-    m_ref,            # [KV, G, 1] running max
-    l_ref,            # [KV, G, 1] running denom
-    acc_ref,          # [KV, G, hd] running numerator
-    *,
-    ks_ref=None,      # int8 pools: [1, page, KV] f32 scales
-    vs_ref=None,
-):
-    t = pl.program_id(0)
-    p = pl.program_id(1)
-    num_p = pl.num_programs(1)
-    page = k_ref.shape[1]
-    quantized = ks_ref is not None
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # Ragged causal limit: within the live cache AND within this token's
-    # causal prefix (slot index == absolute position). Pad tokens carry
-    # q_position == -1 (the pack contract) → limit ≤ 0 → every page is
-    # skipped and the zero accumulators finalize to a zero output.
-    limit = jnp.minimum(kv_lens_ref[row_ids_ref[t]], q_pos_ref[t] + 1)
-
-    @pl.when(p * page < limit)
-    def _attend():
-        q = q_ref[0].astype(jnp.float32)                    # [KV, G, hd]
-        k = k_ref[0].astype(jnp.float32)                    # [page, KV, hd]
-        v = v_ref[0].astype(jnp.float32)
-        hd = q.shape[-1]
-
-        k_t = jnp.transpose(k, (1, 0, 2))                   # [KV, page, hd]
-        v_t = jnp.transpose(v, (1, 0, 2))
-        scores = jax.lax.dot_general(
-            q, k_t,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * (1.0 / (hd ** 0.5))                             # [KV, G, page]
-        if quantized:
-            ks_t = jnp.transpose(ks_ref[0], (1, 0))         # [KV, page]
-            scores = scores * ks_t[:, None, :]
-
-        token_idx = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, dimension=2)
-        scores = jnp.where(token_idx < limit, scores, _NEG_INF)
-
-        m_prev = m_ref[:]                                   # [KV, G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)                     # [KV, G, page]
-
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        pmat = probs
-        if quantized:
-            vs_t = jnp.transpose(vs_ref[0], (1, 0))         # [KV, page]
-            pmat = probs * vs_t[:, None, :]
-        pv = jax.lax.dot_general(
-            pmat, v_t,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                                   # [KV, G, hd]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-
-    @pl.when(p == num_p - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:], 1e-30)                # guard empty rows
-        out_ref[0] = (acc_ref[:] / denom).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _ragged_call(q, k_pages, v_pages, page_table, kv_lens, row_ids, q_pos,
-                 interpret=False):
-    """q: [T, KV, G, hd] packed; pages: [NP, page, KV, hd].
-    Returns [T, KV, G, hd]."""
-    T, KV, G, hd = q.shape
-    _, page, _, _ = k_pages.shape
-    P = page_table.shape[1]
-
-    pick = lambda t, p, table, lens, rows, qpos: (table[rows[t], p], 0, 0, 0)
-    fixed = lambda t, p, table, lens, rows, qpos: (t, 0, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(T, P),
-        in_specs=[
-            pl.BlockSpec((1, KV, G, hd), fixed),
-            pl.BlockSpec((1, page, KV, hd), pick),
-            pl.BlockSpec((1, page, KV, hd), pick),
-        ],
-        out_specs=pl.BlockSpec((1, KV, G, hd), fixed),
-        scratch_shapes=[
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        _ragged_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, KV, G, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(page_table, kv_lens, row_ids, q_pos, q, k_pages, v_pages)
-
-
-def ragged_paged_attention_pallas_tokengrid(q, k_pages, v_pages, page_table,
-                                            q_positions, kv_lens, row_ids,
-                                            interpret: bool = False):
-    """PR-7 token-grid variant of ``ragged_paged_attention_pallas`` —
-    bench baseline, not dispatched by the engine."""
-    _, T, H, hd = q.shape
-    KV = k_pages.shape[2]
-    G = H // KV
-    qg = q.reshape(T, KV, G, hd)
-    out = _ragged_call(qg, k_pages, v_pages,
-                       page_table.astype(jnp.int32),
-                       kv_lens.astype(jnp.int32),
-                       row_ids.astype(jnp.int32),
-                       q_positions.reshape(T).astype(jnp.int32),
-                       interpret=interpret)
-    return out.reshape(1, T, H, hd)

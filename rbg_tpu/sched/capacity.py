@@ -6,8 +6,7 @@ Reference analog: the informer-cache + no-deepcopy-lister hot path the Go
 controllers schedule against (``pkg/utils/client/no_deepcopy_lister.go``) —
 kube-scheduler itself keeps exactly this kind of incremental NodeInfo cache.
 Our ``_place`` used to list every pod and node per decision (O(pods) per pod
-placed), which made a 30-group create burst scheduler-backlog-bound
-(docs/benchmarks.md; VERDICT r1 item 6).
+placed), which made a 30-group create burst scheduler-backlog-bound.
 
 Consistency model: contributions are keyed by pod UID and *replaced* (never
 incremented), and each carries the pod's resourceVersion — a replace only

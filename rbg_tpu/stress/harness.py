@@ -309,9 +309,8 @@ def _fleet_curve_sampler(plane, stop, out: List[dict], interval_s: float):
 
 
 def _trimmed_spread(runs: List[float]) -> float:
-    """(max-min)/median after dropping one min and one max when n ≥ 4
-    (the bench.py estimator): one bimodal-throughput outlier must not
-    flunk an otherwise clean A/B."""
+    """(max-min)/median after dropping one min and one max when n ≥ 4:
+    one bimodal-throughput outlier must not flunk an otherwise clean A/B."""
     if len(runs) < 2:
         return 0.0
     s = sorted(runs)

@@ -36,8 +36,8 @@ Off by default: nothing is patched, zero overhead. Armed by
 ``RBG_JITWATCH=1`` (raise) or ``RBG_JITWATCH=warn`` (log + count, the
 stress-drill mode). Like RBG_RACETRACE, set the env var / call ``arm()``
 BEFORE warmup so the warmup set is recorded; ``rbg-tpu stress --jitwatch``
-and ``bench.py --jitwatch`` do exactly this and fold the verdict into a
-``zero_unwarmed_compiles`` invariant.
+does exactly this and folds the verdict into a ``zero_unwarmed_compiles``
+invariant.
 """
 
 from __future__ import annotations

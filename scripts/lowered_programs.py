@@ -1,0 +1,179 @@
+"""The lowered text of the programs that walk the paged layers, one file each.
+
+Run from the root of a checkout (it imports that checkout's ``rbg_tpu``), on
+the CPU, once in the parent's tree and once in the change's, then compare the
+two directories: a refactor of ``models/llama.py`` that leaves every file the
+same has changed no program.
+
+    python scripts/lowered_programs.py --out /root/scratch/lowered/change
+    diff -r /root/scratch/lowered/parent /root/scratch/lowered/change
+
+Nothing here runs a program, so nothing here is a timing.
+"""
+
+import argparse
+import base64
+import dataclasses
+import functools
+import os
+import re
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.getcwd())
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from rbg_tpu.engine import Engine, EngineConfig
+from rbg_tpu.engine import engine as E
+from rbg_tpu.engine.pd import DecodeWorker
+from rbg_tpu.engine.sampler import row_keys
+from rbg_tpu.models import config as presets
+from rbg_tpu.models import get_config
+
+I32 = jnp.int32
+TINY_KW = dict(page_size=8, num_pages=64, max_seq_len=128, max_batch=4,
+               prefill_chunk=16, enable_radix_cache=False)
+# benchmark/configs/mixtral-8x7b-v0.1.json: its widths, layers and server.
+CELL_KW = dict(page_size=16, num_pages=8192, max_seq_len=8192, max_batch=8,
+               prefill_chunk=256)
+CELL_T = 512
+
+
+def _lora_stack(eng):
+    rng = np.random.default_rng(0)
+    L = eng.mcfg.num_layers
+    adapter = {}
+    for tgt in ("wq", "wo", "w_gate"):
+        _, d_in, d_out = eng.params["blocks"][tgt].shape
+        adapter[tgt] = (rng.normal(size=(L, d_in, 4)).astype(np.float32),
+                        rng.normal(size=(L, 4, d_out)).astype(np.float32))
+    eng.load_lora("a", adapter, alpha=8.0)
+
+
+def programs(eng, S, T, lora=False, window=None):
+    """{file name: lowered text} of the engine's programs at ``max_batch``
+    rows and ``T`` packed tokens. ``S`` makes an abstract argument."""
+    cfg = eng.cfg
+    B, P, K = cfg.max_batch, cfg.max_pages_per_seq, cfg.multi_step
+    vec, pool = S((B,), I32), eng.cache
+    scales = (pool.k_scales, pool.v_scales)
+    kw = dict(lora=eng.lora_stack, lids=vec) if lora else {}
+    temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
+    tail = (row_keys(seeds, eng._sample_base, rids), jnp.asarray(temps),
+            jnp.asarray(ks), jnp.asarray(tps), jnp.asarray(mps))
+    out = {}
+    out["rbg_fused_decode"] = eng._get_decode_fn(
+        B, False, False, False, lora, False).lower(
+        eng.params, vec, vec, vec, S((B, P), I32), S((B, K), bool), vec,
+        pool.k_pages, pool.v_pages, *scales, *tail, **kw)
+    for name, Tq in (("rbg_paged_fwd", cfg.prefill_chunk),
+                     ("rbg_paged_fwd.decode", 1)):
+        out[name] = eng._get_fwd(B, Tq, lora).lower(
+            eng.params, S((B, Tq), I32), S((B, Tq), I32), S((B, Tq), bool),
+            vec, S((B, P), I32), pool.k_pages, pool.v_pages, *scales, **kw)
+    Kq = 5
+    out["rbg_spec_verify"] = eng._get_spec_fn(B, False, la=lora).lower(
+        eng.params, S((B, Kq), I32), S((B, Kq), I32), S((B, Kq), bool), vec,
+        S((B, P), I32), pool.k_pages, pool.v_pages, *scales, *tail, **kw)
+    if not lora:
+        out["rbg_ragged_fwd"] = eng._get_ragged_fn(B, T).lower(
+            eng.params, S((1, T), I32), S((1, T), I32), S((1, T), bool),
+            S((T,), I32), vec, S((B, P), I32), pool.k_pages, pool.v_pages,
+            *scales)
+    if window is not None:
+        D, L = eng.mcfg.hidden_size, eng.mcfg.num_layers
+        for lo, hi in ((0, 1), (1, L)):
+            out[f"rbg_pd_window.{lo}-{hi}"] = window._get_window_fn(
+                lo, hi, B).lower(
+                S((B, 1, D), eng.mcfg.jax_dtype), S((B, 1), I32),
+                S((B, 1), bool), vec, S((B, P), I32), pool.k_pages,
+                pool.v_pages, None, None)
+    return {k: _kernels_without_locations(v.as_text())
+            for k, v in out.items()}
+
+
+_BODY = re.compile(r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)(\\22)')
+
+
+def _kernels_without_locations(text: str) -> str:
+    """A Pallas kernel rides its custom call as serialised MLIR, source
+    paths and line numbers included, which differ between two checkouts of
+    the same kernel: put the kernel's text without them in its place."""
+    from jax._src.lib.mlir import ir
+
+    def plain(m):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            kernel = ir.Module.parse(base64.b64decode(m.group(2)))
+            asm = kernel.operation.get_asm(enable_debug_info=False)
+        return m.group(1) + "\n" + asm + m.group(3)
+
+    return _BODY.sub(plain, text)
+
+
+def tiny_cases():
+    S = jax.ShapeDtypeStruct
+    for case, model, extra in (("tiny", "tiny", {}),
+                               ("tiny-moe", "tiny-moe", {}),
+                               ("tiny-mla", "tiny-mla", {}),
+                               ("tiny-int8", "tiny", {"kv_dtype": "int8"}),
+                               ("tiny-moe-int8", "tiny-moe",
+                                {"kv_dtype": "int8"}),
+                               ("tiny-lora", "tiny", {})):
+        cfg = EngineConfig(model=model, use_pallas="never",
+                           **{**TINY_KW, **extra})
+        eng = Engine(cfg)
+        lora = case == "tiny-lora"
+        if lora:
+            _lora_stack(eng)
+        window = None
+        if not extra and not lora:      # the decode role refuses an int8 pool
+            window = DecodeWorker(cfg, params=eng.params)
+        yield case, programs(eng, S, 2 * cfg.prefill_chunk, lora, window)
+
+
+def cell_case():
+    """The judged cell's programs, lowered for one chip of a described v5e
+    with the Pallas kernels in (as ``tests/test_chip_compile.py`` does):
+    parameters and pool are shapes, so nothing is allocated."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    on = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=chip),
+        tree)
+    init, create = E.init_params, E.PagedKVCache.create
+    E.init_params = lambda m, key: on(jax.eval_shape(lambda: init(m, key)))
+    E.PagedKVCache.create = staticmethod(
+        lambda *a, **kw: on(jax.eval_shape(lambda: create(*a, **kw))))
+    presets._PRESETS["cell"] = dataclasses.replace(
+        get_config("mixtral-8x7b"), name="cell", num_layers=3)
+    eng = Engine(EngineConfig(model="cell", use_pallas="always", **CELL_KW))
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    progs = programs(eng, S, CELL_T)
+    E.init_params, E.PagedKVCache.create = init, staticmethod(create)
+    return "cell-mixtral-3l-v5e", progs
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--out", required=True)
+    args = ap.parse_args()
+    os.makedirs(args.out, exist_ok=True)
+    for case, progs in [*tiny_cases(), cell_case()]:
+        for name, text in progs.items():
+            path = os.path.join(args.out, f"{case}.{name}.txt")
+            with open(path, "w") as f:
+                f.write(text)
+            print(f"{path}: {len(text.splitlines())} lines", flush=True)
+
+
+if __name__ == "__main__":
+    main()

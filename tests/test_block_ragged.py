@@ -14,7 +14,6 @@ from rbg_tpu.ops.mla_attention import (paged_mla_attention_xla,
 from rbg_tpu.ops.paged_attention import quantize_kv
 from rbg_tpu.ops.pallas.ragged_attention_kernel import (
     Q_TILE, ragged_paged_attention_pallas, ragged_paged_attention_pallas_q,
-    ragged_paged_attention_pallas_tokengrid,
     ragged_paged_mla_attention_pallas, ragged_paged_mla_attention_pallas_q)
 from rbg_tpu.ops.ragged_paged_attention import ragged_paged_attention_xla
 
@@ -115,21 +114,6 @@ def test_gqa_int8_straddling_tiles():
                                           interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
-
-
-def test_tokengrid_matches_block_ragged():
-    """The retained PR-7 token-grid kernel (the bench's A/B baseline)
-    still agrees with the block-ragged kernel on a mixed pack."""
-    rng = np.random.RandomState(15)
-    k, v = _pool(rng)
-    q_specs = [(5, 15), (1, 21), (1, 4), (3, 40)]
-    q, table, q_pos, kv_lens, row_ids = _pack(rng, q_specs)
-    new = ragged_paged_attention_pallas(q, k, v, table, q_pos, kv_lens,
-                                        row_ids, interpret=True)
-    old = ragged_paged_attention_pallas_tokengrid(
-        q, k, v, table, q_pos, kv_lens, row_ids, interpret=True)
-    np.testing.assert_allclose(np.asarray(new), np.asarray(old),
-                               rtol=1e-5, atol=1e-5)
 
 
 # ---- MLA ragged latent path ----

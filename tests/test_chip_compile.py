@@ -112,11 +112,6 @@ def test_gqa_kernel_compiles_at_the_cells_table_width(chip, kernel):
     _compiles_with_kernel(getattr(K, kernel), *args)
 
 
-def test_tokengrid_kernel_compiles_for_v5e(chip):
-    args = _gqa_args(chip, "llama3-8b", quantized=False, ragged=True)
-    _compiles_with_kernel(K.ragged_paged_attention_pallas_tokengrid, *args)
-
-
 @pytest.mark.parametrize("kernel", [
     "paged_mla_attention_pallas", "paged_mla_attention_pallas_q",
     "ragged_paged_mla_attention_pallas",

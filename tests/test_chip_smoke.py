@@ -64,7 +64,7 @@ def test_cache_defaults_to_a_fixed_place_in_the_checkout():
 def test_no_other_code_sets_a_cache_directory():
     hits = subprocess.run(
         ["grep", "-rlE", "jax_compilation_cache_dir|compilation_cache.set",
-         "rbg_tpu", "bench.py", "chip_smoke.py", "__graft_entry__.py"],
+         "rbg_tpu", "chip_smoke.py", "__graft_entry__.py"],
         cwd=REPO, capture_output=True, text=True).stdout.split()
     assert hits == ["rbg_tpu/utils/chipenv.py"]
 
@@ -98,11 +98,11 @@ def test_executor_pins_one_chip_pods_apart():
 
 
 def test_importing_the_engine_starts_no_backend():
-    """A parent that launches chip-holding children (chip_smoke.py,
-    bench_slo) imports the engine package for its wire helpers; that must
-    not take the chip away from them."""
+    """A parent that launches chip-holding children (chip_smoke.py)
+    imports the engine package for its wire helpers; that must not take
+    the chip away from them."""
     proc = _python(
-        "import rbg_tpu.engine, rbg_tpu.engine.bench_slo, chip_smoke; "
+        "import rbg_tpu.engine, chip_smoke; "
         "from jax._src import xla_bridge; "
         "print(xla_bridge.backends_are_initialized())",
         scrubbed_cpu_env())

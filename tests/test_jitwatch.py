@@ -236,3 +236,39 @@ def test_programs_catalog_names_are_stamped_constants():
                  if k.startswith("PROGRAM_") and isinstance(v, str)}
     assert constants == set(names.PROGRAMS)
     assert all(p.startswith("rbg_") for p in names.PROGRAMS)
+
+
+# ---- the engine's warmers against the sentry --------------------------------
+
+
+@pytest.mark.parametrize("model", ["tiny", "tiny-mla"])
+def test_warm_engine_serves_a_mixed_trace_without_a_compile(watch, model):
+    """After ``warm_ragged`` / ``warm_decode`` / ``warm_join_windows`` /
+    ``warm_samplers`` (what ``EngineService.warmup`` runs) a trace of mixed
+    prompt lengths, with arrivals joining a running batch, compiles no
+    cataloged program. On the chip the benchmark reads the same count as
+    ``setup.compiles_in_window``."""
+    from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
+    watch.arm()
+    eng = Engine(EngineConfig(
+        model=model, page_size=8, num_pages=128, max_batch=4,
+        max_seq_len=128, prefill_chunk=16, enable_radix_cache=False,
+        multi_step=4, use_pallas="never"))
+    warmed = (eng.warm_ragged() + eng.warm_decode()
+              + eng.warm_join_windows() + eng.warm_samplers())
+    assert warmed > 0 and watch.warmup_complete() >= warmed
+
+    sp = SamplingParams(max_new_tokens=9)
+    prompts = [list(range(1, 1 + n)) for n in (5, 40, 17, 3, 33, 12)]
+    ids, done = [], {}
+    for step in range(400):
+        if prompts and step % 3 == 0:       # joins land mid-decode
+            ids.append(eng.add_request(prompts.pop(), sp))
+        for ev in eng.step():
+            done.setdefault(ev.request_id, []).append(ev.token)
+        if not prompts and not eng.has_work():
+            break
+    assert sorted(done) == sorted(ids)
+    assert all(len(toks) == 9 for toks in done.values())
+    assert watch.violations() == []
+    assert watch.counters()["rbg_jit_unwarmed_compiles_total"] == 0.0

@@ -84,6 +84,20 @@ def test_schema_grammar_features():
     assert _full(g, "{}")
 
 
+@pytest.mark.parametrize("keyword,value", [
+    ("minimum", 3), ("multipleOf", 2), ("format", "date"),
+    ("uniqueItems", True)])
+def test_schema_grammar_refuses_keywords_it_does_not_enforce(keyword, value):
+    """Accepted keywords are a whitelist: a constraint let through
+    unenforced would emit output the client's own schema rejects."""
+    schema = {"type": "object",
+              "properties": {"n": {"type": "integer", keyword: value}}}
+    with pytest.raises(ValueError, match="unrecognized constraint keyword"):
+        JsonSchemaGrammar(schema)
+    JsonSchemaGrammar({"type": "object", "title": "annotations pass",
+                       "properties": {"n": {"type": "integer"}}})
+
+
 def test_schema_grammar_rejects_unsupported():
     for bad in ({"$ref": "#/x"}, {"allOf": []}, {"type": "frob"},
                 {"enum": []}, {"enum": [{"x": 1}]},

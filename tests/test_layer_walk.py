@@ -1,0 +1,153 @@
+"""The one walk over the paged layers (``models/llama.py::paged_layers``).
+
+A chain of layer windows is the whole walk, pool included: the layer-sliced
+decode admission (``engine/pd.py``) rests on it. And the
+programs the engine jits carry the scope names the benchmark's
+``device.*_share`` metrics read, on the operations that carry them.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from rbg_tpu.engine import Engine, EngineConfig
+from rbg_tpu.engine.kvcache import PagedKVCache
+from rbg_tpu.engine.pd import DecodeWorker
+from rbg_tpu.models import get_config, init_params
+from rbg_tpu.models.llama import PoolAddr, paged_layers
+
+I32 = jnp.int32
+ROWS, PAGE, PAGES_PER_ROW = 3, 8, 2
+
+
+def _addr(T, start, packed):
+    """``ROWS`` rows of ``T`` tokens each from position ``start``, as a
+    batch of rows or packed row-major on one token axis."""
+    pos = jnp.broadcast_to(start + jnp.arange(T, dtype=I32), (ROWS, T))
+    table = jnp.arange(ROWS * PAGES_PER_ROW, dtype=I32).reshape(ROWS, -1)
+    lens = jnp.full((ROWS,), start + T, I32)
+    if not packed:
+        return PoolAddr(pos, jnp.ones((ROWS, T), bool), lens, table)
+    return PoolAddr(pos.reshape(1, -1), jnp.ones((1, ROWS * T), bool), lens,
+                    table, jnp.repeat(jnp.arange(ROWS, dtype=I32), T), T)
+
+
+def _close(a, b):
+    # int8 pages may round a last-bit difference to the next code.
+    atol = 1 if a.dtype == np.int8 else 1e-6
+    np.testing.assert_allclose(a.astype(np.float32), b.astype(np.float32),
+                               rtol=1e-5, atol=atol)
+
+
+@pytest.mark.parametrize("preset,quantize,packed", [
+    ("tiny", False, False), ("tiny-moe", False, False),
+    ("tiny-mla", False, False), ("tiny", True, False),
+    ("tiny", False, True)],
+    ids=["dense", "moe", "mla", "int8-pool", "packed"])
+def test_chain_of_layer_windows_is_the_whole_walk(preset, quantize, packed):
+    """Windows of two layers or more run the whole walk's loop body and
+    agree with it to the bit. A window of ONE layer is a loop of one trip,
+    which XLA unrolls and fuses with its surroundings: on the CPU a decode
+    step then differs in the last bit (the token streams of
+    ``test_kvtransfer.py``'s layer-sliced admissions do not)."""
+    cfg = dataclasses.replace(get_config(preset), num_layers=4)
+    params = init_params(cfg, jax.random.key(1))
+    cache = PagedKVCache.create(cfg, ROWS * PAGES_PER_ROW, PAGE,
+                                quantize=quantize)
+    pool = (cache.k_pages, cache.v_pages, cache.k_scales, cache.v_scales)
+
+    def walk(x, pool, addr, windows):
+        for lo, hi in windows:
+            # addr is closed over: its max_q_len is static.
+            x, pool, _ = jax.jit(
+                lambda x, pool, lo=lo, hi=hi: paged_layers(
+                    params, cfg, x, pool, addr, layers=(lo, hi),
+                    use_pallas="never"))(x, pool)
+        return x, pool
+
+    chains = {"whole": [(0, 4)], "exact": [(0, 2), (2, 4)],
+              "one-layer": [(0, 1), (1, 4)]}
+    pools = dict.fromkeys(chains, pool)
+    # A prefill chunk into the empty pool, then a decode step that reads it.
+    for T, start in ((5, 0), (1, 5)):
+        shape = (1, ROWS * T) if packed else (ROWS, T)
+        x = jax.random.normal(jax.random.key(T), shape + (cfg.hidden_size,),
+                              cfg.jax_dtype)
+        addr = _addr(T, start, packed)
+        out = {}
+        for name, windows in chains.items():
+            out[name], pools[name] = walk(x, pools[name], addr, windows)
+        assert np.isfinite(np.asarray(out["whole"], np.float32)).all()
+        for name, same in (("exact", np.testing.assert_array_equal),
+                           ("one-layer", _close)):
+            same(np.asarray(out[name]), np.asarray(out["whole"]))
+            for a, b in zip(pools[name], pools["whole"]):
+                assert (a is None) == (b is None)
+                if a is not None:
+                    same(np.asarray(a), np.asarray(b))
+    assert np.asarray(pools["whole"][0]).any()      # the steps did write
+    assert (pools["whole"][2] is not None) == quantize
+
+
+# ---- the scope names the benchmark reads device time by ---------------------
+
+
+def _scopes_of_dots(lowered) -> list:
+    """For each ``dot_general`` of a lowered program, the named scopes on
+    its path (``jit(f)/while/body/attention/dot_general`` -> attention)."""
+    text = lowered.as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    dots = re.findall(r'stablehlo\.dot_general.*loc\((#loc\d+)\)', text)
+    assert dots
+    known = {"attention", "moe", "mlp", "lm_head", "sampler"}
+    return [sorted(known & set(names[d].split("/"))) for d in dots]
+
+
+@pytest.fixture(scope="module")
+def moe_engine():
+    cfg = EngineConfig(model="tiny-moe", page_size=8, num_pages=64,
+                       max_seq_len=128, max_batch=4, prefill_chunk=16,
+                       enable_radix_cache=False, use_pallas="never")
+    return Engine(cfg)
+
+
+def _lower(eng, program):
+    from rbg_tpu.engine.sampler import row_keys
+    S = jax.ShapeDtypeStruct
+    cfg, pool = eng.cfg, eng.cache
+    B, P, T = cfg.max_batch, cfg.max_pages_per_seq, 2 * cfg.prefill_chunk
+    vec, table = S((B,), I32), S((B, P), I32)
+    if program == "decode":
+        temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
+        return eng._get_decode_fn(B, False, False, False, False, False).lower(
+            eng.params, vec, vec, vec, table, S((B, cfg.multi_step), bool),
+            vec, pool.k_pages, pool.v_pages, None, None,
+            row_keys(seeds, eng._sample_base, rids), jnp.asarray(temps),
+            jnp.asarray(ks), jnp.asarray(tps), jnp.asarray(mps))
+    if program == "ragged":
+        return eng._get_ragged_fn(B, T).lower(
+            eng.params, S((1, T), I32), S((1, T), I32), S((1, T), bool),
+            S((T,), I32), vec, table, pool.k_pages, pool.v_pages, None, None)
+    worker = DecodeWorker(cfg, params=eng.params)
+    return worker._get_window_fn(0, 1, B).lower(
+        S((B, 1, eng.mcfg.hidden_size), eng.mcfg.jax_dtype), S((B, 1), I32),
+        S((B, 1), bool), vec, table, pool.k_pages, pool.v_pages, None, None)
+
+
+@pytest.mark.parametrize("program,want", [
+    ("decode", {"attention", "moe", "lm_head"}),
+    ("ragged", {"attention", "moe", "lm_head"}),
+    ("window", {"attention", "moe"})])
+def test_every_dot_of_a_step_program_sits_in_one_named_scope(
+        moe_engine, program, want):
+    """``device.attention_share``, ``device.moe_share`` and
+    ``device.lm_head_share`` sum a trace's device time by these names: a
+    dot outside every scope, or inside two, is time counted nowhere or
+    twice."""
+    scopes = _scopes_of_dots(_lower(moe_engine, program))
+    assert all(len(s) == 1 for s in scopes), scopes
+    assert {s[0] for s in scopes} == want

@@ -330,7 +330,11 @@ def test_controller_mid_flip_restart_resumes_from_annotations():
 
 
 def test_controller_autoscaler_conflict_backs_off():
-    script = {"fresh": True, "prefill_decode_ratio": 12.0, "judged": 20}
+    # In the deadband until the foreign write has landed: a controller
+    # that already wants the flip can start it before the write (0.1 s of
+    # stabilization against this thread's scheduling), and no conflict is
+    # ever counted.
+    script = {"fresh": True, "prefill_decode_ratio": 4.0, "judged": 20}
     plane, gt = _mk_plane(script)
     conflicts0 = REGISTRY.counter(names.TOPOLOGY_CONFLICTS_TOTAL,
                                   group=GROUP)
@@ -348,6 +352,7 @@ def test_controller_autoscaler_conflict_backs_off():
             a.metadata.annotations[C.ANN_AUTOSCALE_LAST_WRITE] = "1"
             return True
         plane.store.mutate("ScalingAdapter", "default", sa_name, foreign)
+        script["prefill_decode_ratio"] = 12.0
         plane.wait_for(
             lambda: REGISTRY.counter(names.TOPOLOGY_CONFLICTS_TOTAL,
                                      group=GROUP) > conflicts0,
@@ -399,7 +404,9 @@ def test_controller_refuses_infeasible_flip_bounds():
     min_replicas > 0 can never drain; target capped under its plan) must
     refuse the flip UP FRONT — a visible retriable HOLD, never a
     permanent mid-flip wedge."""
-    script = {"fresh": True, "prefill_decode_ratio": 12.0, "judged": 20}
+    # In the deadband until the bounds are pinned (the same race as in
+    # test_controller_autoscaler_conflict_backs_off).
+    script = {"fresh": True, "prefill_decode_ratio": 4.0, "judged": 20}
     plane, gt = _mk_plane(script)
     holds0 = REGISTRY.counter(names.TOPOLOGY_HOLDS_TOTAL, group=GROUP,
                               reason="infeasible")
@@ -414,6 +421,7 @@ def test_controller_refuses_infeasible_flip_bounds():
             a.spec.min_replicas = 1
             return True
         plane.store.mutate("ScalingAdapter", "default", sa_name, pin_min)
+        script["prefill_decode_ratio"] = 12.0
         plane.wait_for(
             lambda: REGISTRY.counter(names.TOPOLOGY_HOLDS_TOTAL,
                                      group=GROUP,

@@ -343,7 +343,7 @@ def child_reference(args) -> None:
         eng.cache.v_pages, None, None).compile()
     temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], R)
     vec = S((R,), i32)
-    decode = eng._get_decode_fn(R, False, False, False, False, False).lower(
+    decode = eng._get_decode_fn(R, False, False).lower(
         eng.params, vec, vec, vec, S((R, P), i32),
         S((R, cfg.multi_step), bool), vec, eng.cache.k_pages,
         eng.cache.v_pages, None, None,

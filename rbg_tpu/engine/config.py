@@ -179,6 +179,13 @@ class SamplingParams:
                 or self.presence_penalty != 0.0
                 or self.frequency_penalty != 0.0)
 
+    def needs_sort(self) -> bool:
+        """Whether this row makes ``sampler.sample`` sort the vocabulary:
+        it samples, and through top-k, top-p or min-p (the predicates of
+        the sampler's ``lax.cond``s, known here without a device read)."""
+        return self.temperature > 0 and (
+            self.top_k > 0 or self.top_p < 1.0 or self.min_p > 0.0)
+
     def validate(self) -> None:
         if self.max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")

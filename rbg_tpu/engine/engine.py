@@ -250,7 +250,12 @@ class Engine:
                         # Decode steps that visited hit experts only
                         # (llama._moe_mlp_hit): experts x layers a step,
                         # and how many of them the step read.
-                        "moe_expert_slots": 0, "moe_experts_visited": 0}
+                        "moe_expert_slots": 0, "moe_experts_visited": 0,
+                        # Runs of ``sample`` as dispatched (a fused window
+                        # is one a step), and those of them in which some
+                        # sampling row set top-k, top-p or min-p: the ones
+                        # that sort the vocabulary.
+                        "sampler_steps": 0, "sampler_sort_steps": 0}
         # The step being run: when each phase last began and which one
         # is running (``_Phase``), and what the step first dispatched
         # (``_note_dispatch``).
@@ -1055,14 +1060,14 @@ class Engine:
             return 0   # the window never shortens (see _decode_window)
         P = self.cfg.max_pages_per_seq
         n = 0
-        for (B, pen, lp, tpmp, la, gr, K) in list(self._dec_fn_cache):
+        for (B, pen, lp, la, gr, K) in list(self._dec_fn_cache):
             if K == 1 or pen or lp or la or gr:
                 continue
-            if (B, pen, lp, tpmp, la, gr, 1) in self._dec_fn_cache:
+            if (B, pen, lp, la, gr, 1) in self._dec_fn_cache:
                 continue
             temps, ks, tps, mps, seeds, rids, _, _, _ = \
                 self._sampling_rows([], B)
-            fn = self._get_decode_fn(B, pen, lp, tpmp, la, gr, K=1)
+            fn = self._get_decode_fn(B, pen, lp, la, gr, K=1)
             # mask all-False: write_ok is False everywhere, so no KV slot
             # is written and pos/kvl never advance — the donated pool
             # buffers round-trip unchanged (tok/pos/kvl/limit are
@@ -1084,8 +1089,8 @@ class Engine:
 
     def warm_decode(self) -> int:
         """Pre-compile the PLAIN fused decode program (no penalties /
-        logprobs / LoRA / grammar) for every decode bucket × top-p
-        variant at the full multi_step window. The jitwatch sentry
+        logprobs / LoRA / grammar) for every decode bucket at the full
+        multi_step window. The jitwatch sentry
         surfaced this gap: warm_ragged covers the unified forward and
         warm_join_windows the K=1 variants, but the full-window decode
         program itself compiled lazily on the first pure-decode batch —
@@ -1102,38 +1107,35 @@ class Engine:
         buckets = sorted({self._bucket(b)
                           for b in range(1, self.cfg.max_batch + 1)})
         for B in buckets:
-            for tpmp in (False, True):
-                if (B, False, False, tpmp, False, False, K) \
-                        in self._dec_fn_cache:
-                    continue
-                temps, ks, tps, mps, seeds, rids, _, _, _ = \
-                    self._sampling_rows([], B)
-                fn = self._get_decode_fn(B, False, False, tpmp, False,
-                                         False, K=K)
-                # mask all-False, [B, 1] as _build_decode_state makes it (any
-                # other shape is another program, and this one would compile
-                # mid-serving): no KV slot is written and pos/kvl never
-                # advance — the donated pool buffers round-trip unchanged
-                # (see warm_join_windows).
-                *_, kp, vp, ksc, vsc, _, _ = fn(
-                    self.params, jnp.zeros(B, jnp.int32),
-                    jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
-                    jnp.zeros((B, P), jnp.int32), jnp.zeros((B, 1), bool),
-                    jnp.zeros(B, jnp.int32),
-                    self.cache.k_pages, self.cache.v_pages,
-                    self.cache.k_scales, self.cache.v_scales,
-                    row_keys(seeds, self._sample_base, rids),
-                    jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
-                    jnp.asarray(mps))
-                self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                          k_scales=ksc, v_scales=vsc)
-                n += 1
+            if (B, False, False, False, False, K) in self._dec_fn_cache:
+                continue
+            temps, ks, tps, mps, seeds, rids, _, _, _ = \
+                self._sampling_rows([], B)
+            fn = self._get_decode_fn(B, False, False, False, False, K=K)
+            # mask all-False, [B, 1] as _build_decode_state makes it (any
+            # other shape is another program, and this one would compile
+            # mid-serving): no KV slot is written and pos/kvl never
+            # advance — the donated pool buffers round-trip unchanged
+            # (see warm_join_windows).
+            *_, kp, vp, ksc, vsc, _, _ = fn(
+                self.params, jnp.zeros(B, jnp.int32),
+                jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
+                jnp.zeros((B, P), jnp.int32), jnp.zeros((B, 1), bool),
+                jnp.zeros(B, jnp.int32),
+                self.cache.k_pages, self.cache.v_pages,
+                self.cache.k_scales, self.cache.v_scales,
+                row_keys(seeds, self._sample_base, rids),
+                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
+                jnp.asarray(mps))
+            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
+                                      k_scales=ksc, v_scales=vsc)
+            n += 1
         return n
 
     def warm_samplers(self) -> int:
         """Pre-compile the host-path sampler (prefill finish + unified
-        emission) for every sample-row bucket × top-p variant. One jitted
-        program per (pen, lp, tpmp) — but XLA compiles per SHAPE under
+        emission) for every sample-row bucket. One jitted
+        program per (pen, lp) — but XLA compiles per SHAPE under
         that wrapper, so each bucket is its own compile; a first-hit
         mid-serving stalls the step exactly like an unwarmed forward.
         Penalties/logprobs variants stay lazy (warm_join_windows
@@ -1145,17 +1147,16 @@ class Engine:
         buckets = sorted({self._bucket(b)
                           for b in range(1, self.cfg.max_batch + 1)})
         for B in buckets:
-            for tpmp in (False, True):
-                temps, ks, tps, mps, seeds, rids, _, _, _ = \
-                    self._sampling_rows([], B)
-                keys = step_keys(row_keys(seeds, self._sample_base, rids),
-                                 jnp.zeros(B, jnp.int32))
-                fn = self._get_sampler(False, False, tpmp)
-                toks, _ = fn(jnp.zeros((B, V), jnp.float32), keys,
-                             jnp.asarray(temps), jnp.asarray(ks),
-                             jnp.asarray(tps), jnp.asarray(mps))
-                toks.block_until_ready()
-                n += 1
+            temps, ks, tps, mps, seeds, rids, _, _, _ = \
+                self._sampling_rows([], B)
+            keys = step_keys(row_keys(seeds, self._sample_base, rids),
+                             jnp.zeros(B, jnp.int32))
+            toks, _ = self._get_sampler(False, False)(
+                jnp.zeros((B, V), jnp.float32), keys,
+                jnp.asarray(temps), jnp.asarray(ks),
+                jnp.asarray(tps), jnp.asarray(mps))
+            toks.block_until_ready()
+            n += 1
         return n
 
     def _grow_decode_pages(self, rows: List[Request]) -> None:
@@ -1313,8 +1314,9 @@ class Engine:
         idx = np.asarray([i for _, i, _, _ in sample_rows] + [0] * pad,
                          np.int32)
         sel = logits[0][jnp.asarray(idx)]                   # [Bs, V]
-        temps, ks, tps, mps, seeds, rids, pen, lp, tpmp = \
+        temps, ks, tps, mps, seeds, rids, pen, lp, sorts = \
             self._sampling_rows(reqs, Bs)
+        self._note_sampler(sorts)
         key_pos = np.zeros(Bs, np.int32)
         for n, (_, _, kpos, _) in enumerate(sample_rows):
             key_pos[n] = kpos
@@ -1337,7 +1339,7 @@ class Engine:
             for n, req in enumerate(reqs):
                 np.add.at(oc[n], np.asarray(req.output, np.int64), 1)
             args += [pmask, jnp.asarray(oc), rep, pres, freq]
-        return self._get_sampler(pen, lp, tpmp)(*args)
+        return self._get_sampler(pen, lp)(*args)
 
     # ---- prefill ----
 
@@ -1407,8 +1409,9 @@ class Engine:
         tok_idx = np.asarray([j for _, j, _ in finishing] + [0] * pad, np.int32)
         sel = logits[jnp.asarray(row_idx), jnp.asarray(tok_idx)]  # [Bs, V]
         reqs = [req for _, _, req in finishing]
-        temps, ks, tps, mps, seeds, rids, pen, lp, tpmp = \
+        temps, ks, tps, mps, seeds, rids, pen, lp, sorts = \
             self._sampling_rows(reqs, Bs)
+        self._note_sampler(sorts)
         poss = np.zeros(Bs, np.int32)
         for n, req in enumerate(reqs):
             poss[n] = req.seq_len  # position of the token being sampled
@@ -1430,13 +1433,15 @@ class Engine:
             # _penalty_rows's oc_base).
             pmask, oc_base, rep, pres, freq = self._penalty_rows(reqs, Bs)
             args += [pmask, jnp.asarray(oc_base), rep, pres, freq]
-        toks, lps = self._get_sampler(pen, lp, tpmp)(*args)
+        toks, lps = self._get_sampler(pen, lp)(*args)
         return toks, lps, reqs
 
     def _sampling_rows(self, reqs, B: int):
         """Per-row sampling arrays + static variant flags for a batch —
         the ONE gather shared by prefill finish, fused decode build, and
-        the speculative verify (a new sampling knob lands here once)."""
+        the speculative verify (a new sampling knob lands here once).
+        The last value is no variant: whether ``sample`` will sort the
+        vocabulary for this batch (``_note_sampler`` counts it)."""
         temps = np.zeros(B, np.float32)
         ks = np.zeros(B, np.int32)
         tps = np.ones(B, np.float32)
@@ -1450,9 +1455,15 @@ class Engine:
             seeds[i], rids[i] = sp.seed, r.id
         pen = any(r.sampling.needs_penalties() for r in reqs)
         lp = any(r.sampling.logprobs for r in reqs)
-        tpmp = any(r.sampling.top_p < 1.0 or r.sampling.min_p > 0.0
-                   for r in reqs)
-        return temps, ks, tps, mps, seeds, rids, pen, lp, tpmp
+        sorts = any(r.sampling.needs_sort() for r in reqs)
+        return temps, ks, tps, mps, seeds, rids, pen, lp, sorts
+
+    def _note_sampler(self, sorts: bool, steps: int = 1) -> None:
+        """Count ``steps`` runs of ``sample`` as dispatched, and those of
+        them in which it sorts (``_sampling_rows``' last value)."""
+        self.metrics["sampler_steps"] += steps
+        if sorts:
+            self.metrics["sampler_sort_steps"] += steps
 
     def _lora_rows(self, reqs, B: int):
         """(lora_ids [B] or None): None when no row uses an adapter —
@@ -1492,8 +1503,8 @@ class Engine:
                 jnp.asarray(pres), jnp.asarray(freq))
 
     # hot_path
-    def _get_sampler(self, pen: bool, lp: bool, tpmp: bool = True):
-        fn = self._samplers.get((pen, lp, tpmp))
+    def _get_sampler(self, pen: bool, lp: bool):
+        fn = self._samplers.get((pen, lp))
         if fn is None:
             if pen:
                 def f(sel, keys, temps, ks, tps, mps, pmask, ocounts,
@@ -1501,14 +1512,14 @@ class Engine:
                     return sample(sel, keys, temps, ks, tps, mps,
                                   prompt_mask=pmask, out_counts=ocounts,
                                   rep=rep, pres=pres, freq=freq,
-                                  want_logprobs=lp, use_top_p_min_p=tpmp)
+                                  want_logprobs=lp)
             else:
                 def f(sel, keys, temps, ks, tps, mps):
                     return sample(sel, keys, temps, ks, tps, mps,
-                                  want_logprobs=lp, use_top_p_min_p=tpmp)
+                                  want_logprobs=lp)
             f.__name__ = PROGRAM_SAMPLER   # jitwatch catalog name
             fn = jax.jit(f)
-            self._samplers[(pen, lp, tpmp)] = fn
+            self._samplers[(pen, lp)] = fn
         return fn
 
     # ---- decode ----
@@ -1595,8 +1606,8 @@ class Engine:
         return K
 
     def _get_decode_fn(self, B: int, pen: bool, lp: bool,
-                       tpmp: bool = True, la: bool = False,
-                       gr: bool = False, K: Optional[int] = None):
+                       la: bool = False, gr: bool = False,
+                       K: Optional[int] = None):
         """One fused jitted program per (decode bucket, penalties-active,
         logprobs-active, grammar-active): a lax.scan window of
         ``multi_step`` iterations, each = forward + on-device sampling +
@@ -1619,7 +1630,7 @@ class Engine:
         mirroring ``_emit``'s keep-state-on-EOS bookkeeping."""
         if K is None:
             K = self.cfg.multi_step
-        fn = self._dec_fn_cache.get((B, pen, lp, tpmp, la, gr, K))
+        fn = self._dec_fn_cache.get((B, pen, lp, la, gr, K))
         if fn is not None:
             return fn
         import functools
@@ -1660,7 +1671,7 @@ class Engine:
                 # ``pos`` here would replay that exact Gumbel noise.
                 toks, lps = sample(lg, step_keys(keys, pos + 1),
                                    temps, ks, tps, mps, want_logprobs=lp,
-                                   use_top_p_min_p=tpmp, **pkw)
+                                   **pkw)
                 active = write_ok[:, 0]
                 if pen:
                     oc = oc.at[jnp.arange(oc.shape[0]), toks].add(
@@ -1695,7 +1706,7 @@ class Engine:
             donate.append(17)  # ocounts
         fused.__name__ = PROGRAM_FUSED_DECODE   # jitwatch catalog name
         fn = jax.jit(fused, donate_argnums=tuple(donate))
-        self._dec_fn_cache[(B, pen, lp, tpmp, la, gr, K)] = fn
+        self._dec_fn_cache[(B, pen, lp, la, gr, K)] = fn
         return fn
 
     def _build_decode_state(self, batch: List[Request]) -> dict:
@@ -1707,7 +1718,7 @@ class Engine:
         mask = np.zeros((B, 1), bool)
         limit = np.zeros(B, np.int32)
         table = np.zeros((B, P), np.int32)
-        temps, ks, tps, mps, seeds, rids, pen, lp, tpmp = \
+        temps, ks, tps, mps, seeds, rids, pen, lp, sorts = \
             self._sampling_rows(batch, B)
         for i, r in enumerate(batch):
             tok[i] = r.last_token
@@ -1719,7 +1730,7 @@ class Engine:
         lids = self._lora_rows(batch, B)
         st = {
             "rows": list(batch), "B": B, "pen": pen, "lp": lp,
-            "tpmp": tpmp, "lids": lids,
+            "sorts": sorts, "lids": lids,
             "tok": jnp.asarray(tok), "pos": jnp.asarray(pos),
             "kvl": jnp.asarray(kvl), "mask": jnp.asarray(mask),
             "limit": jnp.asarray(limit),
@@ -1786,9 +1797,9 @@ class Engine:
         with _Phase(self, _DISPATCH):
             self._note_dispatch("decode", len(batch), len(batch) * K,
                                 st["B"], st["B"] * K)
+            self._note_sampler(st["sorts"], K)
             fn = self._get_decode_fn(st["B"], st["pen"], st["lp"],
-                                     st["tpmp"], st["lids"] is not None,
-                                     st["gr"], K=K)
+                                     st["lids"] is not None, st["gr"], K=K)
             kw = {}
             if st["pen"]:
                 kw.update(pmask=st["pmask"], ocounts=st["ocounts"],
@@ -1921,9 +1932,9 @@ class Engine:
             seq = req.prompt + req.output
             idx.extend(seq[have:total])
 
-    def _get_spec_fn(self, B: int, lp: bool, tpmp: bool = True,
-                     pen: bool = False, gr: bool = False, la: bool = False):
-        """One jitted verify program per (bucket, logprobs, top-p, pen,
+    def _get_spec_fn(self, B: int, lp: bool, pen: bool = False,
+                     gr: bool = False, la: bool = False):
+        """One jitted verify program per (bucket, logprobs, pen,
         grammar): a (B, K+1) paged forward + per-position sampling, keys
         fold_in(row, pos+1) — the same keys the sequential path would use,
         so accepted tokens are exactly what non-speculative decoding would
@@ -1931,7 +1942,7 @@ class Engine:
         across the window — those rows never draft, so only their slot-0
         sample is consumed). Grammar rows get per-slot allowed-token masks
         computed host-side along the draft path."""
-        key = (B, lp, tpmp, pen, gr, la)
+        key = (B, lp, pen, gr, la)
         fn = self._spec_fn_cache.get(key)
         if fn is not None:
             return fn
@@ -1956,7 +1967,7 @@ class Engine:
                     lg_t = jnp.where(gm_t, lg_t, NEG_INF)
                 return sample(lg_t, step_keys(keys, pos_t + 1),
                               temps, ks, tps, mps, want_logprobs=lp,
-                              use_top_p_min_p=tpmp, **pkw)
+                              **pkw)
 
             gm = gmasks if gr else jnp.zeros(
                 (logits.shape[0], logits.shape[1], 1), bool)
@@ -2040,7 +2051,7 @@ class Engine:
             mask = np.zeros((B, T), bool)
             kvl = np.zeros(B, np.int32)
             table = np.zeros((B, P), np.int32)
-            temps, ks, tps, mps, seeds, rids, pen, lp, tpmp = \
+            temps, ks, tps, mps, seeds, rids, pen, lp, sorts = \
                 self._sampling_rows(batch, B)
             gr = any(r.gstate is not None for r in batch)
             gmasks = (np.ones((B, T, self.mcfg.vocab_size), bool)
@@ -2071,7 +2082,8 @@ class Engine:
         with _Phase(self, _DISPATCH):
             self._note_dispatch("spec", len(batch),
                                 int(mask.sum()), B, B * T)
-            fn = self._get_spec_fn(B, lp, tpmp, pen, gr, lids is not None)
+            self._note_sampler(sorts)
+            fn = self._get_spec_fn(B, lp, pen, gr, lids is not None)
             toks_out, lps_out, kp, vp, ksc, vsc = fn(
                 self.params, jnp.asarray(tok), jnp.asarray(pos),
                 jnp.asarray(mask), jnp.asarray(kvl), jnp.asarray(table),

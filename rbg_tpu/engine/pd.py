@@ -915,8 +915,9 @@ class DecodeWorker:
         mask[0, 0] = True
         limit[0] = req.max_len()
         table[0, :len(req.pages)] = req.pages
-        temps, ks, tps, mps, seeds, rids, pen, lp, tpmp = \
+        temps, ks, tps, mps, seeds, rids, pen, lp, sorts = \
             eng._sampling_rows([req], B)
+        eng._note_sampler(sorts)
         write_ok = jnp.asarray(mask & (pos < limit)[:, None])  # [B, 1]
         pos_d = jnp.asarray(pos)
         kvl_d = jnp.asarray(kvl)
@@ -955,7 +956,7 @@ class DecodeWorker:
             pmask, oc, rep, pres, freq = eng._penalty_rows([req], B)
             np.add.at(oc[0], np.asarray(req.output, np.int64), 1)
             args += [pmask, jnp.asarray(oc), rep, pres, freq]
-        toks, lps = eng._get_sampler(pen, lp, tpmp)(*args)
+        toks, lps = eng._get_sampler(pen, lp)(*args)
         tok_out = int(np.asarray(toks)[0])
         lp_val = (float(np.asarray(lps)[0])
                   if lps is not None and req.sampling.logprobs else None)
@@ -1001,12 +1002,11 @@ class DecodeWorker:
         temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
         keys = step_keys(row_keys(seeds, eng._sample_base, rids),
                          jnp.zeros(B, jnp.int32))
-        for tpmp in (False, True):
-            toks, _ = eng._get_sampler(False, False, tpmp)(
-                jnp.zeros((B, eng.mcfg.vocab_size), jnp.float32), keys,
-                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
-                jnp.asarray(mps))
-            toks.block_until_ready()
+        toks, _ = eng._get_sampler(False, False)(
+            jnp.zeros((B, eng.mcfg.vocab_size), jnp.float32), keys,
+            jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
+            jnp.asarray(mps))
+        toks.block_until_ready()
         return time.perf_counter() - t0
 
     def abandon_stream(self, receiver) -> None:

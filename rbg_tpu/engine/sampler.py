@@ -98,32 +98,44 @@ def sample(
     pres: Optional[jnp.ndarray] = None,          # [B] f32
     freq: Optional[jnp.ndarray] = None,          # [B] f32
     want_logprobs: bool = False,
-    use_top_p_min_p: bool = True,
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Returns (token ids [B] int32, logprobs [B] f32 or None).
 
     Penalty arguments are all-or-nothing: pass every one of prompt_mask /
     out_counts / rep / pres / freq, or none (the caller compiles separate
     variants so the penalty-free path never materializes [B, V] state).
-    ``use_top_p_min_p=False`` (static, host-known per batch) compiles out
-    the nucleus/min-p softmax+sort — the common greedy/top-k-only batch
-    should not pay a second O(V log V) sort per token.
+
+    Every stage past the greedy argmax runs only when some row of the
+    batch asks for it, decided on the device from the per-row arrays: the
+    temperature divide, noise and second argmax when a row samples, each
+    O(V log V) sort when a sampling row sets top-k, or top-p / min-p. The
+    predicates read the [B] parameter arrays and never the logits, so a
+    ``vmap`` over logits (the speculative verify) keeps them conditionals.
+    A row's token is the same to the bit whichever branches its batch
+    takes: a closed stage is one that would have changed no row.
     """
     if prompt_mask is not None:
         logits = apply_penalties(logits, prompt_mask, out_counts,
                                  rep, pres, freq)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    samples = temperature > 0
 
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = _mask_top_k(scaled, top_k)
-    if use_top_p_min_p:
-        scaled = _mask_top_p_min_p(scaled, top_p, min_p)
+    def sampled():
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        scaled = jax.lax.cond(
+            jnp.any(samples & (top_k > 0)),
+            lambda s: _mask_top_k(s, top_k), lambda s: s, scaled)
+        scaled = jax.lax.cond(
+            jnp.any(samples & ((top_p < 1.0) | (min_p > 0.0))),
+            lambda s: _mask_top_p_min_p(s, top_p, min_p), lambda s: s,
+            scaled)
+        # Gumbel-max with per-row keys == per-row categorical.
+        noise = jax.vmap(lambda k, row: jax.random.gumbel(
+            k, row.shape, row.dtype))(keys, scaled)
+        drawn = jnp.argmax(scaled + noise, axis=-1).astype(jnp.int32)
+        return jnp.where(samples, drawn, greedy)
 
-    # Gumbel-max with per-row keys == per-row categorical.
-    noise = jax.vmap(lambda k, row: jax.random.gumbel(k, row.shape,
-                                                      row.dtype))(keys, scaled)
-    sampled = jnp.argmax(scaled + noise, axis=-1).astype(jnp.int32)
-    toks = jnp.where(temperature > 0, sampled, greedy)
+    toks = jax.lax.cond(jnp.any(samples), sampled, lambda: greedy)
 
     lps = None
     if want_logprobs:

@@ -67,7 +67,7 @@ def programs(eng, S, T, lora=False, window=None):
             jnp.asarray(ks), jnp.asarray(tps), jnp.asarray(mps))
     out = {}
     out["rbg_fused_decode"] = eng._get_decode_fn(
-        B, False, False, False, lora, False).lower(
+        B, False, False, la=lora).lower(
         eng.params, vec, vec, vec, S((B, P), I32), S((B, K), bool), vec,
         pool.k_pages, pool.v_pages, *scales, *tail, **kw)
     for name, Tq in (("rbg_paged_fwd", cfg.prefill_chunk),

@@ -10,6 +10,7 @@ statement about results or speed — ``chip_smoke.py`` is the run.
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs in /tmp
 
@@ -189,10 +190,67 @@ def test_fused_decode_of_llama3_1b_fits_and_holds_the_kernel(
     tail = _on(chip, (row_keys(seeds, eng._sample_base, rids),
                       jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
                       jnp.asarray(mps)))
-    compiled = eng._get_decode_fn(B, False, False, False, False, False).lower(
+    compiled = eng._get_decode_fn(B, False, False).lower(
         eng.params, *small, eng.cache.k_pages, eng.cache.v_pages, None, None,
         *tail).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---- the sampler's gates, at Mixtral's head and vocabulary -------------------
+
+
+def _unguarded_sorts(hlo: str):
+    """(sorts in a compiled module, those of them reached from the entry
+    without passing through a branch of a ``conditional``)."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            name = "ENTRY" if line.startswith("ENTRY") else head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    sorts = {c: sum(" sort(" in ln for ln in lines)
+             for c, lines in comps.items()}
+    seen, todo = set(), ["ENTRY"]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for ln in comps[c]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)", ln)
+    return sum(sorts.values()), sum(sorts[c] for c in seen)
+
+
+def test_sampler_in_a_decode_scan_keeps_its_sorts_in_conditionals(chip):
+    """The head and ``sample`` of a decode window at ``[8, 32000]``, as the
+    TPU's compiler leaves them: the gates of ``sample`` have to stay
+    ``conditional`` operations with each sort inside a branch. Flattened
+    to selects, a batch of greedy rows would sort the vocabulary again."""
+    from rbg_tpu.engine.sampler import sample, step_keys
+    B, D, V = R, 4096, 32000
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+
+    def window(x, head, keys, temps, ks, tps, mps):
+        def body(pos, _):
+            logits = jnp.dot(x * (1 + pos)[:, None].astype(x.dtype), head,
+                             preferred_element_type=F32)
+            toks, _ = sample(logits, step_keys(keys, pos + 1), temps, ks,
+                             tps, mps)
+            return pos + 1, toks
+        return jax.lax.scan(body, jnp.zeros(B, I32), None, length=4)
+
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), B))
+    compiled = jax.jit(window).lower(
+        S((B, D), BF16), S((D, V), BF16), _on(chip, keys), S((B,), F32),
+        S((B,), I32), S((B,), F32), S((B,), F32)).compile()
+    text = compiled.as_text()
+    total, unguarded = _unguarded_sorts(text)
+    assert " conditional(" in text and total == 2 and unguarded == 0
 
 
 # ---- the hit-experts form of a decode step, at Mixtral's widths -------------

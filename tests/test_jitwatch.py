@@ -272,3 +272,62 @@ def test_warm_engine_serves_a_mixed_trace_without_a_compile(watch, model):
     assert all(len(toks) == 9 for toks in done.values())
     assert watch.violations() == []
     assert watch.counters()["rbg_jit_unwarmed_compiles_total"] == 0.0
+
+
+SAMPLING_MIXES = {
+    "greedy": [{}] * 4,
+    "top_k": [{"temperature": 0.8, "top_k": 5}] * 4,
+    "top_p": [{"temperature": 1.0, "top_p": 0.9, "min_p": 0.05}] * 4,
+    "mixed": [{}, {"temperature": 0.7}, {"temperature": 0.9, "top_k": 3},
+              {"temperature": 1.1, "top_p": 0.8}],
+}
+
+
+@pytest.fixture(scope="module")
+def warmed_engine():
+    from rbg_tpu.engine import Engine, EngineConfig
+    eng = Engine(EngineConfig(
+        model="tiny", page_size=8, num_pages=128, max_batch=4,
+        max_seq_len=128, prefill_chunk=16, enable_radix_cache=False,
+        multi_step=4, use_pallas="never"))
+    eng.warm_ragged(), eng.warm_decode()
+    eng.warm_join_windows(), eng.warm_samplers()
+    return eng
+
+
+@pytest.mark.parametrize("mix", sorted(SAMPLING_MIXES))
+def test_warm_engine_serves_every_sampling_mix_without_a_compile(
+        watch, warmed_engine, mix):
+    """``sample`` gates its stages on the device from the rows' own
+    parameters, so a bucket has ONE plain decode program and ONE host-path
+    sampler, and the warmers compile both: no mix of greedy, top-k and
+    top-p rows compiles anything in serving, and the counters say how many
+    of the sampler's runs sorted."""
+    from rbg_tpu.engine import SamplingParams
+    eng = warmed_engine
+    watch.arm()
+    watch.warmup_complete()     # every cataloged compile from here counts
+    programs = (set(eng._dec_fn_cache), set(eng._samplers))
+    runs, sorting = (eng.metrics[k] for k in ("sampler_steps",
+                                              "sampler_sort_steps"))
+
+    ids, done = [], {}
+    for n, kw in enumerate(SAMPLING_MIXES[mix]):
+        ids.append(eng.add_request(list(range(1, 6 + 7 * n)),
+                                   SamplingParams(max_new_tokens=9, **kw)))
+    while eng.has_work():
+        for ev in eng.step():
+            done.setdefault(ev.request_id, []).append(ev.token)
+    assert sorted(done) == sorted(ids)
+    assert watch.violations() == []
+    assert watch.counters()["rbg_jit_unwarmed_compiles_total"] == 0.0
+    assert (set(eng._dec_fn_cache), set(eng._samplers)) == programs
+    runs = eng.metrics["sampler_steps"] - runs
+    sorting = eng.metrics["sampler_sort_steps"] - sorting
+    assert runs > 0
+    if mix == "greedy":
+        assert sorting == 0
+    elif mix == "mixed":
+        assert 0 < sorting <= runs
+    else:
+        assert sorting == runs

@@ -123,7 +123,7 @@ def _lower(eng, program):
     vec, table = S((B,), I32), S((B, P), I32)
     if program == "decode":
         temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
-        return eng._get_decode_fn(B, False, False, False, False, False).lower(
+        return eng._get_decode_fn(B, False, False).lower(
             eng.params, vec, vec, vec, table, S((B, cfg.multi_step), bool),
             vec, pool.k_pages, pool.v_pages, None, None,
             row_keys(seeds, eng._sample_base, rids), jnp.asarray(temps),

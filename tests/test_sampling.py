@@ -106,6 +106,200 @@ def test_logprobs_returned_and_normalized():
     assert float(lps[0]) == pytest.approx(np.log(0.75), abs=1e-5)
 
 
+# ---- every stage runs only when a row asks for it, and changes no token ----
+
+
+def _straight_line_sample(logits, keys, temperature, top_k, top_p, min_p, *,
+                          prompt_mask=None, out_counts=None, rep=None,
+                          pres=None, freq=None, want_logprobs=False):
+    """The oracle: ``sample`` as it stood before its stages ran under
+    ``lax.cond``: every batch pays the divide, both sorts, the noise and
+    the second argmax, and a ``where`` picks the greedy rows' tokens."""
+    from rbg_tpu.engine.sampler import _mask_top_k, _mask_top_p_min_p
+    if prompt_mask is not None:
+        logits = apply_penalties(logits, prompt_mask, out_counts, rep, pres,
+                                 freq)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    scaled = _mask_top_k(scaled, top_k)
+    scaled = _mask_top_p_min_p(scaled, top_p, min_p)
+    noise = jax.vmap(lambda k, row: jax.random.gumbel(k, row.shape,
+                                                      row.dtype))(keys, scaled)
+    sampled = jnp.argmax(scaled + noise, axis=-1).astype(jnp.int32)
+    toks = jnp.where(temperature > 0, sampled, greedy)
+    lps = None
+    if want_logprobs:
+        full = jax.nn.log_softmax(logits, axis=-1)
+        lps = jnp.take_along_axis(full, toks[:, None], axis=-1)[:, 0]
+    return toks, lps
+
+
+# temperature, top_k, top_p, min_p of the batch's eight rows
+_B = 8
+MIXES = {
+    "greedy": ([0.0] * 8, [0] * 8, [1.0] * 8, [0.0] * 8),
+    "sampled": ([0.7, 1.0, 1.3, 0.2, 0.9, 1.0, 2.0, 0.5],
+                [0] * 8, [1.0] * 8, [0.0] * 8),
+    "top_k": ([0.8] * 8, [1, 5, 40, 3, 0, 7, 0, 2], [1.0] * 8, [0.0] * 8),
+    "top_p": ([1.0] * 8, [0] * 8,
+              [0.9, 0.5, 1.0, 0.1, 0.95, 0.7, 1.0, 0.3], [0.0] * 8),
+    "min_p": ([1.2] * 8, [0] * 8, [1.0] * 8,
+              [0.05, 0.0, 0.2, 0.5, 0.0, 0.1, 0.3, 0.01]),
+    "mixed": ([0.0, 0.9, 1.0, 0.7, 0.0, 1.1, 0.8, 1.5],
+              [0, 0, 5, 0, 0, 20, 3, 0],
+              [1.0, 1.0, 1.0, 0.8, 1.0, 0.9, 1.0, 1.0],
+              [0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.1, 0.2]),
+    # Greedy rows that set filters ask for no sort; one row samples plainly.
+    "filters_on_greedy_rows": ([0.0, 0.0, 0.0, 1.0, 0.0, 0.0, 0.0, 0.0],
+                               [5, 0, 3, 0, 1, 0, 0, 9],
+                               [0.5, 0.9, 1.0, 1.0, 1.0, 0.2, 1.0, 1.0],
+                               [0.0, 0.1, 0.0, 0.0, 0.0, 0.0, 0.3, 0.0]),
+}
+
+
+def _ulps_apart(a, b):
+    """Largest distance between two float32 arrays of one sign pattern,
+    counted in representable values."""
+    a, b = (np.asarray(x).view(np.int32).astype(np.int64) for x in (a, b))
+    return int(np.abs(a - b).max())
+
+
+@pytest.mark.parametrize("where", ["jit", "scan"])
+@pytest.mark.parametrize("pen,lp", [(False, False), (True, False),
+                                    (False, True), (True, True)])
+@pytest.mark.parametrize("mix", sorted(MIXES))
+def test_gated_sampler_is_the_straight_line_sampler_to_the_bit(mix, pen, lp,
+                                                               where):
+    V, T = 384, 3
+    rng = np.random.default_rng(sorted(MIXES).index(mix))
+    logits = jnp.asarray(rng.normal(0, 3, (T, _B, V)), jnp.float32)
+    temps, ks, tps, mps = MIXES[mix]
+    params = (_arr(temps), jnp.asarray(ks, jnp.int32), _arr(tps), _arr(mps))
+    keys = row_keys([None, 11] * (_B // 2), jax.random.key(5),
+                    list(range(_B)))
+    pkw = {}
+    if pen:
+        pkw = dict(
+            prompt_mask=jnp.asarray(rng.random((_B, V)) < 0.1),
+            out_counts=jnp.asarray(rng.integers(0, 3, (_B, V)), jnp.int32),
+            rep=_arr(rng.uniform(1.0, 1.5, _B)),
+            pres=_arr(rng.uniform(0.0, 0.5, _B)),
+            freq=_arr(rng.uniform(0.0, 0.3, _B)))
+
+    def run(fn):
+        def one(lg, t):
+            pos = jnp.full(_B, 17, jnp.int32) + t
+            return fn(lg, step_keys(keys, pos), *params,
+                      want_logprobs=lp, **pkw)
+
+        if where == "jit":
+            return jax.jit(one)(logits[0], 0)
+        # As the fused decode window runs it: the sampler inside a scan.
+        return jax.jit(lambda: jax.lax.scan(
+            lambda c, xs: (c, one(*xs)), 0,
+            (logits, jnp.arange(T, dtype=jnp.int32)))[1])()
+
+    toks, lps = run(sample)
+    want_toks, want_lps = run(_straight_line_sample)
+    assert np.array_equal(np.asarray(toks), np.asarray(want_toks))
+    if lp:
+        # The gates sit between nothing and the logprob: it is the same
+        # expression of the same penalised logits and the same token. Where
+        # XLA fuses ``apply_penalties`` into the log-softmax's reduction in
+        # one program and reads it from memory in the other, the CPU's
+        # sums round apart in the last places (as either does from the
+        # eager result); with no penalty to fuse they are the same bits.
+        assert _ulps_apart(lps, want_lps) <= (16 if pen else 0)
+    else:
+        assert lps is None and want_lps is None
+    if sum(t > 0 for t in temps) > 1 and not pen:
+        # A mix whose sampling rows all drew the greedy token tests nothing.
+        sampling = np.asarray(temps) > 0
+        lg = logits if where == "scan" else logits[:1]
+        greedy = np.asarray(jnp.argmax(lg, -1))[:, sampling]
+        assert (np.asarray(toks).reshape(-1, _B)[:, sampling] != greedy).any()
+
+
+def _sorts(lowered):
+    """(sorts in a lowered program, those of them that run whatever the
+    batch holds): a sort is guarded when it sits in a branch of a
+    ``lax.cond`` (``stablehlo.case``), itself or through its callers."""
+    module = lowered.compiler_ir("stablehlo")
+    open_sorts, open_calls, total = {}, {}, 0
+
+    def walk(op, fn, guarded):
+        nonlocal total
+        for region in op.regions:
+            for block in region:
+                for child in block:
+                    o = child.operation
+                    if o.name == "stablehlo.sort":
+                        total += 1
+                        open_sorts[fn] += not guarded
+                    if o.name == "func.call" and not guarded:
+                        open_calls[fn].append(
+                            str(o.attributes["callee"]).lstrip("@"))
+                    walk(o, fn, guarded
+                         or o.name in ("stablehlo.case", "stablehlo.if"))
+
+    for f in module.body.operations:
+        fn = str(f.attributes["sym_name"]).strip('"')
+        open_sorts[fn], open_calls[fn] = 0, []
+        walk(f.operation, fn, False)
+    seen, todo = set(), ["main"]
+    while todo:
+        fn = todo.pop()
+        if fn not in seen:
+            seen.add(fn)
+            todo += open_calls[fn]
+    return total, sum(open_sorts[fn] for fn in seen)
+
+
+def _lower_step_program(eng, program):
+    S = jax.ShapeDtypeStruct
+    cfg, pool = eng.cfg, eng.cache
+    B, P, V = cfg.max_batch, cfg.max_pages_per_seq, eng.mcfg.vocab_size
+    i32 = jnp.int32
+    vec, table = S((B,), i32), S((B, P), i32)
+    temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
+    tail = (row_keys(seeds, eng._sample_base, rids), jnp.asarray(temps),
+            jnp.asarray(ks), jnp.asarray(tps), jnp.asarray(mps))
+    if program == "rbg_fused_decode":
+        return eng._get_decode_fn(B, False, False).lower(
+            eng.params, vec, vec, vec, table, S((B, 1), bool), vec,
+            pool.k_pages, pool.v_pages, None, None, *tail)
+    if program == "rbg_spec_verify":
+        T = cfg.spec_k + 1
+        return eng._get_spec_fn(B, False).lower(
+            eng.params, S((B, T), i32), S((B, T), i32), S((B, T), bool), vec,
+            table, pool.k_pages, pool.v_pages, None, None, *tail)
+    return eng._get_sampler(False, False).lower(S((B, V), jnp.float32), *tail)
+
+
+@pytest.mark.parametrize("program", ["rbg_fused_decode", "rbg_spec_verify",
+                                     "rbg_sampler"])
+def test_every_sort_of_a_step_program_sits_in_a_conditional(program):
+    """A batch of greedy rows must not pay for a sort: in the decode scan,
+    in the host-path sampler, and in the speculative verify, whose ``vmap``
+    over the draft positions would turn a predicate that read the logits
+    into a select that runs both branches."""
+    total, unguarded = _sorts(_lower_step_program(_engine(multi_step=4),
+                                                  program))
+    assert total >= 1 and unguarded == 0
+
+
+def test_the_straight_line_sampler_sorts_whatever_the_batch_holds():
+    """The control of the test above: the walk does see a sort that no
+    conditional guards."""
+    S = jax.ShapeDtypeStruct
+    vec = S((_B,), jnp.float32)
+    lowered = jax.jit(_straight_line_sample).lower(
+        S((_B, 64), jnp.float32), _keys(_B), vec, S((_B,), jnp.int32), vec,
+        vec)
+    total, unguarded = _sorts(lowered)
+    assert unguarded >= 1
+
+
 # ---- engine behavior ----
 
 

@@ -386,7 +386,7 @@ def window_ctx(before, after):
                     "seconds": 1.0, "trace": None}
 
 
-@pytest.mark.parametrize("metric", NEW_METRICS)
+@pytest.mark.parametrize("metric", NEW_METRICS + ("sampler.sort_step_share",))
 def test_metric_file_reads_a_finite_value_off_the_wire(wire, metric):
     """A misspelt counter fails here, on the CPU, and not on the chip."""
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))

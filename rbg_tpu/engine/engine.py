@@ -251,6 +251,7 @@ class Engine:
                         # (llama._moe_mlp_hit): experts x layers a step,
                         # and how many of them the step read.
                         "moe_expert_slots": 0, "moe_experts_visited": 0,
+                        "moe_routed_rows": 0,
                         # Runs of ``sample`` as dispatched (a fused window
                         # is one a step), and those of them in which some
                         # sampling row set top-k, top-p or min-p: the ones
@@ -380,11 +381,19 @@ class Engine:
             raise ValueError("empty adapter")
         if name in self._lora_slots:
             raise ValueError(f"adapter {name!r} already loaded")
+        if len(self.mcfg.layer_groups) > 1:
+            # The [L, n, ...] adapter stack rides one scan over one kind
+            # of layer; a dense prefix before expert layers is two.
+            raise ValueError(
+                f"model {self.mcfg.name!r} has "
+                f"{len(self.mcfg.layer_groups)} groups of layers: LoRA "
+                f"adapters are not supported on it")
         if self.mcfg.mla:
             # MLA: LoRA targets the PLAIN input projections + output;
             # the absorbed per-head up-projections (w_uk/w_uv) are not
-            # adapter targets.
-            allowed = {"wq", "w_dkv", "wo"}
+            # adapter targets, nor are a low-rank query's two factors.
+            allowed = {"w_dkv", "wo"} | (
+                set() if self.mcfg.q_lora_rank else {"wq"})
         else:
             allowed = set(self._LORA_ATTN_TARGETS)
         if self.mcfg.num_experts == 0:
@@ -1561,8 +1570,13 @@ class Engine:
         with _Phase(self, _EMIT):
             if visited_dev is not None:      # copied since dispatch
                 self.metrics["moe_experts_visited"] += int(visited_dev)
+                moe_layers = self.mcfg.num_moe_layers
                 self.metrics["moe_expert_slots"] += (
-                    len(vals) * self.mcfg.num_layers * self.mcfg.num_experts)
+                    len(vals) * moe_layers * self.mcfg.num_experts)
+                # The (row, expert) pairs those visits served: a row is live
+                # in ``valid`` steps of the window, as the device masks it.
+                self.metrics["moe_routed_rows"] += (
+                    sum(valid) * moe_layers * self.mcfg.experts_per_token)
             for i, req in enumerate(rows):
                 for k in range(valid[i]):
                     if req.state != "running":

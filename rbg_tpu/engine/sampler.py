@@ -57,9 +57,18 @@ def apply_penalties(
     return logits
 
 
+def _sorted_desc(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` sorted descending along its last axis. Values alone are
+    sorted, so a stable and an unstable sort give the same array; the TPU's
+    compiler takes 21 s for the stable one at ``[16, 129280]`` and 8 s for
+    this (compiled for a described v5e), in every program that holds a
+    sampler: each decode bucket's, each host-path sampler's."""
+    return jnp.sort(x, axis=-1, stable=False)[:, ::-1]
+
+
 def _mask_top_k(scaled: jnp.ndarray, top_k: jnp.ndarray) -> jnp.ndarray:
     B, V = scaled.shape
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    sorted_desc = _sorted_desc(scaled)
     k_idx = jnp.clip(top_k - 1, 0, V - 1)
     kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=1)
     return jnp.where((top_k[:, None] > 0) & (scaled < kth), NEG_INF, scaled)
@@ -70,7 +79,7 @@ def _mask_top_p_min_p(scaled: jnp.ndarray, top_p: jnp.ndarray,
     probs = jax.nn.softmax(scaled, axis=-1)
     # top-p: keep the smallest prefix of sorted-desc probs whose exclusive
     # cumulative mass is < top_p; threshold = smallest kept probability.
-    sp = jnp.sort(probs, axis=-1)[:, ::-1]
+    sp = _sorted_desc(probs)
     cum_excl = jnp.cumsum(sp, axis=-1) - sp
     kept = cum_excl < top_p[:, None]
     thresh = jnp.min(jnp.where(kept, sp, jnp.inf), axis=-1, keepdims=True)

@@ -66,17 +66,35 @@ class KVCache:
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random init (normal, 0.02 scale on input projections, depth-scaled on
-    output projections) in cfg.dtype."""
-    d, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
-    hd, h, kv, L = cfg.head_dim_, cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
-    dt = cfg.jax_dtype
-    ks = jax.random.split(key, 8)
+    output projections) in cfg.dtype. One stacked dict of block weights per
+    group of ``cfg.layer_groups``, under the group's key."""
+    d, v, dt = cfg.hidden_size, cfg.vocab_size, cfg.jax_dtype
     s_in = 0.02
-    s_out = 0.02 / jnp.sqrt(2.0 * L)
+    s_out = 0.02 / jnp.sqrt(2.0 * cfg.num_layers)
 
     def nrm(k, shape, scale):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
 
+    params = {
+        "embed": nrm(jax.random.split(key, 8)[0], (v, d), s_in),
+        "final_norm": jnp.ones((d,), dt),
+    }
+    for i, (name, g, lo, hi) in enumerate(cfg.layer_groups):
+        # The group ``blocks`` draws from ``key`` itself, as the one group
+        # of a one-kind model always has.
+        gkey = key if name == "blocks" else jax.random.fold_in(key, 1000 + i)
+        params[name] = _init_blocks(g, gkey, hi - lo, nrm, s_in, s_out)
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = nrm(jax.random.fold_in(key, 99), (d, v), s_in)
+    return params
+
+
+def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
+    """``L`` stacked layers of the one kind ``cfg`` describes."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    hd, h, kv = cfg.head_dim_, cfg.num_heads, cfg.num_kv_heads
+    dt = cfg.jax_dtype
+    ks = jax.random.split(key, 8)
     blocks = {
         "attn_norm": jnp.ones((L, d), dt),
         "mlp_norm": jnp.ones((L, d), dt),
@@ -84,8 +102,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     if cfg.mla:
         dc, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
         dr, dv = cfg.qk_rope_head_dim, cfg.v_head_dim
+        if cfg.q_lora_rank:
+            rq = cfg.q_lora_rank
+            blocks.update({
+                "wq_a": nrm(ks[1], (L, d, rq), s_in),
+                "q_norm": jnp.ones((L, rq), dt),
+                "wq_b": nrm(jax.random.fold_in(ks[1], 1),
+                            (L, rq, h * (dn + dr)), s_in),
+            })
+        else:
+            blocks["wq"] = nrm(ks[1], (L, d, h * (dn + dr)), s_in)
         blocks.update({
-            "wq": nrm(ks[1], (L, d, h * (dn + dr)), s_in),
             "w_dkv": nrm(ks[2], (L, d, dc + dr), s_in),
             "kv_norm": jnp.ones((L, dc), dt),
             "w_uk": nrm(ks[3], (L, dc, h * dn), s_in),
@@ -111,18 +138,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         E, mf = cfg.num_experts, cfg.moe_f
         ke = jax.random.split(jax.random.fold_in(key, 7), 4)
         blocks["router"] = nrm(ke[0], (L, d, E), s_in)
+        if cfg.moe_select_bias:
+            # Selects, never weighs; float32 as the scores it is added to.
+            # N(0, 0.03) moves the chosen set at most positions and leaves
+            # routing near uniform, as a trained balancing bias does (the
+            # benchmark's joyai-llm-flash draws the same; PERF.md section 2).
+            blocks["router_bias"] = 0.03 * jax.random.normal(
+                jax.random.fold_in(ke[0], 1), (L, E), jnp.float32)
         blocks["moe_gate"] = nrm(ke[1], (L, E, d, mf), s_in)
         blocks["moe_up"] = nrm(ke[2], (L, E, d, mf), s_in)
         blocks["moe_down"] = nrm(ke[3], (L, E, mf, d), s_out)
-
-    params = {
-        "embed": nrm(ks[0], (v, d), s_in),
-        "blocks": blocks,
-        "final_norm": jnp.ones((d,), dt),
-    }
-    if not cfg.tie_word_embeddings:
-        params["lm_head"] = nrm(jax.random.fold_in(key, 99), (d, v), s_in)
-    return params
+    return blocks
 
 
 def lora_delta(x, A, B_, ids):
@@ -162,8 +188,8 @@ def _qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
     q = q.reshape(B, T, h, hd)
     k = k.reshape(B, T, kv, hd)
     vv = vv.reshape(B, T, kv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_interleave)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_interleave)
     return q, k, vv
 
 
@@ -171,23 +197,29 @@ def _mla_qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
     """MLA pre-attention math in the absorbed form: norm → q projection
     (split nope/rope, absorb W_uk into q) → latent down-projection
     (+kv-norm) and shared RoPE key. Returns (q_lat [B,T,h,dc],
-    q_pe [B,T,h,dr], c [B,T,dc], k_pe [B,T,dr]). LoRA applies to the
-    plain input projections (wq, w_dkv); the absorbed up-projections
+    q_pe [B,T,h,dr], c [B,T,dc], k_pe [B,T,dr]). With ``q_lora_rank`` the
+    query is low-rank: wq_a → RMSNorm → wq_b. LoRA applies to the plain
+    input projections (wq, w_dkv); the absorbed up-projections
     (w_uk/w_uv) are not adapter targets."""
     B, T, _ = x.shape
     h = cfg.num_heads
     dc, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
-    q = _lora_proj(xa, blk["wq"], "wq", lora, lora_ids)
+    if cfg.q_lora_rank:
+        q = rms_norm(xa @ blk["wq_a"], blk["q_norm"],
+                     cfg.rms_norm_eps) @ blk["wq_b"]
+    else:
+        q = _lora_proj(xa, blk["wq"], "wq", lora, lora_ids)
     q = q.reshape(B, T, h, dn + dr)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, cfg.rope_interleave)
     # Absorb: q_lat·c == q_nope·(c @ W_uk) — per-head K never materializes.
     w_uk = blk["w_uk"].reshape(dc, h, dn)
     q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w_uk)
     kv = _lora_proj(xa, blk["w_dkv"], "w_dkv", lora, lora_ids)  # [B,T,dc+dr]
     c = rms_norm(kv[..., :dc], blk["kv_norm"], cfg.rms_norm_eps)
-    k_pe = apply_rope(kv[..., None, dc:], positions, cfg.rope_theta)[:, :, 0]
+    k_pe = apply_rope(kv[..., None, dc:], positions, cfg.rope_theta,
+                      cfg.rope_interleave)[:, :, 0]
     return q_lat, q_pe, c, k_pe
 
 
@@ -230,20 +262,44 @@ def _mlp(cfg: ModelConfig, blk, xm, lora=None, lora_ids=None):
 
 
 def _route(cfg: ModelConfig, blk, xm):
-    """Combine weights ``[B, T, E]``: the top-k routing probabilities,
-    renormalised, and exact zeros for every other expert."""
-    logits = (xm @ blk["router"]).astype(jnp.float32)          # [B, T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_vals, _ = jax.lax.top_k(probs, cfg.experts_per_token)  # [B, T, K]
-    threshold = top_vals[..., -1:]                              # k-th largest
-    weights = jnp.where(probs >= threshold, probs, 0.0)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
-    return weights.astype(xm.dtype)
+    """Combine weights ``[B, T, E]``: the scores of the top-k experts and
+    exact zeros for every other expert (``_moe_mlp_hit`` reads the zeros).
+    The rule is the configuration's: scores are the softmax or the sigmoid
+    of the router's logits in float32; with ``moe_select_bias`` the top-k
+    is taken of ``scores + router_bias``, which picks and never weighs;
+    the chosen scores are renormalised to sum 1 (``moe_renormalize``) and
+    scaled by ``moe_routed_scale``."""
+    with jax.named_scope("router"):
+        k = cfg.experts_per_token
+        if cfg.moe_scoring == "sigmoid":
+            # Scores near 0.5 lie a bf16 rounding apart: float32 logits.
+            logits = jnp.einsum("btd,de->bte", xm, blk["router"],
+                                preferred_element_type=jnp.float32)
+            scores = jax.nn.sigmoid(logits)
+        else:
+            logits = (xm @ blk["router"]).astype(jnp.float32)  # [B, T, E]
+            scores = jax.nn.softmax(logits, axis=-1)
+        if cfg.moe_select_bias:
+            # By index: the bias breaks "score >= k-th largest score".
+            _, top = jax.lax.top_k(scores + blk["router_bias"], k)
+            chosen = jnp.any(top[..., None] == jnp.arange(
+                cfg.num_experts, dtype=top.dtype), axis=-2)
+        else:
+            top_vals, _ = jax.lax.top_k(scores, k)              # [B, T, K]
+            chosen = scores >= top_vals[..., -1:]               # k-th largest
+        weights = jnp.where(chosen, scores, 0.0)
+        if cfg.moe_renormalize:
+            weights = weights / jnp.maximum(
+                weights.sum(-1, keepdims=True), 1e-9)
+        if cfg.moe_routed_scale != 1.0:
+            weights = weights * cfg.moe_routed_scale
+        return weights.astype(xm.dtype)
 
 
 def _shared_expert(blk, xm):
-    gate = jax.nn.silu(xm @ blk["w_gate"])
-    return (gate * (xm @ blk["w_up"])) @ blk["w_down"]
+    with jax.named_scope("shared"):
+        gate = jax.nn.silu(xm @ blk["w_gate"])
+        return (gate * (xm @ blk["w_up"])) @ blk["w_down"]
 
 
 def _moe_mlp(cfg: ModelConfig, blk, xm):
@@ -415,15 +471,21 @@ def forward(
 
     x = params["embed"].astype(cfg.jax_dtype)[tokens]  # [B, T, D]
 
-    def step(carry, xs):
-        h = carry
-        blk, kc, vc = xs
-        h, kc, vc = _block(cfg, h, blk, kc, vc, write_positions, kv_valid)
-        return h, (kc, vc)
+    k_new, v_new = [], []
+    for name, g, lo, hi in cfg.layer_groups:
+        def step(carry, xs, g=g):
+            blk, kc, vc = xs
+            h, kc, vc = _block(g, carry, blk, kc, vc, write_positions,
+                               kv_valid)
+            return h, (kc, vc)
 
-    x, (k_new, v_new) = jax.lax.scan(step, x, (params["blocks"], cache.k, cache.v))
+        x, (k, v) = jax.lax.scan(
+            step, x, (params[name], cache.k[lo:hi], cache.v[lo:hi]))
+        k_new.append(k)
+        v_new.append(v)
     logits = _head(params, cfg, x)
-    return logits, KVCache(k=k_new, v=v_new, length=new_length)
+    return logits, KVCache(k=jnp.concatenate(k_new), v=jnp.concatenate(v_new),
+                           length=new_length)
 
 
 class PoolAddr(NamedTuple):
@@ -487,9 +549,9 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
     None unless int8). A chain of windows covering every layer is the whole
     walk (``tests/test_layer_walk.py``): ``engine/pd.py`` chains them so that
     a first decode step starts when the leading layers' KV has arrived.
-    Returns (x, pool, visited): ``visited [hi - lo]`` counts the experts each
-    layer visited where the hit-experts form ran (``experts_whole`` and
-    ``hit_experts_pay``), else None."""
+    Returns (x, pool, visited): ``visited`` counts the experts each expert
+    layer of the window visited where the hit-experts form ran
+    (``experts_whole`` and ``hit_experts_pay``), else None."""
     lo, hi = layers
     # The pool rides the layer scan as CARRY over a [L·NP, …] flat view,
     # with each layer addressing its pages as ``layer·NP + page_table``. As
@@ -500,31 +562,56 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
     flat = jax.tree_util.tree_map(
         lambda p: p.reshape((L_ * NP,) + p.shape[2:]), pool)
 
-    # The hit-experts form takes the stacked expert weights as the scan's
-    # invariants, addressed by (layer, expert) like the pool above: as
-    # scanned inputs each layer's [E, D, F] slice would be copied whole.
-    blocks = params["blocks"]
-    hit_only = experts_whole and hit_experts_pay(cfg, x.shape[0] * x.shape[1])
-    if hit_only:
-        stacks = {k: blocks[k] for k in _EXPERT_STACKS}
-        blocks = {k: v for k, v in blocks.items() if k not in stacks}
+    # One scan a group of ``cfg.layer_groups`` that the window meets (a
+    # one-kind model: one scan), each over its own stacked weights; the
+    # pool is the one pool, addressed by absolute layer. The loop's body
+    # stands here and not in a function of its own: one more Python frame
+    # under everything the scan traces cost the server's warm-up 6 s of
+    # CPU (CPython's 16 KiB frame chunks; PERF.md section 6, PR 32).
+    visited = []
+    for name, g, first, end in cfg.layer_groups:
+        glo, ghi = max(lo, first), min(hi, end)
+        if glo >= ghi:
+            continue
+        blocks = params[name]
+        # The hit-experts form takes the stacked expert weights as the
+        # scan's invariants, addressed by (layer, expert) like the pool
+        # above: as scanned inputs each layer's [E, D, F] slice would be
+        # copied whole.
+        hit_only = experts_whole and hit_experts_pay(
+            g, x.shape[0] * x.shape[1])
+        if hit_only:
+            stacks = {k: blocks[k] for k in _EXPERT_STACKS}
+            blocks = {k: v for k, v in blocks.items() if k not in stacks}
 
-    def step(carry, xs):
-        hcur, flat = carry
-        blk, li, lr = xs
-        table = addr.page_table + li * NP
-        with jax.named_scope("attention"):
-            attn, flat = _pool_attention(cfg, blk, hcur, flat, table, addr,
-                                         use_pallas, lr, lora_ids)
-        hit = (stacks, li, addr.token_mask) if hit_only else None
-        out = _post_attention(cfg, blk, hcur, attn, lr, lora_ids, hit)
-        out, visited = out if hit_only else (out, None)
-        return (out, flat), visited
+        def step(carry, xs):     # traced by this turn's scan, below
+            hcur, flat = carry
+            blk, li, lr = xs
+            table = addr.page_table + li * NP
+            with jax.named_scope("attention"):
+                attn, flat = _pool_attention(g, blk, hcur, flat, table, addr,
+                                             use_pallas, lr, lora_ids)
+            # The stacks are the group's: its own layer index (no
+            # subtraction where the group starts the model, so that a
+            # one-group model's program is the one it was).
+            hit = ((stacks, li - first if first else li, addr.token_mask)
+                   if hit_only else None)
+            out = _post_attention(g, blk, hcur, attn, lr, lora_ids, hit)
+            out, seen = out if hit_only else (out, None)
+            return (out, flat), seen
 
-    # Block weights and LoRA A/B carry a leading L: scan-sliced per layer.
-    blocks, lora = jax.tree_util.tree_map(lambda a: a[lo:hi], (blocks, lora))
-    (x, flat), visited = jax.lax.scan(
-        step, (x, flat), (blocks, jnp.arange(lo, hi, dtype=jnp.int32), lora))
+        # Block weights carry the group's layers, LoRA A/B every layer, on
+        # the leading axis: scan-sliced per layer.
+        blocks = jax.tree_util.tree_map(
+            lambda a: a[glo - first:ghi - first], blocks)
+        adapters = jax.tree_util.tree_map(lambda a: a[glo:ghi], lora)
+        (x, flat), seen = jax.lax.scan(
+            step, (x, flat),
+            (blocks, jnp.arange(glo, ghi, dtype=jnp.int32), adapters))
+        if seen is not None:
+            visited.append(seen)
+    visited = (None if not visited else visited[0] if len(visited) == 1
+               else jnp.concatenate(visited))
     return x, jax.tree_util.tree_map(lambda f, p: f.reshape(p.shape), flat,
                                      pool), visited
 
@@ -643,21 +730,22 @@ def _encode_core(params, cfg, tokens, token_mask, mesh=None, remat=False,
 
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
 
-    def body(h, blk):
-        if use_ring:
-            q, k, vv = _qkv(cfg, blk, h, positions)
-            attn = ring_attention(q, k, vv, positions, kv_positions, mesh)
-            return _post_attention(cfg, blk, h, attn)
-        h, _, _ = _block(cfg, h, blk, None, None, positions, token_mask)
-        return h
+    for name, g, _, _ in cfg.layer_groups:
+        def body(h, blk, g=g):
+            if use_ring:
+                q, k, vv = _qkv(g, blk, h, positions)
+                attn = ring_attention(q, k, vv, positions, kv_positions, mesh)
+                return _post_attention(g, blk, h, attn)
+            h, _, _ = _block(g, h, blk, None, None, positions, token_mask)
+            return h
 
-    if remat:
-        body = jax.checkpoint(body)
+        if remat:
+            body = jax.checkpoint(body)
 
-    def step(h, blk):
-        return body(h, blk), None
+        def step(h, blk, body=body):
+            return body(h, blk), None
 
-    x, _ = jax.lax.scan(step, x, params["blocks"])
+        x, _ = jax.lax.scan(step, x, params[name])
     if final_norm:
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return x

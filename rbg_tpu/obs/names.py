@@ -584,6 +584,18 @@ SPAN_ENGINE_DISPATCH = "engine.dispatch"
 SPAN_ENGINE_SYNC = "engine.sync"
 SPAN_ENGINE_EMIT = "engine.emit"
 
+# ---- model scopes (device time by block) ----
+#
+# The ``jax.named_scope`` components ``models/llama.py`` and
+# ``engine/sampler.py`` open around a layer's blocks: a device trace
+# carries them in each operation's path, and the benchmark's
+# ``device.*_share`` metrics sum device time by them
+# (docs/observability.md "Outlet 2"). Every dot of a step program sits in
+# exactly one of the first five (``tests/test_layer_walk.py``); ``router``
+# and ``shared`` open inside ``moe`` (paths ``moe/router``, ``moe/shared``).
+MODEL_SCOPES = ("attention", "mlp", "moe", "lm_head", "sampler")
+MOE_INNER_SCOPES = ("router", "shared")
+
 # ---- jitted program catalog (jitwatch sentry + warmers) ----
 #
 # Same contract as the metric catalog: every named hot-path XLA program

@@ -123,6 +123,25 @@ def block_specs(pools, page_id):
     return specs, operands
 
 
+def latent_pools(c_pages, pe_pages):
+    """The latent pools ``[NP, page, 1, d]`` as ``[NP, page, d]``, inside
+    the jitted call that hands them to a kernel. A custom call takes its
+    operands in the default layout, and a 4-D pool's trailing ``(1, d)``
+    tiles unlike the ``(page, d)`` the step's scatter keeps the pool in:
+    handed over 4-D, the whole pool was copied before every call (0.75 GB
+    a layer a step at the benchmark's joyai cell). The reshape is free."""
+    return tuple(p.reshape(p.shape[:2] + p.shape[3:])
+                 for p in (c_pages, pe_pages))
+
+
+def load_latent_blocks(page_refs):
+    """``load_blocks`` of the latent pools ``c, pe [n·page, d]`` and, for
+    int8 pools, their per-slot scales ``[n·page]`` (else None, None)."""
+    c, pe, *scales = load_blocks(page_refs)
+    cs, ps = (s[:, 0] for s in scales) if scales else (None, None)
+    return c, pe, cs, ps
+
+
 def load_blocks(page_refs):
     """The item's block of each pool, ``[n·page, ...]``, from the page refs
     a kernel received (``block_specs``' order: pool by pool)."""

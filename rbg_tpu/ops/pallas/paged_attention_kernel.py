@@ -214,7 +214,7 @@ def _mla_decode_kernel(
     ql_ref,           # [1, H, dc] (VMEM) — q_nope absorbed through W_uk
     qp_ref,           # [1, H, dr] — RoPE'd query part
     *refs,            # the item's pages, picked by index_map: n c refs
-                      # [1, page, 1, dc] and n pe refs [1, page, 1, dr]
+                      # [1, page, dc] and n pe refs [1, page, dr]
                       # (int8 pools: then n + n scale refs [1, page, 1]
                       # f32); out_ref [1, H, dc] — latent attention output;
                       # scratch: m [H, 1], l [H, 1], acc [H, dc]
@@ -233,8 +233,7 @@ def _mla_decode_kernel(
 
     @pl.when(token0 < kv_len)
     def _attend():
-        c, pe, *scales = (blk[:, 0] for blk in W.load_blocks(pages))
-        cs, ps = scales or (None, None)
+        c, pe, cs, ps = W.load_latent_blocks(pages)
         W.mla_attend(ql_ref[0], qp_ref[0], c, pe, cs, ps, token0, kv_len,
                      scale, m_ref, l_ref, acc_ref)
 
@@ -247,6 +246,7 @@ def _mla_decode(q_lat, q_pe, pools, page_table, kv_lens, scale, interpret):
     """q_lat: [B, H, dc], q_pe: [B, H, dr]; pools: c, pe pages
     [NP, page, 1, d], and for int8 pools their scales [NP, page, 1] f32.
     Returns the latent attention output [B, H, dc]."""
+    pools = W.latent_pools(*pools[:2]) + tuple(pools[2:])
     B, H, dc = q_lat.shape
     dr = q_pe.shape[-1]
     page = pools[0].shape[1]

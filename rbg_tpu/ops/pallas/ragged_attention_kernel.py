@@ -310,8 +310,8 @@ def _block_ragged_mla_kernel(
     # blocks — the tile's tokens are folded into the query-row axis
     ql_ref,           # [TILE·H, dc]
     qp_ref,           # [TILE·H, dr]
-    *refs,            # the item's pages: n c refs [1, page, 1, dc] and n
-                      # pe refs [1, page, 1, dr] (int8 pools: then n + n
+    *refs,            # the item's pages: n c refs [1, page, dc] and n
+                      # pe refs [1, page, dr] (int8 pools: then n + n
                       # scale refs [1, page, 1] f32); out_ref [TILE·H, dc];
                       # scratch: m, l [TILE·H, 1], acc [TILE·H, dc]
     scale: float,
@@ -333,8 +333,7 @@ def _block_ragged_mla_kernel(
         rows_q = ql_ref.shape[0]
         limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0,
                               row_ids_ref[t], tile, rows_q // tile)
-        c, pe, *scales = (blk[:, 0] for blk in W.load_blocks(pages))
-        cs, ps = scales or (None, None)
+        c, pe, cs, ps = W.load_latent_blocks(pages)
         W.mla_attend(ql_ref[...], qp_ref[...], c, pe, cs, ps, token0,
                      limits, scale, m_ref, l_ref, acc_ref)
 
@@ -356,7 +355,7 @@ def _block_ragged_mla_call(ql, qp, c_pages, pe_pages, c_scales, pe_scales,
     rows_q = ql.shape[0] // Tp * Q_TILE                     # TILE·H
     page = c_pages.shape[1]
     lead, starts = _tile_segments(row_ids, q_pos, kv_lens, page)
-    pools = (c_pages, pe_pages)
+    pools = W.latent_pools(c_pages, pe_pages)
     if c_scales is not None:
         pools += (c_scales, pe_scales)
     page_specs, page_operands = W.block_specs(

@@ -113,6 +113,10 @@ def pipeline_forward_train(params, cfg, tokens, token_mask=None, *, mesh: Mesh,
     """forward_train equivalent with the block stack pipelined over ``axis``."""
     from rbg_tpu.models.llama import _head
 
+    if len(cfg.layer_groups) > 1:
+        raise NotImplementedError(
+            f"{cfg.name}: the pipeline stages one stack of one kind of "
+            f"layer; this model has {len(cfg.layer_groups)} groups")
     B, T = tokens.shape
     if token_mask is None:
         token_mask = jnp.ones((B, T), bool)

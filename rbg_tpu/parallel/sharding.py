@@ -30,12 +30,38 @@ def param_specs(cfg: ModelConfig, params: Optional[dict] = None) -> dict:
     Pass ``params`` to align with optional checkpoint-dependent keys
     (Qwen2 attention biases) that the config alone can't predict.
     """
+    specs = {
+        "embed": P("tp", None),
+        "final_norm": P(None),
+    }
+    for name, g, _, _ in cfg.layer_groups:
+        specs[name] = _block_specs(g)
+    if params is not None and "bq" in params.get("blocks", {}):
+        # QKV bias columns follow their projection's output sharding.
+        specs["blocks"].update(bq=P(None, "tp"), bk=P(None, "tp"),
+                               bv=P(None, "tp"))
+    if not cfg.tie_word_embeddings:
+        specs["lm_head"] = P(None, "tp")
+    return specs
+
+
+def _block_specs(cfg: ModelConfig) -> dict:
+    """Specs of one group's stacked block weights (``llama._init_blocks``)."""
     blocks = {
         "attn_norm": P(None, None),
-        "wq": P(None, None, "tp"),
         "wo": P(None, "tp", None),
         "mlp_norm": P(None, None),
     }
+    if cfg.mla and cfg.q_lora_rank:
+        # The low-rank query: the down-projection and its norm replicate,
+        # the up-projection shards over heads.
+        blocks.update({
+            "wq_a": P(None, None, None),
+            "q_norm": P(None, None),
+            "wq_b": P(None, None, "tp"),
+        })
+    else:
+        blocks["wq"] = P(None, None, "tp")
     if cfg.mla:
         # MLA: query-side weights shard over heads (tp); the latent
         # down-projection and its norm replicate (no head axis — the latent
@@ -58,22 +84,12 @@ def param_specs(cfg: ModelConfig, params: Optional[dict] = None) -> dict:
     if cfg.num_experts:
         # Experts split over ep; inside each expert, Megatron tp as usual.
         blocks["router"] = P(None, None, None)
+        if cfg.moe_select_bias:
+            blocks["router_bias"] = P(None, None)
         blocks["moe_gate"] = P(None, "ep", None, "tp")
         blocks["moe_up"] = P(None, "ep", None, "tp")
         blocks["moe_down"] = P(None, "ep", "tp", None)
-    if params is not None and "bq" in params.get("blocks", {}):
-        # QKV bias columns follow their projection's output sharding.
-        blocks["bq"] = P(None, "tp")
-        blocks["bk"] = P(None, "tp")
-        blocks["bv"] = P(None, "tp")
-    specs = {
-        "embed": P("tp", None),
-        "blocks": blocks,
-        "final_norm": P(None),
-    }
-    if not cfg.tie_word_embeddings:
-        specs["lm_head"] = P(None, "tp")
-    return specs
+    return blocks
 
 
 def cache_specs() -> dict:

@@ -142,14 +142,12 @@ def test_mla_kernel_compiles_for_v5e(chip, kernel):
 # ---- the engine's own step programs, whole, at chip_smoke's serving size ----
 
 
-@pytest.fixture()
-def abstract_engine(chip, monkeypatch):
-    """An ``Engine`` of llama3-1b whose parameters and KV pool are shapes
-    on the described chip: its jitted step programs are the ones a server
-    on the chip compiles, pool and program together against 16 GB. The
-    engine picks the kernel from ``jax.default_backend()``, which is the
-    CPU here, so the test asks for it outright."""
-    import chip_smoke
+def _abstract_engine(chip, monkeypatch, **serve_config):
+    """An ``Engine`` whose parameters and KV pool are shapes on the
+    described chip: its jitted step programs are the ones a server on the
+    chip compiles, pool and program together against 16 GB. The engine
+    picks the kernel from ``jax.default_backend()``, which is the CPU
+    here, so the test asks for it outright."""
     from rbg_tpu.engine import engine as E
 
     init, create = E.init_params, E.PagedKVCache.create
@@ -159,29 +157,27 @@ def abstract_engine(chip, monkeypatch):
     monkeypatch.setattr(
         E.PagedKVCache, "create",
         lambda *a, **kw: _on(chip, jax.eval_shape(lambda: create(*a, **kw))))
-    cfg = EngineConfig(use_pallas="always", **chip_smoke.SERVE_CONFIG)
-    return E.Engine(cfg)
+    return E.Engine(EngineConfig(use_pallas="always", **serve_config))
 
 
-def test_unified_step_of_llama3_1b_fits_and_holds_the_kernel(
-        chip, abstract_engine):
-    eng = abstract_engine
+@pytest.fixture()
+def abstract_engine(chip, monkeypatch):
+    """llama3-1b at ``chip_smoke.py``'s serving size."""
+    import chip_smoke
+    return _abstract_engine(chip, monkeypatch, **chip_smoke.SERVE_CONFIG)
+
+
+def _compile_unified(chip, eng):
     Rb, Tb = eng.cfg.max_batch, eng.cfg.max_batch * eng.cfg.prefill_chunk
     S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
-    compiled = eng._get_ragged_fn(Rb, Tb).lower(
+    return eng._get_ragged_fn(Rb, Tb).lower(
         eng.params, S((1, Tb), I32), S((1, Tb), I32), S((1, Tb), bool),
         S((Tb,), I32), S((Rb,), I32), S((Rb, eng.cfg.max_pages_per_seq), I32),
         eng.cache.k_pages, eng.cache.v_pages, None, None).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
-# Half a minute of compile, so outside tier-1; chip_smoke.py's reference
-# phase lowers the same program on the chip in every run.
-@pytest.mark.slow
-def test_fused_decode_of_llama3_1b_fits_and_holds_the_kernel(
-        chip, abstract_engine):
+def _compile_decode(chip, eng):
     from rbg_tpu.engine.sampler import row_keys
-    eng = abstract_engine
     B, Pm, Kw = eng.cfg.max_batch, eng.cfg.max_pages_per_seq, eng.cfg.multi_step
     temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
     small = _on(chip, (
@@ -190,10 +186,62 @@ def test_fused_decode_of_llama3_1b_fits_and_holds_the_kernel(
     tail = _on(chip, (row_keys(seeds, eng._sample_base, rids),
                       jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
                       jnp.asarray(mps)))
-    compiled = eng._get_decode_fn(B, False, False).lower(
+    return eng._get_decode_fn(B, False, False).lower(
         eng.params, *small, eng.cache.k_pages, eng.cache.v_pages, None, None,
         *tail).compile()
+
+
+def test_unified_step_of_llama3_1b_fits_and_holds_the_kernel(
+        chip, abstract_engine):
+    assert "tpu_custom_call" in _compile_unified(chip,
+                                                 abstract_engine).as_text()
+
+
+# Half a minute of compile, so outside tier-1; chip_smoke.py's reference
+# phase lowers the same program on the chip in every run.
+@pytest.mark.slow
+def test_fused_decode_of_llama3_1b_fits_and_holds_the_kernel(
+        chip, abstract_engine):
+    assert "tpu_custom_call" in _compile_decode(chip,
+                                                abstract_engine).as_text()
+
+
+# ---- the benchmark's joyai.longgen16 cell: its two step programs -------------
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_step_programs_of_the_joyai_cell_fit_and_copy_no_expert_stack(
+        chip, monkeypatch, program):
+    """``benchmark/configs/joyai-llm-flash.json`` as served: 1 dense + 4
+    expert layers, ``[4, 256, 2048, 768]`` expert stacks, 16 rows, a table
+    256 wide over 8192 pages of latents. Both programs hold the latent
+    kernel, and neither keeps a temporary the size of one layer's expert
+    matrix (0.8 GB): not a copy of the scan's slice (the hit form reads
+    ``(layer, expert)`` in place, the dense dispatch fuses the layer's
+    slice into its dot) and not a copy of the latent pool, which a kernel
+    handed the pool with its singleton axis cost a decode step (1.5 GB of
+    temporaries before ``page_walk.latent_pools``)."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from harness import serve
+    from rbg_tpu.models import config as presets
+    with open(os.path.join(bench, "configs", "joyai-llm-flash.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setitem(presets._PRESETS, "joyai-cell",
+                        serve.model_config(cfg, "joyai-cell"))
+    eng = _abstract_engine(chip, monkeypatch, model="joyai-cell",
+                           **cfg["server"])
+    assert eng.params["blocks"]["moe_gate"].shape == (4, 256, 2048, 768)
+    assert (eng.cfg.max_batch, eng.cfg.max_pages_per_seq) == (16, 256)
+    compiled = (_compile_decode if program == "decode"
+                else _compile_unified)(chip, eng)
     assert "tpu_custom_call" in compiled.as_text()
+    one_matrix = 256 * 2048 * 768 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_matrix // 2
 
 
 # ---- the sampler's gates, at Mixtral's head and vocabulary -------------------

@@ -241,7 +241,7 @@ def test_programs_catalog_names_are_stamped_constants():
 # ---- the engine's warmers against the sentry --------------------------------
 
 
-@pytest.mark.parametrize("model", ["tiny", "tiny-mla"])
+@pytest.mark.parametrize("model", ["tiny", "tiny-mla", "tiny-joyai"])
 def test_warm_engine_serves_a_mixed_trace_without_a_compile(watch, model):
     """After ``warm_ragged`` / ``warm_decode`` / ``warm_join_windows`` /
     ``warm_samplers`` (what ``EngineService.warmup`` runs) a trace of mixed

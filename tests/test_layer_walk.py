@@ -43,18 +43,29 @@ def _close(a, b):
                                rtol=1e-5, atol=atol)
 
 
-@pytest.mark.parametrize("preset,quantize,packed", [
-    ("tiny", False, False), ("tiny-moe", False, False),
-    ("tiny-mla", False, False), ("tiny", True, False),
-    ("tiny", False, True)],
-    ids=["dense", "moe", "mla", "int8-pool", "packed"])
-def test_chain_of_layer_windows_is_the_whole_walk(preset, quantize, packed):
+# Two dense layers before four expert layers: two groups, two scans.
+_GROUPS = dict(num_layers=6, first_dense_layers=2)
+
+
+@pytest.mark.parametrize("preset,quantize,packed,shape", [
+    ("tiny", False, False, {}), ("tiny-moe", False, False, {}),
+    ("tiny-mla", False, False, {}), ("tiny", True, False, {}),
+    ("tiny", False, True, {}), ("tiny-joyai", False, False, _GROUPS),
+    ("tiny-joyai", False, True, _GROUPS)],
+    ids=["dense", "moe", "mla", "int8-pool", "packed", "groups",
+         "groups-packed"])
+def test_chain_of_layer_windows_is_the_whole_walk(preset, quantize, packed,
+                                                  shape):
     """Windows of two layers or more run the whole walk's loop body and
     agree with it to the bit. A window of ONE layer is a loop of one trip,
     which XLA unrolls and fuses with its surroundings: on the CPU a decode
     step then differs in the last bit (the token streams of
-    ``test_kvtransfer.py``'s layer-sliced admissions do not)."""
-    cfg = dataclasses.replace(get_config(preset), num_layers=4)
+    ``test_kvtransfer.py``'s layer-sliced admissions do not). A model of
+    two groups of layers is one scan a group, and a window that spans the
+    boundary runs its part of each."""
+    cfg = dataclasses.replace(get_config(preset),
+                              **(shape or {"num_layers": 4}))
+    L = cfg.num_layers
     params = init_params(cfg, jax.random.key(1))
     cache = PagedKVCache.create(cfg, ROWS * PAGES_PER_ROW, PAGE,
                                 quantize=quantize)
@@ -69,8 +80,10 @@ def test_chain_of_layer_windows_is_the_whole_walk(preset, quantize, packed):
                     use_pallas="never"))(x, pool)
         return x, pool
 
-    chains = {"whole": [(0, 4)], "exact": [(0, 2), (2, 4)],
-              "one-layer": [(0, 1), (1, 4)]}
+    # L = 6, groups [0, 2) and [2, 6): "exact" spans the boundary with two
+    # layers either side of it, "one-layer" leaves one layer of a group.
+    chains = {"whole": [(0, L)], "exact": [(0, L - 2), (L - 2, L)],
+              "one-layer": [(0, L - 3), (L - 3, L)]}
     pools = dict.fromkeys(chains, pool)
     # A prefill chunk into the empty pool, then a decode step that reads it.
     for T, start in ((5, 0), (1, 5)):
@@ -96,20 +109,26 @@ def test_chain_of_layer_windows_is_the_whole_walk(preset, quantize, packed):
 # ---- the scope names the benchmark reads device time by ---------------------
 
 
-def _scopes_of_dots(lowered) -> list:
-    """For each ``dot_general`` of a lowered program, the named scopes on
-    its path (``jit(f)/while/body/attention/dot_general`` -> attention)."""
+def _paths_of_dots(lowered) -> list:
+    """The scope path of each ``dot_general`` of a lowered program
+    (``jit(f)/while/body/attention/dot_general``), split at ``/``."""
     text = lowered.as_text(debug_info=True)
     names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
     dots = re.findall(r'stablehlo\.dot_general.*loc\((#loc\d+)\)', text)
     assert dots
-    known = {"attention", "moe", "mlp", "lm_head", "sampler"}
-    return [sorted(known & set(names[d].split("/"))) for d in dots]
+    return [names[d].split("/") for d in dots]
 
 
-@pytest.fixture(scope="module")
-def moe_engine():
-    cfg = EngineConfig(model="tiny-moe", page_size=8, num_pages=64,
+def _scopes_of_dots(lowered) -> list:
+    """For each ``dot_general``, the model scopes on its path."""
+    from rbg_tpu.obs.names import MODEL_SCOPES
+    return [sorted(set(MODEL_SCOPES) & set(path))
+            for path in _paths_of_dots(lowered)]
+
+
+@pytest.fixture(scope="module", params=["tiny-moe", "tiny-joyai"])
+def moe_engine(request):
+    cfg = EngineConfig(model=request.param, page_size=8, num_pages=64,
                        max_seq_len=128, max_batch=4, prefill_chunk=16,
                        enable_radix_cache=False, use_pallas="never")
     return Engine(cfg)
@@ -150,4 +169,22 @@ def test_every_dot_of_a_step_program_sits_in_one_named_scope(
     twice."""
     scopes = _scopes_of_dots(_lower(moe_engine, program))
     assert all(len(s) == 1 for s in scopes), scopes
+    if moe_engine.mcfg.first_dense_layers:
+        # the dense layer before the expert layers; a window of layer 0
+        # alone holds no expert layer
+        want = want | {"mlp"} if program != "window" else {"attention", "mlp"}
     assert {s[0] for s in scopes} == want
+
+
+def test_router_and_shared_expert_open_inside_the_expert_scope(moe_engine):
+    """``device.moe_router_share`` and ``device.moe_shared_share`` read the
+    paths ``moe/router`` and ``moe/shared``: both dots of a decode step's
+    expert layer that are not a visit's."""
+    from rbg_tpu.obs.names import MOE_INNER_SCOPES
+    inner = {path[path.index("moe") + 1]
+             for path in _paths_of_dots(_lower(moe_engine, "decode"))
+             if "moe" in path}
+    want = {"router"} | ({"shared"} if moe_engine.mcfg.moe_shared_expert
+                         else set())
+    assert want <= inner
+    assert inner & set(MOE_INNER_SCOPES) == want

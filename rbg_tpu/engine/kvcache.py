@@ -4,7 +4,9 @@ The serving engine's memory system (SGLang/vLLM-equivalent, see PAPERS.md
 "Ragged Paged Attention" for the TPU kernel this layout feeds):
 
 * Device: ``k_pages/v_pages [L, num_pages, page_size, KV, hd]`` — one shared
-  pool for all sequences, static shapes (XLA-friendly).
+  pool for all sequences, static shapes (XLA-friendly). A latent-attention
+  model's pair is the latent ``[L, NP, page, 1, dc]`` and the rotary key
+  ``[L, NP, page, 1, dr rounded up to 128]`` (``rope_pool_width``).
 * Host: ``PageAllocator`` free list + per-sequence page tables (plain ints —
   page logistics never enter the compiled graph; only gather/scatter indices
   do).
@@ -25,12 +27,33 @@ import numpy as np
 from rbg_tpu.models.config import ModelConfig
 
 
+_LANES = 128    # a TPU lane tile: the minor dim of every tiled layout
+
+
+def rope_pool_width(cfg: ModelConfig) -> int:
+    """Channels of a latent model's rotary-key pool: ``qk_rope_head_dim``
+    rounded up to whole lane tiles (64 -> 128), the rest zero. A pool whose
+    last dim is under a lane tile enters a step program in a layout with
+    the PAGE axis minor, while the step's scatter and the kernels want
+    channels minor, so every step program transposed the whole pool on the
+    way in and again on the way out (7.3 % of the device time of the
+    benchmark's joyai cell; PERF.md, PR 33). The price is a page of 4 KB
+    where it was 2 in each block's copies: the latent walk is 1.4 % slower
+    a block inside that cell's step programs. The write pads the key
+    (``models/llama.py::_pool_attention``), the attends use the pool's
+    first ``dr`` channels."""
+    return -(-cfg.qk_rope_head_dim // _LANES) * _LANES
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:
     """k/v pages [L, NP, page, KV, hd]. With int8 quantization the pages are
     int8 and per-(slot, head) scales live alongside ([L, NP, page, KV, 1]) —
-    halving KV HBM at a small accuracy cost (per-vector absmax scaling)."""
+    halving KV HBM at a small accuracy cost (per-vector absmax scaling).
+    For a latent-attention model ``k_pages`` is the latent ``[L, NP, page,
+    1, dc]`` and ``v_pages`` the shared rotary key ``[L, NP, page, 1,
+    rope_pool_width(cfg)]``, zero beyond its first ``dr`` channels."""
 
     k_pages: jnp.ndarray
     v_pages: jnp.ndarray
@@ -57,11 +80,13 @@ class PagedKVCache:
             # RoPE key — ~an order of magnitude less HBM than per-head KV.
             # int8 halves it again: per-token absmax over the latent/rope
             # vector (the write path quantizes generically — the latent is
-            # just a 1-head "KV" with dc/dr channel dims).
+            # just a 1-head "KV" with dc/dr channel dims; the rotary key's
+            # zero channels move no absmax, so its scales are what they
+            # were).
             kshape = (cfg.num_layers, num_pages, page_size, 1,
                       cfg.kv_lora_rank)
             vshape = (cfg.num_layers, num_pages, page_size, 1,
-                      cfg.qk_rope_head_dim)
+                      rope_pool_width(cfg))
             if quantize:
                 sshape = kshape[:-1] + (1,)
                 return PagedKVCache(
@@ -89,8 +114,10 @@ class PagedKVCache:
     @staticmethod
     def hbm_bytes(cfg: ModelConfig, num_pages: int, page_size: int = 16,
                   dtype_bytes: int = 2) -> int:
+        """Bytes of the two page pools ``create`` allocates (an int8
+        pool's scales are 4 bytes a (slot, head) more, not counted)."""
         if cfg.mla:
-            per_tok = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+            per_tok = cfg.kv_lora_rank + rope_pool_width(cfg)
             return (cfg.num_layers * num_pages * page_size * per_tok
                     * dtype_bytes)
         return (2 * cfg.num_layers * num_pages * page_size

@@ -57,7 +57,12 @@ def new_stream_id(prefix: str = "kvs") -> str:
 
 @dataclasses.dataclass
 class KVBundle:
-    """A sequence's transferable KV state (the whole-blob form)."""
+    """A sequence's transferable KV state (the whole-blob form): the
+    sequence's pages as the pool holds them. A latent model's ``v_data``
+    is therefore the rotary key a whole lane tile wide
+    (``kvcache.rope_pool_width``: 128 channels for a key of 64, zeros
+    beyond it), and its bundle 640 values a token a layer where the model
+    caches 576: 11 % more on the wire."""
 
     prompt: List[int]
     first_token: int

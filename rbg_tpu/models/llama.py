@@ -525,6 +525,11 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
         rows, bound = (row_ids,), {"max_q_len": max_q_len}
     if cfg.mla:
         *q, c, k_pe = _mla_qkv(cfg, blk, x, positions, lora, lora_ids)
+        # The rotary key's pool is a whole lane tile wide
+        # (``kvcache.rope_pool_width``): the key goes in zero-padded, the
+        # attends read its first ``dr`` channels.
+        k_pe = jnp.pad(k_pe, ((0, 0), (0, 0),
+                              (0, pool[1].shape[-1] - k_pe.shape[-1])))
         k, v = c[:, :, None, :], k_pe[:, :, None, :]
     else:
         q, k, v = _qkv(cfg, blk, x, positions, lora, lora_ids)

@@ -66,7 +66,9 @@ def paged_mla_attention_xla(
     q_lat: jnp.ndarray,       # [B, T, H, dc]
     q_pe: jnp.ndarray,        # [B, T, H, dr]
     c_pages: jnp.ndarray,     # [NP_layer, page, 1, dc] — this layer's pool view
-    pe_pages: jnp.ndarray,    # [NP_layer, page, 1, dr]
+    pe_pages: jnp.ndarray,    # [NP_layer, page, 1, >= dr]: the rotary key in
+                              # the first dr channels, zeros up to a whole
+                              # lane tile (kvcache.rope_pool_width)
     page_table: jnp.ndarray,  # [B, P] physical page ids (layer-offset applied)
     q_positions: jnp.ndarray,  # [B, T]
     kv_lens: jnp.ndarray,     # [B] — valid tokens post-write
@@ -88,7 +90,7 @@ def paged_mla_attention_xla(
     S = P * page
     gather = lambda pages: pages[page_table][:, :, :, 0, :].reshape(B, S, -1)
     c = gather(c_pages)
-    pe = gather(pe_pages)
+    pe = gather(pe_pages)[..., :q_pe.shape[-1]]
     if c_scales is not None:
         # int8 latent pool: dequantize the gathered view (per-token
         # absmax scales stored alongside the pages).
@@ -126,7 +128,7 @@ def ragged_paged_mla_attention_xla(
     q_lat: jnp.ndarray,        # [1, T, H, dc] packed tokens (row-major)
     q_pe: jnp.ndarray,         # [1, T, H, dr]
     c_pages: jnp.ndarray,      # [NP_layer, page, 1, dc]
-    pe_pages: jnp.ndarray,     # [NP_layer, page, 1, dr]
+    pe_pages: jnp.ndarray,     # [NP_layer, page, 1, >= dr] (first dr used)
     page_table: jnp.ndarray,   # [R, P] int32 — per ROW
     q_positions: jnp.ndarray,  # [1, T] int32 absolute positions
     kv_lens: jnp.ndarray,      # [R] int32 — post-write cache length per row

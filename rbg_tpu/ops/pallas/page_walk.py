@@ -211,14 +211,16 @@ def gqa_attend(q, k, v, ks, vs, token0, limit, m_ref, l_ref, acc_ref):
 def mla_attend(ql, qp, c, pe, cs, ps, token0, limit, scale,
                m_ref, l_ref, acc_ref):
     """The latent twin of ``gqa_attend``: ``ql [rows, dc]``, ``qp
-    [rows, dr]`` against one block ``c [S, dc]``, ``pe [S, dr]``; the
-    values are the latents. int8 pools hand per-slot scales ``cs, ps
+    [rows, dr]`` against one block ``c [S, dc]``, ``pe [S, >= dr]``, of
+    which the first ``dr`` channels are the rotary key (the pool is a
+    whole lane tile wide, zero beyond them: ``kvcache.rope_pool_width``);
+    the values are the latents. int8 pools hand per-slot scales ``cs, ps
     [S]``: the latent scale multiplies the latent score term and the
     probabilities before the value dot, the RoPE scale the RoPE term."""
     ql = ql.astype(jnp.float32)
     qp = qp.astype(jnp.float32)
     c = c.astype(jnp.float32)
-    pe = pe.astype(jnp.float32)
+    pe = pe[:, :qp.shape[-1]].astype(jnp.float32)
     s_c = jax.lax.dot_general(ql, c, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
     s_pe = jax.lax.dot_general(qp, pe, (((1,), (1,)), ((), ())),

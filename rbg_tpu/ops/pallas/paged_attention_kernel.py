@@ -214,7 +214,8 @@ def _mla_decode_kernel(
     ql_ref,           # [1, H, dc] (VMEM) — q_nope absorbed through W_uk
     qp_ref,           # [1, H, dr] — RoPE'd query part
     *refs,            # the item's pages, picked by index_map: n c refs
-                      # [1, page, dc] and n pe refs [1, page, dr]
+                      # [1, page, dc] and n pe refs [1, page, dr rounded
+                      # up to 128] (kvcache.rope_pool_width)
                       # (int8 pools: then n + n scale refs [1, page, 1]
                       # f32); out_ref [1, H, dc] — latent attention output;
                       # scratch: m [H, 1], l [H, 1], acc [H, dc]
@@ -243,8 +244,9 @@ def _mla_decode_kernel(
 
 
 def _mla_decode(q_lat, q_pe, pools, page_table, kv_lens, scale, interpret):
-    """q_lat: [B, H, dc], q_pe: [B, H, dr]; pools: c, pe pages
-    [NP, page, 1, d], and for int8 pools their scales [NP, page, 1] f32.
+    """q_lat: [B, H, dc], q_pe: [B, H, dr]; pools: c pages
+    [NP, page, 1, dc], pe pages [NP, page, 1, dr rounded up to 128], and
+    for int8 pools their scales [NP, page, 1] f32.
     Returns the latent attention output [B, H, dc]."""
     pools = W.latent_pools(*pools[:2]) + tuple(pools[2:])
     B, H, dc = q_lat.shape

@@ -311,8 +311,9 @@ def _block_ragged_mla_kernel(
     ql_ref,           # [TILE·H, dc]
     qp_ref,           # [TILE·H, dr]
     *refs,            # the item's pages: n c refs [1, page, dc] and n
-                      # pe refs [1, page, dr] (int8 pools: then n + n
-                      # scale refs [1, page, 1] f32); out_ref [TILE·H, dc];
+                      # pe refs [1, page, dr rounded up to 128] (int8
+                      # pools: then n + n scale refs [1, page, 1] f32);
+                      # out_ref [TILE·H, dc];
                       # scratch: m, l [TILE·H, 1], acc [TILE·H, dc]
     scale: float,
     tile: int,
@@ -347,8 +348,9 @@ def _block_ragged_mla_call(ql, qp, c_pages, pe_pages, c_scales, pe_scales,
                            page_table, kv_lens, row_ids, q_pos, scale,
                            interpret=False):
     """ql: [Tp·H, dc], qp: [Tp·H, dr] packed (Tp a Q_TILE multiple, H a
-    static divisor of the block); pages: [NP, page, 1, d]; scales (int8
-    pools) [NP, page, 1] f32 or None. Returns [Tp·H, dc]."""
+    static divisor of the block); pages: c [NP, page, 1, dc], pe
+    [NP, page, 1, dr rounded up to 128]; scales (int8 pools) [NP, page, 1]
+    f32 or None. Returns [Tp·H, dc]."""
     dc = ql.shape[1]
     dr = qp.shape[1]
     Tp = row_ids.shape[0]

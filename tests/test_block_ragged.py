@@ -380,3 +380,37 @@ def test_tile_segments_count_live_blocks_only():
     assert np.diff(np.asarray(starts)).tolist() == (
         [-(-300 // block), 0, 0, -(-128 // block), 0, 0, 0, 0]
         + [1] + [0] * 7)
+
+
+# ---- the rotary key's pool a whole lane tile wide (PR 33) ----
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_mla_ragged_reads_the_same_from_a_padded_rotary_pool(form, quantized):
+    """The pool as the engine holds it (``dr`` rounded up to 128 channels,
+    zeros beyond the key) against the pool ``dr`` wide: the same output,
+    ``dr`` taken from ``q_pe``."""
+    rng = np.random.RandomState(41)
+    q_specs = [(Q_TILE + 1, Q_TILE + 9), (1, 17), (3, 3)]
+    ql, qp, c, pe, table, q_pos, lens, rows, scale = _mla_pack(rng, q_specs)
+    scales = {}
+    if quantized:
+        (c, cs), (pe, pes) = quantize_kv(c), quantize_kv(pe)
+        scales = dict(c_scales=cs, pe_scales=pes)
+
+    def attend(pool):
+        if form == "xla":
+            return ragged_paged_mla_attention_xla(
+                ql, qp, c, pool, table, q_pos, lens, rows, scale, **scales)
+        if quantized:
+            return ragged_paged_mla_attention_pallas_q(
+                ql, qp, c, pool, table, q_pos, lens, rows, scale, cs, pes,
+                interpret=True)
+        return ragged_paged_mla_attention_pallas(
+            ql, qp, c, pool, table, q_pos, lens, rows, scale, interpret=True)
+
+    wide = jnp.pad(pe, ((0, 0),) * 3 + ((0, 128 - pe.shape[-1]),))
+    narrow, wide = attend(pe), attend(wide)
+    assert np.abs(np.asarray(narrow)).max() > 0
+    np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))

@@ -220,7 +220,16 @@ def test_step_programs_of_the_joyai_cell_fit_and_copy_no_expert_stack(
     ``(layer, expert)`` in place, the dense dispatch fuses the layer's
     slice into its dot) and not a copy of the latent pool, which a kernel
     handed the pool with its singleton axis cost a decode step (1.5 GB of
-    temporaries before ``page_walk.latent_pools``)."""
+    temporaries before ``page_walk.latent_pools``).
+
+    Nor does either hold a ``copy`` of a latent pool's shape at all. The
+    second copy was the rotary key's pool, ``bf16[5,8192,16,1,64]``: a last
+    dim under a lane tile gave that entry parameter a layout with the page
+    axis minor, and every step program transposed the whole pool on the
+    way in and back before the result (two pools' worth of temporaries,
+    170 MB, and 7.3 % of the cell's device time). Held a whole lane tile
+    wide (``kvcache.rope_pool_width``) it aliases through untouched: 10.5
+    and 7.0 MB of temporaries."""
     import json
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -239,9 +248,18 @@ def test_step_programs_of_the_joyai_cell_fit_and_copy_no_expert_stack(
     assert (eng.cfg.max_batch, eng.cfg.max_pages_per_seq) == (16, 256)
     compiled = (_compile_decode if program == "decode"
                 else _compile_unified)(chip, eng)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     one_matrix = 256 * 2048 * 768 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix // 2
+    assert eng.cache.v_pages.shape == (5, 8192, 16, 1, 128)
+    # A pool under any of its shapes (whole, flat over layers, without its
+    # singleton axis) has a pool's count of values.
+    pools = {eng.cache.k_pages.size, eng.cache.v_pages.size}
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert copied and not [dims for dims in copied if np.prod(
+        [int(d) for d in dims.split(",")]) in pools]
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
 
 
 # ---- the sampler's gates, at Mixtral's head and vocabulary -------------------

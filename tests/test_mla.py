@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
-from rbg_tpu.engine.kvcache import PagedKVCache
+from rbg_tpu.engine.kvcache import PagedKVCache, rope_pool_width
 from rbg_tpu.models import get_config, init_params
 from rbg_tpu.models.llama import (KVCache, forward, forward_train,
                                   prefill_and_decode_greedy)
@@ -97,7 +97,8 @@ def test_mla_kv_pool_is_smaller():
                    / (100 * 16 * mla_big.num_layers))
     gqa_per_tok = (PagedKVCache.hbm_bytes(gqa_same, 100)
                    / (100 * 16 * gqa_same.num_layers))
-    # 576 * 2 bytes vs 2*8*128*2 bytes per token-layer → ~3.6x smaller
+    # 640 * 2 bytes (the rotary key held 128 wide) vs 2*8*128*2 bytes per
+    # token-layer → 3.2x smaller
     assert mla_per_tok * 3 < gqa_per_tok
 
 
@@ -193,7 +194,7 @@ def test_mla_decode_service_warm_bundle_shapes():
     try:
         b = svc._warm_item(16, 0, 0)
         assert b.k_data.shape[4] == CFG.kv_lora_rank
-        assert b.v_data.shape[4] == CFG.qk_rope_head_dim
+        assert b.v_data.shape[4] == rope_pool_width(CFG) == 128
         # And the bundle actually injects + decodes (the crash site).
         toks = svc.submit_bundle(b, SamplingParams(max_new_tokens=2),
                                  timeout=240)
@@ -229,3 +230,99 @@ def test_mla_int8_latent_pool_numerics():
     # Pages balance after generation (quantized pool accounting intact).
     assert not q.running and not q.waiting
     assert q.allocator.free_pages == q.cfg.num_pages - 1  # null page
+
+
+# ---- the rotary key's pool, a whole lane tile wide (PR 33) -------------------
+
+
+@pytest.mark.parametrize("quantize", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("model", ["tiny-mla", "tiny-joyai",
+                                   "deepseek-v2-lite"])
+def test_latent_pool_is_whole_lane_tiles_wide(model, quantize):
+    """``v_pages`` of a latent model is ``dr`` rounded up to 128 channels
+    (a last dim under a lane tile gives the pool a page-minor layout on the
+    chip, and every step program transposed it whole twice); the latent's
+    pool and the scales keep their shapes; ``hbm_bytes`` counts what
+    ``create`` allocates."""
+    cfg = get_config(model)
+    cache = PagedKVCache.create(cfg, 8, 4, quantize=quantize)
+    width = cache.v_pages.shape[-1]
+    assert width % 128 == 0 and 0 <= width - cfg.qk_rope_head_dim < 128
+    assert cache.k_pages.shape == (cfg.num_layers, 8, 4, 1, cfg.kv_lora_rank)
+    assert cache.v_pages.shape[:-1] == cache.k_pages.shape[:-1]
+    assert cache.v_pages.dtype == cache.k_pages.dtype == (
+        jnp.int8 if quantize else cfg.jax_dtype)
+    if quantize:
+        assert cache.v_scales.shape == cache.k_scales.shape == (
+            cfg.num_layers, 8, 4, 1, 1)
+    assert PagedKVCache.hbm_bytes(
+        cfg, 8, 4, dtype_bytes=cache.k_pages.dtype.itemsize) == (
+        cache.k_pages.nbytes + cache.v_pages.nbytes)
+
+
+@pytest.mark.parametrize("quantize", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("packed", [False, True], ids=["row", "ragged"])
+def test_latent_step_leaves_the_pool_padding_zero(packed, quantize):
+    """After a step of a latent model (``write_kv_pages`` for rows,
+    ``write_kv_pages_ragged`` for a packed batch) the rotary-key pool holds
+    the keys in its first ``dr`` channels and zeros beyond, in every slot."""
+    from rbg_tpu.models.llama import forward_paged, forward_ragged
+    cache = PagedKVCache.create(CFG, 16, 4, quantize=quantize)
+    dr = CFG.qk_rope_head_dim
+    table = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0]], jnp.int32)
+    lens = jnp.asarray([9, 5], jnp.int32)
+    pool = (cache.k_pages, cache.v_pages)
+    scales = dict(k_scales=cache.k_scales, v_scales=cache.v_scales)
+    if packed:
+        T = 14
+        rows = jnp.asarray([0] * 9 + [1] * 5, jnp.int32)
+        pos = jnp.asarray([list(range(9)) + list(range(5))], jnp.int32)
+        toks = (jnp.arange(T, dtype=jnp.int32)[None] * 7) % CFG.vocab_size
+        out = forward_ragged(PARAMS, CFG, toks, pos, jnp.ones((1, T), bool),
+                             rows, lens, table, *pool, use_pallas="never",
+                             max_q_len=9, **scales)
+    else:
+        pos = jnp.broadcast_to(jnp.arange(9, dtype=jnp.int32), (2, 9))
+        mask = pos < lens[:, None]
+        toks = (pos * 7 + 3) % CFG.vocab_size
+        out = forward_paged(PARAMS, CFG, toks, pos, mask, lens, table, *pool,
+                            use_pallas="never", **scales)
+    v = np.asarray(out[2].astype(jnp.float32))
+    assert v.shape[-1] == 128 and not v[..., dr:].any()
+    written = np.abs(v[..., :dr]).sum(-1)[:, :, :, 0]       # [L, NP, page]
+    assert (written[:, [1, 2, 4]] > 0).all() and (written[:, 3, 0] > 0).all()
+    assert (written[:, 5, 0] > 0).all() and not written[:, 5, 1:].any()
+    assert not written[:, [0] + list(range(6, 16))].any()
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["row", "ragged"])
+def test_int8_rotary_scales_are_those_of_the_unpadded_keys(packed):
+    """The int8 pool's per-token scale is an absmax, which zero channels do
+    not move: the padded key's values and scales are bit-equal to
+    ``quantize_kv`` of the key as the model made it."""
+    from rbg_tpu.ops.paged_attention import quantize_kv, write_kv_pages
+    from rbg_tpu.ops.ragged_paged_attention import write_kv_pages_ragged
+    rng = np.random.RandomState(5)
+    T, dc, dr, page, NP = 6, 64, 16, 4, 8
+    c = jnp.asarray(rng.randn(1, T, 1, dc), jnp.float32)
+    k_pe = jnp.asarray(rng.randn(1, T, 1, dr), jnp.float32)
+    padded = jnp.pad(k_pe, ((0, 0),) * 3 + ((0, 128 - dr),))
+    pools = (jnp.zeros((NP, page, 1, dc), jnp.int8),
+             jnp.zeros((NP, page, 1, 128), jnp.int8))
+    scales = (jnp.zeros((NP, page, 1, 1), jnp.float32),) * 2
+    table = jnp.asarray([[3, 5]], jnp.int32)
+    pos = jnp.arange(T, dtype=jnp.int32)[None]
+    mask = jnp.ones((1, T), bool)
+    if packed:
+        _, v, _, vs = write_kv_pages_ragged(
+            *pools, c, padded, table, jnp.zeros(T, jnp.int32), pos, mask,
+            *scales)
+    else:
+        _, v, _, vs = write_kv_pages(*pools, c, padded, table, pos, mask,
+                                     *scales)
+    want_q, want_s = quantize_kv(k_pe[0])                   # [T, 1, dr], [T,1,1]
+    got = np.asarray(v)[[3, 5]].reshape(2 * page, 1, 128)[:T]
+    got_s = np.asarray(vs)[[3, 5]].reshape(2 * page, 1, 1)[:T]
+    np.testing.assert_array_equal(got[..., :dr], np.asarray(want_q))
+    assert not got[..., dr:].any()
+    np.testing.assert_array_equal(got_s, np.asarray(want_s))

@@ -374,3 +374,36 @@ def test_decode_empty_row_finalizes_to_zero():
     ref = np.asarray(paged_attention_xla(q, k, v, table, q_pos, lens))
     assert np.all(got[1] == 0)
     np.testing.assert_allclose(got[[0, 2]], ref[[0, 2]], rtol=1e-5, atol=1e-5)
+
+
+# ---- the rotary key's pool a whole lane tile wide (PR 33) ----
+#
+# The engine holds that pool ``dr`` rounded up to 128 channels, zeros
+# beyond the key; an attend takes ``dr`` from ``q_pe`` and uses the pool's
+# first ``dr`` channels, whatever its width.
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_mla_decode_reads_the_same_from_a_padded_rotary_pool(form, quantized):
+    ql, qp, c, pe, table, q_pos, lens, scale = _mla_setup(seed=31)
+    scales = {}
+    if quantized:
+        (c, cs), (pe, pes) = quantize_kv(c), quantize_kv(pe)
+        scales = dict(c_scales=cs, pe_scales=pes)
+
+    def attend(pool):
+        if form == "xla":
+            return paged_mla_attention_xla(ql, qp, c, pool, table, q_pos,
+                                           lens, scale, **scales)
+        if quantized:
+            return paged_mla_attention_pallas_q(
+                ql, qp, c, pool, table, q_pos, lens, scale, cs, pes,
+                interpret=True)
+        return paged_mla_attention_pallas(ql, qp, c, pool, table, q_pos,
+                                          lens, scale, interpret=True)
+
+    wide = jnp.pad(pe, ((0, 0),) * 3 + ((0, 128 - pe.shape[-1]),))
+    narrow, wide = attend(pe), attend(wide)
+    assert np.abs(np.asarray(narrow)).max() > 0
+    np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))

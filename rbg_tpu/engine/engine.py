@@ -35,7 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from rbg_tpu.engine.config import EngineConfig, SamplingParams
-from rbg_tpu.engine.kvcache import PageAllocator, PagedKVCache, pages_for_tokens
+from rbg_tpu.engine.kvcache import (PageAllocator, PagedKVCache, StatePool,
+                                    pages_for_tokens)
 from rbg_tpu.engine.radix_cache import RadixCache
 from rbg_tpu.engine.sampler import NEG_INF, row_keys, sample, step_keys
 from rbg_tpu.obs.names import (PROGRAM_FUSED_DECODE, PROGRAM_PAGED_FWD,
@@ -118,6 +119,7 @@ class Request:
         self.output: List[int] = []
         self.state = "waiting"          # waiting | prefill | running | finished
         self.pages: List[int] = []
+        self.state_slot: Optional[int] = None   # recurrent-state pool slot
         self.shared_tokens = 0          # radix-matched prefix (page-aligned)
         self.prefill_pos = 0            # next prompt index to prefill
         self.seq_len = 0                # tokens materialized in KV
@@ -173,6 +175,12 @@ class Engine:
         self.cache = PagedKVCache.create(self.mcfg, cfg.num_pages, cfg.page_size,
                                          quantize=(cfg.kv_dtype == "int8"))
         self.allocator = PageAllocator(cfg.num_pages)
+        # A model with recurrent layers keeps a state slot a row beside
+        # the pages (of its attention layers alone).
+        self.state: Optional[StatePool] = None
+        if self.mcfg.recurrent:
+            self._refuse_for_recurrent()
+            self.state = StatePool(self.mcfg, cfg.max_batch)
         self.radix = RadixCache(self.allocator, cfg.page_size) if cfg.enable_radix_cache else None
         # Host-DRAM spill tier under the device pool (engine/kvtier.py):
         # radix evictions spill into it, admission promotes out of it.
@@ -187,6 +195,9 @@ class Engine:
         # A decode step may read hit experts only where every device holds
         # every expert (parallel/sharding.py splits them over ``ep``).
         self._experts_whole = mesh is None or mesh.shape.get("ep", 1) == 1
+
+        # Step programs take the state pool by keyword and give it back.
+        self._donate_state = ("state",) if self.state is not None else ()
 
         self.waiting: List[Request] = []
         self.running: List[Request] = []
@@ -252,6 +263,17 @@ class Engine:
                         # and how many of them the step read.
                         "moe_expert_slots": 0, "moe_experts_visited": 0,
                         "moe_routed_rows": 0,
+                        # The recurrent-state pool (a model with recurrent
+                        # layers), per step that dispatched: slots held
+                        # and slots whose row the step advanced; slots
+                        # handed out at admission; bytes those rows'
+                        # states move if every recurrent layer reads and
+                        # writes each once; admissions that skipped the
+                        # prefix lookup (a hit would need the state at
+                        # the prefix's end).
+                        "state_slots_held": 0, "state_slots_live": 0,
+                        "state_resets": 0, "state_bytes_moved": 0,
+                        "prefix_skipped": 0,
                         # Runs of ``sample`` as dispatched (a fused window
                         # is one a step), and those of them in which some
                         # sampling row set top-k, top-p or min-p: the ones
@@ -271,6 +293,58 @@ class Engine:
             maxlen=STEP_RING)
         self._ring_dropped = 0
         self._ring_dropped_t0 = 0.0
+
+    def _refuse_for_recurrent(self) -> None:
+        """What is not built for a model with recurrent layers, refused
+        here, in one place (prefix reuse is bypassed in ``_admit`` and
+        ``_finish``; LoRA is refused by ``load_lora``, a model of several
+        groups)."""
+        cfg, why = self.cfg, None
+        if cfg.speculative != "off":
+            why = ("speculative decoding: a rejected draft would have to "
+                   "be taken out of the state again")
+        elif cfg.kv_dtype == "int8":
+            why = "kv_dtype int8: the recurrent state is float32 only"
+        elif cfg.mode != "unified":
+            why = (f"mode {cfg.mode!r}: a PD bundle carries pages, not the "
+                   f"recurrent state")
+        elif cfg.host_tier_bytes:
+            why = ("host_tier_bytes: the host tier keeps prefixes, which a "
+                   "recurrent model cannot reuse")
+        elif self.mesh is not None:
+            why = "a device mesh: the state pool has no sharding"
+        if why:
+            raise ValueError(
+                f"model {self.mcfg.name!r} has recurrent layers "
+                f"(kda_layers), which do not support {why}")
+
+    def _slot_rows(self, reqs, B: int):
+        """``[B]`` state slots of ``reqs`` in row order, on the device; a
+        row of padding names a slot out of range, so its write is
+        dropped."""
+        slots = np.full(B, self.state.slots, np.int32)
+        for i, r in enumerate(reqs):
+            slots[i] = r.state_slot
+        return jnp.asarray(slots)
+
+    def _state_kw(self, reqs, B: int) -> dict:
+        """The keyword arguments by which a step program of a model with
+        recurrent layers gets the state pool and its rows' slots."""
+        if self.state is None:
+            return {}
+        return {"state": self.state.arrays, "slots": self._slot_rows(reqs, B)}
+
+    def _put_pools(self, kp, vp, ksc, vsc, state=None) -> None:
+        """Take back the pools a step program was given (donated)."""
+        self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
+                                  k_scales=ksc, v_scales=vsc)
+        if state is not None:
+            self.state.arrays = state
+
+    def _release_slot(self, req: "Request") -> None:
+        if req.state_slot is not None:
+            self.state.release(req.state_slot)
+            req.state_slot = None
 
     def _shard_state(self, mesh):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -628,6 +702,10 @@ class Engine:
         page-aligned and < len(prompt) (the last token always prefills for
         logits). Returns None when no pages are free (caller falls back to
         a cold prefill through the normal admission queue)."""
+        if self.state is not None:
+            raise ValueError(
+                f"model {self.mcfg.name!r} has recurrent layers: a prefix's "
+                f"pages without the state at its end cannot be resumed")
         sampling = sampling or SamplingParams()
         self._check_prompt(prompt)
         self._grammar_check(sampling)
@@ -719,6 +797,10 @@ class Engine:
         if self._dispatched is None:
             self._dispatched = (kind, rows, q_tokens,
                                 (row_bucket, token_bucket))
+            if self.state is not None:
+                # Held as the step starts: a row that finishes in this
+                # step frees its slot before the step is recorded.
+                self._slots_at_dispatch = self.state.held
 
     def _record_step(self, t0: float, t_end: float, ann) -> None:
         """Account one step that dispatched: its wall time by kind, the
@@ -743,6 +825,14 @@ class Engine:
             held += len(r.pages)
         m["kv_live_token_steps"] += live
         m["kv_held_slot_steps"] += held * self.cfg.page_size
+        if self.state is not None:
+            # A fused window advances each row once a step, a unified step
+            # each of its rows once: ``q_tokens`` of the former, ``rows`` of
+            # the latter, are the row-steps whose state moved.
+            moved = q_tokens if kind == "decode" else rows
+            m["state_slots_held"] += self._slots_at_dispatch
+            m["state_slots_live"] += rows
+            m["state_bytes_moved"] += moved * self.state.row_bytes
         marks = self._marks
         prev = marks[_PACK]
         for i in (_DISPATCH, _SYNC, _EMIT):
@@ -798,7 +888,13 @@ class Engine:
             req = self.waiting[0]
             matched, shared_pages = 0, []
             radix_matched = host_matched = 0
-            if (self.radix is not None and req.state == "waiting"
+            if self.state is not None:
+                # No prefix reuse for a model with recurrent layers: a hit
+                # would have to restore the state at the prefix's end, and
+                # nothing keeps it (``_finish`` inserts nothing either).
+                if self.radix is not None:
+                    self.metrics["prefix_skipped"] += 1
+            elif (self.radix is not None and req.state == "waiting"
                     and req.lora_idx == 0):
                 # Keep at least the prompt's last token for prefill (logits).
                 # Adapter requests skip the prefix cache: their KV differs
@@ -840,6 +936,10 @@ class Engine:
             del self.last_join_waits[:-1024]
             req.blocked_steps = 0
             req.pages = shared_pages + pages
+            if self.state is not None:
+                # Its rows start at position 0, which is what zeroes it.
+                req.state_slot = self.state.take()
+                self.metrics["state_resets"] += 1
             req.shared_tokens = matched
             req.prefill_pos = matched
             req.seq_len = matched
@@ -1000,16 +1100,18 @@ class Engine:
 
             def wrapped(params, tokens, positions, token_mask, row_ids,
                         kv_lens, page_table, k_pages, v_pages, k_scales,
-                        v_scales):
+                        v_scales, state=None, slots=None):
                 return base(params, tokens=tokens, positions=positions,
                             token_mask=token_mask, row_ids=row_ids,
                             kv_lens=kv_lens, page_table=page_table,
                             k_pages=k_pages, v_pages=v_pages,
-                            k_scales=k_scales, v_scales=v_scales)
+                            k_scales=k_scales, v_scales=v_scales,
+                            state=state, state_slots=slots)
 
             wrapped.__name__ = PROGRAM_RAGGED_FWD   # jitwatch catalog name
             donate = (7, 8, 9, 10) if self.cache.quantized else (7, 8)
-            fn = jax.jit(wrapped, donate_argnums=donate)
+            fn = jax.jit(wrapped, donate_argnums=donate,
+                         donate_argnames=self._donate_state)
             self._ragged_fn_cache[(R, T, RAGGED_GRID_REV)] = fn
         return fn
 
@@ -1031,12 +1133,14 @@ class Engine:
         n = 0
         buckets = sorted({self._bucket(b)
                           for b in range(1, self.cfg.max_batch + 1)})
-        for R in buckets:
-            t = 8
+        for i, R in enumerate(buckets):
+            # A step of this row bucket holds more rows than the bucket
+            # below, and a token a row at least: no smaller pack exists.
+            t = self._token_bucket(buckets[i - 1] + 1 if i else 1)
             t_max = self._token_bucket(R * self.cfg.prefill_chunk)
             while True:
                 fn = self._get_ragged_fn(R, t)
-                _, kp, vp, ksc, vsc = fn(
+                _, *pools = fn(
                     self.params,
                     jnp.zeros((1, t), jnp.int32),
                     jnp.full((1, t), -1, jnp.int32),       # all pad
@@ -1045,9 +1149,9 @@ class Engine:
                     jnp.zeros((R,), jnp.int32),
                     jnp.zeros((R, P), jnp.int32),
                     self.cache.k_pages, self.cache.v_pages,
-                    self.cache.k_scales, self.cache.v_scales)
-                self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                          k_scales=ksc, v_scales=vsc)
+                    self.cache.k_scales, self.cache.v_scales,
+                    **self._state_kw([], R))
+                self._put_pools(*pools)
                 n += 1
                 if t >= t_max:
                     break
@@ -1081,7 +1185,7 @@ class Engine:
             # is written and pos/kvl never advance — the donated pool
             # buffers round-trip unchanged (tok/pos/kvl/limit are
             # separate arrays: pos and kvl are donated, tok is not).
-            *_, kp, vp, ksc, vsc, _, _ = fn(
+            out = fn(
                 self.params, jnp.zeros(B, jnp.int32),
                 jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
                 jnp.zeros((B, P), jnp.int32), jnp.zeros((B, 1), bool),
@@ -1090,9 +1194,8 @@ class Engine:
                 self.cache.k_scales, self.cache.v_scales,
                 row_keys(seeds, self._sample_base, rids),
                 jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
-                jnp.asarray(mps))
-            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                      k_scales=ksc, v_scales=vsc)
+                jnp.asarray(mps), **self._state_kw([], B))
+            self._put_pools(*out[6:10], *out[12:])
             n += 1
         return n
 
@@ -1126,7 +1229,7 @@ class Engine:
             # mid-serving): no KV slot is written and pos/kvl never
             # advance — the donated pool buffers round-trip unchanged
             # (see warm_join_windows).
-            *_, kp, vp, ksc, vsc, _, _ = fn(
+            out = fn(
                 self.params, jnp.zeros(B, jnp.int32),
                 jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
                 jnp.zeros((B, P), jnp.int32), jnp.zeros((B, 1), bool),
@@ -1135,9 +1238,8 @@ class Engine:
                 self.cache.k_scales, self.cache.v_scales,
                 row_keys(seeds, self._sample_base, rids),
                 jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
-                jnp.asarray(mps))
-            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                      k_scales=ksc, v_scales=vsc)
+                jnp.asarray(mps), **self._state_kw([], B))
+            self._put_pools(*out[6:10], *out[12:])
             n += 1
         return n
 
@@ -1215,11 +1317,11 @@ class Engine:
         with _Phase(self, _DISPATCH):
             self._note_dispatch("unified", len(entries), Ttot, Rb, Tb)
             fn = self._get_ragged_fn(Rb, Tb)
-            logits, kp, vp, ksc, vsc = fn(
+            logits, *pools = fn(
                 self.params, *dev, self.cache.k_pages, self.cache.v_pages,
-                self.cache.k_scales, self.cache.v_scales)
-            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                      k_scales=ksc, v_scales=vsc)
+                self.cache.k_scales, self.cache.v_scales,
+                **self._state_kw([r for r, _, _ in entries], Rb))
+            self._put_pools(*pools)
 
             # Host bookkeeping for prefill rows (before emission, matching
             # the legacy order: seq_len is advanced, then the finish token
@@ -1572,11 +1674,14 @@ class Engine:
                 self.metrics["moe_experts_visited"] += int(visited_dev)
                 moe_layers = self.mcfg.num_moe_layers
                 self.metrics["moe_expert_slots"] += (
-                    len(vals) * moe_layers * self.mcfg.num_experts)
+                    len(vals) * moe_layers * self.mcfg.experts_here)
                 # The (row, expert) pairs those visits served: a row is live
                 # in ``valid`` steps of the window, as the device masks it.
+                # Of a held range of the experts, the pairs that fall on it
+                # where routing is uniform (what the host can know).
                 self.metrics["moe_routed_rows"] += (
-                    sum(valid) * moe_layers * self.mcfg.experts_per_token)
+                    sum(valid) * moe_layers * self.mcfg.experts_per_token
+                    * self.mcfg.experts_here // self.mcfg.num_experts)
             for i, req in enumerate(rows):
                 for k in range(valid[i]):
                     if req.state != "running":
@@ -1656,9 +1761,9 @@ class Engine:
                   v_pages, k_scales, v_scales, keys, temps, ks, tps, mps,
                   pmask=None, ocounts=None, rep=None, pres=None, freq=None,
                   lora=None, lids=None, gnext=None, glegal=None,
-                  gstate=None, gactive=None):
+                  gstate=None, gactive=None, state=None, slots=None):
             def body(carry, _):
-                tok, pos, kvl, kp, vp, ksc, vsc, oc, gs = carry
+                tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st = carry
                 # Rows at their length limit (mid-window finishers) stop
                 # writing KV and stop advancing — their sampled values are
                 # discarded host-side via the per-row valid count.
@@ -1667,7 +1772,9 @@ class Engine:
                     params, tokens=tok[:, None], positions=pos[:, None],
                     token_mask=write_ok, kv_lens=kvl, page_table=table,
                     k_pages=kp, v_pages=vp, k_scales=ksc, v_scales=vsc,
-                    lora=lora, lora_ids=lids)
+                    lora=lora, lora_ids=lids, state=st, state_slots=slots)
+                if st is not None:           # the new state follows the pools
+                    st = visited.pop(0)
                 # The experts a hit-only step visited; none to count where
                 # the experts are sharded or the step is dense.
                 visited = visited[0] if visited else None
@@ -1696,20 +1803,22 @@ class Engine:
                 pos = jnp.where(active, pos + 1, pos)
                 kvl = jnp.where(active, kvl + 1, kvl)
                 tok = jnp.where(active, toks, tok)
-                return (tok, pos, kvl, kp, vp, ksc, vsc, oc, gs), (
+                return (tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st), (
                     toks, lps if lp else None, visited)
 
             oc0 = ocounts if pen else jnp.zeros((), jnp.int32)
             gs0 = gstate if gr else jnp.zeros((), jnp.int32)
             carry, ys = jax.lax.scan(
                 body, (tok, pos, kvl, k_pages, v_pages, k_scales, v_scales,
-                       oc0, gs0), None, length=K)
-            tok, pos, kvl, kp, vp, ksc, vsc, oc, gs = carry
+                       oc0, gs0, state), None, length=K)
+            tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st = carry
             toks_seq, lp_seq, visited = ys
             if visited is not None:          # hit experts only: the window's
                 visited = visited.sum()      # count rides out beside the tokens
-            return (toks_seq, lp_seq, visited, tok, pos, kvl, kp, vp, ksc,
-                    vsc, oc, gs)
+            out = (toks_seq, lp_seq, visited, tok, pos, kvl, kp, vp, ksc,
+                   vsc, oc, gs)
+            # A model with recurrent layers: its state pool comes last.
+            return out if st is None else out + (st,)
 
         # tok is NOT donated: the pending fetch reads last window's output
         # after it has been fed back as this window's input. keys is reused
@@ -1719,7 +1828,8 @@ class Engine:
         if pen:
             donate.append(17)  # ocounts
         fused.__name__ = PROGRAM_FUSED_DECODE   # jitwatch catalog name
-        fn = jax.jit(fused, donate_argnums=tuple(donate))
+        fn = jax.jit(fused, donate_argnums=tuple(donate),
+                     donate_argnames=self._donate_state)
         self._dec_fn_cache[(B, pen, lp, la, gr, K)] = fn
         return fn
 
@@ -1754,6 +1864,8 @@ class Engine:
             "table_np": table, "table": jnp.asarray(table),
             "pending": None,
         }
+        if self.state is not None:
+            st["slots"] = self._slot_rows(batch, B)
         if pen:
             pmask, oc, rep, pres, freq = self._penalty_rows(batch, B)
             for i, r in enumerate(batch):
@@ -1823,14 +1935,15 @@ class Engine:
             if st["gr"]:
                 kw.update(gnext=st["gnext"], glegal=st["glegal"],
                           gstate=st["gstate"], gactive=st["gactive"])
+            if self.state is not None:
+                kw.update(state=self.state.arrays, slots=st["slots"])
             (toks_seq, lp_seq, visited, tok, pos, kvl, kp, vp, ksc, vsc, oc,
-             gs) = fn(
+             gs, *state) = fn(
                 self.params, st["tok"], st["pos"], st["kvl"], st["table"],
                 st["mask"], st["limit"], self.cache.k_pages,
                 self.cache.v_pages, self.cache.k_scales, self.cache.v_scales,
                 st["keys"], st["temps"], st["ks"], st["tps"], st["mps"], **kw)
-            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                      k_scales=ksc, v_scales=vsc)
+            self._put_pools(kp, vp, ksc, vsc, *state)
             if visited is not None:
                 # On the host by the time the lagged fetch reads it: a
                 # second blocking read a step would cost 0.3 ms of emit.
@@ -2098,7 +2211,7 @@ class Engine:
                                 int(mask.sum()), B, B * T)
             self._note_sampler(sorts)
             fn = self._get_spec_fn(B, lp, pen, gr, lids is not None)
-            toks_out, lps_out, kp, vp, ksc, vsc = fn(
+            toks_out, lps_out, *pools = fn(
                 self.params, jnp.asarray(tok), jnp.asarray(pos),
                 jnp.asarray(mask), jnp.asarray(kvl), jnp.asarray(table),
                 self.cache.k_pages, self.cache.v_pages,
@@ -2106,8 +2219,7 @@ class Engine:
                 row_keys(seeds, self._sample_base, rids),
                 jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(tps),
                 jnp.asarray(mps), **kw)
-            self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                      k_scales=ksc, v_scales=vsc)
+            self._put_pools(*pools)
         with _Phase(self, _SYNC):
             vals = np.asarray(toks_out)                       # [T, B]
             lpv = np.asarray(lps_out) if lps_out is not None else None
@@ -2161,9 +2273,12 @@ class Engine:
             # exports them to a decode peer, then calls release_request().
             req.state = "exported"
             return
-        if self.radix is not None and req.lora_idx == 0:
+        self._release_slot(req)
+        if (self.radix is not None and req.lora_idx == 0
+                and self.state is None):
             # Cache the full sequence (prompt + output) for future prefixes
-            # (base-model requests only — adapter KV must not cross-match).
+            # (base-model requests only — adapter KV must not cross-match;
+            # never a model with recurrent layers: see ``_admit``).
             self.radix.insert(req.prompt + req.output[:-1], req.pages)
             if self.host_tier is not None:
                 self._publish_tier_gauges()
@@ -2192,6 +2307,7 @@ class Engine:
         if req.pages:
             self.allocator.release(req.pages)
             req.pages = []
+        self._release_slot(req)
         self.requests.pop(req_id, None)
         return True
 
@@ -2199,6 +2315,8 @@ class Engine:
         self.metrics["preemptions"] += 1
         self.allocator.release(req.pages)
         req.pages = []
+        # Its next admission prefills from position 0 into a fresh slot.
+        self._release_slot(req)
         req.state = "waiting"
         req.prefill_pos = 0
         req.seq_len = 0
@@ -2246,16 +2364,18 @@ class Engine:
 
             def wrapped(params, tokens, positions, token_mask, kv_lens,
                         page_table, k_pages, v_pages, k_scales, v_scales,
-                        lora=None, lids=None):
+                        lora=None, lids=None, state=None, slots=None):
                 return base(params, tokens=tokens, positions=positions,
                             token_mask=token_mask, kv_lens=kv_lens,
                             page_table=page_table, k_pages=k_pages,
                             v_pages=v_pages, k_scales=k_scales,
-                            v_scales=v_scales, lora=lora, lora_ids=lids)
+                            v_scales=v_scales, lora=lora, lora_ids=lids,
+                            state=state, state_slots=slots)
 
             wrapped.__name__ = PROGRAM_PAGED_FWD   # jitwatch catalog name
             donate = (6, 7, 8, 9) if self.cache.quantized else (6, 7)
-            fn = jax.jit(wrapped, donate_argnums=donate)
+            fn = jax.jit(wrapped, donate_argnums=donate,
+                         donate_argnames=self._donate_state)
             self._fwd_cache[key] = fn
         return fn
 
@@ -2282,12 +2402,12 @@ class Engine:
             lids = self._lora_rows(reqs, B) if reqs is not None else None
             kw = ({"lora": self.lora_stack, "lids": lids}
                   if lids is not None else {})
+            kw.update(self._state_kw(reqs or [], B))
             dev = [jnp.asarray(a) for a in (tok, pos, mask, kvl, table)]
         with _Phase(self, _DISPATCH):
             fn = self._get_fwd(B, T, lids is not None)
-            logits, k_pages, v_pages, k_scales, v_scales = fn(
+            logits, *pools = fn(
                 self.params, *dev, self.cache.k_pages, self.cache.v_pages,
                 self.cache.k_scales, self.cache.v_scales, **kw)
-            self.cache = PagedKVCache(k_pages=k_pages, v_pages=v_pages,
-                                      k_scales=k_scales, v_scales=v_scales)
+            self._put_pools(*pools)
         return logits  # device array; callers slice what they need

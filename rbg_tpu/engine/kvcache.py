@@ -11,6 +11,11 @@ The serving engine's memory system (SGLang/vLLM-equivalent, see PAPERS.md
   page logistics never enter the compiled graph; only gather/scatter indices
   do).
 
+A model with recurrent layers (``cfg.kda_layers``) keeps pages for its
+attention layers alone (``[attention layers, NP, ...]``, a layer's pages by
+its ordinal among them) and, beside them, a ``StatePool``: a slot a live
+row, holding each recurrent layer's fixed state.
+
 Sharding: pages shard over ``tp`` on the KV-head dim like the contiguous
 cache (see rbg_tpu.parallel.sharding.cache_specs).
 """
@@ -18,6 +23,7 @@ cache (see rbg_tpu.parallel.sharding.cache_specs).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Optional
 
 import jax
@@ -75,6 +81,7 @@ class PagedKVCache:
     @staticmethod
     def create(cfg: ModelConfig, num_pages: int, page_size: int = 16,
                dtype=None, quantize: bool = False) -> "PagedKVCache":
+        layers = paged_layer_count(cfg)
         if cfg.mla:
             # MLA latent pool: k holds the compressed latent, v the shared
             # RoPE key — ~an order of magnitude less HBM than per-head KV.
@@ -83,10 +90,8 @@ class PagedKVCache:
             # just a 1-head "KV" with dc/dr channel dims; the rotary key's
             # zero channels move no absmax, so its scales are what they
             # were).
-            kshape = (cfg.num_layers, num_pages, page_size, 1,
-                      cfg.kv_lora_rank)
-            vshape = (cfg.num_layers, num_pages, page_size, 1,
-                      rope_pool_width(cfg))
+            kshape = (layers, num_pages, page_size, 1, cfg.kv_lora_rank)
+            vshape = (layers, num_pages, page_size, 1, rope_pool_width(cfg))
             if quantize:
                 sshape = kshape[:-1] + (1,)
                 return PagedKVCache(
@@ -98,7 +103,7 @@ class PagedKVCache:
             dtype = dtype or cfg.jax_dtype
             return PagedKVCache(k_pages=jnp.zeros(kshape, dtype),
                                 v_pages=jnp.zeros(vshape, dtype))
-        shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim_)
+        shape = (layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim_)
         if quantize:
             sshape = shape[:-1] + (1,)
             return PagedKVCache(
@@ -115,13 +120,72 @@ class PagedKVCache:
     def hbm_bytes(cfg: ModelConfig, num_pages: int, page_size: int = 16,
                   dtype_bytes: int = 2) -> int:
         """Bytes of the two page pools ``create`` allocates (an int8
-        pool's scales are 4 bytes a (slot, head) more, not counted)."""
+        pool's scales are 4 bytes a (slot, head) more, not counted). A
+        model with recurrent layers holds ``StatePool.hbm_bytes`` more."""
+        layers = paged_layer_count(cfg)
         if cfg.mla:
             per_tok = cfg.kv_lora_rank + rope_pool_width(cfg)
-            return (cfg.num_layers * num_pages * page_size * per_tok
-                    * dtype_bytes)
-        return (2 * cfg.num_layers * num_pages * page_size
+            return layers * num_pages * page_size * per_tok * dtype_bytes
+        return (2 * layers * num_pages * page_size
                 * cfg.num_kv_heads * cfg.head_dim_ * dtype_bytes)
+
+
+def paged_layer_count(cfg: ModelConfig) -> int:
+    """Layers that keep pages: all but the recurrent ones."""
+    return cfg.num_layers - len(cfg.kda_layers)
+
+
+class StatePool:
+    """The recurrent layers' cache: ``slots`` slots, one a live row, each
+    holding for every recurrent layer the state ``[H, dk, dk]`` in float32
+    and the convolution's tail, the last ``K - 1`` inputs of q, k and v,
+    flat (``models/llama.py::_kda_attention`` reads and writes them; the step
+    programs carry ``arrays`` as they carry the pages). The host side is a
+    free list. A slot is never cleared on the device: a row whose tokens
+    start at position 0 starts from zeros in the step program itself, so a
+    slot taken at admission is a zero state whatever it held."""
+
+    def __init__(self, cfg: ModelConfig, slots: int):
+        self.slots = slots
+        self.arrays = {
+            "s": jnp.zeros(self.state_shape(cfg, slots), jnp.float32),
+            "conv": jnp.zeros(self.tail_shape(cfg, slots), cfg.jax_dtype)}
+        self._free: List[int] = list(range(slots - 1, -1, -1))
+        # Bytes one live row's state moves a step if each recurrent layer
+        # reads and writes it once (the wire counter ``state_bytes_moved``).
+        self.row_bytes = 2 * sum(a.nbytes for a in self.arrays.values()) \
+            // slots
+
+    @staticmethod
+    def state_shape(cfg: ModelConfig, slots: int):
+        return (len(cfg.kda_layers), slots, cfg.kda_num_heads,
+                cfg.kda_head_dim, cfg.kda_head_dim)
+
+    @staticmethod
+    def tail_shape(cfg: ModelConfig, slots: int):
+        # The taps side by side on the minor axis: a second-minor axis of
+        # K - 1 = 3 would be padded to a whole tile of 16.
+        return (len(cfg.kda_layers), slots, (cfg.kda_conv_kernel - 1)
+                * 3 * cfg.kda_num_heads * cfg.kda_head_dim)
+
+    @staticmethod
+    def hbm_bytes(cfg: ModelConfig, slots: int) -> int:
+        """Bytes of the arrays a pool of ``slots`` slots allocates."""
+        return (4 * math.prod(StatePool.state_shape(cfg, slots))
+                + cfg.jax_dtype.itemsize
+                * math.prod(StatePool.tail_shape(cfg, slots)))
+
+    @property
+    def held(self) -> int:
+        return self.slots - len(self._free)
+
+    def take(self) -> int:
+        """A free slot (admission holds at most ``slots`` rows)."""
+        return self._free.pop()
+
+    def release(self, slot: int) -> None:
+        assert slot not in self._free, f"double free of state slot {slot}"
+        self._free.append(slot)
 
 
 class PageAllocator:

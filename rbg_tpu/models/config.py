@@ -8,9 +8,30 @@ SGLang). Presets below mirror the benchmark configs in BASELINE.md.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+
+class _Numbers(tuple):
+    """A tuple of numbers that also equals the list of them: a
+    configuration file gives lists, the config is hashed, and a value read
+    back from the config equals the one the file gave."""
+
+    def __eq__(self, other):
+        return tuple.__eq__(self, tuple(other) if isinstance(other, list)
+                            else other)
+
+    def __ne__(self, other):
+        return not self == other
+
+    __hash__ = tuple.__hash__
+
+
+def _half_keys(g) -> tuple:
+    """(mixer's params key, MLP's params key) of a layer of kind ``g``."""
+    return ("kda_mixers" if g.attention == "kda" else "mixers",
+            "moe_mlps" if g.num_experts else "dense_mlps")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +86,46 @@ class ModelConfig:
     # RoPE pairing: rotate-half pairs (x_i, x_{i+hd/2}); interleaved pairs
     # (x_2i, x_2i+1), the published DeepSeek convention.
     rope_interleave: bool = False
+    # False: attention without positions (Kimi-Linear ``mla_use_nope``): the
+    # latent attention's ``qk_rope_head_dim`` channels stay, unrotated.
+    use_rope: bool = True
+    # Recurrent layers (Kimi Delta Attention, ``ops/kda.py``): the 1-based
+    # numbers, as published, of the layers that mix tokens by a gated delta
+    # rule over a fixed state ``[kda_num_heads, kda_head_dim, kda_head_dim]``
+    # a sequence in place of this config's attention. q, k and v pass a
+    # causal depthwise convolution of ``kda_conv_kernel`` taps; the decay
+    # and the output gate are low-rank (``kda_rank``). Such a model's
+    # layers come in runs by kind (``layer_groups``) and its cache is pages
+    # for the attention layers and a state slot a row for these
+    # (``engine/kvcache.py::StatePool``).
+    kda_layers: Tuple[int, ...] = ()
+    kda_num_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_kernel: int = 4
+    kda_rank: int = 128
+    # What a GROUP config's layers mix tokens by: ``full`` (this config's
+    # attention) or ``kda``. Set by ``layer_groups`` alone.
+    attention: str = "full"
+    # Which half of a layer a group config of ``param_groups`` stands for:
+    # ``mixer`` (norm, what mixes tokens, its output projection), ``mlp``
+    # (norm, MLP or experts), or both (""). Set by ``param_groups`` alone.
+    half: str = ""
+    # The contiguous range ``[lo, hi)`` of routed experts this device holds
+    # (None: all). The router keeps its published width; the expert stacks
+    # hold ``hi - lo`` experts and only their part of the layer is
+    # computed: what the absent experts would add is added elsewhere (a
+    # deployment that divides a layer's experts over chips).
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "kda_layers", _Numbers(self.kda_layers))
+        if self.experts_held is not None:
+            lo, hi = self.experts_held
+            if not 0 <= lo < hi <= self.num_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no range of the "
+                    f"{self.num_experts} experts")
+            object.__setattr__(self, "experts_held", _Numbers((lo, hi)))
 
     @property
     def head_dim_(self) -> int:
@@ -83,19 +144,92 @@ class ModelConfig:
         return self.moe_shared_expert_size or self.intermediate_size
 
     @property
+    def experts_here(self) -> int:
+        """Routed experts whose weights this device holds."""
+        if self.experts_held is None:
+            return self.num_experts
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layer keeps a recurrent state in place of pages."""
+        return bool(self.kda_layers)
+
+    @property
     def layer_groups(self):
-        """``((params key, group config, lo, hi), ...)``: the runs of layers
-        of one kind, in order. Each run's parameters are stacked under its
-        key with a leading axis ``hi - lo``; ``lo``/``hi`` are absolute
-        layer numbers (the KV pool is indexed by them). A model of one kind
-        of layer is the one group ``blocks`` and the group config is this
-        config itself."""
+        """``((key, group config, lo, hi), ...)``: the runs of layers of one
+        kind, in order; ``lo``/``hi`` are absolute layer numbers. A kind is
+        (what mixes tokens, what the MLP is): ``blocks`` (this config's
+        attention, experts if it has any), ``dense_blocks`` (the same
+        before the expert layers start), ``kda_blocks`` and
+        ``kda_dense_blocks`` (the recurrent mixer). A model of one kind of
+        layer is the one group ``blocks`` and the group config is this
+        config itself. Where every kind stands in ONE run (no recurrent
+        layers) a run's key is its parameters' too, stacked with a
+        leading axis ``hi - lo``; a model whose kinds alternate names a
+        key in several runs and stacks its parameters by half-layer
+        (``param_groups``)."""
         n = self.num_layers - self.num_moe_layers if self.num_experts else 0
-        if not n:
+        if not n and not self.kda_layers:
             return (("blocks", self, 0, self.num_layers),)
-        dense = dataclasses.replace(self, num_experts=0, first_dense_layers=0)
-        return (("dense_blocks", dense, 0, n),
-                ("blocks", self, n, self.num_layers))
+        if not self.kda_layers:
+            dense = dataclasses.replace(self, num_experts=0,
+                                        first_dense_layers=0)
+            return (("dense_blocks", dense, 0, n),
+                    ("blocks", self, n, self.num_layers))
+        kinds, runs = {}, []
+        for layer in range(self.num_layers):
+            kda, dense = (layer + 1) in self.kda_layers, layer < n
+            key = ("kda_" if kda else "") + ("dense_" if dense else "") \
+                + "blocks"
+            if key not in kinds:
+                kinds[key] = dataclasses.replace(
+                    self, kda_layers=(), first_dense_layers=0,
+                    attention="kda" if kda else "full",
+                    **({"num_experts": 0, "experts_held": None}
+                       if dense else {}))
+            if runs and runs[-1][0] == key:
+                runs[-1][3] = layer + 1
+            else:
+                runs.append([key, kinds[key], layer, layer + 1])
+        return tuple(tuple(r) for r in runs)
+
+    @property
+    def param_groups(self):
+        """``((params key, group config, layers), ...)``: what is stacked
+        under each key of the parameters, with a leading axis ``layers``.
+        The groups of ``layer_groups`` where each stands in one run. A
+        model with recurrent layers stacks HALF-layers, in layer order:
+        the mixers by kind (``kda_mixers``, ``mixers``) and the MLPs by
+        kind (``dense_mlps``, ``moe_mlps``), so that a walk compiles each
+        mixer once whatever MLP follows it (``layer_halves`` says which
+        entries are a layer's)."""
+        if not self.kda_layers:
+            return tuple((key, g, hi - lo) for key, g, lo, hi
+                         in self.layer_groups)
+        groups = {}
+        for key, g, lo, hi in self.layer_groups:
+            for name, half in zip(_half_keys(g), ("mixer", "mlp")):
+                if name not in groups:
+                    groups[name] = [name, dataclasses.replace(g, half=half), 0]
+                groups[name][2] += hi - lo
+        return tuple(tuple(v) for v in groups.values())
+
+    @property
+    def layer_halves(self):
+        """Per layer of a model with recurrent layers: ``(group config,
+        mixer's params key, its ordinal there, MLP's params key, its
+        ordinal there)``; the group config is the layer's kind's
+        (``layer_groups``)."""
+        seen, out = {}, []
+        for _, g, lo, hi in self.layer_groups:
+            for _ in range(lo, hi):
+                mixer, mlp = _half_keys(g)
+                out.append((g, mixer, seen.get(mixer, 0), mlp,
+                            seen.get(mlp, 0)))
+                seen[mixer] = seen.get(mixer, 0) + 1
+                seen[mlp] = seen.get(mlp, 0) + 1
+        return tuple(out)
 
     @property
     def num_moe_layers(self) -> int:
@@ -123,7 +257,8 @@ class ModelConfig:
         else:
             attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
         dense_mlp = 3 * d * f
-        moe_mlp = self.num_experts * 3 * d * self.moe_f + d * self.num_experts
+        # The experts this device holds; the router is whole.
+        moe_mlp = self.experts_here * 3 * d * self.moe_f + d * self.num_experts
         if self.moe_select_bias:
             moe_mlp += self.num_experts
         if self.moe_shared_expert:
@@ -131,7 +266,18 @@ class ModelConfig:
         n_moe = self.num_moe_layers
         mlp = n_moe * moe_mlp + (self.num_layers - n_moe) * dense_mlp
         head = 0 if self.tie_word_embeddings else d * v
-        return (v * d + self.num_layers * (attn + 2 * d) + mlp + d + head)
+        n_kda = len(self.kda_layers)
+        if n_kda:
+            kh, kd, r = self.kda_num_heads, self.kda_head_dim, self.kda_rank
+            ch = kh * kd
+            kda = (3 * d * ch + self.kda_conv_kernel * 3 * ch   # qkv, conv
+                   + 2 * (d * r + r * ch)       # decay and gate, low-rank
+                   + kh + ch + d * kh           # A_log, dt_bias, beta
+                   + kd + ch * d)               # o_norm, wo
+            attn_all = n_kda * kda + (self.num_layers - n_kda) * attn
+        else:
+            attn_all = self.num_layers * attn
+        return (v * d + attn_all + self.num_layers * 2 * d + mlp + d + head)
 
 
 _PRESETS = {
@@ -229,6 +375,24 @@ _PRESETS = {
         moe_routed_scale=2.5,
         mla=True, kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16,
         v_head_dim=32, q_lora_rank=96, rope_interleave=True,
+    ),
+    # Tiny Kimi-Linear-shaped model for tests (the layers of the benchmark's
+    # kimi-linear-48b-a3b): a dense recurrent layer, then expert layers
+    # K K M K K K M, the latent attention without positions and with a
+    # full-rank query, a held range of the experts.
+    "tiny-kimi-linear": ModelConfig(
+        name="tiny-kimi-linear", vocab_size=256, hidden_size=128,
+        intermediate_size=320, num_layers=8, num_heads=4, num_kv_heads=4,
+        max_seq_len=256, rope_theta=10000.0, rms_norm_eps=1e-5,
+        dtype="float32",
+        num_experts=16, experts_per_token=2, moe_intermediate_size=48,
+        moe_shared_expert=True, moe_shared_expert_size=48,
+        first_dense_layers=1, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_routed_scale=2.446, experts_held=(4, 12),
+        mla=True, kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16,
+        v_head_dim=32, use_rope=False,
+        kda_layers=(1, 2, 3, 5, 6, 7), kda_num_heads=4, kda_head_dim=32,
+        kda_rank=16,
     ),
 }
 

@@ -67,7 +67,7 @@ class KVCache:
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random init (normal, 0.02 scale on input projections, depth-scaled on
     output projections) in cfg.dtype. One stacked dict of block weights per
-    group of ``cfg.layer_groups``, under the group's key."""
+    group of ``cfg.param_groups``, under the group's key."""
     d, v, dt = cfg.hidden_size, cfg.vocab_size, cfg.jax_dtype
     s_in = 0.02
     s_out = 0.02 / jnp.sqrt(2.0 * cfg.num_layers)
@@ -79,18 +79,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         "embed": nrm(jax.random.split(key, 8)[0], (v, d), s_in),
         "final_norm": jnp.ones((d,), dt),
     }
-    for i, (name, g, lo, hi) in enumerate(cfg.layer_groups):
+    for i, (name, g, n) in enumerate(cfg.param_groups):
         # The group ``blocks`` draws from ``key`` itself, as the one group
         # of a one-kind model always has.
         gkey = key if name == "blocks" else jax.random.fold_in(key, 1000 + i)
-        params[name] = _init_blocks(g, gkey, hi - lo, nrm, s_in, s_out)
+        params[name] = _init_blocks(g, gkey, n, nrm, s_in, s_out)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = nrm(jax.random.fold_in(key, 99), (d, v), s_in)
     return params
 
 
 def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
-    """``L`` stacked layers of the one kind ``cfg`` describes."""
+    """``L`` stacked layers of the one kind ``cfg`` describes, or that
+    half of them ``cfg.half`` names."""
     d, f = cfg.hidden_size, cfg.intermediate_size
     hd, h, kv = cfg.head_dim_, cfg.num_heads, cfg.num_kv_heads
     dt = cfg.jax_dtype
@@ -99,7 +100,12 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
         "attn_norm": jnp.ones((L, d), dt),
         "mlp_norm": jnp.ones((L, d), dt),
     }
-    if cfg.mla:
+    if cfg.half == "mlp":
+        del blocks["attn_norm"]
+    elif cfg.attention == "kda":
+        blocks.update(_init_kda(cfg, jax.random.fold_in(key, 11), L, nrm,
+                                s_in, s_out))
+    elif cfg.mla:
         dc, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
         dr, dv = cfg.qk_rope_head_dim, cfg.v_head_dim
         if cfg.q_lora_rank:
@@ -126,6 +132,9 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
             "wv": nrm(ks[3], (L, d, kv * hd), s_in),
             "wo": nrm(ks[4], (L, h * hd, d), s_out),
         })
+    if cfg.half == "mixer":
+        del blocks["mlp_norm"]
+        return blocks
     dense_mlp = cfg.num_experts == 0 or cfg.moe_shared_expert
     if dense_mlp:
         # The shared expert (DeepSeek-style) can be narrower than the
@@ -145,10 +154,46 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
             # benchmark's joyai-llm-flash draws the same; PERF.md section 2).
             blocks["router_bias"] = 0.03 * jax.random.normal(
                 jax.random.fold_in(ke[0], 1), (L, E), jnp.float32)
+        E = cfg.experts_here     # the router is whole, the stacks are held
         blocks["moe_gate"] = nrm(ke[1], (L, E, d, mf), s_in)
         blocks["moe_up"] = nrm(ke[2], (L, E, d, mf), s_in)
         blocks["moe_down"] = nrm(ke[3], (L, E, mf, d), s_out)
     return blocks
+
+
+# Ranges the initialiser draws the decay's parameters from, uniformly.
+KDA_A_LOG = (-1.4, 0.0)         # exp: 0.25 .. 1
+KDA_DT_BIAS = (-2.5, 0.3)       # softplus: 0.08 .. 0.85
+
+
+def _init_kda(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
+    """The recurrent mixer's weights (``_kda_attention``). The decay's
+    ``kda_a_log`` and ``kda_dt_bias`` and the convolution are drawn so that
+    a token's decay ``exp(-exp(a_log) softplus(f + dt_bias))`` spreads
+    over about (0.5, 1) across channels and the convolution's output has
+    about its input's size; the benchmark's configuration draws the same
+    (``assumed`` in its file)."""
+    d, h, dk, r = (cfg.hidden_size, cfg.kda_num_heads, cfg.kda_head_dim,
+                   cfg.kda_rank)
+    ch, dt = h * dk, cfg.jax_dtype
+    ks = jax.random.split(key, 10)
+    uniform = functools.partial(jax.random.uniform, dtype=jnp.float32)
+    return {
+        "kda_qkv": nrm(ks[0], (L, d, 3 * ch), s_in),
+        "kda_conv": nrm(ks[1], (L, cfg.kda_conv_kernel, 3 * ch),
+                        cfg.kda_conv_kernel ** -0.5),
+        "kda_f_down": nrm(ks[2], (L, d, r), s_in),
+        "kda_f_up": nrm(ks[3], (L, r, ch), s_in),
+        "kda_a_log": uniform(ks[4], (L, h), minval=KDA_A_LOG[0],
+                             maxval=KDA_A_LOG[1]),
+        "kda_dt_bias": uniform(ks[5], (L, ch), minval=KDA_DT_BIAS[0],
+                               maxval=KDA_DT_BIAS[1]),
+        "kda_wb": nrm(ks[6], (L, d, h), s_in),
+        "kda_g_down": nrm(ks[7], (L, d, r), s_in),
+        "kda_g_up": nrm(ks[8], (L, r, ch), s_in),
+        "kda_o_norm": jnp.ones((L, dk), dt),
+        "wo": nrm(ks[9], (L, ch, d), s_out),
+    }
 
 
 def lora_delta(x, A, B_, ids):
@@ -198,7 +243,8 @@ def _mla_qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
     (split nope/rope, absorb W_uk into q) → latent down-projection
     (+kv-norm) and shared RoPE key. Returns (q_lat [B,T,h,dc],
     q_pe [B,T,h,dr], c [B,T,dc], k_pe [B,T,dr]). With ``q_lora_rank`` the
-    query is low-rank: wq_a → RMSNorm → wq_b. LoRA applies to the plain
+    query is low-rank: wq_a → RMSNorm → wq_b; without ``use_rope`` nothing
+    is rotated and the ``dr`` channels are plain ones. LoRA applies to the plain
     input projections (wq, w_dkv); the absorbed up-projections
     (w_uk/w_uv) are not adapter targets."""
     B, T, _ = x.shape
@@ -212,14 +258,19 @@ def _mla_qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
         q = _lora_proj(xa, blk["wq"], "wq", lora, lora_ids)
     q = q.reshape(B, T, h, dn + dr)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, cfg.rope_interleave)
+    if cfg.use_rope:
+        q_pe = apply_rope(q_pe, positions, cfg.rope_theta,
+                          cfg.rope_interleave)
     # Absorb: q_lat·c == q_nope·(c @ W_uk) — per-head K never materializes.
     w_uk = blk["w_uk"].reshape(dc, h, dn)
     q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w_uk)
     kv = _lora_proj(xa, blk["w_dkv"], "w_dkv", lora, lora_ids)  # [B,T,dc+dr]
     c = rms_norm(kv[..., :dc], blk["kv_norm"], cfg.rms_norm_eps)
-    k_pe = apply_rope(kv[..., None, dc:], positions, cfg.rope_theta,
-                      cfg.rope_interleave)[:, :, 0]
+    if cfg.use_rope:
+        k_pe = apply_rope(kv[..., None, dc:], positions, cfg.rope_theta,
+                          cfg.rope_interleave)[:, :, 0]
+    else:
+        k_pe = kv[..., dc:]
     return q_lat, q_pe, c, k_pe
 
 
@@ -241,7 +292,8 @@ def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
     With ``hit_experts`` (``_moe_mlp_hit``'s stacks, layer and live rows)
     the experts are the hit ones only, and their count is returned too."""
     B, T, _ = x.shape
-    with jax.named_scope("attention"):
+    with jax.named_scope("attention/kda" if cfg.attention == "kda"
+                         else "attention"):
         x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
                            lora_ids)
     with jax.named_scope("moe" if cfg.num_experts else "mlp"):
@@ -302,6 +354,16 @@ def _shared_expert(blk, xm):
         return (gate * (xm @ blk["w_up"])) @ blk["w_down"]
 
 
+def _held(cfg: ModelConfig, weights):
+    """The combine weights of the experts this device holds
+    (``cfg.experts_held``): the router chose and renormalised over all the
+    published experts, and the absent ones' terms are left out, not stood
+    in for."""
+    if cfg.experts_held is None:
+        return weights
+    return weights[..., cfg.experts_held[0]:cfg.experts_held[1]]
+
+
 def _moe_mlp(cfg: ModelConfig, blk, xm):
     """Top-k sparse MoE (DeepSeek/Mixtral-style) in the dense-dispatch
     formulation: every expert is evaluated and combined with its (mostly
@@ -311,7 +373,7 @@ def _moe_mlp(cfg: ModelConfig, blk, xm):
     scalar code enters the graph. Right wherever a step's tokens hit every
     expert anyway (prefill, training); a step of few rows takes
     ``_moe_mlp_hit``. Routing math is exact either way."""
-    weights = _route(cfg, blk, xm)
+    weights = _held(cfg, _route(cfg, blk, xm))
     hg = jnp.einsum("btd,edf->btef", xm, blk["moe_gate"])
     hu = jnp.einsum("btd,edf->btef", xm, blk["moe_up"])
     h = jax.nn.silu(hg) * hu
@@ -327,7 +389,8 @@ _EXPERT_STACKS = ("moe_gate", "moe_up", "moe_down")
 
 def hit_experts_pay(cfg: ModelConfig, rows: int) -> bool:
     """Whether a step of ``rows`` tokens should visit hit experts only.
-    The expected share of experts hit is 1 - (1 - K/E)^rows; at rows·K =
+    The expected share of experts hit is 1 - (1 - K/E)^rows, of the
+    published E whichever of them this device holds; at rows·K =
     2·E it is 0.87-0.90 (Mixtral: 8 rows), and a step with every expert
     hit costs 1.4 % over the dense dispatch (PERF.md, PR 29). Above that
     there is nothing to skip."""
@@ -352,10 +415,10 @@ def _moe_mlp_hit(cfg: ModelConfig, blk, xm, stacks, layer, live):
     nowhere, so it makes no expert live and only the shared expert adds
     to it. Returns (out ``[B, T, D]``, the number of experts visited)."""
     B, T, D = xm.shape
-    E = cfg.num_experts
+    E = cfg.experts_here
     x = xm.reshape(B * T, D)
     w = jnp.where(live.reshape(B * T, 1),
-                  _route(cfg, blk, xm).reshape(B * T, E), 0)
+                  _held(cfg, _route(cfg, blk, xm)).reshape(B * T, E), 0)
     w = w.astype(jnp.float32)
     hit = jnp.any(w > 0, axis=0)                                # [E]
     visited = jnp.sum(hit, dtype=jnp.int32)
@@ -448,6 +511,7 @@ def forward(
     real-token writes past capacity are dropped silently (they cannot raise
     under jit). The static part (T ≤ S) is checked at trace time.
     """
+    _no_recurrent(cfg, "the contiguous cache (models.llama.forward)")
     B, T = tokens.shape
     if T > cache.k.shape[2]:
         raise ValueError(
@@ -488,6 +552,15 @@ def forward(
                            length=new_length)
 
 
+def _no_recurrent(cfg: ModelConfig, what: str) -> None:
+    """Recurrent layers are served over the paged pools alone."""
+    if cfg.recurrent:
+        raise NotImplementedError(
+            f"{cfg.name} has recurrent layers (kda_layers): {what} keeps "
+            f"no state for them; serve it through the engine "
+            f"(forward_paged / forward_ragged)")
+
+
 class PoolAddr(NamedTuple):
     """How a step's tokens address the paged KV pool: a ``[B, T]`` batch has
     a table line per row; a packed ``[1, T]`` step (``forward_ragged``) names
@@ -498,6 +571,9 @@ class PoolAddr(NamedTuple):
     page_table: jnp.ndarray     # [R, P] int32 physical page ids
     row_ids: Optional[jnp.ndarray] = None   # [T] int32 token → row
     max_q_len: Optional[int] = None         # static, packed steps only
+    # [R] int32: each row's slot of the recurrent-state pool (a model with
+    # recurrent layers only); a row of padding names a slot out of range.
+    state_slots: Optional[jnp.ndarray] = None
 
 
 def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
@@ -506,14 +582,19 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
     MLA latents, which ride the pool as the (c, k_pe) pair), the write of
     this step's slots, the attend, by the row or the packed operations as
     the input says (``row_ids``). ``table`` is the layer's own. Returns
-    (attn ``[B, T, h, dv]``, pool)."""
+    (attn ``[B, T, h, dv]``, pool). For a recurrent layer (``cfg.attention
+    == "kda"``) ``pool`` is the state pool's arrays and ``table`` the
+    layer's ordinal in them (``_kda_attention``)."""
     from rbg_tpu.ops.mla_attention import (paged_mla_attention,
                                             ragged_paged_mla_attention)
     from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
     from rbg_tpu.ops.ragged_paged_attention import (ragged_paged_attention,
                                                     write_kv_pages_ragged)
 
-    positions, token_mask, kv_lens, _, row_ids, max_q_len = addr
+    if cfg.attention == "kda":
+        with jax.named_scope("kda"):
+            return _kda_attention(cfg, blk, x, pool, table, addr)
+    positions, token_mask, kv_lens, _, row_ids, max_q_len, _ = addr
     if row_ids is None:
         write, attend, attend_mla = (write_kv_pages, paged_attention,
                                      paged_mla_attention)
@@ -544,6 +625,209 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
                   v_scales=vsf, **bound), pool
 
 
+def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr):
+    """The recurrent mixer of one layer (Kimi Delta Attention; the
+    recurrence and its forms are ``ops/kda.py``'s). ``state`` is the pool's
+    ``{"s": [Lk, slots, H, dk, dk] float32, "conv": [Lk, slots, (K-1) 3 H
+    dk]}`` and ``layer`` this layer's ordinal in it; a row's slot is
+    ``addr.state_slots``. A row whose tokens start at position 0 starts
+    from a zero state and a zero convolution tail, whatever its slot held;
+    every other row goes on from what its slot holds; a row with no real
+    token leaves it as it was. A packed step's tokens are laid out a row
+    a line (``[R, max_q_len]``) for the convolution and the recurrence and
+    packed again. Returns (``[B, T, H, dk]``, normed by head and gated,
+    before ``wo``; state)."""
+    from rbg_tpu.ops import kda
+    from rbg_tpu.ops.ragged_paged_attention import _unpack_offsets
+
+    B, T, _ = x.shape
+    h, dk = cfg.kda_num_heads, cfg.kda_head_dim
+    ch, f32 = h * dk, jnp.float32
+    xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
+    qkv = xa @ blk["kda_qkv"]                                # [B, T, 3 ch]
+    f = ((xa @ blk["kda_f_down"]) @ blk["kda_f_up"]).astype(f32)
+    g = -jnp.exp(blk["kda_a_log"].astype(f32))[:, None] * jax.nn.softplus(
+        f + blk["kda_dt_bias"].astype(f32)).reshape(B, T, h, dk)
+    beta = jax.nn.sigmoid((xa @ blk["kda_wb"]).astype(f32))  # [B, T, h]
+    gate = jax.nn.sigmoid(
+        ((xa @ blk["kda_g_down"]) @ blk["kda_g_up"]).astype(f32))
+
+    mask, pos = addr.token_mask, addr.positions
+    if addr.row_ids is not None:     # [1, T] packed -> [R, C], a row a line
+        R = addr.kv_lens.shape[0]
+        C = T if addr.max_q_len is None else min(addr.max_q_len, T)
+        col = _unpack_offsets(addr.row_ids)
+        row = jnp.where(mask[0], addr.row_ids, R)            # padding: dropped
+
+        def lines(a):
+            return jnp.zeros((R, C) + a.shape[2:], a.dtype).at[row, col].set(
+                a[0], mode="drop")
+
+        qkv, g, beta, pos, mask = (lines(a) for a in (qkv, g, beta, pos,
+                                                      mask))
+    g = jnp.where(mask[..., None, None], g, 0.0)
+    beta = jnp.where(mask[..., None], beta, 0.0)
+    lens = jnp.sum(mask, axis=1, dtype=jnp.int32)
+    slots = addr.state_slots
+    fresh = (pos[:, 0] == 0) & mask[:, 0]
+    S = jnp.where(fresh[:, None, None, None], 0.0,
+                  state["s"].at[layer, slots].get(mode="clip"))
+    tail = state["conv"].at[layer, slots].get(mode="clip")
+    tail = jnp.where(fresh[:, None], jnp.zeros_like(tail), tail)
+    qkv, tail = kda.short_conv(qkv, tail.reshape(tail.shape[0], -1, 3 * ch),
+                               blk["kda_conv"], lens)
+    tail = tail.reshape(tail.shape[0], -1)
+    q, k, v = (a.reshape(a.shape[:2] + (h, dk)).astype(f32)
+               for a in jnp.split(qkv, 3, axis=-1))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+        * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    if q.shape[1] == 1:
+        o, S = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S)
+        o = o[:, None]
+    else:
+        o, S = kda.kda_chunk(q, k, v, g, beta, S)
+    state = {"s": state["s"].at[layer, slots].set(S, mode="drop"),
+             "conv": state["conv"].at[layer, slots].set(tail, mode="drop")}
+    if addr.row_ids is not None:
+        o = o[addr.row_ids, col][None]                       # [1, T, h, dk]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                          + cfg.rms_norm_eps) * blk["kda_o_norm"].astype(f32)
+    return (o * gate.reshape(B, T, h, dk)).astype(x.dtype), state
+
+
+def _hybrid_plan(cfg: ModelConfig):
+    """How ``_hybrid_layers`` walks a model whose mixers alternate, by
+    MIXER kind (its params key): ``("run", key, lo, hi)`` for a kind that
+    stands in one run of layers, and ``("turns", key of A, key of B,
+    rows)`` for a stretch in which two kinds take turns, a row ``(layers
+    of A, layers of B, first layer)`` a turn."""
+    mixers = [h[1] for h in cfg.layer_halves]
+    runs = []                                   # [key, lo, hi] by mixer kind
+    for layer, key in enumerate(mixers):
+        if runs and runs[-1][0] == key:
+            runs[-1][2] = layer + 1
+        else:
+            runs.append([key, layer, layer + 1])
+    times = {key: sum(r[0] == key for r in runs) for key in set(mixers)}
+    plan, i = [], 0
+    while i < len(runs):
+        key, lo, hi = runs[i]
+        if times[key] == 1:
+            plan.append(("run", key, lo, hi))
+            i += 1
+            continue
+        a, b, rows = key, None, []
+        while i < len(runs) and times[runs[i][0]] > 1:
+            key, lo, hi = runs[i]
+            if key == a:
+                rows.append([hi - lo, 0, lo])
+            else:
+                b = b or key
+                if key != b:
+                    raise NotImplementedError(
+                        f"{cfg.name}: mixers of more than two kinds take "
+                        f"turns ({a}, {b}, {key})")
+                rows[-1][1] = hi - lo
+            i += 1
+        plan.append(("turns", a, b, rows))
+    return plan
+
+
+def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
+                   use_pallas: str, experts_whole: bool):
+    """``paged_layers`` for a model whose layers differ in what mixes
+    tokens (``cfg.kda_layers``): every layer, in order, each over its own
+    cache: the page pool ``[attention layers, NP, ...]`` by the layer's
+    ordinal among the attention layers, the state pool (``pool[4]``) by
+    its ordinal among the recurrent ones. A compile follows the number of
+    distinct loop bodies, so each KIND of mixer is traced once, not each
+    of the runs: the parameters are stacked by half-layer
+    (``cfg.param_groups``), mixers that take turns are walked a turn at a
+    time, the layers of a turn in a loop of that turn's own length, and a
+    layer's weights are read from its kinds' stacks by their ordinals
+    there. Where layers of one mixer differ in their MLP (a dense first
+    layer before expert layers) the loop's body branches on it."""
+    *pages, state = pool
+    NP = pages[0].shape[1]
+    flat = jax.tree_util.tree_map(
+        lambda p: p.reshape((-1,) + p.shape[2:]), tuple(pages))
+    rows = x.shape[0] * x.shape[1]
+    halves = cfg.layer_halves
+    # By absolute layer: the ordinal among its mixer kind's layers, in its
+    # params and in its pool alike (a kind's layers are its pool's, in order).
+    mixer_at = jnp.asarray([h[2] for h in halves], jnp.int32)
+    mlp_at = jnp.asarray([h[4] for h in halves], jnp.int32)
+    dense_at = jnp.asarray([not h[0].num_experts for h in halves])
+
+    def layer(key, li, carry):
+        """Layer ``li``, whose mixer's kind is ``key``."""
+        h, flat, state, seen = carry
+        # the kinds of layer that this mixer leads, by their MLP's key
+        kinds = {m[3]: m[0] for m in halves if m[1] == key}
+        g = next(iter(kinds.values()))
+        blk = {k: v[mixer_at[li]] for k, v in params[key].items()}
+        with jax.named_scope("attention"):
+            if g.attention == "kda":
+                attn, state = _pool_attention(g, blk, h, state, mixer_at[li],
+                                              addr, use_pallas)
+            else:
+                attn, flat = _pool_attention(
+                    g, blk, h, flat, addr.page_table + mixer_at[li] * NP, addr,
+                    use_pallas)
+
+        def rest(mlp, g):
+            """``wo`` and the MLP of kind ``mlp``; its weights are read
+            from their stack in here, where each slice meets its dot."""
+            n = mlp_at[li]
+            hit_only = experts_whole and hit_experts_pay(g, rows)
+            stacks = ({k: params[mlp][k] for k in _EXPERT_STACKS}
+                      if hit_only else {})
+            both = {k: v[n] for k, v in params[mlp].items()
+                    if k not in stacks}
+            both["wo"] = params[key]["wo"][mixer_at[li]]
+            if hit_only:
+                return _post_attention(
+                    g, both, h, attn, hit_experts=(stacks, n, addr.token_mask))
+            return _post_attention(g, both, h, attn), jnp.zeros((), jnp.int32)
+
+        if len(kinds) == 1:
+            h, visited = rest(*next(iter(kinds.items())))
+        else:
+            h, visited = jax.lax.cond(
+                dense_at[li], *(functools.partial(rest, mlp, kinds[mlp])
+                                for mlp in ("dense_mlps", "moe_mlps")))
+        return h, flat, state, seen + visited
+
+    def loop(key, count, l0, carry):
+        return jax.lax.fori_loop(
+            0, count, lambda i, c: layer(key, l0 + i, c), carry)
+
+    carry = (x, flat, state, jnp.zeros((), jnp.int32))
+    for seg in _hybrid_plan(cfg):
+        if seg[0] == "run":
+            _, key, lo, hi = seg
+            carry = loop(key, hi - lo, lo, carry)
+            continue
+        _, a, b, turns = seg
+
+        def turn(carry, t):
+            na, nb, l0 = t
+            carry = loop(a, na, l0, carry)
+            if b is not None:
+                carry = loop(b, nb, l0 + na, carry)
+            return carry, None
+
+        carry, _ = jax.lax.scan(turn, carry, tuple(
+            jnp.asarray(col, jnp.int32) for col in zip(*turns)))
+    x, flat, state, seen = carry
+    experts = experts_whole and any(
+        hit_experts_pay(h[0], rows) for h in halves)
+    pages = jax.tree_util.tree_map(lambda f, p: f.reshape(p.shape), flat,
+                                   tuple(pages))
+    return x, (*pages, state), seen[None] if experts else None
+
+
 def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
                  layers: Tuple[int, int], use_pallas: str = "auto", lora=None,
                  lora_ids=None, experts_whole: bool = False):
@@ -551,13 +835,23 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
     over the hidden states ``x [B, T, D]`` entering layer ``lo``, writing and
     attending those layers' pages of the FULL pool, the tuple ``(k_pages,
     v_pages, k_scales, v_scales)``, ``[L, NP, page, KV, hd]`` each (scales
-    None unless int8). A chain of windows covering every layer is the whole
+    None unless int8; a model with recurrent layers adds a fifth, the state
+    pool's arrays, and its pages are the attention layers' alone:
+    ``_hybrid_layers``). A chain of windows covering every layer is the whole
     walk (``tests/test_layer_walk.py``): ``engine/pd.py`` chains them so that
     a first decode step starts when the leading layers' KV has arrived.
     Returns (x, pool, visited): ``visited`` counts the experts each expert
     layer of the window visited where the hit-experts form ran
     (``experts_whole`` and ``hit_experts_pay``), else None."""
     lo, hi = layers
+    if cfg.recurrent:
+        if (lo, hi) != (0, cfg.num_layers) or lora is not None:
+            raise NotImplementedError(
+                f"{cfg.name} has recurrent layers: its layers are walked "
+                f"whole and without adapters (a window {layers}, or LoRA, "
+                f"was asked for)")
+        return _hybrid_layers(params, cfg, x, pool, addr, use_pallas,
+                              experts_whole)
     # The pool rides the layer scan as CARRY over a [L·NP, …] flat view,
     # with each layer addressing its pages as ``layer·NP + page_table``. As
     # a per-layer scan INPUT/OUTPUT (stacked ys) the entire pool would be
@@ -637,6 +931,8 @@ def forward_paged(
     lora: Optional[dict] = None,    # {w: (A [L,n,d,r], B [L,n,r,o]·alpha/r)}
     lora_ids: Optional[jnp.ndarray] = None,  # [B] int32 adapter slot per row
     experts_whole: bool = False,    # no mesh axis shards the expert dim
+    state: Optional[dict] = None,   # recurrent layers: the state pool's arrays
+    state_slots: Optional[jnp.ndarray] = None,   # [B] int32 slot per row
 ):
     """Serving forward over the paged KV pool (prefill chunks and decode steps
     share this one traced program per (B, T) bucket). With scales, the pool
@@ -645,11 +941,17 @@ def forward_paged(
     A caller whose experts are whole on every device says so with
     ``experts_whole`` and gets a sixth value: the experts visited, summed
     over the layers, where the step ran as ``_moe_mlp_hit`` (small enough
-    for ``hit_experts_pay``); else None, and the program is the dense one."""
+    for ``hit_experts_pay``); else None, and the program is the dense one.
+    With ``state`` (a model with recurrent layers) the new state follows
+    the pools, before that count."""
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
+    pool = (k_pages, v_pages, k_scales, v_scales)
+    if state is not None:
+        pool += (state,)
     x, pool, visited = paged_layers(
-        params, cfg, x, (k_pages, v_pages, k_scales, v_scales),
-        PoolAddr(positions, token_mask, kv_lens, page_table),
+        params, cfg, x, pool,
+        PoolAddr(positions, token_mask, kv_lens, page_table,
+                 state_slots=state_slots),
         layers=(0, cfg.num_layers), use_pallas=use_pallas, lora=lora,
         lora_ids=lora_ids, experts_whole=experts_whole)
     out = (_head(params, cfg, x), *pool)
@@ -673,19 +975,26 @@ def forward_ragged(
     k_scales: Optional[jnp.ndarray] = None,
     v_scales: Optional[jnp.ndarray] = None,
     max_q_len: Optional[int] = None,  # static bound on a row's query len
-):                                    # (engine: prefill_chunk)
+                                      # (engine: prefill_chunk)
+    state: Optional[dict] = None,     # recurrent layers: the state pool
+    state_slots: Optional[jnp.ndarray] = None,   # [R] int32 slot per row
+):
     """Serving forward over a RAGGED packed batch: prefill chunks and decode
     steps of different rows ride ONE dispatch (tokens packed row-major on the
     flat token axis, ``row_ids`` naming each token's page table line and kv
     length). Only the KV scatter and the attention read the ragged metadata
     (``_pool_attention``). No LoRA: ``lora_delta`` gathers adapters per batch
     ROW and the packed batch axis is 1, so the engine gates such rows out.
-    Returns (logits [1, T, V] f32, k_pages, v_pages, k_scales, v_scales)."""
+    Returns (logits [1, T, V] f32, k_pages, v_pages, k_scales, v_scales),
+    and the new state after them where ``state`` was given."""
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
+    pool = (k_pages, v_pages, k_scales, v_scales)
+    if state is not None:
+        pool += (state,)
     x, pool, _ = paged_layers(
-        params, cfg, x, (k_pages, v_pages, k_scales, v_scales),
+        params, cfg, x, pool,
         PoolAddr(positions, token_mask, kv_lens, page_table, row_ids,
-                 max_q_len),
+                 max_q_len, state_slots),
         layers=(0, cfg.num_layers), use_pallas=use_pallas)
     return (_head(params, cfg, x), *pool)
 
@@ -716,6 +1025,7 @@ def _encode_core(params, cfg, tokens, token_mask, mesh=None, remat=False,
                  final_norm=True):
     """Shared cache-free causal body (training AND embeddings paths — one
     copy of the embed → scan-over-blocks → norm pipeline)."""
+    _no_recurrent(cfg, "the cache-free forward (training, embeddings)")
     B, T = tokens.shape
     if token_mask is None:
         token_mask = jnp.ones((B, T), bool)

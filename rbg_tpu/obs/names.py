@@ -595,6 +595,9 @@ SPAN_ENGINE_EMIT = "engine.emit"
 # and ``shared`` open inside ``moe`` (paths ``moe/router``, ``moe/shared``).
 MODEL_SCOPES = ("attention", "mlp", "moe", "lm_head", "sampler")
 MOE_INNER_SCOPES = ("router", "shared")
+# Inside ``attention``: a recurrent layer's mixer (``models/llama.py::
+# _kda_attention`` and its ``wo``); ``device.kda_share`` reads the path.
+ATTENTION_INNER_SCOPES = ("kda",)
 
 # ---- jitted program catalog (jitwatch sentry + warmers) ----
 #

@@ -167,13 +167,22 @@ def abstract_engine(chip, monkeypatch):
     return _abstract_engine(chip, monkeypatch, **chip_smoke.SERVE_CONFIG)
 
 
+def _state_kw(chip, eng, rows):
+    """A recurrent model's state pool and slots, as shapes on the chip."""
+    if eng.state is None:
+        return {}
+    return _on(chip, {"state": eng.state.arrays,
+                      "slots": jnp.zeros(rows, I32)})
+
+
 def _compile_unified(chip, eng):
     Rb, Tb = eng.cfg.max_batch, eng.cfg.max_batch * eng.cfg.prefill_chunk
     S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
     return eng._get_ragged_fn(Rb, Tb).lower(
         eng.params, S((1, Tb), I32), S((1, Tb), I32), S((1, Tb), bool),
         S((Tb,), I32), S((Rb,), I32), S((Rb, eng.cfg.max_pages_per_seq), I32),
-        eng.cache.k_pages, eng.cache.v_pages, None, None).compile()
+        eng.cache.k_pages, eng.cache.v_pages, None, None,
+        **_state_kw(chip, eng, Rb)).compile()
 
 
 def _compile_decode(chip, eng):
@@ -188,7 +197,7 @@ def _compile_decode(chip, eng):
                       jnp.asarray(mps)))
     return eng._get_decode_fn(B, False, False).lower(
         eng.params, *small, eng.cache.k_pages, eng.cache.v_pages, None, None,
-        *tail).compile()
+        *tail, **_state_kw(chip, eng, B)).compile()
 
 
 def test_unified_step_of_llama3_1b_fits_and_holds_the_kernel(
@@ -260,6 +269,55 @@ def test_step_programs_of_the_joyai_cell_fit_and_copy_no_expert_stack(
     assert copied and not [dims for dims in copied if np.prod(
         [int(d) for d in dims.split(",")]) in pools]
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
+# ---- the benchmark's kimi-linear.longgen16 cell: its two step programs -------
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
+        chip, monkeypatch, program):
+    """``benchmark/configs/kimi-linear-48b-a3b.json`` as served: all 27
+    layers (one dense recurrent layer, 19 recurrent and 7 latent expert
+    layers), 16 of 256 experts a layer, 16 rows, pages for the 7 latent
+    layers alone and a state slot a row beside them. Each KIND of mixer is
+    one loop body read from its kind's stack by a dynamic index (the MLP's
+    weights inside the branch that uses them): no
+    temporary the size of a layer's held experts (226 MB) or of a pool, and
+    no ``copy`` of a pool's shape: not of the page pools, not of the state
+    pool ``f32[20,16,32,128,128]``, not of the convolution tails (held flat:
+    with an axis of 3 before the channels every step program copied them
+    whole, 24 MB, to pad that axis to a tile)."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from harness import serve
+    from rbg_tpu.models import config as presets
+    with open(os.path.join(bench, "configs", "kimi-linear-48b-a3b.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setitem(presets._PRESETS, "kimi-cell",
+                        serve.model_config(cfg, "kimi-cell"))
+    eng = _abstract_engine(chip, monkeypatch, model="kimi-cell",
+                           **cfg["server"])
+    assert eng.params["moe_mlps"]["moe_gate"].shape == (26, 16, 2304, 1024)
+    assert eng.params["moe_mlps"]["router"].shape == (26, 2304, 256)
+    assert eng.params["kda_mixers"]["kda_qkv"].shape == (20, 2304, 12288)
+    assert eng.cache.k_pages.shape == (7, 4096, 16, 1, 512)
+    assert eng.state.arrays["s"].shape == (20, 16, 32, 128, 128)
+    compiled = (_compile_decode if program == "decode"
+                else _compile_unified)(chip, eng)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pools = {eng.cache.k_pages.size, eng.cache.v_pages.size,
+             eng.state.arrays["s"].size, eng.state.arrays["conv"].size}
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert copied and not [dims for dims in copied if np.prod(
+        [int(d) for d in dims.split(",")]) in pools]
+    # 14 MB and 85 MB when written; a layer's held experts are 226 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
 
 
 # ---- the sampler's gates, at Mixtral's head and vocabulary -------------------

@@ -140,17 +140,19 @@ def _lower(eng, program):
     cfg, pool = eng.cfg, eng.cache
     B, P, T = cfg.max_batch, cfg.max_pages_per_seq, 2 * cfg.prefill_chunk
     vec, table = S((B,), I32), S((B, P), I32)
+    state = eng._state_kw([], B)     # a recurrent model's pool and slots
     if program == "decode":
         temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
         return eng._get_decode_fn(B, False, False).lower(
             eng.params, vec, vec, vec, table, S((B, cfg.multi_step), bool),
             vec, pool.k_pages, pool.v_pages, None, None,
             row_keys(seeds, eng._sample_base, rids), jnp.asarray(temps),
-            jnp.asarray(ks), jnp.asarray(tps), jnp.asarray(mps))
+            jnp.asarray(ks), jnp.asarray(tps), jnp.asarray(mps), **state)
     if program == "ragged":
         return eng._get_ragged_fn(B, T).lower(
             eng.params, S((1, T), I32), S((1, T), I32), S((1, T), bool),
-            S((T,), I32), vec, table, pool.k_pages, pool.v_pages, None, None)
+            S((T,), I32), vec, table, pool.k_pages, pool.v_pages, None, None,
+            **state)
     worker = DecodeWorker(cfg, params=eng.params)
     return worker._get_window_fn(0, 1, B).lower(
         S((B, 1, eng.mcfg.hidden_size), eng.mcfg.jax_dtype), S((B, 1), I32),
@@ -188,3 +190,35 @@ def test_router_and_shared_expert_open_inside_the_expert_scope(moe_engine):
                          else set())
     assert want <= inner
     assert inner & set(MOE_INNER_SCOPES) == want
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_every_dot_of_the_recurrent_layers_sits_under_attention_and_kda(
+        program):
+    """``device.kda_share`` reads the path ``attention/kda``: every dot of a
+    recurrent layer's mixer (projections, the state's dots, ``wo``) carries
+    it, no dot of a latent layer or of an MLP does, and each dot of the
+    step sits in one model scope still (the recurrent layers' time is part
+    of ``device.attention_share``)."""
+    from rbg_tpu.obs.names import ATTENTION_INNER_SCOPES
+    assert ATTENTION_INNER_SCOPES == ("kda",)
+    eng = Engine(EngineConfig(
+        model="tiny-kimi-linear", page_size=8, num_pages=64, max_seq_len=128,
+        max_batch=4, prefill_chunk=16, use_pallas="never"))
+    # The compiled module's metadata, which is what a device trace
+    # carries: the lowered text's locations stop at the scan that walks a
+    # chunk's sub-chunks (``ops/kda.py``), the metadata joins them.
+    from rbg_tpu.obs.names import MODEL_SCOPES
+    text = _lower(eng, program).compile().as_text()
+    paths = [p.split("/") for p in sorted(set(re.findall(
+        r'op_name="([^"]*dot_general)"', text)))]
+    scopes = [sorted(set(MODEL_SCOPES) & set(p)) for p in paths]
+    assert all(len(s) == 1 for s in scopes), [
+        p for p, s in zip(paths, scopes) if len(s) != 1]
+    assert {s[0] for s in scopes} == {"attention", "mlp", "moe", "lm_head"}
+    kda = [p for p in paths if "kda" in p]
+    assert kda and all(p[p.index("kda") - 1] == "attention" for p in kda)
+    assert [p for p in paths if "attention" in p and "kda" not in p]
+    assert not [p for p in kda if {"moe", "mlp", "lm_head"} & set(p)]
+    # distinct paths: the projections' and ``wo``'s, and the state's dots
+    assert len(kda) >= 2

@@ -593,7 +593,7 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
 
     if cfg.attention == "kda":
         with jax.named_scope("kda"):
-            return _kda_attention(cfg, blk, x, pool, table, addr)
+            return _kda_attention(cfg, blk, x, pool, table, addr, use_pallas)
     positions, token_mask, kv_lens, _, row_ids, max_q_len, _ = addr
     if row_ids is None:
         write, attend, attend_mla = (write_kv_pages, paged_attention,
@@ -625,7 +625,8 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
                   v_scales=vsf, **bound), pool
 
 
-def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr):
+def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
+                   use_pallas: str):
     """The recurrent mixer of one layer (Kimi Delta Attention; the
     recurrence and its forms are ``ops/kda.py``'s). ``state`` is the pool's
     ``{"s": [Lk, slots, H, dk, dk] float32, "conv": [Lk, slots, (K-1) 3 H
@@ -633,8 +634,11 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr):
     ``addr.state_slots``. A row whose tokens start at position 0 starts
     from a zero state and a zero convolution tail, whatever its slot held;
     every other row goes on from what its slot holds; a row with no real
-    token leaves it as it was. A packed step's tokens are laid out a row
-    a line (``[R, max_q_len]``) for the convolution and the recurrence and
+    token leaves it as it was. A step of one token a row advances the
+    states where they lie in the pool (``kda.kda_decode``: a kernel on a
+    TPU, by ``use_pallas``); longer rows' states are gathered, walked in
+    chunks and scattered back. A packed step's tokens are laid out a row a
+    line (``[R, max_q_len]``) for the convolution and the recurrence and
     packed again. Returns (``[B, T, H, dk]``, normed by head and gated,
     before ``wo``; state)."""
     from rbg_tpu.ops import kda
@@ -670,8 +674,6 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr):
     lens = jnp.sum(mask, axis=1, dtype=jnp.int32)
     slots = addr.state_slots
     fresh = (pos[:, 0] == 0) & mask[:, 0]
-    S = jnp.where(fresh[:, None, None, None], 0.0,
-                  state["s"].at[layer, slots].get(mode="clip"))
     tail = state["conv"].at[layer, slots].get(mode="clip")
     tail = jnp.where(fresh[:, None], jnp.zeros_like(tail), tail)
     qkv, tail = kda.short_conv(qkv, tail.reshape(tail.shape[0], -1, 3 * ch),
@@ -683,11 +685,16 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr):
         * dk ** -0.5
     k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
     if q.shape[1] == 1:
-        o, S = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S)
+        o, s = kda.kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                              state["s"], layer, slots, fresh,
+                              use_pallas=use_pallas)
         o = o[:, None]
     else:
+        S = jnp.where(fresh[:, None, None, None], 0.0,
+                      state["s"].at[layer, slots].get(mode="clip"))
         o, S = kda.kda_chunk(q, k, v, g, beta, S)
-    state = {"s": state["s"].at[layer, slots].set(S, mode="drop"),
+        s = state["s"].at[layer, slots].set(S, mode="drop")
+    state = {"s": s,
              "conv": state["conv"].at[layer, slots].set(tail, mode="drop")}
     if addr.row_ids is not None:
         o = o[addr.row_ids, col][None]                       # [1, T, h, dk]

@@ -8,9 +8,16 @@ log-decay ``g <= 0`` a key channel (``a = exp(g)``) and write strength
     S <- (I - b k k^T) Diag(a) S + b k v^T        o = S^T q
 
 that is, with ``S' = Diag(a) S``: ``u = b (v - S'^T k)``, ``S <- S' + k
-u^T``. Plain XLA, three forms of the one recurrence:
+u^T``. Three forms of the one recurrence, the decode one in plain XLA and
+as a kernel:
 
-* ``kda_step``: one token a row (a decode step);
+* ``kda_step``: one token a row (a decode step). On the state pool it is
+  ``kda_decode``: on a TPU the Pallas kernel that reads each live row's
+  state out of its slot and writes it back once, in place
+  (``pallas/kda_kernel.py``; the trace prints it ``_kda_decode_call``, under
+  the ``attention/kda`` scope), elsewhere ``kda_step_in_pool``, ``kda_step``
+  between a gather and a scatter, which is also what the kernel has to
+  equal (``dispatch_pallas``'s one policy);
 * ``kda_chunk``: ``C`` tokens a row at once, rows in parallel (a prompt's
   chunk). Sub-chunks of ``SUB`` tokens are walked in order; inside one,
   with ``G`` the running sum of ``g`` from its start, the pseudo-values
@@ -28,7 +35,8 @@ u^T``. Plain XLA, three forms of the one recurrence:
 A token that is padding has ``g = 0`` and ``b = 0``: it leaves the state
 as it was. The state is float32. A decode step's two dots run at
 ``highest`` precision (a few MFLOP, and the state lives for thousands of
-tokens); a chunk's dozen at the default one, their inputs rounded to
+tokens; the kernel's are float32 products and sums on the VPU); a chunk's
+dozen at the default one, their inputs rounded to
 bfloat16 as every other dot of a bfloat16 model rounds its own (at
 ``highest`` they were 40 % of a step program's compile time, paid in every
 one of a server's thirty packed programs); the decay and the sums into the
@@ -81,6 +89,28 @@ def kda_step(q, k, v, g, b, S):
     u = b[..., None] * (v - _step_einsum("rhkv,rhk->rhv", S, k))
     S = S + k[..., None] * u[..., None, :]
     return _step_einsum("rhkv,rhk->rhv", S, q), S
+
+
+def kda_step_in_pool(q, k, v, g, b, pool, layer, slots, fresh):
+    """``kda_step`` on the rows' slots of a pool ``[layers, slots, H, dk,
+    dv]``, in plain XLA: gathered (a row of padding names a slot out of
+    range and reads a clipped one; a ``fresh`` row starts from zeros),
+    advanced, scattered back (padding dropped). Returns (``o``, pool)."""
+    S = jnp.where(fresh[:, None, None, None], 0.0,
+                  pool.at[layer, slots].get(mode="clip"))
+    o, S = kda_step(q, k, v, g, b, S)
+    return o, pool.at[layer, slots].set(S, mode="drop")
+
+
+def kda_decode(q, k, v, g, b, pool, layer, slots, fresh, *,
+               use_pallas: str = "auto"):
+    """A decode step on the pool: the kernel that reads and writes each
+    live row's state once, in place (``pallas/kda_kernel.py``), or
+    ``kda_step_in_pool``, by the one policy (``dispatch_pallas``). The
+    kernel leaves zeros in a padding row's lines of ``o``."""
+    from rbg_tpu.ops.paged_attention import dispatch_pallas
+    return dispatch_pallas(use_pallas, "kda_decode_pallas", kda_step_in_pool,
+                           (q, k, v, g, b, pool, layer, slots, fresh))
 
 
 def kda_recurrence(q, k, v, g, b, S):

@@ -343,3 +343,6 @@ from rbg_tpu.ops.pallas.ragged_attention_kernel import (  # noqa: E402,F401
     ragged_paged_mla_attention_pallas,
     ragged_paged_mla_attention_pallas_q,
 )
+
+# The recurrent layers' decode kernel, resolved by the same dispatch.
+from rbg_tpu.ops.pallas.kda_kernel import kda_decode_pallas  # noqa: E402,F401

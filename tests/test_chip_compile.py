@@ -318,6 +318,36 @@ def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
         [int(d) for d in dims.split(",")]) in pools]
     # 14 MB and 85 MB when written; a layer's held experts are 226 MB
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+    # A decode step advances the states where they lie (the kernel of
+    # ``ops/pallas/kda_kernel.py``, aliased onto the pool inside the layer
+    # scan): nothing gathers the rows' states out of the pool or scatters
+    # them back, and no pass over ``[rows, H, dk, dv]`` is left. A unified
+    # step walks its chunks in plain XLA and has both.
+    kernel = "_kda_decode_call" in text
+    passes = re.findall(r"= f32\[16,32,128,128\]\S* fusion\(", text)
+    moved = re.findall(r"= f32\[20,16,32,128,128\]\S* (?:fusion|scatter)\(",
+                       text)
+    assert (kernel, bool(passes), bool(moved)) == (
+        (True, False, False) if program == "decode" else (False, True, True))
+
+
+@pytest.mark.parametrize("heads_per_block", [8, 16])
+def test_kda_decode_kernel_compiles_for_v5e_in_place(chip, heads_per_block):
+    """The decode kernel alone at the Kimi cell's widths: 16 rows, 32 heads
+    of 128 x 128 float32, 20 layers' pool of 16 slots (671 MB), which the
+    call takes and gives back as one buffer."""
+    from rbg_tpu.ops.pallas.kda_kernel import kda_decode_pallas
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    rows, pool = S((16, 32, 128), F32), S((20, 16, 32, 128, 128), F32)
+    compiled = jax.jit(
+        functools.partial(kda_decode_pallas, heads_per_block=heads_per_block),
+        donate_argnums=(5,)).lower(
+            rows, rows, rows, rows, S((16, 32), F32), pool, S((), I32),
+            S((16,), I32), S((16,), bool)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 20 * 16 * 32 * 128 * 128 * 4
+    assert memory.temp_size_in_bytes < 1 << 20
 
 
 # ---- the sampler's gates, at Mixtral's head and vocabulary -------------------

@@ -10,6 +10,7 @@ benchmark's plain reference of the architecture
 program), and each new rule to its definition."""
 
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -188,6 +189,97 @@ def test_a_prompt_in_chunks_carries_the_state_from_each_to_the_next():
     np.testing.assert_allclose(S, S_all, rtol=1e-4, atol=1e-5)
 
 
+# ---- the decode step as a kernel, in place on the pool -----------------------
+
+POOL_LAYERS, POOL_SLOTS, POOL_LAYER = 3, 6, 1
+
+
+def _pool_case(slots, fresh, H=4, dk=16, seed=0):
+    """One token a row against a pool every slot of which holds an old
+    state; a row of padding names a slot out of range and has ``g = 0``,
+    ``b = 0`` (as ``_kda_attention`` masks them)."""
+    (q, k, v, g, b, _), _ = _kda_inputs(
+        len(slots), 1, H, dk, [s < POOL_SLOTS for s in slots], seed)
+    pool = jax.random.normal(jax.random.key(seed + 1),
+                             (POOL_LAYERS, POOL_SLOTS, H, dk, dk))
+    return (q[:, 0], k[:, 0], v[:, 0], g[:, 0], b[:, 0], pool,
+            jnp.int32(POOL_LAYER), jnp.asarray(slots, jnp.int32),
+            jnp.asarray(fresh, bool))
+
+
+POOL_CASES = {
+    "slots permuted against rows": ([3, 0, 5, 1, 2, 4], [0] * 6),
+    "a fresh row on a slot that holds an old state": ([2, 4, 1], [0, 1, 0]),
+    "a row of padding alone": ([POOL_SLOTS], [0]),
+    # rows 1 and 3 clip to slot 5, which row 0 advances
+    "padding whose clipped slot is a live row's": ([5, 6, 0, 9], [0] * 4),
+    "fewer live rows than slots": ([1, 6, 4, 6], [0, 0, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("heads_per_block", [2, 4])
+@pytest.mark.parametrize("case", sorted(POOL_CASES))
+def test_the_decode_kernel_is_kda_step_on_the_pools_slots(case,
+                                                          heads_per_block):
+    from rbg_tpu.ops.pallas.kda_kernel import kda_decode_pallas
+    slots, fresh = POOL_CASES[case]
+    args = _pool_case(slots, fresh, seed=len(case))
+    o_ref, pool_ref = kda.kda_step_in_pool(*args)
+    o, pool = kda_decode_pallas(*args, interpret=True,
+                                heads_per_block=heads_per_block)
+    assert o.dtype == pool.dtype == jnp.float32
+    live = np.asarray(slots) < POOL_SLOTS
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(o_ref)[live],
+                               rtol=1e-5, atol=1e-5)
+    assert not np.asarray(o)[~live].any()       # padding's lines: zeros
+    np.testing.assert_allclose(pool, pool_ref, rtol=1e-5, atol=1e-6)
+    # and a second step goes on from the first one's states
+    o2_ref, pool2_ref = kda.kda_step_in_pool(*args[:5], pool_ref, *args[6:])
+    o2, pool2 = kda_decode_pallas(*args[:5], pool, *args[6:], interpret=True,
+                                  heads_per_block=heads_per_block)
+    np.testing.assert_allclose(pool2, pool2_ref, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(o2)[live], np.asarray(o2_ref)[live],
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_the_decode_kernel_moves_no_other_slot_and_no_other_layer():
+    from rbg_tpu.ops.pallas.kda_kernel import kda_decode_pallas
+    slots = [4, POOL_SLOTS, 1, 7]
+    args = _pool_case(slots, [0, 0, 1, 0], seed=11)
+    before = np.asarray(args[5])
+    _, pool = kda_decode_pallas(*args, interpret=True, heads_per_block=2)
+    after = np.asarray(pool)
+    touched = np.zeros(before.shape[:2], bool)
+    touched[POOL_LAYER, [4, 1]] = True
+    np.testing.assert_array_equal(after[~touched], before[~touched])
+    assert (after[touched] != before[touched]).mean() > 0.99
+
+
+def test_a_step_of_one_token_a_row_takes_the_kernel_by_the_one_policy(
+        monkeypatch):
+    """``kda_decode`` is ``dispatch_pallas``'s: 'never' (and 'auto' off a
+    TPU) is plain XLA, 'always' the kernel with the arguments in order."""
+    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    args = _pool_case([2, POOL_SLOTS, 0], [0, 0, 1], seed=5)
+    want = kda.kda_step_in_pool(*args)
+    real, calls = K.kda_decode_pallas, []
+
+    def spy(*a):
+        calls.append(a)
+        return real(*a, interpret=True)
+
+    monkeypatch.setattr(K, "kda_decode_pallas", spy)
+    for policy in ("never", "auto"):
+        got = kda.kda_decode(*args, use_pallas=policy)
+        assert not calls
+        for a, w in zip(got, want):
+            np.testing.assert_array_equal(a, w)
+    o, pool = kda.kda_decode(*args, use_pallas="always")
+    assert len(calls) == 1 and len(calls[0]) == 9
+    np.testing.assert_allclose(pool, want[1], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(o[0], want[0][0], rtol=1e-5, atol=1e-5)
+
+
 def test_the_convolution_is_causal_and_keeps_the_last_inputs():
     ks = jax.random.split(jax.random.key(3), 3)
     x = jax.random.normal(ks[0], (3, 10, 5))
@@ -342,6 +434,17 @@ def _prompts(cfg, lens, seed=1):
     return [rng.integers(1, cfg["vocab_size"], n).tolist() for n in lens]
 
 
+@pytest.fixture()
+def interpreted(monkeypatch):
+    """``use_pallas="always"`` off the chip: the kernels a served
+    ``tiny-kimi-linear`` reaches, in interpret mode."""
+    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    for name in ("kda_decode_pallas", "paged_mla_attention_pallas",
+                 "ragged_paged_mla_attention_pallas"):
+        monkeypatch.setattr(K, name, functools.partial(getattr(K, name),
+                                                       interpret=True))
+
+
 def test_the_file_reaches_the_preset_the_tests_use(bench):
     from rbg_tpu.models import config as presets
     got = dataclasses.replace(presets._PRESETS[NAME], name="tiny-kimi-linear",
@@ -349,19 +452,21 @@ def test_the_file_reaches_the_preset_the_tests_use(bench):
     assert got == CFG
 
 
-@pytest.mark.parametrize("ragged,hit", [("auto", True), ("off", True),
-                                        ("auto", False)],
-                         ids=["packed-hit", "rows-hit", "packed-dense"])
-def test_served_path_agrees_with_the_plain_reference(bench, monkeypatch,
-                                                     ragged, hit):
+@pytest.mark.parametrize("ragged,hit,use_pallas", [
+    ("auto", True, "auto"), ("off", True, "auto"), ("auto", False, "auto"),
+    ("auto", True, "always")],
+    ids=["packed-hit", "rows-hit", "packed-dense", "packed-hit-kernels"])
+def test_served_path_agrees_with_the_plain_reference(
+        bench, monkeypatch, interpreted, ragged, hit, use_pallas):
     """Three prompts side by side, the longest of three prefill chunks: the
     state carried from chunk to chunk (packed with the other rows' decode
-    steps, or by row), then decode steps through the state pool."""
+    steps, or by row), then decode steps through the state pool: in plain
+    XLA, and by the kernel that advances the states in place."""
     cfg, reference, params = bench
     if not hit:
         monkeypatch.setattr(llama, "hit_experts_pay", lambda c, rows: False)
     prompts = _prompts(cfg, (80, 23, 40))
-    eng = _engine(cfg, params, ragged=ragged)
+    eng = _engine(cfg, params, ragged=ragged, use_pallas=use_pallas)
     served = _serve(eng, prompts, 8)
     assert (eng.metrics["moe_experts_visited"] > 0) == hit
     for prompt, (toks, lps) in zip(prompts, served):
@@ -381,15 +486,19 @@ def test_the_controls_fail_the_tiny_limits(bench):
         assert _rms(ctl, ref) > 3 * cfg["correct"]["limit"], quant
 
 
+@pytest.mark.parametrize("use_pallas", ["auto", "always"])
 @pytest.mark.parametrize("fault", ["state not carried", "slot not zeroed"])
-def test_a_wrong_state_fails_the_tiny_limits(bench, monkeypatch, fault):
+def test_a_wrong_state_fails_the_tiny_limits(bench, monkeypatch, interpreted,
+                                             fault, use_pallas):
     """What the check's six-chunk prompt is for: a program that starts
     every chunk from zeros, or one that goes on from what a slot's last
-    row left, is far from the reference."""
+    row left, is far from the reference (the decode steps in plain XLA or
+    by the kernel, which takes its ``fresh`` rows from the same
+    positions)."""
     cfg, reference, params = bench
     real = llama._kda_attention
 
-    def broken(g, blk, x, state, layer, addr):
+    def broken(g, blk, x, state, layer, addr, use_pallas):
         pos = addr.positions
         if fault == "state not carried":
             if x.shape[1] > 1:      # every chunk of a prompt looks first
@@ -397,10 +506,11 @@ def test_a_wrong_state_fails_the_tiny_limits(bench, monkeypatch, fault):
                     jnp.where(addr.token_mask, 0, pos)
         else:
             pos = jnp.where(pos == 0, 1 << 20, pos)     # never looks first
-        return real(g, blk, x, state, layer, addr._replace(positions=pos))
+        return real(g, blk, x, state, layer, addr._replace(positions=pos),
+                    use_pallas)
 
     monkeypatch.setattr(llama, "_kda_attention", broken)
-    eng = _engine(cfg, params, max_batch=1)
+    eng = _engine(cfg, params, max_batch=1, use_pallas=use_pallas)
     first, second = _prompts(cfg, (80, 72), seed=5)
     (toks, lps), = _serve(eng, [first], 8)
     if fault == "slot not zeroed":          # the second row inherits a state

@@ -271,12 +271,13 @@ ENGINE_OPS: Dict[str, OpSpec] = {
     OP_SLO: _spec(OP_SLO, PLANE_ENGINE, False, {"window": "float?"},
                   {"ok": SLO_RESPONSE_FIELDS}),
     # ``steps_since`` (time.monotonic() seconds; 0 = all) asks for the
-    # engine's step records too: ``steps`` later than the cursor, and
-    # ``steps_dropped`` (Engine.steps_since).
+    # engine's step records too: ``steps`` later than the cursor,
+    # ``steps_dropped`` and the ``late_steps`` records by the same
+    # cursor (Engine.steps_since).
     OP_TRACES: _spec(OP_TRACES, PLANE_ENGINE, True,
                      {"n": "int?", "steps_since": "float?"},
                      {"ok": TRACES_RESPONSE_FIELDS
-                      + ("steps", "steps_dropped")}),
+                      + ("steps", "steps_dropped", "late_steps")}),
     OP_GENERATE: _spec(OP_GENERATE, PLANE_ENGINE, True,
                        {"prompt": "tokens", "stream": "bool?",
                         **_SAMPLING_REQ},

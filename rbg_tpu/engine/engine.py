@@ -27,6 +27,7 @@ import collections
 import dataclasses
 import itertools
 import json
+import logging
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -47,8 +48,13 @@ from rbg_tpu.obs import names as obs_names
 from rbg_tpu.obs import trace
 from rbg_tpu.obs.metrics import REGISTRY
 
+log = logging.getLogger("rbg_tpu.engine")
+
 # Step records kept for the ``traces`` op (about a minute of 14 ms steps).
 STEP_RING = 4096
+# Late-step records kept beside them: they are rare by definition, so 64
+# hold hours of a sound server and every one of a bad minute.
+LATE_RING = 64
 
 
 class _Phase:
@@ -258,6 +264,16 @@ class Engine:
                         "t_unified_s": 0.0, "unified_steps_run": 0,
                         "t_decode_s": 0.0, "decode_steps_run": 0,
                         "kv_live_token_steps": 0, "kv_held_slot_steps": 0,
+                        # What makes a step late, always on: the turns'
+                        # seconds outside sync and idle, and those less
+                        # the loop thread's CPU seconds; late turns and
+                        # their late seconds (``_BatchService._loop`` has
+                        # both rules); steps dispatched with nothing of
+                        # this engine's left running on the device
+                        # (``_note_dispatch``).
+                        "t_host_s": 0.0, "t_host_off_s": 0.0,
+                        "late_steps": 0, "t_late_s": 0.0,
+                        "device_waited_steps": 0,
                         # Decode steps that visited hit experts only
                         # (llama._moe_mlp_hit): experts x layers a step,
                         # and how many of them the step read.
@@ -293,6 +309,10 @@ class Engine:
             maxlen=STEP_RING)
         self._ring_dropped = 0
         self._ring_dropped_t0 = 0.0
+        # One record per late turn of the service loop (``note_late``
+        # has the fields), same clock, same cursor rule.
+        self.late_ring: collections.deque = collections.deque(
+            maxlen=LATE_RING)
 
     def _refuse_for_recurrent(self) -> None:
         """What is not built for a model with recurrent layers, refused
@@ -797,6 +817,14 @@ class Engine:
         if self._dispatched is None:
             self._dispatched = (kind, rows, q_tokens,
                                 (row_bucket, token_bucket))
+            # Is a program this engine dispatched earlier still running?
+            # Only a fused decode window is left unfetched between steps:
+            # with none pending, or its tokens ready, the device has
+            # finished all it was given and idles until this dispatch
+            # lands. No device read, no sync.
+            window = self._dec["pending"] if self._dec is not None else None
+            if window is None or window[1].is_ready():
+                self.metrics["device_waited_steps"] += 1
             if self.state is not None:
                 # Held as the step starts: a row that finishes in this
                 # step frees its slot before the step is recorded.
@@ -854,18 +882,40 @@ class Engine:
                      marks[_EMIT], t_end, kind, rows, q_tokens, bucket,
                      step_num))
 
+    def note_late(self, rec: tuple, late_s: float) -> None:
+        """Account one late turn of the service loop (late by ``late_s``
+        seconds: its time outside ``engine.sync`` and ``service.idle``, the
+        watchdog's lateness in it or a stalled sync wait) and keep its record,
+        made by ``_BatchService._note_late``: ``(t0, t_end, step_num,
+        kind, phase, phase_wall_s, cpu_s, nvcsw, nivcsw, majflt,
+        minflt, gc_collections, gc_pause_s, compiles, relay_frames,
+        watchdog_late_s, stack)`` on time.monotonic(). Rare by definition,
+        so each is also a WARNING in the log and a zero-length
+        ``engine.late_step`` annotation: a device trace that catches one
+        shows it on its own clock."""
+        self.metrics["late_steps"] += 1
+        self.metrics["t_late_s"] += late_s
+        self.late_ring.append(rec)
+        with trace.annotation(obs_names.SPAN_ENGINE_LATE_STEP, phase=rec[4],
+                              wall_ms=round(late_s * 1e3, 3)):
+            pass
+        log.warning("late step: %s", json.dumps(rec))
+
     def steps_since(self, since: float = 0.0) -> dict:
-        """The ``traces`` op's view of the ring: the records that began
+        """The ``traces`` op's view of the rings: the records that began
         after ``since`` (time.monotonic() seconds), oldest first, and
         ``steps_dropped``: 0 when the ring still held every such record,
         else how many it has overwritten so far (an upper bound on those
-        missed; ``step_num``, the last field, counts them exactly)."""
+        missed; ``step_num``, the last field, counts them exactly).
+        ``late_steps`` holds the late turns' records by the same cursor."""
         # Called from a connection thread while the loop thread appends:
-        # the copy is one C call under the interpreter lock.
+        # each copy is one C call under the interpreter lock.
         records = list(self.step_ring)
+        late = list(self.late_ring)
         dropped = self._ring_dropped if self._ring_dropped_t0 > since else 0
         return {"steps": [r for r in records if r[0] > since],
-                "steps_dropped": dropped}
+                "steps_dropped": dropped,
+                "late_steps": [r for r in late if r[0] > since]}
 
     def generate(self, prompts: List[List[int]],
                  sampling: Optional[SamplingParams] = None) -> List[List[int]]:

@@ -130,18 +130,27 @@ class Handler(socketserver.BaseRequestHandler):
                     send_msg(self.request, frame)
                     return
                 tokens = list(pending.tokens)
+                n = len(tokens)
+                frame = None
                 if with_logprobs:
                     lps = list(pending.logprobs)
-                    n = len(tokens) if done else min(len(tokens), len(lps))
+                    if not done:
+                        n = min(n, len(lps))
                     if n > sent:
-                        send_msg(self.request, {"tokens": tokens[sent:n],
-                                                "logprobs": lps[sent:n],
-                                                "done": False})
-                        sent = n
-                elif len(tokens) > sent:
-                    send_msg(self.request, {"tokens": tokens[sent:],
-                                            "done": False})
-                    sent = len(tokens)
+                        frame = {"tokens": tokens[sent:n],
+                                 "logprobs": lps[sent:n], "done": False}
+                elif n > sent:
+                    frame = {"tokens": tokens[sent:], "done": False}
+                if frame is not None:
+                    # The relay's span and clocks: the socket write on the
+                    # profiler's clock, and how long the frame's oldest
+                    # token waited for this thread since its delivery.
+                    t0 = _time.monotonic()
+                    with trace.annotation(names.SPAN_SERVER_RELAY_SEND):
+                        send_msg(self.request, frame)
+                    service.note_relay(n - sent, _time.monotonic() - t0,
+                                       t0 - pending.stamps[sent])
+                    sent = n
                 if done and sent == len(pending.tokens):
                     break
                 if _time.monotonic() > deadline:
@@ -235,8 +244,9 @@ class Handler(socketserver.BaseRequestHandler):
             from rbg_tpu.obs.trace import traces_response
             resp = traces_response(obj.get("n", 10))
             if "steps_since" in obj:
-                # The step timeline's ring (docs/observability.md): the
-                # engine's step records later than the caller's cursor.
+                # The step timeline's rings (docs/observability.md): the
+                # engine's step records, and its late-step records, later
+                # than the caller's cursor.
                 svc = srv.service or srv.decode
                 eng = svc.engine if svc is not None else (
                     srv.prefill.engine if srv.prefill is not None else None)
@@ -245,7 +255,8 @@ class Handler(socketserver.BaseRequestHandler):
                 except (TypeError, ValueError):
                     since = 0.0
                 resp.update(eng.steps_since(since) if eng is not None
-                            else {"steps": [], "steps_dropped": 0})
+                            else {"steps": [], "steps_dropped": 0,
+                                  "late_steps": []})
             send_msg(self.request, resp)
             return
         if op in self._DATA_OPS:
@@ -728,7 +739,7 @@ def serve(args) -> None:
     server.service = server.prefill = server.decode = None
     server.device = None           # set by init_engine, reported by health
     chipenv.configure_compile_cache()
-    server.compile_counter = chipenv.CompileCounter().install()
+    server.compile_counter = chipenv.compile_counter()
     server.auth_token = (args.auth_token
                          or os.environ.get("RBG_DATA_TOKEN") or None)
     server.pd_lock = named_lock("engine.server_pd")

@@ -12,12 +12,14 @@ recycle batch slots + KV pages), and the event pump.
 from __future__ import annotations
 
 import collections
+import resource
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
 from rbg_tpu.engine.config import EngineConfig, SamplingParams
-from rbg_tpu.engine.engine import Engine
+from rbg_tpu.engine.engine import _SYNC, Engine, _Phase
 # Re-exported here for callers that think in service terms; defined in
 # protocol.py so jax-free processes (server startup) can import them.
 from rbg_tpu.engine.protocol import (CODE_DEADLINE, DeadlineExceeded,
@@ -25,18 +27,22 @@ from rbg_tpu.engine.protocol import (CODE_DEADLINE, DeadlineExceeded,
 from rbg_tpu.obs import names, trace
 from rbg_tpu.obs.metrics import REGISTRY
 from rbg_tpu.obs.slo import SLOTargets, SLOTracker
-from rbg_tpu.utils import jitwatch
+from rbg_tpu.utils import chipenv, jitwatch
 from rbg_tpu.utils.locktrace import named_lock
 from rbg_tpu.utils.racetrace import guard as _race_guard
 
 
 class _Pending:
-    __slots__ = ("tokens", "logprobs", "done", "t_submit", "t_admit",
-                 "t_first", "error", "code", "deadline", "span_parent",
-                 "span_queue", "span_scan", "stream_rx")
+    __slots__ = ("tokens", "stamps", "logprobs", "done", "t_submit",
+                 "t_admit", "t_first", "error", "code", "deadline",
+                 "span_parent", "span_queue", "span_scan", "stream_rx")
 
     def __init__(self, deadline: Optional[float] = None):
         self.tokens: List[int] = []
+        # 1:1 with tokens, appended first: the time.monotonic() of the
+        # ``_deliver`` that handed each on (one read a delivery), so that
+        # the relay finds the stamp of its first unsent index.
+        self.stamps: List[float] = []
         self.logprobs: List[float] = []   # 1:1 with tokens when requested
         self.done = threading.Event()
         self.t_submit = time.perf_counter()
@@ -68,6 +74,34 @@ _RATE_WINDOW = 64
 _PF_RATE_TTL_S = 30.0
 # Fallback backpressure hint when no throughput estimate exists yet.
 _RETRY_AFTER_FLOOR_S = 0.5
+# A turn of the loop whose wall time outside ``engine.sync`` and
+# ``service.idle`` passes this is a late step: five times the longest host
+# part of any step on record (10 ms of a unified step), under half the
+# shortest pause on record (105 ms; PERF.md section 5).
+LATE_STEP_S = 0.05
+# A sync wait this long is a turn late by itself: the longest device step
+# on record is 56 ms, so a second of it is a device or a runtime that
+# stands, or the process stood while this thread waited.
+SYNC_STALL_S = 1.0
+# The watchdog's period: a late turn's stack is taken within one period
+# of its becoming late, and 50 wake-ups a second cost a core nothing.
+WATCHDOG_PERIOD_S = 0.02
+# The loop reads its thread's rusage every so many turns, and at the end
+# of every late one: the call costs 6 us on the chip's host (30 in place,
+# between a sync wait and the next turn), and the CPU clock it reads
+# ticks in steps of 10 ms there, so a reading a turn would buy nothing.
+RUSAGE_EVERY = 8
+# Most CPU seconds a turn may leave owing to later turns' off-CPU time:
+# two ticks of the coarsest thread CPU clock met (10 ms, the chip's host).
+_CPU_DEBT_S = 0.02
+# Innermost frames of the loop thread kept in a late-step record.
+STACK_FRAMES = 12
+# The host phases of a turn, in the order a late-step record weighs them,
+# and the sync wait, which it names only where no host phase was late.
+_HOST_PHASES = ((names.SPAN_SERVICE_INTAKE,) + tuple(
+    n for i, n in enumerate(_Phase.SPANS) if i != _SYNC)
+    + (names.SPAN_SERVICE_DELIVER,))
+_LATE_PHASES = _HOST_PHASES + (names.SPAN_ENGINE_SYNC,)
 
 
 def embed_prompts(engine: Engine, prompts: List[List[int]]) -> List[List[float]]:
@@ -210,6 +244,34 @@ class _BatchService:
                          "t_deliver_s": 0.0, "t_idle_s": 0.0,
                          "queue_wait_s": 0.0, "queue_waited": 0,
                          "ttft_s": 0.0, "first_tokens": 0}
+        # The relay's clocks (``server.Handler._stream_pending``, one
+        # thread a streaming request), folded under a plain lock of their
+        # own, never the queue's: token frames sent and their tokens,
+        # seconds inside the socket write, and per frame the write's start
+        # less the loop thread's stamp of the frame's oldest token.
+        self.relay = {"relay_frames": 0, "relay_tokens": 0,
+                      "t_relay_send_s": 0.0, "relay_lag_s": 0.0}
+        self._relay_lock = threading.Lock()
+        # What a late-step record differences over its turn beside the
+        # thread's own rusage: the collector's clock, programs compiled.
+        self._gc = trace.GC_CLOCK.install()
+        self._compiles = chipenv.compile_counter()
+        # The turn in progress, for the watchdog: (start, number, the sync
+        # clock as it started), and whether the loop is in its idle wait.
+        # What the watchdog leaves for the loop thread: the number of the
+        # turn whose stack it took with the stack; when it last woke; and
+        # each of its sleeps that overran by a period or more, (asleep
+        # since, seconds late), for the turn that ends next to take.
+        # ``_wd_since`` is the loop thread's own: a sleep begun before it
+        # is accounted for (the end of the last late turn, or of the turn
+        # that took a sleep as overdue), whenever its report comes.
+        self._turn = (time.monotonic(), 0, 0.0)
+        self._idling = False
+        self._wd_stack: Tuple[int, tuple] = (0, ())
+        self._wd_wake = time.monotonic()
+        self._wd_lates: collections.deque = collections.deque(maxlen=64)
+        self._wd_since = 0.0
+        self._wd_stop = threading.Event()
         self._lock = named_lock("engine.service_queue")
         self._wake = threading.Event()
         self._stopped = False
@@ -223,6 +285,10 @@ class _BatchService:
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name=type(self).__name__.lower())
         self._thread.start()
+        self._watchdog = threading.Thread(
+            target=self._watch, daemon=True,
+            name=type(self).__name__.lower() + "-watchdog")
+        self._watchdog.start()
 
     # -- subclass hooks --
     def _admit(self, item, sampling: SamplingParams) -> Optional[int]:
@@ -492,6 +558,10 @@ class _BatchService:
             depth = len(self._queue)
             out = dict(self.counters)
         out.update(self.timeline)
+        with self._relay_lock:
+            out.update(self.relay)
+        out["gc_collections"] = self._gc.collections
+        out["gc_pause_s"] = self._gc.pause_s
         est = self.estimated_wait_s(depth)
         out["queue_depth"] = depth
         out["max_queue"] = self.max_queue
@@ -508,9 +578,21 @@ class _BatchService:
             self._cancels.append(pending)
         self._wake.set()
 
+    def note_relay(self, tokens: int, send_s: float, lag_s: float) -> None:
+        """One token frame the relay sent (a connection thread)."""
+        with self._relay_lock:
+            r = self.relay
+            r["relay_frames"] += 1
+            r["relay_tokens"] += tokens
+            r["t_relay_send_s"] += send_s
+            r["relay_lag_s"] += lag_s
+
     def stop(self):
         self._stopped = True
         self._wake.set()
+        self._wd_stop.set()
+        if threading.current_thread() is not self._watchdog:
+            self._watchdog.join(timeout=5.0)
         # Join so stop() actually frees the CPU: a "stopped" service whose
         # loop thread lingers keeps polling (and in a test suite, dozens of
         # leaked loops become ambient load that starves later tests).
@@ -578,17 +660,60 @@ class _BatchService:
     def _loop(self):
         """One turn: intake, then either an idle wait or one engine step
         and the delivery of what it emitted. Each part is a phase of the
-        step timeline: a profiler annotation and a cumulative clock."""
+        step timeline: a profiler annotation and a cumulative clock.
+        Every ``RUSAGE_EVERY`` turns, and at a late turn's end, the loop
+        reads its thread's rusage: the turns' wall time outside
+        ``engine.sync`` and ``service.idle`` since the last reading goes
+        to ``t_host_s``, and that less the thread's CPU time to
+        ``t_host_off_s``. A turn is late, and leaves a record of what it
+        can know of the cause (``_note_late``), when that host time
+        passes ``LATE_STEP_S``; and a turn that ran a step also when the
+        watchdog woke that late in it (the process stood, wherever this
+        thread waited) or its sync wait passed ``SYNC_STALL_S``."""
         eng = self.engine
         tl = self.timeline
+        m = eng.metrics
+        clocks = _Phase.CLOCKS
         t_start = time.monotonic()
+        counts = self._counts()
+        seq = 0
+        host_acc = 0.0      # host seconds of the turns since that reading
+        # CPU seconds read beyond the turns' host time, set against later
+        # readings (never above 0): a CPU clock that ticks in steps of
+        # 10 ms gives most readings none and some a whole tick. Bounded,
+        # so that CPU burnt inside a sync wait cannot hide a later pause.
+        carry = 0.0
         while not self._stopped:
             now = time.monotonic()
+            seq += 1
+            # The engine's phase clocks as the turn starts: the turn's
+            # own phases are their differences at its end.
+            phase0 = [m[c] for c in clocks]
+            self._turn = (now, seq, phase0[_SYNC])
             with trace.annotation(names.SPAN_SERVICE_INTAKE):
                 self._intake(now)
             t = time.monotonic()
-            tl["t_intake_s"] += t - now
-            if not eng.has_work():
+            intake = t - now
+            tl["t_intake_s"] += intake
+            if eng.has_work():
+                stepped = True
+                events = eng.step()
+                t = time.monotonic()
+                with trace.annotation(names.SPAN_SERVICE_DELIVER):
+                    self._deliver(events, t)
+                t_end = time.monotonic()
+                deliver = t_end - t
+                tl["t_deliver_s"] += deliver
+                sync_s = m["t_sync_s"] - phase0[_SYNC]
+                host_s = late_s = t_end - now - sync_s
+                wd_late = self._watchdog_late(t_end)
+                if wd_late > late_s:
+                    late_s = wd_late
+                if sync_s > SYNC_STALL_S and sync_s > late_s:
+                    late_s = sync_s
+            else:
+                stepped = False
+                deliver = sync_s = 0.0
                 with self._lock:
                     empty = not self._queue and not self._cancels
                 if empty:
@@ -598,20 +723,133 @@ class _BatchService:
                     # (past the TTL) REPLACE the EMA with that near-zero
                     # rate — shedding the whole next burst.
                     self._pf_t = time.monotonic()
-                    self._pf_tokens = eng.metrics.get("prefill_tokens", 0)
+                    self._pf_tokens = m.get("prefill_tokens", 0)
+                    self._idling = True
                     with trace.annotation(names.SPAN_SERVICE_IDLE):
                         self._wake.wait(0.01)
                         self._wake.clear()
+                    self._idling = False
                     tl["t_idle_s"] += time.monotonic() - t
-                tl["t_loop_s"] = time.monotonic() - t_start
-                continue
-            events = eng.step()
-            t = time.monotonic()
-            with trace.annotation(names.SPAN_SERVICE_DELIVER):
-                self._deliver(events)
-            t_end = time.monotonic()
-            tl["t_deliver_s"] += t_end - t
+                t_end = time.monotonic()
+                host_s = late_s = intake
+                # A sleep the watchdog overran while the loop idled is
+                # nobody's late step: taken here, and left out.
+                wd_late = self._watchdog_late(t_end)
             tl["t_loop_s"] = t_end - t_start
+            host_acc += host_s
+            late = late_s > LATE_STEP_S
+            if late or seq % RUSAGE_EVERY == 0:
+                was, counts = counts, self._counts()
+                carry += host_acc - (counts[0] - was[0])
+                m["t_host_s"] += host_acc
+                host_acc = 0.0
+                if carry > 0.0:
+                    m["t_host_off_s"] += carry
+                    carry = 0.0
+                elif carry < -_CPU_DEBT_S:
+                    carry = -_CPU_DEBT_S
+            if late:
+                self._wd_since = t_end
+                walls = [m[c] - w for c, w in zip(clocks, phase0)]
+                sync_s = walls.pop(_SYNC)
+                walls = [intake] + walls + [deliver, 0.0]
+                if host_s <= LATE_STEP_S:
+                    # Late while this thread waited for the device.
+                    walls = [0.0] * 6 + [sync_s]
+                self._note_late(now, t_end, late_s, seq, stepped, walls,
+                                tuple(b - a for a, b in zip(was, counts)),
+                                wd_late)
+
+    def _watchdog_late(self, t_end: float) -> float:
+        """How late the watchdog ran in the turn that ends now: the
+        longest of the late sleeps it has reported since the last turn
+        ended, or, if it has not woken since the process stood still, how
+        overdue it is (once that alone makes the turn late). A sleep is
+        one turn's: one begun before ``_wd_since`` is left out."""
+        late = 0.0
+        since = self._wd_since
+        lates = self._wd_lates
+        while lates:
+            asleep_since, overran = lates.popleft()
+            if overran > late and asleep_since >= since:
+                late = overran
+        woke = self._wd_wake
+        overdue = t_end - woke - WATCHDOG_PERIOD_S
+        if overdue > LATE_STEP_S and overdue > late and woke >= since:
+            self._wd_since = t_end
+            late = overdue
+        return late
+
+    def _counts(self) -> tuple:
+        """What the loop differences between two readings: its thread's
+        CPU seconds, voluntary and involuntary context switches, major and
+        minor faults (one ``getrusage``, the costly call); the collector's
+        runs and seconds; programs compiled or loaded; token frames the
+        relay sent. A late turn's record holds the differences since the
+        last reading, at most ``RUSAGE_EVERY`` - 1 turns before it."""
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        return (ru.ru_utime + ru.ru_stime, ru.ru_nvcsw, ru.ru_nivcsw,
+                ru.ru_majflt, ru.ru_minflt, self._gc.collections,
+                self._gc.pause_s, self._compiles.programs,
+                self.relay["relay_frames"])
+
+    def _note_late(self, t0: float, t_end: float, late_s: float, seq: int,
+                   stepped: bool, walls: list, counts: tuple,
+                   wd_late: float) -> None:
+        """The record of one late turn (``Engine.note_late`` has the
+        fields): the phase that took most of it (of ``walls``, in
+        ``_LATE_PHASES``' order), the thread's CPU seconds and the other
+        counts over the turn, how late the watchdog itself ran and the
+        stack it took of this thread."""
+        eng = self.engine
+        kind, step_num = "idle", eng.metrics["steps_run"]
+        if stepped:
+            kind = "none"
+            if eng._dispatched is not None:
+                kind, step_num = eng._dispatched[0], step_num - 1
+        worst = max(range(len(walls)), key=walls.__getitem__)
+        wd_seq, stack = self._wd_stack
+        eng.note_late(
+            (t0, t_end, step_num, kind, _LATE_PHASES[worst], walls[worst])
+            + counts + (wd_late, stack if wd_seq == seq else ()), late_s)
+
+    def _watch(self) -> None:
+        """The watchdog: wakes every ``WATCHDOG_PERIOD_S``, reports a
+        sleep of its own that overran by a period or more, and takes the
+        loop thread's Python stack once in a turn that is past
+        ``LATE_STEP_S`` outside ``engine.sync`` and ``service.idle``, or
+        ``SYNC_STALL_S`` into a sync wait. A stack says in what the loop
+        thread stood; a watchdog as late as the turn says that every
+        Python thread of the process stood."""
+        eng = self.engine
+        loop_ident = self._thread.ident
+        last = self._wd_wake
+        while not self._wd_stop.wait(WATCHDOG_PERIOD_S):
+            now = time.monotonic()
+            late = now - last - WATCHDOG_PERIOD_S
+            if late > WATCHDOG_PERIOD_S:
+                # Reported by the sleep's start: a turn that was late
+                # meanwhile, or took the sleep as overdue, leaves it out.
+                self._wd_lates.append((last, late))
+            self._wd_wake = last = now
+            t0, seq, sync0 = self._turn
+            if seq == self._wd_stack[0] or self._idling:
+                continue
+            phase = eng._phase_now
+            if phase is not None and phase.idx == _SYNC:
+                if now - phase.t0 <= SYNC_STALL_S:
+                    continue
+            elif now - t0 - (eng.metrics["t_sync_s"] - sync0) <= LATE_STEP_S:
+                continue
+            frame = sys._current_frames().get(loop_ident)
+            stack = []
+            while frame is not None and len(stack) < STACK_FRAMES:
+                code = frame.f_code
+                stack.append("%s:%d %s" % (
+                    "/".join(code.co_filename.rsplit("/", 2)[-2:]),
+                    frame.f_lineno, code.co_name))
+                frame = frame.f_back
+            self._wd_stack = (seq, tuple(stack))
 
     def _intake(self, now: float) -> None:
         """The turn's bookkeeping before the engine runs: expired and
@@ -690,10 +928,11 @@ class _BatchService:
                 pending.span_queue.end(outcome="cancelled")
                 pending.done.set()
 
-    def _deliver(self, events) -> None:
+    def _deliver(self, events, now: float) -> None:
         """What one engine step produced, handed on: admissions and the
-        step's occupancy observed, tokens to their ``_Pending``s, finished
-        requests judged and released."""
+        step's occupancy observed, tokens to their ``_Pending``s (each
+        stamped ``now``, the delivery's one time.monotonic() read),
+        finished requests judged and released."""
         eng = self.engine
         tl = self.timeline
         self._note_prefill_progress()
@@ -729,6 +968,7 @@ class _BatchService:
                     # kv_stream_overlap invariant compares this
                     # against the stream's FIN arrival.
                     pending.stream_rx.t_first_step = time.monotonic()
+            pending.stamps.append(now)
             pending.tokens.append(ev.token)
             if ev.logprob is not None:
                 pending.logprobs.append(ev.logprob)

@@ -583,6 +583,10 @@ SPAN_ENGINE_PACK = "engine.pack"
 SPAN_ENGINE_DISPATCH = "engine.dispatch"
 SPAN_ENGINE_SYNC = "engine.sync"
 SPAN_ENGINE_EMIT = "engine.emit"
+# A late turn of the loop (zero length, entered when its record is made)
+# and the relay's socket write of one token frame (a connection thread).
+SPAN_ENGINE_LATE_STEP = "engine.late_step"
+SPAN_SERVER_RELAY_SEND = "server.relay_send"
 
 # ---- model scopes (device time by block) ----
 #
@@ -677,4 +681,6 @@ SPANS = frozenset({
     SPAN_ENGINE_DISPATCH,
     SPAN_ENGINE_SYNC,
     SPAN_ENGINE_EMIT,
+    SPAN_ENGINE_LATE_STEP,
+    SPAN_SERVER_RELAY_SEND,
 })

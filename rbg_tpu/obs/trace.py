@@ -42,6 +42,7 @@ beside the device ops with one (docs/observability.md "Step timeline").
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -116,6 +117,36 @@ def annotation(name: str, **attrs):
     if "step_num" in attrs:
         return _profiler.StepTraceAnnotation(name, **attrs)
     return _profiler.TraceAnnotation(name, **attrs)
+
+
+class _GcClock:
+    """Collections and seconds of Python's collector in this process
+    (it runs on whichever thread allocates, holding the interpreter, so
+    its pause is every thread's): one ``gc.callbacks`` hook, installed
+    by the first serving loop that starts and left in place."""
+
+    def __init__(self):
+        self.collections = 0
+        self.pause_s = 0.0
+        self._t0: Optional[float] = None
+        self._lock = threading.Lock()
+
+    def install(self) -> "_GcClock":
+        with self._lock:
+            if self._on_gc not in gc.callbacks:
+                gc.callbacks.append(self._on_gc)
+        return self
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.monotonic()
+        elif self._t0 is not None:
+            self.pause_s += time.monotonic() - self._t0
+            self.collections += 1
+            self._t0 = None
+
+
+GC_CLOCK = _GcClock()
 
 
 def new_trace_id() -> str:

@@ -164,3 +164,19 @@ class CompileCounter:
                     "seconds": round(self.seconds, 3),
                     "cache_hits": self.cache_hits,
                     "recent": [list(r) for r in self.recent]}
+
+
+_PROCESS_COUNTER: Optional[CompileCounter] = None
+_PROCESS_COUNTER_LOCK = threading.Lock()
+
+
+def compile_counter() -> CompileCounter:
+    """This process's one installed counter, for the server's ``metrics``
+    op and the serving loop's late-step records: ``jax.monitoring`` keeps
+    a listener for the life of the process, so those that only read share
+    one."""
+    global _PROCESS_COUNTER
+    with _PROCESS_COUNTER_LOCK:
+        if _PROCESS_COUNTER is None:
+            _PROCESS_COUNTER = CompileCounter().install()
+        return _PROCESS_COUNTER

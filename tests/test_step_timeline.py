@@ -1,13 +1,18 @@
 """The step timeline (docs/observability.md "Step timeline"): the phase
 clocks and counts the engine and the service loop keep, the ring of step
-records behind the ``traces`` op, the profiler annotations' names, and the
-benchmark's metric files that read the clocks off the wire."""
+records behind the ``traces`` op, the late-step records and what they say
+of a turn made late on purpose, the relay's clocks, the profiler
+annotations' names, and the benchmark's metric files that read the clocks
+off the wire."""
 
 import collections
 import glob
 import json
 import math
 import os
+import re
+import resource
+import socket
 import sys
 import time
 
@@ -16,7 +21,8 @@ import pytest
 
 from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
 from rbg_tpu.engine import engine as engine_mod
-from rbg_tpu.engine.protocol import request_once
+from rbg_tpu.engine import service as service_mod
+from rbg_tpu.engine.protocol import recv_msg, request_once, send_msg
 from rbg_tpu.engine.service import EngineService
 from rbg_tpu.models import get_config, init_params
 from rbg_tpu.obs import names, trace
@@ -28,18 +34,36 @@ ENGINE_CLOCKS = ("t_step_s", "t_admit_s", "t_pack_s", "t_dispatch_s",
 PHASE_CLOCKS = ("t_admit_s", "t_pack_s", "t_dispatch_s", "t_sync_s",
                 "t_emit_s")
 LOOP_CLOCKS = ("t_loop_s", "t_intake_s", "t_deliver_s", "t_idle_s",
-               "queue_wait_s", "ttft_s")
+               "queue_wait_s", "ttft_s", "t_host_s", "t_host_off_s",
+               "t_late_s", "gc_pause_s", "t_relay_send_s", "relay_lag_s")
 COUNTS = ("steps", "steps_run", "unified_steps_run", "decode_steps_run",
           "queue_waited", "first_tokens", "kv_live_token_steps",
-          "kv_held_slot_steps")
+          "kv_held_slot_steps", "late_steps", "device_waited_steps",
+          "gc_collections", "relay_frames", "relay_tokens")
+# Nothing in a service driven in-process has to move these: no idle turn,
+# no collection, and no relay (a connection thread of the server).
+MAY_STAY_ZERO = ("t_idle_s", "gc_pause_s", "gc_collections",
+                 "t_relay_send_s", "relay_lag_s", "relay_frames",
+                 "relay_tokens")
 
-# The eleven metric files this timeline brought to the benchmark.
+# The eleven metric files this timeline brought to the benchmark, and the
+# eight that put a late step down to a cause.
 NEW_METRICS = (
     "engine.decode_step_ms", "engine.unified_step_ms",
     "engine.prefill_time_share", "engine.host_ms_per_step",
     "engine.sync_wait_share", "service.queue_wait_mean_ms",
     "service.ttft_server_mean_ms", "service.loop_idle_share",
-    "kv.page_fill_share", "setup.compile_s", "setup.warmup_s")
+    "kv.page_fill_share", "setup.compile_s", "setup.warmup_s",
+    "engine.late_time_share", "engine.late_step_share",
+    "engine.host_offcpu_share", "engine.device_waited_share",
+    "relay.lag_mean_ms", "relay.tokens_per_frame", "engine.gc_pause_share",
+    "relay.send_mean_ms")
+
+# Fields of a late-step record (``Engine.note_late``).
+LATE_FIELDS = ("t0", "t_end", "step_num", "kind", "phase", "phase_wall_s",
+               "cpu_s", "nvcsw", "nivcsw", "majflt", "minflt",
+               "gc_collections", "gc_pause_s", "compiles", "relay_frames",
+               "watchdog_late_s", "stack")
 
 
 @pytest.fixture(scope="module")
@@ -76,18 +100,31 @@ def wait_for_turn(svc):
 # ---- (1) clocks --------------------------------------------------------
 
 
-def test_clocks_are_monotone_and_phases_fit_inside_the_step(svc):
-    seen = []
-    for n in (3, 6, 4):
-        svc.submit([1, 2, 3, 4, 5], SamplingParams(max_new_tokens=n))
-        wait_for_turn(svc)
-        seen.append(svc.stats())
-    for name in ENGINE_CLOCKS + LOOP_CLOCKS + COUNTS:
-        vals = [s[name] for s in seen]
-        assert all(v >= 0 for v in vals), (name, vals)
-        assert vals == sorted(vals), (name, vals)
-        assert vals[-1] > 0 or name == "t_idle_s", (name, vals)
-    last = seen[-1]
+@pytest.fixture(scope="module")
+def three_snapshots(params):
+    s = EngineService(engine_config(), params=params)
+    try:
+        seen = []
+        for n in (3, 6, 4):
+            s.submit([1, 2, 3, 4, 5], SamplingParams(max_new_tokens=n))
+            wait_for_turn(s)
+            seen.append(s.stats())
+        return seen
+    finally:
+        s.stop()
+
+
+@pytest.mark.parametrize("name", ENGINE_CLOCKS + LOOP_CLOCKS + COUNTS)
+def test_clock_or_count_is_present_monotone_and_never_negative(
+        three_snapshots, name):
+    vals = [s[name] for s in three_snapshots]
+    assert all(v >= 0 for v in vals), (name, vals)
+    assert vals == sorted(vals), (name, vals)
+    assert vals[-1] > 0 or name in MAY_STAY_ZERO, (name, vals)
+
+
+def test_phases_fit_inside_the_step(three_snapshots):
+    last = three_snapshots[-1]
     # The phase clocks never overlap, so they sum to no more than the
     # steps' wall time; steps by kind are some of all steps.
     assert sum(last[c] for c in PHASE_CLOCKS) <= last["t_step_s"] + 1e-9
@@ -174,6 +211,274 @@ def test_queue_wait_shows_a_delay_before_admission(svc):
     assert st["ttft_s"] / st["first_tokens"] > st["queue_wait_s"]
 
 
+# ---- (3b) a late step and its cause -------------------------------------
+
+PAUSE_S = 0.12        # over LATE_STEP_S by three watchdog periods
+
+
+REQUEST = ([7, 7, 3, 1, 2], SamplingParams(max_new_tokens=4))
+
+
+def warmed(params):
+    """A service whose programs for ``REQUEST`` are compiled."""
+    s = EngineService(engine_config(), params=params)
+    for _ in range(2):
+        s.submit(*REQUEST)
+    wait_for_turn(s)
+    return s
+
+
+def once(fn, inner):
+    """``inner`` with ``fn()`` before its first call only."""
+    state = {"done": False}
+
+    def slow_once(*a, **kw):
+        if not state["done"]:
+            state["done"] = True
+            fn()
+        return inner(*a, **kw)
+    return slow_once
+
+
+def spin():
+    end = time.thread_time() + PAUSE_S
+    while time.thread_time() < end:
+        pass
+
+
+def late_turn(params, where, pause):
+    """Serve ``REQUEST`` on a warmed service with one phase made slow
+    once: (stats before, stats after, the new late-step records)."""
+    s = warmed(params)
+    try:
+        before = s.stats()
+        obj, attr = {"service.intake": (s, "_pump"),
+                     "engine.admit": (s.engine, "_admit")}[where]
+        real = getattr(obj, attr)
+        setattr(obj, attr, once(pause, real))
+        try:
+            s.submit(*REQUEST)
+        finally:
+            setattr(obj, attr, real)
+        wait_for_turn(s)
+        after = s.stats()
+        new = list(s.engine.late_ring)[before["late_steps"]:]
+        return before, after, [dict(zip(LATE_FIELDS, r)) for r in new]
+    finally:
+        s.stop()
+
+
+def cpu_tick_s():
+    """The step of the thread CPU clock ``getrusage`` reads on this host
+    (a sandboxed kernel's is 10 ms), found by spinning over one."""
+    def read():
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        return ru.ru_utime + ru.ru_stime
+    first = read()
+    while read() == first:
+        pass
+    a = read()
+    while read() == a:
+        pass
+    return read() - a
+
+
+def cpu_far_under_pause():
+    """Most CPU seconds a record of a turn that slept ``PAUSE_S`` may
+    show: a clock of coarse ticks gives a reading of a few ordinary turns
+    a whole tick or two."""
+    return PAUSE_S / 4 + 2 * cpu_tick_s()
+
+
+@pytest.mark.parametrize("where", ["service.intake", "engine.admit"])
+def test_a_turn_that_slept_is_late_off_the_cpu_and_its_stack_says_where(
+        params, where):
+    cpu_bound = cpu_far_under_pause()
+    for attempt in range(3):            # a loaded machine may pause a turn
+        before, after, new = late_turn(params, where,
+                                       lambda: time.sleep(PAUSE_S))
+        if (len(new) == 1 and new[0]["cpu_s"] <= cpu_bound
+                and new[0]["watchdog_late_s"] < PAUSE_S / 2):
+            break
+    assert after["late_steps"] - before["late_steps"] == 1 == len(new)
+    rec = new[0]
+    assert rec["phase"] == where
+    assert rec["phase_wall_s"] >= PAUSE_S and rec["cpu_s"] <= cpu_bound
+    assert rec["t_end"] - rec["t0"] >= rec["phase_wall_s"]
+    assert rec["compiles"] == 0 and rec["gc_pause_s"] < PAUSE_S / 2
+    # The watchdog took the loop thread's stack inside the sleep, and
+    # was itself on time: the thread stood, not the process.
+    assert any(f.endswith(" slow_once") for f in rec["stack"]), rec["stack"]
+    assert all(re.fullmatch(r"\S+\.py:\d+ \S+", f) for f in rec["stack"])
+    assert len(rec["stack"]) <= service_mod.STACK_FRAMES
+    assert rec["watchdog_late_s"] < PAUSE_S / 2
+    off = after["t_host_off_s"] - before["t_host_off_s"]
+    # All of the sleep but what earlier turns' CPU readings left owing.
+    assert PAUSE_S - service_mod._CPU_DEBT_S - 0.01 <= off <= PAUSE_S + 0.1
+    # The clock it is a share of covers the same turns, the sleep's too.
+    assert after["t_host_s"] - before["t_host_s"] >= off
+    late = after["t_late_s"] - before["t_late_s"]
+    assert PAUSE_S <= late <= rec["t_end"] - rec["t0"]
+
+
+def test_a_turn_that_spun_is_late_on_the_cpu(params):
+    before, after, new = late_turn(params, "service.intake", spin)
+    assert after["late_steps"] - before["late_steps"] == 1 == len(new)
+    rec = new[0]
+    assert rec["phase"] == "service.intake"
+    assert rec["cpu_s"] >= PAUSE_S * 0.99
+    assert any(f.endswith(" spin") for f in rec["stack"]), rec["stack"]
+    # Off the CPU only as long as other threads held the interpreter.
+    assert after["t_host_off_s"] - before["t_host_off_s"] < PAUSE_S / 2
+
+
+class SlowWindow:
+    """A pending window's token array that takes its time to arrive."""
+
+    def __init__(self, arr):
+        self.arr = arr
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, *a, **kw):
+        time.sleep(PAUSE_S)
+        return jax.device_get(self.arr)
+
+
+def test_a_sync_wait_that_stalled_is_late_and_named_sync(params, monkeypatch):
+    monkeypatch.setattr(service_mod, "SYNC_STALL_S", 0.05)
+    s = warmed(params)
+    try:
+        before = s.stats()
+        real = s.engine._emit_pending
+        state = {"done": False}
+
+        def slow_fetch(pending):
+            if not state["done"]:
+                state["done"] = True
+                pending = (pending[0], SlowWindow(pending[1])) + pending[2:]
+            return real(pending)
+
+        s.engine._emit_pending = slow_fetch
+        s.submit(*REQUEST)
+        wait_for_turn(s)
+        after = s.stats()
+        new = [dict(zip(LATE_FIELDS, r)) for r in
+               list(s.engine.late_ring)[before["late_steps"]:]]
+        assert after["late_steps"] - before["late_steps"] == len(new)
+        # With the limit this low a loaded machine's ordinary sync wait
+        # may pass it too: the stalled fetch is the one that long.
+        stalled = [r for r in new if r["phase"] == "engine.sync"
+                   and r["phase_wall_s"] >= PAUSE_S]
+        assert len(stalled) == 1, new
+        rec = stalled[0]
+        assert rec["cpu_s"] <= cpu_far_under_pause()
+        assert any(f.endswith(" __array__") for f in rec["stack"]), rec
+        assert after["t_late_s"] - before["t_late_s"] >= PAUSE_S
+        # Waiting for the device is not time the host wanted to run.
+        assert after["t_host_off_s"] - before["t_host_off_s"] < PAUSE_S / 2
+    finally:
+        s.stop()
+
+
+def test_a_turn_in_which_the_watchdog_woke_late_is_late_whatever_its_host_part(
+        params):
+    """The process stood while the loop thread waited in sync: its host
+    part is short, and the watchdog's own lateness is what says so."""
+    s = warmed(params)
+    try:
+        before = s.stats()
+        real_admit = s.engine._admit
+        s.engine._admit = lambda: (time.sleep(0.003), real_admit())
+        p = s.submit_async([7, 7, 3, 1, 2], SamplingParams(max_new_tokens=100))
+        s._wd_stop.wait = once(lambda: time.sleep(PAUSE_S), s._wd_stop.wait)
+        s.wait(p, 60.0)
+        wait_for_turn(s)
+        after = s.stats()
+        new = [dict(zip(LATE_FIELDS, r)) for r in
+               list(s.engine.late_ring)[before["late_steps"]:]]
+        # The loop thread went on turning while the watchdog overslept
+        # (a process that stands holds both): the first turn that finds
+        # it overdue by LATE_STEP_S takes that sleep, and no later one.
+        assert len(new) == 1 == after["late_steps"] - before["late_steps"]
+        rec = new[0]
+        assert rec["watchdog_late_s"] > service_mod.LATE_STEP_S
+        assert rec["phase"] == "engine.sync" and rec["kind"] == "decode"
+        assert rec["t_end"] - rec["t0"] < service_mod.LATE_STEP_S
+        assert (after["t_late_s"] - before["t_late_s"]
+                == pytest.approx(rec["watchdog_late_s"]))
+    finally:
+        s.stop()
+
+
+def test_an_ordinary_turn_of_a_warmed_service_records_none(params):
+    s = warmed(params)
+    try:
+        for _ in range(3):              # a loaded machine may pause a turn
+            before = s.stats()["late_steps"]
+            s.submit(*REQUEST)
+            wait_for_turn(s)
+            if s.stats()["late_steps"] == before:
+                return
+        pytest.fail(f"every request had a late step: {list(s.engine.late_ring)[-3:]}")
+    finally:
+        s.stop()
+
+
+def test_a_first_step_that_compiles_is_late_and_says_so(svc):
+    svc.submit(*REQUEST)
+    wait_for_turn(svc)
+    rec = dict(zip(LATE_FIELDS, svc.engine.late_ring[0]))
+    assert rec["compiles"] > 0 and rec["phase"] == "engine.dispatch"
+    assert rec["kind"] == "unified" and rec["step_num"] == 0
+    assert svc.stats()["late_steps"] == len(svc.engine.late_ring) >= 1
+
+
+def test_late_ring_is_bounded_and_stop_joins_the_watchdog(params):
+    s = EngineService(engine_config(), params=params)
+    assert s.engine.late_ring.maxlen == engine_mod.LATE_RING == 64
+    assert s._watchdog.is_alive() and s._watchdog.daemon
+    s.stop()
+    assert not s._watchdog.is_alive() and not s._thread.is_alive()
+
+
+# ---- (3c) the steps the device waited for --------------------------------
+
+
+class FakeWindow:
+    """The pending window's token array with a readiness of our choosing."""
+
+    def __init__(self, arr, ready):
+        self.arr, self.ready = arr, ready
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, *a, **kw):
+        return jax.device_get(self.arr)
+
+
+@pytest.mark.parametrize("ready", [True, False])
+def test_device_waited_counts_a_dispatch_with_nothing_left_running(
+        params, ready):
+    eng = Engine(engine_config(), params=params)
+    eng.add_request([3, 1, 4, 1, 5], SamplingParams(max_new_tokens=40))
+    m = eng.metrics
+    while m["decode_steps_run"] < 3:
+        eng.step()
+    # Every unified step fetched its own result, and the first decode step
+    # found no window pending: the device waited for each of them.
+    assert m["device_waited_steps"] >= m["unified_steps_run"] + 1
+    pend = eng._dec["pending"]
+    eng._dec["pending"] = (pend[0], FakeWindow(pend[1], ready)) + pend[2:]
+    was, steps = m["device_waited_steps"], m["steps_run"]
+    eng.step()
+    assert m["steps_run"] == steps + 1
+    assert m["device_waited_steps"] - was == (1 if ready else 0)
+    assert m["device_waited_steps"] <= m["steps_run"]
+
+
 # ---- (4) the ring ------------------------------------------------------
 
 
@@ -201,7 +506,8 @@ def test_ring_is_bounded_and_ordered(params):
     later = eng.steps_since(ring[2][0])
     assert [r[10] for r in later["steps"]] == nums[3:]
     assert later["steps_dropped"] == 0
-    assert eng.steps_since(ring[-1][0]) == {"steps": [], "steps_dropped": 0}
+    assert eng.steps_since(ring[-1][0]) == {
+        "steps": [], "steps_dropped": 0, "late_steps": []}
 
 
 # ---- (5) cache held against cache used ----------------------------------
@@ -269,11 +575,34 @@ def test_every_phase_annotation_is_cataloged():
     phases = set(engine_mod._Phase.SPANS)
     assert len(phases) == len(engine_mod._Phase.CLOCKS) == 5
     loop = {names.SPAN_SERVICE_INTAKE, names.SPAN_SERVICE_DELIVER,
-            names.SPAN_SERVICE_IDLE, names.SPAN_ENGINE_STEP}
+            names.SPAN_SERVICE_IDLE, names.SPAN_ENGINE_STEP,
+            names.SPAN_ENGINE_LATE_STEP, names.SPAN_SERVER_RELAY_SEND}
     assert phases | loop <= names.SPANS
     assert {n for n in names.SPANS
             if n.startswith("engine.") and n != names.SPAN_ENGINE_OP} \
-        == phases | {names.SPAN_ENGINE_STEP}
+        == phases | {names.SPAN_ENGINE_STEP, names.SPAN_ENGINE_LATE_STEP}
+    # The host phases a late-step record weighs: all but sync and idle.
+    assert service_mod._LATE_PHASES == service_mod._HOST_PHASES + (
+        names.SPAN_ENGINE_SYNC,)
+    assert set(service_mod._HOST_PHASES) == (phases | loop) - {
+        names.SPAN_ENGINE_SYNC, names.SPAN_SERVICE_IDLE,
+        names.SPAN_ENGINE_STEP, names.SPAN_ENGINE_LATE_STEP,
+        names.SPAN_SERVER_RELAY_SEND}
+
+
+def test_every_cataloged_span_is_entered_somewhere():
+    """The lint's other direction: a name in ``SPANS`` that no call site
+    of the program passes to the tracer is as wrong as one entered and
+    not cataloged."""
+    consts = {k for k, v in vars(names).items()
+              if k.startswith("SPAN_") and v in names.SPANS}
+    assert len(consts) == len(names.SPANS)
+    used = set()
+    for path in glob.glob(os.path.join(ROOT, "rbg_tpu", "**", "*.py"),
+                          recursive=True):
+        if not path.endswith(os.path.join("obs", "names.py")):
+            used |= set(re.findall(r"\bSPAN_[A-Z_]+\b", open(path).read()))
+    assert consts <= used, sorted(consts - used)
 
 
 def test_strict_mode_rejects_an_uncataloged_annotation():
@@ -349,8 +678,27 @@ def wire():
         # A shape the warm-up never met: a program compiled after the
         # first snapshot (the embedding program of a 3-row batch).
         ask({"op": "embed", "prompts": [[1, 2, 3]] * 3})
+        # One request through the relay, so that its clocks have moved.
+        streamed = stream(srv.addr, {"op": "generate", "stream": True,
+                                     "prompt": list(range(1, 24)),
+                                     "max_new_tokens": 9})
         after = ask({"op": "metrics"})["metrics"]
-        yield {"ask": ask, "before": before, "after": after, "t_mid": t_mid}
+        yield {"ask": ask, "before": before, "after": after, "t_mid": t_mid,
+               "addr": srv.addr, "streamed": streamed}
+
+
+def stream(addr, obj):
+    """The token frames of one streamed request, as the relay sent them."""
+    host, port = addr.rsplit(":", 1)
+    frames = []
+    with socket.create_connection((host, int(port)), timeout=120) as sock:
+        send_msg(sock, obj)
+        while True:
+            frame, _, _ = recv_msg(sock)
+            assert frame is not None and "error" not in frame, frame
+            if frame["done"]:
+                return frames
+            frames.append(frame["tokens"])
 
 
 def test_traces_op_returns_the_steps_after_the_cursor(wire):
@@ -371,6 +719,41 @@ def test_traces_op_returns_the_steps_after_the_cursor(wire):
     assert len(late) == (wire["after"]["steps_run"]
                          - wire["before"]["steps_run"])
     assert ask({"op": "traces", "steps_since": steps[-1][0]})["steps"] == []
+
+
+def test_traces_op_returns_the_late_steps_by_the_same_cursor(wire):
+    ask = wire["ask"]
+    assert "late_steps" not in ask({"op": "traces"})
+    late = ask({"op": "traces", "steps_since": 0})["late_steps"]
+    # The warm-up's first steps compiled their programs: late, each.
+    assert len(late) == min(64, wire["after"]["late_steps"]) > 0
+    assert all(len(r) == len(LATE_FIELDS) for r in late)
+    recs = [dict(zip(LATE_FIELDS, r)) for r in late]
+    assert any(r["compiles"] > 0 for r in recs)
+    assert [r["t0"] for r in recs] == sorted(r["t0"] for r in recs)
+    assert all(r["t_end"] - r["t0"] > service_mod.LATE_STEP_S for r in recs)
+    cursor = recs[len(recs) // 2]["t0"]
+    later = ask({"op": "traces", "steps_since": cursor})["late_steps"]
+    assert later == late[len(recs) // 2 + 1:]
+
+
+def test_a_streamed_request_moves_the_relays_clocks(wire):
+    frames = wire["streamed"]
+    assert sum(len(f) for f in frames) == 9 and all(frames)
+    poll = 0.005
+    lags = []
+    for _ in range(3):                  # a loaded machine may oversleep a poll
+        was = wire["ask"]({"op": "metrics"})["metrics"]
+        frames = stream(wire["addr"], {"op": "generate", "stream": True,
+                                       "prompt": [5, 4, 3, 2, 1],
+                                       "max_new_tokens": 12})
+        now = wire["ask"]({"op": "metrics"})["metrics"]
+        sent = now["relay_frames"] - was["relay_frames"]
+        assert sent == len(frames) > 0
+        assert now["relay_tokens"] - was["relay_tokens"] == 12
+        assert now["t_relay_send_s"] > was["t_relay_send_s"]
+        lags.append((now["relay_lag_s"] - was["relay_lag_s"]) / sent)
+    assert 0 < min(lags) <= 3 * poll, lags
 
 
 def window_ctx(before, after):
@@ -405,7 +788,7 @@ def test_metric_file_reads_a_finite_value_off_the_wire(wire, metric):
             assert name in ctx["scalars"], name
 
 
-def test_the_new_metric_files_are_the_eleven():
+def test_the_new_metric_files_are_the_eleven_and_the_eight():
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     listed = [m["name"] for m in bench["per_layer"]]
     assert set(NEW_METRICS) <= set(listed)

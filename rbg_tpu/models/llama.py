@@ -703,38 +703,60 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
     return (o * gate.reshape(B, T, h, dk)).astype(x.dtype), state
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _kda_mixer(cfg: ModelConfig, use_pallas, max_q_len, blk, x, state, layer,
+               addr: PoolAddr):
+    """``_pool_attention`` of a recurrent layer as a program of its own:
+    ``_hybrid_layers`` walks the recurrent mixer in two places (a dense
+    first layer alone, the expert layers in their loop), and a step
+    program traces and lowers it once for both; the compiler inlines it.
+    ``layer`` is an int32 array in both places, ``addr.max_q_len`` rides
+    beside ``addr`` because it is static. The scope opens in here so that
+    an operation's path holds ``attention/kda`` with nothing between."""
+    with jax.named_scope("attention"):
+        return _pool_attention(cfg, blk, x, state, layer,
+                               addr._replace(max_q_len=max_q_len), use_pallas)
+
+
 def _hybrid_plan(cfg: ModelConfig):
-    """How ``_hybrid_layers`` walks a model whose mixers alternate, by
-    MIXER kind (its params key): ``("run", key, lo, hi)`` for a kind that
-    stands in one run of layers, and ``("turns", key of A, key of B,
-    rows)`` for a stretch in which two kinds take turns, a row ``(layers
-    of A, layers of B, first layer)`` a turn."""
-    mixers = [h[1] for h in cfg.layer_halves]
-    runs = []                                   # [key, lo, hi] by mixer kind
-    for layer, key in enumerate(mixers):
-        if runs and runs[-1][0] == key:
+    """How ``_hybrid_layers`` walks a model whose mixers alternate, by KIND
+    of layer, the pair ``(mixer's params key, MLP's params key)``:
+    ``("run", kind, lo, hi)`` for a kind that stands in one run of layers,
+    and ``("turns", kind A, kind B, rows)`` for a stretch in which two kinds
+    take turns, a row ``(layers of A, layers of B, first layer)`` a turn.
+    A dense first layer before the expert layers its mixer leads is a kind
+    in one run, so a segment of its own, and the first turn is that much
+    shorter. More than two kinds in turns (dense layers that reach into
+    the second turn) are not walked."""
+    kinds = [(h[1], h[3]) for h in cfg.layer_halves]
+    runs = []                                   # [kind, lo, hi]
+    for layer, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
             runs[-1][2] = layer + 1
         else:
-            runs.append([key, layer, layer + 1])
-    times = {key: sum(r[0] == key for r in runs) for key in set(mixers)}
+            runs.append([kind, layer, layer + 1])
+    times = {kind: sum(r[0] == kind for r in runs) for kind in set(kinds)}
     plan, i = [], 0
     while i < len(runs):
-        key, lo, hi = runs[i]
-        if times[key] == 1:
-            plan.append(("run", key, lo, hi))
+        kind, lo, hi = runs[i]
+        if times[kind] == 1:
+            plan.append(("run", kind, lo, hi))
             i += 1
             continue
-        a, b, rows = key, None, []
+        a, b, rows = kind, None, []
         while i < len(runs) and times[runs[i][0]] > 1:
-            key, lo, hi = runs[i]
-            if key == a:
+            kind, lo, hi = runs[i]
+            if kind == a:
                 rows.append([hi - lo, 0, lo])
             else:
-                b = b or key
-                if key != b:
+                b = b or kind
+                if kind != b:
+                    name = "+".join
                     raise NotImplementedError(
-                        f"{cfg.name}: mixers of more than two kinds take "
-                        f"turns ({a}, {b}, {key})")
+                        f"{cfg.name}: layers of more than two kinds take "
+                        f"turns ({name(a)}, {name(b)}, {name(kind)}) in the "
+                        f"pattern " + " ".join(
+                            f"{name(k)}:{hi - lo}" for k, lo, hi in runs))
                 rows[-1][1] = hi - lo
             i += 1
         plan.append(("turns", a, b, rows))
@@ -748,73 +770,71 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
     cache: the page pool ``[attention layers, NP, ...]`` by the layer's
     ordinal among the attention layers, the state pool (``pool[4]``) by
     its ordinal among the recurrent ones. A compile follows the number of
-    distinct loop bodies, so each KIND of mixer is traced once, not each
-    of the runs: the parameters are stacked by half-layer
-    (``cfg.param_groups``), mixers that take turns are walked a turn at a
-    time, the layers of a turn in a loop of that turn's own length, and a
-    layer's weights are read from its kinds' stacks by their ordinals
-    there. Where layers of one mixer differ in their MLP (a dense first
-    layer before expert layers) the loop's body branches on it."""
+    distinct loop bodies, so each KIND of layer (a mixer and an MLP) is
+    traced once, not each of the runs: the parameters are stacked by
+    half-layer (``cfg.param_groups``), kinds that take turns are walked a
+    turn at a time, the layers of a turn in a loop of that turn's own
+    length, and a layer's weights are read from its halves' stacks by
+    their ordinals there. A loop carries one kind: a kind of a single
+    layer (a dense first layer before expert layers) is walked on its
+    own, its ordinals static. No body branches on the MLP's kind: the
+    operands of a ``lax.cond`` in a loop are copied whole in every trip,
+    whichever branch runs (a dense MLP's ``w_down`` and ``w_up``, 85 MB
+    a trip; PERF.md, PR 38)."""
     *pages, state = pool
     NP = pages[0].shape[1]
     flat = jax.tree_util.tree_map(
         lambda p: p.reshape((-1,) + p.shape[2:]), tuple(pages))
     rows = x.shape[0] * x.shape[1]
     halves = cfg.layer_halves
+    configs = {(h[1], h[3]): h[0] for h in halves}
+    # A mixer reads its own fields of a layer's config, so one layer's
+    # stands for every layer of that mixer: ``_kda_mixer``'s static key.
+    mixer_cfg = {h[1]: h[0] for h in reversed(halves)}
     # By absolute layer: the ordinal among its mixer kind's layers, in its
     # params and in its pool alike (a kind's layers are its pool's, in order).
     mixer_at = jnp.asarray([h[2] for h in halves], jnp.int32)
     mlp_at = jnp.asarray([h[4] for h in halves], jnp.int32)
-    dense_at = jnp.asarray([not h[0].num_experts for h in halves])
 
-    def layer(key, li, carry):
-        """Layer ``li``, whose mixer's kind is ``key``."""
+    def layer(kind, li, carry):
+        """Layer ``li`` of ``kind``: a loop's counter, or the layer's own
+        number (an int) where it is walked alone."""
         h, flat, state, seen = carry
-        # the kinds of layer that this mixer leads, by their MLP's key
-        kinds = {m[3]: m[0] for m in halves if m[1] == key}
-        g = next(iter(kinds.values()))
-        blk = {k: v[mixer_at[li]] for k, v in params[key].items()}
-        with jax.named_scope("attention"):
-            if g.attention == "kda":
-                attn, state = _pool_attention(g, blk, h, state, mixer_at[li],
-                                              addr, use_pallas)
-            else:
-                attn, flat = _pool_attention(
-                    g, blk, h, flat, addr.page_table + mixer_at[li] * NP, addr,
-                    use_pallas)
-
-        def rest(mlp, g):
-            """``wo`` and the MLP of kind ``mlp``; its weights are read
-            from their stack in here, where each slice meets its dot."""
-            n = mlp_at[li]
-            hit_only = experts_whole and hit_experts_pay(g, rows)
-            stacks = ({k: params[mlp][k] for k in _EXPERT_STACKS}
-                      if hit_only else {})
-            both = {k: v[n] for k, v in params[mlp].items()
-                    if k not in stacks}
-            both["wo"] = params[key]["wo"][mixer_at[li]]
-            if hit_only:
-                return _post_attention(
-                    g, both, h, attn, hit_experts=(stacks, n, addr.token_mask))
-            return _post_attention(g, both, h, attn), jnp.zeros((), jnp.int32)
-
-        if len(kinds) == 1:
-            h, visited = rest(*next(iter(kinds.items())))
+        (key, mlp), g = kind, configs[kind]
+        m, n = ((halves[li][2], halves[li][4]) if isinstance(li, int)
+                else (mixer_at[li], mlp_at[li]))
+        blk = {k: v[m] for k, v in params[key].items()}
+        if g.attention == "kda":
+            attn, state = _kda_mixer(
+                mixer_cfg[key], use_pallas, addr.max_q_len, blk, h, state,
+                jnp.asarray(m, jnp.int32), addr._replace(max_q_len=None))
         else:
-            h, visited = jax.lax.cond(
-                dense_at[li], *(functools.partial(rest, mlp, kinds[mlp])
-                                for mlp in ("dense_mlps", "moe_mlps")))
+            with jax.named_scope("attention"):
+                attn, flat = _pool_attention(
+                    g, blk, h, flat, addr.page_table + m * NP, addr,
+                    use_pallas)
+        # The expert stacks stay whole for the hit form, which reads
+        # ``(layer, expert)`` at each visit.
+        hit_only = experts_whole and hit_experts_pay(g, rows)
+        blk.update((k, v[n]) for k, v in params[mlp].items()
+                   if not (hit_only and k in _EXPERT_STACKS))
+        if not hit_only:
+            return _post_attention(g, blk, h, attn), flat, state, seen
+        stacks = {k: params[mlp][k] for k in _EXPERT_STACKS}
+        h, visited = _post_attention(
+            g, blk, h, attn, hit_experts=(stacks, n, addr.token_mask))
         return h, flat, state, seen + visited
 
-    def loop(key, count, l0, carry):
+    def loop(kind, count, l0, carry):
         return jax.lax.fori_loop(
-            0, count, lambda i, c: layer(key, l0 + i, c), carry)
+            0, count, lambda i, c: layer(kind, l0 + i, c), carry)
 
     carry = (x, flat, state, jnp.zeros((), jnp.int32))
     for seg in _hybrid_plan(cfg):
         if seg[0] == "run":
-            _, key, lo, hi = seg
-            carry = loop(key, hi - lo, lo, carry)
+            _, kind, lo, hi = seg
+            carry = (layer(kind, lo, carry) if hi - lo == 1
+                     else loop(kind, hi - lo, lo, carry))
             continue
         _, a, b, turns = seg
 

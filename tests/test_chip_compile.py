@@ -280,9 +280,9 @@ def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
     """``benchmark/configs/kimi-linear-48b-a3b.json`` as served: all 27
     layers (one dense recurrent layer, 19 recurrent and 7 latent expert
     layers), 16 of 256 experts a layer, 16 rows, pages for the 7 latent
-    layers alone and a state slot a row beside them. Each KIND of mixer is
-    one loop body read from its kind's stack by a dynamic index (the MLP's
-    weights inside the branch that uses them): no
+    layers alone and a state slot a row beside them. Each KIND of layer is
+    one loop body read from its halves' stacks by a dynamic index, the dense
+    first layer on its own before the loops: no
     temporary the size of a layer's held experts (226 MB) or of a pool, and
     no ``copy`` of a pool's shape: not of the page pools, not of the state
     pool ``f32[20,16,32,128,128]``, not of the convolution tails (held flat:
@@ -318,6 +318,17 @@ def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
         [int(d) for d in dims.split(",")]) in pools]
     # 14 MB and 85 MB when written; a layer's held experts are 226 MB
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+    # No loop body copies a matrix of the dense MLP (42.5 MB each): as
+    # operands of a ``conditional`` in the recurrent layers' body, ``w_down``
+    # and ``w_up`` were copied into VMEM in every one of its 20 trips
+    # (``copy-done bf16[1,9216,2304]``, 1.77 ms of a decode step). What the
+    # compiler prefetches once a step stands in the entry computation.
+    dense = eng.params["dense_mlps"]["w_down"].size
+    in_loops = [dims for dims in re.findall(
+        r"= \w+\[([\d,]+)\]\S* copy(?:-done)?\(",
+        text[:text.index("\nENTRY ")])
+        if np.prod([int(d) for d in dims.split(",")]) == dense]
+    assert not in_loops
     # A decode step advances the states where they lie (the kernel of
     # ``ops/pallas/kda_kernel.py``, aliased onto the pool inside the layer
     # scan): nothing gathers the rows' states out of the pool or scatters

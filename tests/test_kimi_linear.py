@@ -88,25 +88,130 @@ def test_num_params_counts_what_init_makes():
     assert CFG.num_params == n
 
 
+KDA_DENSE, KDA_MOE, MLA_MOE = (("kda_mixers", "dense_mlps"),
+                               ("kda_mixers", "moe_mlps"),
+                               ("mixers", "moe_mlps"))
+
+
+def _published(**kw):
+    """The published pattern at the tiny widths: 27 layers, every fourth
+    and the last with latent attention."""
+    return dataclasses.replace(
+        CFG, num_layers=27,
+        kda_layers=tuple(n for n in range(1, 27) if n % 4), **kw)
+
+
 def test_mixers_that_take_turns_are_walked_a_turn_at_a_time():
     # (layers of A, layers of B, first layer) a turn; the dense first layer
-    # is the first turn's first recurrent layer
+    # is a segment of its own, and the first turn one recurrent layer shorter
     assert _hybrid_plan(CFG) == [
-        ("turns", "kda_mixers", "mixers", [[3, 1, 0], [3, 1, 4]])]
-    # the published pattern: 3 3 3 3 3 3 2 recurrent layers a turn
-    full = dataclasses.replace(
-        CFG, num_layers=27, kda_layers=tuple(
-            n for n in range(1, 27) if n % 4))
-    (_, a, b, turns), = _hybrid_plan(full)
-    assert (a, b) == ("kda_mixers", "mixers")
-    assert [t[0] for t in turns] == [3, 3, 3, 3, 3, 3, 2]
+        ("run", KDA_DENSE, 0, 1),
+        ("turns", KDA_MOE, MLA_MOE, [[2, 1, 1], [3, 1, 4]])]
+    # the published pattern: 2 3 3 3 3 3 2 recurrent layers a turn
+    full = _published()
+    alone, (_, a, b, turns) = _hybrid_plan(full)
+    assert alone == ("run", KDA_DENSE, 0, 1)
+    assert (a, b) == (KDA_MOE, MLA_MOE)
+    assert [t[0] for t in turns] == [2, 3, 3, 3, 3, 3, 2]
     assert all(t[1] == 1 for t in turns)
-    assert [t[2] for t in turns] == [0, 4, 8, 12, 16, 20, 24]
+    assert [t[2] for t in turns] == [1, 4, 8, 12, 16, 20, 24]
     assert len(full.layer_groups) == 15 and len(full.param_groups) == 4
-    # a kind in one run is a loop of its own; three kinds in turns raise
+    # a kind in one run is a segment of its own, whatever its length
     lead = dataclasses.replace(CFG, kda_layers=(1, 2, 3))
-    assert _hybrid_plan(lead) == [("run", "kda_mixers", 0, 3),
-                                  ("run", "mixers", 3, 8)]
+    assert _hybrid_plan(lead) == [("run", KDA_DENSE, 0, 1),
+                                  ("run", KDA_MOE, 1, 3),
+                                  ("run", MLA_MOE, 3, 8)]
+    # dense layers that fill the first turn are two runs before the turns
+    assert _hybrid_plan(_published(first_dense_layers=4))[:2] == [
+        ("run", KDA_DENSE, 0, 3), ("run", ("mixers", "dense_mlps"), 3, 4)]
+
+
+@pytest.mark.parametrize("dense,third,pattern", [
+    (5, "(kda_mixers+dense_mlps, kda_mixers+moe_mlps, mixers+moe_mlps)",
+     "kda_mixers+dense_mlps:3 mixers+dense_mlps:1 kda_mixers+dense_mlps:1 "
+     "kda_mixers+moe_mlps:2 mixers+moe_mlps:1 kda_mixers+moe_mlps:3"),
+    (8, "(kda_mixers+dense_mlps, mixers+dense_mlps, kda_mixers+moe_mlps)",
+     "kda_mixers+dense_mlps:3 mixers+dense_mlps:1 kda_mixers+dense_mlps:3 "
+     "mixers+dense_mlps:1 kda_mixers+moe_mlps:3 mixers+moe_mlps:1")],
+    ids=["dense-into-the-second-turn", "two-dense-turns"])
+def test_a_pattern_of_three_kinds_in_turns_raises_and_names_it(
+        dense, third, pattern):
+    """Leading dense layers that reach past the first turn: a kind that
+    comes back after another has begun taking turns."""
+    with pytest.raises(NotImplementedError) as e:
+        _hybrid_plan(_published(first_dense_layers=dense))
+    said = str(e.value)
+    assert "more than two kinds take turns" in said
+    assert third in said and pattern in said
+
+
+def _eqns(jaxpr, in_loop=False):
+    """Every equation of ``jaxpr`` at any depth, each with whether it sits
+    inside the body of a ``while`` or a ``scan``."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_loop
+        inner = in_loop or eqn.primitive.name in ("while", "scan")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inner)
+
+
+def _step_jaxpr(program, R=4):
+    """The jaxpr of ``tiny-kimi-linear``'s decode step (by row, the hit
+    experts' form) or its ragged step (packed), ``R`` rows."""
+    T = 1 if program == "decode" else 16
+    cache, pool = PagedKVCache.create(CFG, 64, 8), StatePool(CFG, R)
+    I32 = jnp.int32
+    table = jnp.zeros((R, 8), I32)
+    slots = {"state": pool.arrays, "state_slots": jnp.arange(R, dtype=I32)}
+    if program == "decode":
+        fn = functools.partial(llama.forward_paged, PARAMS, CFG,
+                               experts_whole=True, **slots)
+        args = (jnp.ones((R, T), I32), jnp.zeros((R, T), I32),
+                jnp.ones((R, T), bool), jnp.ones(R, I32), table)
+    else:
+        fn = functools.partial(llama.forward_ragged, PARAMS, CFG,
+                               max_q_len=T, **slots)
+        args = (jnp.ones((1, T), I32), jnp.zeros((1, T), I32),
+                jnp.ones((1, T), bool), jnp.zeros(T, I32),
+                jnp.full(R, T, I32), table)
+    return jax.make_jaxpr(fn)(*args, cache.k_pages, cache.v_pages).jaxpr
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_no_loop_body_branches_over_an_mlps_weights(program):
+    """A ``lax.cond``'s operands are copied whole in every trip of the loop
+    that holds it, whichever branch runs (PERF.md, PR 38): no loop of the
+    walk hands one a matrix of an MLP, whole or a layer's slice."""
+    jaxpr = _step_jaxpr(program)
+    matrices = {a.shape[cut:] for key in ("dense_mlps", "moe_mlps")
+                for a in PARAMS[key].values() if a.ndim > 2
+                for cut in (0, 1)}
+    assert (1, 320, 128) in matrices and (320, 128) in matrices
+    # the walk is there: loops, and in them the sampler-free step's only
+    # conds are those of the operations (none takes a matrix of an MLP)
+    assert any(e.primitive.name in ("while", "scan") for e in jaxpr.eqns)
+    fed = [(e.params["branches"][0].jaxpr.eqns[:1], v.aval.shape)
+           for e, inside in _eqns(jaxpr)
+           if inside and e.primitive.name == "cond" for v in e.invars
+           if getattr(v.aval, "shape", None) in matrices]
+    assert not fed
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_a_step_program_traces_the_recurrent_mixer_once(program, monkeypatch):
+    """The walk meets the recurrent mixer in two places, the dense first
+    layer alone and the expert layers' loop: ``_kda_mixer`` makes them one
+    trace (a third body cost 16 s of warm set-up on the chip; PERF.md,
+    PR 38); none where an earlier program of these shapes left it one."""
+    traced = []
+    mixer = llama._kda_attention
+    monkeypatch.setattr(llama, "_kda_attention", lambda *a, **kw: (
+        traced.append(a[4]), mixer(*a, **kw))[1])
+    jaxpr = _step_jaxpr(program, R=3)
+    assert len(traced) <= 1
+    calls = [e for e, _ in _eqns(jaxpr)
+             if e.params.get("name") == "_kda_mixer"]
+    assert len(calls) == 2              # layer 0, and the loop's body
 
 
 def test_pages_are_the_attention_layers_and_states_the_recurrent_ones():

@@ -178,8 +178,10 @@ class Engine:
         # or from this base + request id (distinct streams). See sampler.py.
         self._sample_base = jax.random.key(cfg.seed + 1)
 
-        self.cache = PagedKVCache.create(self.mcfg, cfg.num_pages, cfg.page_size,
-                                         quantize=(cfg.kv_dtype == "int8"))
+        self.cache = PagedKVCache.create(
+            self.mcfg, cfg.num_pages, cfg.page_size,
+            quantize=(cfg.kv_dtype == "int8"),
+            tp=1 if mesh is None else mesh.shape.get("tp", 1))
         self.allocator = PageAllocator(cfg.num_pages)
         # A model with recurrent layers keeps a state slot a row beside
         # the pages (of its attention layers alone).
@@ -324,7 +326,9 @@ class Engine:
             why = ("speculative decoding: a rejected draft would have to "
                    "be taken out of the state again")
         elif cfg.kv_dtype == "int8":
-            why = "kv_dtype int8: the recurrent state is float32 only"
+            why = ("kv_dtype int8: the state pool has no quantised form (a "
+                   "delta-rule state is float32, a convolution's tail the "
+                   "model's dtype)")
         elif cfg.mode != "unified":
             why = (f"mode {cfg.mode!r}: a PD bundle carries pages, not the "
                    f"recurrent state")
@@ -336,7 +340,8 @@ class Engine:
         if why:
             raise ValueError(
                 f"model {self.mcfg.name!r} has recurrent layers "
-                f"(kda_layers), which do not support {why}")
+                f"({', '.join(self.mcfg.recurrent_kinds)}), which do not "
+                f"support {why}")
 
     def _slot_rows(self, reqs, B: int):
         """``[B]`` state slots of ``reqs`` in row order, on the device; a

@@ -4,17 +4,19 @@ The serving engine's memory system (SGLang/vLLM-equivalent, see PAPERS.md
 "Ragged Paged Attention" for the TPU kernel this layout feeds):
 
 * Device: ``k_pages/v_pages [L, num_pages, page_size, KV, hd]`` — one shared
-  pool for all sequences, static shapes (XLA-friendly). A latent-attention
+  pool for all sequences, static shapes (XLA-friendly). Heads smaller than
+  a lane tile lie side by side in it, ``[..., KV / p, p * hd]``
+  (``heads_per_lane_tile``). A latent-attention
   model's pair is the latent ``[L, NP, page, 1, dc]`` and the rotary key
   ``[L, NP, page, 1, dr rounded up to 128]`` (``rope_pool_width``).
 * Host: ``PageAllocator`` free list + per-sequence page tables (plain ints —
   page logistics never enter the compiled graph; only gather/scatter indices
   do).
 
-A model with recurrent layers (``cfg.kda_layers``) keeps pages for its
-attention layers alone (``[attention layers, NP, ...]``, a layer's pages by
-its ordinal among them) and, beside them, a ``StatePool``: a slot a live
-row, holding each recurrent layer's fixed state.
+A model with recurrent layers (``cfg.mixer_kinds``: ``kda``, ``conv``) keeps
+pages for its attention layers alone (``[attention layers, NP, ...]``, a
+layer's pages by its ordinal among them) and, beside them, a ``StatePool``:
+a slot a live row, holding each recurrent layer's fixed state.
 
 Sharding: pages shard over ``tp`` on the KV-head dim like the contiguous
 cache (see rbg_tpu.parallel.sharding.cache_specs).
@@ -51,10 +53,35 @@ def rope_pool_width(cfg: ModelConfig) -> int:
     return -(-cfg.qk_rope_head_dim // _LANES) * _LANES
 
 
+def heads_per_lane_tile(cfg: ModelConfig, tp: int = 1) -> int:
+    """How many KV heads a GQA pool keeps side by side on its minor axis,
+    ``p``: the pool is ``[L, NP, page, KV / p, p * hd]``, the row-major
+    view of ``[..., KV, hd]`` with whole lane tiles last. Heads of 64 lie
+    two to a tile (``p = 2``); heads of a tile or more, or a head count
+    that ``p`` (times the ``tp`` shards of the head axis) does not divide,
+    stay as they are (``p = 1``). The reason is ``rope_pool_width``'s: a
+    last dim under a lane tile gives a step program's pool parameter
+    another layout than its scatter and its kernels use, and every step
+    program copied the whole pool on the way in and out, padded to 128
+    lanes (3U of HBM for a pool of U; ROADMAP S2 (b), seen on llama3-1b in
+    PR 21). Padding the heads as the rotary key is padded would double
+    the cache; side by side nothing is added. A token's write is a free
+    reshape (``ops/paged_attention.py::_as_stored``); the kernels attend a
+    tile's heads at once with each head's queries zero in the other
+    heads' lanes (``ops/pallas/page_walk.py::pack_queries``). An int8 pool
+    stays ``[..., KV, hd]``: its scales are a (slot, head)."""
+    hd, kv = cfg.head_dim_, cfg.num_kv_heads
+    if cfg.mla or hd >= _LANES or _LANES % hd:
+        return 1
+    p = _LANES // hd
+    return p if kv % (p * tp) == 0 else 1
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:
-    """k/v pages [L, NP, page, KV, hd]. With int8 quantization the pages are
+    """k/v pages [L, NP, page, KV, hd] (heads under a lane tile side by
+    side: ``heads_per_lane_tile``). With int8 quantization the pages are
     int8 and per-(slot, head) scales live alongside ([L, NP, page, KV, 1]) —
     halving KV HBM at a small accuracy cost (per-vector absmax scaling).
     For a latent-attention model ``k_pages`` is the latent ``[L, NP, page,
@@ -80,7 +107,10 @@ class PagedKVCache:
 
     @staticmethod
     def create(cfg: ModelConfig, num_pages: int, page_size: int = 16,
-               dtype=None, quantize: bool = False) -> "PagedKVCache":
+               dtype=None, quantize: bool = False,
+               tp: int = 1) -> "PagedKVCache":
+        """``tp``: over how many devices the pool's head axis will be
+        sharded (``Engine._shard_state``)."""
         layers = paged_layer_count(cfg)
         if cfg.mla:
             # MLA latent pool: k holds the compressed latent, v the shared
@@ -113,6 +143,8 @@ class PagedKVCache:
                 v_scales=jnp.zeros(sshape, jnp.float32),
             )
         dtype = dtype or cfg.jax_dtype
+        p = heads_per_lane_tile(cfg, tp)
+        shape = shape[:3] + (cfg.num_kv_heads // p, p * cfg.head_dim_)
         return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
                             v_pages=jnp.zeros(shape, dtype))
 
@@ -132,48 +164,56 @@ class PagedKVCache:
 
 def paged_layer_count(cfg: ModelConfig) -> int:
     """Layers that keep pages: all but the recurrent ones."""
-    return cfg.num_layers - len(cfg.kda_layers)
+    return cfg.mixer_count("full")
 
 
 class StatePool:
     """The recurrent layers' cache: ``slots`` slots, one a live row, each
-    holding for every recurrent layer the state ``[H, dk, dk]`` in float32
-    and the convolution's tail, the last ``K - 1`` inputs of q, k and v,
-    flat (``models/llama.py::_kda_attention`` reads and writes them; the step
-    programs carry ``arrays`` as they carry the pages). The host side is a
-    free list. A slot is never cleared on the device: a row whose tokens
-    start at position 0 starts from zeros in the step program itself, so a
-    slot taken at admission is a zero state whatever it held."""
+    holding every recurrent layer's state. Which arrays there are follows
+    the mixer kinds the model has (``array_shapes``): a KDA layer keeps
+    ``s``, the state ``[H, dk, dk]`` in float32, and ``conv``, the last
+    ``K - 1`` inputs of q, k and v (``models/llama.py::_kda_attention``); a
+    gated short convolution keeps ``tail`` alone, the last ``K - 1`` gated
+    inputs (``_conv_attention``). Each array leads with its own kind's
+    layers, and a tail is held flat. The step programs carry ``arrays`` as
+    they carry the pages. The host side is a free list. A slot is never
+    cleared on the device: a row whose tokens start at position 0 starts
+    from zeros in the step program itself, so a slot taken at admission is
+    a zero state whatever it held."""
 
     def __init__(self, cfg: ModelConfig, slots: int):
         self.slots = slots
-        self.arrays = {
-            "s": jnp.zeros(self.state_shape(cfg, slots), jnp.float32),
-            "conv": jnp.zeros(self.tail_shape(cfg, slots), cfg.jax_dtype)}
+        self.arrays = {name: jnp.zeros(shape, dtype) for name, (shape, dtype)
+                       in self.array_shapes(cfg, slots).items()}
         self._free: List[int] = list(range(slots - 1, -1, -1))
         # Bytes one live row's state moves a step if each recurrent layer
         # reads and writes it once (the wire counter ``state_bytes_moved``).
-        self.row_bytes = 2 * sum(a.nbytes for a in self.arrays.values()) \
-            // slots
+        self.row_bytes = 2 * self.hbm_bytes(cfg, slots) // slots
 
     @staticmethod
-    def state_shape(cfg: ModelConfig, slots: int):
-        return (len(cfg.kda_layers), slots, cfg.kda_num_heads,
-                cfg.kda_head_dim, cfg.kda_head_dim)
-
-    @staticmethod
-    def tail_shape(cfg: ModelConfig, slots: int):
-        # The taps side by side on the minor axis: a second-minor axis of
-        # K - 1 = 3 would be padded to a whole tile of 16.
-        return (len(cfg.kda_layers), slots, (cfg.kda_conv_kernel - 1)
-                * 3 * cfg.kda_num_heads * cfg.kda_head_dim)
+    def array_shapes(cfg: ModelConfig, slots: int) -> dict:
+        """``{name: (shape, dtype)}`` of the arrays the model's recurrent
+        mixers keep. A tail's taps lie side by side on the minor axis: a
+        second-minor axis of ``K - 1`` (3, 2) would be padded to a whole
+        tile of 16, and every step program copied such an array whole."""
+        out = {}
+        kda, conv = cfg.mixer_count("kda"), cfg.mixer_count("conv")
+        if kda:
+            out["s"] = ((kda, slots, cfg.kda_num_heads, cfg.kda_head_dim,
+                         cfg.kda_head_dim), jnp.dtype(jnp.float32))
+            out["conv"] = ((kda, slots, (cfg.kda_conv_kernel - 1) * 3
+                            * cfg.kda_num_heads * cfg.kda_head_dim),
+                           cfg.jax_dtype)
+        if conv:
+            out["tail"] = ((conv, slots, (cfg.conv_kernel - 1)
+                            * cfg.hidden_size), cfg.jax_dtype)
+        return out
 
     @staticmethod
     def hbm_bytes(cfg: ModelConfig, slots: int) -> int:
         """Bytes of the arrays a pool of ``slots`` slots allocates."""
-        return (4 * math.prod(StatePool.state_shape(cfg, slots))
-                + cfg.jax_dtype.itemsize
-                * math.prod(StatePool.tail_shape(cfg, slots)))
+        return sum(math.prod(shape) * dtype.itemsize for shape, dtype
+                   in StatePool.array_shapes(cfg, slots).values())
 
     @property
     def held(self) -> int:

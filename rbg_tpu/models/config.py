@@ -28,9 +28,15 @@ class _Numbers(tuple):
     __hash__ = tuple.__hash__
 
 
+# What mixes tokens in a layer -> the key its half-layers are stacked under.
+MIXER_KEYS = {"full": "mixers", "kda": "kda_mixers", "conv": "conv_mixers"}
+# A published ``layer_types`` entry -> the mixer kind.
+_LAYER_TYPES = {"full_attention": "full", "conv": "conv"}
+
+
 def _half_keys(g) -> tuple:
     """(mixer's params key, MLP's params key) of a layer of kind ``g``."""
-    return ("kda_mixers" if g.attention == "kda" else "mixers",
+    return (MIXER_KEYS[g.attention],
             "moe_mlps" if g.num_experts else "dense_mlps")
 
 
@@ -103,8 +109,21 @@ class ModelConfig:
     kda_head_dim: int = 128
     kda_conv_kernel: int = 4
     kda_rank: int = 128
+    # What mixes tokens in each layer, as published (LFM2 ``layer_types``):
+    # ``full_attention`` (this config's attention) or ``conv``, the gated
+    # short convolution (``ops/short_conv.py``): ``[B, C, X] = W_in x``,
+    # a causal depthwise convolution of ``conv_kernel`` taps over ``B * X``
+    # with no activation, ``W_out (C * .)``. Its state is the last
+    # ``conv_kernel - 1`` values of ``B * X`` a sequence, kept in a
+    # ``StatePool`` slot like a KDA layer's. Empty: ``kda_layers`` says
+    # which layers are recurrent (``mixer_kinds`` reads both).
+    layer_types: Tuple[str, ...] = ()
+    conv_kernel: int = 3
+    # RMSNorm over each query and key head, with a learned weight, before
+    # RoPE (GQA attention only).
+    qk_norm: bool = False
     # What a GROUP config's layers mix tokens by: ``full`` (this config's
-    # attention) or ``kda``. Set by ``layer_groups`` alone.
+    # attention), ``kda`` or ``conv``. Set by ``layer_groups`` alone.
     attention: str = "full"
     # Which half of a layer a group config of ``param_groups`` stands for:
     # ``mixer`` (norm, what mixes tokens, its output projection), ``mlp``
@@ -119,6 +138,17 @@ class ModelConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "kda_layers", _Numbers(self.kda_layers))
+        object.__setattr__(self, "layer_types", _Numbers(self.layer_types))
+        if self.layer_types:
+            unknown = sorted(set(self.layer_types) - set(_LAYER_TYPES))
+            if (unknown or self.kda_layers
+                    or len(self.layer_types) != self.num_layers):
+                raise ValueError(
+                    f"layer_types names a kind for each of the "
+                    f"{self.num_layers} layers, one of {sorted(_LAYER_TYPES)}"
+                    f", and leaves kda_layers empty; got "
+                    f"{len(self.layer_types)} entries, unknown {unknown}, "
+                    f"kda_layers {tuple(self.kda_layers)}")
         if self.experts_held is not None:
             lo, hi = self.experts_held
             if not 0 <= lo < hi <= self.num_experts:
@@ -151,9 +181,28 @@ class ModelConfig:
         return self.experts_held[1] - self.experts_held[0]
 
     @property
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        """What mixes tokens in each layer, in order: ``full`` (pages),
+        ``kda`` or ``conv`` (a slot of the state pool)."""
+        if self.layer_types:
+            return tuple(_LAYER_TYPES[t] for t in self.layer_types)
+        return tuple("kda" if layer + 1 in self.kda_layers else "full"
+                     for layer in range(self.num_layers))
+
+    def mixer_count(self, kind: str) -> int:
+        """Layers whose mixer is ``kind``."""
+        return self.mixer_kinds.count(kind)
+
+    @property
+    def recurrent_kinds(self) -> Tuple[str, ...]:
+        """The mixer kinds that keep a recurrent state in place of pages,
+        by name (a refusal's message names them)."""
+        return tuple(sorted(set(self.mixer_kinds) - {"full"}))
+
+    @property
     def recurrent(self) -> bool:
         """Whether some layer keeps a recurrent state in place of pages."""
-        return bool(self.kda_layers)
+        return bool(self.recurrent_kinds)
 
     @property
     def layer_groups(self):
@@ -170,22 +219,22 @@ class ModelConfig:
         key in several runs and stacks its parameters by half-layer
         (``param_groups``)."""
         n = self.num_layers - self.num_moe_layers if self.num_experts else 0
-        if not n and not self.kda_layers:
+        if not n and not self.recurrent:
             return (("blocks", self, 0, self.num_layers),)
-        if not self.kda_layers:
+        if not self.recurrent:
             dense = dataclasses.replace(self, num_experts=0,
                                         first_dense_layers=0)
             return (("dense_blocks", dense, 0, n),
                     ("blocks", self, n, self.num_layers))
         kinds, runs = {}, []
-        for layer in range(self.num_layers):
-            kda, dense = (layer + 1) in self.kda_layers, layer < n
-            key = ("kda_" if kda else "") + ("dense_" if dense else "") \
-                + "blocks"
+        for layer, mixer in enumerate(self.mixer_kinds):
+            dense = layer < n
+            key = ("" if mixer == "full" else mixer + "_") \
+                + ("dense_" if dense else "") + "blocks"
             if key not in kinds:
                 kinds[key] = dataclasses.replace(
-                    self, kda_layers=(), first_dense_layers=0,
-                    attention="kda" if kda else "full",
+                    self, kda_layers=(), layer_types=(),
+                    first_dense_layers=0, attention=mixer,
                     **({"num_experts": 0, "experts_held": None}
                        if dense else {}))
             if runs and runs[-1][0] == key:
@@ -200,11 +249,12 @@ class ModelConfig:
         under each key of the parameters, with a leading axis ``layers``.
         The groups of ``layer_groups`` where each stands in one run. A
         model with recurrent layers stacks HALF-layers, in layer order:
-        the mixers by kind (``kda_mixers``, ``mixers``) and the MLPs by
+        the mixers by kind (``kda_mixers``, ``conv_mixers``, ``mixers``)
+        and the MLPs by
         kind (``dense_mlps``, ``moe_mlps``), so that a walk compiles each
         mixer once whatever MLP follows it (``layer_halves`` says which
         entries are a layer's)."""
-        if not self.kda_layers:
+        if not self.recurrent:
             return tuple((key, g, hi - lo) for key, g, lo, hi
                          in self.layer_groups)
         groups = {}
@@ -256,6 +306,8 @@ class ModelConfig:
                     + h * dv * d)            # wo
         else:
             attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
+            if self.qk_norm:
+                attn += 2 * hd
         dense_mlp = 3 * d * f
         # The experts this device holds; the router is whole.
         moe_mlp = self.experts_here * 3 * d * self.moe_f + d * self.num_experts
@@ -266,17 +318,15 @@ class ModelConfig:
         n_moe = self.num_moe_layers
         mlp = n_moe * moe_mlp + (self.num_layers - n_moe) * dense_mlp
         head = 0 if self.tie_word_embeddings else d * v
-        n_kda = len(self.kda_layers)
-        if n_kda:
-            kh, kd, r = self.kda_num_heads, self.kda_head_dim, self.kda_rank
-            ch = kh * kd
-            kda = (3 * d * ch + self.kda_conv_kernel * 3 * ch   # qkv, conv
-                   + 2 * (d * r + r * ch)       # decay and gate, low-rank
-                   + kh + ch + d * kh           # A_log, dt_bias, beta
-                   + kd + ch * d)               # o_norm, wo
-            attn_all = n_kda * kda + (self.num_layers - n_kda) * attn
-        else:
-            attn_all = self.num_layers * attn
+        kh, kd, r = self.kda_num_heads, self.kda_head_dim, self.kda_rank
+        ch = kh * kd
+        kda = (3 * d * ch + self.kda_conv_kernel * 3 * ch   # qkv, conv
+               + 2 * (d * r + r * ch)       # decay and gate, low-rank
+               + kh + ch + d * kh           # A_log, dt_bias, beta
+               + kd + ch * d)               # o_norm, wo
+        conv = 3 * d * d + self.conv_kernel * d + d * d     # in, taps, out
+        attn_all = sum({"full": attn, "kda": kda, "conv": conv}[kind]
+                       for kind in self.mixer_kinds)
         return (v * d + attn_all + self.num_layers * 2 * d + mlp + d + head)
 
 
@@ -393,6 +443,23 @@ _PRESETS = {
         v_head_dim=32, use_rope=False,
         kda_layers=(1, 2, 3, 5, 6, 7), kda_num_heads=4, kda_head_dim=32,
         kda_rank=16,
+    ),
+    # Tiny LFM2-shaped model for tests (the layers of the benchmark's
+    # lfm2-24b-a2b): two dense layers with the gated short convolution,
+    # then expert layers A C C C A C C C (A: grouped-query attention with
+    # head norms over heads of 64, whose pages pack two heads a lane tile;
+    # C: the convolution), bias-selected sigmoid experts without a shared
+    # one, a held range of them, a tied head.
+    "tiny-lfm2": ModelConfig(
+        name="tiny-lfm2", vocab_size=256, hidden_size=128,
+        intermediate_size=320, num_layers=10, num_heads=4, num_kv_heads=2,
+        head_dim=64, max_seq_len=256, rope_theta=1000000.0,
+        rms_norm_eps=1e-5, dtype="float32", tie_word_embeddings=True,
+        num_experts=16, experts_per_token=4, moe_intermediate_size=48,
+        first_dense_layers=2, moe_scoring="sigmoid", moe_select_bias=True,
+        experts_held=(4, 12), qk_norm=True, conv_kernel=3,
+        layer_types=("conv", "conv") + ("full_attention", "conv", "conv",
+                                        "conv") * 2,
     ),
 }
 

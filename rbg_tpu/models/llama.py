@@ -105,6 +105,15 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
     elif cfg.attention == "kda":
         blocks.update(_init_kda(cfg, jax.random.fold_in(key, 11), L, nrm,
                                 s_in, s_out))
+    elif cfg.attention == "conv":
+        # The taps are drawn as KDA's: the convolution's output has about
+        # its input's size.
+        blocks.update({
+            "conv_in": nrm(ks[1], (L, d, 3 * d), s_in),
+            "conv_w": nrm(ks[2], (L, cfg.conv_kernel, d),
+                          cfg.conv_kernel ** -0.5),
+            "wo": nrm(ks[4], (L, d, d), s_out),
+        })
     elif cfg.mla:
         dc, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
         dr, dv = cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -132,6 +141,9 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
             "wv": nrm(ks[3], (L, d, kv * hd), s_in),
             "wo": nrm(ks[4], (L, h * hd, d), s_out),
         })
+        if cfg.qk_norm:
+            blocks.update({"q_head_norm": jnp.ones((L, hd), dt),
+                           "k_head_norm": jnp.ones((L, hd), dt)})
     if cfg.half == "mixer":
         del blocks["mlp_norm"]
         return blocks
@@ -219,7 +231,8 @@ def _lora_proj(xa, base_w, name, lora, lora_ids):
 
 
 def _qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
-    """Shared pre-attention math: norm → projections (+opt bias) → RoPE."""
+    """Shared pre-attention math: norm → projections (+opt bias) → (opt
+    RMSNorm of each query and key head, ``cfg.qk_norm``) → RoPE."""
     B, T, _ = x.shape
     hd, h, kv = cfg.head_dim_, cfg.num_heads, cfg.num_kv_heads
     xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
@@ -233,6 +246,9 @@ def _qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
     q = q.reshape(B, T, h, hd)
     k = k.reshape(B, T, kv, hd)
     vv = vv.reshape(B, T, kv, hd)
+    if cfg.qk_norm:
+        q = rms_norm(q, blk["q_head_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, blk["k_head_norm"], cfg.rms_norm_eps)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_interleave)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_interleave)
     return q, k, vv
@@ -292,8 +308,8 @@ def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
     With ``hit_experts`` (``_moe_mlp_hit``'s stacks, layer and live rows)
     the experts are the hit ones only, and their count is returned too."""
     B, T, _ = x.shape
-    with jax.named_scope("attention/kda" if cfg.attention == "kda"
-                         else "attention"):
+    with jax.named_scope("attention" if cfg.attention == "full"
+                         else "attention/" + cfg.attention):
         x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
                            lora_ids)
     with jax.named_scope("moe" if cfg.num_experts else "mlp"):
@@ -556,8 +572,9 @@ def _no_recurrent(cfg: ModelConfig, what: str) -> None:
     """Recurrent layers are served over the paged pools alone."""
     if cfg.recurrent:
         raise NotImplementedError(
-            f"{cfg.name} has recurrent layers (kda_layers): {what} keeps "
-            f"no state for them; serve it through the engine "
+            f"{cfg.name} has recurrent layers "
+            f"({', '.join(cfg.recurrent_kinds)}): "
+            f"{what} keeps no state for them; serve it through the engine "
             f"(forward_paged / forward_ragged)")
 
 
@@ -582,18 +599,20 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
     MLA latents, which ride the pool as the (c, k_pe) pair), the write of
     this step's slots, the attend, by the row or the packed operations as
     the input says (``row_ids``). ``table`` is the layer's own. Returns
-    (attn ``[B, T, h, dv]``, pool). For a recurrent layer (``cfg.attention
-    == "kda"``) ``pool`` is the state pool's arrays and ``table`` the
-    layer's ordinal in them (``_kda_attention``)."""
+    (attn ``[B, T, h, dv]``, pool). For a recurrent layer (``cfg.attention``
+    ``kda`` or ``conv``) ``pool`` is the state pool's arrays and ``table``
+    the layer's ordinal in them (``_kda_attention``, ``_conv_attention``)."""
     from rbg_tpu.ops.mla_attention import (paged_mla_attention,
                                             ragged_paged_mla_attention)
     from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
     from rbg_tpu.ops.ragged_paged_attention import (ragged_paged_attention,
                                                     write_kv_pages_ragged)
 
-    if cfg.attention == "kda":
-        with jax.named_scope("kda"):
-            return _kda_attention(cfg, blk, x, pool, table, addr, use_pallas)
+    if cfg.attention != "full":
+        mixer = {"kda": _kda_attention, "conv": _conv_attention}
+        with jax.named_scope(cfg.attention):
+            return mixer[cfg.attention](cfg, blk, x, pool, table, addr,
+                                        use_pallas)
     positions, token_mask, kv_lens, _, row_ids, max_q_len, _ = addr
     if row_ids is None:
         write, attend, attend_mla = (write_kv_pages, paged_attention,
@@ -625,6 +644,63 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
                   v_scales=vsf, **bound), pool
 
 
+def _row_lines(addr: PoolAddr, T: int):
+    """How a recurrent mixer lays a packed step's tokens out a row a line:
+    ``(lines, packed)``. ``lines(a)`` takes ``[1, T, ...]`` to ``[R, C,
+    ...]`` (``C`` the longest row the step may hold; padding is dropped,
+    what no token fills is zero) and ``packed(o)`` takes ``[R, C, ...]``
+    back to ``[1, T, ...]``. A step that is by row already gets
+    identities."""
+    from rbg_tpu.ops.ragged_paged_attention import _unpack_offsets
+
+    if addr.row_ids is None:
+        return (lambda a: a), (lambda o: o)
+    R = addr.kv_lens.shape[0]
+    C = T if addr.max_q_len is None else min(addr.max_q_len, T)
+    col = _unpack_offsets(addr.row_ids)
+    row = jnp.where(addr.token_mask[0], addr.row_ids, R)     # padding: dropped
+
+    def lines(a):
+        return jnp.zeros((R, C) + a.shape[2:], a.dtype).at[row, col].set(
+            a[0], mode="drop")
+
+    return lines, lambda o: o[addr.row_ids, col][None]
+
+
+def _conv_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
+                    use_pallas: str):
+    """The gated short convolution of one layer (LFM2; the walk is
+    ``ops/short_conv.py``'s): ``[B, C, X] = x~ W_in``, a causal depthwise
+    convolution of ``cfg.conv_kernel`` taps over ``u = B * X``, nothing
+    activated, ``C *`` the result. ``state["tail"] [Lc, slots, (K-1) d]``
+    holds each row's last ``K - 1`` values of ``u``, flat, and ``layer`` is
+    this layer's ordinal in it; the rules of a slot are
+    ``_kda_attention``'s: a row whose tokens start at position 0 starts
+    from a zero tail whatever its slot held, every other row goes on from
+    its slot, a row with no real token leaves it as it was. Plain XLA on
+    every backend (``use_pallas`` is not read): a decode step moves 8 KB a
+    row. Returns (``[B, T, d]`` before ``wo``; state)."""
+    from rbg_tpu.ops.short_conv import gated_short_conv
+
+    T, d = x.shape[1], cfg.hidden_size
+    xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
+    lines, packed = _row_lines(addr, T)
+    b, c, xg, pos, mask = (lines(a) for a in (
+        *jnp.split(xa @ blk["conv_in"], 3, axis=-1), addr.positions,
+        addr.token_mask))
+    lens = jnp.sum(mask, axis=1, dtype=jnp.int32)
+    slots = addr.state_slots
+    fresh = (pos[:, 0] == 0) & mask[:, 0]
+    tail = state["tail"].at[layer, slots].get(mode="clip")
+    tail = jnp.where(fresh[:, None], jnp.zeros_like(tail), tail)
+    out, tail = gated_short_conv(b, c, xg, tail.reshape(tail.shape[0], -1, d),
+                                 blk["conv_w"], lens)
+    tail = tail.reshape(tail.shape[0], -1)
+    state = {**state,
+             "tail": state["tail"].at[layer, slots].set(tail, mode="drop")}
+    return packed(out), state
+
+
 def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
                    use_pallas: str):
     """The recurrent mixer of one layer (Kimi Delta Attention; the
@@ -642,7 +718,6 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
     packed again. Returns (``[B, T, H, dk]``, normed by head and gated,
     before ``wo``; state)."""
     from rbg_tpu.ops import kda
-    from rbg_tpu.ops.ragged_paged_attention import _unpack_offsets
 
     B, T, _ = x.shape
     h, dk = cfg.kda_num_heads, cfg.kda_head_dim
@@ -656,19 +731,10 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
     gate = jax.nn.sigmoid(
         ((xa @ blk["kda_g_down"]) @ blk["kda_g_up"]).astype(f32))
 
-    mask, pos = addr.token_mask, addr.positions
-    if addr.row_ids is not None:     # [1, T] packed -> [R, C], a row a line
-        R = addr.kv_lens.shape[0]
-        C = T if addr.max_q_len is None else min(addr.max_q_len, T)
-        col = _unpack_offsets(addr.row_ids)
-        row = jnp.where(mask[0], addr.row_ids, R)            # padding: dropped
-
-        def lines(a):
-            return jnp.zeros((R, C) + a.shape[2:], a.dtype).at[row, col].set(
-                a[0], mode="drop")
-
-        qkv, g, beta, pos, mask = (lines(a) for a in (qkv, g, beta, pos,
-                                                      mask))
+    # [1, T] packed -> [R, C], a row a line
+    lines, packed = _row_lines(addr, T)
+    qkv, g, beta, pos, mask = (lines(a) for a in (
+        qkv, g, beta, addr.positions, addr.token_mask))
     g = jnp.where(mask[..., None, None], g, 0.0)
     beta = jnp.where(mask[..., None], beta, 0.0)
     lens = jnp.sum(mask, axis=1, dtype=jnp.int32)
@@ -694,28 +760,35 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
                       state["s"].at[layer, slots].get(mode="clip"))
         o, S = kda.kda_chunk(q, k, v, g, beta, S)
         s = state["s"].at[layer, slots].set(S, mode="drop")
-    state = {"s": s,
+    state = {**state, "s": s,
              "conv": state["conv"].at[layer, slots].set(tail, mode="drop")}
-    if addr.row_ids is not None:
-        o = o[addr.row_ids, col][None]                       # [1, T, h, dk]
+    o = packed(o)                                            # [1, T, h, dk]
     o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                           + cfg.rms_norm_eps) * blk["kda_o_norm"].astype(f32)
     return (o * gate.reshape(B, T, h, dk)).astype(x.dtype), state
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _kda_mixer(cfg: ModelConfig, use_pallas, max_q_len, blk, x, state, layer,
-               addr: PoolAddr):
-    """``_pool_attention`` of a recurrent layer as a program of its own:
-    ``_hybrid_layers`` walks the recurrent mixer in two places (a dense
-    first layer alone, the expert layers in their loop), and a step
-    program traces and lowers it once for both; the compiler inlines it.
-    ``layer`` is an int32 array in both places, ``addr.max_q_len`` rides
-    beside ``addr`` because it is static. The scope opens in here so that
-    an operation's path holds ``attention/kda`` with nothing between."""
-    with jax.named_scope("attention"):
-        return _pool_attention(cfg, blk, x, state, layer,
-                               addr._replace(max_q_len=max_q_len), use_pallas)
+def _mixer_program(kind: str):
+    """``_pool_attention`` of a recurrent layer of ``kind`` as a program of
+    its own, ``_<kind>_mixer``: ``_hybrid_layers`` walks a recurrent mixer
+    in two places (the dense first layers alone, the expert layers in
+    their loop), and a step program traces and lowers it once for both;
+    the compiler inlines it. ``layer`` is an int32 array in both places,
+    ``addr.max_q_len`` rides beside ``addr`` because it is static. The
+    scope opens in here so that an operation's path holds
+    ``attention/<kind>`` with nothing between."""
+    def mixer(cfg: ModelConfig, use_pallas, max_q_len, blk, x, state, layer,
+              addr: PoolAddr):
+        with jax.named_scope("attention"):
+            return _pool_attention(cfg, blk, x, state, layer,
+                                   addr._replace(max_q_len=max_q_len),
+                                   use_pallas)
+
+    mixer.__name__ = mixer.__qualname__ = f"_{kind}_mixer"
+    return jax.jit(mixer, static_argnums=(0, 1, 2))
+
+
+_RECURRENT_MIXERS = {kind: _mixer_program(kind) for kind in ("kda", "conv")}
 
 
 def _hybrid_plan(cfg: ModelConfig):
@@ -766,7 +839,7 @@ def _hybrid_plan(cfg: ModelConfig):
 def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
                    use_pallas: str, experts_whole: bool):
     """``paged_layers`` for a model whose layers differ in what mixes
-    tokens (``cfg.kda_layers``): every layer, in order, each over its own
+    tokens (``cfg.mixer_kinds``): every layer, in order, each over its own
     cache: the page pool ``[attention layers, NP, ...]`` by the layer's
     ordinal among the attention layers, the state pool (``pool[4]``) by
     its ordinal among the recurrent ones. A compile follows the number of
@@ -789,7 +862,7 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
     halves = cfg.layer_halves
     configs = {(h[1], h[3]): h[0] for h in halves}
     # A mixer reads its own fields of a layer's config, so one layer's
-    # stands for every layer of that mixer: ``_kda_mixer``'s static key.
+    # stands for every layer of that mixer: ``_mixer_program``'s static key.
     mixer_cfg = {h[1]: h[0] for h in reversed(halves)}
     # By absolute layer: the ordinal among its mixer kind's layers, in its
     # params and in its pool alike (a kind's layers are its pool's, in order).
@@ -804,8 +877,8 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
         m, n = ((halves[li][2], halves[li][4]) if isinstance(li, int)
                 else (mixer_at[li], mlp_at[li]))
         blk = {k: v[m] for k, v in params[key].items()}
-        if g.attention == "kda":
-            attn, state = _kda_mixer(
+        if g.attention != "full":
+            attn, state = _RECURRENT_MIXERS[g.attention](
                 mixer_cfg[key], use_pallas, addr.max_q_len, blk, h, state,
                 jnp.asarray(m, jnp.int32), addr._replace(max_q_len=None))
         else:

@@ -599,9 +599,10 @@ SPAN_SERVER_RELAY_SEND = "server.relay_send"
 # and ``shared`` open inside ``moe`` (paths ``moe/router``, ``moe/shared``).
 MODEL_SCOPES = ("attention", "mlp", "moe", "lm_head", "sampler")
 MOE_INNER_SCOPES = ("router", "shared")
-# Inside ``attention``: a recurrent layer's mixer (``models/llama.py::
-# _kda_attention`` and its ``wo``); ``device.kda_share`` reads the path.
-ATTENTION_INNER_SCOPES = ("kda",)
+# Inside ``attention``: a recurrent layer's mixer and its ``wo``, by kind
+# (``models/llama.py::_kda_attention``, ``_conv_attention``);
+# ``device.kda_share`` and ``device.conv_share`` read the paths.
+ATTENTION_INNER_SCOPES = ("kda", "conv")
 
 # ---- jitted program catalog (jitwatch sentry + warmers) ----
 #

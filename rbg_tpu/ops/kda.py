@@ -43,7 +43,8 @@ one of a server's thirty packed programs); the decay and the sums into the
 state stay float32.
 
 ``short_conv`` is the causal depthwise convolution over time that q, k and
-v pass first; its cache is the last ``K - 1`` inputs of the row.
+v pass first, then SiLU; its cache is the last ``K - 1`` inputs of the row
+(the walk is ``short_conv.causal_conv``, which the LFM2 mixer shares).
 """
 
 from __future__ import annotations
@@ -52,6 +53,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from rbg_tpu.ops.short_conv import causal_conv
 
 SUB = 16            # tokens of a sub-chunk
 _MAX_LOG = 80.0     # bound of -G inside a sub-chunk
@@ -70,14 +73,8 @@ def short_conv(x, tail, w, lens):
     them (zeros where the sequence starts), ``w [K, ch]`` (the last tap
     meets the current input). Returns (``y [R, C, ch]``, the new tail:
     the last ``K - 1`` real inputs of each row)."""
-    K = w.shape[0]
-    C = x.shape[1]
-    xx = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    y = sum(xx[:, j:j + C].astype(jnp.float32) * w[j].astype(jnp.float32)
-            for j in range(K))
-    new_tail = jax.vmap(
-        lambda a, n: jax.lax.dynamic_slice_in_dim(a, n, K - 1, 0))(xx, lens)
-    return jax.nn.silu(y).astype(x.dtype), new_tail.astype(tail.dtype)
+    y, new_tail = causal_conv(x, tail, w, lens)
+    return jax.nn.silu(y).astype(x.dtype), new_tail
 
 
 def kda_step(q, k, v, g, b, S):

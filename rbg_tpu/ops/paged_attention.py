@@ -41,12 +41,17 @@ def paged_attention_xla(
     v_scales: jnp.ndarray = None,
 ) -> jnp.ndarray:
     B, T, H, hd = q.shape
-    KV = k_pages.shape[2]
-    G = H // KV
     S = page_table.shape[1] * k_pages.shape[1]
 
-    k = gather_kv(k_pages, page_table).astype(jnp.float32)  # [B, S, KV, hd]
-    v = gather_kv(v_pages, page_table).astype(jnp.float32)
+    # A pool that keeps small heads side by side (``[NP, page, KV / p,
+    # p * hd]``, ``kvcache.heads_per_lane_tile``) is ``[.., KV, hd]`` row-major:
+    # the gathered view is reshaped, never the pool.
+    k = gather_kv(k_pages, page_table).reshape(B, S, -1, hd).astype(
+        jnp.float32)                                        # [B, S, KV, hd]
+    v = gather_kv(v_pages, page_table).reshape(B, S, -1, hd).astype(
+        jnp.float32)
+    KV = k.shape[2]
+    G = H // KV
     if k_scales is not None:
         k = k * gather_kv(k_scales, page_table)
         v = v * gather_kv(v_scales, page_table)
@@ -74,6 +79,13 @@ def quantize_kv(x: jnp.ndarray):
     return jnp.clip(q, -127, 127).astype(jnp.int8), scale
 
 
+def _as_stored(new, pages):
+    """``new [..., KV, hd]`` as the pool ``[NP, page, KV / p, p * hd]`` stores
+    a slot: heads under a lane tile side by side (a row-major reshape; the
+    same array where the pool is ``[NP, page, KV, hd]``)."""
+    return new.astype(pages.dtype).reshape(new.shape[:-2] + pages.shape[2:])
+
+
 def write_kv_pages(k_pages, v_pages, k_new, v_new, page_table, positions,
                    token_mask, k_scales=None, v_scales=None):
     """Scatter new K/V into the pool (quantizing when the pool is int8).
@@ -97,8 +109,10 @@ def write_kv_pages(k_pages, v_pages, k_new, v_new, page_table, positions,
         k_scales = k_scales.at[phys, slot].set(k_s, mode="drop")
         v_scales = v_scales.at[phys, slot].set(v_s, mode="drop")
         return k_pages, v_pages, k_scales, v_scales
-    k_pages = k_pages.at[phys, slot].set(k_new.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[phys, slot].set(v_new.astype(v_pages.dtype), mode="drop")
+    k_pages = k_pages.at[phys, slot].set(_as_stored(k_new, k_pages),
+                                         mode="drop")
+    v_pages = v_pages.at[phys, slot].set(_as_stored(v_new, v_pages),
+                                         mode="drop")
     return k_pages, v_pages, None, None
 
 

@@ -134,6 +134,37 @@ def latent_pools(c_pages, pe_pages):
                  for p in (c_pages, pe_pages))
 
 
+def pack_queries(qg, p: int):
+    """Queries ``[..., KV, G, hd]`` for a pool that keeps ``p`` heads side
+    by side, ``[NP, page, KV / p, p * hd]`` (``kvcache.heads_per_lane_tile``):
+    ``[..., KV / p, p * G, p * hd]``, in which row ``i * G + g`` of a tile
+    holds head ``i``'s query ``g`` in lanes ``i * hd ..`` and zeros in the
+    other heads' lanes. A dot with the tile's keys over all ``p * hd``
+    lanes is then that head's own score, the zeros taking the other heads
+    out, and the kernels run as on ``KV / p`` heads of ``p * hd`` with
+    ``p * G`` queries each (at ``p`` times the MXU work, of a walk that
+    memory bounds). In XLA, on a step's queries. ``p = 1`` changes
+    nothing."""
+    if p == 1:
+        return qg
+    *lead, KV, G, hd = qg.shape
+    q5 = qg.reshape(*lead, KV // p, p, G, 1, hd)
+    own = jnp.eye(p, dtype=qg.dtype).reshape(p, 1, p, 1)
+    return (q5 * own).reshape(*lead, KV // p, p * G, p * hd)
+
+
+def unpack_outputs(out, p: int):
+    """The inverse on a kernel's output ``[..., KV / p, p * G, p * hd]``:
+    each row's own head's lanes, ``[..., KV, G, hd]``."""
+    if p == 1:
+        return out
+    *lead, KVp, pG, phd = out.shape
+    G, hd = pG // p, phd // p
+    o6 = out.reshape(*lead, KVp, p, G, p, hd)
+    own = jnp.stack([o6[..., i, :, i, :] for i in range(p)], axis=-3)
+    return own.reshape(*lead, KVp * p, G, hd)
+
+
 def load_latent_blocks(page_refs):
     """``load_blocks`` of the latent pools ``c, pe [n·page, d]`` and, for
     int8 pools, their per-slot scales ``[n·page]`` (else None, None)."""
@@ -177,16 +208,19 @@ def _update(scores, mask, m_ref, l_ref, acc_ref, values):
     acc_ref[...] = acc_ref[...] * alpha + values(probs)
 
 
-def gqa_attend(q, k, v, ks, vs, token0, limit, m_ref, l_ref, acc_ref):
+def gqa_attend(q, k, v, ks, vs, token0, limit, m_ref, l_ref, acc_ref,
+               head_dim=None):
     """Attend query rows ``q [KV, rows, hd]`` to one block ``k, v
     [S, KV, hd]`` whose first slot is token ``token0`` of the row; query
-    row ``j`` sees slots ``< limit`` (a scalar, or ``[rows, 1]``).
+    row ``j`` sees slots ``< limit`` (a scalar, or ``[rows, 1]``). Scores
+    are scaled by ``head_dim ** -0.5``: ``hd``, but for a pool of packed
+    heads (``pack_queries``), whose ``hd`` here is ``p`` heads wide.
 
     int8 pools hand their per-(slot, head) scales ``ks, vs [S, KV]``:
     they factor out of both dots (scores ·= ks, pv = (probs·vs)·v), so
     the pages are never multiplied elementwise."""
     q = q.astype(jnp.float32)
-    rows, hd = q.shape[1], q.shape[2]
+    rows, hd = q.shape[1], head_dim or q.shape[2]
     k_t = jnp.transpose(k.astype(jnp.float32), (1, 0, 2))   # [KV, S, hd]
     v_t = jnp.transpose(v.astype(jnp.float32), (1, 0, 2))
     scores = jax.lax.dot_general(

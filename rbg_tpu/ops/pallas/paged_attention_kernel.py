@@ -66,6 +66,7 @@ def _decode_kernel(
                       # scale refs [1, page, KV] f32); out_ref [1, KV, G,
                       # hd]; scratch: m [KV, G, 1] running max, l [KV, G, 1]
                       # running denom, acc [KV, G, hd] running numerator
+    head_dim=None,    # a head's size where ``hd`` is several packed heads
 ):
     *pages, out_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(0)
@@ -86,16 +87,18 @@ def _decode_kernel(
         k, v, *scales = W.load_blocks(pages)
         ks, vs = scales or (None, None)
         W.gqa_attend(q_ref[0], k, v, ks, vs, token0, kv_len,
-                     m_ref, l_ref, acc_ref)
+                     m_ref, l_ref, acc_ref, head_dim)
 
     @pl.when(w + 1 == starts_ref[b + 1])
     def _finalize():
         out_ref[0] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
-def _decode(q, pools, page_table, kv_lens, interpret):
+def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None):
     """q: [B, KV, G, hd]; pools: k, v pages [NP, page, KV, hd], and for
-    int8 pools their scales [NP, page, KV] f32. Returns q's shape."""
+    int8 pools their scales [NP, page, KV] f32. Returns q's shape.
+    ``head_dim``: a head's size where the pool keeps several side by side
+    and ``q`` is ``page_walk.pack_queries``'."""
     B, KV, G, hd = q.shape
     page = pools[0].shape[1]
     starts = W.live_block_starts(kv_lens, page, True)
@@ -113,7 +116,8 @@ def _decode(q, pools, page_table, kv_lens, interpret):
         ],
     )
     return pl.pallas_call(
-        _decode_kernel,
+        _decode_kernel if head_dim is None else functools.partial(
+            _decode_kernel, head_dim=head_dim),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -122,9 +126,11 @@ def _decode(q, pools, page_table, kv_lens, interpret):
     )(page_table, kv_lens, starts, q, *page_operands)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _decode_call(q, k_pages, v_pages, page_table, kv_lens, interpret=False):
-    return _decode(q, (k_pages, v_pages), page_table, kv_lens, interpret)
+@functools.partial(jax.jit, static_argnames=("interpret", "head_dim"))
+def _decode_call(q, k_pages, v_pages, page_table, kv_lens, interpret=False,
+                 head_dim=None):
+    return _decode(q, (k_pages, v_pages), page_table, kv_lens, interpret,
+                   head_dim)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, q_positions,
@@ -133,17 +139,19 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, q_positions,
     other shapes fall back to the XLA path (the engine sends prefill
     through the ragged kernel, not through here)."""
     B, T, H, hd = q.shape
-    KV = k_pages.shape[2]
     if T != 1:
         from rbg_tpu.ops.paged_attention import paged_attention_xla
         return paged_attention_xla(q, k_pages, v_pages, page_table,
                                    q_positions, kv_lens)
-    G = H // KV
-    qg = q.reshape(B, KV, G, hd)
+    # heads side by side in the pool (1: the pool is [NP, page, KV, hd])
+    p = k_pages.shape[3] // hd
+    KV = k_pages.shape[2] * p
+    qg = W.pack_queries(q.reshape(B, KV, H // KV, hd), p)
     out = _decode_call(qg, k_pages, v_pages,
                        page_table.astype(jnp.int32),
-                       kv_lens.astype(jnp.int32), interpret=interpret)
-    return out.reshape(B, T, H, hd)
+                       kv_lens.astype(jnp.int32), interpret=interpret,
+                       head_dim=hd if p > 1 else None)
+    return W.unpack_outputs(out, p).reshape(B, T, H, hd)
 
 
 # ---- int8 (quantized pool) decode ------------------------------------------

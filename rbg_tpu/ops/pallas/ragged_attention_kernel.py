@@ -151,6 +151,7 @@ def _block_ragged_kernel(
                       # TILE·G, hd]; scratch — online softmax state for the
                       # WHOLE tile: m, l [KV, TILE·G, 1], acc [KV, TILE·G, hd]
     tile: int,
+    head_dim=None,    # a head's size where ``hd`` is several packed heads
 ):
     *pages, out_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(0)
@@ -176,7 +177,7 @@ def _block_ragged_kernel(
         ks, vs = scales or (None, None)
         # The tile's whole query block rides ONE batched dot per block.
         W.gqa_attend(q_ref[0], k, v, ks, vs, token0, limits,
-                     m_ref, l_ref, acc_ref)
+                     m_ref, l_ref, acc_ref, head_dim)
 
     @pl.when(w + 1 == starts_ref[t0 + tile])
     def _finalize():
@@ -202,12 +203,14 @@ def _unfold_tile(out, G):
         NT * Q_TILE, KV * G, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "head_dim"))
 def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
-                       kv_lens, row_ids, q_pos, interpret=False):
+                       kv_lens, row_ids, q_pos, interpret=False,
+                       head_dim=None):
     """q: [Tp/TILE, KV, TILE·G, hd] folded tiles; pages: [NP, page, KV,
     hd]; scales (int8 pools) [NP, page, KV] f32 or None. Returns q's
-    shape."""
+    shape. ``head_dim``: a head's size where the pool keeps several side
+    by side and ``q`` is ``page_walk.pack_queries``'."""
     NT, KV, rows_q, hd = q.shape
     page = k_pages.shape[1]
     lead, starts = _tile_segments(row_ids, q_pos, kv_lens, page)
@@ -227,8 +230,11 @@ def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
             pltpu.VMEM((KV, rows_q, hd), jnp.float32),
         ],
     )
+    kernel = functools.partial(_block_ragged_kernel, tile=Q_TILE)
+    if head_dim is not None:
+        kernel = functools.partial(kernel, head_dim=head_dim)
     return pl.pallas_call(
-        functools.partial(_block_ragged_kernel, tile=Q_TILE),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -256,17 +262,23 @@ def _pad_pack(qg, rows, qpos):
 def _block_ragged(q, k_pages, v_pages, k_scales, v_scales, page_table,
                   q_positions, kv_lens, row_ids, interpret):
     _, T, H, hd = q.shape
-    KV = k_pages.shape[2]
+    # heads side by side in the pool (1: the pool is [NP, page, KV, hd])
+    p = k_pages.shape[3] // hd
+    KV = k_pages.shape[2] * p
     G = H // KV
-    qg, rows, qpos = _pad_pack(q.reshape(T, KV, G, hd),
+    qg, rows, qpos = _pad_pack(W.pack_queries(q.reshape(T, KV, G, hd), p),
                                row_ids.astype(jnp.int32),
                                q_positions.reshape(T).astype(jnp.int32))
     out = _block_ragged_call(_fold_tile(qg), k_pages, v_pages,
                              k_scales, v_scales,
                              page_table.astype(jnp.int32),
                              kv_lens.astype(jnp.int32),
-                             rows, qpos, interpret=interpret)
-    return _unfold_tile(out, G)[:T].reshape(1, T, H, hd)
+                             rows, qpos, interpret=interpret,
+                             head_dim=hd if p > 1 else None)
+    out = _unfold_tile(out, p * G)
+    if p > 1:
+        out = W.unpack_outputs(out.reshape(-1, KV // p, p * G, p * hd), p)
+    return out[:T].reshape(1, T, H, hd)
 
 
 def ragged_paged_attention_pallas(q, k_pages, v_pages, page_table,

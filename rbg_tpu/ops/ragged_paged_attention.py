@@ -45,8 +45,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from rbg_tpu.ops.paged_attention import (dispatch_pallas, paged_attention_xla,
-                                         quantize_kv)
+from rbg_tpu.ops.paged_attention import (_as_stored, dispatch_pallas,
+                                         paged_attention_xla, quantize_kv)
 
 
 def _unpack_offsets(row_ids: jnp.ndarray) -> jnp.ndarray:
@@ -130,9 +130,9 @@ def write_kv_pages_ragged(k_pages, v_pages, k_new, v_new, page_table,
         k_scales = k_scales.at[phys, slot].set(k_s, mode="drop")
         v_scales = v_scales.at[phys, slot].set(v_s, mode="drop")
         return k_pages, v_pages, k_scales, v_scales
-    k_pages = k_pages.at[phys, slot].set(kn.astype(k_pages.dtype),
+    k_pages = k_pages.at[phys, slot].set(_as_stored(kn, k_pages),
                                          mode="drop")
-    v_pages = v_pages.at[phys, slot].set(vn.astype(v_pages.dtype),
+    v_pages = v_pages.at[phys, slot].set(_as_stored(vn, v_pages),
                                          mode="drop")
     return k_pages, v_pages, None, None
 

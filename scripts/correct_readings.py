@@ -1,0 +1,176 @@
+"""The readings a configuration's ``correct`` limits are set between.
+
+Run on the chip, from the root of a checkout, in one process:
+
+    python scripts/correct_readings.py --config benchmark/configs/<c>.json \
+        --weights 1 2 --prompts 11 12 13 [--faults]
+
+For each weight seed it builds the program's engine on the benchmark's
+weights (``benchmark/harness/serve.py``'s own preset mapping and reference
+module), serves the reference check's sample (``benchmark/run.py::
+check_sample``'s: one prompt alone, then ``max_batch`` side by side) for each
+prompt seed, and prints the three numbers ``run.py`` compares (the median and
+the 75th percentile of |served - reference| log-probability over all
+positions, the worst request's own median):
+
+* ``sound``: the program against the reference;
+* ``int8``: the reference computed in int8 against the reference, on the
+  same served tokens (the precision one step below bfloat16);
+* with ``--faults``, for a model with recurrent layers, the program with
+  its recurrent mixer patched: ``not carried`` (every chunk of a prompt
+  starts from a zero state) and ``not zeroed`` (no row starts from zeros,
+  served on slots another sample has used).
+
+A limit lies between the largest ``sound`` and the smallest control
+(``PERF.md`` section 2). Nothing here is a timing.
+"""
+
+import argparse
+import json
+import os
+import statistics
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
+
+import numpy as np  # noqa: E402
+
+
+def sample(cfg, seed):
+    """``run.py::check_sample``'s prompts: (alone, side by side)."""
+    spec, vocab = cfg["correct"], cfg["vocab_size"]
+    rng = np.random.default_rng([seed, 99])
+    a = rng.integers(1, vocab, spec["first_len"]).tolist()
+    rest = [a[:len(a) // 2] + rng.integers(1, vocab,
+                                           spec["tail_len"]).tolist(), a]
+    rest += [rng.integers(1, vocab, n).tolist() for n in spec["other_lens"]]
+    return [a], rest
+
+
+def serve_all(eng, prompts, new):
+    from rbg_tpu.engine import SamplingParams
+    ids = [eng.add_request(p, SamplingParams(max_new_tokens=new,
+                                             logprobs=True)) for p in prompts]
+    out = {i: ([], []) for i in ids}
+    while eng.has_work():
+        for ev in eng.step():
+            out[ev.request_id][0].append(ev.token)
+            out[ev.request_id][1].append(ev.logprob)
+    return [out[i] for i in ids]
+
+
+def numbers(diffs):
+    """(median, 75th percentile, worst request's median) of per-request
+    lists of absolute differences."""
+    pooled = sorted(d for ds in diffs for d in ds)
+    return (statistics.median(pooled),
+            float(np.percentile(pooled, 75)),
+            max(statistics.median(ds) for ds in diffs))
+
+
+def patched(fault):
+    """The model code's recurrent mixers with ``fault`` in them."""
+    import jax.numpy as jnp
+    from rbg_tpu.models import llama
+
+    def breaker(real):
+        def broken(g, blk, x, state, layer, addr, use_pallas):
+            pos = addr.positions
+            if fault == "not carried":
+                if x.shape[1] > 1:      # every chunk of a prompt looks first
+                    pos = pos - pos[..., :1] if addr.row_ids is None else \
+                        jnp.where(addr.token_mask, 0, pos)
+            else:
+                pos = jnp.where(pos == 0, 1 << 20, pos)   # never looks first
+            return real(g, blk, x, state, layer,
+                        addr._replace(positions=pos), use_pallas)
+        return broken
+
+    return {name: breaker(getattr(llama, name))
+            for name in ("_kda_attention", "_conv_attention")}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--weights", type=int, nargs="+", default=[3000000019])
+    ap.add_argument("--prompts", type=int, nargs="+", default=[1, 2, 3])
+    ap.add_argument("--faults", action="store_true")
+    args = ap.parse_args(argv)
+
+    import jax
+    from harness import serve
+    from rbg_tpu.engine import Engine, EngineConfig
+    from rbg_tpu.models import config as presets
+    from rbg_tpu.models import llama
+    from rbg_tpu.utils.chipenv import configure_compile_cache
+    configure_compile_cache()
+    with open(os.path.join(ROOT, args.config)) as f:
+        cfg = json.load(f)
+    name = "correct-readings"
+    presets._PRESETS[name] = serve.model_config(cfg, name)
+    reference = serve.load_reference(cfg)
+    new = cfg["correct"]["new_tokens"]
+    print(f"device: {jax.devices()[0].platform} "
+          f"{jax.devices()[0].device_kind}", flush=True)
+
+    def engine(params):
+        return Engine(EngineConfig(model=name, **cfg["server"]),
+                      params=params)
+
+    def against_reference(params, prompts, served, quant=None):
+        diffs = []
+        for prompt, (toks, lps) in zip(prompts, served):
+            ref = np.asarray(reference.chosen_logprobs(cfg, params, prompt,
+                                                       toks))
+            got = lps if quant is None else np.asarray(
+                reference.chosen_logprobs(cfg, params, prompt, toks, quant))
+            diffs.append(np.abs(np.asarray(got) - ref).tolist())
+        return diffs
+
+    def say(kind, w, p, diffs):
+        med, p75, worst = numbers(diffs)
+        print(json.dumps({"kind": kind, "weights": w, "prompts": p,
+                          "median": round(med, 5), "p75": round(p75, 5),
+                          "request": round(worst, 5)}), flush=True)
+
+    for w in args.weights:
+        params = reference.make_params(cfg, w)
+        eng = engine(params)
+        for p in args.prompts:
+            alone, rest = sample(cfg, p)
+            served = serve_all(eng, alone, new) + serve_all(eng, rest, new)
+            say("sound", w, p, against_reference(params, alone + rest,
+                                                 served))
+            say("int8", w, p, against_reference(params, alone + rest, served,
+                                                "int8"))
+        del eng
+        if not (args.faults and presets._PRESETS[name].recurrent):
+            continue
+        for fault in ("not carried", "not zeroed"):
+            real = {n: getattr(llama, n) for n in patched(fault)}
+            for n, fn in patched(fault).items():
+                setattr(llama, n, fn)
+            jax.clear_caches()      # the mixers' own programs hold the real
+            try:
+                eng = engine(params)
+                # another sample first, so that every slot has been used
+                other = sample(cfg, args.prompts[0] + 1000)
+                serve_all(eng, other[0], new)
+                serve_all(eng, other[1], new)
+                alone, rest = sample(cfg, args.prompts[0])
+                served = serve_all(eng, alone, new) + serve_all(eng, rest,
+                                                                new)
+                say(fault, w, args.prompts[0],
+                    against_reference(params, alone + rest, served))
+            finally:
+                for n, fn in real.items():
+                    setattr(llama, n, fn)
+                jax.clear_caches()
+                del eng
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

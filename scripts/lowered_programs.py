@@ -62,6 +62,9 @@ def programs(eng, S, T, lora=False, window=None):
     vec, pool = S((B,), I32), eng.cache
     scales = (pool.k_scales, pool.v_scales)
     kw = dict(lora=eng.lora_stack, lids=vec) if lora else {}
+    # A model with recurrent layers: the state pool and its rows' slots ride
+    # every step program; it has no draft to verify.
+    kw.update(eng._state_kw([], B))
     temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
     tail = (row_keys(seeds, eng._sample_base, rids), jnp.asarray(temps),
             jnp.asarray(ks), jnp.asarray(tps), jnp.asarray(mps))
@@ -76,14 +79,16 @@ def programs(eng, S, T, lora=False, window=None):
             eng.params, S((B, Tq), I32), S((B, Tq), I32), S((B, Tq), bool),
             vec, S((B, P), I32), pool.k_pages, pool.v_pages, *scales, **kw)
     Kq = 5
-    out["rbg_spec_verify"] = eng._get_spec_fn(B, False, la=lora).lower(
-        eng.params, S((B, Kq), I32), S((B, Kq), I32), S((B, Kq), bool), vec,
-        S((B, P), I32), pool.k_pages, pool.v_pages, *scales, *tail, **kw)
+    if eng.state is None:
+        out["rbg_spec_verify"] = eng._get_spec_fn(B, False, la=lora).lower(
+            eng.params, S((B, Kq), I32), S((B, Kq), I32), S((B, Kq), bool),
+            vec, S((B, P), I32), pool.k_pages, pool.v_pages, *scales, *tail,
+            **kw)
     if not lora:
         out["rbg_ragged_fwd"] = eng._get_ragged_fn(B, T).lower(
             eng.params, S((1, T), I32), S((1, T), I32), S((1, T), bool),
             S((T,), I32), vec, S((B, P), I32), pool.k_pages, pool.v_pages,
-            *scales)
+            *scales, **kw)
     if window is not None:
         D, L = eng.mcfg.hidden_size, eng.mcfg.num_layers
         for lo, hi in ((0, 1), (1, L)):
@@ -124,7 +129,12 @@ def tiny_cases():
                                ("tiny-int8", "tiny", {"kv_dtype": "int8"}),
                                ("tiny-moe-int8", "tiny-moe",
                                 {"kv_dtype": "int8"}),
-                               ("tiny-lora", "tiny", {})):
+                               ("tiny-lora", "tiny", {}),
+                               ("tiny-joyai", "tiny-joyai", {}),
+                               ("tiny-kimi-linear", "tiny-kimi-linear", {}),
+                               ("tiny-lfm2", "tiny-lfm2", {})):
+        if model not in presets._PRESETS:   # an older checkout
+            continue
         cfg = EngineConfig(model=model, use_pallas="never",
                            **{**TINY_KW, **extra})
         eng = Engine(cfg)
@@ -132,7 +142,8 @@ def tiny_cases():
         if lora:
             _lora_stack(eng)
         window = None
-        if not extra and not lora:      # the decode role refuses an int8 pool
+        # the decode role refuses an int8 pool and a recurrent model
+        if not extra and not lora and eng.state is None:
             window = DecodeWorker(cfg, params=eng.params)
         yield case, programs(eng, S, 2 * cfg.prefill_chunk, lora, window)
 

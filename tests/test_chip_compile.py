@@ -103,6 +103,24 @@ def test_gqa_kernel_compiles_for_v5e(chip, kernel, width):
     _compiles_with_kernel(getattr(K, kernel), *args)
 
 
+@pytest.mark.parametrize("width", ["llama3-1b", "qwen2-0.5b"])
+@pytest.mark.parametrize("kernel", ["paged_attention_pallas",
+                                    "ragged_paged_attention_pallas"])
+def test_gqa_kernel_compiles_on_a_pool_of_heads_side_by_side(chip, kernel,
+                                                             width):
+    """Heads of 64 as the engine's pool holds them since PR 39, two to a
+    lane tile (``kvcache.heads_per_lane_tile``: ``[NP, page, KV / 2, 128]``,
+    the benchmark's lfm2 cell's 8 heads as 4, qwen2's 2 as 1): the
+    kernels take each head's queries zero in the other head's lanes."""
+    H, KV, hd = GQA_WIDTHS[width]
+    args = _gqa_args(chip, width, quantized=False,
+                     ragged=kernel.startswith("ragged"))
+    pages = jax.ShapeDtypeStruct((NP, PAGE, KV // 2, 2 * hd), BF16,
+                                 sharding=chip)
+    args[1] = args[2] = pages
+    _compiles_with_kernel(getattr(K, kernel), *args)
+
+
 @pytest.mark.parametrize("kernel", GQA_KERNELS)
 def test_gqa_kernel_compiles_at_the_cells_table_width(chip, kernel):
     """The table's width reaches a kernel only as the shape of a
@@ -340,6 +358,53 @@ def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
                        text)
     assert (kernel, bool(passes), bool(moved)) == (
         (True, False, False) if program == "decode" else (False, True, True))
+
+
+# ---- the benchmark's lfm2.longgen32 cell: its two step programs --------------
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_step_programs_of_the_lfm2_cell_fit_and_copy_neither_pool(
+        chip, monkeypatch, program):
+    """``benchmark/configs/lfm2-24b-a2b.json`` as served: all 40 layers (two
+    dense layers with the gated short convolution, then 10 attention and
+    28 convolution layers with 8 of 64 experts each), 32 rows, pages for
+    the 10 attention layers alone, 8 heads of 64 held two to a lane tile,
+    and the convolutions' tails a slot a row beside them. No ``copy`` of a
+    pool's shape: with the heads as ``[.., 8, 64]`` every step program
+    copied both page pools whole on the way in and out, padded to 128
+    lanes (2.68 GB of pools; ROADMAP S2 (b)); and no temporary the size
+    of a layer's held experts (151 MB)."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from harness import serve
+    from rbg_tpu.models import config as presets
+    with open(os.path.join(bench, "configs", "lfm2-24b-a2b.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setitem(presets._PRESETS, "lfm2-cell",
+                        serve.model_config(cfg, "lfm2-cell"))
+    eng = _abstract_engine(chip, monkeypatch, model="lfm2-cell",
+                           **cfg["server"])
+    assert eng.params["moe_mlps"]["moe_gate"].shape == (38, 8, 2048, 1536)
+    assert eng.params["moe_mlps"]["router"].shape == (38, 2048, 64)
+    assert eng.params["conv_mixers"]["conv_in"].shape == (30, 2048, 6144)
+    assert "lm_head" not in eng.params
+    assert eng.cache.k_pages.shape == (10, 8192, 16, 4, 128)
+    assert {k: v.shape for k, v in eng.state.arrays.items()} == {
+        "tail": (30, 32, 4096)}
+    compiled = (_compile_decode if program == "decode"
+                else _compile_unified)(chip, eng)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pools = {eng.cache.k_pages.size, eng.state.arrays["tail"].size}
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert not [dims for dims in copied if np.prod(
+        [int(d) for d in dims.split(",")]) in pools]
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
 
 
 @pytest.mark.parametrize("heads_per_block", [8, 16])

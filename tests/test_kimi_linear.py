@@ -720,7 +720,7 @@ def test_state_counters_count_slots_rows_and_bytes(bench):
 
 @pytest.mark.parametrize("kw,match", [
     (dict(speculative="ngram"), "speculative decoding"),
-    (dict(kv_dtype="int8"), "state is float32 only"),
+    (dict(kv_dtype="int8"), "the state pool has no quantised form"),
     (dict(mode="prefill"), "PD bundle carries pages"),
     (dict(mode="decode"), "PD bundle carries pages"),
     (dict(host_tier_bytes=1 << 20), "host tier keeps prefixes"),

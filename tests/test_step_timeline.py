@@ -240,10 +240,15 @@ def once(fn, inner):
     return slow_once
 
 
-def spin():
+def spin(took=None):
+    """Burn ``PAUSE_S`` of this thread's CPU time; ``took`` is given the
+    wall seconds that needed."""
+    t0 = time.monotonic()
     end = time.thread_time() + PAUSE_S
     while time.thread_time() < end:
         pass
+    if took is not None:
+        took.append(time.monotonic() - t0)
 
 
 def late_turn(params, where, pause):
@@ -322,7 +327,17 @@ def test_a_turn_that_slept_is_late_off_the_cpu_and_its_stack_says_where(
 
 
 def test_a_turn_that_spun_is_late_on_the_cpu(params):
-    before, after, new = late_turn(params, "service.intake", spin)
+    # Six busy test workers share this machine's cores. A spin that the
+    # machine kept off the CPU for a quarter of its own length, by the
+    # spin's own two clocks, or a run in which it made another turn late
+    # too, measures the machine and not the clocks under test: such an
+    # attempt is made again, and the last one is held to the same numbers.
+    for attempt in range(5):
+        took = []
+        before, after, new = late_turn(params, "service.intake",
+                                       lambda: spin(took))
+        if len(new) == 1 and took[0] - PAUSE_S < PAUSE_S / 4:
+            break
     assert after["late_steps"] - before["late_steps"] == 1 == len(new)
     rec = new[0]
     assert rec["phase"] == "service.intake"

@@ -10,12 +10,30 @@ nothing still costs its index maps and the pipeline's bookkeeping, and at
 Here the walk is a flat list of LIVE work items. A kernel's work falls
 into *segments* (a decode row; in the ragged kernels a row's first token
 in a query tile), each of which attends its row's pages in ascending
-order, a *block* of ``pages_per_block`` consecutive pages an item.
+order, a *block* of consecutive pages an item (``pages_per_block``;
+the decode kernels' blocks are wider, ``decode_pages_per_block``).
 ``live_block_starts`` turns the segments' live token counts into
 cumulative block counts in XLA; the grid is one axis whose DYNAMIC length
-is their total, and ``find_item`` maps grid step ``w`` back to (segment,
-block of the segment) by bisecting the scalar-prefetched starts. Nothing
-a kernel does depends on the table's width any more.
+is their total. Nothing a kernel does depends on the table's width any
+more.
+
+Which (segment, block, physical pages) grid step ``w`` is, two ways:
+
+* The ragged kernels and ``_kda_decode_call`` BISECT: ``find_item`` maps
+  ``w`` back to (segment, block of the segment) over the scalar-prefetched
+  starts, and ``page_of_block`` walks the segment's line of the page
+  table, in every index map of every operand of every step (a pipeline
+  evaluates the maps of the step ahead as well). Their segments are a
+  step's packed tokens, up to 512 x 64 items, and they are a few per
+  cent of a cell's device time.
+* The decode kernels (``paged_attention_kernel.py``) READ A TABLE:
+  ``walk_items`` resolves every item once a call, in XLA, from the same
+  starts, lengths and page table, and an index map is one read of it.
+  A block of four pages and two pools has ten index maps; bisecting,
+  each cost about fifty scalar operations, and the walk was bound by
+  them, not by bytes: a block of 64 cached tokens took 1.31-1.50 us on a
+  v5e where its 128 KB are 0.16 us of HBM time, 0.81-0.86 us with the
+  table (PERF.md, PR 40).
 
 A block's pages are scattered over the pool, so each pool is handed to
 the kernel once per page of a block (``block_specs``): the Pallas
@@ -42,29 +60,54 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 _I32 = np.int32
 
-# Slots of one block. A per-step cost (0.5 us on a v5e) is paid once a
-# block, so wider is faster per page: 0.78, 0.51, 0.39, 0.33, 0.30 us at
-# 1, 2, 4, 8, 16 pages of 16 slots (PERF.md, PR 25). But every page of a
-# block is one more operand per pool, whose index map is traced and
-# lowered in each step program an engine warms: at 128 slots warm-up was
-# 5 s longer than at 64, for 0.4 % of a decode step.
+# Slots of one block of the ragged kernels. A per-step cost (0.5 us on a
+# v5e) is paid once a block, so wider is faster per page: 0.78, 0.51,
+# 0.39, 0.33, 0.30 us at 1, 2, 4, 8, 16 pages of 16 slots (PERF.md, PR
+# 25). But every page of a block is one more operand per pool, whose
+# bisecting index map (``find_item``) is traced and lowered in each step
+# program an engine warms: at 128 slots warm-up was 5 s longer than at 64,
+# for 0.4 % of a decode step.
 _BLOCK_SLOTS = 64
+
+# Slots of one block of the decode kernels, whose index maps are one read
+# of ``walk_items``' table each and cost next to nothing to trace: the
+# Python calls inside ``.lower()`` of a cell's decode program are 1.284 M
+# on PR 39's tree, 1.221 M with the table at 64 slots, 1.251 M at 128,
+# 1.310 M at 256 (lfm2; mixtral 0.902, 0.877, 0.906 M). A block then
+# costs 0.30-0.42 us a step and 0.10-0.14 us a page of 16 slots where
+# the bisecting maps cost 0.5 and 0.27 (a v5e, PERF.md, PR 40; us a call
+# of ``_decode_call`` at 32 rows of 128-2048 tokens and heads of 64,
+# ``_mla_decode_call`` at 16 rows, ``_decode_call`` at 8 rows and heads of
+# 128): 912 / 447 / 159 bisecting, then 490 / 261 / 104 at 64 slots,
+# 410 / 198 / 89 at 128, 401 / 164 / 83 at 256. 128 takes 15-24 % off
+# every one; 256 another 2 % and 6 % off the GQA calls for twice the
+# operands again. The price is a row under 65 tokens, whose one block now
+# fetches its last live page eight times over where it was four.
+_DECODE_BLOCK_SLOTS = 128
 
 
 def pages_per_block(page: int) -> int:
-    """Pages one work item attends (4 pages of 16 slots)."""
+    """Pages one work item of a ragged kernel attends (4 pages of 16
+    slots)."""
     return max(1, _BLOCK_SLOTS // page)
 
 
-def live_block_starts(live_tokens, page: int, at_least_one):
-    """Cumulative live blocks of the segments, ``[S + 1]`` int32 (XLA).
+def decode_pages_per_block(page: int) -> int:
+    """Pages one work item of a decode kernel attends (8 pages of 16
+    slots)."""
+    return max(1, _DECODE_BLOCK_SLOTS // page)
+
+
+def live_block_starts(live_tokens, page: int, at_least_one, n: int = None):
+    """Cumulative live blocks of the segments, ``[S + 1]`` int32 (XLA),
+    a block ``n`` pages (``pages_per_block`` unless given).
 
     ``live_tokens [S]`` is how many slots of its row each segment
     attends (<= 0: none). A segment where ``at_least_one`` holds gets one
     item even then, which attends nothing: the kernels initialise and
     write an output block in the items of its first and last segment, so
     every output block needs one. ``starts[-1]`` is the grid's length."""
-    block = pages_per_block(page) * page
+    block = (n or pages_per_block(page)) * page
     blocks = jnp.maximum(-(-live_tokens // block), 0)
     blocks = jnp.where(at_least_one, jnp.maximum(blocks, 1), blocks)
     return jnp.concatenate([jnp.zeros((1,), jnp.int32),
@@ -105,13 +148,61 @@ def page_of_block(table_ref, row, block, j: int, live_tokens, page: int):
     return table_ref[row, p]
 
 
-def block_specs(pools, page_id):
+def walk_items(starts, live_tokens, table, page: int, n: int):
+    """The walk's items, resolved once a call (XLA): for every work item
+    ``w`` the decode kernels may be asked for, its row, its block of the
+    row and the physical page of each page of that block, so that a
+    kernel's index maps are one read each.
+
+    ``starts [S + 1]`` are ``live_block_starts`` of ``live_tokens [S]``
+    at ``n`` pages a block, ``table [S, P]`` the rows' lines of the page
+    table. Returns ``item_row, item_block [W + 1]`` and ``item_page
+    [n, W + 1]`` int32 (page ``j`` of item ``w`` at ``[j, w]``; held this
+    way round because a last dim of ``n`` would pad to a lane tile in
+    SMEM), with ``W = S * ceil(P / n)``, the most items the table can
+    hold: the table re-indexed, item by item where it was row by row.
+    The rules are ``find_item``'s and ``page_of_block``'s: an item at or
+    past the grid's end (``starts[S]``), which a pipeline may ask for
+    ahead of time (hence the ``+ 1``), resolves to the last row; a page
+    past a row's last live page is that last page again, so no entry
+    beyond the live pages is ever in ``item_page``, whatever it names.
+
+    A dozen small fusions, 4-8 us a call on a v5e: an item's row by
+    comparing ``w`` with every start, not by a search, and its pages as
+    ONE slice of ``n`` entries of the row's line (a gather of single
+    entries took 55 us at 32 rows under a table 256 wide)."""
+    S, P = table.shape
+    blocks = -(-P // n)                            # of a whole line
+    w = jnp.arange(S * blocks + 1, dtype=jnp.int32)[:, None]
+    # The last row's end is open: the items past the grid's end are its.
+    ends = jnp.concatenate(
+        [starts[1:S], jnp.full((1,), np.iinfo(np.int32).max, jnp.int32)])
+    own = (starts[None, :S] <= w) & (w < ends[None])          # [W + 1, S]
+    last = jnp.clip(-(-live_tokens // page) - 1, 0, P - 1)    # its column
+    last_page = jnp.sum(jnp.where(
+        jnp.arange(P, dtype=jnp.int32)[None] == last[:, None], table, 0),
+        axis=1, dtype=jnp.int32)
+    row, first, last, last_page = (
+        jnp.sum(jnp.where(own, of_row[None], 0), axis=1, dtype=jnp.int32)
+        for of_row in (jnp.arange(S, dtype=jnp.int32), starts[:S], last,
+                       last_page))
+    block = w[:, 0] - first
+    lines = jnp.pad(table, ((0, 0), (0, blocks * n - P))).reshape(-1, n)
+    pages = lines.at[jnp.minimum(row * blocks + block, S * blocks - 1)].get(
+        mode="promise_in_bounds")                             # [W + 1, n]
+    column = block[:, None] * n + jnp.arange(n, dtype=jnp.int32)[None]
+    pages = jnp.where(column <= last[:, None], pages, last_page[:, None])
+    return row, block, pages.T
+
+
+def block_specs(pools, page_id, n: int = None):
     """Block specs and operands that hand each of ``pools`` (arrays
-    ``[NP, page, ...]``) to a kernel once per page of a block:
+    ``[NP, page, ...]``) to a kernel once per page of a block of ``n``
+    pages (``pages_per_block`` unless given):
     ``page_id(j, *grid_and_prefetch)`` is the physical page of the item's
-    ``j``-th page. The kernel receives, pool by pool, ``pages_per_block``
-    refs ``[1, page, ...]``."""
-    n = pages_per_block(pools[0].shape[1])
+    ``j``-th page. The kernel receives, pool by pool, ``n`` refs
+    ``[1, page, ...]``."""
+    n = n or pages_per_block(pools[0].shape[1])
     specs, operands = [], []
     for pool in pools:
         tail = (0,) * (pool.ndim - 1)
@@ -165,18 +256,18 @@ def unpack_outputs(out, p: int):
     return own.reshape(*lead, KVp * p, G, hd)
 
 
-def load_latent_blocks(page_refs):
+def load_latent_blocks(page_refs, n: int = None):
     """``load_blocks`` of the latent pools ``c, pe [n·page, d]`` and, for
     int8 pools, their per-slot scales ``[n·page]`` (else None, None)."""
-    c, pe, *scales = load_blocks(page_refs)
+    c, pe, *scales = load_blocks(page_refs, n)
     cs, ps = (s[:, 0] for s in scales) if scales else (None, None)
     return c, pe, cs, ps
 
 
-def load_blocks(page_refs):
+def load_blocks(page_refs, n: int = None):
     """The item's block of each pool, ``[n·page, ...]``, from the page refs
-    a kernel received (``block_specs``' order: pool by pool)."""
-    n = pages_per_block(page_refs[0].shape[1])
+    a kernel received (``block_specs``' order: pool by pool, ``n`` each)."""
+    n = n or pages_per_block(page_refs[0].shape[1])
     return [jnp.concatenate([r[0] for r in page_refs[i:i + n]], axis=0)
             for i in range(0, len(page_refs), n)]
 

@@ -13,12 +13,19 @@ Design (see /opt/skills/guides/pallas_guide.md and ``page_walk.py``):
   across a row's walk). The grid used to be (B, P), every page of
   ``max_seq_len`` for every row; a row of 900 tokens under a table 512
   wide spent eight steps in nine stepping over nothing.
-* page_table, kv_lens and the rows' cumulative block counts are
-  scalar-prefetch args: the k/v BlockSpec index_maps find the step's row
-  and block by bisection and dereference the page table, so the pipeline
-  DMAs the RIGHT physical pages ahead of compute (double-buffered by the
-  Pallas pipeline itself). Entries past a row's live pages are never
-  followed.
+* the walk's items are resolved ONCE A CALL, in XLA
+  (``page_walk.walk_items``): the row, the block of the row and the
+  physical pages of every work item, scalar-prefetched beside kv_lens
+  and the rows' cumulative block counts. An index map is one read of
+  that table, so the pipeline DMAs the RIGHT physical pages ahead of
+  compute (double-buffered by the Pallas pipeline itself) at the cost of
+  a load. Until PR 40 every map of every operand bisected the counts and
+  walked the page table itself, and the scalar core's work bound the
+  walk (1.3-1.5 us a block of 64 cached tokens, 0.8 with the table).
+  Entries past a row's live pages are never followed.
+* a block is ``page_walk.decode_pages_per_block`` pages (128 slots), twice
+  the ragged kernels': with maps that cost nothing to trace the width is
+  the kernel's to choose.
 * GQA via one batched dot per block: [KV, G, hd] × [KV, n·page, hd].
 * A row of length 0 (a free slot of the batch) keeps one step that
   attends nothing and writes zeros.
@@ -41,22 +48,32 @@ from jax.experimental.pallas import tpu as pltpu
 from rbg_tpu.ops.pallas import page_walk as W
 
 
-def _page_id(j, w, table, lens, starts, *, page):
-    """Physical page of page ``j`` of decode work item ``w`` (every row
-    is a segment)."""
-    b, block = W.find_item(starts, w, lens.shape[0])
-    return W.page_of_block(table, b, block, j, lens[b], page)
+def _page_id(j, w, item_row, item_block, item_page, lens, starts):
+    """Physical page of page ``j`` of decode work item ``w``."""
+    return item_page[j, w]
 
 
 def _row_of(rank):
-    def index_map(w, table, lens, starts):
-        return (W.find_item(starts, w, lens.shape[0])[0],) + (0,) * (rank - 1)
+    def index_map(w, item_row, item_block, item_page, lens, starts):
+        return (item_row[w],) + (0,) * (rank - 1)
     return index_map
 
 
+def _walk(page_table, kv_lens, page, n):
+    """The scalar-prefetch operands of a decode walk (every row is a
+    segment) in blocks of ``n`` pages: ``page_walk.walk_items``' three
+    arrays, the rows' lengths and their cumulative live blocks, whose
+    last is the grid's length."""
+    starts = W.live_block_starts(kv_lens, page, True, n)
+    return (*W.walk_items(starts, kv_lens, page_table, page, n), kv_lens,
+            starts)
+
+
 def _decode_kernel(
-    # scalar prefetch
-    page_table_ref,   # [B, P] int32 (SMEM)
+    # scalar prefetch (``_walk``)
+    item_row_ref,     # [W + 1] int32 (SMEM) — the item's row
+    item_block_ref,   # [W + 1] int32 (SMEM) — its block of the row
+    item_page_ref,    # [n, W + 1] int32 (SMEM) — read by the index maps
     kv_lens_ref,      # [B] int32 (SMEM)
     starts_ref,       # [B + 1] int32 (SMEM) — cumulative live blocks
     # blocks
@@ -70,10 +87,11 @@ def _decode_kernel(
 ):
     *pages, out_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(0)
-    b, block = W.find_item(starts_ref, w, kv_lens_ref.shape[0])
+    b, block = item_row_ref[w], item_block_ref[w]
     kv_len = kv_lens_ref[b]
     page = pages[0].shape[1]
-    token0 = block * (W.pages_per_block(page) * page)   # the block's first slot
+    n = W.decode_pages_per_block(page)
+    token0 = block * (n * page)                     # the block's first slot
 
     @pl.when(block == 0)
     def _init():
@@ -84,7 +102,7 @@ def _decode_kernel(
     def _attend():
         # int8 pools: per-(slot, head) absmax scales, folded
         # ALGEBRAICALLY, so the int8 pages feed the MXU directly.
-        k, v, *scales = W.load_blocks(pages)
+        k, v, *scales = W.load_blocks(pages, n)
         ks, vs = scales or (None, None)
         W.gqa_attend(q_ref[0], k, v, ks, vs, token0, kv_len,
                      m_ref, l_ref, acc_ref, head_dim)
@@ -101,12 +119,12 @@ def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None):
     and ``q`` is ``page_walk.pack_queries``'."""
     B, KV, G, hd = q.shape
     page = pools[0].shape[1]
-    starts = W.live_block_starts(kv_lens, page, True)
-    page_specs, page_operands = W.block_specs(
-        pools, functools.partial(_page_id, page=page))
+    n = W.decode_pages_per_block(page)
+    walk = _walk(page_table, kv_lens, page, n)
+    page_specs, page_operands = W.block_specs(pools, _page_id, n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(starts[B],),
+        num_scalar_prefetch=len(walk),
+        grid=(walk[-1][B],),
         in_specs=[pl.BlockSpec((1, KV, G, hd), _row_of(4))] + page_specs,
         out_specs=pl.BlockSpec((1, KV, G, hd), _row_of(4)),
         scratch_shapes=[
@@ -123,7 +141,7 @@ def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, kv_lens, starts, q, *page_operands)
+    )(*walk, q, *page_operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "head_dim"))
@@ -214,8 +232,10 @@ def paged_attention_pallas_q(q, k_pages, v_pages, page_table, q_positions,
 
 
 def _mla_decode_kernel(
-    # scalar prefetch
-    page_table_ref,   # [B, P] int32 (SMEM)
+    # scalar prefetch (``_walk``)
+    item_row_ref,     # [W + 1] int32 (SMEM) — the item's row
+    item_block_ref,   # [W + 1] int32 (SMEM) — its block of the row
+    item_page_ref,    # [n, W + 1] int32 (SMEM) — read by the index maps
     kv_lens_ref,      # [B] int32 (SMEM)
     starts_ref,       # [B + 1] int32 (SMEM) — cumulative live blocks
     # blocks
@@ -231,10 +251,11 @@ def _mla_decode_kernel(
 ):
     *pages, out_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(0)
-    b, block = W.find_item(starts_ref, w, kv_lens_ref.shape[0])
+    b, block = item_row_ref[w], item_block_ref[w]
     kv_len = kv_lens_ref[b]
     page = pages[0].shape[1]
-    token0 = block * (W.pages_per_block(page) * page)   # the block's first slot
+    n = W.decode_pages_per_block(page)
+    token0 = block * (n * page)                     # the block's first slot
 
     @pl.when(block == 0)
     def _init():
@@ -242,7 +263,7 @@ def _mla_decode_kernel(
 
     @pl.when(token0 < kv_len)
     def _attend():
-        c, pe, cs, ps = W.load_latent_blocks(pages)
+        c, pe, cs, ps = W.load_latent_blocks(pages, n)
         W.mla_attend(ql_ref[0], qp_ref[0], c, pe, cs, ps, token0, kv_len,
                      scale, m_ref, l_ref, acc_ref)
 
@@ -260,12 +281,12 @@ def _mla_decode(q_lat, q_pe, pools, page_table, kv_lens, scale, interpret):
     B, H, dc = q_lat.shape
     dr = q_pe.shape[-1]
     page = pools[0].shape[1]
-    starts = W.live_block_starts(kv_lens, page, True)
-    page_specs, page_operands = W.block_specs(
-        pools, functools.partial(_page_id, page=page))
+    n = W.decode_pages_per_block(page)
+    walk = _walk(page_table, kv_lens, page, n)
+    page_specs, page_operands = W.block_specs(pools, _page_id, n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(starts[B],),
+        num_scalar_prefetch=len(walk),
+        grid=(walk[-1][B],),
         in_specs=[pl.BlockSpec((1, H, dc), _row_of(3)),
                   pl.BlockSpec((1, H, dr), _row_of(3))] + page_specs,
         out_specs=pl.BlockSpec((1, H, dc), _row_of(3)),
@@ -282,7 +303,7 @@ def _mla_decode(q_lat, q_pe, pools, page_table, kv_lens, scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, kv_lens, starts, q_lat, q_pe, *page_operands)
+    )(*walk, q_lat, q_pe, *page_operands)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))

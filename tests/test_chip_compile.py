@@ -157,6 +157,56 @@ def test_mla_kernel_compiles_for_v5e(chip, kernel):
     _compiles_with_kernel(fn, *args)
 
 
+# ---- the four decode kernels at the shapes of every cell of the benchmark ----
+
+# cell: (rows, the table's width, the pool's pages over all its layers, and
+# for GQA (heads, kv heads, head size), for latents the heads).
+CELL_DECODE = {
+    "mixtral.longgen": (8, 512, 3 * 8192, (32, 8, 128)),
+    "lfm2.longgen32": (32, 256, 10 * 8192, (32, 8, 64)),
+    "joyai.longgen16": (16, 256, 5 * 8192, 32),
+    "kimi-linear.longgen16": (16, 256, 7 * 4096, 32),
+}
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELL_DECODE))
+def test_decode_kernel_compiles_at_the_cells_shapes(chip, cell, quantized):
+    """A decode kernel's scalar operands are the walk's item table
+    (``page_walk.walk_items``): ``rows * ceil(width / pages a block) + 1``
+    items, 1025 at 32 rows under a table 256 wide, which Mosaic has to
+    hold in SMEM beside the rows' lengths; and the pools are the cells'
+    own, all layers flat (LFM2's bf16 heads of 64 two to a lane tile,
+    ``[81920, 16, 4, 128]``; the latent pools without their singleton
+    axis, the rotary key's a lane tile wide)."""
+    rows, width, pages, heads = CELL_DECODE[cell]
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    dt = I8 if quantized else BF16
+    tail = [S((rows, width), I32), S((rows, 1), I32), S((rows,), I32)]
+    if isinstance(heads, int):
+        kern = (K.paged_mla_attention_pallas_q if quantized
+                else K.paged_mla_attention_pallas)
+        scales = [S((pages, PAGE, 1, 1), F32)] * 2 if quantized else []
+        fn = lambda ql, qp, c, pe, tab, pos, lens, *sc: kern(
+            ql, qp, c, pe, tab, pos, lens, MLA_SCALE, *sc)
+        args = [S((rows, 1, heads, MLA_DC), BF16),
+                S((rows, 1, heads, MLA_DR), BF16),
+                S((pages, PAGE, 1, MLA_DC), dt), S((pages, PAGE, 1, 128), dt),
+                *tail, *scales]
+    else:
+        H, KV, hd = heads
+        # int8 pools keep a head a tile (no cell serves one)
+        p = 1 if quantized else 128 // hd
+        pool = S((pages, PAGE, KV // p, p * hd), dt)
+        fn = (K.paged_attention_pallas_q if quantized
+              else K.paged_attention_pallas)
+        scales = [S((pages, PAGE, KV, 1), F32)] * 2 if quantized else []
+        args = [S((rows, 1, H, hd), BF16), pool, pool, *tail, *scales]
+    text = _compiles_with_kernel(fn, *args).as_text()
+    n = K.W.decode_pages_per_block(PAGE)
+    assert f"s32[{n},{rows * (width // n) + 1}]" in text
+
+
 # ---- the engine's own step programs, whole, at chip_smoke's serving size ----
 
 
@@ -277,6 +327,8 @@ def test_step_programs_of_the_joyai_cell_fit_and_copy_no_expert_stack(
                 else _compile_unified)(chip, eng)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert ("_mla_decode_call" if program == "decode"
+            else "_block_ragged_mla_call") in text
     one_matrix = 256 * 2048 * 768 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix // 2
     assert eng.cache.v_pages.shape == (5, 8192, 16, 1, 128)
@@ -329,6 +381,8 @@ def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
                 else _compile_unified)(chip, eng)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert ("_mla_decode_call" if program == "decode"
+            else "_block_ragged_mla_call") in text
     pools = {eng.cache.k_pages.size, eng.cache.v_pages.size,
              eng.state.arrays["s"].size, eng.state.arrays["conv"].size}
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
@@ -400,6 +454,8 @@ def test_step_programs_of_the_lfm2_cell_fit_and_copy_neither_pool(
                 else _compile_unified)(chip, eng)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert ("_decode_call" if program == "decode"
+            else "_block_ragged_call") in text
     pools = {eng.cache.k_pages.size, eng.state.arrays["tail"].size}
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
     assert not [dims for dims in copied if np.prod(

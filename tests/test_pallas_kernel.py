@@ -260,9 +260,9 @@ from rbg_tpu.ops.pallas.paged_attention_kernel import (
     paged_mla_attention_pallas_q)
 
 _WALK_P = 4
-# Pages of 4 slots: a row is one work item (a block holds 16 such pages,
-# more than the narrow table). Pages of 64: a block is one page, so the
-# rows below take one to four items.
+# Pages of 4 slots: a row is one work item (a decode block holds 32 such
+# pages, more than the narrow table). Pages of 64: a block is two pages,
+# so the rows below take one or two items.
 _WALK_PAGES = [4, 64]
 
 

@@ -88,6 +88,11 @@ def load_hf_llama(path: str, cfg: ModelConfig) -> dict:
         raise NotImplementedError(
             "HF import does not map MLA layouts yet (kv_a/kv_b projections "
             "→ w_dkv/w_uk/w_uv) — load via orbax instead.")
+    if cfg.attn_gate:
+        raise NotImplementedError(
+            "HF import does not map attn_gate's projection (wg): no "
+            "dense llama-family checkpoint publishes one — load via orbax "
+            "instead.")
     sd = _hf_state_dict(path)
     dt = cfg.jax_dtype
     L = cfg.num_layers

@@ -92,9 +92,17 @@ class ModelConfig:
     # RoPE pairing: rotate-half pairs (x_i, x_{i+hd/2}); interleaved pairs
     # (x_2i, x_2i+1), the published DeepSeek convention.
     rope_interleave: bool = False
-    # False: attention without positions (Kimi-Linear ``mla_use_nope``): the
-    # latent attention's ``qk_rope_head_dim`` channels stay, unrotated.
+    # False: attention without positions: neither path rotates its queries
+    # and keys (``llama._qkv``, ``_mla_qkv``). The latent attention's
+    # ``qk_rope_head_dim`` channels stay, as plain shared key channels
+    # (Kimi-Linear ``mla_use_nope``); grouped-query attention caches its
+    # keys as projected (Solar-Open2 ``use_rope`` false).
     use_rope: bool = True
+    # An output gate on grouped-query attention (Solar-Open2
+    # ``use_gqa_gate``; arXiv:2505.06708's elementwise form): ``wo (o *
+    # sigmoid(x~ wg))``, ``wg [d, heads x head_dim]``, a gate a channel of
+    # every head, from the layer's normed input (``llama._attn_gate``).
+    attn_gate: bool = False
     # Recurrent layers (Kimi Delta Attention, ``ops/kda.py``): the 1-based
     # numbers, as published, of the layers that mix tokens by a gated delta
     # rule over a fixed state ``[kda_num_heads, kda_head_dim, kda_head_dim]``
@@ -109,6 +117,10 @@ class ModelConfig:
     kda_head_dim: int = 128
     kda_conv_kernel: int = 4
     kda_rank: int = 128
+    # The write strength is ``kda_beta_scale x sigmoid(.)``: 1 keeps ``I -
+    # b k k^T``'s eigenvalues in [0, 1]; 2 lets them reach -1 (Solar-Open2
+    # ``kda_allow_neg_eigval``).
+    kda_beta_scale: float = 1.0
     # What mixes tokens in each layer, as published (LFM2 ``layer_types``):
     # ``full_attention`` (this config's attention) or ``conv``, the gated
     # short convolution (``ops/short_conv.py``): ``[B, C, X] = W_in x``,
@@ -149,6 +161,10 @@ class ModelConfig:
                     f", and leaves kda_layers empty; got "
                     f"{len(self.layer_types)} entries, unknown {unknown}, "
                     f"kda_layers {tuple(self.kda_layers)}")
+        if self.attn_gate and self.mla:
+            raise ValueError(
+                "attn_gate gates grouped-query attention's output; the "
+                "latent attention (mla) has no gate")
         if self.experts_held is not None:
             lo, hi = self.experts_held
             if not 0 <= lo < hi <= self.num_experts:
@@ -308,6 +324,8 @@ class ModelConfig:
             attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
             if self.qk_norm:
                 attn += 2 * hd
+            if self.attn_gate:
+                attn += d * self.num_heads * hd     # wg
         dense_mlp = 3 * d * f
         # The experts this device holds; the router is whole.
         moe_mlp = self.experts_here * 3 * d * self.moe_f + d * self.num_experts
@@ -460,6 +478,24 @@ _PRESETS = {
         experts_held=(4, 12), qk_norm=True, conv_kernel=3,
         layer_types=("conv", "conv") + ("full_attention", "conv", "conv",
                                         "conv") * 2,
+    ),
+    # Tiny Solar-Open2-shaped model for tests (the layers of the benchmark's
+    # solar-open2-250b): expert layers A K K K A K K K with no dense layer
+    # (A: grouped-query attention without positions and with an output
+    # gate, on K/V pages; K: the gated delta rule with a write strength of
+    # 2 sigmoid, its states beside those pages), sigmoid experts picked by
+    # a bias beside a shared one, a held range of them.
+    "tiny-solar-open2": ModelConfig(
+        name="tiny-solar-open2", vocab_size=256, hidden_size=128,
+        intermediate_size=320, num_layers=8, num_heads=4, num_kv_heads=2,
+        head_dim=32, max_seq_len=256, rope_theta=10000.0, rms_norm_eps=1e-5,
+        dtype="float32",
+        num_experts=16, experts_per_token=4, moe_intermediate_size=48,
+        moe_shared_expert=True, moe_shared_expert_size=48,
+        moe_scoring="sigmoid", moe_select_bias=True, experts_held=(4, 8),
+        use_rope=False, attn_gate=True,
+        kda_layers=(2, 3, 4, 6, 7, 8), kda_num_heads=4, kda_head_dim=32,
+        kda_rank=16, kda_beta_scale=2.0,
     ),
 }
 

@@ -144,6 +144,9 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
         if cfg.qk_norm:
             blocks.update({"q_head_norm": jnp.ones((L, hd), dt),
                            "k_head_norm": jnp.ones((L, hd), dt)})
+        if cfg.attn_gate:
+            blocks["wg"] = nrm(jax.random.fold_in(ks[1], 2), (L, d, h * hd),
+                               s_in)
     if cfg.half == "mixer":
         del blocks["mlp_norm"]
         return blocks
@@ -232,7 +235,8 @@ def _lora_proj(xa, base_w, name, lora, lora_ids):
 
 def _qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
     """Shared pre-attention math: norm → projections (+opt bias) → (opt
-    RMSNorm of each query and key head, ``cfg.qk_norm``) → RoPE."""
+    RMSNorm of each query and key head, ``cfg.qk_norm``) → RoPE (none
+    without ``cfg.use_rope``: keys are cached as projected)."""
     B, T, _ = x.shape
     hd, h, kv = cfg.head_dim_, cfg.num_heads, cfg.num_kv_heads
     xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
@@ -249,9 +253,24 @@ def _qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
     if cfg.qk_norm:
         q = rms_norm(q, blk["q_head_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, blk["k_head_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_interleave)
-    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_interleave)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_interleave)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_interleave)
     return q, k, vv
+
+
+def _attn_gate(cfg: ModelConfig, blk, x, attn):
+    """``cfg.attn_gate``: grouped-query attention's output ``[B, T, h, hd]``
+    times ``sigmoid(x~ wg)``, a gate a channel of every head, from the
+    layer's normed input (the norm is ``_qkv``'s own, which the compiler
+    computes once). Called inside the ``attention`` scope: its operations'
+    paths hold ``attention/gate``. Without the field, ``attn`` as it is."""
+    if not cfg.attn_gate:
+        return attn
+    with jax.named_scope("gate"):
+        xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
+        gate = jax.nn.sigmoid((xa @ blk["wg"]).astype(jnp.float32))
+        return (attn * gate.reshape(attn.shape)).astype(attn.dtype)
 
 
 def _mla_qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
@@ -302,14 +321,19 @@ def _mla_scale(cfg: ModelConfig) -> float:
     return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
 
 
+# The scope of a mixer's output projection, by what mixes tokens: a KDA
+# layer's lies with its input projections (``_kda_attention``).
+_WO_SCOPES = {"full": "attention", "conv": "attention/conv",
+              "kda": "attention/kda/proj"}
+
+
 def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
                     lora_ids=None, hit_experts=None):
     """Shared post-attention math: residual → norm → MLP/MoE → residual.
     With ``hit_experts`` (``_moe_mlp_hit``'s stacks, layer and live rows)
     the experts are the hit ones only, and their count is returned too."""
     B, T, _ = x.shape
-    with jax.named_scope("attention" if cfg.attention == "full"
-                         else "attention/" + cfg.attention):
+    with jax.named_scope(_WO_SCOPES[cfg.attention]):
         x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
                            lora_ids)
     with jax.named_scope("moe" if cfg.num_experts else "mlp"):
@@ -506,6 +530,7 @@ def _block(cfg: ModelConfig, x, blk, k_cache, v_cache, positions, kv_valid):
             attn = gqa_attention(q, k_cache, v_cache, positions, kv_valid)
         else:
             attn = gqa_attention(q, k, vv, positions, kv_valid)
+        attn = _attn_gate(cfg, blk, x, attn)
     return _post_attention(cfg, blk, x, attn), k_cache, v_cache
 
 
@@ -640,8 +665,9 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
         return _mla_out(cfg, blk, attend_mla(
             *q, *where, _mla_scale(cfg), use_pallas=use_pallas, c_scales=ksf,
             pe_scales=vsf, **bound)), pool
-    return attend(q, *where, use_pallas=use_pallas, k_scales=ksf,
-                  v_scales=vsf, **bound), pool
+    return _attn_gate(cfg, blk, x, attend(
+        q, *where, use_pallas=use_pallas, k_scales=ksf, v_scales=vsf,
+        **bound)), pool
 
 
 def _row_lines(addr: PoolAddr, T: int):
@@ -723,13 +749,19 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
     h, dk = cfg.kda_num_heads, cfg.kda_head_dim
     ch, f32 = h * dk, jnp.float32
     xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
-    qkv = xa @ blk["kda_qkv"]                                # [B, T, 3 ch]
-    f = ((xa @ blk["kda_f_down"]) @ blk["kda_f_up"]).astype(f32)
-    g = -jnp.exp(blk["kda_a_log"].astype(f32))[:, None] * jax.nn.softplus(
-        f + blk["kda_dt_bias"].astype(f32)).reshape(B, T, h, dk)
-    beta = jax.nn.sigmoid((xa @ blk["kda_wb"]).astype(f32))  # [B, T, h]
-    gate = jax.nn.sigmoid(
-        ((xa @ blk["kda_g_down"]) @ blk["kda_g_up"]).astype(f32))
+    # The projections and what shapes them; ``wo`` lies under the same
+    # path, ``attention/kda/proj`` (``_WO_SCOPES``).
+    with jax.named_scope("proj"):
+        qkv = xa @ blk["kda_qkv"]                            # [B, T, 3 ch]
+        f = ((xa @ blk["kda_f_down"]) @ blk["kda_f_up"]).astype(f32)
+        g = -jnp.exp(blk["kda_a_log"].astype(f32))[:, None] \
+            * jax.nn.softplus(f + blk["kda_dt_bias"].astype(f32)).reshape(
+                B, T, h, dk)
+        beta = jax.nn.sigmoid((xa @ blk["kda_wb"]).astype(f32))  # [B, T, h]
+        if cfg.kda_beta_scale != 1.0:   # 2: eigenvalues down to -1
+            beta = cfg.kda_beta_scale * beta
+        gate = jax.nn.sigmoid(
+            ((xa @ blk["kda_g_down"]) @ blk["kda_g_up"]).astype(f32))
 
     # [1, T] packed -> [R, C], a row a line
     lines, packed = _row_lines(addr, T)
@@ -1150,7 +1182,8 @@ def _encode_core(params, cfg, tokens, token_mask, mesh=None, remat=False,
             if use_ring:
                 q, k, vv = _qkv(g, blk, h, positions)
                 attn = ring_attention(q, k, vv, positions, kv_positions, mesh)
-                return _post_attention(g, blk, h, attn)
+                return _post_attention(g, blk, h,
+                                       _attn_gate(g, blk, h, attn))
             h, _, _ = _block(g, h, blk, None, None, positions, token_mask)
             return h
 

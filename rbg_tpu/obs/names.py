@@ -603,6 +603,11 @@ MOE_INNER_SCOPES = ("router", "shared")
 # (``models/llama.py::_kda_attention``, ``_conv_attention``);
 # ``device.kda_share`` and ``device.conv_share`` read the paths.
 ATTENTION_INNER_SCOPES = ("kda", "conv")
+# Parts of a mixer with a path of their own under ``attention``: the
+# output gate of a gated grouped-query attention layer
+# (``models/llama.py::_attn_gate``; ``device.attn_gate_share``), and a KDA
+# layer's projections with its ``wo`` (``device.kda_proj_share``).
+ATTENTION_PART_SCOPES = ("gate", "kda/proj")
 
 # ---- jitted program catalog (jitwatch sentry + warmers) ----
 #

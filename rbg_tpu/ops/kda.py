@@ -3,7 +3,9 @@
 A head keeps a state ``S [dk, dv]`` in float32 in place of cached keys and
 values. For a token with key ``k`` (unit length), value ``v``, query ``q``,
 log-decay ``g <= 0`` a key channel (``a = exp(g)``) and write strength
-``b`` in (0, 1)::
+``b`` in (0, 1), or in (0, 2) where the model lets ``I - b k k^T`` have
+negative eigenvalues (``ModelConfig.kda_beta_scale``; all three forms take
+``b`` as data)::
 
     S <- (I - b k k^T) Diag(a) S + b k v^T        o = S^T q
 

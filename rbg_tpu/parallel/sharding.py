@@ -77,6 +77,9 @@ def _block_specs(cfg: ModelConfig) -> dict:
             "wk": P(None, None, "tp"),
             "wv": P(None, None, "tp"),
         })
+        if cfg.attn_gate:
+            # The output gate's columns are the heads' channels, as wq's.
+            blocks["wg"] = P(None, None, "tp")
     if cfg.num_experts == 0 or cfg.moe_shared_expert:
         blocks["w_gate"] = P(None, None, "tp")
         blocks["w_up"] = P(None, None, "tp")

@@ -19,13 +19,18 @@ positions, the worst request's own median):
 * with ``--faults``, for a model with recurrent layers, the program with
   its recurrent mixer patched: ``not carried`` (every chunk of a prompt
   starts from a zero state) and ``not zeroed`` (no row starts from zeros,
-  served on slots another sample has used).
+  served on slots another sample has used);
+* with ``--faults``, for a model whose preset sets them, each of its rules
+  left out of the served path, one at a time (``left_out``): the attention
+  layers' output gate dropped, the delta rule's write strength not scaled,
+  attention without positions rotated.
 
 A limit lies between the largest ``sound`` and the smallest control
 (``PERF.md`` section 2). Nothing here is a timing.
 """
 
 import argparse
+import dataclasses
 import json
 import os
 import statistics
@@ -91,6 +96,19 @@ def patched(fault):
             for name in ("_kda_attention", "_conv_attention")}
 
 
+def left_out(m) -> dict:
+    """``{fault: ModelConfig fields}``: the preset ``m`` with one of the
+    rules it sets left out."""
+    rules = {}
+    if m.attn_gate:
+        rules["gate dropped"] = dict(attn_gate=False)
+    if m.kda_beta_scale != 1.0:
+        rules["b not scaled"] = dict(kda_beta_scale=1.0)
+    if not m.use_rope and not m.mla:
+        rules["rotated"] = dict(use_rope=True)
+    return rules
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
@@ -115,8 +133,8 @@ def main(argv=None) -> int:
     print(f"device: {jax.devices()[0].platform} "
           f"{jax.devices()[0].device_kind}", flush=True)
 
-    def engine(params):
-        return Engine(EngineConfig(model=name, **cfg["server"]),
+    def engine(params, model=name):
+        return Engine(EngineConfig(model=model, **cfg["server"]),
                       params=params)
 
     def against_reference(params, prompts, served, quant=None):
@@ -146,7 +164,18 @@ def main(argv=None) -> int:
             say("int8", w, p, against_reference(params, alone + rest, served,
                                                 "int8"))
         del eng
-        if not (args.faults and presets._PRESETS[name].recurrent):
+        if not args.faults:
+            continue
+        for fault, fields in left_out(presets._PRESETS[name]).items():
+            presets._PRESETS[name + "-fault"] = dataclasses.replace(
+                presets._PRESETS[name], name=name + "-fault", **fields)
+            eng = engine(params, name + "-fault")
+            alone, rest = sample(cfg, args.prompts[0])
+            served = serve_all(eng, alone, new) + serve_all(eng, rest, new)
+            say(fault, w, args.prompts[0],
+                against_reference(params, alone + rest, served))
+            del eng
+        if not presets._PRESETS[name].recurrent:
             continue
         for fault in ("not carried", "not zeroed"):
             real = {n: getattr(llama, n) for n in patched(fault)}

@@ -132,7 +132,8 @@ def tiny_cases():
                                ("tiny-lora", "tiny", {}),
                                ("tiny-joyai", "tiny-joyai", {}),
                                ("tiny-kimi-linear", "tiny-kimi-linear", {}),
-                               ("tiny-lfm2", "tiny-lfm2", {})):
+                               ("tiny-lfm2", "tiny-lfm2", {}),
+                               ("tiny-solar-open2", "tiny-solar-open2", {})):
         if model not in presets._PRESETS:   # an older checkout
             continue
         cfg = EngineConfig(model=model, use_pallas="never",

@@ -166,6 +166,7 @@ CELL_DECODE = {
     "lfm2.longgen32": (32, 256, 10 * 8192, (32, 8, 64)),
     "joyai.longgen16": (16, 256, 5 * 8192, 32),
     "kimi-linear.longgen16": (16, 256, 7 * 4096, 32),
+    "solar-open2.longgen32": (32, 256, 2 * 8192, (64, 8, 128)),
 }
 
 
@@ -463,6 +464,62 @@ def test_step_programs_of_the_lfm2_cell_fit_and_copy_neither_pool(
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
 
 
+# ---- the benchmark's solar-open2.longgen32 cell: its two step programs -------
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_step_programs_of_the_solar_cell_fit_and_copy_neither_pool(
+        chip, monkeypatch, program):
+    """``benchmark/configs/solar-open2-250b.json`` as served: 8 layers in
+    two turns A K K K (A: 64 / 8 heads of 128 without positions, gated; K:
+    64 delta-rule heads of 128), 20 of 320 experts a layer, 32 rows, K/V
+    pages for the 2 attention layers and beside them a state slot a row
+    for the 6 recurrent ones, ``f32[6,32,64,128,128]`` (805 MB) and the
+    tails ``bf16[6,32,73728]``. A decode step holds the attention layers'
+    walk and the state's kernel in one program; no ``copy`` of a pool's
+    shape, no temporary the size of a layer's held experts (629 MB), and
+    the router's 320 outputs (2.5 lane tiles) compile."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from harness import serve
+    from rbg_tpu.models import config as presets
+    with open(os.path.join(bench, "configs", "solar-open2-250b.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setitem(presets._PRESETS, "solar-cell",
+                        serve.model_config(cfg, "solar-cell"))
+    eng = _abstract_engine(chip, monkeypatch, model="solar-cell",
+                           **cfg["server"])
+    assert eng.params["moe_mlps"]["moe_gate"].shape == (8, 20, 4096, 1280)
+    assert eng.params["moe_mlps"]["router"].shape == (8, 4096, 320)
+    assert eng.params["kda_mixers"]["kda_qkv"].shape == (6, 4096, 24576)
+    assert eng.params["mixers"]["wg"].shape == (2, 4096, 8192)
+    assert eng.params["lm_head"].shape == (4096, 24576)
+    assert eng.cache.k_pages.shape == (2, 8192, 16, 8, 128)
+    assert {k: v.shape for k, v in eng.state.arrays.items()} == {
+        "s": (6, 32, 64, 128, 128), "conv": (6, 32, 73728)}
+    compiled = (_compile_decode if program == "decode"
+                else _compile_unified)(chip, eng)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("_decode_call" if program == "decode"
+            else "_block_ragged_call") in text
+    assert ("_kda_decode_call" in text) == (program == "decode")
+    pools = {eng.cache.k_pages.size, eng.state.arrays["s"].size,
+             eng.state.arrays["conv"].size}
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert not [dims for dims in copied if np.prod(
+        [int(d) for d in dims.split(",")]) in pools]
+    # 512 MB when written for the unified step's 2048 packed tokens (their
+    # q, k, v in float32 a row a line, the dense dispatch's [2048, 20,
+    # 1280]); a decode step's stay under 256 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        256 << 20 if program == "decode" else 640 << 20)
+
+
 @pytest.mark.parametrize("heads_per_block", [8, 16])
 def test_kda_decode_kernel_compiles_for_v5e_in_place(chip, heads_per_block):
     """The decode kernel alone at the Kimi cell's widths: 16 rows, 32 heads
@@ -479,6 +536,23 @@ def test_kda_decode_kernel_compiles_for_v5e_in_place(chip, heads_per_block):
     assert "tpu_custom_call" in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 20 * 16 * 32 * 128 * 128 * 4
+    assert memory.temp_size_in_bytes < 1 << 20
+
+
+def test_kda_decode_kernel_compiles_at_64_heads_in_four_blocks(chip):
+    """The decode kernel at the Solar cell's widths: 32 rows, 64 heads of
+    128 x 128 float32 (four head blocks a row where Kimi's 32 heads make
+    two), 6 layers' pool of 32 slots (805 MB), in place."""
+    from rbg_tpu.ops.pallas.kda_kernel import HEADS_PER_BLOCK, kda_decode_pallas
+    assert 64 // HEADS_PER_BLOCK == 4
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    rows, pool = S((32, 64, 128), F32), S((6, 32, 64, 128, 128), F32)
+    compiled = jax.jit(kda_decode_pallas, donate_argnums=(5,)).lower(
+        rows, rows, rows, rows, S((32, 64), F32), pool, S((), I32),
+        S((32,), I32), S((32,), bool)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 6 * 32 * 64 * 128 * 128 * 4
     assert memory.temp_size_in_bytes < 1 << 20
 
 

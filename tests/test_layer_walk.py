@@ -227,3 +227,40 @@ def test_every_dot_of_the_recurrent_layers_sits_under_attention_and_kda(
     assert not [p for p in kda if {"moe", "mlp", "lm_head"} & set(p)]
     # distinct paths: the projections' and ``wo``'s, and the state's dots
     assert len(kda) >= 2
+    # a KDA layer's projections and ``wo`` sit one scope further in, which
+    # ``device.kda_proj_share`` reads; the recurrence's dots do not
+    proj = [p for p in kda if "proj" in p]
+    assert bool(proj) == (inner == "kda") and len(proj) < len(kda)
+    assert all(p[p.index(inner) + 1] == "proj" for p in proj)
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_the_gate_and_the_kda_projections_have_scopes_of_their_own(program):
+    """``tiny-solar-open2``: ``device.attn_gate_share`` reads the path
+    ``attention/gate`` (the gate's projection, on the attention layers
+    alone) and ``device.kda_proj_share`` the path ``attention/kda/proj``;
+    every dot of the step sits in one model scope, none under ``mlp`` (no
+    layer is dense)."""
+    from rbg_tpu.obs.names import ATTENTION_PART_SCOPES, MODEL_SCOPES
+    assert ATTENTION_PART_SCOPES == ("gate", "kda/proj")
+    eng = Engine(EngineConfig(
+        model="tiny-solar-open2", page_size=8, num_pages=64, max_seq_len=128,
+        max_batch=4, prefill_chunk=16, use_pallas="never"))
+    text = _lower(eng, program).compile().as_text()
+    paths = [p.split("/") for p in sorted(set(re.findall(
+        r'op_name="([^"]*dot_general)"', text)))]
+    scopes = [sorted(set(MODEL_SCOPES) & set(p)) for p in paths]
+    assert all(len(s) == 1 for s in scopes), [
+        p for p, s in zip(paths, scopes) if len(s) != 1]
+    assert {s[0] for s in scopes} == {"attention", "moe", "lm_head"}
+    gate = [p for p in paths if "gate" in p]
+    assert gate and all(p[p.index("gate") - 1] == "attention"
+                        and "kda" not in p for p in gate)
+    kda = [p for p in paths if "kda" in p]
+    proj = [p for p in kda if "proj" in p]
+    assert proj and len(proj) < len(kda)
+    assert all(p[p.index("kda") - 1:p.index("kda") + 2] == [
+        "attention", "kda", "proj"] for p in proj)
+    # q, k, v and the attend's own dots: under ``attention`` alone
+    assert [p for p in paths if "attention" in p and "kda" not in p
+            and "gate" not in p]

@@ -474,7 +474,8 @@ def test_state_counters_count_slots_rows_and_the_tails_bytes(bench):
 
 
 KINDS = pytest.mark.parametrize("model,kinds", [
-    ("tiny-kimi-linear", "kda"), ("tiny-lfm2", "conv")])
+    ("tiny-kimi-linear", "kda"), ("tiny-lfm2", "conv"),
+    ("tiny-solar-open2", "kda")])
 TINY_KW = dict(page_size=8, num_pages=32, max_seq_len=64, max_batch=2,
                prefill_chunk=16)
 

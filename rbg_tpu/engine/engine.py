@@ -296,7 +296,13 @@ class Engine:
                         # is one a step), and those of them in which some
                         # sampling row set top-k, top-p or min-p: the ones
                         # that sort the vocabulary.
-                        "sampler_steps": 0, "sampler_sort_steps": 0}
+                        "sampler_steps": 0, "sampler_sort_steps": 0,
+                        # Rows of the unified steps that dispatched, and
+                        # those of them with more than one query token
+                        # (a prompt's chunk): how often a recurrent
+                        # layer's walk over chunk rows engages
+                        # (``llama._kda_packed``), from the pack.
+                        "unified_rows": 0, "unified_chunk_rows": 0}
         # The step being run: when each phase last began and which one
         # is running (``_Phase``), and what the step first dispatched
         # (``_note_dispatch``).
@@ -1371,6 +1377,9 @@ class Engine:
 
         with _Phase(self, _DISPATCH):
             self._note_dispatch("unified", len(entries), Ttot, Rb, Tb)
+            self.metrics["unified_rows"] += len(entries)
+            self.metrics["unified_chunk_rows"] += sum(
+                end - start > 1 for _, start, end in entries)
             fn = self._get_ragged_fn(Rb, Tb)
             logits, *pools = fn(
                 self.params, *dev, self.cache.k_pages, self.cache.v_pages,

@@ -27,7 +27,7 @@ from rbg_tpu.engine.protocol import (CODE_DEADLINE, DeadlineExceeded,
 from rbg_tpu.obs import names, trace
 from rbg_tpu.obs.metrics import REGISTRY
 from rbg_tpu.obs.slo import SLOTargets, SLOTracker
-from rbg_tpu.utils import chipenv, jitwatch
+from rbg_tpu.utils import chipenv, jitwatch, pystack
 from rbg_tpu.utils.locktrace import named_lock
 from rbg_tpu.utils.racetrace import guard as _race_guard
 
@@ -282,8 +282,11 @@ class _BatchService:
         # (DecodeService.watch_stream fills it; _pump drains it).
         self._new_streams: List[object] = []  # guarded_by[engine.service_queue]
         self._done_times = collections.deque(maxlen=_RATE_WINDOW)
-        self._thread = threading.Thread(target=self._loop, daemon=True,
-                                        name=type(self).__name__.lower())
+        # The loop traces the programs a warm wave first meets: on a roomy
+        # stack, as the warm-up's own thread is (``pystack``).
+        self._thread = threading.Thread(
+            target=pystack.on_roomy_stack, args=(self._loop,), daemon=True,
+            name=type(self).__name__.lower())
         self._thread.start()
         self._watchdog = threading.Thread(
             target=self._watch, daemon=True,
@@ -469,7 +472,12 @@ class _BatchService:
         (``rolebasedgroup_controller.go`` buildWarmupPod:535; our control
         plane's warmup controller readies images, this readies the jit
         cache). One wave per bucket size, largest first, through the
-        normal submit path. Returns elapsed seconds."""
+        normal submit path. Returns elapsed seconds. Most of a warm
+        start is JAX tracing and lowering in this thread, so it runs on a
+        roomy stack (``pystack``)."""
+        return pystack.on_roomy_stack(self._warmup, input_len, out_len)
+
+    def _warmup(self, input_len: int, out_len: int) -> float:
         t0 = time.monotonic()
         took = {}
 

@@ -671,12 +671,14 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
 
 
 def _row_lines(addr: PoolAddr, T: int):
-    """How a recurrent mixer lays a packed step's tokens out a row a line:
-    ``(lines, packed)``. ``lines(a)`` takes ``[1, T, ...]`` to ``[R, C,
-    ...]`` (``C`` the longest row the step may hold; padding is dropped,
-    what no token fills is zero) and ``packed(o)`` takes ``[R, C, ...]``
-    back to ``[1, T, ...]``. A step that is by row already gets
-    identities."""
+    """How the gated short convolution lays a packed step's tokens out a
+    row a line: ``(lines, packed)``. ``lines(a)`` takes ``[1, T, ...]`` to
+    ``[R, C, ...]`` (``C`` the longest row the step may hold; padding is
+    dropped, what no token fills is zero) and ``packed(o)`` takes ``[R, C,
+    ...]`` back to ``[1, T, ...]``. A step that is by row already gets
+    identities. ``_conv_attention``'s alone: its lines are ``d`` wide and
+    its state is a tail; the delta rule's packed step lays no line out
+    (``_kda_packed``)."""
     from rbg_tpu.ops.ragged_paged_attention import _unpack_offsets
 
     if addr.row_ids is None:
@@ -727,6 +729,176 @@ def _conv_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
     return packed(out), state
 
 
+def _kda_conv_qkv(h: int, dk: int, conv_w, qkv, tail, fresh, lens):
+    """What a row's new tokens pass between the projection and the
+    recurrence: ``qkv [R, C, 3 h dk]`` through the causal convolution
+    (taps ``conv_w``) that goes on from ``tail [R, (K-1) 3 h dk]`` (the
+    slot's, flat; zeros for a ``fresh`` row), split by head, ``q`` and
+    ``k`` brought to unit length (``q`` scaled by ``dk ** -0.5``).
+    Returns (``q, k, v [R, C, h, dk]`` float32, the new tail, flat)."""
+    from rbg_tpu.ops import kda
+
+    tail = jnp.where(fresh[:, None], jnp.zeros_like(tail), tail)
+    qkv, tail = kda.short_conv(qkv, tail.reshape(tail.shape[0], -1, 3 * h * dk),
+                               conv_w, lens)
+    tail = tail.reshape(tail.shape[0], -1)
+    q, k, v = (a.reshape(a.shape[:2] + (h, dk)).astype(jnp.float32)
+               for a in jnp.split(qkv, 3, axis=-1))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+        * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    return q, k, v, tail
+
+
+def _kda_rows(cfg: ModelConfig, blk, qkv, g, beta, state, layer,
+              addr: PoolAddr, use_pallas: str):
+    """The convolution and the recurrence of a step by row (``[B, T]``: a
+    decode step, a chunk of the split prefill path): one token a row
+    advances the states where they lie in the pool (``kda.kda_decode``),
+    longer rows' states are gathered, walked in chunks and scattered back,
+    every row's. Returns (``o [B, T, H, dk]`` float32, state)."""
+    from rbg_tpu.ops import kda
+
+    mask = addr.token_mask
+    g = jnp.where(mask[..., None, None], g, 0.0)
+    beta = jnp.where(mask[..., None], beta, 0.0)
+    lens = jnp.sum(mask, axis=1, dtype=jnp.int32)
+    slots = addr.state_slots
+    fresh = (addr.positions[:, 0] == 0) & mask[:, 0]
+    tail = state["conv"].at[layer, slots].get(mode="clip")
+    q, k, v, tail = _kda_conv_qkv(cfg.kda_num_heads, cfg.kda_head_dim,
+                                  blk["kda_conv"], qkv, tail, fresh, lens)
+    if q.shape[1] == 1:
+        o, s = kda.kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                              state["s"], layer, slots, fresh,
+                              use_pallas=use_pallas)
+        o = o[:, None]
+    else:
+        S = jnp.where(fresh[:, None, None, None], 0.0,
+                      state["s"].at[layer, slots].get(mode="clip"))
+        o, S = kda.kda_chunk(q, k, v, g, beta, S)
+        s = state["s"].at[layer, slots].set(S, mode="drop")
+    return o, {**state, "s": s, "conv": state["conv"].at[layer, slots].set(
+        tail, mode="drop")}
+
+
+def _kda_one_token_rows(h: int, dk: int, use_pallas: str, conv_w, qkv, g,
+                        beta, s, conv, layer, slots, fresh):
+    """The decode step's own path for a packed step's rows of one token:
+    ``qkv [R, 3 h dk]``, ``g [R, h, dk]``, ``beta [R, h]`` each row's one
+    token (zeros in ``g`` and ``beta`` for any other row), ``slots`` out
+    of range for any other row. The pool's arrays ``s`` and ``conv``
+    advance where they lie (``kda.kda_decode``). Returns (``o [R, h, dk]``
+    float32, s, conv)."""
+    from rbg_tpu.ops import kda
+
+    live = (slots >= 0) & (slots < s.shape[1])
+    tail = conv.at[layer, slots].get(mode="clip")
+    q, k, v, tail = _kda_conv_qkv(h, dk, conv_w, qkv[:, None], tail, fresh,
+                                  live.astype(jnp.int32))
+    o, s = kda.kda_decode(q[:, 0], k[:, 0], v[:, 0], g, beta, s, layer,
+                          slots, fresh, use_pallas=use_pallas)
+    return o, s, conv.at[layer, slots].set(tail, mode="drop")
+
+
+def _kda_chunk_row(h: int, dk: int, conv_w, qkv, g, beta, tail, S, fresh, n):
+    """One row's chunk by the chunked form: ``qkv [C, 3 h dk]``, ``g [C,
+    h, dk]``, ``beta [C, h]`` the ``C`` tokens from the row's first on, of
+    which ``n`` are its own; ``tail [1, (K-1) 3 h dk]`` and ``S [1, h, dk,
+    dk]`` its slot's; ``fresh`` a scalar. Returns (``o [C, h, dk]``
+    float32, S, tail)."""
+    from rbg_tpu.ops import kda
+
+    real = jnp.arange(qkv.shape[0], dtype=jnp.int32) < n
+    q, k, v, tail = _kda_conv_qkv(h, dk, conv_w, qkv[None], tail, fresh[None],
+                                  n[None])
+    o, S = kda.kda_chunk(
+        q, k, v, jnp.where(real[:, None, None], g, 0.0)[None],
+        jnp.where(real[:, None], beta, 0.0)[None], jnp.where(fresh, 0.0, S))
+    return o[0], S, tail
+
+
+def _kda_packed(cfg: ModelConfig, blk, qkv, g, beta, state, layer,
+                addr: PoolAddr, use_pallas: str):
+    """The convolution and the recurrence of a packed step (``[1, T]``,
+    ``addr.row_ids``), whose cost follows the rows that hold a chunk and
+    not the row bucket. A row's tokens lie side by side on the packed
+    axis; how many it has, and where its first lies, is read off
+    ``row_ids`` and ``token_mask``.
+
+    * A row of ONE token takes the decode step's path
+      (``_kda_one_token_rows``): its token is gathered ``[R, 1]`` and
+      advances its state where it lies in the pool (``kda.kda_decode``),
+      every other row's slot named out of range, which that path treats
+      as padding.
+    * Rows of two tokens or more are walked by ``kda.kda_chunk``, they
+      alone: ordered to the front, one a trip (``_kda_chunk_row``) of a
+      loop whose length is their count (data, no static key). A trip reads
+      its row's ``C`` tokens off the packed axis, its state and tail out
+      of its slot, and writes state, tail and ``o`` back where they lie,
+      so that no array holds every row's line or every row's state. One
+      row a trip: a row's sub-chunk is some twenty small operations the
+      chip runs one after another, and a trip of two or four rows cost
+      the step of one chunk row more than it saved the ramp (PERF.md,
+      PR 42).
+    * A row with no token is in neither set: its slot is not touched.
+
+    Returns (``o [1, T, H, dk]`` float32, state)."""
+    T, R = qkv.shape[1], addr.kv_lens.shape[0]
+    C = T if addr.max_q_len is None else min(addr.max_q_len, T)
+    I32 = jnp.int32
+    h, dk, conv_w = cfg.kda_num_heads, cfg.kda_head_dim, blk["kda_conv"]
+    qkv, g, beta = qkv[0], g[0], beta[0]
+    slots, n_slots = addr.state_slots, state["s"].shape[1]
+    mine = (addr.row_ids == jnp.arange(R, dtype=I32)[:, None]) \
+        & addr.token_mask                                        # [R, T]
+    q_len = jnp.sum(mine, axis=1, dtype=I32)
+    start = jnp.min(jnp.where(mine, jnp.arange(T, dtype=I32), T), axis=1)
+    first = jnp.minimum(start, T - 1)
+    fresh = (addr.positions[0, first] == 0) & (q_len > 0)
+    layer = jnp.asarray(layer, I32)
+
+    # one token: the decode step's own path, in place on the pool
+    one = q_len == 1
+    o, s, conv = _kda_one_token_rows(
+        h, dk, use_pallas, conv_w, qkv[first],
+        jnp.where(one[:, None, None], g[first], 0.0),
+        jnp.where(one[:, None], beta[first], 0.0), state["s"], state["conv"],
+        layer, jnp.where(one, slots, n_slots), fresh)
+    o = jnp.zeros((T,) + o.shape[1:], o.dtype).at[
+        jnp.where(one, start, T)].set(o, mode="drop")
+
+    # two tokens or more: the chunked form, a row a trip
+    chunk = (q_len > 1) & (slots >= 0) & (slots < n_slots)
+    order = jnp.argsort(~chunk, stable=True).astype(I32)         # they lead
+    col = jnp.arange(C, dtype=I32)
+
+    def entry(pool, slot):          # [1, ...]: the slot's own, of this layer
+        return jax.lax.dynamic_slice(
+            pool, (layer, slot) + (0,) * (pool.ndim - 2),
+            (1, 1) + pool.shape[2:])[0]
+
+    def put(pool, slot, new):
+        return jax.lax.dynamic_update_slice(
+            pool, new[None].astype(pool.dtype),
+            (layer, slot) + (0,) * (pool.ndim - 2))
+
+    def trip(i, carry):
+        s, conv, o = carry
+        row = order[i]
+        at, where = slots[row], start[row] + col
+        read = jnp.minimum(where, T - 1)
+        o_row, S, tail = _kda_chunk_row(
+            h, dk, conv_w, qkv[read], g[read], beta[read], entry(conv, at),
+            entry(s, at), fresh[row], q_len[row])
+        return (put(s, at, S), put(conv, at, tail), o.at[jnp.where(
+            col < q_len[row], where, T)].set(o_row, mode="drop"))
+
+    s, conv, o = jax.lax.fori_loop(0, jnp.sum(chunk, dtype=I32), trip,
+                                   (s, conv, o))
+    return o[None], {**state, "s": s, "conv": conv}
+
+
 def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
                    use_pallas: str):
     """The recurrent mixer of one layer (Kimi Delta Attention; the
@@ -736,18 +908,15 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
     ``addr.state_slots``. A row whose tokens start at position 0 starts
     from a zero state and a zero convolution tail, whatever its slot held;
     every other row goes on from what its slot holds; a row with no real
-    token leaves it as it was. A step of one token a row advances the
-    states where they lie in the pool (``kda.kda_decode``: a kernel on a
-    TPU, by ``use_pallas``); longer rows' states are gathered, walked in
-    chunks and scattered back. A packed step's tokens are laid out a row a
-    line (``[R, max_q_len]``) for the convolution and the recurrence and
-    packed again. Returns (``[B, T, H, dk]``, normed by head and gated,
-    before ``wo``; state)."""
-    from rbg_tpu.ops import kda
-
+    token leaves it as it was. The projections run on the step's tokens as
+    they come, by row ``[B, T]`` or packed ``[1, T]``; the convolution and
+    the recurrence are ``_kda_rows``'s for a step by row and
+    ``_kda_packed``'s for a packed one, whose rows of one token take the
+    decode step's path in place on the pool and whose rows that hold a
+    chunk are walked by the chunked form, they alone. Returns (``[B, T, H,
+    dk]``, normed by head and gated, before ``wo``; state)."""
     B, T, _ = x.shape
-    h, dk = cfg.kda_num_heads, cfg.kda_head_dim
-    ch, f32 = h * dk, jnp.float32
+    h, dk, f32 = cfg.kda_num_heads, cfg.kda_head_dim, jnp.float32
     xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
     # The projections and what shapes them; ``wo`` lies under the same
     # path, ``attention/kda/proj`` (``_WO_SCOPES``).
@@ -763,38 +932,8 @@ def _kda_attention(cfg: ModelConfig, blk, x, state, layer, addr: PoolAddr,
         gate = jax.nn.sigmoid(
             ((xa @ blk["kda_g_down"]) @ blk["kda_g_up"]).astype(f32))
 
-    # [1, T] packed -> [R, C], a row a line
-    lines, packed = _row_lines(addr, T)
-    qkv, g, beta, pos, mask = (lines(a) for a in (
-        qkv, g, beta, addr.positions, addr.token_mask))
-    g = jnp.where(mask[..., None, None], g, 0.0)
-    beta = jnp.where(mask[..., None], beta, 0.0)
-    lens = jnp.sum(mask, axis=1, dtype=jnp.int32)
-    slots = addr.state_slots
-    fresh = (pos[:, 0] == 0) & mask[:, 0]
-    tail = state["conv"].at[layer, slots].get(mode="clip")
-    tail = jnp.where(fresh[:, None], jnp.zeros_like(tail), tail)
-    qkv, tail = kda.short_conv(qkv, tail.reshape(tail.shape[0], -1, 3 * ch),
-                               blk["kda_conv"], lens)
-    tail = tail.reshape(tail.shape[0], -1)
-    q, k, v = (a.reshape(a.shape[:2] + (h, dk)).astype(f32)
-               for a in jnp.split(qkv, 3, axis=-1))
-    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
-        * dk ** -0.5
-    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-    if q.shape[1] == 1:
-        o, s = kda.kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                              state["s"], layer, slots, fresh,
-                              use_pallas=use_pallas)
-        o = o[:, None]
-    else:
-        S = jnp.where(fresh[:, None, None, None], 0.0,
-                      state["s"].at[layer, slots].get(mode="clip"))
-        o, S = kda.kda_chunk(q, k, v, g, beta, S)
-        s = state["s"].at[layer, slots].set(S, mode="drop")
-    state = {**state, "s": s,
-             "conv": state["conv"].at[layer, slots].set(tail, mode="drop")}
-    o = packed(o)                                            # [1, T, h, dk]
+    walk = _kda_rows if addr.row_ids is None else _kda_packed
+    o, state = walk(cfg, blk, qkv, g, beta, state, layer, addr, use_pallas)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                           + cfg.rms_norm_eps) * blk["kda_o_norm"].astype(f32)
     return (o * gate.reshape(B, T, h, dk)).astype(x.dtype), state

@@ -574,6 +574,11 @@ SPAN_ROUTER_RESHARD = "router.reshard"
 # The step timeline (docs/observability.md "Step timeline"): phases of one
 # turn of the serving loop, emitted as ``jax.profiler`` annotations by
 # ``trace.annotation`` on the loop thread, never as per-request spans.
+# The timeline's clocks and counts are no metrics of this catalog: they ride
+# the ``metrics`` op as ``Engine.metrics`` holds them (docs/observability.md
+# section 2.5 lists each: ``steps_run``, ``unified_steps_run`` and beside it
+# ``unified_rows`` / ``unified_chunk_rows``, the rows of the unified steps
+# that dispatched and those of them that held a chunk).
 SPAN_SERVICE_INTAKE = "service.intake"
 SPAN_SERVICE_DELIVER = "service.deliver"
 SPAN_SERVICE_IDLE = "service.idle"

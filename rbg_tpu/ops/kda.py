@@ -21,7 +21,10 @@ as a kernel:
   between a gather and a scatter, which is also what the kernel has to
   equal (``dispatch_pallas``'s one policy);
 * ``kda_chunk``: ``C`` tokens a row at once, rows in parallel (a prompt's
-  chunk). Sub-chunks of ``SUB`` tokens are walked in order; inside one,
+  chunk: every row of a step by row, and one row a call in a packed step,
+  where ``models/llama.py::_kda_packed`` hands it the rows that hold a
+  chunk one after another and the rows of one token go to ``kda_decode``).
+  Sub-chunks of ``SUB`` tokens are walked in order; inside one,
   with ``G`` the running sum of ``g`` from its start, the pseudo-values
   ``u`` solve the unit lower-triangular system ``(I + Diag(b) A) U =
   Diag(b) (V - (K e^G) S)``, ``A[t, i] = (k_t e^{G_t}) . (k_i e^{-G_i})``

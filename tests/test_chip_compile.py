@@ -405,14 +405,23 @@ def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
     # A decode step advances the states where they lie (the kernel of
     # ``ops/pallas/kda_kernel.py``, aliased onto the pool inside the layer
     # scan): nothing gathers the rows' states out of the pool or scatters
-    # them back, and no pass over ``[rows, H, dk, dv]`` is left. A unified
-    # step walks its chunks in plain XLA and has both.
-    kernel = "_kda_decode_call" in text
-    passes = re.findall(r"= f32\[16,32,128,128\]\S* fusion\(", text)
-    moved = re.findall(r"= f32\[20,16,32,128,128\]\S* (?:fusion|scatter)\(",
-                       text)
-    assert (kernel, bool(passes), bool(moved)) == (
-        (True, False, False) if program == "decode" else (False, True, True))
+    # them back, and no pass over ``[rows, H, dk, dv]`` is left. Since
+    # PR 42 a unified step does the same for its rows of one token, and
+    # walks the rows that hold a chunk one a trip: it too has no pass over
+    # every row's state, nor over every row's line ``[rows, chunk, H,
+    # dk]``, and what it writes into the pool is a row's slot, in place.
+    assert "_kda_decode_call" in text
+    assert not re.findall(r"= f32\[16,32,128,128\]\S* \w[\w-]*\(", text)
+    assert not re.findall(r"= f32\[16,64,32,128\]\S* \w[\w-]*\(", text)
+    whole = re.findall(
+        r"= f32\[20,16,32,128,128\]\S* (fusion|scatter|copy)\(", text)
+    # the mixer's program is inlined twice: the dense first layer, the loop
+    assert whole == ([] if program == "decode" else ["fusion", "fusion"])
+    # each of the two is the update of a chunk row's slot, its root a
+    # dynamic-update-slice of the pool: in place, as the pages' are
+    assert len(re.findall(
+        r"ROOT \S+ = f32\[20,16,32,128,128\]\S* dynamic-update-slice\(",
+        text)) == len(whole)
 
 
 # ---- the benchmark's lfm2.longgen32 cell: its two step programs --------------
@@ -507,7 +516,12 @@ def test_step_programs_of_the_solar_cell_fit_and_copy_neither_pool(
     assert "tpu_custom_call" in text
     assert ("_decode_call" if program == "decode"
             else "_block_ragged_call") in text
-    assert ("_kda_decode_call" in text) == (program == "decode")
+    # both: a unified step's rows of one token take the kernel too, and
+    # its rows that hold a chunk are walked one a trip, so that no array
+    # holds every row's state or every row's line (PR 42)
+    assert "_kda_decode_call" in text
+    assert not re.findall(r"= f32\[32,64,128,128\]\S* \w[\w-]*\(", text)
+    assert not re.findall(r"= f32\[32,64,64,128\]\S* \w[\w-]*\(", text)
     pools = {eng.cache.k_pages.size, eng.state.arrays["s"].size,
              eng.state.arrays["conv"].size}
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)

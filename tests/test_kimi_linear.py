@@ -29,6 +29,10 @@ from rbg_tpu.models.llama import (_EXPERT_STACKS, _hybrid_plan, _mla_qkv,
                                   _moe_mlp, _moe_mlp_hit, _route)
 from rbg_tpu.ops import kda
 
+from kda_packed_case import STEPS as PACKED_STEPS
+from kda_packed_case import (assert_rows_equal_the_recurrence,
+                             inside_the_mixer)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 if BENCH not in sys.path:
@@ -155,14 +159,15 @@ def _eqns(jaxpr, in_loop=False):
             yield from _eqns(sub, inner)
 
 
-def _step_jaxpr(program, R=4):
+def _step_jaxpr(program, R=4, use_pallas="auto"):
     """The jaxpr of ``tiny-kimi-linear``'s decode step (by row, the hit
     experts' form) or its ragged step (packed), ``R`` rows."""
     T = 1 if program == "decode" else 16
     cache, pool = PagedKVCache.create(CFG, 64, 8), StatePool(CFG, R)
     I32 = jnp.int32
     table = jnp.zeros((R, 8), I32)
-    slots = {"state": pool.arrays, "state_slots": jnp.arange(R, dtype=I32)}
+    slots = {"state": pool.arrays, "state_slots": jnp.arange(R, dtype=I32),
+             "use_pallas": use_pallas}
     if program == "decode":
         fn = functools.partial(llama.forward_paged, PARAMS, CFG,
                                experts_whole=True, **slots)
@@ -383,6 +388,37 @@ def test_a_step_of_one_token_a_row_takes_the_kernel_by_the_one_policy(
     assert len(calls) == 1 and len(calls[0]) == 9
     np.testing.assert_allclose(pool, want[1], rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(o[0], want[0][0], rtol=1e-5, atol=1e-5)
+
+
+# ---- a packed step: the rows that hold a chunk are walked, they alone ---------
+
+
+@pytest.mark.parametrize("use_pallas", ["never", "always"])
+@pytest.mark.parametrize("step", sorted(PACKED_STEPS))
+def test_a_packed_steps_rows_equal_the_recurrence_row_by_row(
+        step, use_pallas, interpreted):
+    """``_kda_packed`` at 32 heads, ``b`` < 1: rows of one token through
+    the decode step's path (plain XLA, or the kernel in place), rows of 2,
+    17 and 64 through the chunked form, fresh ones of each kind, a row
+    with no token, padding; no other slot moves."""
+    assert_rows_equal_the_recurrence(step, 32, 1.0, use_pallas)
+
+
+def test_a_packed_step_lays_out_no_line_and_no_state_for_every_row(
+        interpreted):
+    """The unified program with the kernel in holds no float32 array of
+    ``[R, C, H, dk]`` (every row's line) or ``[R, H, dk, dv]`` (every
+    row's state), which the decode step by row, in plain XLA, has; it has
+    the kernel, and a loop over the rows that hold a chunk, a row a trip."""
+    R, C, H, dk = 5, 16, CFG.kda_num_heads, CFG.kda_head_dim
+    wide = {(R, C, H, dk), (R, H, dk, dk)}
+    shapes, names = inside_the_mixer(_step_jaxpr("ragged", R,
+                                                 use_pallas="always"))
+    assert not shapes & wide and (1, C, H, dk) in shapes
+    assert {"while", "pallas_call"} <= names
+    shapes, names = inside_the_mixer(_step_jaxpr("decode", R,
+                                                 use_pallas="never"))
+    assert (R, H, dk, dk) in shapes and "pallas_call" not in names
 
 
 def test_the_convolution_is_causal_and_keeps_the_last_inputs():

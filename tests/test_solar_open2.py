@@ -30,6 +30,10 @@ from rbg_tpu.models.config import ModelConfig
 from rbg_tpu.models.llama import _hybrid_plan, _moe_mlp
 from rbg_tpu.ops import kda
 
+from kda_packed_case import STEPS as PACKED_STEPS
+from kda_packed_case import (assert_rows_equal_the_recurrence,
+                             inside_the_mixer)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 if BENCH not in sys.path:
@@ -205,6 +209,37 @@ def test_the_decode_kernel_walks_64_heads_in_four_blocks_with_b_up_to_two():
                                       pool[1, 2][None])
     np.testing.assert_allclose(o[0], o_tok[0, 0], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(new[1, 2], S_tok[0], rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("use_pallas", ["never", "always"])
+@pytest.mark.parametrize("step", sorted(PACKED_STEPS))
+def test_a_packed_steps_rows_equal_the_recurrence_at_64_heads_and_b_to_two(
+        step, use_pallas, interpreted):
+    """``_kda_packed`` as the cell's layers run it: 64 heads (four head
+    blocks a row in the kernel), a write strength up to 2; rows of one
+    token in place on the pool, rows of 2, 17 and 64 by the chunked form,
+    they alone, every other slot as it was."""
+    assert_rows_equal_the_recurrence(step, 64, 2.0, use_pallas, seed=3)
+
+
+def test_the_packed_program_walks_chunk_rows_in_a_loop_and_no_row_line(
+        interpreted):
+    """``tiny-solar-open2``'s unified program with the kernel in: no
+    float32 ``[R, C, H, dk]`` or ``[R, H, dk, dv]``, a ``while`` in the
+    mixer's program and the decode kernel beside it."""
+    R, C, H, dk = 5, 16, CFG.kda_num_heads, CFG.kda_head_dim
+    cache, pool = PagedKVCache.create(CFG, 64, 8), StatePool(CFG, R)
+    I32 = jnp.int32
+    jaxpr = jax.make_jaxpr(functools.partial(
+        llama.forward_ragged, PARAMS, CFG, max_q_len=C, use_pallas="always",
+        state=pool.arrays, state_slots=jnp.arange(R, dtype=I32)))(
+        jnp.ones((1, C), I32), jnp.zeros((1, C), I32), jnp.ones((1, C), bool),
+        jnp.zeros(C, I32), jnp.full(R, C, I32), jnp.zeros((R, 8), I32),
+        cache.k_pages, cache.v_pages).jaxpr
+    shapes, names = inside_the_mixer(jaxpr)
+    assert not shapes & {(R, C, H, dk), (R, H, dk, dk)}
+    assert (1, C, H, dk) in shapes                  # a trip's one row
+    assert {"while", "pallas_call"} <= names
 
 
 # ---- the held experts --------------------------------------------------------

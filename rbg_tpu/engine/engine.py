@@ -1819,7 +1819,8 @@ class Engine:
         import functools
         base = functools.partial(forward_paged, cfg=self.mcfg,
                                  use_pallas=self.cfg.use_pallas,
-                                 experts_whole=self._experts_whole)
+                                 experts_whole=self._experts_whole,
+                                 sharded=self.mesh is not None)
 
         def fused(params, tok, pos, kvl, table, mask, limit, k_pages,
                   v_pages, k_scales, v_scales, keys, temps, ks, tps, mps,

@@ -330,8 +330,9 @@ _WO_SCOPES = {"full": "attention", "conv": "attention/conv",
 def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
                     lora_ids=None, hit_experts=None):
     """Shared post-attention math: residual → norm → MLP/MoE → residual.
-    With ``hit_experts`` (``_moe_mlp_hit``'s stacks, layer and live rows)
-    the experts are the hit ones only, and their count is returned too."""
+    With ``hit_experts`` (``_moe_mlp_hit``'s stacks, layer, live rows and
+    kernel policy) the experts are the hit ones only, and their count is
+    returned too."""
     B, T, _ = x.shape
     with jax.named_scope(_WO_SCOPES[cfg.attention]):
         x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
@@ -438,32 +439,12 @@ def hit_experts_pay(cfg: ModelConfig, rows: int) -> bool:
         rows * cfg.experts_per_token <= 2 * cfg.num_experts)
 
 
-def _moe_mlp_hit(cfg: ModelConfig, blk, xm, stacks, layer, live):
-    """``_moe_mlp`` for a step of few rows: only the experts some LIVE
-    row routed to are evaluated, so only their weights are read. Decode is
-    bound by expert bytes, and 8 rows x top-2 of 8 experts hit 7.2 of them
-    on average (4 / 2 / 1 rows: 5.5 / 3.5 / 2). Every term left out is a
-    product with a combine weight of exactly 0. Weights stay in their
-    dtype; gate, up and the combine accumulate in float32.
-
-    ``stacks`` are the STACKED expert weights ``[L, E, D, F]`` /
-    ``[L, E, F, D]`` and ``layer`` the scan's index: each visit slices
-    ``(layer, expert)`` at once, which XLA fuses into the dot. The scan's
-    own slice ``blk["moe_gate"] [E, D, F]`` would be an operand of the
-    inner loop, and so a copy of 0.94 GB per matrix per layer.
-    ``live [B, T]`` marks the real rows: a padded or finished row routes
-    nowhere, so it makes no expert live and only the shared expert adds
-    to it. Returns (out ``[B, T, D]``, the number of experts visited)."""
-    B, T, D = xm.shape
-    E = cfg.experts_here
-    x = xm.reshape(B * T, D)
-    w = jnp.where(live.reshape(B * T, 1),
-                  _held(cfg, _route(cfg, blk, xm)).reshape(B * T, E), 0)
-    w = w.astype(jnp.float32)
-    hit = jnp.any(w > 0, axis=0)                                # [E]
-    visited = jnp.sum(hit, dtype=jnp.int32)
-    ids = jnp.nonzero(hit, size=E, fill_value=0)[0].astype(jnp.int32)
-
+def _visit_loop(x, w, stacks, layer, ids, visited):
+    """The visits in plain XLA: a loop over the first ``visited`` experts
+    of ``ids``, three fusions a visit, each of which slices ``(layer,
+    expert)`` out of a stack at its dot. ``x [R, D]``, ``w [R, E]``
+    float32. Returns ``[R, D]`` float32. The CPU's path, the one under a
+    mesh, and what ``pallas/moe_visit_kernel.py`` is held equal to."""
     dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
 
     def visit(i, acc):
@@ -473,8 +454,42 @@ def _moe_mlp_hit(cfg: ModelConfig, blk, xm, stacks, layer, live):
         y = dot(h.astype(x.dtype), stacks["moe_down"][layer, e])
         return acc + jax.lax.dynamic_slice_in_dim(w, e, 1, axis=1) * y
 
-    out = jax.lax.fori_loop(0, visited, visit,
-                            jnp.zeros((B * T, D), jnp.float32))
+    return jax.lax.fori_loop(0, visited, visit,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def _moe_mlp_hit(cfg: ModelConfig, blk, xm, stacks, layer, live,
+                 use_pallas: str = "never"):
+    """``_moe_mlp`` for a step of few rows: only the experts some LIVE
+    row routed to are evaluated, so only their weights are read. Decode is
+    bound by expert bytes, and 8 rows x top-2 of 8 experts hit 7.2 of them
+    on average (4 / 2 / 1 rows: 5.5 / 3.5 / 2). Every term left out is a
+    product with a combine weight of exactly 0. Weights stay in their
+    dtype; gate, up and the combine accumulate in float32.
+
+    ``stacks`` are the STACKED expert weights ``[L, E, D, F]`` /
+    ``[L, E, F, D]`` and ``layer`` the scan's index: each visit reads
+    ``(layer, expert)`` in place, as one walk of a kernel over the hit
+    experts on a TPU (``pallas/moe_visit_kernel.py``) and as a slice that
+    XLA fuses into each dot elsewhere (``_visit_loop``), by
+    ``dispatch_pallas``'s one policy. The scan's
+    own slice ``blk["moe_gate"] [E, D, F]`` would be an operand of the
+    inner loop, and so a copy of 0.94 GB per matrix per layer.
+    ``live [B, T]`` marks the real rows: a padded or finished row routes
+    nowhere, so it makes no expert live and only the shared expert adds
+    to it. Returns (out ``[B, T, D]``, the number of experts visited)."""
+    from rbg_tpu.ops.paged_attention import dispatch_pallas
+    B, T, D = xm.shape
+    E = cfg.experts_here
+    x = xm.reshape(B * T, D)
+    w = jnp.where(live.reshape(B * T, 1),
+                  _held(cfg, _route(cfg, blk, xm)).reshape(B * T, E), 0)
+    w = w.astype(jnp.float32)
+    hit = jnp.any(w > 0, axis=0)                                # [E]
+    visited = jnp.sum(hit, dtype=jnp.int32)
+    ids = jnp.nonzero(hit, size=E, fill_value=0)[0].astype(jnp.int32)
+    out = dispatch_pallas(use_pallas, "moe_visit_pallas", _visit_loop,
+                          (x, w, stacks, layer, ids, visited))
     out = out.astype(xm.dtype).reshape(B, T, D)
     if cfg.moe_shared_expert:
         out = out + _shared_expert(blk, xm)
@@ -1066,7 +1081,8 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
             return _post_attention(g, blk, h, attn), flat, state, seen
         stacks = {k: params[mlp][k] for k in _EXPERT_STACKS}
         h, visited = _post_attention(
-            g, blk, h, attn, hit_experts=(stacks, n, addr.token_mask))
+            g, blk, h, attn,
+            hit_experts=(stacks, n, addr.token_mask, use_pallas))
         return h, flat, state, seen + visited
 
     def loop(kind, count, l0, carry):
@@ -1101,7 +1117,8 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
 
 def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
                  layers: Tuple[int, int], use_pallas: str = "auto", lora=None,
-                 lora_ids=None, experts_whole: bool = False):
+                 lora_ids=None, experts_whole: bool = False,
+                 sharded: bool = False):
     """The one walk over cache-bearing layers: layers ``[lo, hi)`` (static)
     over the hidden states ``x [B, T, D]`` entering layer ``lo``, writing and
     attending those layers' pages of the FULL pool, the tuple ``(k_pages,
@@ -1113,7 +1130,10 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
     a first decode step starts when the leading layers' KV has arrived.
     Returns (x, pool, visited): ``visited`` counts the experts each expert
     layer of the window visited where the hit-experts form ran
-    (``experts_whole`` and ``hit_experts_pay``), else None."""
+    (``experts_whole`` and ``hit_experts_pay``), else None. ``sharded``:
+    the parameters lie over a mesh, so the visits keep the XLA loop, whose
+    F the compiler splits (the kernel would need a ``shard_map`` of its
+    own)."""
     lo, hi = layers
     if cfg.recurrent:
         if (lo, hi) != (0, cfg.num_layers) or lora is not None:
@@ -1139,6 +1159,7 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
     # under everything the scan traces cost the server's warm-up 6 s of
     # CPU (CPython's 16 KiB frame chunks; PERF.md section 6, PR 32).
     visited = []
+    visit_policy = "never" if sharded else use_pallas
     for name, g, first, end in cfg.layer_groups:
         glo, ghi = max(lo, first), min(hi, end)
         if glo >= ghi:
@@ -1164,8 +1185,8 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
             # The stacks are the group's: its own layer index (no
             # subtraction where the group starts the model, so that a
             # one-group model's program is the one it was).
-            hit = ((stacks, li - first if first else li, addr.token_mask)
-                   if hit_only else None)
+            hit = ((stacks, li - first if first else li, addr.token_mask,
+                    visit_policy) if hit_only else None)
             out = _post_attention(g, blk, hcur, attn, lr, lora_ids, hit)
             out, seen = out if hit_only else (out, None)
             return (out, flat), seen
@@ -1204,6 +1225,7 @@ def forward_paged(
     experts_whole: bool = False,    # no mesh axis shards the expert dim
     state: Optional[dict] = None,   # recurrent layers: the state pool's arrays
     state_slots: Optional[jnp.ndarray] = None,   # [B] int32 slot per row
+    sharded: bool = False,          # the parameters lie over a mesh
 ):
     """Serving forward over the paged KV pool (prefill chunks and decode steps
     share this one traced program per (B, T) bucket). With scales, the pool
@@ -1224,7 +1246,7 @@ def forward_paged(
         PoolAddr(positions, token_mask, kv_lens, page_table,
                  state_slots=state_slots),
         layers=(0, cfg.num_layers), use_pallas=use_pallas, lora=lora,
-        lora_ids=lora_ids, experts_whole=experts_whole)
+        lora_ids=lora_ids, experts_whole=experts_whole, sharded=sharded)
     out = (_head(params, cfg, x), *pool)
     if experts_whole:
         out += (None if visited is None else visited.sum(),)

@@ -614,6 +614,22 @@ ATTENTION_INNER_SCOPES = ("kda", "conv")
 # layer's projections with its ``wo`` (``device.kda_proj_share``).
 ATTENTION_PART_SCOPES = ("gate", "kda/proj")
 
+# ---- kernel calls (device time by kernel) ----
+#
+# The Pallas kernels' jitted wrappers: a device trace prints each custom
+# call under its wrapper's name (``_moe_visit_call.<n>``), and the
+# benchmark's ``kernel.*`` metrics find it by that name
+# (docs/observability.md "Outlet 2"), so a rename is a change of a metric's
+# source. The ``_q`` forms (int8 pools) share their prefix.
+KERNEL_CALLS = (
+    "_decode_call",             # paged_attention_kernel: a decode walk
+    "_mla_decode_call",         # ... over latent pages
+    "_block_ragged_call",       # ragged_attention_kernel: a packed step
+    "_block_ragged_mla_call",   # ... over latent pages
+    "_kda_decode_call",         # kda_kernel: a decode step's delta rule
+    "_moe_visit_call",          # moe_visit_kernel: a decode step's visits
+)                               # to its hit experts, a call a layer
+
 # ---- jitted program catalog (jitwatch sentry + warmers) ----
 #
 # Same contract as the metric catalog: every named hot-path XLA program

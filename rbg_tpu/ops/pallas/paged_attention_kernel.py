@@ -375,3 +375,6 @@ from rbg_tpu.ops.pallas.ragged_attention_kernel import (  # noqa: E402,F401
 
 # The recurrent layers' decode kernel, resolved by the same dispatch.
 from rbg_tpu.ops.pallas.kda_kernel import kda_decode_pallas  # noqa: E402,F401
+
+# A decode step's walk over the hit experts, resolved by the same dispatch.
+from rbg_tpu.ops.pallas.moe_visit_kernel import moe_visit_pallas  # noqa: E402,F401

@@ -330,6 +330,9 @@ def test_step_programs_of_the_joyai_cell_fit_and_copy_no_expert_stack(
     assert "tpu_custom_call" in text
     assert ("_mla_decode_call" if program == "decode"
             else "_block_ragged_mla_call") in text
+    # a decode step's visits are the kernel's walk over the stacks (PR 43);
+    # a unified step dispatches densely
+    assert ("_moe_visit_call" in text) == (program == "decode")
     one_matrix = 256 * 2048 * 768 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix // 2
     assert eng.cache.v_pages.shape == (5, 8192, 16, 1, 128)
@@ -384,6 +387,7 @@ def test_step_programs_of_the_kimi_cell_fit_and_copy_neither_pool(
     assert "tpu_custom_call" in text
     assert ("_mla_decode_call" if program == "decode"
             else "_block_ragged_mla_call") in text
+    assert ("_moe_visit_call" in text) == (program == "decode")
     pools = {eng.cache.k_pages.size, eng.cache.v_pages.size,
              eng.state.arrays["s"].size, eng.state.arrays["conv"].size}
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
@@ -466,6 +470,7 @@ def test_step_programs_of_the_lfm2_cell_fit_and_copy_neither_pool(
     assert "tpu_custom_call" in text
     assert ("_decode_call" if program == "decode"
             else "_block_ragged_call") in text
+    assert ("_moe_visit_call" in text) == (program == "decode")
     pools = {eng.cache.k_pages.size, eng.state.arrays["tail"].size}
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
     assert not [dims for dims in copied if np.prod(
@@ -516,6 +521,7 @@ def test_step_programs_of_the_solar_cell_fit_and_copy_neither_pool(
     assert "tpu_custom_call" in text
     assert ("_decode_call" if program == "decode"
             else "_block_ragged_call") in text
+    assert ("_moe_visit_call" in text) == (program == "decode")
     # both: a unified step's rows of one token take the kernel too, and
     # its rows that hold a chunk are walked one a trip, so that no array
     # holds every row's state or every row's line (PR 42)
@@ -658,3 +664,66 @@ def test_hit_experts_read_the_stacked_weights_in_place(chip):
         S((L, E, D, F), BF16), S((L, E, D, F), BF16),
         S((L, E, F, D), BF16)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+# ---- the walk over the hit experts, at each cell's widths -------------------
+
+# cell: (rows, D, F, experts held, layers of the stacks): the benchmark's
+# five cells' ``[L, E, D, F]`` expert stacks as served.
+CELL_EXPERTS = {"mixtral": (8, 4096, 14336, 8, 3),
+                "joyai": (16, 2048, 768, 256, 4),
+                "kimi": (16, 2304, 1024, 16, 26),
+                "lfm2": (32, 2048, 1536, 8, 38),
+                "solar": (32, 4096, 1280, 20, 8)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_EXPERTS))
+def test_visit_kernel_compiles_at_the_cells_widths_in_place(chip, cell):
+    """``moe_visit_pallas`` inside a layer scan over the cell's stacked
+    expert weights: Mosaic takes the tile ``tile_f`` picks (a whole expert
+    of JoyAI, Kimi and LFM2, half of Solar's, a fourteenth of Mixtral's)
+    within the VMEM the call asks for, and the program holds no temporary
+    the size of an expert's matrix: the stacks are read where they lie."""
+    rows, D, F, E, L = CELL_EXPERTS[cell]
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+
+    def experts(x, w, ids, visited, gate, up, down):
+        stacks = {"moe_gate": gate, "moe_up": up, "moe_down": down}
+
+        def layer(h, li):
+            out = K.moe_visit_pallas(h, w, stacks, li, ids, visited)
+            return h + out.astype(h.dtype), None
+        return jax.lax.scan(layer, x, jnp.arange(L, dtype=I32))[0]
+
+    compiled = _compiles_with_kernel(
+        experts, S((rows, D), BF16), S((rows, E), F32), S((E,), I32),
+        S((), I32), S((L, E, D, F), BF16), S((L, E, D, F), BF16),
+        S((L, E, F, D), BF16))
+    assert "_moe_visit_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_decode_program_of_the_mixtral_cell_holds_the_visit_kernel(
+        chip, monkeypatch):
+    """``benchmark/configs/mixtral-8x7b-v0.1.json`` as served: 3 layers,
+    ``[3, 8, 4096, 14336]`` expert stacks, 8 rows. The fused decode program
+    holds the page walk's kernel and the experts' (14 tiles of 1024 a
+    visit), and no temporary the size of an expert's matrix (117 MB)."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from harness import serve
+    from rbg_tpu.models import config as presets
+    with open(os.path.join(bench, "configs", "mixtral-8x7b-v0.1.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setitem(presets._PRESETS, "mixtral-cell",
+                        serve.model_config(cfg, "mixtral-cell"))
+    eng = _abstract_engine(chip, monkeypatch, model="mixtral-cell",
+                           **cfg["server"])
+    assert eng.params["blocks"]["moe_gate"].shape == (3, 8, 4096, 14336)
+    text = (compiled := _compile_decode(chip, eng)).as_text()
+    assert "_decode_call" in text and "_moe_visit_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

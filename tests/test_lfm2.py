@@ -341,7 +341,8 @@ def interpreted(monkeypatch):
     """``use_pallas="always"`` off the chip: the kernels a served
     ``tiny-lfm2`` reaches, in interpret mode."""
     from rbg_tpu.ops.pallas import paged_attention_kernel as K
-    for name in ("paged_attention_pallas", "ragged_paged_attention_pallas"):
+    for name in ("paged_attention_pallas", "ragged_paged_attention_pallas",
+                 "moe_visit_pallas"):
         monkeypatch.setattr(K, name, functools.partial(getattr(K, name),
                                                        interpret=True))
 

@@ -235,3 +235,148 @@ def test_hit_experts_form_under_a_tp_mesh(moe_setup):
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                rtol=1e-4, atol=1e-4)
     assert int(got[5]) == int(want[5])
+
+
+# ---- the visits as one kernel walk (ops/pallas/moe_visit_kernel.py) ----------
+
+
+def _lane_toy(E, K, shared, held=None, dtype="float32"):
+    """A toy whose expert is two lane tiles wide (D 128, F 256), so that a
+    walk in tiles of 128 has two steps a visit."""
+    import dataclasses
+    return dataclasses.replace(
+        _toy(E, K, shared), name=f"lane-e{E}k{K}", hidden_size=128,
+        num_heads=2, moe_intermediate_size=256, experts_held=held,
+        dtype=dtype)
+
+
+VISIT_CASES = {
+    # name: (E, K, shared expert, experts held, dtype, the kernel's tile of F
+    #        (None: ``tile_f``'s, the whole 256), picks per row (None: as the
+    #        weights fall), live rows (None: all), experts visited)
+    "f32-one-tile-rows3": (8, 2, False, None, "float32", None,
+                           [None] * 3, None, None),
+    "f32-two-tiles-rows3": (8, 2, False, None, "float32", 128,
+                            [None] * 3, None, None),
+    "bf16-one-tile-rows5": (8, 2, False, None, "bfloat16", None,
+                            [None] * 5, None, None),
+    "bf16-two-tiles-rows16": (16, 2, False, None, "bfloat16", 128,
+                              [None] * 16, None, None),
+    "bf16-two-tiles-a-dead-row": (
+        8, 2, False, None, "bfloat16", 128,
+        [(0, 1), (1, 2), (6, 7)], [True, True, False], 3),
+    "f32-no-row-live": (8, 2, False, None, "float32", None,
+                        [(0, 1)] * 2, [False] * 2, 0),
+    "bf16-two-tiles-no-row-live": (8, 2, False, None, "bfloat16", 128,
+                                   [(0, 1)] * 2, [False] * 2, 0),
+    "f32-every-expert-hit": (
+        8, 2, False, None, "float32", 128,
+        [(2 * r % 8, (2 * r + 1) % 8) for r in range(8)], None, 8),
+    "f32-one-expert-hit": (8, 1, False, None, "float32", None,
+                           [(6,)] * 4, None, 1),
+    # The router picks among the published 16; the device holds 4..11.
+    "f32-held-a-sub-range": (16, 4, False, (4, 12), "float32", 128,
+                             [(3, 4, 7, 12), (5, 7, 11, 15)], None, 4),
+    "bf16-held-a-sub-range-shared": (16, 4, True, (4, 12), "bfloat16", None,
+                                     [None] * 6, None, None),
+    "f32-shared-beside-it": (16, 4, True, None, "float32", 128,
+                             [None] * 4, None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(VISIT_CASES))
+def test_the_visit_kernel_equals_the_xla_loop(case, monkeypatch):
+    """``_moe_mlp_hit`` by the kernel (``use_pallas="always"``, interpreted)
+    against the XLA loop: the kernel is handed the loop's own arguments, its
+    float32 sums equal the loop's within a sum's reordering, the layer's
+    output within a rounding of the activations' dtype, the count of
+    experts visited to the expert; a call without a live row gives zeros."""
+    from rbg_tpu.models import llama
+    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    E, Kt, shared, held, dtype, tile, picks, live, want_visited = \
+        VISIT_CASES[case]
+    cfg = _lane_toy(E, Kt, shared, held, dtype)
+    assert llama.hit_experts_pay(cfg, len(picks))
+    params = init_params(cfg, jax.random.key(5))
+    blk, xm = _routed(cfg, params, picks)
+    xm = xm.astype(cfg.jax_dtype)
+    live = jnp.asarray([True] * len(picks) if live is None else live)[:, None]
+    stacks = {k: params["blocks"][k] for k in llama._EXPERT_STACKS}
+    assert stacks["moe_gate"].shape[1] == cfg.experts_here
+
+    real, calls = K.moe_visit_pallas, []
+
+    def spy(*args):
+        calls.append((args, real(*args, interpret=True, tile=tile)))
+        return calls[-1][1]
+
+    monkeypatch.setattr(K, "moe_visit_pallas", spy)
+    want, seen = llama._moe_mlp_hit(cfg, blk, xm, stacks, jnp.int32(1), live,
+                                    "never")
+    assert not calls
+    got, visited = llama._moe_mlp_hit(cfg, blk, xm, stacks, jnp.int32(1),
+                                      live, "always")
+    (args, summed), = calls
+    assert summed.dtype == jnp.float32 and int(visited) == int(seen)
+    if want_visited is not None:
+        assert int(visited) == want_visited
+    loop = np.asarray(llama._visit_loop(*args))
+    size = np.abs(loop).max()
+    assert (size > 0) == (int(visited) > 0)
+    np.testing.assert_allclose(np.asarray(summed), loop, rtol=1e-5,
+                               atol=1e-5 * size)
+    ulp = 2.0 ** -7 if dtype == "bfloat16" else 1e-5
+    out = np.asarray(want, np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32), out,
+                               rtol=ulp, atol=ulp * np.abs(out).max())
+    if not shared:
+        assert not np.asarray(got, np.float32)[~np.asarray(live)[:, 0]].any()
+
+
+def test_served_tokens_are_equal_by_the_visit_kernel_and_by_the_loop(
+        moe_setup, monkeypatch):
+    """A served ``tiny-moe``, greedy: the same tokens with the decode
+    steps' visits by the kernel (``use_pallas="always"``, every kernel the
+    model reaches interpreted) and by the XLA loop, and the same count of
+    experts visited."""
+    import functools
+    from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
+    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    cfg, params = moe_setup
+    walked = []
+    for name in ("paged_attention_pallas", "ragged_paged_attention_pallas",
+                 "moe_visit_pallas"):
+        def interpreted(*args, _real=getattr(K, name), _name=name):
+            walked.append(_name)
+            return _real(*args, interpret=True)
+        monkeypatch.setattr(K, name, interpreted)
+    prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8, 2, 8]]
+    served = {}
+    for policy in ("never", "always"):
+        eng = Engine(EngineConfig(model="tiny-moe", page_size=8, num_pages=64,
+                                  max_seq_len=128, prefill_chunk=16,
+                                  use_pallas=policy), params=params)
+        assert "moe_visit_pallas" not in walked
+        served[policy] = (
+            eng.generate(prompts, SamplingParams(max_new_tokens=8)),
+            eng.metrics["moe_experts_visited"])
+        assert served[policy][1] > 0
+    assert "moe_visit_pallas" in walked
+    assert served["always"] == served["never"]
+
+
+def test_the_kernel_calls_are_named_as_the_catalog_says():
+    """``obs/names.py::KERNEL_CALLS``: each is the jitted wrapper of a
+    Pallas kernel in ``ops/pallas`` under that very name, which is what a
+    device trace prints and the benchmark's ``kernel.*`` metrics read."""
+    from rbg_tpu.obs.names import KERNEL_CALLS
+    from rbg_tpu.ops.pallas import (kda_kernel, moe_visit_kernel,
+                                    paged_attention_kernel,
+                                    ragged_attention_kernel)
+    modules = (paged_attention_kernel, ragged_attention_kernel, kda_kernel,
+               moe_visit_kernel)
+    assert "_moe_visit_call" in KERNEL_CALLS
+    for name in KERNEL_CALLS:
+        owners = [m for m in modules if name in vars(m)]
+        assert owners, name
+        assert getattr(owners[0], name).__name__ == name

@@ -316,7 +316,7 @@ def interpreted(monkeypatch):
     ``tiny-solar-open2`` reaches, in interpret mode."""
     from rbg_tpu.ops.pallas import paged_attention_kernel as K
     for name in ("kda_decode_pallas", "paged_attention_pallas",
-                 "ragged_paged_attention_pallas"):
+                 "ragged_paged_attention_pallas", "moe_visit_pallas"):
         monkeypatch.setattr(K, name, functools.partial(getattr(K, name),
                                                        interpret=True))
 

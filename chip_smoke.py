@@ -181,6 +181,7 @@ def child_kernels(args) -> None:
                                            ragged_paged_mla_attention_xla)
     from rbg_tpu.ops.paged_attention import paged_attention_xla, quantize_kv
     from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    from rbg_tpu.ops.pallas import ragged_attention_kernel as RK
     from rbg_tpu.ops.ragged_paged_attention import ragged_paged_attention_xla
 
     interpret = args.rehearse
@@ -231,9 +232,9 @@ def child_kernels(args) -> None:
             rag = (table, qpos, kv_lens, row_ids)
             dec = (table, dec_pos, kv_lens)
             label = f"[{width}]"
-            check(K.ragged_paged_attention_pallas, ragged_paged_attention_xla,
+            check(RK.ragged_paged_attention_pallas, ragged_paged_attention_xla,
                   label, case, (q_rag, k, v, *rag), packed)
-            check(K.ragged_paged_attention_pallas_q,
+            check(RK.ragged_paged_attention_pallas_q,
                   ragged_paged_attention_xla,
                   label, case, (q_rag, kq, vq, *rag, ks, vs), packed)
             check(K.paged_attention_pallas, paged_attention_xla,
@@ -249,10 +250,10 @@ def child_kernels(args) -> None:
         (cq, cs), (pq, ps) = quantize_kv(c), quantize_kv(pe)
         rag = (table, qpos, kv_lens, row_ids, scale)
         dec = (table, dec_pos, kv_lens, scale)
-        check(K.ragged_paged_mla_attention_pallas,
+        check(RK.ragged_paged_mla_attention_pallas,
               ragged_paged_mla_attention_xla,
               "", case, (ql_rag, qp_rag, c, pe, *rag), packed)
-        check(K.ragged_paged_mla_attention_pallas_q,
+        check(RK.ragged_paged_mla_attention_pallas_q,
               ragged_paged_mla_attention_xla,
               "", case, (ql_rag, qp_rag, cq, pq, *rag, cs, ps), packed)
         check(K.paged_mla_attention_pallas, paged_mla_attention_xla,

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from rbg_tpu.models.config import ModelConfig
 from rbg_tpu.ops.attention import gqa_attention
 from rbg_tpu.ops.norms import rms_norm
+from rbg_tpu.ops.pallas import dispatch_pallas
 from rbg_tpu.ops.rope import apply_rope
 
 
@@ -478,7 +479,6 @@ def _moe_mlp_hit(cfg: ModelConfig, blk, xm, stacks, layer, live,
     ``live [B, T]`` marks the real rows: a padded or finished row routes
     nowhere, so it makes no expert live and only the shared expert adds
     to it. Returns (out ``[B, T, D]``, the number of experts visited)."""
-    from rbg_tpu.ops.paged_attention import dispatch_pallas
     B, T, D = xm.shape
     E = cfg.experts_here
     x = xm.reshape(B * T, D)

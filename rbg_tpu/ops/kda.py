@@ -59,6 +59,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from rbg_tpu.ops.pallas import dispatch_pallas
 from rbg_tpu.ops.short_conv import causal_conv
 
 SUB = 16            # tokens of a sub-chunk
@@ -110,7 +111,6 @@ def kda_decode(q, k, v, g, b, pool, layer, slots, fresh, *,
     live row's state once, in place (``pallas/kda_kernel.py``), or
     ``kda_step_in_pool``, by the one policy (``dispatch_pallas``). The
     kernel leaves zeros in a padding row's lines of ``o``."""
-    from rbg_tpu.ops.paged_attention import dispatch_pallas
     return dispatch_pallas(use_pallas, "kda_decode_pallas", kda_step_in_pool,
                            (q, k, v, g, b, pool, layer, slots, fresh))
 

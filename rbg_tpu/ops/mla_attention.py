@@ -29,6 +29,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from rbg_tpu.ops.pallas import dispatch_pallas
+
 _NEG_INF = -1e30
 
 
@@ -111,7 +113,6 @@ def paged_mla_attention(q_lat, q_pe, c_pages, pe_pages, page_table,
     route to the ``_q`` kernel, which folds the per-slot scales
     algebraically like the GQA dequant variant — ``use_pallas='always'``
     + int8 is a working path (the round-2 seam closure)."""
-    from rbg_tpu.ops.paged_attention import dispatch_pallas
     if c_scales is not None:
         return dispatch_pallas(
             use_pallas, "paged_mla_attention_pallas_q",
@@ -169,7 +170,6 @@ def ragged_paged_mla_attention(q_lat, q_pe, c_pages, pe_pages, page_table,
     """Dispatch the ragged MLA latent path: block-ragged Pallas kernel
     over the ``c/pe`` pools vs the XLA unpack/repack fallback — the seam
     that lets ``_unified_step()`` drop its ``mcfg.mla`` exclusion."""
-    from rbg_tpu.ops.paged_attention import dispatch_pallas
 
     def xla_fn(*args):
         return ragged_paged_mla_attention_xla(*args, max_q_len=max_q_len)

@@ -16,8 +16,9 @@ Two implementations behind one signature:
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
+
+from rbg_tpu.ops.pallas import dispatch_pallas
 
 _NEG_INF = -1e30
 
@@ -114,18 +115,6 @@ def write_kv_pages(k_pages, v_pages, k_new, v_new, page_table, positions,
     v_pages = v_pages.at[phys, slot].set(_as_stored(v_new, v_pages),
                                          mode="drop")
     return k_pages, v_pages, None, None
-
-
-def dispatch_pallas(use_pallas: str, kernel_name: str, xla_fn, args):
-    """The ONE kernel-vs-XLA dispatch policy (GQA and MLA both use it):
-    'always' takes the kernel everywhere, 'auto' takes it on a TPU and
-    the XLA path on any other backend, 'never' the XLA path. A kernel
-    that cannot be imported is an error, never a reason to run XLA."""
-    if use_pallas == "always" or (use_pallas == "auto"
-                                  and jax.default_backend() == "tpu"):
-        from rbg_tpu.ops.pallas import paged_attention_kernel as K
-        return getattr(K, kernel_name)(*args)
-    return xla_fn(*args)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_positions, kv_lens,

@@ -359,22 +359,3 @@ def paged_mla_attention_pallas_q(q_lat, q_pe, c_pages, pe_pages, page_table,
                              scale=float(scale), interpret=interpret)
     return out[:, None]
 
-
-# ---- ragged (mixed prefill/decode) kernels ---------------------------------
-#
-# Re-exported here because ``dispatch_pallas`` resolves every kernel name
-# against this module; the implementations live in
-# ragged_attention_kernel.py (block-ragged tile grid).
-
-from rbg_tpu.ops.pallas.ragged_attention_kernel import (  # noqa: E402,F401
-    ragged_paged_attention_pallas,
-    ragged_paged_attention_pallas_q,
-    ragged_paged_mla_attention_pallas,
-    ragged_paged_mla_attention_pallas_q,
-)
-
-# The recurrent layers' decode kernel, resolved by the same dispatch.
-from rbg_tpu.ops.pallas.kda_kernel import kda_decode_pallas  # noqa: E402,F401
-
-# A decode step's walk over the hit experts, resolved by the same dispatch.
-from rbg_tpu.ops.pallas.moe_visit_kernel import moe_visit_pallas  # noqa: E402,F401

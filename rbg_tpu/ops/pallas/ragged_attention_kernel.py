@@ -45,7 +45,7 @@ kernel (the engine's fused multi-step path owns them).
 Same family of int8 variants as the decode kernel: scales fold
 algebraically into scores/probs, pages feed the MXU as int8. The MLA
 (latent) ragged kernels live here too — same tiling over the ``c/pe``
-pools, re-exported via paged_attention_kernel for ``dispatch_pallas``.
+pools.
 """
 
 from __future__ import annotations

@@ -45,8 +45,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from rbg_tpu.ops.paged_attention import (_as_stored, dispatch_pallas,
-                                         paged_attention_xla, quantize_kv)
+from rbg_tpu.ops.paged_attention import (_as_stored, paged_attention_xla,
+                                         quantize_kv)
+from rbg_tpu.ops.pallas import dispatch_pallas
 
 
 def _unpack_offsets(row_ids: jnp.ndarray) -> jnp.ndarray:

@@ -25,6 +25,10 @@ positions, the worst request's own median):
   layers' output gate dropped, the delta rule's write strength not scaled,
   attention without positions rotated.
 
+The serving loop, the faults and ``left_out`` are ``tests/model_contract.py``'s:
+the controls tier-1 holds every tiny configuration to are the ones run here
+(that module imports ``pytest``, so the machine that runs this needs it).
+
 A limit lies between the largest ``sound`` and the smallest control
 (``PERF.md`` section 2). Nothing here is a timing.
 """
@@ -37,7 +41,8 @@ import statistics
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark")]
+sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmark"),
+                os.path.join(ROOT, "tests")]
 
 import numpy as np  # noqa: E402
 
@@ -53,18 +58,6 @@ def sample(cfg, seed):
     return [a], rest
 
 
-def serve_all(eng, prompts, new):
-    from rbg_tpu.engine import SamplingParams
-    ids = [eng.add_request(p, SamplingParams(max_new_tokens=new,
-                                             logprobs=True)) for p in prompts]
-    out = {i: ([], []) for i in ids}
-    while eng.has_work():
-        for ev in eng.step():
-            out[ev.request_id][0].append(ev.token)
-            out[ev.request_id][1].append(ev.logprob)
-    return [out[i] for i in ids]
-
-
 def numbers(diffs):
     """(median, 75th percentile, worst request's median) of per-request
     lists of absolute differences."""
@@ -72,41 +65,6 @@ def numbers(diffs):
     return (statistics.median(pooled),
             float(np.percentile(pooled, 75)),
             max(statistics.median(ds) for ds in diffs))
-
-
-def patched(fault):
-    """The model code's recurrent mixers with ``fault`` in them."""
-    import jax.numpy as jnp
-    from rbg_tpu.models import llama
-
-    def breaker(real):
-        def broken(g, blk, x, state, layer, addr, use_pallas):
-            pos = addr.positions
-            if fault == "not carried":
-                if x.shape[1] > 1:      # every chunk of a prompt looks first
-                    pos = pos - pos[..., :1] if addr.row_ids is None else \
-                        jnp.where(addr.token_mask, 0, pos)
-            else:
-                pos = jnp.where(pos == 0, 1 << 20, pos)   # never looks first
-            return real(g, blk, x, state, layer,
-                        addr._replace(positions=pos), use_pallas)
-        return broken
-
-    return {name: breaker(getattr(llama, name))
-            for name in ("_kda_attention", "_conv_attention")}
-
-
-def left_out(m) -> dict:
-    """``{fault: ModelConfig fields}``: the preset ``m`` with one of the
-    rules it sets left out."""
-    rules = {}
-    if m.attn_gate:
-        rules["gate dropped"] = dict(attn_gate=False)
-    if m.kda_beta_scale != 1.0:
-        rules["b not scaled"] = dict(kda_beta_scale=1.0)
-    if not m.use_rope and not m.mla:
-        rules["rotated"] = dict(use_rope=True)
-    return rules
 
 
 def main(argv=None) -> int:
@@ -118,17 +76,17 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    from harness import serve
+    from harness import serve as harness
+    from model_contract import FAULTS, faulty, left_out, serve
     from rbg_tpu.engine import Engine, EngineConfig
     from rbg_tpu.models import config as presets
-    from rbg_tpu.models import llama
     from rbg_tpu.utils.chipenv import configure_compile_cache
     configure_compile_cache()
     with open(os.path.join(ROOT, args.config)) as f:
         cfg = json.load(f)
     name = "correct-readings"
-    presets._PRESETS[name] = serve.model_config(cfg, name)
-    reference = serve.load_reference(cfg)
+    presets._PRESETS[name] = harness.model_config(cfg, name)
+    reference = harness.load_reference(cfg)
     new = cfg["correct"]["new_tokens"]
     print(f"device: {jax.devices()[0].platform} "
           f"{jax.devices()[0].device_kind}", flush=True)
@@ -158,7 +116,7 @@ def main(argv=None) -> int:
         eng = engine(params)
         for p in args.prompts:
             alone, rest = sample(cfg, p)
-            served = serve_all(eng, alone, new) + serve_all(eng, rest, new)
+            served = serve(eng, alone, new) + serve(eng, rest, new)
             say("sound", w, p, against_reference(params, alone + rest,
                                                  served))
             say("int8", w, p, against_reference(params, alone + rest, served,
@@ -171,32 +129,23 @@ def main(argv=None) -> int:
                 presets._PRESETS[name], name=name + "-fault", **fields)
             eng = engine(params, name + "-fault")
             alone, rest = sample(cfg, args.prompts[0])
-            served = serve_all(eng, alone, new) + serve_all(eng, rest, new)
+            served = serve(eng, alone, new) + serve(eng, rest, new)
             say(fault, w, args.prompts[0],
                 against_reference(params, alone + rest, served))
             del eng
         if not presets._PRESETS[name].recurrent:
             continue
-        for fault in ("not carried", "not zeroed"):
-            real = {n: getattr(llama, n) for n in patched(fault)}
-            for n, fn in patched(fault).items():
-                setattr(llama, n, fn)
-            jax.clear_caches()      # the mixers' own programs hold the real
-            try:
+        for fault in FAULTS:
+            with faulty(fault):
                 eng = engine(params)
                 # another sample first, so that every slot has been used
                 other = sample(cfg, args.prompts[0] + 1000)
-                serve_all(eng, other[0], new)
-                serve_all(eng, other[1], new)
+                serve(eng, other[0], new)
+                serve(eng, other[1], new)
                 alone, rest = sample(cfg, args.prompts[0])
-                served = serve_all(eng, alone, new) + serve_all(eng, rest,
-                                                                new)
+                served = serve(eng, alone, new) + serve(eng, rest, new)
                 say(fault, w, args.prompts[0],
                     against_reference(params, alone + rest, served))
-            finally:
-                for n, fn in real.items():
-                    setattr(llama, n, fn)
-                jax.clear_caches()
                 del eng
     return 0
 

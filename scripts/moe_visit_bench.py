@@ -208,7 +208,7 @@ def main():
                 fn, fargs = case(name, form, args.seed)
                 swap = None
                 if stand_in is not None:
-                    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+                    from rbg_tpu.ops.pallas import moe_visit_kernel as K
                     where = (K, "moe_visit_pallas") if policy == "always" \
                         else (llama, "_visit_loop")
                     swap = (*where, getattr(*where))

@@ -16,11 +16,28 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import pytest  # noqa: E402
 
+# not collected, so not rewritten unless asked: a contract test that fails
+# then shows the RMS and the limit it held it to
+pytest.register_assert_rewrite("model_contract", "kda_packed_case")
+
 
 @pytest.fixture(scope="session")
 def mesh8():
     from rbg_tpu.parallel import make_mesh
     return make_mesh(dp=2, sp=2, tp=2)
+
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    """``use_pallas="always"`` off the chip: every kernel
+    ``dispatch_pallas`` can take (``rbg_tpu.ops.pallas.KERNELS``), in
+    interpret mode. The one seam: a test names no kernel."""
+    import functools
+
+    from rbg_tpu.ops import pallas
+    for name in pallas.KERNELS:
+        monkeypatch.setattr(pallas.home(name), name, functools.partial(
+            pallas.kernel(name), interpret=True))
 
 
 class SpawnedEngineServer:

@@ -1,7 +1,9 @@
 """A packed step of one delta-rule layer against the recurrence, row by row.
 
 Shared by ``test_kimi_linear.py`` (32 heads, ``b`` < 1) and
-``test_solar_open2.py`` (64 heads, ``b`` up to 2): the step's rows are
+``test_solar_open2.py`` (64 heads, ``b`` up to 2), with the delta rule's
+inputs (``kda_inputs``) and a step program's jaxpr (``step_jaxpr``) that
+both files' cases start from: the step's rows are
 packed on one token axis as ``Engine._pack_unified`` packs them (a row's
 tokens side by side, padding at the end of the token bucket and of the row
 bucket), ``llama._kda_packed`` walks them, and each row is held to
@@ -15,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from rbg_tpu.engine.kvcache import PagedKVCache, StatePool
 from rbg_tpu.models import get_config, llama
 from rbg_tpu.ops import kda
 
@@ -38,6 +41,59 @@ STEPS = {
     "a short chunk at the end of the bucket": (4, 32, 16, [(16, 0), (1, 0),
                                                            (15, 1)]),
 }
+
+
+def kda_inputs(R, C, H, dk, lens, seed=0, b_scale=1.0, b_spread=1.0):
+    """``(q, k, v, g, b, S)`` of ``[R, C, H, dk]`` tokens with unit keys, and
+    which of them are real: a row's first ``lens[r]``, the padding after
+    them with ``g = 0`` and ``b = 0`` as ``_kda_attention`` masks them.
+    ``b = b_scale sigmoid(b_spread n)``, ``n`` normal."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    q = jax.random.normal(ks[0], (R, C, H, dk))
+    k = jax.random.normal(ks[1], (R, C, H, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (R, C, H, dk))
+    g = -jax.random.uniform(ks[3], (R, C, H, dk)) * 0.7
+    b = b_scale * jax.nn.sigmoid(
+        b_spread * jax.random.normal(ks[4], (R, C, H)))
+    real = jnp.arange(C)[None] < jnp.asarray(lens)[:, None]
+    g = jnp.where(real[..., None, None], g, 0.0)
+    b = jnp.where(real[..., None], b, 0.0)
+    S = jax.random.normal(ks[5], (R, H, dk, dk))
+    return (q, k, v, g, b, S), np.asarray(real)
+
+
+def step_jaxpr(cfg, params, program, R=4, use_pallas="auto"):
+    """The jaxpr of a recurrent model's decode step (by row, the hit
+    experts' form) or its ragged step (packed), ``R`` rows."""
+    T = 1 if program == "decode" else 16
+    cache, pool = PagedKVCache.create(cfg, 64, 8), StatePool(cfg, R)
+    I32 = jnp.int32
+    table = jnp.zeros((R, 8), I32)
+    slots = {"state": pool.arrays, "state_slots": jnp.arange(R, dtype=I32),
+             "use_pallas": use_pallas}
+    if program == "decode":
+        fn = functools.partial(llama.forward_paged, params, cfg,
+                               experts_whole=True, **slots)
+        args = (jnp.ones((R, T), I32), jnp.zeros((R, T), I32),
+                jnp.ones((R, T), bool), jnp.ones(R, I32), table)
+    else:
+        fn = functools.partial(llama.forward_ragged, params, cfg,
+                               max_q_len=T, **slots)
+        args = (jnp.ones((1, T), I32), jnp.zeros((1, T), I32),
+                jnp.ones((1, T), bool), jnp.zeros(T, I32),
+                jnp.full(R, T, I32), table)
+    return jax.make_jaxpr(fn)(*args, cache.k_pages, cache.v_pages).jaxpr
+
+
+def eqns(jaxpr, in_loop=False):
+    """Every equation of ``jaxpr`` at any depth, each with whether it sits
+    inside the body of a ``while`` or a ``scan``."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_loop
+        inner = in_loop or eqn.primitive.name in ("while", "scan")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from eqns(sub, inner)
 
 
 def packed_step(name, heads, b_scale, seed=0):
@@ -121,15 +177,26 @@ def assert_rows_equal_the_recurrence(name, heads, b_scale, use_pallas,
 def inside_the_mixer(jaxpr):
     """What a step program's ``_kda_mixer`` holds, at any depth: (the
     shapes of its float32 values, the names of its primitives)."""
-    def eqns(jaxpr):
-        for e in jaxpr.eqns:
-            yield e
-            for sub in jax.core.jaxprs_in_params(e.params):
-                yield from eqns(sub)
-
-    mixer = next(e for e in eqns(jaxpr)
+    mixer = next(e for e, _ in eqns(jaxpr)
                  if e.params.get("name") == "_kda_mixer")
-    inside = list(eqns(mixer.params["jaxpr"].jaxpr))
+    inside = [e for e, _ in eqns(mixer.params["jaxpr"].jaxpr)]
     shapes = {v.aval.shape for e in inside for v in e.outvars
               if getattr(v.aval, "dtype", None) == jnp.float32}
     return shapes, {e.primitive.name for e in inside}
+
+
+def assert_no_line_and_no_state_for_every_row(cfg, params):
+    """A delta-rule model's unified program with the kernel in holds no
+    float32 array of ``[R, C, H, dk]`` (every row's line) or ``[R, H, dk,
+    dv]`` (every row's state), which the decode step by row, in plain XLA,
+    has; it has the kernel, and a loop over the rows that hold a chunk, a
+    row a trip (``[1, C, H, dk]``)."""
+    R, C, H, dk = 5, 16, cfg.kda_num_heads, cfg.kda_head_dim
+    wide = {(R, C, H, dk), (R, H, dk, dk)}
+    shapes, names = inside_the_mixer(
+        step_jaxpr(cfg, params, "ragged", R, use_pallas="always"))
+    assert not shapes & wide and (1, C, H, dk) in shapes
+    assert {"while", "pallas_call"} <= names
+    shapes, names = inside_the_mixer(
+        step_jaxpr(cfg, params, "decode", R, use_pallas="never"))
+    assert (R, H, dk, dk) in shapes and "pallas_call" not in names

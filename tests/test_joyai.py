@@ -2,45 +2,28 @@
 layers, latent attention with a low-rank query and interleaved rotary pairs,
 sigmoid routing with a selection bias and a scaling factor, a shared expert.
 
-The served path (chunked prefill, then decode through the pool; packed and
-by row; the hit-experts form and the dense dispatch) is held to the
-benchmark's plain reference of the architecture
-(``benchmark/references/joyai_flash.py``, which shares no code with the
-program), and each new rule to its definition."""
+Each new rule is held to its definition here; the served path against the
+benchmark's plain reference of the architecture is the contract every model
+is a case of (``model_contract.py``, ``test_joyai_contract.py``)."""
 
 import dataclasses
-import json
-import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
+from rbg_tpu.engine import Engine, EngineConfig
 from rbg_tpu.models import get_config, init_params
 from rbg_tpu.models import llama
-from rbg_tpu.models.llama import (KVCache, _EXPERT_STACKS, _mla_qkv, _moe_mlp,
-                                  _moe_mlp_hit, _route, forward,
-                                  forward_train)
+from rbg_tpu.models.llama import (_EXPERT_STACKS, _mla_qkv, _moe_mlp,
+                                  _moe_mlp_hit, _route)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
-if BENCH not in sys.path:
-    sys.path.append(BENCH)          # the benchmark's ``harness`` package
+from model_contract import read
 
 CFG = get_config("tiny-joyai")
 PARAMS = init_params(CFG, jax.random.key(0))
-TINY_FILE = os.path.join(BENCH, "tests", "rehearse", "configs",
-                         "tiny-joyai.json")
-CELL_FILE = os.path.join(BENCH, "configs", "joyai-llm-flash.json")
-
-
-def _load(path):
-    with open(path) as f:
-        return json.load(f)
+CELL_FILE = "configs", "joyai-llm-flash.json"
 
 
 def _expert_block(layer=0):
@@ -74,12 +57,6 @@ def test_a_dense_layer_before_expert_layers_is_two_groups():
     assert [(n, lo, hi) for n, _, lo, hi in one.layer_groups] == [
         ("blocks", 0, 2)]
     assert one.layer_groups[0][1] is one
-
-
-def test_num_params_counts_what_init_makes():
-    real = sum(int(np.prod(v.shape))
-               for v in jax.tree_util.tree_leaves(PARAMS))
-    assert CFG.num_params == real
 
 
 def test_param_specs_match_the_parameters():
@@ -192,7 +169,7 @@ def test_the_programs_init_draws_the_selection_bias_as_the_cell_does():
     these tests) routes like the measured cell: the bias moves the chosen
     set at most positions and leaves routing near uniform. At the first
     runs' 0.1 it concentrated routing on the favoured experts."""
-    want = _load(CELL_FILE)["assumed"]["e_score_correction_bias_scale"]
+    want = read(*CELL_FILE)["assumed"]["e_score_correction_bias_scale"]
     wide = dataclasses.replace(CFG, num_experts=256, experts_per_token=8)
     blk = jax.tree_util.tree_map(
         lambda a: a[0], init_params(wide, jax.random.key(1))["blocks"])
@@ -266,139 +243,12 @@ def test_absorbed_low_rank_query_equals_the_materialised_form():
                                rtol=1e-5, atol=1e-6)
 
 
-# ---- the served path against the benchmark's reference ----------------------
-
-
-@pytest.fixture(scope="module")
-def bench():
-    from harness import serve
-    cfg = _load(TINY_FILE)
-    reference = serve.load_reference(cfg)
-    params = reference.make_params(cfg, 3000000019)
-    from rbg_tpu.models import config as presets
-    presets._PRESETS["tiny-joyai-file"] = serve.model_config(
-        cfg, "tiny-joyai-file")
-    return cfg, reference, params
-
-
-def _served(cfg, params, prompts, new, **kw):
-    eng = Engine(EngineConfig(model="tiny-joyai-file", **cfg["server"], **kw),
-                 params=params)
-    ids = [eng.add_request(p, SamplingParams(max_new_tokens=new,
-                                             logprobs=True)) for p in prompts]
-    out = {i: ([], []) for i in ids}
-    while eng.has_work():
-        for ev in eng.step():
-            out[ev.request_id][0].append(ev.token)
-            out[ev.request_id][1].append(ev.logprob)
-    return [out[i] for i in ids], eng
-
-
-def _rms(a, b):
-    d = np.asarray(a, np.float64) - np.asarray(b, np.float64)
-    return math.sqrt(float(np.mean(d * d)))
-
-
-def test_the_file_reaches_the_preset_the_tests_use(bench):
-    from rbg_tpu.models import config as presets
-    got = dataclasses.replace(presets._PRESETS["tiny-joyai-file"],
-                              name="tiny-joyai", max_seq_len=256)
-    assert got == CFG
-
-
-@pytest.mark.parametrize("ragged,hit", [("auto", True), ("off", True),
-                                        ("auto", False), ("off", False)],
-                         ids=["packed-hit", "rows-hit", "packed-dense",
-                              "rows-dense"])
-def test_served_path_agrees_with_the_plain_reference(bench, monkeypatch,
-                                                     ragged, hit):
-    """Three prompts side by side, the longest of three prefill chunks:
-    chunked prefill (packed with the other rows' decode steps, or by row),
-    then decode through the pool, the experts in the hit form or densely
-    dispatched."""
-    cfg, reference, params = bench
-    if not hit:
-        monkeypatch.setattr(llama, "hit_experts_pay", lambda c, rows: False)
-    rng = np.random.default_rng(1)
-    prompts = [rng.integers(1, cfg["vocab_size"], n).tolist()
-               for n in (80, 23, 40)]
-    served, eng = _served(cfg, params, prompts, 8, ragged=ragged)
-    assert (eng.metrics["moe_experts_visited"] > 0) == hit
-    for prompt, (toks, lps) in zip(prompts, served):
-        assert len(toks) == 8
-        ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-        assert _rms(lps, ref) <= cfg["correct"]["limit"]
-
-
-def test_the_controls_fail_the_tiny_limits(bench):
-    cfg, reference, params = bench
-    limit = cfg["correct"]["limit"]
-    prompt = np.random.default_rng(2).integers(1, cfg["vocab_size"],
-                                               80).tolist()
-    [(toks, lps)], _ = _served(cfg, params, [prompt], 8)
-    ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-    assert _rms(lps, ref) <= limit
-    for quant in ("bf16", "int8", "fp8"):
-        ctl = reference.chosen_logprobs(cfg, params, prompt, toks, quant)
-        assert _rms(ctl, ref) > 3 * limit, quant
-    # the selection bias dropped: other experts at most positions
-    flat = dict(params, blocks=dict(
-        params["blocks"],
-        router_bias=jnp.zeros_like(params["blocks"]["router_bias"])))
-    ctl = reference.chosen_logprobs(cfg, flat, prompt, toks)
-    assert _rms(ctl, ref) > 30 * limit
-    # the program's own int8 cache
-    [(toks8, lps8)], _ = _served(cfg, params, [prompt], 8, kv_dtype="int8")
-    ref8 = reference.chosen_logprobs(cfg, params, prompt, toks8)
-    assert _rms(lps8, ref8) > limit
-
-
-def test_contiguous_forward_agrees_with_the_reference_and_trains(bench):
-    cfg, reference, params = bench
-    mcfg = dataclasses.replace(CFG, max_seq_len=512)
-    toks = np.random.default_rng(3).integers(1, 256, 24).tolist()
-    logits, cache = forward(params, mcfg, jnp.asarray([toks], jnp.int32),
-                            KVCache.create(mcfg, 1, 32))
-    assert cache.k.shape[0] == 3 and int(cache.length[0]) == 24
-    lp = jax.nn.log_softmax(logits[0], -1)
-    got = lp[jnp.arange(15, 23), jnp.asarray(toks[16:24])]
-    ref = reference.chosen_logprobs(cfg, params, toks[:16], toks[16:])
-    assert _rms(got, ref) <= cfg["correct"]["limit"]
-    train = forward_train(params, mcfg, jnp.asarray([toks], jnp.int32),
-                          remat=True)
-    np.testing.assert_allclose(np.asarray(train), np.asarray(logits),
-                               rtol=1e-3, atol=1e-4)
-
-
-# ---- counters ----------------------------------------------------------------
-
-
-def test_expert_counters_count_expert_layers_only():
-    eng = Engine(EngineConfig(model="tiny-joyai", page_size=8, num_pages=64,
-                              max_seq_len=128, max_batch=2,
-                              decode_buckets=(1, 2),
-                              enable_radix_cache=False), params=PARAMS)
-    for p in ([3, 1, 4, 1, 5], [9, 2, 6]):
-        eng.add_request(p, SamplingParams(max_new_tokens=6))
-    m = eng.metrics
-    while eng.has_work():
-        eng.step()
-    steps = m["decode_steps_run"]
-    assert steps > 0
-    # 2 expert layers of 16 experts a step; the dense layer has no slot
-    assert m["moe_expert_slots"] == steps * 2 * CFG.num_experts
-    # every live row routes to 4 experts in each of the 2 expert layers
-    assert m["moe_routed_rows"] == m["decode_tokens"] * 2 * 4
-    assert 0 < m["moe_experts_visited"] <= m["moe_routed_rows"]
-    assert m["moe_experts_visited"] <= m["moe_expert_slots"]
-
-
 # ---- the benchmark's configuration file --------------------------------------
 
 
 def test_cell_file_holds_the_catalog_and_its_preset_follows_its_keys():
     from harness import serve
-    cfg = _load(CELL_FILE)
+    cfg = read(*CELL_FILE)
     published = {
         "first_k_dense_replace": 1, "hidden_size": 2048,
         "intermediate_size": 7168, "kv_lora_rank": 512,

@@ -3,46 +3,31 @@ layer, then expert layers K K M K K K M (K: Kimi Delta Attention, a gated
 delta rule over a fixed state a sequence; M: latent attention without
 positions), sigmoid-routed experts of which this device holds a range.
 
-The served path (chunked prefill then decode through the state pool, packed
-and by row, through preemption and the reuse of a slot) is held to the
-benchmark's plain reference of the architecture
-(``benchmark/references/kimi_linear.py``, which shares no code with the
-program), and each new rule to its definition."""
+Each new rule is held to its definition here; the served path against the
+benchmark's plain reference of the architecture is the contract every model
+is a case of (``model_contract.py``, ``test_kimi_linear_contract.py``)."""
 
 import dataclasses
-import functools
-import json
-import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
 from rbg_tpu.engine.kvcache import PagedKVCache, StatePool
 from rbg_tpu.models import get_config, init_params
 from rbg_tpu.models import llama
 from rbg_tpu.models.llama import (_EXPERT_STACKS, _hybrid_plan, _mla_qkv,
-                                  _moe_mlp, _moe_mlp_hit, _route)
+                                  _moe_mlp, _route)
 from rbg_tpu.ops import kda
 
 from kda_packed_case import STEPS as PACKED_STEPS
-from kda_packed_case import (assert_rows_equal_the_recurrence,
-                             inside_the_mixer)
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
-if BENCH not in sys.path:
-    sys.path.append(BENCH)          # the benchmark's ``harness`` package
+from kda_packed_case import (assert_no_line_and_no_state_for_every_row,
+                             assert_rows_equal_the_recurrence, eqns,
+                             kda_inputs, step_jaxpr)
 
 CFG = get_config("tiny-kimi-linear")
 PARAMS = init_params(CFG, jax.random.key(0))
-TINY_FILE = os.path.join(BENCH, "tests", "rehearse", "configs",
-                         "tiny-kimi-linear.json")
-NAME = "tiny-kimi-linear-file"
 
 
 # ---- the layers come in kinds ------------------------------------------------
@@ -85,11 +70,6 @@ def test_a_one_kind_model_and_a_dense_prefix_are_the_groups_they_were():
         ("dense_blocks", 0, 1), ("blocks", 1, 3)]
     assert [(k, n, g.half) for k, g, n in joyai.param_groups] == [
         ("dense_blocks", 1, ""), ("blocks", 2, "")]
-
-
-def test_num_params_counts_what_init_makes():
-    n = sum(a.size for a in jax.tree_util.tree_leaves(PARAMS))
-    assert CFG.num_params == n
 
 
 KDA_DENSE, KDA_MOE, MLA_MOE = (("kda_mixers", "dense_mlps"),
@@ -149,45 +129,12 @@ def test_a_pattern_of_three_kinds_in_turns_raises_and_names_it(
     assert third in said and pattern in said
 
 
-def _eqns(jaxpr, in_loop=False):
-    """Every equation of ``jaxpr`` at any depth, each with whether it sits
-    inside the body of a ``while`` or a ``scan``."""
-    for eqn in jaxpr.eqns:
-        yield eqn, in_loop
-        inner = in_loop or eqn.primitive.name in ("while", "scan")
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub, inner)
-
-
-def _step_jaxpr(program, R=4, use_pallas="auto"):
-    """The jaxpr of ``tiny-kimi-linear``'s decode step (by row, the hit
-    experts' form) or its ragged step (packed), ``R`` rows."""
-    T = 1 if program == "decode" else 16
-    cache, pool = PagedKVCache.create(CFG, 64, 8), StatePool(CFG, R)
-    I32 = jnp.int32
-    table = jnp.zeros((R, 8), I32)
-    slots = {"state": pool.arrays, "state_slots": jnp.arange(R, dtype=I32),
-             "use_pallas": use_pallas}
-    if program == "decode":
-        fn = functools.partial(llama.forward_paged, PARAMS, CFG,
-                               experts_whole=True, **slots)
-        args = (jnp.ones((R, T), I32), jnp.zeros((R, T), I32),
-                jnp.ones((R, T), bool), jnp.ones(R, I32), table)
-    else:
-        fn = functools.partial(llama.forward_ragged, PARAMS, CFG,
-                               max_q_len=T, **slots)
-        args = (jnp.ones((1, T), I32), jnp.zeros((1, T), I32),
-                jnp.ones((1, T), bool), jnp.zeros(T, I32),
-                jnp.full(R, T, I32), table)
-    return jax.make_jaxpr(fn)(*args, cache.k_pages, cache.v_pages).jaxpr
-
-
 @pytest.mark.parametrize("program", ["decode", "ragged"])
 def test_no_loop_body_branches_over_an_mlps_weights(program):
     """A ``lax.cond``'s operands are copied whole in every trip of the loop
     that holds it, whichever branch runs (PERF.md, PR 38): no loop of the
     walk hands one a matrix of an MLP, whole or a layer's slice."""
-    jaxpr = _step_jaxpr(program)
+    jaxpr = step_jaxpr(CFG, PARAMS, program)
     matrices = {a.shape[cut:] for key in ("dense_mlps", "moe_mlps")
                 for a in PARAMS[key].values() if a.ndim > 2
                 for cut in (0, 1)}
@@ -196,27 +143,10 @@ def test_no_loop_body_branches_over_an_mlps_weights(program):
     # conds are those of the operations (none takes a matrix of an MLP)
     assert any(e.primitive.name in ("while", "scan") for e in jaxpr.eqns)
     fed = [(e.params["branches"][0].jaxpr.eqns[:1], v.aval.shape)
-           for e, inside in _eqns(jaxpr)
+           for e, inside in eqns(jaxpr)
            if inside and e.primitive.name == "cond" for v in e.invars
            if getattr(v.aval, "shape", None) in matrices]
     assert not fed
-
-
-@pytest.mark.parametrize("program", ["decode", "ragged"])
-def test_a_step_program_traces_the_recurrent_mixer_once(program, monkeypatch):
-    """The walk meets the recurrent mixer in two places, the dense first
-    layer alone and the expert layers' loop: ``_kda_mixer`` makes them one
-    trace (a third body cost 16 s of warm set-up on the chip; PERF.md,
-    PR 38); none where an earlier program of these shapes left it one."""
-    traced = []
-    mixer = llama._kda_attention
-    monkeypatch.setattr(llama, "_kda_attention", lambda *a, **kw: (
-        traced.append(a[4]), mixer(*a, **kw))[1])
-    jaxpr = _step_jaxpr(program, R=3)
-    assert len(traced) <= 1
-    calls = [e for e, _ in _eqns(jaxpr)
-             if e.params.get("name") == "_kda_mixer"]
-    assert len(calls) == 2              # layer 0, and the loop's body
 
 
 def test_pages_are_the_attention_layers_and_states_the_recurrent_ones():
@@ -241,23 +171,8 @@ def test_pages_are_the_attention_layers_and_states_the_recurrent_ones():
 # ---- the recurrence and its forms --------------------------------------------
 
 
-def _kda_inputs(R, C, H, dk, lens, seed=0):
-    ks = jax.random.split(jax.random.key(seed), 6)
-    q = jax.random.normal(ks[0], (R, C, H, dk))
-    k = jax.random.normal(ks[1], (R, C, H, dk))
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (R, C, H, dk))
-    g = -jax.random.uniform(ks[3], (R, C, H, dk)) * 0.7
-    b = jax.nn.sigmoid(jax.random.normal(ks[4], (R, C, H)))
-    real = jnp.arange(C)[None] < jnp.asarray(lens)[:, None]
-    g = jnp.where(real[..., None, None], g, 0.0)
-    b = jnp.where(real[..., None], b, 0.0)
-    S = jax.random.normal(ks[5], (R, H, dk, dk))
-    return (q, k, v, g, b, S), np.asarray(real)
-
-
 def test_one_token_is_the_delta_rule_as_written():
-    (q, k, v, g, b, S), _ = _kda_inputs(2, 1, 3, 8, [1, 1])
+    (q, k, v, g, b, S), _ = kda_inputs(2, 1, 3, 8, [1, 1])
     o, S1 = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], b[:, 0], S)
     for r in range(2):
         for h in range(3):
@@ -274,7 +189,7 @@ def test_one_token_is_the_delta_rule_as_written():
 @pytest.mark.parametrize("C,lens", [(64, [64, 17, 0, 1]), (40, [40, 33, 5, 16]),
                                     (16, [16, 16, 3, 0])])
 def test_the_chunked_form_equals_the_token_by_token_recurrence(C, lens):
-    args, real = _kda_inputs(4, C, 2, 16, lens, seed=C)
+    args, real = kda_inputs(4, C, 2, 16, lens, seed=C)
     o_tok, S_tok = kda.kda_recurrence(*args)
     o_chk, S_chk = jax.jit(kda.kda_chunk)(*args)
     np.testing.assert_allclose(np.asarray(o_chk)[real], np.asarray(o_tok)[real],
@@ -287,7 +202,7 @@ def test_the_chunked_form_equals_the_token_by_token_recurrence(C, lens):
 
 
 def test_a_prompt_in_chunks_carries_the_state_from_each_to_the_next():
-    args, _ = _kda_inputs(2, 48, 2, 16, [48, 48], seed=7)
+    args, _ = kda_inputs(2, 48, 2, 16, [48, 48], seed=7)
     o_all, S_all = kda.kda_chunk(*args)
     *xs, S = args
     outs = []
@@ -308,7 +223,7 @@ def _pool_case(slots, fresh, H=4, dk=16, seed=0):
     """One token a row against a pool every slot of which holds an old
     state; a row of padding names a slot out of range and has ``g = 0``,
     ``b = 0`` (as ``_kda_attention`` masks them)."""
-    (q, k, v, g, b, _), _ = _kda_inputs(
+    (q, k, v, g, b, _), _ = kda_inputs(
         len(slots), 1, H, dk, [s < POOL_SLOTS for s in slots], seed)
     pool = jax.random.normal(jax.random.key(seed + 1),
                              (POOL_LAYERS, POOL_SLOTS, H, dk, dk))
@@ -369,7 +284,7 @@ def test_a_step_of_one_token_a_row_takes_the_kernel_by_the_one_policy(
         monkeypatch):
     """``kda_decode`` is ``dispatch_pallas``'s: 'never' (and 'auto' off a
     TPU) is plain XLA, 'always' the kernel with the arguments in order."""
-    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    from rbg_tpu.ops.pallas import kda_kernel as K
     args = _pool_case([2, POOL_SLOTS, 0], [0, 0, 1], seed=5)
     want = kda.kda_step_in_pool(*args)
     real, calls = K.kda_decode_pallas, []
@@ -406,19 +321,7 @@ def test_a_packed_steps_rows_equal_the_recurrence_row_by_row(
 
 def test_a_packed_step_lays_out_no_line_and_no_state_for_every_row(
         interpreted):
-    """The unified program with the kernel in holds no float32 array of
-    ``[R, C, H, dk]`` (every row's line) or ``[R, H, dk, dv]`` (every
-    row's state), which the decode step by row, in plain XLA, has; it has
-    the kernel, and a loop over the rows that hold a chunk, a row a trip."""
-    R, C, H, dk = 5, 16, CFG.kda_num_heads, CFG.kda_head_dim
-    wide = {(R, C, H, dk), (R, H, dk, dk)}
-    shapes, names = inside_the_mixer(_step_jaxpr("ragged", R,
-                                                 use_pallas="always"))
-    assert not shapes & wide and (1, C, H, dk) in shapes
-    assert {"while", "pallas_call"} <= names
-    shapes, names = inside_the_mixer(_step_jaxpr("decode", R,
-                                                 use_pallas="never"))
-    assert (R, H, dk, dk) in shapes and "pallas_call" not in names
+    assert_no_line_and_no_state_for_every_row(CFG, PARAMS)
 
 
 def test_the_convolution_is_causal_and_keeps_the_last_inputs():
@@ -468,35 +371,9 @@ def _expert_layer(held):
                               lambda k, shape, scale: jax.random.normal(
                                   k, shape, jnp.float32) * scale, 0.5, 0.5)
     blk = jax.tree_util.tree_map(lambda a: a[0], full)
-    if held is None:
-        return whole, blk
     lo, hi = held
     blk = dict(blk, **{k: blk[k][lo:hi] for k in _EXPERT_STACKS})
     return dataclasses.replace(whole, experts_held=held), blk
-
-
-def test_the_shares_partial_results_add_up_to_the_whole_expert_layer():
-    x = jax.random.normal(jax.random.key(2), (6, 1, CFG.hidden_size))
-    whole_cfg, whole_blk = _expert_layer(None)
-    whole = np.asarray(_moe_mlp(whole_cfg, whole_blk, x), np.float64)
-    shared = np.asarray(llama._shared_expert(whole_blk, x), np.float64)
-    parts = np.zeros_like(whole)
-    for lo in (0, 4, 8, 12):
-        cfg, blk = _expert_layer((lo, lo + 4))
-        part = np.asarray(_moe_mlp(cfg, blk, x), np.float64)
-        parts += part - shared          # the shared expert counted once
-        # the hit form computes the same share, and visits held experts only
-        stacks = {k: blk[k][None] for k in _EXPERT_STACKS}
-        got, visited = jax.jit(lambda b, x, s: _moe_mlp_hit(
-            cfg, b, x, s, jnp.int32(0), jnp.ones((6, 1), bool)))(
-                blk, x, stacks)
-        np.testing.assert_allclose(got, part, rtol=1e-4,
-                                   atol=1e-5 * np.abs(part).max())
-        w = np.asarray(_route(cfg, blk, x))[:, 0]
-        assert w.shape == (6, 16)       # the router keeps its width
-        assert int(visited) == (w[:, lo:lo + 4] > 0).any(0).sum() <= 4
-    np.testing.assert_allclose(parts + shared, whole, rtol=1e-4,
-                               atol=1e-5 * np.abs(whole).max())
 
 
 def test_exact_zeros_off_the_chosen_set_survive_a_held_range():
@@ -525,267 +402,3 @@ def test_hit_experts_pay_reckons_with_the_published_count():
     assert llama.hit_experts_pay(cell, 16) and llama.hit_experts_pay(cell, 64)
     with pytest.raises(ValueError, match="no range"):
         dataclasses.replace(g, experts_held=(8, 17))
-
-
-# ---- the served path against the plain reference -----------------------------
-
-
-@pytest.fixture(scope="module")
-def bench():
-    from harness import serve
-    from rbg_tpu.models import config as presets
-    with open(TINY_FILE) as f:
-        cfg = json.load(f)
-    reference = serve.load_reference(cfg)
-    params = reference.make_params(cfg, 3000000019)
-    presets._PRESETS[NAME] = serve.model_config(cfg, NAME)
-    return cfg, reference, params
-
-
-def _engine(cfg, params, **kw):
-    return Engine(EngineConfig(model=NAME, **{**cfg["server"], **kw}),
-                  params=params)
-
-
-def _drive(eng, ids=None, between=None):
-    out = {}
-    while eng.has_work():
-        for ev in eng.step():
-            toks, lps = out.setdefault(ev.request_id, ([], []))
-            toks.append(ev.token)
-            lps.append(ev.logprob)
-        if between is not None:
-            between(eng, out)
-    return out if ids is None else [out[i] for i in ids]
-
-
-def _serve(eng, prompts, new):
-    ids = [eng.add_request(p, SamplingParams(max_new_tokens=new,
-                                             logprobs=True)) for p in prompts]
-    return _drive(eng, ids)
-
-
-def _rms(a, b):
-    d = np.asarray(a, np.float64) - np.asarray(b, np.float64)
-    return math.sqrt(float(np.mean(d * d)))
-
-
-def _prompts(cfg, lens, seed=1):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(1, cfg["vocab_size"], n).tolist() for n in lens]
-
-
-@pytest.fixture()
-def interpreted(monkeypatch):
-    """``use_pallas="always"`` off the chip: the kernels a served
-    ``tiny-kimi-linear`` reaches, in interpret mode."""
-    from rbg_tpu.ops.pallas import paged_attention_kernel as K
-    for name in ("kda_decode_pallas", "paged_mla_attention_pallas",
-                 "ragged_paged_mla_attention_pallas", "moe_visit_pallas"):
-        monkeypatch.setattr(K, name, functools.partial(getattr(K, name),
-                                                       interpret=True))
-
-
-def test_the_file_reaches_the_preset_the_tests_use(bench):
-    from rbg_tpu.models import config as presets
-    got = dataclasses.replace(presets._PRESETS[NAME], name="tiny-kimi-linear",
-                              max_seq_len=256, head_dim=None)
-    assert got == CFG
-
-
-@pytest.mark.parametrize("ragged,hit,use_pallas", [
-    ("auto", True, "auto"), ("off", True, "auto"), ("auto", False, "auto"),
-    ("auto", True, "always")],
-    ids=["packed-hit", "rows-hit", "packed-dense", "packed-hit-kernels"])
-def test_served_path_agrees_with_the_plain_reference(
-        bench, monkeypatch, interpreted, ragged, hit, use_pallas):
-    """Three prompts side by side, the longest of three prefill chunks: the
-    state carried from chunk to chunk (packed with the other rows' decode
-    steps, or by row), then decode steps through the state pool: in plain
-    XLA, and by the kernel that advances the states in place."""
-    cfg, reference, params = bench
-    if not hit:
-        monkeypatch.setattr(llama, "hit_experts_pay", lambda c, rows: False)
-    prompts = _prompts(cfg, (80, 23, 40))
-    eng = _engine(cfg, params, ragged=ragged, use_pallas=use_pallas)
-    served = _serve(eng, prompts, 8)
-    assert (eng.metrics["moe_experts_visited"] > 0) == hit
-    for prompt, (toks, lps) in zip(prompts, served):
-        assert len(toks) == 8
-        ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-        assert _rms(lps, ref) <= cfg["correct"]["limit"]
-    assert eng.state.held == 0 and eng.allocator.free_pages == 255
-
-
-def test_the_controls_fail_the_tiny_limits(bench):
-    cfg, reference, params = bench
-    prompt, = _prompts(cfg, (80,))
-    (toks, lps), = _serve(_engine(cfg, params), [prompt], 8)
-    ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-    for quant in ("bf16", "int8", "kv_int8"):
-        ctl = reference.chosen_logprobs(cfg, params, prompt, toks, quant)
-        assert _rms(ctl, ref) > 3 * cfg["correct"]["limit"], quant
-
-
-@pytest.mark.parametrize("use_pallas", ["auto", "always"])
-@pytest.mark.parametrize("fault", ["state not carried", "slot not zeroed"])
-def test_a_wrong_state_fails_the_tiny_limits(bench, monkeypatch, interpreted,
-                                             fault, use_pallas):
-    """What the check's six-chunk prompt is for: a program that starts
-    every chunk from zeros, or one that goes on from what a slot's last
-    row left, is far from the reference (the decode steps in plain XLA or
-    by the kernel, which takes its ``fresh`` rows from the same
-    positions)."""
-    cfg, reference, params = bench
-    real = llama._kda_attention
-
-    def broken(g, blk, x, state, layer, addr, use_pallas):
-        pos = addr.positions
-        if fault == "state not carried":
-            if x.shape[1] > 1:      # every chunk of a prompt looks first
-                pos = pos - pos[..., :1] if addr.row_ids is None else \
-                    jnp.where(addr.token_mask, 0, pos)
-        else:
-            pos = jnp.where(pos == 0, 1 << 20, pos)     # never looks first
-        return real(g, blk, x, state, layer, addr._replace(positions=pos),
-                    use_pallas)
-
-    monkeypatch.setattr(llama, "_kda_attention", broken)
-    eng = _engine(cfg, params, max_batch=1, use_pallas=use_pallas)
-    first, second = _prompts(cfg, (80, 72), seed=5)
-    (toks, lps), = _serve(eng, [first], 8)
-    if fault == "slot not zeroed":          # the second row inherits a state
-        (toks, lps), = _serve(eng, [second], 8)
-        first = second
-    ref = reference.chosen_logprobs(cfg, params, first, toks)
-    assert _rms(lps, ref) > 100 * cfg["correct"]["limit"]
-
-
-def test_a_slot_reused_after_finish_and_after_preemption_starts_from_zero(
-        bench):
-    cfg, reference, params = bench
-    a, b, c = _prompts(cfg, (70, 50, 33), seed=2)
-    alone = _serve(_engine(cfg, params, max_batch=1), [b], 6)[0]
-    eng = _engine(cfg, params, max_batch=1)
-    _serve(eng, [a], 6)                     # leaves its state in slot 0
-    assert eng.state.held == 0
-    again = _serve(eng, [b], 6)[0]          # the same slot
-    assert again[0] == alone[0] and _rms(again[1], alone[1]) < 1e-5
-    # a row preempted in the middle of its prompt, then another in its slot
-    rid = eng.add_request(a, SamplingParams(max_new_tokens=6, logprobs=True))
-    eng.step()
-    req = eng.requests[rid]
-    assert req.state == "prefill" and req.state_slot == 0
-    eng._preempt(req)
-    assert req.state_slot is None and eng.state.held == 0
-    eng.waiting.remove(req)
-    eng.requests.pop(rid)
-    after = _serve(eng, [c], 6)[0]
-    ref = reference.chosen_logprobs(cfg, params, c, after[0])
-    assert _rms(after[1], ref) <= cfg["correct"]["limit"]
-    assert eng.metrics["state_resets"] == 4
-
-
-def test_a_preempted_requests_second_run_gives_the_first_runs_logits(bench):
-    cfg, reference, params = bench
-    prompt, other = _prompts(cfg, (60, 30), seed=3)
-    whole = _serve(_engine(cfg, params), [prompt], 12)[0]
-
-    eng = _engine(cfg, params)
-    ids = [eng.add_request(p, SamplingParams(max_new_tokens=12,
-                                             logprobs=True))
-           for p in (prompt, other)]
-    done = []
-
-    def preempt_once(eng, out):
-        req = eng.requests.get(ids[0])
-        if not done and req is not None and len(out.get(ids[0], ([],))[0]) >= 5:
-            for ev in eng._drain_decode():      # tokens in flight first
-                out[ev.request_id][0].append(ev.token)
-                out[ev.request_id][1].append(ev.logprob)
-            if req.state == "running":
-                eng._preempt(req)
-                done.append(len(req.prompt))
-
-    toks, lps = _drive(eng, ids, preempt_once)[0]
-    assert done and done[0] > len(prompt)       # prefilled again from token 0
-    assert eng.metrics["preemptions"] == 1
-    assert toks == whole[0] and _rms(lps, whole[1]) < 1e-4
-    ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-    assert _rms(lps, ref) <= cfg["correct"]["limit"]
-
-
-def test_a_cached_prefix_matches_nothing_and_still_agrees(bench):
-    cfg, reference, params = bench
-    first, tail = _prompts(cfg, (64, 20), seed=4)
-    eng = _engine(cfg, params)
-    assert eng.radix is not None
-    _serve(eng, [first], 4)
-    second = first + tail                   # its first 64 tokens were served
-    (toks, lps), = _serve(eng, [second], 6)
-    m = eng.metrics
-    assert m["radix_hit_tokens"] == 0 and m["prefix_skipped"] == 2
-    assert eng.radix.match(first[:-1])[0] == 0      # nothing was inserted
-    assert m["prefill_tokens"] == len(first) + len(second)
-    ref = reference.chosen_logprobs(cfg, params, second, toks)
-    assert _rms(lps, ref) <= cfg["correct"]["limit"]
-
-
-def test_state_counters_count_slots_rows_and_bytes(bench):
-    cfg, _, params = bench
-    eng = _engine(cfg, params)
-    _serve(eng, _prompts(cfg, (40, 20)), 5)
-    m = eng.metrics
-    assert m["state_resets"] == 2
-    assert 0 < m["state_slots_live"] <= m["state_slots_held"]
-    row = 2 * (6 * 4 * 32 * 32 * 4 + 6 * 3 * 3 * 4 * 32 * 4)
-    assert eng.state.row_bytes == row
-    assert m["state_bytes_moved"] % row == 0
-    # every row-step of a decode or unified step moved one row's state
-    assert m["state_bytes_moved"] // row >= m["decode_tokens"]
-    # experts: slots count the held experts, routed pairs the held share
-    assert m["moe_expert_slots"] % (7 * 8) == 0
-    # (row, chosen expert) pairs: rows x top 2 x 7 expert layers x 8 / 16
-    assert m["moe_routed_rows"] % 7 == 0
-    assert 0 < m["moe_routed_rows"] <= 7 * m["decode_tokens"]
-    assert m["moe_experts_visited"] <= m["moe_expert_slots"]
-
-
-# ---- what is not built for a recurrent model is refused, with a message ------
-
-
-@pytest.mark.parametrize("kw,match", [
-    (dict(speculative="ngram"), "speculative decoding"),
-    (dict(kv_dtype="int8"), "the state pool has no quantised form"),
-    (dict(mode="prefill"), "PD bundle carries pages"),
-    (dict(mode="decode"), "PD bundle carries pages"),
-    (dict(host_tier_bytes=1 << 20), "host tier keeps prefixes"),
-])
-def test_engine_refuses_what_a_recurrent_model_does_not_support(kw, match):
-    with pytest.raises(ValueError, match=match):
-        Engine(EngineConfig(model="tiny-kimi-linear", page_size=8,
-                            num_pages=32, max_seq_len=64, max_batch=2,
-                            prefill_chunk=16, **kw), params=PARAMS)
-
-
-def test_other_paths_refuse_a_recurrent_model():
-    eng = Engine(EngineConfig(model="tiny-kimi-linear", page_size=8,
-                              num_pages=32, max_seq_len=64, max_batch=2,
-                              prefill_chunk=16), params=PARAMS)
-    with pytest.raises(ValueError, match="groups of layers"):
-        eng.load_lora("a", {"wo": (np.zeros((8, 128, 4), np.float32),
-                                   np.zeros((8, 4, 128), np.float32))})
-    with pytest.raises(ValueError, match="state at its end"):
-        eng.add_request_with_prefix(list(range(1, 20)), None, 8, None, None)
-    tokens = jnp.ones((1, 4), jnp.int32)
-    with pytest.raises(NotImplementedError, match="contiguous cache"):
-        llama.forward(PARAMS, CFG, tokens, llama.KVCache(
-            k=jnp.zeros((8, 1, 8, 1, 64)), v=jnp.zeros((8, 1, 8, 1, 16)),
-            length=jnp.zeros((1,), jnp.int32)))
-    with pytest.raises(NotImplementedError, match="cache-free forward"):
-        llama.forward_train(PARAMS, CFG, tokens)
-    with pytest.raises(NotImplementedError, match="walked whole"):
-        llama.paged_layers(PARAMS, CFG, None, (), None, layers=(0, 4))
-    from rbg_tpu.parallel import pipeline
-    with pytest.raises(NotImplementedError, match="groups"):
-        pipeline.pipeline_forward_train(PARAMS, CFG, tokens, mesh=None)

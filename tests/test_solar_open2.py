@@ -4,47 +4,34 @@ and with an output gate, on K/V pages; K: the gated delta rule with a write
 strength of ``2 sigmoid``, its states beside those pages), sigmoid experts
 picked by a bias beside a shared one, of which this device holds a range.
 
-The served path (chunked prefill then decode through the pages and the
-state pool, packed and by row, in plain XLA and by the kernels) is held to
-the benchmark's plain reference of the architecture
-(``benchmark/references/solar_open2.py``, which shares no code with the
-program), and each new rule to its definition."""
+Each new rule is held to its definition here; the served path against the
+benchmark's plain reference of the architecture, and each of the three rules
+left out of it, is the contract every model is a case of
+(``model_contract.py``, ``test_solar_open2_contract.py``)."""
 
 import dataclasses
 import functools
-import json
-import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
 from rbg_tpu.engine.kvcache import PagedKVCache, StatePool
 from rbg_tpu.models import get_config, init_params
 from rbg_tpu.models import llama
 from rbg_tpu.models.config import ModelConfig
-from rbg_tpu.models.llama import _hybrid_plan, _moe_mlp
+from rbg_tpu.models.llama import _hybrid_plan
 from rbg_tpu.ops import kda
 
 from kda_packed_case import STEPS as PACKED_STEPS
-from kda_packed_case import (assert_rows_equal_the_recurrence,
-                             inside_the_mixer)
+from kda_packed_case import (assert_no_line_and_no_state_for_every_row,
+                             assert_rows_equal_the_recurrence, kda_inputs)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
-if BENCH not in sys.path:
-    sys.path.append(BENCH)          # the benchmark's ``harness`` package
+from model_contract import read
 
 CFG = get_config("tiny-solar-open2")
 PARAMS = init_params(CFG, jax.random.key(0))
-TINY_FILE = os.path.join(BENCH, "tests", "rehearse", "configs",
-                         "tiny-solar-open2.json")
-CELL_FILE = os.path.join(BENCH, "configs", "solar-open2-250b.json")
-NAME = "tiny-solar-open2-file"
 
 ATT_MOE, KDA_MOE = ("mixers", "moe_mlps"), ("kda_mixers", "moe_mlps")
 
@@ -82,8 +69,7 @@ def test_attention_leads_the_turns_and_no_layer_is_dense():
 def test_the_published_48_layers_are_twelve_turns_and_a_cut_keeps_whole_ones():
     """The published 0-based ``gqa_layers`` become the 1-based ``kda_layers``
     of whatever depth is kept: the complement among the layers served."""
-    with open(CELL_FILE) as f:
-        cell = json.load(f)
+    cell = read("configs", "solar-open2-250b.json")
     gqa = cell["gqa_layers"]
     assert gqa == list(range(0, 48, 4)) and cell["gqa_interval"] == 3
 
@@ -136,21 +122,8 @@ def test_the_state_pool_stands_beside_kv_pages_of_the_attention_layers():
 # ---- the delta rule with a write strength in (0, 2) --------------------------
 
 
-def _kda_inputs(R, C, H, dk, lens, seed=0):
-    """As ``test_kimi_linear._kda_inputs`` with ``b = 2 sigmoid(.)``: drawn
-    over (0, 2), a third of it above 1.2."""
-    ks = jax.random.split(jax.random.key(seed), 6)
-    q = jax.random.normal(ks[0], (R, C, H, dk))
-    k = jax.random.normal(ks[1], (R, C, H, dk))
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (R, C, H, dk))
-    g = -jax.random.uniform(ks[3], (R, C, H, dk)) * 0.7
-    b = 2.0 * jax.nn.sigmoid(1.5 * jax.random.normal(ks[4], (R, C, H)))
-    real = jnp.arange(C)[None] < jnp.asarray(lens)[:, None]
-    g = jnp.where(real[..., None, None], g, 0.0)
-    b = jnp.where(real[..., None], b, 0.0)
-    S = jax.random.normal(ks[5], (R, H, dk, dk))
-    return (q, k, v, g, b, S), np.asarray(real)
+# ``b = 2 sigmoid(.)``: drawn over (0, 2), a third of it above 1.2
+_kda_inputs = functools.partial(kda_inputs, b_scale=2.0, b_spread=1.5)
 
 
 def test_a_write_strength_of_two_reflects_the_state_along_the_key():
@@ -224,185 +197,10 @@ def test_a_packed_steps_rows_equal_the_recurrence_at_64_heads_and_b_to_two(
 
 def test_the_packed_program_walks_chunk_rows_in_a_loop_and_no_row_line(
         interpreted):
-    """``tiny-solar-open2``'s unified program with the kernel in: no
-    float32 ``[R, C, H, dk]`` or ``[R, H, dk, dv]``, a ``while`` in the
-    mixer's program and the decode kernel beside it."""
-    R, C, H, dk = 5, 16, CFG.kda_num_heads, CFG.kda_head_dim
-    cache, pool = PagedKVCache.create(CFG, 64, 8), StatePool(CFG, R)
-    I32 = jnp.int32
-    jaxpr = jax.make_jaxpr(functools.partial(
-        llama.forward_ragged, PARAMS, CFG, max_q_len=C, use_pallas="always",
-        state=pool.arrays, state_slots=jnp.arange(R, dtype=I32)))(
-        jnp.ones((1, C), I32), jnp.zeros((1, C), I32), jnp.ones((1, C), bool),
-        jnp.zeros(C, I32), jnp.full(R, C, I32), jnp.zeros((R, 8), I32),
-        cache.k_pages, cache.v_pages).jaxpr
-    shapes, names = inside_the_mixer(jaxpr)
-    assert not shapes & {(R, C, H, dk), (R, H, dk, dk)}
-    assert (1, C, H, dk) in shapes                  # a trip's one row
-    assert {"while", "pallas_call"} <= names
+    assert_no_line_and_no_state_for_every_row(CFG, PARAMS)
 
 
-# ---- the held experts --------------------------------------------------------
-
-
-def test_four_shares_of_four_and_the_shared_expert_once_make_the_layer():
-    g = dict((k, c) for k, c, _, _ in CFG.layer_groups)["blocks"]
-    whole = dataclasses.replace(g, experts_held=None)
-    blk = llama._init_blocks(dataclasses.replace(whole, half="mlp"),
-                             jax.random.key(3), 1, lambda k, s, sc: (
-                                 jax.random.normal(k, s) * sc), 0.2, 0.2)
-    blk = {k: v[0] for k, v in blk.items()}
-    assert blk["router"].shape == (128, 16) and "w_gate" in blk
-    xm = jax.random.normal(jax.random.key(4), (2, 5, 128))
-    total = _moe_mlp(whole, blk, xm)
-    shared = llama._shared_expert(blk, xm)
-    parts = 0
-    for lo in range(0, 16, 4):
-        share = dataclasses.replace(g, experts_held=(lo, lo + 4))
-        held = {k: (v[lo:lo + 4] if k in llama._EXPERT_STACKS else v)
-                for k, v in blk.items()}
-        parts = parts + _moe_mlp(share, held, xm) - shared
-    # every share computes the shared expert alike: counted once
-    np.testing.assert_allclose(np.asarray(parts + shared), np.asarray(total),
-                               rtol=1e-4, atol=1e-5)
-    assert float(jnp.abs(shared).max()) > 1e-3
-
-
-# ---- the served path against the plain reference -----------------------------
-
-
-@pytest.fixture(scope="module")
-def bench():
-    from harness import serve
-    from rbg_tpu.models import config as presets
-    with open(TINY_FILE) as f:
-        cfg = json.load(f)
-    reference = serve.load_reference(cfg)
-    params = reference.make_params(cfg, 3000000019)
-    presets._PRESETS[NAME] = serve.model_config(cfg, NAME)
-    return cfg, reference, params
-
-
-def _engine(cfg, params, model=NAME, **kw):
-    return Engine(EngineConfig(model=model, **{**cfg["server"], **kw}),
-                  params=params)
-
-
-def _serve(eng, prompts, new):
-    ids = [eng.add_request(p, SamplingParams(max_new_tokens=new,
-                                             logprobs=True)) for p in prompts]
-    out = {}
-    while eng.has_work():
-        for ev in eng.step():
-            toks, lps = out.setdefault(ev.request_id, ([], []))
-            toks.append(ev.token)
-            lps.append(ev.logprob)
-    return [out[i] for i in ids]
-
-
-def _rms(a, b):
-    d = np.asarray(a, np.float64) - np.asarray(b, np.float64)
-    return math.sqrt(float(np.mean(d * d)))
-
-
-def _prompts(cfg, lens, seed=1):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(1, cfg["vocab_size"], n).tolist() for n in lens]
-
-
-@pytest.fixture()
-def interpreted(monkeypatch):
-    """``use_pallas="always"`` off the chip: the kernels a served
-    ``tiny-solar-open2`` reaches, in interpret mode."""
-    from rbg_tpu.ops.pallas import paged_attention_kernel as K
-    for name in ("kda_decode_pallas", "paged_attention_pallas",
-                 "ragged_paged_attention_pallas", "moe_visit_pallas"):
-        monkeypatch.setattr(K, name, functools.partial(getattr(K, name),
-                                                       interpret=True))
-
-
-@pytest.fixture()
-def fresh_mixers():
-    """``_kda_mixer`` is a program of its own and keeps what it traced: a
-    test that changes what it calls clears the caches around itself."""
-    jax.clear_caches()
-    yield
-    jax.clear_caches()
-
-
-def test_the_file_reaches_the_preset_the_tests_use(bench):
-    from rbg_tpu.models import config as presets
-    got = dataclasses.replace(presets._PRESETS[NAME], name="tiny-solar-open2",
-                              max_seq_len=256)
-    assert got == CFG
-    cfg, reference, params = bench
-    own = jax.eval_shape(lambda: init_params(presets._PRESETS[NAME],
-                                             jax.random.key(0)))
-    assert jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), params) == \
-        jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), own)
-
-
-@pytest.mark.parametrize("ragged,hit,use_pallas", [
-    ("auto", True, "auto"), ("off", True, "auto"), ("auto", False, "auto"),
-    ("auto", True, "always")],
-    ids=["packed-hit", "rows-hit", "packed-dense", "packed-hit-kernels"])
-def test_served_path_agrees_with_the_plain_reference(
-        bench, monkeypatch, interpreted, ragged, hit, use_pallas):
-    """Three prompts side by side, the longest of three prefill chunks: the
-    state and the convolution's tail carried from chunk to chunk (packed
-    with the other rows' decode steps, or by row) while the attention
-    layers fill their pages, then decode steps through both: in plain XLA,
-    and by the delta rule's kernel and the page walk's in one step."""
-    cfg, reference, params = bench
-    if not hit:
-        monkeypatch.setattr(llama, "hit_experts_pay", lambda c, rows: False)
-    prompts = _prompts(cfg, (80, 23, 40))
-    eng = _engine(cfg, params, ragged=ragged, use_pallas=use_pallas)
-    served = _serve(eng, prompts, 8)
-    assert (eng.metrics["moe_experts_visited"] > 0) == hit
-    for prompt, (toks, lps) in zip(prompts, served):
-        assert len(toks) == 8
-        ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-        assert _rms(lps, ref) <= cfg["correct"]["limit"]
-    assert eng.state.held == 0 and eng.allocator.free_pages == 255
-
-
-@pytest.mark.parametrize("chunk", [16, 48, 128])
-def test_a_prompt_in_chunks_equals_it_whole(bench, chunk):
-    cfg, reference, params = bench
-    prompt, = _prompts(cfg, (90,), seed=7)
-    whole = _serve(_engine(cfg, params, prefill_chunk=128), [prompt], 6)[0]
-    got = _serve(_engine(cfg, params, prefill_chunk=chunk), [prompt], 6)[0]
-    assert got[0] == whole[0] and _rms(got[1], whole[1]) < 1e-5
-    ref = reference.chosen_logprobs(cfg, params, prompt, got[0])
-    assert _rms(got[1], ref) <= cfg["correct"]["limit"]
-
-
-RULES = {"the gate dropped": dict(attn_gate=False),
-         "b not doubled": dict(kda_beta_scale=1.0),
-         "the attention layers rotated": dict(use_rope=True)}
-
-
-@pytest.mark.parametrize("rule", sorted(RULES))
-def test_each_new_rule_left_out_moves_the_logits_far_past_the_limit(
-        bench, fresh_mixers, rule):
-    """The three rules the architecture adds, left out of the served path
-    one at a time, as the chip's controls leave them out: each is far from
-    the reference, which keeps all three."""
-    from rbg_tpu.models import config as presets
-    cfg, reference, params = bench
-    name = NAME + "-broken"
-    presets._PRESETS[name] = dataclasses.replace(
-        presets._PRESETS[NAME], name=name, **RULES[rule])
-    prompt, = _prompts(cfg, (80,), seed=5)
-    (toks, lps), = _serve(_engine(cfg, params, model=name), [prompt], 8)
-    ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-    # (rotation moves this toy least, 30 limits: its scores are near uniform)
-    assert _rms(lps, ref) > 10 * cfg["correct"]["limit"]
-    # and the sound program on the same prompt is within it
-    (toks, lps), = _serve(_engine(cfg, params), [prompt], 8)
-    ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-    assert _rms(lps, ref) <= cfg["correct"]["limit"]
+# ---- the new fields cost the other models nothing ---------------------------
 
 
 def test_without_the_new_fields_nothing_is_added_to_a_program():
@@ -415,72 +213,3 @@ def test_without_the_new_fields_nothing_is_added_to_a_program():
     kimi = get_config("tiny-kimi-linear")
     assert kimi.kda_beta_scale == 1.0 and not kimi.attn_gate
     assert get_config("tiny").use_rope and ModelConfig().kda_beta_scale == 1.0
-
-
-def test_the_controls_fail_the_tiny_limits(bench):
-    cfg, reference, params = bench
-    prompt, = _prompts(cfg, (80,))
-    (toks, lps), = _serve(_engine(cfg, params), [prompt], 8)
-    ref = reference.chosen_logprobs(cfg, params, prompt, toks)
-    # kv_int8 rounds the cached K and V AND the recurrent state
-    for quant in ("bf16", "int8", "fp8", "kv_int8"):
-        ctl = reference.chosen_logprobs(cfg, params, prompt, toks, quant)
-        assert _rms(ctl, ref) > 3 * cfg["correct"]["limit"], quant
-
-
-@pytest.mark.parametrize("use_pallas", ["auto", "always"])
-@pytest.mark.parametrize("fault", ["state not carried", "slot not zeroed"])
-def test_a_wrong_state_fails_the_tiny_limits(bench, monkeypatch, interpreted,
-                                             fresh_mixers, fault, use_pallas):
-    cfg, reference, params = bench
-    real = llama._kda_attention
-
-    def broken(g, blk, x, state, layer, addr, use_pallas):
-        pos = addr.positions
-        if fault == "state not carried":
-            if x.shape[1] > 1:      # every chunk of a prompt looks first
-                pos = pos - pos[..., :1] if addr.row_ids is None else \
-                    jnp.where(addr.token_mask, 0, pos)
-        else:
-            pos = jnp.where(pos == 0, 1 << 20, pos)     # never looks first
-        return real(g, blk, x, state, layer, addr._replace(positions=pos),
-                    use_pallas)
-
-    monkeypatch.setattr(llama, "_kda_attention", broken)
-    eng = _engine(cfg, params, max_batch=1, use_pallas=use_pallas)
-    first, second = _prompts(cfg, (80, 72), seed=5)
-    (toks, lps), = _serve(eng, [first], 8)
-    if fault == "slot not zeroed":          # the second row inherits a state
-        (toks, lps), = _serve(eng, [second], 8)
-        first = second
-    ref = reference.chosen_logprobs(cfg, params, first, toks)
-    assert _rms(lps, ref) > 100 * cfg["correct"]["limit"]
-
-
-def test_a_slot_reused_after_finish_starts_from_zeros(bench):
-    cfg, reference, params = bench
-    a, b = _prompts(cfg, (70, 50), seed=2)
-    alone = _serve(_engine(cfg, params, max_batch=1), [b], 6)[0]
-    eng = _engine(cfg, params, max_batch=1)
-    _serve(eng, [a], 6)                     # leaves its states in slot 0
-    assert eng.state.held == 0
-    assert float(jnp.abs(eng.state.arrays["s"][:, 0]).max()) > 0
-    again = _serve(eng, [b], 6)[0]          # the same slot
-    assert again[0] == alone[0] and _rms(again[1], alone[1]) < 1e-5
-    assert eng.metrics["state_resets"] == 2
-
-
-def test_state_counters_count_slots_rows_and_bytes(bench):
-    cfg, _, params = bench
-    eng = _engine(cfg, params)
-    _serve(eng, _prompts(cfg, (40, 20)), 5)
-    m = eng.metrics
-    assert m["state_resets"] == 2
-    assert 0 < m["state_slots_live"] <= m["state_slots_held"]
-    # read and written: 6 layers x (4 x 32 x 32 state + 3 x 384 tail), f32
-    row = 2 * 6 * (4 * 32 * 32 + 1152) * 4
-    assert eng.state.row_bytes == row and m["state_bytes_moved"] % row == 0
-    assert m["state_bytes_moved"] // row >= m["decode_tokens"]
-    assert m["moe_expert_slots"] % (4 * 8) == 0     # 4 held x 8 layers
-    assert m["moe_experts_visited"] <= m["moe_expert_slots"]
-    assert m["prefix_skipped"] == 2 and m["radix_hit_tokens"] == 0

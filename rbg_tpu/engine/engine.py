@@ -37,6 +37,7 @@ import numpy as np
 
 from rbg_tpu.engine.config import EngineConfig, SamplingParams
 from rbg_tpu.engine.kvcache import (PageAllocator, PagedKVCache, StatePool,
+                                    window_pool_pages,
                                     pages_for_tokens)
 from rbg_tpu.engine.radix_cache import RadixCache
 from rbg_tpu.engine.sampler import NEG_INF, row_keys, sample, step_keys
@@ -126,6 +127,11 @@ class Request:
         self.state = "waiting"          # waiting | prefill | running | finished
         self.pages: List[int] = []
         self.state_slot: Optional[int] = None   # recurrent-state pool slot
+        # The window class's pages (a model with window layers): those of
+        # the row's line from column ``window_lo`` on; what lay below was
+        # given back (``Engine._window_pages_for``).
+        self.window_pages: List[int] = []
+        self.window_lo = 0
         self.shared_tokens = 0          # radix-matched prefix (page-aligned)
         self.prefill_pos = 0            # next prompt index to prefill
         self.seq_len = 0                # tokens materialized in KV
@@ -178,16 +184,26 @@ class Engine:
         # or from this base + request id (distinct streams). See sampler.py.
         self._sample_base = jax.random.key(cfg.seed + 1)
 
+        if self.mcfg.unbuilt_for:
+            self._refuse_unbuilt()
+        # A model with window layers keeps a second class of page for
+        # them, sized by the rows' windows, with an allocator of its own.
+        self.window_allocator: Optional[PageAllocator] = None
+        if self.mcfg.sliding_window:
+            self.window_allocator = PageAllocator(window_pool_pages(
+                self.mcfg, cfg.page_size, cfg.max_batch,
+                max(cfg.prefill_chunk, cfg.multi_step)))
         self.cache = PagedKVCache.create(
             self.mcfg, cfg.num_pages, cfg.page_size,
             quantize=(cfg.kv_dtype == "int8"),
-            tp=1 if mesh is None else mesh.shape.get("tp", 1))
+            tp=1 if mesh is None else mesh.shape.get("tp", 1),
+            window_num_pages=(self.window_allocator.num_pages
+                              if self.window_allocator else 0))
         self.allocator = PageAllocator(cfg.num_pages)
         # A model with recurrent layers keeps a state slot a row beside
         # the pages (of its attention layers alone).
         self.state: Optional[StatePool] = None
         if self.mcfg.recurrent:
-            self._refuse_for_recurrent()
             self.state = StatePool(self.mcfg, cfg.max_batch)
         self.radix = RadixCache(self.allocator, cfg.page_size) if cfg.enable_radix_cache else None
         # Host-DRAM spill tier under the device pool (engine/kvtier.py):
@@ -204,8 +220,10 @@ class Engine:
         # every expert (parallel/sharding.py splits them over ``ep``).
         self._experts_whole = mesh is None or mesh.shape.get("ep", 1) == 1
 
-        # Step programs take the state pool by keyword and give it back.
-        self._donate_state = ("state",) if self.state is not None else ()
+        # Step programs take the state pool and the window class's pools
+        # by keyword and give them back.
+        self._donate_state = (("state",) if self.state is not None else ()) \
+            + (("window",) if self.window_allocator is not None else ())
 
         self.waiting: List[Request] = []
         self.running: List[Request] = []
@@ -266,6 +284,15 @@ class Engine:
                         "t_unified_s": 0.0, "unified_steps_run": 0,
                         "t_decode_s": 0.0, "decode_steps_run": 0,
                         "kv_live_token_steps": 0, "kv_held_slot_steps": 0,
+                        # The window class of page (a model with window
+                        # layers), per step that dispatched: the slots its
+                        # rows' windows hold live (at most
+                        # ``sliding_window`` a row) against the slots of
+                        # the pages they hold; and the pages given back
+                        # because every later window lies above them.
+                        "kv_window_live_token_steps": 0,
+                        "kv_window_held_slot_steps": 0,
+                        "kv_window_pages_released": 0,
                         # What makes a step late, always on: the turns'
                         # seconds outside sync and idle, and those less
                         # the loop thread's CPU seconds; late turns and
@@ -322,32 +349,43 @@ class Engine:
         self.late_ring: collections.deque = collections.deque(
             maxlen=LATE_RING)
 
-    def _refuse_for_recurrent(self) -> None:
-        """What is not built for a model with recurrent layers, refused
-        here, in one place (prefix reuse is bypassed in ``_admit`` and
-        ``_finish``; LoRA is refused by ``load_lora``, a model of several
-        groups)."""
+    def _refuse_unbuilt(self) -> None:
+        """What is not built for a model with recurrent layers, or with
+        window layers, refused here, in one place, by the mechanism that is
+        missing (prefix reuse is bypassed in ``_admit`` and ``_finish``;
+        LoRA is refused by ``load_lora``, a model of several groups)."""
         cfg, why = self.cfg, None
+        recurrent = self.mcfg.recurrent
         if cfg.speculative != "off":
             why = ("speculative decoding: a rejected draft would have to "
-                   "be taken out of the state again")
+                   "be taken out of the state again" if recurrent else
+                   "speculative decoding: the verify step has no window "
+                   "table, and a rejected draft's pages could already "
+                   "have been given back")
         elif cfg.kv_dtype == "int8":
             why = ("kv_dtype int8: the state pool has no quantised form (a "
                    "delta-rule state is float32, a convolution's tail the "
-                   "model's dtype)")
+                   "model's dtype)" if recurrent else
+                   "kv_dtype int8: the window class of page has no "
+                   "quantised form (no scales pool, no dequantising walk)")
         elif cfg.mode != "unified":
             why = (f"mode {cfg.mode!r}: a PD bundle carries pages, not the "
-                   f"recurrent state")
+                   f"recurrent state" if recurrent else
+                   f"mode {cfg.mode!r}: a PD bundle carries one class of "
+                   f"page, not the window class and its table")
         elif cfg.host_tier_bytes:
             why = ("host_tier_bytes: the host tier keeps prefixes, which a "
-                   "recurrent model cannot reuse")
+                   "recurrent model cannot reuse" if recurrent else
+                   "host_tier_bytes: the host tier keeps prefixes, and a "
+                   "prefix's window pages were given back")
         elif self.mesh is not None:
-            why = "a device mesh: the state pool has no sharding"
+            why = ("a device mesh: the state pool has no sharding"
+                   if recurrent else
+                   "a device mesh: the window class of page has no sharding")
         if why:
             raise ValueError(
-                f"model {self.mcfg.name!r} has recurrent layers "
-                f"({', '.join(self.mcfg.recurrent_kinds)}), which do not "
-                f"support {why}")
+                f"model {self.mcfg.name!r} {self.mcfg.unbuilt_for}, which "
+                f"do not support {why}")
 
     def _slot_rows(self, reqs, B: int):
         """``[B]`` state slots of ``reqs`` in row order, on the device; a
@@ -359,18 +397,77 @@ class Engine:
         return jnp.asarray(slots)
 
     def _state_kw(self, reqs, B: int) -> dict:
-        """The keyword arguments by which a step program of a model with
-        recurrent layers gets the state pool and its rows' slots."""
-        if self.state is None:
-            return {}
-        return {"state": self.state.arrays, "slots": self._slot_rows(reqs, B)}
+        """The keyword arguments by which a step program gets what a model
+        keeps beside its pages: the state pool and its rows' slots
+        (recurrent layers), the window class's pools and its rows' lines
+        (window layers)."""
+        kw = {}
+        if self.state is not None:
+            kw.update(state=self.state.arrays,
+                      slots=self._slot_rows(reqs, B))
+        if self.window_allocator is not None:
+            kw.update(window=self.cache.window_pages,
+                      wtable=jnp.asarray(self._window_table(reqs, B)))
+        return kw
 
-    def _put_pools(self, kp, vp, ksc, vsc, state=None) -> None:
-        """Take back the pools a step program was given (donated)."""
+    def _put_pools(self, kp, vp, ksc, vsc, state=None, window=None) -> None:
+        """Take back the pools a step program was given (donated), in
+        ``forward_paged``'s order."""
+        window = window or (None, None)
         self.cache = PagedKVCache(k_pages=kp, v_pages=vp,
-                                  k_scales=ksc, v_scales=vsc)
+                                  k_scales=ksc, v_scales=vsc,
+                                  window_k=window[0], window_v=window[1])
         if state is not None:
             self.state.arrays = state
+
+    # ---- the window class of page ----
+
+    def _window_table(self, reqs, B: int) -> np.ndarray:
+        """``[B, P]`` lines of the window class for ``reqs`` in row order:
+        a row's pages at their absolute columns, 0 (the null page) wherever
+        it holds none: below its live range, where they were given back,
+        and beyond it."""
+        table = np.zeros((B, self.cfg.max_pages_per_seq), np.int32)
+        for i, r in enumerate(reqs):
+            table[i, r.window_lo:r.window_lo + len(r.window_pages)] = \
+                r.window_pages
+        return table
+
+    def _window_pages_for(self, req: "Request", first_query: int,
+                          horizon: int) -> bool:
+        """Bring ``req``'s pages of the window class to what the step about
+        to be dispatched reads and writes: its oldest query stands at
+        ``first_query`` and its slots end before ``horizon``. A page is
+        given back once every slot of it lies below ``first_query -
+        sliding_window + 1``, the oldest slot that query attends: no later
+        query reaches lower, and the walk starts above it. The pool holds
+        every row's most (``window_pool_pages``), so taking cannot fail.
+        Returns whether the row's line changed."""
+        ps = self.cfg.page_size
+        lo = max(first_query - self.mcfg.sliding_window + 1, 0) // ps
+        drop = min(max(lo - req.window_lo, 0), len(req.window_pages))
+        if drop:
+            self.window_allocator.release(req.window_pages[:drop])
+            del req.window_pages[:drop]
+            self.metrics["kv_window_pages_released"] += drop
+        if not req.window_pages:
+            req.window_lo = lo
+        else:
+            req.window_lo += drop
+        need = (pages_for_tokens(horizon, ps) - req.window_lo
+                - len(req.window_pages))
+        if need > 0:
+            pages = self.window_allocator.alloc(need)
+            assert pages is not None, "the window class is sized per row"
+            req.window_pages.extend(pages)
+        return bool(drop) or need > 0
+
+    def _release_window(self, req: "Request") -> None:
+        """Every page ``req`` holds of the window class, back (a finished,
+        cancelled or preempted request)."""
+        if req.window_pages:
+            self.window_allocator.release(req.window_pages)
+        req.window_pages, req.window_lo = [], 0
 
     def _release_slot(self, req: "Request") -> None:
         if req.state_slot is not None:
@@ -733,10 +830,11 @@ class Engine:
         page-aligned and < len(prompt) (the last token always prefills for
         logits). Returns None when no pages are free (caller falls back to
         a cold prefill through the normal admission queue)."""
-        if self.state is not None:
+        if self.mcfg.unbuilt_for:
             raise ValueError(
-                f"model {self.mcfg.name!r} has recurrent layers: a prefix's "
-                f"pages without the state at its end cannot be resumed")
+                f"model {self.mcfg.name!r} {self.mcfg.unbuilt_for}: a "
+                f"prefix's pages without the state at its end "
+                f"(the window class's pages below it) cannot be resumed")
         sampling = sampling or SamplingParams()
         self._check_prompt(prompt)
         self._grammar_check(sampling)
@@ -864,6 +962,12 @@ class Engine:
             held += len(r.pages)
         m["kv_live_token_steps"] += live
         m["kv_held_slot_steps"] += held * self.cfg.page_size
+        if self.window_allocator is not None:
+            W = self.mcfg.sliding_window
+            m["kv_window_live_token_steps"] += sum(
+                min(r.seq_len, W) for r in self.running)
+            m["kv_window_held_slot_steps"] += self.cfg.page_size * sum(
+                len(r.window_pages) for r in self.running)
         if self.state is not None:
             # A fused window advances each row once a step, a unified step
             # each of its rows once: ``q_tokens`` of the former, ``rows`` of
@@ -949,10 +1053,12 @@ class Engine:
             req = self.waiting[0]
             matched, shared_pages = 0, []
             radix_matched = host_matched = 0
-            if self.state is not None:
+            if self.mcfg.unbuilt_for:
                 # No prefix reuse for a model with recurrent layers: a hit
                 # would have to restore the state at the prefix's end, and
                 # nothing keeps it (``_finish`` inserts nothing either).
+                # Nor for one with window layers: a cached prefix's pages
+                # of the window class were given back as it was served.
                 if self.radix is not None:
                     self.metrics["prefix_skipped"] += 1
             elif (self.radix is not None and req.state == "waiting"
@@ -1161,13 +1267,15 @@ class Engine:
 
             def wrapped(params, tokens, positions, token_mask, row_ids,
                         kv_lens, page_table, k_pages, v_pages, k_scales,
-                        v_scales, state=None, slots=None):
+                        v_scales, state=None, slots=None, window=None,
+                        wtable=None):
                 return base(params, tokens=tokens, positions=positions,
                             token_mask=token_mask, row_ids=row_ids,
                             kv_lens=kv_lens, page_table=page_table,
                             k_pages=k_pages, v_pages=v_pages,
                             k_scales=k_scales, v_scales=v_scales,
-                            state=state, state_slots=slots)
+                            state=state, state_slots=slots,
+                            window_pages=window, window_table=wtable)
 
             wrapped.__name__ = PROGRAM_RAGGED_FWD   # jitwatch catalog name
             donate = (7, 8, 9, 10) if self.cache.quantized else (7, 8)
@@ -1437,6 +1545,10 @@ class Engine:
                 entries.append((r, r.seq_len, r.seq_len))
         if not entries:
             return None
+        if self.window_allocator is not None:
+            with trace.annotation(obs_names.SPAN_KV_WINDOW_RELEASE):
+                for r, start, end in entries:
+                    self._window_pages_for(r, start, max(end, start + 1))
 
         P = self.cfg.max_pages_per_seq
         Rb = self._bucket(len(entries))
@@ -1532,6 +1644,8 @@ class Engine:
             start = req.prefill_pos
             end = min(start + chunk, len(req.prompt))
             rows.append((req, start, end))
+            if self.window_allocator is not None:
+                self._window_pages_for(req, start, end)
 
         B = self._bucket(len(batch))
         self._note_dispatch("prefill", len(rows),
@@ -1826,9 +1940,10 @@ class Engine:
                   v_pages, k_scales, v_scales, keys, temps, ks, tps, mps,
                   pmask=None, ocounts=None, rep=None, pres=None, freq=None,
                   lora=None, lids=None, gnext=None, glegal=None,
-                  gstate=None, gactive=None, state=None, slots=None):
+                  gstate=None, gactive=None, state=None, slots=None,
+                  window=None, wtable=None):
             def body(carry, _):
-                tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st = carry
+                tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st, wp = carry
                 # Rows at their length limit (mid-window finishers) stop
                 # writing KV and stop advancing — their sampled values are
                 # discarded host-side via the per-row valid count.
@@ -1837,9 +1952,14 @@ class Engine:
                     params, tokens=tok[:, None], positions=pos[:, None],
                     token_mask=write_ok, kv_lens=kvl, page_table=table,
                     k_pages=kp, v_pages=vp, k_scales=ksc, v_scales=vsc,
-                    lora=lora, lora_ids=lids, state=st, state_slots=slots)
-                if st is not None:           # the new state follows the pools
+                    lora=lora, lora_ids=lids, state=st, state_slots=slots,
+                    window_pages=wp, window_table=wtable)
+                # The new state follows the pools (its place, where the
+                # model has window layers alone), then the window class.
+                if st is not None or wp is not None:
                     st = visited.pop(0)
+                if wp is not None:
+                    wp = visited.pop(0)
                 # The experts a hit-only step visited; none to count where
                 # the experts are sharded or the step is dense.
                 visited = visited[0] if visited else None
@@ -1868,21 +1988,24 @@ class Engine:
                 pos = jnp.where(active, pos + 1, pos)
                 kvl = jnp.where(active, kvl + 1, kvl)
                 tok = jnp.where(active, toks, tok)
-                return (tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st), (
+                return (tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st, wp), (
                     toks, lps if lp else None, visited)
 
             oc0 = ocounts if pen else jnp.zeros((), jnp.int32)
             gs0 = gstate if gr else jnp.zeros((), jnp.int32)
             carry, ys = jax.lax.scan(
                 body, (tok, pos, kvl, k_pages, v_pages, k_scales, v_scales,
-                       oc0, gs0, state), None, length=K)
-            tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st = carry
+                       oc0, gs0, state, window), None, length=K)
+            tok, pos, kvl, kp, vp, ksc, vsc, oc, gs, st, wp = carry
             toks_seq, lp_seq, visited = ys
             if visited is not None:          # hit experts only: the window's
                 visited = visited.sum()      # count rides out beside the tokens
             out = (toks_seq, lp_seq, visited, tok, pos, kvl, kp, vp, ksc,
                    vsc, oc, gs)
-            # A model with recurrent layers: its state pool comes last.
+            # A model with recurrent layers: its state pool comes last;
+            # one with window layers: the state's place, then that class.
+            if wp is not None:
+                return out + (st, wp)
             return out if st is None else out + (st,)
 
         # tok is NOT donated: the pending fetch reads last window's output
@@ -1931,6 +2054,8 @@ class Engine:
         }
         if self.state is not None:
             st["slots"] = self._slot_rows(batch, B)
+        if self.window_allocator is not None:
+            st["wtable"] = jnp.asarray(self._window_table(batch, B))
         if pen:
             pmask, oc, rep, pres, freq = self._penalty_rows(batch, B)
             for i, r in enumerate(batch):
@@ -2002,6 +2127,9 @@ class Engine:
                           gstate=st["gstate"], gactive=st["gactive"])
             if self.state is not None:
                 kw.update(state=self.state.arrays, slots=st["slots"])
+            if self.window_allocator is not None:
+                kw.update(window=self.cache.window_pages,
+                          wtable=st["wtable"])
             (toks_seq, lp_seq, visited, tok, pos, kvl, kp, vp, ksc, vsc, oc,
              gs, *state) = fn(
                 self.params, st["tok"], st["pos"], st["kvl"], st["table"],
@@ -2098,6 +2226,16 @@ class Engine:
         if not batch:
             return None
 
+        if self.window_allocator is not None:
+            # A row's line moves once in ``page_size`` steps; the table is
+            # laid out anew in the steps in which some row's did.
+            with trace.annotation(obs_names.SPAN_KV_WINDOW_RELEASE):
+                moved = [self._window_pages_for(
+                    r, r.seq_len, min(r.seq_len + K, r.max_len()))
+                    for r in batch]
+            if st is not None and any(moved):
+                st["wtable"] = jnp.asarray(
+                    self._window_table(batch, st["B"]))
         if st is None:
             st = self._dec = self._build_decode_state(batch)
         elif pages_changed:
@@ -2339,11 +2477,12 @@ class Engine:
             req.state = "exported"
             return
         self._release_slot(req)
+        self._release_window(req)
         if (self.radix is not None and req.lora_idx == 0
-                and self.state is None):
+                and not self.mcfg.unbuilt_for):
             # Cache the full sequence (prompt + output) for future prefixes
             # (base-model requests only — adapter KV must not cross-match;
-            # never a model with recurrent layers: see ``_admit``).
+            # never a model with recurrent or window layers: see ``_admit``).
             self.radix.insert(req.prompt + req.output[:-1], req.pages)
             if self.host_tier is not None:
                 self._publish_tier_gauges()
@@ -2373,6 +2512,7 @@ class Engine:
             self.allocator.release(req.pages)
             req.pages = []
         self._release_slot(req)
+        self._release_window(req)
         self.requests.pop(req_id, None)
         return True
 
@@ -2380,8 +2520,10 @@ class Engine:
         self.metrics["preemptions"] += 1
         self.allocator.release(req.pages)
         req.pages = []
-        # Its next admission prefills from position 0 into a fresh slot.
+        # Its next admission prefills from position 0 into a fresh slot
+        # (and a window line that holds nothing).
         self._release_slot(req)
+        self._release_window(req)
         req.state = "waiting"
         req.prefill_pos = 0
         req.seq_len = 0
@@ -2429,13 +2571,15 @@ class Engine:
 
             def wrapped(params, tokens, positions, token_mask, kv_lens,
                         page_table, k_pages, v_pages, k_scales, v_scales,
-                        lora=None, lids=None, state=None, slots=None):
+                        lora=None, lids=None, state=None, slots=None,
+                        window=None, wtable=None):
                 return base(params, tokens=tokens, positions=positions,
                             token_mask=token_mask, kv_lens=kv_lens,
                             page_table=page_table, k_pages=k_pages,
                             v_pages=v_pages, k_scales=k_scales,
                             v_scales=v_scales, lora=lora, lora_ids=lids,
-                            state=state, state_slots=slots)
+                            state=state, state_slots=slots,
+                            window_pages=window, window_table=wtable)
 
             wrapped.__name__ = PROGRAM_PAGED_FWD   # jitwatch catalog name
             donate = (6, 7, 8, 9) if self.cache.quantized else (6, 7)

@@ -18,6 +18,15 @@ pages for its attention layers alone (``[attention layers, NP, ...]``, a
 layer's pages by its ordinal among them) and, beside them, a ``StatePool``:
 a slot a live row, holding each recurrent layer's fixed state.
 
+A model with window layers (``cfg.sliding_window``) keeps TWO CLASSES of
+page in one cache: the full class above for its full-attention layers, and
+a window class ``window_k/window_v [window layers, NPw, page, KV, hd]`` with
+an allocator, a pool size (``window_pool_pages``) and a table line a row of
+its own. Both classes index a row's line by absolute column (``position //
+page``), so the write is one rule; a window line's entries below the row's
+live range name pages that were given back (the engine writes 0 there), and
+the walk never follows them.
+
 Sharding: pages shard over ``tp`` on the KV-head dim like the contiguous
 cache (see rbg_tpu.parallel.sharding.cache_specs).
 """
@@ -36,6 +45,22 @@ from rbg_tpu.models.config import ModelConfig
 
 
 _LANES = 128    # a TPU lane tile: the minor dim of every tiled layout
+
+# What the window class's null page holds in every slot of K and V. A window
+# line's entries below the live range are 0, the null page, and the walk
+# masks whatever of it a first live block brings along; a page given back
+# too early, or a mask that forgot the window, would ATTEND it. Zeros there
+# would pass for sixteen more keys of no weight (measured on the chip, PR
+# 46: a page given back one page early read 1.03-1.32 times the sound
+# program's largest `correct` readings); keys of this size take the softmax
+# of every head whose query sums positive, so such a read is loud. Finite,
+# and a masked slot still leaves nothing behind, though not because its
+# probability is 0 throughout: a query row wholly masked in its first block
+# (a later token of a ragged tile) starts from a running maximum of -1e30,
+# so that block's slots weigh 1 each and these values stand in its
+# accumulator until the first block with a live slot raises the maximum
+# and its ``alpha = exp(-1e30 - m) = 0`` wipes sum and accumulator alike.
+WINDOW_NULL_PAGE = 8.0
 
 
 def rope_pool_width(cfg: ModelConfig) -> int:
@@ -92,6 +117,15 @@ class PagedKVCache:
     v_pages: jnp.ndarray
     k_scales: Optional[jnp.ndarray] = None
     v_scales: Optional[jnp.ndarray] = None
+    # The window class (a model with window layers): [Lw, NPw, page, KV, hd]
+    window_k: Optional[jnp.ndarray] = None
+    window_v: Optional[jnp.ndarray] = None
+
+    @property
+    def window_pages(self) -> Optional[tuple]:
+        """The window class's pools as a step program takes them."""
+        return None if self.window_k is None else (self.window_k,
+                                                   self.window_v)
 
     @property
     def page_size(self) -> int:
@@ -108,9 +142,10 @@ class PagedKVCache:
     @staticmethod
     def create(cfg: ModelConfig, num_pages: int, page_size: int = 16,
                dtype=None, quantize: bool = False,
-               tp: int = 1) -> "PagedKVCache":
+               tp: int = 1, window_num_pages: int = 0) -> "PagedKVCache":
         """``tp``: over how many devices the pool's head axis will be
-        sharded (``Engine._shard_state``)."""
+        sharded (``Engine._shard_state``). ``window_num_pages``: pages of
+        the window class, where the model has window layers."""
         layers = paged_layer_count(cfg)
         if cfg.mla:
             # MLA latent pool: k holds the compressed latent, v the shared
@@ -145,16 +180,24 @@ class PagedKVCache:
         dtype = dtype or cfg.jax_dtype
         p = heads_per_lane_tile(cfg, tp)
         shape = shape[:3] + (cfg.num_kv_heads // p, p * cfg.head_dim_)
+        window = {}
+        if cfg.sliding_window:
+            wshape = (paged_layer_count(cfg, "window"),
+                      window_num_pages) + shape[2:]
+            window = {name: jnp.zeros(wshape, dtype).at[:, 0].set(
+                WINDOW_NULL_PAGE) for name in ("window_k", "window_v")}
         return PagedKVCache(k_pages=jnp.zeros(shape, dtype),
-                            v_pages=jnp.zeros(shape, dtype))
+                            v_pages=jnp.zeros(shape, dtype), **window)
 
     @staticmethod
     def hbm_bytes(cfg: ModelConfig, num_pages: int, page_size: int = 16,
-                  dtype_bytes: int = 2) -> int:
-        """Bytes of the two page pools ``create`` allocates (an int8
-        pool's scales are 4 bytes a (slot, head) more, not counted). A
-        model with recurrent layers holds ``StatePool.hbm_bytes`` more."""
-        layers = paged_layer_count(cfg)
+                  dtype_bytes: int = 2, kind: str = "full") -> int:
+        """Bytes of the two page pools ``create`` allocates for the class
+        of page ``kind`` (``full``, or ``window`` at its own ``num_pages``;
+        an int8 pool's scales are 4 bytes a (slot, head) more, not
+        counted). A model with recurrent layers holds
+        ``StatePool.hbm_bytes`` more."""
+        layers = paged_layer_count(cfg, kind)
         if cfg.mla:
             per_tok = cfg.kv_lora_rank + rope_pool_width(cfg)
             return layers * num_pages * page_size * per_tok * dtype_bytes
@@ -162,9 +205,27 @@ class PagedKVCache:
                 * cfg.num_kv_heads * cfg.head_dim_ * dtype_bytes)
 
 
-def paged_layer_count(cfg: ModelConfig) -> int:
-    """Layers that keep pages: all but the recurrent ones."""
-    return cfg.mixer_count("full")
+def paged_layer_count(cfg: ModelConfig, kind: str = "full") -> int:
+    """Layers that keep pages of the class ``kind``: ``full`` (this
+    config's attention) or ``window``; the recurrent ones keep none."""
+    return cfg.mixer_count(kind)
+
+
+def window_pages_per_row(cfg: ModelConfig, page_size: int,
+                         ahead: int) -> int:
+    """The most pages of the window class one row can hold: its live
+    range is the ``sliding_window - 1`` slots below the oldest query of a
+    step and up to ``ahead`` slots from that query on (a prompt's chunk,
+    or a fused decode window), and either end can stand inside a page."""
+    return pages_for_tokens(cfg.sliding_window - 1 + ahead, page_size) + 1
+
+
+def window_pool_pages(cfg: ModelConfig, page_size: int, max_batch: int,
+                      ahead: int) -> int:
+    """Pages of the window class's pool: every row's most, and the null
+    page. Sized by the rows' WINDOWS, not their contexts: it never runs
+    out, so nothing is preempted for it."""
+    return max_batch * window_pages_per_row(cfg, page_size, ahead) + 1
 
 
 class StatePool:

@@ -93,6 +93,13 @@ def load_hf_llama(path: str, cfg: ModelConfig) -> dict:
             "HF import does not map attn_gate's projection (wg): no "
             "dense llama-family checkpoint publishes one — load via orbax "
             "instead.")
+    if cfg.sliding_window or cfg.rope_scaling \
+            or cfg.partial_rotary_factor != 1.0:
+        raise NotImplementedError(
+            "HF import does not map a model with window layers "
+            "(sliding_window: per-kind head counts, wq / wo stacked by "
+            "kind) or with scaled or partial rotary settings: no dense "
+            "llama-family checkpoint has them — load via orbax instead.")
     sd = _hf_state_dict(path)
     dt = cfg.jax_dtype
     L = cfg.num_layers

@@ -28,10 +28,32 @@ class _Numbers(tuple):
     __hash__ = tuple.__hash__
 
 
+class _Fields(tuple):
+    """A sorted tuple of ``(field, value)`` pairs that also equals the dict
+    of them, for ``_Numbers``' reasons."""
+
+    def __eq__(self, other):
+        return tuple.__eq__(self, tuple(sorted(other.items()))
+                            if isinstance(other, dict) else other)
+
+    def __ne__(self, other):
+        return not self == other
+
+    __hash__ = tuple.__hash__
+
+
 # What mixes tokens in a layer -> the key its half-layers are stacked under.
-MIXER_KEYS = {"full": "mixers", "kda": "kda_mixers", "conv": "conv_mixers"}
+MIXER_KEYS = {"full": "mixers", "kda": "kda_mixers", "conv": "conv_mixers",
+              "window": "window_mixers"}
 # A published ``layer_types`` entry -> the mixer kind.
-_LAYER_TYPES = {"full_attention": "full", "conv": "conv"}
+_LAYER_TYPES = {"full_attention": "full", "conv": "conv",
+                "sliding_attention": "window"}
+# The mixer kinds that keep K/V pages, each a class of page of its own
+# (``engine/kvcache.py``); every other kind keeps a recurrent state.
+PAGED_KINDS = ("full", "window")
+# What ``attn_gate`` may be: no gate, a gate a channel of every head (True,
+# as a file that predates the forms says it), a gate a head.
+_GATE_FORMS = (False, True, "channel", "head")
 
 
 def _half_keys(g) -> tuple:
@@ -102,7 +124,35 @@ class ModelConfig:
     # ``use_gqa_gate``; arXiv:2505.06708's elementwise form): ``wo (o *
     # sigmoid(x~ wg))``, ``wg [d, heads x head_dim]``, a gate a channel of
     # every head, from the layer's normed input (``llama._attn_gate``).
-    attn_gate: bool = False
+    # The FORM is the value: ``"channel"`` (what ``True`` means) or
+    # ``"head"``, one scalar a head a token, ``wg [d, heads]`` (Laguna
+    # ``gating``; the paper's headwise form); ``gate_a_head`` reads it.
+    attn_gate: object = False
+    # Rotary settings (``ops/rope.py::rotary_tables``). The first
+    # ``partial_rotary_factor`` of a head's channels are rotated and the
+    # rest pass as they are; ``rope_scaling`` ``"yarn"`` blends each
+    # frequency between its own and its ``1 / rope_factor`` by where its
+    # wavelength lies against ``rope_original_max`` positions
+    # (``rope_beta_fast`` / ``rope_beta_slow`` rotations) and multiplies
+    # cos and sin by ``rope_attention_factor`` (0: ``0.1 ln(factor) + 1``).
+    # Of this config's attention; a window layer's are ``window_layer``'s.
+    partial_rotary_factor: float = 1.0
+    rope_scaling: str = ""
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attention_factor: float = 0.0
+    # Window layers (``layer_types`` ``sliding_attention``, mixer kind
+    # ``window``): a query attends the ``sliding_window`` newest keys, its
+    # own included (``j > i - sliding_window``), so a row's pages of such a
+    # layer are given back once they lie below every window a later query
+    # can have: a second class of page (``engine/kvcache.py``).
+    # ``window_layer`` holds what else differs in a window layer, ``{field:
+    # value}`` over this config's (its head count, its rotary settings); the
+    # KIND's group config (``layer_groups``) is this config with them.
+    sliding_window: int = 0
+    window_layer: object = ()
     # Recurrent layers (Kimi Delta Attention, ``ops/kda.py``): the 1-based
     # numbers, as published, of the layers that mix tokens by a gated delta
     # rule over a fixed state ``[kda_num_heads, kda_head_dim, kda_head_dim]``
@@ -134,8 +184,20 @@ class ModelConfig:
     # RMSNorm over each query and key head, with a learned weight, before
     # RoPE (GQA attention only).
     qk_norm: bool = False
+    # Grouped-query attention's input projections held ``[L, out, in]``
+    # (``wq [L, h hd, d]``, ``wk``, ``wv`` likewise), the layout their dots
+    # take on the chip: held ``[L, in, out]`` the compiler transposes a
+    # layer's slice in every trip, or the whole stack in every step where
+    # a kind's loop has several trips (ROADMAP S23 + S14). No option: a
+    # model with window layers holds them so, in both its kinds (it takes
+    # no adapters, ``unbuilt_for``), and no constructor takes the field:
+    # ``__post_init__`` reads it off ``sliding_window`` and ``_kind`` hands
+    # it to the full kind's group config.
+    proj_out_in: bool = dataclasses.field(default=False, init=False)
     # What a GROUP config's layers mix tokens by: ``full`` (this config's
-    # attention), ``kda`` or ``conv``. Set by ``layer_groups`` alone.
+    # attention), ``window`` (the same under ``window_layer``'s fields,
+    # within ``sliding_window``), ``kda`` or ``conv``. Set by
+    # ``layer_groups`` alone.
     attention: str = "full"
     # Which half of a layer a group config of ``param_groups`` stands for:
     # ``mixer`` (norm, what mixes tokens, its output projection), ``mlp``
@@ -161,6 +223,38 @@ class ModelConfig:
                     f", and leaves kda_layers empty; got "
                     f"{len(self.layer_types)} entries, unknown {unknown}, "
                     f"kda_layers {tuple(self.kda_layers)}")
+        if self.attn_gate not in _GATE_FORMS:
+            raise ValueError(f"attn_gate is one of {_GATE_FORMS} (True: "
+                             f"'channel'); got {self.attn_gate!r}")
+        if self.rope_scaling not in ("", "yarn"):
+            raise ValueError(
+                f"rope_scaling {self.rope_scaling!r}: only 'yarn' is built "
+                f"(ops/rope.py::rotary_tables)")
+        window_layer = dict(self.window_layer)
+        unknown = sorted(set(window_layer) - {
+            f.name for f in dataclasses.fields(self)})
+        if unknown:
+            raise ValueError(f"window_layer names {unknown}: no field of "
+                             f"ModelConfig")
+        object.__setattr__(self, "window_layer",
+                           _Fields(sorted(window_layer.items())))
+        if ("window" in self.mixer_kinds or self.attention == "window") \
+                != bool(self.sliding_window):
+            raise ValueError(
+                f"sliding_window {self.sliding_window} and layer_types "
+                f"disagree: a sliding_attention layer needs a window, and a "
+                f"window needs such a layer")
+        if self.sliding_window and self.mla:
+            raise ValueError("window layers are grouped-query attention's; "
+                             "the latent attention (mla) has none")
+        if window_layer.get("sliding_window",
+                            self.sliding_window) != self.sliding_window:
+            raise ValueError(
+                f"window_layer.sliding_window "
+                f"{window_layer['sliding_window']} is not sliding_window "
+                f"{self.sliding_window}: the model's mask and the engine's "
+                f"pages read one window")
+        object.__setattr__(self, "proj_out_in", bool(self.sliding_window))
         if self.attn_gate and self.mla:
             raise ValueError(
                 "attn_gate gates grouped-query attention's output; the "
@@ -198,8 +292,9 @@ class ModelConfig:
 
     @property
     def mixer_kinds(self) -> Tuple[str, ...]:
-        """What mixes tokens in each layer, in order: ``full`` (pages),
-        ``kda`` or ``conv`` (a slot of the state pool)."""
+        """What mixes tokens in each layer, in order: ``full`` or ``window``
+        (pages, a class each), ``kda`` or ``conv`` (a slot of the state
+        pool)."""
         if self.layer_types:
             return tuple(_LAYER_TYPES[t] for t in self.layer_types)
         return tuple("kda" if layer + 1 in self.kda_layers else "full"
@@ -213,12 +308,43 @@ class ModelConfig:
     def recurrent_kinds(self) -> Tuple[str, ...]:
         """The mixer kinds that keep a recurrent state in place of pages,
         by name (a refusal's message names them)."""
-        return tuple(sorted(set(self.mixer_kinds) - {"full"}))
+        return tuple(sorted(set(self.mixer_kinds) - set(PAGED_KINDS)))
 
     @property
     def recurrent(self) -> bool:
-        """Whether some layer keeps a recurrent state in place of pages."""
+        """Whether some layer keeps a recurrent state in place of pages
+        (the engine holds a ``StatePool``)."""
         return bool(self.recurrent_kinds)
+
+    @property
+    def by_kind(self) -> bool:
+        """Whether the layers are stacked and walked by KIND: some layer's
+        mixer is not this config's own attention, so the parameters come in
+        half-layer stacks (``param_groups``) and the pools are walked by
+        ``llama._hybrid_layers``. Says nothing of what the kinds keep:
+        ``recurrent`` (a state a row) and ``sliding_window`` (a second
+        class of page) say that."""
+        return set(self.mixer_kinds) != {"full"}
+
+    @property
+    def unbuilt_for(self) -> str:
+        """Why this model is served by the unified engine over its pools
+        alone, as the start of a refusal's message; empty where nothing is
+        refused. What is refused and why is the refuser's to say."""
+        if self.recurrent:
+            return (f"has recurrent layers "
+                    f"({', '.join(self.recurrent_kinds)})")
+        if self.sliding_window:
+            return (f"has window layers (sliding_window "
+                    f"{self.sliding_window})")
+        return ""
+
+    def _kind(self, **fields):
+        """This config with ``fields`` replaced, as a KIND's group config:
+        it holds its projections as this one does (``proj_out_in``)."""
+        kind = dataclasses.replace(self, **fields)
+        object.__setattr__(kind, "proj_out_in", self.proj_out_in)
+        return kind
 
     @property
     def layer_groups(self):
@@ -235,9 +361,9 @@ class ModelConfig:
         key in several runs and stacks its parameters by half-layer
         (``param_groups``)."""
         n = self.num_layers - self.num_moe_layers if self.num_experts else 0
-        if not n and not self.recurrent:
+        if not n and not self.by_kind:
             return (("blocks", self, 0, self.num_layers),)
-        if not self.recurrent:
+        if not self.by_kind:
             dense = dataclasses.replace(self, num_experts=0,
                                         first_dense_layers=0)
             return (("dense_blocks", dense, 0, n),
@@ -248,11 +374,14 @@ class ModelConfig:
             key = ("" if mixer == "full" else mixer + "_") \
                 + ("dense_" if dense else "") + "blocks"
             if key not in kinds:
-                kinds[key] = dataclasses.replace(
-                    self, kda_layers=(), layer_types=(),
+                kinds[key] = self._kind(
+                    kda_layers=(), layer_types=(),
                     first_dense_layers=0, attention=mixer,
                     **({"num_experts": 0, "experts_held": None}
-                       if dense else {}))
+                       if dense else {}),
+                    **(dict(self.window_layer, window_layer=())
+                       if mixer == "window" else
+                       {"sliding_window": 0, "window_layer": ()}))
             if runs and runs[-1][0] == key:
                 runs[-1][3] = layer + 1
             else:
@@ -270,14 +399,14 @@ class ModelConfig:
         kind (``dense_mlps``, ``moe_mlps``), so that a walk compiles each
         mixer once whatever MLP follows it (``layer_halves`` says which
         entries are a layer's)."""
-        if not self.recurrent:
+        if not self.by_kind:
             return tuple((key, g, hi - lo) for key, g, lo, hi
                          in self.layer_groups)
         groups = {}
         for key, g, lo, hi in self.layer_groups:
             for name, half in zip(_half_keys(g), ("mixer", "mlp")):
                 if name not in groups:
-                    groups[name] = [name, dataclasses.replace(g, half=half), 0]
+                    groups[name] = [name, g._kind(half=half), 0]
                 groups[name][2] += hi - lo
         return tuple(tuple(v) for v in groups.values())
 
@@ -304,6 +433,23 @@ class ModelConfig:
             return 0
         return self.num_layers - min(self.first_dense_layers, self.num_layers)
 
+    def _gqa_params(self, heads: int) -> int:
+        """Parameters of one grouped-query mixer of ``heads`` query heads:
+        q, k, v, o, the head norms, the gate by its form."""
+        d, hd = self.hidden_size, self.head_dim_
+        n = 2 * d * heads * hd + 2 * d * self.num_kv_heads * hd
+        if self.qk_norm:
+            n += 2 * hd
+        if self.attn_gate:      # wg: a channel of every head, or a head
+            n += d * heads * (1 if self.gate_a_head else hd)
+        return n
+
+    @property
+    def gate_a_head(self) -> bool:
+        """Whether ``attn_gate`` is the headwise form (else, where there
+        is a gate, a gate a channel)."""
+        return self.attn_gate == "head"
+
     @property
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + blocks + head)."""
@@ -321,11 +467,7 @@ class ModelConfig:
                     + dc * h * dv            # w_uv
                     + h * dv * d)            # wo
         else:
-            attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
-            if self.qk_norm:
-                attn += 2 * hd
-            if self.attn_gate:
-                attn += d * self.num_heads * hd     # wg
+            attn = self._gqa_params(self.num_heads)
         dense_mlp = 3 * d * f
         # The experts this device holds; the router is whole.
         moe_mlp = self.experts_here * 3 * d * self.moe_f + d * self.num_experts
@@ -343,8 +485,10 @@ class ModelConfig:
                + kh + ch + d * kh           # A_log, dt_bias, beta
                + kd + ch * d)               # o_norm, wo
         conv = 3 * d * d + self.conv_kernel * d + d * d     # in, taps, out
-        attn_all = sum({"full": attn, "kda": kda, "conv": conv}[kind]
-                       for kind in self.mixer_kinds)
+        window = self._gqa_params(
+            dict(self.window_layer).get("num_heads", self.num_heads))
+        attn_all = sum({"full": attn, "kda": kda, "conv": conv,
+                        "window": window}[kind] for kind in self.mixer_kinds)
         return (v * d + attn_all + self.num_layers * 2 * d + mlp + d + head)
 
 
@@ -496,6 +640,32 @@ _PRESETS = {
         use_rope=False, attn_gate=True,
         kda_layers=(2, 3, 4, 6, 7, 8), kda_num_heads=4, kda_head_dim=32,
         kda_rank=16, kda_beta_scale=2.0,
+    ),
+    # Tiny Laguna-shaped model for tests (the layers of the benchmark's
+    # laguna-xs2): two periods F W W W (F: full attention of 6 heads that
+    # rotates half its channels under YaRN; W: 8 heads within a window of 8
+    # tokens, plain RoPE over the whole head, its pages a class of their
+    # own), 2 KV heads in both (groups of 3 and 4), a gate a head, a dense
+    # first layer, bias-selected sigmoid experts scaled by 2.5 beside a
+    # shared one, a held range of them.
+    "tiny-laguna": ModelConfig(
+        name="tiny-laguna", vocab_size=256, hidden_size=128,
+        intermediate_size=320, num_layers=8, num_heads=6, num_kv_heads=2,
+        head_dim=32, max_seq_len=256, rope_theta=50000.0, rms_norm_eps=1e-6,
+        dtype="float32",
+        num_experts=16, experts_per_token=4, moe_intermediate_size=48,
+        moe_shared_expert=True, moe_shared_expert_size=48,
+        first_dense_layers=1, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_routed_scale=2.5, experts_held=(4, 12),
+        attn_gate="head",
+        partial_rotary_factor=0.5, rope_scaling="yarn",
+        rope_factor=8.0, rope_original_max=32, rope_beta_fast=4.0,
+        rope_beta_slow=1.0, rope_attention_factor=1.2079441541679836,
+        sliding_window=8,
+        window_layer={"num_heads": 8, "rope_theta": 10000.0,
+                      "partial_rotary_factor": 1.0, "rope_scaling": ""},
+        layer_types=("full_attention",) + ("sliding_attention",) * 3
+        + ("full_attention",) + ("sliding_attention",) * 3,
     ),
 }
 

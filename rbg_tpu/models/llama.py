@@ -29,7 +29,7 @@ from rbg_tpu.models.config import ModelConfig
 from rbg_tpu.ops.attention import gqa_attention
 from rbg_tpu.ops.norms import rms_norm
 from rbg_tpu.ops.pallas import dispatch_pallas
-from rbg_tpu.ops.rope import apply_rope
+from rbg_tpu.ops.rope import apply_rope, rotary_tables
 
 
 @jax.tree_util.register_dataclass
@@ -136,18 +136,22 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
             "wo": nrm(ks[4], (L, h * dv, d), s_out),
         })
     else:
+        # ``cfg.proj_out_in``: the input projections as ``[L, out, in]``
+        into = (lambda n: (L, n, d)) if cfg.proj_out_in else (
+            lambda n: (L, d, n))
         blocks.update({
-            "wq": nrm(ks[1], (L, d, h * hd), s_in),
-            "wk": nrm(ks[2], (L, d, kv * hd), s_in),
-            "wv": nrm(ks[3], (L, d, kv * hd), s_in),
+            "wq": nrm(ks[1], into(h * hd), s_in),
+            "wk": nrm(ks[2], into(kv * hd), s_in),
+            "wv": nrm(ks[3], into(kv * hd), s_in),
             "wo": nrm(ks[4], (L, h * hd, d), s_out),
         })
         if cfg.qk_norm:
             blocks.update({"q_head_norm": jnp.ones((L, hd), dt),
                            "k_head_norm": jnp.ones((L, hd), dt)})
-        if cfg.attn_gate:
-            blocks["wg"] = nrm(jax.random.fold_in(ks[1], 2), (L, d, h * hd),
-                               s_in)
+        if cfg.attn_gate:       # a gate a channel of every head, or a head
+            blocks["wg"] = nrm(
+                jax.random.fold_in(ks[1], 2),
+                (L, d, h if cfg.gate_a_head else h * hd), s_in)
     if cfg.half == "mixer":
         del blocks["mlp_norm"]
         return blocks
@@ -236,14 +240,20 @@ def _lora_proj(xa, base_w, name, lora, lora_ids):
 
 def _qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
     """Shared pre-attention math: norm → projections (+opt bias) → (opt
-    RMSNorm of each query and key head, ``cfg.qk_norm``) → RoPE (none
-    without ``cfg.use_rope``: keys are cached as projected)."""
+    RMSNorm of each query and key head, ``cfg.qk_norm``) → RoPE by the
+    layer kind's rotary settings (``rope.rotary_tables`` of ``cfg``, the
+    kind's group config; none without ``cfg.use_rope``: keys are cached as
+    projected)."""
     B, T, _ = x.shape
     hd, h, kv = cfg.head_dim_, cfg.num_heads, cfg.num_kv_heads
     xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
-    q = _lora_proj(xa, blk["wq"], "wq", lora, lora_ids)
-    k = _lora_proj(xa, blk["wk"], "wk", lora, lora_ids)
-    vv = _lora_proj(xa, blk["wv"], "wv", lora, lora_ids)
+    if cfg.proj_out_in:     # held [out, in]: a window model, no adapters
+        q, k, vv = (jnp.einsum("btd,nd->btn", xa, blk[w])
+                    for w in ("wq", "wk", "wv"))
+    else:
+        q = _lora_proj(xa, blk["wq"], "wq", lora, lora_ids)
+        k = _lora_proj(xa, blk["wk"], "wk", lora, lora_ids)
+        vv = _lora_proj(xa, blk["wv"], "wv", lora, lora_ids)
     if "bq" in blk:  # Qwen2-style attention bias
         q = q + blk["bq"]
         k = k + blk["bk"]
@@ -255,14 +265,18 @@ def _qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
         q = rms_norm(q, blk["q_head_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, blk["k_head_norm"], cfg.rms_norm_eps)
     if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_interleave)
-        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_interleave)
+        rotary = rotary_tables(cfg)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_interleave,
+                       rotary)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_interleave,
+                       rotary)
     return q, k, vv
 
 
 def _attn_gate(cfg: ModelConfig, blk, x, attn):
     """``cfg.attn_gate``: grouped-query attention's output ``[B, T, h, hd]``
-    times ``sigmoid(x~ wg)``, a gate a channel of every head, from the
+    times ``sigmoid(x~ wg)``, a gate a channel of every head (``"channel"``,
+    ``wg [d, h hd]``) or a gate a head (``"head"``, ``wg [d, h]``), from the
     layer's normed input (the norm is ``_qkv``'s own, which the compiler
     computes once). Called inside the ``attention`` scope: its operations'
     paths hold ``attention/gate``. Without the field, ``attn`` as it is."""
@@ -271,7 +285,9 @@ def _attn_gate(cfg: ModelConfig, blk, x, attn):
     with jax.named_scope("gate"):
         xa = rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps)
         gate = jax.nn.sigmoid((xa @ blk["wg"]).astype(jnp.float32))
-        return (attn * gate.reshape(attn.shape)).astype(attn.dtype)
+        gate = (gate[..., None] if cfg.gate_a_head
+                else gate.reshape(attn.shape))
+        return (attn * gate).astype(attn.dtype)
 
 
 def _mla_qkv(cfg: ModelConfig, blk, x, positions, lora=None, lora_ids=None):
@@ -324,8 +340,8 @@ def _mla_scale(cfg: ModelConfig) -> float:
 
 # The scope of a mixer's output projection, by what mixes tokens: a KDA
 # layer's lies with its input projections (``_kda_attention``).
-_WO_SCOPES = {"full": "attention", "conv": "attention/conv",
-              "kda": "attention/kda/proj"}
+_WO_SCOPES = {"full": "attention", "window": "attention/window",
+              "conv": "attention/conv", "kda": "attention/kda/proj"}
 
 
 def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
@@ -609,13 +625,14 @@ def forward(
 
 
 def _no_recurrent(cfg: ModelConfig, what: str) -> None:
-    """Recurrent layers are served over the paged pools alone."""
-    if cfg.recurrent:
+    """Recurrent layers and window layers are served over the paged pools
+    alone (``cfg.unbuilt_for`` names which the model has)."""
+    if cfg.unbuilt_for:
+        keeps = ("keeps no state for them" if cfg.recurrent else
+                 "keeps every key and has no window mask")
         raise NotImplementedError(
-            f"{cfg.name} has recurrent layers "
-            f"({', '.join(cfg.recurrent_kinds)}): "
-            f"{what} keeps no state for them; serve it through the engine "
-            f"(forward_paged / forward_ragged)")
+            f"{cfg.name} {cfg.unbuilt_for}: {what} {keeps}; serve it "
+            f"through the engine (forward_paged / forward_ragged)")
 
 
 class PoolAddr(NamedTuple):
@@ -631,6 +648,10 @@ class PoolAddr(NamedTuple):
     # [R] int32: each row's slot of the recurrent-state pool (a model with
     # recurrent layers only); a row of padding names a slot out of range.
     state_slots: Optional[jnp.ndarray] = None
+    # [R, P] int32: the rows' lines of the WINDOW class of page (a model
+    # with window layers only), by absolute column like ``page_table``;
+    # an entry whose page was given back is 0 and is never attended.
+    window_table: Optional[jnp.ndarray] = None
 
 
 def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
@@ -641,19 +662,22 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
     the input says (``row_ids``). ``table`` is the layer's own. Returns
     (attn ``[B, T, h, dv]``, pool). For a recurrent layer (``cfg.attention``
     ``kda`` or ``conv``) ``pool`` is the state pool's arrays and ``table``
-    the layer's ordinal in them (``_kda_attention``, ``_conv_attention``)."""
+    the layer's ordinal in them (``_kda_attention``, ``_conv_attention``);
+    for a window layer (``window``) ``pool`` is the window class's pools
+    and ``table`` the layer's own of ``addr.window_table``: the write is
+    the same, the attend keeps to ``cfg.sliding_window``."""
     from rbg_tpu.ops.mla_attention import (paged_mla_attention,
                                             ragged_paged_mla_attention)
     from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
     from rbg_tpu.ops.ragged_paged_attention import (ragged_paged_attention,
                                                     write_kv_pages_ragged)
 
-    if cfg.attention != "full":
-        mixer = {"kda": _kda_attention, "conv": _conv_attention}
+    mixer = {"kda": _kda_attention, "conv": _conv_attention}
+    if cfg.attention in mixer:
         with jax.named_scope(cfg.attention):
             return mixer[cfg.attention](cfg, blk, x, pool, table, addr,
                                         use_pallas)
-    positions, token_mask, kv_lens, _, row_ids, max_q_len, _ = addr
+    positions, token_mask, kv_lens, _, row_ids, max_q_len, *_ = addr
     if row_ids is None:
         write, attend, attend_mla = (write_kv_pages, paged_attention,
                                      paged_mla_attention)
@@ -680,6 +704,8 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
         return _mla_out(cfg, blk, attend_mla(
             *q, *where, _mla_scale(cfg), use_pallas=use_pallas, c_scales=ksf,
             pe_scales=vsf, **bound)), pool
+    if cfg.attention == "window":   # static: another walk, another mask
+        bound["window"] = cfg.sliding_window
     return _attn_gate(cfg, blk, x, attend(
         q, *where, use_pallas=use_pallas, k_scales=ksf, v_scales=vsf,
         **bound)), pool
@@ -1028,7 +1054,10 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
     tokens (``cfg.mixer_kinds``): every layer, in order, each over its own
     cache: the page pool ``[attention layers, NP, ...]`` by the layer's
     ordinal among the attention layers, the state pool (``pool[4]``) by
-    its ordinal among the recurrent ones. A compile follows the number of
+    its ordinal among the recurrent ones, the window class's pools
+    (``pool[5]``, ``(k, v) [window layers, NPw, ...]``, where the model has
+    window layers) by its ordinal among those, under the rows' second
+    table (``addr.window_table``). A compile follows the number of
     distinct loop bodies, so each KIND of layer (a mixer and an MLP) is
     traced once, not each of the runs: the parameters are stacked by
     half-layer (``cfg.param_groups``), kinds that take turns are walked a
@@ -1040,10 +1069,15 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
     operands of a ``lax.cond`` in a loop are copied whole in every trip,
     whichever branch runs (a dense MLP's ``w_down`` and ``w_up``, 85 MB
     a trip; PERF.md, PR 38)."""
-    *pages, state = pool
+    *pages, state = pool[:5]
+    window = pool[5] if len(pool) > 5 else None
     NP = pages[0].shape[1]
-    flat = jax.tree_util.tree_map(
-        lambda p: p.reshape((-1,) + p.shape[2:]), tuple(pages))
+    flatten = functools.partial(
+        jax.tree_util.tree_map, lambda p: p.reshape((-1,) + p.shape[2:]))
+    flat = flatten(tuple(pages))
+    wflat = None
+    if window is not None:      # the window class, with no int8 form
+        NPw, wflat = window[0].shape[1], (*flatten(tuple(window)), None, None)
     rows = x.shape[0] * x.shape[1]
     halves = cfg.layer_halves
     configs = {(h[1], h[3]): h[0] for h in halves}
@@ -1058,15 +1092,20 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
     def layer(kind, li, carry):
         """Layer ``li`` of ``kind``: a loop's counter, or the layer's own
         number (an int) where it is walked alone."""
-        h, flat, state, seen = carry
+        h, flat, state, seen, wflat = carry
         (key, mlp), g = kind, configs[kind]
         m, n = ((halves[li][2], halves[li][4]) if isinstance(li, int)
                 else (mixer_at[li], mlp_at[li]))
         blk = {k: v[m] for k, v in params[key].items()}
-        if g.attention != "full":
+        if g.attention in _RECURRENT_MIXERS:
             attn, state = _RECURRENT_MIXERS[g.attention](
                 mixer_cfg[key], use_pallas, addr.max_q_len, blk, h, state,
                 jnp.asarray(m, jnp.int32), addr._replace(max_q_len=None))
+        elif g.attention == "window":
+            with jax.named_scope("attention"), jax.named_scope("window"):
+                attn, wflat = _pool_attention(
+                    g, blk, h, wflat, addr.window_table + m * NPw, addr,
+                    use_pallas)
         else:
             with jax.named_scope("attention"):
                 attn, flat = _pool_attention(
@@ -1078,18 +1117,18 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
         blk.update((k, v[n]) for k, v in params[mlp].items()
                    if not (hit_only and k in _EXPERT_STACKS))
         if not hit_only:
-            return _post_attention(g, blk, h, attn), flat, state, seen
+            return _post_attention(g, blk, h, attn), flat, state, seen, wflat
         stacks = {k: params[mlp][k] for k in _EXPERT_STACKS}
         h, visited = _post_attention(
             g, blk, h, attn,
             hit_experts=(stacks, n, addr.token_mask, use_pallas))
-        return h, flat, state, seen + visited
+        return h, flat, state, seen + visited, wflat
 
     def loop(kind, count, l0, carry):
         return jax.lax.fori_loop(
             0, count, lambda i, c: layer(kind, l0 + i, c), carry)
 
-    carry = (x, flat, state, jnp.zeros((), jnp.int32))
+    carry = (x, flat, state, jnp.zeros((), jnp.int32), wflat)
     for seg in _hybrid_plan(cfg):
         if seg[0] == "run":
             _, kind, lo, hi = seg
@@ -1107,12 +1146,15 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr,
 
         carry, _ = jax.lax.scan(turn, carry, tuple(
             jnp.asarray(col, jnp.int32) for col in zip(*turns)))
-    x, flat, state, seen = carry
+    x, flat, state, seen, wflat = carry
     experts = experts_whole and any(
         hit_experts_pay(h[0], rows) for h in halves)
-    pages = jax.tree_util.tree_map(lambda f, p: f.reshape(p.shape), flat,
-                                   tuple(pages))
-    return x, (*pages, state), seen[None] if experts else None
+    unflatten = functools.partial(jax.tree_util.tree_map,
+                                  lambda f, p: f.reshape(p.shape))
+    pool = (*unflatten(flat, tuple(pages)), state)
+    if window is not None:
+        pool += (unflatten(wflat[:2], tuple(window)),)
+    return x, pool, seen[None] if experts else None
 
 
 def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
@@ -1135,11 +1177,11 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
     F the compiler splits (the kernel would need a ``shard_map`` of its
     own)."""
     lo, hi = layers
-    if cfg.recurrent:
+    if cfg.by_kind:
         if (lo, hi) != (0, cfg.num_layers) or lora is not None:
             raise NotImplementedError(
-                f"{cfg.name} has recurrent layers: its layers are walked "
-                f"whole and without adapters (a window {layers}, or LoRA, "
+                f"{cfg.name} {cfg.unbuilt_for}: its layers are walked "
+                f"whole and without adapters (layers {layers}, or LoRA, "
                 f"was asked for)")
         return _hybrid_layers(params, cfg, x, pool, addr, use_pallas,
                               experts_whole)
@@ -1207,6 +1249,19 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
                                      pool), visited
 
 
+def _all_pools(k_pages, v_pages, k_scales, v_scales, state, window_pages):
+    """The pools a step walks, as ``paged_layers`` takes and returns them:
+    the four page pools, then the state pool's arrays where the model has
+    recurrent layers, then (after the state's place) the window class's
+    pools where it has window layers."""
+    pool = (k_pages, v_pages, k_scales, v_scales)
+    if state is not None or window_pages is not None:
+        pool += (state,)
+    if window_pages is not None:
+        pool += (tuple(window_pages),)
+    return pool
+
+
 def forward_paged(
     params: dict,
     cfg: ModelConfig,
@@ -1226,6 +1281,9 @@ def forward_paged(
     state: Optional[dict] = None,   # recurrent layers: the state pool's arrays
     state_slots: Optional[jnp.ndarray] = None,   # [B] int32 slot per row
     sharded: bool = False,          # the parameters lie over a mesh
+    window_pages: Optional[tuple] = None,   # window layers: their class's
+                                            # (k, v) [Lw, NPw, page, KV, hd]
+    window_table: Optional[jnp.ndarray] = None,  # [B, P] int32, that class's
 ):
     """Serving forward over the paged KV pool (prefill chunks and decode steps
     share this one traced program per (B, T) bucket). With scales, the pool
@@ -1236,15 +1294,16 @@ def forward_paged(
     over the layers, where the step ran as ``_moe_mlp_hit`` (small enough
     for ``hit_experts_pay``); else None, and the program is the dense one.
     With ``state`` (a model with recurrent layers) the new state follows
-    the pools, before that count."""
+    the pools, before that count; with ``window_pages`` (a model with
+    window layers) the state's place (None without one) and then the
+    window class's new pools do."""
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
-    pool = (k_pages, v_pages, k_scales, v_scales)
-    if state is not None:
-        pool += (state,)
+    pool = _all_pools(k_pages, v_pages, k_scales, v_scales, state,
+                      window_pages)
     x, pool, visited = paged_layers(
         params, cfg, x, pool,
         PoolAddr(positions, token_mask, kv_lens, page_table,
-                 state_slots=state_slots),
+                 state_slots=state_slots, window_table=window_table),
         layers=(0, cfg.num_layers), use_pallas=use_pallas, lora=lora,
         lora_ids=lora_ids, experts_whole=experts_whole, sharded=sharded)
     out = (_head(params, cfg, x), *pool)
@@ -1271,6 +1330,8 @@ def forward_ragged(
                                       # (engine: prefill_chunk)
     state: Optional[dict] = None,     # recurrent layers: the state pool
     state_slots: Optional[jnp.ndarray] = None,   # [R] int32 slot per row
+    window_pages: Optional[tuple] = None,    # window layers: their class
+    window_table: Optional[jnp.ndarray] = None,  # [R, P] int32, its lines
 ):
     """Serving forward over a RAGGED packed batch: prefill chunks and decode
     steps of different rows ride ONE dispatch (tokens packed row-major on the
@@ -1279,15 +1340,16 @@ def forward_ragged(
     (``_pool_attention``). No LoRA: ``lora_delta`` gathers adapters per batch
     ROW and the packed batch axis is 1, so the engine gates such rows out.
     Returns (logits [1, T, V] f32, k_pages, v_pages, k_scales, v_scales),
-    and the new state after them where ``state`` was given."""
+    and the new state after them where ``state`` was given, the window
+    class's pools after that where ``window_pages`` were
+    (``forward_paged``'s order)."""
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
-    pool = (k_pages, v_pages, k_scales, v_scales)
-    if state is not None:
-        pool += (state,)
+    pool = _all_pools(k_pages, v_pages, k_scales, v_scales, state,
+                      window_pages)
     x, pool, _ = paged_layers(
         params, cfg, x, pool,
         PoolAddr(positions, token_mask, kv_lens, page_table, row_ids,
-                 max_q_len, state_slots),
+                 max_q_len, state_slots, window_table),
         layers=(0, cfg.num_layers), use_pallas=use_pallas)
     return (_head(params, cfg, x), *pool)
 

@@ -592,6 +592,13 @@ SPAN_ENGINE_EMIT = "engine.emit"
 # and the relay's socket write of one token frame (a connection thread).
 SPAN_ENGINE_LATE_STEP = "engine.late_step"
 SPAN_SERVER_RELAY_SEND = "server.relay_send"
+# Inside ``engine.pack``, a model with window layers: the rows' pages of
+# the window class brought to what the step reads (those below every later
+# window given back, those the step writes taken;
+# ``Engine._window_pages_for``). The wire counters beside it:
+# ``kv_window_pages_released``, ``kv_window_live_token_steps``,
+# ``kv_window_held_slot_steps``.
+SPAN_KV_WINDOW_RELEASE = "kv.window_release"
 
 # ---- model scopes (device time by block) ----
 #
@@ -607,7 +614,9 @@ MOE_INNER_SCOPES = ("router", "shared")
 # Inside ``attention``: a recurrent layer's mixer and its ``wo``, by kind
 # (``models/llama.py::_kda_attention``, ``_conv_attention``);
 # ``device.kda_share`` and ``device.conv_share`` read the paths.
-ATTENTION_INNER_SCOPES = ("kda", "conv")
+# ``window``: a window layer's mixer and its ``wo`` (a full layer's stay
+# directly under ``attention``); ``device.window_attn_share`` reads it.
+ATTENTION_INNER_SCOPES = ("kda", "conv", "window")
 # Parts of a mixer with a path of their own under ``attention``: the
 # output gate of a gated grouped-query attention layer
 # (``models/llama.py::_attn_gate``; ``device.attn_gate_share``), and a KDA
@@ -710,4 +719,5 @@ SPANS = frozenset({
     SPAN_ENGINE_EMIT,
     SPAN_ENGINE_LATE_STEP,
     SPAN_SERVER_RELAY_SEND,
+    SPAN_KV_WINDOW_RELEASE,
 })

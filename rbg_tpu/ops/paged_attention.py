@@ -40,6 +40,7 @@ def paged_attention_xla(
     kv_lens: jnp.ndarray,      # [B] int32 — valid tokens in cache (post-write)
     k_scales: jnp.ndarray = None,  # [NP, page, KV, 1] f32 (int8 pools)
     v_scales: jnp.ndarray = None,
+    window: int = None,        # a window layer: ``slot > position - window``
 ) -> jnp.ndarray:
     B, T, H, hd = q.shape
     S = page_table.shape[1] * k_pages.shape[1]
@@ -64,6 +65,8 @@ def paged_attention_xla(
         slot <= q_positions[:, :, None],          # causal (slot == position)
         slot < kv_lens[:, None, None],            # within the live cache
     )
+    if window is not None:      # what lies below was given back: never read
+        mask = mask & (slot > q_positions[:, :, None] - window)
     scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)
@@ -118,16 +121,21 @@ def write_kv_pages(k_pages, v_pages, k_new, v_new, page_table, positions,
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_positions, kv_lens,
-                    *, use_pallas: str = "auto", k_scales=None, v_scales=None):
+                    *, use_pallas: str = "auto", k_scales=None, v_scales=None,
+                    window: int = None):
     """Dispatch between the Pallas TPU kernel and the XLA fallback.
     Quantized (int8 + scales) pools route to the dequantizing kernel
     variant — the pool stays int8 in HBM, so the page walk moves half
-    the bytes."""
+    the bytes. ``window`` (static, a layer kind's): a query attends its
+    ``window`` newest slots, its own included, and the walk starts at the
+    block that holds the oldest of them (no int8 form)."""
+    kw = {} if window is None else {"window": window}
     if k_scales is not None:
+        assert window is None, "a window layer's pool has no int8 form"
         return dispatch_pallas(
             use_pallas, "paged_attention_pallas_q", paged_attention_xla,
             (q, k_pages, v_pages, page_table, q_positions, kv_lens,
              k_scales, v_scales))
     return dispatch_pallas(
         use_pallas, "paged_attention_pallas", paged_attention_xla,
-        (q, k_pages, v_pages, page_table, q_positions, kv_lens))
+        (q, k_pages, v_pages, page_table, q_positions, kv_lens), **kw)

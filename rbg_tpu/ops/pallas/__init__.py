@@ -43,13 +43,14 @@ def kernel(kernel_name: str):
     return getattr(home(kernel_name), kernel_name)
 
 
-def dispatch_pallas(use_pallas: str, kernel_name: str, xla_fn, args):
+def dispatch_pallas(use_pallas: str, kernel_name: str, xla_fn, args, **kw):
     """The ONE kernel-vs-XLA dispatch policy (the page walks, the delta
     rule's decode step and the experts' visits all use it):
     'always' takes the kernel everywhere, 'auto' takes it on a TPU and
     the XLA path on any other backend, 'never' the XLA path. A kernel
-    that cannot be imported is an error, never a reason to run XLA."""
+    that cannot be imported is an error, never a reason to run XLA.
+    ``kw``: what both forms take by name (a window layer's ``window``)."""
     if use_pallas == "always" or (use_pallas == "auto"
                                   and jax.default_backend() == "tpu"):
-        return kernel(kernel_name)(*args)
-    return xla_fn(*args)
+        return kernel(kernel_name)(*args, **kw)
+    return xla_fn(*args, **kw)

@@ -106,12 +106,26 @@ def live_block_starts(live_tokens, page: int, at_least_one, n: int = None):
     attends (<= 0: none). A segment where ``at_least_one`` holds gets one
     item even then, which attends nothing: the kernels initialise and
     write an output block in the items of its first and last segment, so
-    every output block needs one. ``starts[-1]`` is the grid's length."""
+    every output block needs one. ``starts[-1]`` is the grid's length.
+
+    A window layer's segment starts at the block that holds its first
+    live token (``first_live_block``): its ``live_tokens`` are counted from
+    that block's first slot, so the blocks below it get no item."""
     block = (n or pages_per_block(page)) * page
     blocks = jnp.maximum(-(-live_tokens // block), 0)
     blocks = jnp.where(at_least_one, jnp.maximum(blocks, 1), blocks)
     return jnp.concatenate([jnp.zeros((1,), jnp.int32),
                             jnp.cumsum(blocks, dtype=jnp.int32)])
+
+
+def first_live_block(first_token, page: int, n: int = None):
+    """The block (``n`` pages; ``pages_per_block`` unless given) that holds
+    a segment's first live token, and that block's first slot: where a
+    window layer's walk of the row starts. ``first_token`` below 0 (a
+    window longer than the row) is the row's start."""
+    block = (n or pages_per_block(page)) * page
+    first = jnp.maximum(first_token, 0) // block
+    return first, first * block
 
 
 def find_item(starts_ref, w, n_segments: int):
@@ -148,7 +162,8 @@ def page_of_block(table_ref, row, block, j: int, live_tokens, page: int):
     return table_ref[row, p]
 
 
-def walk_items(starts, live_tokens, table, page: int, n: int):
+def walk_items(starts, live_tokens, table, page: int, n: int,
+               first_block=None):
     """The walk's items, resolved once a call (XLA): for every work item
     ``w`` the decode kernels may be asked for, its row, its block of the
     row and the physical page of each page of that block, so that a
@@ -166,6 +181,12 @@ def walk_items(starts, live_tokens, table, page: int, n: int):
     ahead of time (hence the ``+ 1``), resolves to the last row; a page
     past a row's last live page is that last page again, so no entry
     beyond the live pages is ever in ``item_page``, whatever it names.
+    ``first_block [S]`` (a window layer's walk): the block of its row at
+    which each row's items start, ``live_tokens`` still the row's whole
+    length; the entries of the line below that block are not followed
+    either, and those of that block below the window are whatever the
+    line holds there (the null page: ``engine``'s lines hold 0 for a page
+    given back), masked by the kernel.
 
     A dozen small fusions, 4-8 us a call on a v5e: an item's row by
     comparing ``w`` with every start, not by a search, and its pages as
@@ -182,11 +203,15 @@ def walk_items(starts, live_tokens, table, page: int, n: int):
     last_page = jnp.sum(jnp.where(
         jnp.arange(P, dtype=jnp.int32)[None] == last[:, None], table, 0),
         axis=1, dtype=jnp.int32)
-    row, first, last, last_page = (
+    per_row = (jnp.arange(S, dtype=jnp.int32), starts[:S], last, last_page)
+    if first_block is not None:
+        per_row += (first_block,)
+    row, first, last, last_page, *below = (
         jnp.sum(jnp.where(own, of_row[None], 0), axis=1, dtype=jnp.int32)
-        for of_row in (jnp.arange(S, dtype=jnp.int32), starts[:S], last,
-                       last_page))
+        for of_row in per_row)
     block = w[:, 0] - first
+    if below:
+        block = block + below[0]
     lines = jnp.pad(table, ((0, 0), (0, blocks * n - P))).reshape(-1, n)
     pages = lines.at[jnp.minimum(row * blocks + block, S * blocks - 1)].get(
         mode="promise_in_bounds")                             # [W + 1, n]
@@ -300,10 +325,11 @@ def _update(scores, mask, m_ref, l_ref, acc_ref, values):
 
 
 def gqa_attend(q, k, v, ks, vs, token0, limit, m_ref, l_ref, acc_ref,
-               head_dim=None):
+               head_dim=None, lower=None):
     """Attend query rows ``q [KV, rows, hd]`` to one block ``k, v
     [S, KV, hd]`` whose first slot is token ``token0`` of the row; query
-    row ``j`` sees slots ``< limit`` (a scalar, or ``[rows, 1]``). Scores
+    row ``j`` sees slots ``< limit`` (a scalar, or ``[rows, 1]``) and, in
+    a window layer, ``>= lower`` (the same shapes). Scores
     are scaled by ``head_dim ** -0.5``: ``hd``, but for a pool of packed
     heads (``pack_queries``), whose ``hd`` here is ``p`` heads wide.
 
@@ -321,7 +347,10 @@ def gqa_attend(q, k, v, ks, vs, token0, limit, m_ref, l_ref, acc_ref,
         scores = scores * jnp.transpose(ks, (1, 0))[:, None, :]
     token_idx = token0 + jax.lax.broadcasted_iota(
         jnp.int32, (rows, k.shape[0]), dimension=1)
-    mask = (token_idx < limit)[None]                        # [1, rows, S]
+    mask = token_idx < limit
+    if lower is not None:
+        mask = mask & (token_idx >= lower)
+    mask = mask[None]                                       # [1, rows, S]
 
     def values(probs):
         if vs is not None:

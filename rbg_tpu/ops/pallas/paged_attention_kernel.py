@@ -59,14 +59,21 @@ def _row_of(rank):
     return index_map
 
 
-def _walk(page_table, kv_lens, page, n):
+def _walk(page_table, kv_lens, page, n, window=None):
     """The scalar-prefetch operands of a decode walk (every row is a
     segment) in blocks of ``n`` pages: ``page_walk.walk_items``' three
     arrays, the rows' lengths and their cumulative live blocks, whose
-    last is the grid's length."""
-    starts = W.live_block_starts(kv_lens, page, True, n)
-    return (*W.walk_items(starts, kv_lens, page_table, page, n), kv_lens,
-            starts)
+    last is the grid's length. In a window layer a row's walk starts at
+    the block that holds token ``kv_len - window``, the oldest its one
+    query attends."""
+    if window is None:
+        starts = W.live_block_starts(kv_lens, page, True, n)
+        return (*W.walk_items(starts, kv_lens, page_table, page, n), kv_lens,
+                starts)
+    first_block, below = W.first_live_block(kv_lens - window, page, n)
+    starts = W.live_block_starts(kv_lens - below, page, True, n)
+    return (*W.walk_items(starts, kv_lens, page_table, page, n, first_block),
+            kv_lens, starts)
 
 
 def _decode_kernel(
@@ -84,6 +91,7 @@ def _decode_kernel(
                       # hd]; scratch: m [KV, G, 1] running max, l [KV, G, 1]
                       # running denom, acc [KV, G, hd] running numerator
     head_dim=None,    # a head's size where ``hd`` is several packed heads
+    window=None,      # a window layer: the query attends its newest slots
 ):
     *pages, out_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(0)
@@ -93,7 +101,8 @@ def _decode_kernel(
     n = W.decode_pages_per_block(page)
     token0 = block * (n * page)                     # the block's first slot
 
-    @pl.when(block == 0)
+    # A row's first item: its block 0, or the first live block of a window.
+    @pl.when(block == 0 if window is None else w == starts_ref[b])
     def _init():
         W.init_softmax(m_ref, l_ref, acc_ref)
 
@@ -105,22 +114,25 @@ def _decode_kernel(
         k, v, *scales = W.load_blocks(pages, n)
         ks, vs = scales or (None, None)
         W.gqa_attend(q_ref[0], k, v, ks, vs, token0, kv_len,
-                     m_ref, l_ref, acc_ref, head_dim)
+                     m_ref, l_ref, acc_ref, head_dim,
+                     None if window is None else kv_len - window)
 
     @pl.when(w + 1 == starts_ref[b + 1])
     def _finalize():
         out_ref[0] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
-def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None):
+def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None,
+            window=None):
     """q: [B, KV, G, hd]; pools: k, v pages [NP, page, KV, hd], and for
     int8 pools their scales [NP, page, KV] f32. Returns q's shape.
     ``head_dim``: a head's size where the pool keeps several side by side
-    and ``q`` is ``page_walk.pack_queries``'."""
+    and ``q`` is ``page_walk.pack_queries``'. ``window``: a window layer's
+    width (``_walk``)."""
     B, KV, G, hd = q.shape
     page = pools[0].shape[1]
     n = W.decode_pages_per_block(page)
-    walk = _walk(page_table, kv_lens, page, n)
+    walk = _walk(page_table, kv_lens, page, n, window)
     page_specs, page_operands = W.block_specs(pools, _page_id, n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(walk),
@@ -133,9 +145,12 @@ def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None):
             pltpu.VMEM((KV, G, hd), jnp.float32),
         ],
     )
+    kernel = _decode_kernel if head_dim is None else functools.partial(
+        _decode_kernel, head_dim=head_dim)
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
     return pl.pallas_call(
-        _decode_kernel if head_dim is None else functools.partial(
-            _decode_kernel, head_dim=head_dim),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -144,15 +159,16 @@ def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None):
     )(*walk, q, *page_operands)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "head_dim"))
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "head_dim", "window"))
 def _decode_call(q, k_pages, v_pages, page_table, kv_lens, interpret=False,
-                 head_dim=None):
+                 head_dim=None, window=None):
     return _decode(q, (k_pages, v_pages), page_table, kv_lens, interpret,
-                   head_dim)
+                   head_dim, window)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, q_positions,
-                           kv_lens, interpret: bool = False):
+                           kv_lens, interpret: bool = False, window=None):
     """Drop-in for ``paged_attention_xla``. Decode (T == 1) runs the kernel;
     other shapes fall back to the XLA path (the engine sends prefill
     through the ragged kernel, not through here)."""
@@ -160,7 +176,7 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, q_positions,
     if T != 1:
         from rbg_tpu.ops.paged_attention import paged_attention_xla
         return paged_attention_xla(q, k_pages, v_pages, page_table,
-                                   q_positions, kv_lens)
+                                   q_positions, kv_lens, window=window)
     # heads side by side in the pool (1: the pool is [NP, page, KV, hd])
     p = k_pages.shape[3] // hd
     KV = k_pages.shape[2] * p
@@ -168,7 +184,8 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, q_positions,
     out = _decode_call(qg, k_pages, v_pages,
                        page_table.astype(jnp.int32),
                        kv_lens.astype(jnp.int32), interpret=interpret,
-                       head_dim=hd if p > 1 else None)
+                       head_dim=hd if p > 1 else None,
+                       window=window)
     return W.unpack_outputs(out, p).reshape(B, T, H, hd)
 
 

@@ -74,7 +74,7 @@ Q_TILE = 8
 RAGGED_GRID_REV = 3
 
 
-def _tile_segments(row_ids, q_pos, kv_lens, page):
+def _tile_segments(row_ids, q_pos, kv_lens, page, window=None):
     """The ragged kernels' work, in XLA: per packed token (a segment of
     the walk) how many slots of its row it leads the tile through, and
     the cumulative live blocks of that (``page_walk.live_block_starts``).
@@ -87,6 +87,12 @@ def _tile_segments(row_ids, q_pos, kv_lens, page):
     their grid steps do not exist. Each tile's first token keeps one
     item regardless, which writes the tile's output block.
 
+    In a window layer (``window``) a leader's walk starts at the block
+    that holds the oldest slot any tile token of its row attends
+    (``position - window + 1`` of the row's first real token in the tile):
+    a third value, ``first_block [Tp]``, the block each segment's items
+    start at, and ``starts`` count the blocks from there on.
+
     Returns (lead_tokens [Tp] int32, starts [Tp + 1] int32)."""
     rows = row_ids.reshape(-1, Q_TILE)
     lims = jnp.minimum(kv_lens[row_ids], q_pos + 1).reshape(-1, Q_TILE)
@@ -96,29 +102,43 @@ def _tile_segments(row_ids, q_pos, kv_lens, page):
     row_limit = jnp.max(jnp.where(same, lims[:, None, :], 0), axis=2)
     lead = jnp.where(dup, 0, row_limit).astype(jnp.int32).reshape(-1)
     first_of_tile = jnp.arange(lead.shape[0]) % Q_TILE == 0
-    return lead, W.live_block_starts(lead, page, first_of_tile)
+    if window is None:
+        return lead, W.live_block_starts(lead, page, first_of_tile)
+    pos = q_pos.reshape(-1, Q_TILE)[:, None, :]             # pads: -1
+    oldest = jnp.min(jnp.where(same & (pos >= 0), pos - window + 1,
+                               np.iinfo(np.int32).max), axis=2)
+    first_block, below = W.first_live_block(
+        jnp.where(lead > 0, oldest.reshape(-1), 0), page)
+    starts = W.live_block_starts(lead - below, page, first_of_tile)
+    return lead, starts, first_block.astype(jnp.int32)
 
 
-def _page_id(j, w, table, lens, rows, qpos, lead, starts, *, page):
+def _page_id(j, w, table, lens, rows, qpos, lead, starts, *first, page):
     """Physical page of page ``j`` of ragged work item ``w`` (every
-    packed token is a segment; only leaders have items)."""
+    packed token is a segment; only leaders have items). ``first``: a
+    window layer's ``first_block``, at which a segment's items start."""
     t, block = W.find_item(starts, w, rows.shape[0])
+    if first:
+        block = jax.lax.add(block, first[0][t])
     return W.page_of_block(table, rows[t], block, j, lead[t], page)
 
 
 def _tile_of(rank):
     """Index map of the query/output tile of an item."""
-    def index_map(w, table, lens, rows, qpos, lead, starts):
+    def index_map(w, table, lens, rows, qpos, lead, starts, *first):
         t, _ = W.find_item(starts, w, rows.shape[0])
         return (jax.lax.div(t, np.int32(Q_TILE)),) + (0,) * (rank - 1)
     return index_map
 
 
-def _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row, tile, group):
+def _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row, tile, group,
+                 window=None):
     """Per-query-row causal limits of one tile as a ``[TILE·group, 1]``
     column (query row ``j`` belongs to tile token ``j // group``). Tokens
     of OTHER rows get limit 0 (fully masked), so every tile token rides
-    the same softmax update and only ``row``'s tokens accumulate.
+    the same softmax update and only ``row``'s tokens accumulate. Beside
+    it, with ``window``, the column of the oldest slot each query row
+    attends, ``position - window + 1`` (else None): (limits, lowers).
 
     Built from SMEM scalars by ``tile`` selects against a 2-D iota: Mosaic
     has no layout for a rank-1 vector of stacked scalars, nor for the
@@ -131,7 +151,13 @@ def _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row, tile, group):
             rk == row,
             jnp.minimum(kv_lens_ref[rk], q_pos_ref[t0 + k] + 1), 0)
         limits = jnp.where(j >= k * group, lim_k, limits)
-    return limits
+    if window is None:
+        return limits, None
+    lowers = jnp.zeros((tile * group, 1), jnp.int32)
+    for k in range(tile):
+        lowers = jnp.where(j >= k * group, q_pos_ref[t0 + k] + 1 - window,
+                           lowers)
+    return limits, lowers
 
 
 def _block_ragged_kernel(
@@ -143,26 +169,36 @@ def _block_ragged_kernel(
     lead_ref,         # [Tp] int32 (SMEM) — slots each token leads (0: none)
     starts_ref,       # [Tp + 1] int32 (SMEM) — cumulative live blocks
     # blocks
-    q_ref,            # [1, KV, TILE·G, hd] (VMEM) — one query tile, the
-                      # tile's tokens already folded into the query-row axis
-    *refs,            # the item's pages, picked by index_map: n k refs and
-                      # n v refs [1, page, KV, hd] (int8 pools: then n + n
-                      # scale refs [1, page, KV] f32); out_ref [1, KV,
-                      # TILE·G, hd]; scratch — online softmax state for the
-                      # WHOLE tile: m, l [KV, TILE·G, 1], acc [KV, TILE·G, hd]
+    *refs,            # a window layer's one more scalar prefetch first,
+                      # first_ref [Tp] int32 (SMEM): the block each
+                      # segment's items start at; then q_ref [1, KV, TILE·G,
+                      # hd] (VMEM), one query tile, the tile's tokens already
+                      # folded into the query-row axis; the item's pages,
+                      # picked by index_map: n k refs and n v refs [1, page,
+                      # KV, hd] (int8 pools: then n + n scale refs [1, page,
+                      # KV] f32); out_ref [1, KV, TILE·G, hd]; scratch —
+                      # online softmax state for the WHOLE tile: m, l [KV,
+                      # TILE·G, 1], acc [KV, TILE·G, hd]
     tile: int,
     head_dim=None,    # a head's size where ``hd`` is several packed heads
+    window=None,      # a window layer's width
 ):
-    *pages, out_ref, m_ref, l_ref, acc_ref = refs
+    first_ref = None
+    if window is not None:
+        first_ref, *refs = refs
+    q_ref, *pages, out_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(0)
     t, block = W.find_item(starts_ref, w, row_ids_ref.shape[0])
     t0 = t // tile * tile
     page = pages[0].shape[1]
+    if window is not None:
+        block = block + first_ref[t]
     token0 = block * (W.pages_per_block(page) * page)   # the block's first slot
 
     # A tile's first token always has an item, so the tile's first item
     # is that token's first.
-    @pl.when((t == t0) & (block == 0))
+    @pl.when((t == t0) & (block == 0) if window is None
+             else w == starts_ref[t0])
     def _init():
         W.init_softmax(m_ref, l_ref, acc_ref)
 
@@ -171,13 +207,14 @@ def _block_ragged_kernel(
     @pl.when(token0 < lead_ref[t])
     def _attend():
         rows_q = q_ref.shape[2]
-        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0,
-                              row_ids_ref[t], tile, rows_q // tile)
+        limits, lowers = _tile_limits(
+            row_ids_ref, kv_lens_ref, q_pos_ref, t0, row_ids_ref[t], tile,
+            rows_q // tile, window)
         k, v, *scales = W.load_blocks(pages)
         ks, vs = scales or (None, None)
         # The tile's whole query block rides ONE batched dot per block.
         W.gqa_attend(q_ref[0], k, v, ks, vs, token0, limits,
-                     m_ref, l_ref, acc_ref, head_dim)
+                     m_ref, l_ref, acc_ref, head_dim, lowers)
 
     @pl.when(w + 1 == starts_ref[t0 + tile])
     def _finalize():
@@ -203,24 +240,27 @@ def _unfold_tile(out, G):
         NT * Q_TILE, KV * G, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "head_dim"))
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "head_dim", "window"))
 def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
                        kv_lens, row_ids, q_pos, interpret=False,
-                       head_dim=None):
+                       head_dim=None, window=None):
     """q: [Tp/TILE, KV, TILE·G, hd] folded tiles; pages: [NP, page, KV,
     hd]; scales (int8 pools) [NP, page, KV] f32 or None. Returns q's
     shape. ``head_dim``: a head's size where the pool keeps several side
-    by side and ``q`` is ``page_walk.pack_queries``'."""
+    by side and ``q`` is ``page_walk.pack_queries``'. ``window``: a
+    window layer's width (``_tile_segments``)."""
     NT, KV, rows_q, hd = q.shape
     page = k_pages.shape[1]
-    lead, starts = _tile_segments(row_ids, q_pos, kv_lens, page)
+    lead, starts, *first = _tile_segments(row_ids, q_pos, kv_lens, page,
+                                          window)
     pools = (k_pages, v_pages)
     if k_scales is not None:
         pools += (k_scales, v_scales)
     page_specs, page_operands = W.block_specs(
         pools, functools.partial(_page_id, page=page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=6 + len(first),
         grid=(starts[NT * Q_TILE],),
         in_specs=[pl.BlockSpec((1, KV, rows_q, hd), _tile_of(4))] + page_specs,
         out_specs=pl.BlockSpec((1, KV, rows_q, hd), _tile_of(4)),
@@ -233,6 +273,8 @@ def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
     kernel = functools.partial(_block_ragged_kernel, tile=Q_TILE)
     if head_dim is not None:
         kernel = functools.partial(kernel, head_dim=head_dim)
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -240,7 +282,8 @@ def _block_ragged_call(q, k_pages, v_pages, k_scales, v_scales, page_table,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, kv_lens, row_ids, q_pos, lead, starts, q, *page_operands)
+    )(page_table, kv_lens, row_ids, q_pos, lead, starts, *first, q,
+      *page_operands)
 
 
 def _pad_pack(qg, rows, qpos):
@@ -260,7 +303,7 @@ def _pad_pack(qg, rows, qpos):
 
 
 def _block_ragged(q, k_pages, v_pages, k_scales, v_scales, page_table,
-                  q_positions, kv_lens, row_ids, interpret):
+                  q_positions, kv_lens, row_ids, interpret, window=None):
     _, T, H, hd = q.shape
     # heads side by side in the pool (1: the pool is [NP, page, KV, hd])
     p = k_pages.shape[3] // hd
@@ -274,7 +317,7 @@ def _block_ragged(q, k_pages, v_pages, k_scales, v_scales, page_table,
                              page_table.astype(jnp.int32),
                              kv_lens.astype(jnp.int32),
                              rows, qpos, interpret=interpret,
-                             head_dim=hd if p > 1 else None)
+                             head_dim=hd if p > 1 else None, window=window)
     out = _unfold_tile(out, p * G)
     if p > 1:
         out = W.unpack_outputs(out.reshape(-1, KV // p, p * G, p * hd), p)
@@ -283,11 +326,11 @@ def _block_ragged(q, k_pages, v_pages, k_scales, v_scales, page_table,
 
 def ragged_paged_attention_pallas(q, k_pages, v_pages, page_table,
                                   q_positions, kv_lens, row_ids,
-                                  interpret: bool = False):
+                                  interpret: bool = False, window=None):
     """Drop-in for ``ragged_paged_attention_xla`` (q packed [1, T, H, hd]),
     block-ragged grid."""
     return _block_ragged(q, k_pages, v_pages, None, None, page_table,
-                         q_positions, kv_lens, row_ids, interpret)
+                         q_positions, kv_lens, row_ids, interpret, window)
 
 
 # ---- int8 (quantized pool) variant ------------------------------------------
@@ -344,8 +387,8 @@ def _block_ragged_mla_kernel(
     @pl.when(token0 < lead_ref[t])
     def _attend():
         rows_q = ql_ref.shape[0]
-        limits = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0,
-                              row_ids_ref[t], tile, rows_q // tile)
+        limits, _ = _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0,
+                                 row_ids_ref[t], tile, rows_q // tile)
         c, pe, cs, ps = W.load_latent_blocks(pages)
         W.mla_attend(ql_ref[...], qp_ref[...], c, pe, cs, ps, token0,
                      limits, scale, m_ref, l_ref, acc_ref)

@@ -77,6 +77,7 @@ def ragged_paged_attention_xla(
     max_q_len: Optional[int] = None,  # static bound on any row's q_len
                                       # (the engine's prefill_chunk);
                                       # None = T (always safe)
+    window: Optional[int] = None,     # a window layer's width (static)
 ) -> jnp.ndarray:
     """XLA fallback: unpack → padded batch attention → repack.
 
@@ -100,7 +101,7 @@ def ragged_paged_attention_xla(
     pp = jnp.zeros((R, Tmax), jnp.int32)
     pp = pp.at[scatter_row, idx_in_row].set(q_positions[0], mode="drop")
     out = paged_attention_xla(qp, k_pages, v_pages, page_table, pp, kv_lens,
-                              k_scales, v_scales)
+                              k_scales, v_scales, window)
     return out[row_ids, idx_in_row][None]                   # [1, T, H, hd]
 
 
@@ -141,19 +142,23 @@ def write_kv_pages_ragged(k_pages, v_pages, k_new, v_new, page_table,
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_positions,
                            kv_lens, row_ids, *, use_pallas: str = "auto",
                            k_scales=None, v_scales=None,
-                           max_q_len: Optional[int] = None):
+                           max_q_len: Optional[int] = None,
+                           window: Optional[int] = None):
     """Dispatch between the ragged Pallas kernel and the XLA fallback —
     the same per-platform policy as ``paged_attention``. ``max_q_len``
     (static) only shapes the XLA fallback's padded detour; the kernel is
-    padding-free."""
-    def xla_fn(*args):
-        return ragged_paged_attention_xla(*args, max_q_len=max_q_len)
+    padding-free. ``window`` (static): as ``paged_attention``'s."""
+    def xla_fn(*args, **kw):
+        return ragged_paged_attention_xla(*args, max_q_len=max_q_len, **kw)
 
+    kw = {} if window is None else {"window": window}
     if k_scales is not None:
+        assert window is None, "a window layer's pool has no int8 form"
         return dispatch_pallas(
             use_pallas, "ragged_paged_attention_pallas_q", xla_fn,
             (q, k_pages, v_pages, page_table, q_positions, kv_lens, row_ids,
              k_scales, v_scales))
     return dispatch_pallas(
         use_pallas, "ragged_paged_attention_pallas", xla_fn,
-        (q, k_pages, v_pages, page_table, q_positions, kv_lens, row_ids))
+        (q, k_pages, v_pages, page_table, q_positions, kv_lens, row_ids),
+        **kw)

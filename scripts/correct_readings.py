@@ -23,7 +23,9 @@ positions, the worst request's own median):
 * with ``--faults``, for a model whose preset sets them, each of its rules
   left out of the served path, one at a time (``left_out``): the attention
   layers' output gate dropped, the delta rule's write strength not scaled,
-  attention without positions rotated.
+  attention without positions rotated; YaRN dropped for plain RoPE, the
+  whole head rotated, the window not kept, a window's page given back one
+  page early, the window layers served with the full layers' head count.
 
 The serving loop, the faults and ``left_out`` are ``tests/model_contract.py``'s:
 the controls tier-1 holds every tiny configuration to are the ones run here
@@ -34,7 +36,6 @@ A limit lies between the largest ``sound`` and the smallest control
 """
 
 import argparse
-import dataclasses
 import json
 import os
 import statistics
@@ -77,7 +78,8 @@ def main(argv=None) -> int:
 
     import jax
     from harness import serve as harness
-    from model_contract import FAULTS, faulty, left_out, serve
+    from model_contract import (FAULTS, broken_preset, faulty, fitted,
+                                left_out, serve)
     from rbg_tpu.engine import Engine, EngineConfig
     from rbg_tpu.models import config as presets
     from rbg_tpu.utils.chipenv import configure_compile_cache
@@ -124,10 +126,11 @@ def main(argv=None) -> int:
         del eng
         if not args.faults:
             continue
-        for fault, fields in left_out(presets._PRESETS[name]).items():
-            presets._PRESETS[name + "-fault"] = dataclasses.replace(
-                presets._PRESETS[name], name=name + "-fault", **fields)
-            eng = engine(params, name + "-fault")
+        for fault, fields in left_out(presets._PRESETS[name],
+                                      cfg["server"]["page_size"]).items():
+            broken = presets._PRESETS[name + "-fault"] = broken_preset(
+                presets._PRESETS[name], name + "-fault", fields)
+            eng = engine(fitted(broken, params), name + "-fault")
             alone, rest = sample(cfg, args.prompts[0])
             served = serve(eng, alone, new) + serve(eng, rest, new)
             say(fault, w, args.prompts[0],

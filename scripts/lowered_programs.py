@@ -63,7 +63,8 @@ def programs(eng, S, T, lora=False, window=None):
     scales = (pool.k_scales, pool.v_scales)
     kw = dict(lora=eng.lora_stack, lids=vec) if lora else {}
     # A model with recurrent layers: the state pool and its rows' slots ride
-    # every step program; it has no draft to verify.
+    # every step program; one with window layers: the window class's pools
+    # and its rows' lines. Neither has a draft to verify.
     kw.update(eng._state_kw([], B))
     temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], B)
     tail = (row_keys(seeds, eng._sample_base, rids), jnp.asarray(temps),
@@ -79,7 +80,7 @@ def programs(eng, S, T, lora=False, window=None):
             eng.params, S((B, Tq), I32), S((B, Tq), I32), S((B, Tq), bool),
             vec, S((B, P), I32), pool.k_pages, pool.v_pages, *scales, **kw)
     Kq = 5
-    if eng.state is None:
+    if not eng.mcfg.unbuilt_for:
         out["rbg_spec_verify"] = eng._get_spec_fn(B, False, la=lora).lower(
             eng.params, S((B, Kq), I32), S((B, Kq), I32), S((B, Kq), bool),
             vec, S((B, P), I32), pool.k_pages, pool.v_pages, *scales, *tail,
@@ -133,7 +134,8 @@ def tiny_cases():
                                ("tiny-joyai", "tiny-joyai", {}),
                                ("tiny-kimi-linear", "tiny-kimi-linear", {}),
                                ("tiny-lfm2", "tiny-lfm2", {}),
-                               ("tiny-solar-open2", "tiny-solar-open2", {})):
+                               ("tiny-solar-open2", "tiny-solar-open2", {}),
+                               ("tiny-laguna", "tiny-laguna", {})):
         if model not in presets._PRESETS:   # an older checkout
             continue
         cfg = EngineConfig(model=model, use_pallas="never",
@@ -143,8 +145,9 @@ def tiny_cases():
         if lora:
             _lora_stack(eng)
         window = None
-        # the decode role refuses an int8 pool and a recurrent model
-        if not extra and not lora and eng.state is None:
+        # the decode role refuses an int8 pool, a recurrent model and one
+        # with window layers
+        if not extra and not lora and not eng.mcfg.unbuilt_for:
             window = DecodeWorker(cfg, params=eng.params)
         yield case, programs(eng, S, 2 * cfg.prefill_chunk, lora, window)
 

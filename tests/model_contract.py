@@ -24,6 +24,7 @@ import functools
 import json
 import math
 import os
+import re
 import sys
 from typing import NamedTuple
 
@@ -185,9 +186,16 @@ def faulty(fault):
     return mixers(breaker)
 
 
-def left_out(m) -> dict:
+def left_out(m, page_size: int = 16) -> dict:
     """``{fault: ModelConfig fields}``: the preset ``m`` with one of the
-    rules it sets left out."""
+    rules it sets left out. A model with window layers: the MODEL reads the
+    window of its window kind's group config (``window_layer``) and the
+    ENGINE the preset's own ``sliding_window``, so a fault of either is the
+    two set apart: the mask and the walk without the window over pages
+    given back as ever, or pages given back one page (``page_size`` slots)
+    early under the mask and the walk as they are (``broken_preset``
+    builds it: the constructor refuses a preset of two windows). ``fitted``
+    cuts the weights to a fault that changes a head count."""
     rules = {}
     if m.attn_gate:
         rules["gate dropped"] = dict(attn_gate=False)
@@ -195,7 +203,53 @@ def left_out(m) -> dict:
         rules["b not scaled"] = dict(kda_beta_scale=1.0)
     if not m.use_rope and not m.mla:
         rules["rotated"] = dict(use_rope=True)
+    if m.rope_scaling:
+        rules["yarn dropped"] = dict(rope_scaling="")
+    if m.partial_rotary_factor != 1.0:
+        rules["whole head rotated"] = dict(partial_rotary_factor=1.0)
+    if m.sliding_window:
+        kind = dict(m.window_layer)
+        rules["window not kept"] = dict(
+            window_layer={**kind, "sliding_window": 1 << 20})
+        rules["page given back early"] = dict(
+            sliding_window=m.sliding_window - page_size,
+            window_layer={**kind, "sliding_window": m.sliding_window})
+        if kind.get("num_heads", m.num_heads) != m.num_heads:
+            rules["head counts as one"] = dict(
+                window_layer={**kind, "num_heads": m.num_heads})
     return rules
+
+
+def broken_preset(m, name: str, fields: dict):
+    """The preset ``m`` under ``name`` with a fault's ``fields``
+    (``left_out``). ``window_layer`` is set behind the constructor, which
+    holds a preset's two readings of the window to one value: the fault is
+    the only config whose model and engine read different windows."""
+    fields = dict(fields)
+    kind = fields.pop("window_layer", None)
+    broken = dataclasses.replace(m, name=name, **fields)
+    if kind is not None:
+        object.__setattr__(broken, "window_layer",
+                           type(m.window_layer)(sorted(kind.items())))
+    return broken
+
+
+def fitted(m, params: dict) -> dict:
+    """``params`` cut to the preset ``m`` where a fault gave its window
+    layers fewer heads than the weights hold: the first heads' columns of
+    ``wq`` and ``wg`` and rows of ``wo`` (the others are dropped, and a
+    query head reads the KV head of the other kind's grouping)."""
+    kind = dict(m.window_layer)
+    if "window_mixers" not in params or "num_heads" not in kind:
+        return params
+    h, hd = kind["num_heads"], m.head_dim_
+    held = params["window_mixers"]
+    out = 1 if m.proj_out_in else 2     # ``wq``'s axis of heads
+    if held["wq"].shape[out] == h * hd:
+        return params
+    cut = {**held, "wq": jnp.take(held["wq"], jnp.arange(h * hd), axis=out),
+           "wo": held["wo"][:, :h * hd], "wg": held["wg"][..., :h]}
+    return {**params, "window_mixers": cut}
 
 
 def places(m, kind) -> int:
@@ -252,9 +306,12 @@ def pytest_generate_tests(metafunc):
     m = get_config(metafunc.module.CASE.tiny)
     if "form" in metafunc.fixturenames:
         metafunc.parametrize("form", [f for f in FORMS if not (
-            m.recurrent and f == "rows-dense")])
+            m.unbuilt_for and f == "rows-dense")])
     if "rule" in metafunc.fixturenames:
         metafunc.parametrize("rule", sorted(left_out(m)))
+    if "refused" in metafunc.fixturenames:
+        metafunc.parametrize("refused", sorted(
+            REFUSALS["recurrent" if m.recurrent else "window"]))
     if "state_by" in metafunc.fixturenames:
         metafunc.parametrize("state_by", ["auto", "always"]
                              if m.mixer_count("kda") else ["auto"])
@@ -306,9 +363,13 @@ def test_served_path_agrees_with_the_plain_reference(
     for prompt, got in zip(asked, served):
         assert len(got[0]) == 8
         assert error(bench, prompt, got) <= cfg["correct"]["limit"]
-    if m.recurrent:     # no prefix is kept: every slot and page comes back
-        assert eng.state.held == 0
+    if m.unbuilt_for:   # no prefix is kept: every slot and page comes back
+        assert eng.state is None or eng.state.held == 0
         assert eng.allocator.free_pages == cfg["server"]["num_pages"] - 1
+    if m.sliding_window:    # both classes of page end where they began
+        pool = eng.window_allocator
+        assert pool.free_pages == pool.num_pages - 1
+        assert eng.metrics["kv_window_pages_released"] > 0
 
 
 def test_num_params_counts_what_init_makes(case):
@@ -350,7 +411,7 @@ def test_the_controls_fail_the_tiny_limits(bench, case):
             lambda path, a: jnp.zeros_like(a)
             if path[-1].key == "router_bias" else a, bench.params)
         assert error(bench, prompt, got, params=flat) > 30 * limit
-    if not m.recurrent:     # a recurrent model's state has no int8 form
+    if not m.unbuilt_for:   # a state, a window class: neither has an int8 form
         got8, = serve(engine(bench, kv_dtype="int8"), [prompt], 8)
         assert error(bench, prompt, got8) > limit
 
@@ -369,6 +430,15 @@ def test_the_counters_count_state_rows_and_expert_visits(bench, case):
         assert c["state_bytes_moved"] % row == 0
         assert c["state_bytes_moved"] // row >= c["decode_tokens"]
         assert c["prefix_skipped"] == 2 and c["radix_hit_tokens"] == 0
+    if m.sliding_window:
+        # a prompt of 40 passes several windows: pages came back, and what
+        # is held is the live windows' pages, never more than a row's most
+        assert c["prefix_skipped"] == 2 and c["radix_hit_tokens"] == 0
+        assert c["kv_window_pages_released"] > 0
+        live, held = (c["kv_window_live_token_steps"],
+                      c["kv_window_held_slot_steps"])
+        assert 0 < live <= held < 3 * live
+        assert live <= 2 * m.sliding_window * c["steps_run"]
     if m.num_experts:
         # slots count the experts held, in the expert layers alone; (row,
         # chosen expert) pairs the held share of rows x top k x layers
@@ -438,10 +508,12 @@ def test_each_rule_left_out_moves_the_logits_far_past_the_limit(bench, rule):
     reference, which keeps them all."""
     cfg = bench.cfg
     name = bench.preset.name + "-broken"
-    presets._PRESETS[name] = dataclasses.replace(
-        bench.preset, name=name, **left_out(bench.preset)[rule])
+    broken = presets._PRESETS[name] = broken_preset(
+        bench.preset, name,
+        left_out(bench.preset, cfg["server"]["page_size"])[rule])
     prompt, = prompts(cfg, (80,), seed=5)
-    got, = serve(engine(bench, model=name), [prompt], 8)
+    got, = serve(Engine(EngineConfig(model=name, **cfg["server"]),
+                        params=fitted(broken, bench.params)), [prompt], 8)
     # (rotation moves a toy least, 30 limits: its scores are near uniform)
     assert error(bench, prompt, got) > 10 * cfg["correct"]["limit"]
     # and the sound program on the same prompt is within it
@@ -449,7 +521,7 @@ def test_each_rule_left_out_moves_the_logits_far_past_the_limit(bench, rule):
     assert error(bench, prompt, got) <= cfg["correct"]["limit"]
 
 
-@where(lambda m: not m.recurrent)
+@where(lambda m: not m.unbuilt_for)
 def test_contiguous_forward_agrees_with_the_reference_and_trains(bench):
     cfg, m = bench.cfg, bench.preset
     toks = np.random.default_rng(3).integers(1, cfg["vocab_size"],
@@ -471,6 +543,9 @@ def test_contiguous_forward_agrees_with_the_reference_and_trains(bench):
 # ---- a model with recurrent layers: its state is a row's own ------------------
 
 recurrent = where(lambda m: m.recurrent, part="state")
+# ... and what holds of every model that keeps no prefix: recurrent layers,
+# or window layers, whose second class of page is given back as it is served
+pools_alone = where(lambda m: m.unbuilt_for, part="state")
 TINY_KW = dict(page_size=8, num_pages=32, max_seq_len=64, max_batch=2,
                prefill_chunk=16)
 
@@ -522,7 +597,7 @@ def test_a_slot_reused_after_finish_and_after_preemption_starts_from_zeros(
     assert eng.metrics["state_resets"] == 4
 
 
-@recurrent
+@pools_alone
 def test_a_preempted_requests_second_run_gives_the_first_runs_logits(bench):
     prompt, other = prompts(bench.cfg, (60, 30), seed=3)
     whole = serve(engine(bench), [prompt], 12)[0]
@@ -550,7 +625,7 @@ def test_a_preempted_requests_second_run_gives_the_first_runs_logits(bench):
     assert error(bench, prompt, (toks, lps)) <= bench.cfg["correct"]["limit"]
 
 
-@recurrent
+@pools_alone
 def test_a_cached_prefix_matches_nothing_and_still_agrees(bench):
     first, tail = prompts(bench.cfg, (64, 20), seed=4)
     eng = engine(bench)
@@ -565,18 +640,51 @@ def test_a_cached_prefix_matches_nothing_and_still_agrees(bench):
     assert error(bench, second, got) <= bench.cfg["correct"]["limit"]
 
 
-@recurrent
-@pytest.mark.parametrize("kw,match", [
-    (dict(speculative="ngram"), "speculative decoding: a rejected draft"),
-    (dict(kv_dtype="int8"), "the state pool has no quantised form"),
-    (dict(mode="prefill"), "PD bundle carries pages, not the recurrent state"),
-    (dict(mode="decode"), "PD bundle carries pages, not the recurrent state"),
-    (dict(host_tier_bytes=1 << 20), "host tier keeps prefixes"),
-    (dict(mesh=True), "a device mesh: the state pool has no sharding"),
-])
-def test_engine_refuses_what_a_recurrent_model_does_not_support(
-        case, kw, match):
-    """``Engine._refuse_for_recurrent``'s five reasons, by message."""
+# {what the model has: {what is asked for: (EngineConfig fields, the
+# refusal's message)}}: ``Engine._refuse_unbuilt``'s reasons, by mechanism
+REFUSALS = {
+    "recurrent": {
+        "speculative": (dict(speculative="ngram"),
+                        "speculative decoding: a rejected draft"),
+        "int8": (dict(kv_dtype="int8"),
+                 "the state pool has no quantised form"),
+        "prefill role": (dict(mode="prefill"),
+                         "PD bundle carries pages, not the recurrent state"),
+        "decode role": (dict(mode="decode"),
+                        "PD bundle carries pages, not the recurrent state"),
+        "host tier": (dict(host_tier_bytes=1 << 20),
+                      "host tier keeps prefixes"),
+        "mesh": (dict(mesh=True),
+                 "a device mesh: the state pool has no sharding"),
+    },
+    "window": {
+        "speculative": (dict(speculative="ngram"),
+                        "the verify step has no window table"),
+        "int8": (dict(kv_dtype="int8"),
+                 "the window class of page has no quantised form"),
+        "prefill role": (dict(mode="prefill"),
+                         "PD bundle carries one class of page"),
+        "decode role": (dict(mode="decode"),
+                        "PD bundle carries one class of page"),
+        "host tier": (dict(host_tier_bytes=1 << 20),
+                      "a prefix's window pages were given back"),
+        "mesh": (dict(mesh=True),
+                 "the window class of page has no sharding"),
+    },
+}
+
+
+def _named(cfg) -> str:
+    """How a refusal names what ``cfg`` has, as a pattern."""
+    return re.escape(cfg.unbuilt_for)
+
+
+@pools_alone
+def test_engine_refuses_what_the_model_does_not_support(case, refused):
+    """``Engine._refuse_unbuilt``'s reasons, by message: each names the
+    mechanism that is missing for what the model has."""
+    cfg = get_config(case.tiny)
+    kw, match = REFUSALS["recurrent" if cfg.recurrent else "window"][refused]
     kw, mesh = dict(kw), None
     if kw.pop("mesh", False):
         from jax.sharding import Mesh
@@ -584,12 +692,11 @@ def test_engine_refuses_what_a_recurrent_model_does_not_support(
                     ("dp", "tp"))
     with pytest.raises(ValueError, match=match) as e:
         Engine(EngineConfig(model=case.tiny, **TINY_KW, **kw), mesh=mesh)
-    kinds = ", ".join(get_config(case.tiny).recurrent_kinds)
-    assert f"has recurrent layers ({kinds})" in str(e.value)
+    assert cfg.unbuilt_for in str(e.value)
 
 
-@recurrent
-def test_other_paths_refuse_a_recurrent_model(case):
+@pools_alone
+def test_other_paths_refuse_a_model_served_from_its_pools_alone(case):
     cfg = get_config(case.tiny)
     params = init_params(cfg, jax.random.key(0))
     eng = Engine(EngineConfig(model=case.tiny, **TINY_KW), params=params)
@@ -600,7 +707,7 @@ def test_other_paths_refuse_a_recurrent_model(case):
     with pytest.raises(ValueError, match="state at its end"):
         eng.add_request_with_prefix(list(range(1, 20)), None, 8, None, None)
     tokens = jnp.ones((1, 4), jnp.int32)
-    named = rf"has recurrent layers \({', '.join(cfg.recurrent_kinds)}\)"
+    named = _named(cfg)
     with pytest.raises(NotImplementedError,
                        match=named + ": the contiguous cache"):
         llama.forward(params, cfg, tokens, llama.KVCache(

@@ -237,11 +237,15 @@ def abstract_engine(chip, monkeypatch):
 
 
 def _state_kw(chip, eng, rows):
-    """A recurrent model's state pool and slots, as shapes on the chip."""
-    if eng.state is None:
-        return {}
-    return _on(chip, {"state": eng.state.arrays,
-                      "slots": jnp.zeros(rows, I32)})
+    """A recurrent model's state pool and slots, a window model's second
+    class of page and its rows' lines, as shapes on the chip."""
+    kw = {}
+    if eng.state is not None:
+        kw.update(state=eng.state.arrays, slots=jnp.zeros(rows, I32))
+    if eng.window_allocator is not None:
+        kw.update(window=eng.cache.window_pages,
+                  wtable=jnp.zeros((rows, eng.cfg.max_pages_per_seq), I32))
+    return _on(chip, kw)
 
 
 def _compile_unified(chip, eng):
@@ -393,6 +397,32 @@ CELL_PROGRAMS = {
                 "state/conv": (6, 32, 73728)},
         walk=("_decode_call", "_block_ragged_call"),
         temps=(256 * MB, 640 * MB)),
+    # 20 layers in five periods F W W W (F: 48 / 8 heads of 128, half of
+    # each rotated under YaRN; W: 64 / 8 heads within 512 tokens), a gate a
+    # head, a dense first layer and 32 of 256 experts in the other 19, 32
+    # rows. TWO classes of page: the full layers' pool over 8192 pages, the
+    # window layers' over 32 rows x 37 + 1, each walked by the two kernels
+    # (a window layer's with its first live block and its lower mask), and
+    # neither copied. No temporary the size of a layer's held experts
+    # (201 MB) or of a pool, and none the size of a mixer's projections:
+    # held ``[L, in, out]`` the three stacks were transposed whole in every
+    # step's entry computation (0.75 GB read and written, 782 MB of a decode
+    # step's temporaries: ROADMAP S23 + S14, hoisted out of a loop of three
+    # trips); held ``[L, out, in]`` (``proj_out_in``) a decode step keeps
+    # 6 MB, a unified step of 32 x 64 packed tokens 81.
+    "laguna": dict(
+        file="laguna-xs2.json",
+        shapes={"moe_mlps/moe_gate": (19, 32, 2048, 512),
+                "moe_mlps/router": (19, 2048, 256),
+                "mixers/wq": (5, 6144, 2048), "mixers/wg": (5, 2048, 48),
+                "window_mixers/wq": (15, 8192, 2048),
+                "window_mixers/wk": (15, 1024, 2048),
+                "window_mixers/wg": (15, 2048, 64),
+                "lm_head": (2048, 12544),
+                "k_pages": (5, 8192, 16, 8, 128),
+                "window_k": (15, 1185, 16, 8, 128)},
+        walk=("_decode_call", "_block_ragged_call"),
+        temps=(32 * MB, 192 * MB)),
 }
 
 
@@ -401,8 +431,24 @@ def _served_shapes(eng):
     what the engine holds on the chip."""
     held = {"k_pages": eng.cache.k_pages, "v_pages": eng.cache.v_pages,
             "state": eng.state.arrays if eng.state else {}, **eng.params}
+    if eng.cache.window_k is not None:
+        held.update(window_k=eng.cache.window_k, window_v=eng.cache.window_v)
     return {"/".join(str(k.key) for k in path): leaf.shape for path, leaf
             in jax.tree_util.tree_flatten_with_path(held)[0]}
+
+
+def _packed_queries(eng, dims):
+    """Whether ``dims`` is a unified step's packed queries of one of the
+    model's layer kinds: ``[..., heads, head_dim]`` or ``[..., kv heads,
+    group, head_dim]`` over ``max_batch x prefill_chunk`` tokens."""
+    packed = eng.cfg.max_batch * eng.cfg.prefill_chunk
+    for _, cfg, _, _ in eng.mcfg.layer_groups:
+        kv, hd = cfg.num_kv_heads, cfg.head_dim_
+        for tail in ((cfg.num_heads, hd), (kv, cfg.num_heads // kv, hd)):
+            if (dims[-len(tail):] == tail
+                    and np.prod(dims[:-len(tail)]) == packed):
+                return True
+    return False
 
 
 def _shaped(text, shape, ops=r"\w[\w-]*"):
@@ -443,7 +489,8 @@ def test_step_programs_of_a_cell_fit_and_copy_no_pool(chip, monkeypatch, cell,
     # singleton axis) has a pool's count of values.
     state = eng.state.arrays if eng.state else {}
     pools = {eng.cache.k_pages.size, eng.cache.v_pages.size,
-             *(a.size for a in state.values())}
+             *(a.size for a in state.values()),
+             *(a.size for a in eng.cache.window_pages or ())}
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
     assert not [dims for dims in copied if np.prod(
         [int(d) for d in dims.split(",")]) in pools]
@@ -457,11 +504,18 @@ def test_step_programs_of_a_cell_fit_and_copy_no_pool(chip, monkeypatch, cell,
         # of its 20 trips (``copy-done bf16[1,9216,2304]``, 1.77 ms of a
         # decode step). What the compiler prefetches once a step stands in
         # the entry computation.
+        # (found by its count of values, under any shape. One activation
+        # has as many, and is named: a unified step's packed queries of a
+        # layer kind, Laguna's 32 x 64 tokens x 64 window heads x 128 =
+        # 8192 x 2048, which the ragged kernel's wrapper lays out by tile
+        # as ``[256,8,64,128]`` and ``[256,8,8,8,128]``)
         dense = eng.params["dense_mlps"]["w_down"].size
-        in_loops = [dims for dims in re.findall(
-            r"= \w+\[([\d,]+)\]\S* copy(?:-done)?\(",
-            text[:text.index("\nENTRY ")])
-            if np.prod([int(d) for d in dims.split(",")]) == dense]
+        in_loops = [dims for dims in (
+            tuple(int(d) for d in found.split(",")) for found in re.findall(
+                r"= \w+\[([\d,]+)\]\S* copy(?:-done)?\(",
+                text[:text.index("\nENTRY ")]))
+            if np.prod(dims) == dense
+            and not (not decode and _packed_queries(eng, dims))]
         assert not in_loops
     if "s" in state:
         # A decode step advances the delta rule's states where they lie

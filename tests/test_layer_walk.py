@@ -194,17 +194,20 @@ def test_router_and_shared_expert_open_inside_the_expert_scope(moe_engine):
 
 @pytest.mark.parametrize("program", ["decode", "ragged"])
 @pytest.mark.parametrize("model,inner", [("tiny-kimi-linear", "kda"),
-                                         ("tiny-lfm2", "conv")])
+                                         ("tiny-lfm2", "conv"),
+                                         ("tiny-laguna", "window")])
 def test_every_dot_of_the_recurrent_layers_sits_under_attention_and_kda(
         program, model, inner):
-    """``device.kda_share`` reads the path ``attention/kda`` and
-    ``device.conv_share`` the path ``attention/conv``: every dot of a
-    recurrent layer's mixer (projections, the state's dots, ``wo``) carries
-    its kind's, no dot of an attention layer or of an MLP does, and each
-    dot of the step sits in one model scope still (the recurrent layers'
-    time is part of ``device.attention_share``)."""
+    """``device.kda_share`` reads the path ``attention/kda``,
+    ``device.conv_share`` the path ``attention/conv`` and
+    ``device.window_attn_share`` the path ``attention/window``: every dot
+    of a recurrent layer's, or of a window layer's, mixer (projections, the
+    state's or the attend's dots, ``wo``) carries its kind's, no dot of a
+    full-attention layer or of an MLP does, and each dot of the step sits
+    in one model scope still (these layers' time is part of
+    ``device.attention_share``)."""
     from rbg_tpu.obs.names import ATTENTION_INNER_SCOPES
-    assert ATTENTION_INNER_SCOPES == ("kda", "conv")
+    assert ATTENTION_INNER_SCOPES == ("kda", "conv", "window")
     eng = Engine(EngineConfig(
         model=model, page_size=8, num_pages=64, max_seq_len=128,
         max_batch=4, prefill_chunk=16, use_pallas="never"))
@@ -219,8 +222,8 @@ def test_every_dot_of_the_recurrent_layers_sits_under_attention_and_kda(
     assert all(len(s) == 1 for s in scopes), [
         p for p, s in zip(paths, scopes) if len(s) != 1]
     assert {s[0] for s in scopes} == {"attention", "mlp", "moe", "lm_head"}
-    other, = set(ATTENTION_INNER_SCOPES) - {inner}
-    assert not [p for p in paths if other in p]
+    others = set(ATTENTION_INNER_SCOPES) - {inner}
+    assert not [p for p in paths if others & set(p)]
     kda = [p for p in paths if inner in p]
     assert kda and all(p[p.index(inner) - 1] == "attention" for p in kda)
     assert [p for p in paths if "attention" in p and inner not in p]

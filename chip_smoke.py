@@ -341,7 +341,8 @@ def child_reference(args) -> None:
     unified = eng._get_ragged_fn(R, T).lower(
         eng.params, S((1, T), i32), S((1, T), i32), S((1, T), bool),
         S((T,), i32), S((R,), i32), S((R, P), i32), eng.cache.k_pages,
-        eng.cache.v_pages, None, None).compile()
+        eng.cache.v_pages, None, None, S((1, T), i32),
+        S((eng._rows_max,), i32), S((eng._rows_max,), i32)).compile()
     temps, ks, tps, mps, seeds, rids, _, _, _ = eng._sampling_rows([], R)
     vec = S((R,), i32)
     decode = eng._get_decode_fn(R, False, False).lower(

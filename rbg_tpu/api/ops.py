@@ -266,6 +266,11 @@ ENGINE_OPS: Dict[str, OpSpec] = {
     OP_WARMUP: _spec(OP_WARMUP, PLANE_ENGINE, True,
                      {"input_len": "int?"},
                      {"ok": ("ok", "elapsed_s")}),
+    # ``metrics`` carries the step timeline's clocks and counts as
+    # ``Engine.metrics`` holds them (docs/observability.md section 2.5):
+    # among them ``steps_run``, ``device_waited_steps`` and, beside it,
+    # ``lagged_steps`` (steps dispatched while the step before them was
+    # unread: the one pending read of both step kinds).
     OP_METRICS: _spec(OP_METRICS, PLANE_ENGINE, False, {},
                       {"ok": ("metrics", "mode")}),
     OP_SLO: _spec(OP_SLO, PLANE_ENGINE, False, {"window": "float?"},

@@ -29,7 +29,7 @@ import itertools
 import json
 import logging
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,8 +42,8 @@ from rbg_tpu.engine.kvcache import (PageAllocator, PagedKVCache, StatePool,
 from rbg_tpu.engine.radix_cache import RadixCache
 from rbg_tpu.engine.sampler import NEG_INF, row_keys, sample, step_keys
 from rbg_tpu.obs.names import (PROGRAM_FUSED_DECODE, PROGRAM_PAGED_FWD,
-                               PROGRAM_RAGGED_FWD, PROGRAM_SAMPLER,
-                               PROGRAM_SPEC_VERIFY)
+                               PROGRAM_PLACE_TOKENS, PROGRAM_RAGGED_FWD,
+                               PROGRAM_SAMPLER, PROGRAM_SPEC_VERIFY)
 from rbg_tpu.models.llama import forward_paged, forward_ragged, init_params
 from rbg_tpu.obs import names as obs_names
 from rbg_tpu.obs import trace
@@ -62,8 +62,8 @@ class _Phase:
     """One phase of a step: its ``jax.profiler`` annotation, the stamp of
     when it last began (for the step record), and its wall time added to
     a cumulative clock in ``Engine.metrics``. A phase entered inside
-    another suspends the outer one's clock (a drain of the pending decode
-    window inside ``engine.pack`` is sync and emit time, not pack time),
+    another suspends the outer one's clock (a read of the pending step
+    inside ``engine.pack`` is sync and emit time, not pack time),
     so the clocks never overlap and sum to no more than the step."""
 
     __slots__ = ("eng", "idx", "ann", "outer", "t0")
@@ -110,6 +110,34 @@ class StepEvent:
     finished: bool
     text_done: bool = False
     logprob: Optional[float] = None
+
+
+class _Unread(NamedTuple):
+    """A step whose sampled tokens are still on the device: the engine's
+    one pending read, whichever kind of step left it. ``rows[j]`` owns
+    column ``j`` of ``toks`` (``[K, B]`` of a fused window, one line of a
+    unified step) and of ``lps``, ``valid[j]`` of its tokens count, and
+    entry ``j`` of ``last`` is the token its next step reads
+    (``Engine._unread_tokens``): the window's carried ``tok``, a unified
+    step's ``toks`` itself."""
+    rows: list
+    toks: object
+    lps: object
+    visited: object             # a fused window's count of expert visits
+    valid: List[int]
+    first: Optional[List[bool]]  # a unified step: the row's first token
+    last: object
+
+
+def _place_tokens(tok, take, prev):
+    """``tok`` where ``take`` is negative, entry ``take`` of ``prev``
+    elsewhere: how a step reads a row's input token off the step before
+    it while the host has not seen that token."""
+    return jnp.where(take >= 0, prev[jnp.maximum(take, 0)], tok)
+
+
+_place_tokens.__name__ = PROGRAM_PLACE_TOKENS   # jitwatch catalog name
+_place = jax.jit(_place_tokens)
 
 
 class Request:
@@ -231,9 +259,18 @@ class Engine:
         self._fwd_cache: Dict[Tuple[int, int], object] = {}
         self._samplers: Dict[Tuple[bool, bool], object] = {}
         # Fused decode path: device-resident (tok, pos, kvl, table, …) state
-        # plus a one-step emission lag so host bookkeeping for step N+1
-        # overlaps the device computing step N (see _decode_step).
+        # of the window's rows, built anew when the batch changes.
         self._dec: Optional[dict] = None
+        # The one-step emission lag of every step kind: the step whose
+        # tokens are still on the device. The step after it is dispatched
+        # first and takes its rows' input tokens from ``last``, then the
+        # host fetches and emits these (``_emit_pending``), so its
+        # bookkeeping for step N+1 overlaps the device computing it.
+        self._pending: Optional[_Unread] = None
+        # What a step that reads nothing off the step before it is given
+        # in ``last``'s place: as wide as the widest line of rows.
+        self._rows_max = self._bucket(cfg.max_batch)
+        self._no_tokens = jnp.zeros(self._rows_max, jnp.int32)
         self._dec_fn_cache: Dict[Tuple[int, bool, bool], object] = {}
         self._spec_fn_cache: Dict[Tuple[int, bool, bool, bool, bool], object] = {}
         # Ragged unified prefill/decode dispatch: one compiled program per
@@ -303,6 +340,10 @@ class Engine:
                         "t_host_s": 0.0, "t_host_off_s": 0.0,
                         "late_steps": 0, "t_late_s": 0.0,
                         "device_waited_steps": 0,
+                        # Steps dispatched while the step before them
+                        # was unread (``_pending``): the host's work
+                        # for them ran beside the device's.
+                        "lagged_steps": 0,
                         # Decode steps that visited hit experts only
                         # (llama._moe_mlp_hit): experts x layers a step,
                         # and how many of them the step read.
@@ -927,13 +968,15 @@ class Engine:
             self._dispatched = (kind, rows, q_tokens,
                                 (row_bucket, token_bucket))
             # Is a program this engine dispatched earlier still running?
-            # Only a fused decode window is left unfetched between steps:
-            # with none pending, or its tokens ready, the device has
-            # finished all it was given and idles until this dispatch
-            # lands. No device read, no sync.
-            window = self._dec["pending"] if self._dec is not None else None
-            if window is None or window[1].is_ready():
+            # A step is left unread between steps, whichever its kind,
+            # unless its rows needed the host: with none pending, or its
+            # tokens ready, the device has finished all it was given and
+            # idles until this dispatch lands. No device read, no sync.
+            unread = self._pending
+            if unread is None or unread.toks.is_ready():
                 self.metrics["device_waited_steps"] += 1
+            if unread is not None:
+                self.metrics["lagged_steps"] += 1
             if self.state is not None:
                 # Held as the step starts: a row that finishes in this
                 # step frees its slot before the step is recorded.
@@ -1267,15 +1310,23 @@ class Engine:
 
             def wrapped(params, tokens, positions, token_mask, row_ids,
                         kv_lens, page_table, k_pages, v_pages, k_scales,
-                        v_scales, state=None, slots=None, window=None,
-                        wtable=None):
-                return base(params, tokens=tokens, positions=positions,
-                            token_mask=token_mask, row_ids=row_ids,
-                            kv_lens=kv_lens, page_table=page_table,
-                            k_pages=k_pages, v_pages=v_pages,
-                            k_scales=k_scales, v_scales=v_scales,
-                            state=state, state_slots=slots,
-                            window_pages=window, window_table=wtable)
+                        v_scales, take, prev, rows, state=None, slots=None,
+                        window=None, wtable=None):
+                # A decode row's token may still be on the device, in the
+                # unread step before this one (``_unread_tokens``).
+                tokens = _place_tokens(tokens, take, prev)
+                logits, *pools = base(
+                    params, tokens=tokens, positions=positions,
+                    token_mask=token_mask, row_ids=row_ids,
+                    kv_lens=kv_lens, page_table=page_table,
+                    k_pages=k_pages, v_pages=v_pages,
+                    k_scales=k_scales, v_scales=v_scales,
+                    state=state, state_slots=slots,
+                    window_pages=window, window_table=wtable,
+                    head_rows=rows)
+                # The sampling rows' logits alone, [rows, V]: with two
+                # steps in flight a whole packed line's would be held twice.
+                return (logits[0], *pools)
 
             wrapped.__name__ = PROGRAM_RAGGED_FWD   # jitwatch catalog name
             donate = (7, 8, 9, 10) if self.cache.quantized else (7, 8)
@@ -1319,7 +1370,8 @@ class Engine:
                     jnp.zeros((R, P), jnp.int32),
                     self.cache.k_pages, self.cache.v_pages,
                     self.cache.k_scales, self.cache.v_scales,
-                    **self._state_kw([], R))
+                    jnp.full((1, t), -1, jnp.int32), self._no_tokens,
+                    self._no_tokens, **self._state_kw([], R))
                 self._put_pools(*pools)
                 n += 1
                 if t >= t_max:
@@ -1439,19 +1491,52 @@ class Engine:
             n += 1
         return n
 
-    def _grow_decode_pages(self, rows: List[Request]) -> None:
+    def warm_place(self) -> int:
+        """Pre-compile the two small programs by which a step reads its
+        rows' tokens off the unread step before it, outside the ragged
+        program (which holds its own placement): a decode state built
+        while a step is unread (``_build_decode_state``, after a unified
+        step or when the batch changed: a line of a decode bucket, taken
+        from the widest line), and a fused window's carried tokens
+        widened to that line (``_unread_tokens``). One program a decode
+        bucket each. Returns the number of programs compiled."""
+        if self.cfg.mode == "prefill" or self.cfg.speculative != "off":
+            return 0   # no fused window: every step is read as it ends
+        n = 0
+        wide = self._rows_max
+        for B in sorted({self._bucket(b)
+                         for b in range(1, self.cfg.max_batch + 1)}):
+            none = jnp.full(B, -1, jnp.int32)
+            _place(jnp.zeros(B, jnp.int32), none, self._no_tokens)
+            n += 1
+            if B != wide:
+                _place(self._no_tokens, jnp.full(wide, -1, jnp.int32),
+                       jnp.zeros(B, jnp.int32))
+                n += 1
+        return n
+
+    def _grow_decode_pages(self, rows: List[Request],
+                           events: List[StepEvent]) -> None:
         """Ensure every decode row has a page for its next token (the
         unified step advances decode rows by exactly one). Preempts the
-        youngest on exhaustion, mirroring the fused path — but with no
-        pending device window to drain (the caller already drained)."""
+        youngest on exhaustion, mirroring the fused path: the tokens in
+        flight are read first (their events go to ``events``), so that a
+        preempted request is sent no stale token and host bookkeeping
+        sees every page it releases consistently; a finish among them may
+        free enough on its own."""
         for req in sorted(rows, key=lambda r: r.t_submit):
             if req.state != "running":
-                continue  # preempted earlier in this very loop
+                continue  # preempted or finished earlier in this very loop
             need = (pages_for_tokens(req.seq_len + 1, self.cfg.page_size)
                     - len(req.pages))
             if need <= 0:
                 continue
             extra = self._alloc(need)
+            if extra is None and self._pending is not None:
+                events.extend(self._read_pending())
+                if req.state != "running":
+                    continue  # the read just finished THIS request
+                extra = self._alloc(need)
             while extra is None:
                 if self._preempt_youngest(exclude=req) is None:
                     break
@@ -1460,6 +1545,19 @@ class Engine:
                 self._preempt(req)
                 continue
             req.pages.extend(extra)
+
+    def _host_bound(self, rows) -> bool:
+        """Whether the next step of ``rows`` needs the host to have seen
+        their last tokens, decided by what the rows are: a grammar row's
+        mask or device state and a penalty row's counts are built on the
+        host from the tokens it has seen (``_sample_unified``,
+        ``_build_decode_state``), and a prefill-role engine's product is
+        the export of the step that just ran. A unified step of such rows
+        keeps the synchronous order: it reads the step before it ahead of
+        its own dispatch, and its own tokens right after."""
+        return self.cfg.mode == "prefill" or any(
+            r.gstate is not None or r.sampling.needs_penalties()
+            for r in rows)
 
     # hot_path
     def _unified_step(self) -> List[StepEvent]:
@@ -1471,15 +1569,30 @@ class Engine:
         grammar masks apply before penalties — so outputs are
         bit-identical to the split prefill/decode programs.
 
-        The pending fused-decode window is drained FIRST: its tokens are
-        already counted in seq_len (the same invariant the runtime-LoRA
-        drain protects — see _rebuild_lora_stack), so dispatching decode
-        rows on top of an undrained window would double-write KV slots
-        and corrupt the stream."""
-        events: List[StepEvent] = list(self._drain_decode())
+        The step ends at the sampler's dispatch and leaves its tokens as
+        the engine's pending read; what it returns are the events of the
+        step BEFORE it, fetched after this one was dispatched, so the
+        device computes this step while the host emits, delivers, admits
+        and packs the next. A decode row whose last token is still unread
+        takes it from that step's device array (``_unread_tokens``), be it
+        a unified step's or a fused window's. What the next pack needs of
+        the host is booked at dispatch: a decode row's ``seq_len`` (a
+        pending step's tokens are already counted in it, the invariant
+        the runtime-LoRA drain protects — see _rebuild_lora_stack), a
+        finishing prefill row's ``state``. Rows that need the host
+        between steps keep the synchronous order (``_host_bound``): the
+        same two reads, each made one call earlier."""
+        events: List[StepEvent] = []
+        # The fused window's device state goes stale with the rows this
+        # step advances; its unread tokens stay the pending read.
+        self._dec = None
+        host_bound = self._host_bound(self.running)
+        if host_bound:
+            events.extend(self._read_pending())
         with _Phase(self, _PACK):
-            packed = self._pack_unified()
+            packed = self._pack_unified(events)
         if packed is None:
+            events.extend(self._read_pending())
             return events
         entries, sample_rows, Ttot, Rb, Tb, dev = packed
 
@@ -1490,58 +1603,82 @@ class Engine:
                 end - start > 1 for _, start, end in entries)
             fn = self._get_ragged_fn(Rb, Tb)
             logits, *pools = fn(
-                self.params, *dev, self.cache.k_pages, self.cache.v_pages,
-                self.cache.k_scales, self.cache.v_scales,
-                **self._state_kw([r for r, _, _ in entries], Rb))
+                self.params, *dev[:6], self.cache.k_pages,
+                self.cache.v_pages, self.cache.k_scales, self.cache.v_scales,
+                *dev[6:], **self._state_kw([r for r, _, _ in entries], Rb))
             self._put_pools(*pools)
 
-            # Host bookkeeping for prefill rows (before emission, matching
-            # the legacy order: seq_len is advanced, then the finish token
-            # emits).
+            # Host bookkeeping at dispatch: the next pack reads it before
+            # this step's tokens are read.
             for req, start, end in entries:
                 if end > start:
                     req.prefill_pos = end
                     req.seq_len = end
                     self.metrics["prefill_tokens"] += end - start
-            if not sample_rows:
-                return events
-            toks, lps = self._sample_unified(logits, sample_rows)
-        with _Phase(self, _SYNC):
-            # One batched fetch instead of two sequential np.asarray syncs
-            # (device_get resolves both leaves in a single transfer; a
-            # None lps leaf passes through untouched).
-            # lint: allow[jit-hygiene] the step's one intrinsic emission fetch — sampled tokens must reach the host to stream
-            toks, lps = jax.device_get((toks, lps))
-        with _Phase(self, _EMIT):
-            for n, (req, _, _, is_decode) in enumerate(sample_rows):
-                lpv = (float(lps[n])
-                       if lps is not None and req.sampling.logprobs else None)
-                if is_decode:
-                    req.seq_len += 1
-                    self.metrics["decode_tokens"] += 1
+                    if end == len(req.prompt):
+                        req.state = "running"
                 else:
-                    req.state = "running"
-                    req.t_first = time.perf_counter()
-                events.append(self._emit(req, int(toks[n]), lpv))
+                    req.seq_len += 1
+            prev, self._pending = self._pending, None
+            if sample_rows:
+                toks, lps = self._sample_unified(logits, sample_rows)
+                self._pending = _Unread(
+                    rows=[r for r, _, _, _ in sample_rows], toks=toks,
+                    lps=lps, visited=None, valid=[1] * len(sample_rows),
+                    first=[not dec for _, _, _, dec in sample_rows],
+                    last=toks)
+        if prev is not None:
+            events.extend(self._emit_pending(prev))
+        if host_bound:
+            events.extend(self._read_pending())
         return events
 
+    def _unread_tokens(self):
+        """Where rows about to decode find their input tokens while the
+        step before them is unread: ``(column, prev)``. ``column`` maps
+        ``id(request)`` to its entry of ``prev``, that step's ``last`` on
+        the device; a row that is not in it takes the host's
+        ``last_token`` (``_place_tokens``: ``take`` -1). ``prev`` is as
+        wide as the widest line of rows, so that one program a shape
+        serves every step before it (``warm_place`` compiles the
+        widening). No device read."""
+        unread = self._pending
+        if unread is None:
+            return {}, self._no_tokens
+        prev = unread.last
+        n = prev.shape[0]
+        if n != self._rows_max:
+            wide = np.full(self._rows_max, -1, np.int32)
+            wide[:n] = np.arange(n, dtype=np.int32)
+            prev = _place(self._no_tokens, jnp.asarray(wide), prev)
+        return {id(r): j for j, r in enumerate(unread.rows)}, prev
+
     # hot_path
-    def _pack_unified(self):
+    def _pack_unified(self, events: List[StepEvent]):
         """The unified step's host side: this step's rows, their packed
-        arrays in numpy, and the uploads. Returns None when no row has
-        work, else (entries, sample_rows, packed tokens, row bucket, token
-        bucket, the six device arrays in the ragged program's argument
-        order)."""
-        decode = [r for r in self.running if r.state == "running"]
-        self._grow_decode_pages(decode)
+        arrays in numpy, and the uploads. Whatever a read ahead of a
+        preemption emits is appended to ``events``. Returns None when no
+        row has work, else (entries, sample_rows, packed tokens, row
+        bucket, token bucket, the device arrays in the ragged program's
+        argument order: the six packed lines, then where its decode rows'
+        tokens are taken from, then the packed offsets its head runs
+        on)."""
+        # A row whose tokens in flight already fill its length budget can
+        # only finish: it is known to end without its token being read.
+        inflight = self._pending_counts()
+        decode = [r for r in self.running if r.state == "running"
+                  and len(r.output) + inflight.get(id(r), 0)
+                  < r.sampling.max_new_tokens]
+        self._grow_decode_pages(decode, events)
 
         entries = []                 # (req, start, end) — end==start: decode
+        stepping = {id(r) for r in decode}
         for r in self.running:
             if r.state == "prefill":
                 start = r.prefill_pos
                 end = min(start + self.cfg.prefill_chunk, len(r.prompt))
                 entries.append((r, start, end))
-            elif r.state == "running":
+            elif r.state == "running" and id(r) in stepping:
                 entries.append((r, r.seq_len, r.seq_len))
         if not entries:
             return None
@@ -1555,6 +1692,10 @@ class Engine:
         Ttot = sum((e - s) if e > s else 1 for _, s, e in entries)
         Tb = self._token_bucket(Ttot)
         tok = np.zeros((1, Tb), np.int32)
+        # Where a decode row's token is still on the device: its entry of
+        # ``prev``; -1 elsewhere (``_place_tokens``, inside the program).
+        take = np.full((1, Tb), -1, np.int32)
+        column, prev = self._unread_tokens()
         # Pad tokens carry position -1 — the ragged-pack pad contract
         # (ops/ragged_paged_attention): the XLA fallback's unpack routes
         # them out of its scatter and the kernel skips them outright.
@@ -1576,9 +1717,13 @@ class Engine:
                     # position right after the prompt (key rule: a token at
                     # absolute position p is keyed by p).
                     sample_rows.append((req, off + n - 1, end, False))
-            else:                    # decode step: write last_token, sample
+            else:                    # decode step: write last token, sample
                 n = 1
-                tok[0, off] = req.last_token
+                j = column.get(id(req))
+                if j is None:
+                    tok[0, off] = req.last_token
+                else:
+                    take[0, off] = j
                 pos[0, off] = req.seq_len
                 kvl[i] = req.seq_len + 1
                 sample_rows.append((req, off, req.seq_len + 1, True))
@@ -1586,21 +1731,29 @@ class Engine:
             row_ids[off:off + n] = i
             table[i, :len(req.pages)] = req.pages
             off += n
-        dev = [jnp.asarray(a) for a in (tok, pos, tmask, row_ids, kvl, table)]
-        return entries, sample_rows, Ttot, Rb, Tb, dev
+        # The packed offsets the head runs on: the sampling rows', in
+        # ``sample_rows``' order, as wide as the widest line of rows.
+        rows = np.zeros(self._rows_max, np.int32)
+        rows[:len(sample_rows)] = [i for _, i, _, _ in sample_rows]
+        dev = [jnp.asarray(a) for a in (tok, pos, tmask, row_ids, kvl, table,
+                                        take)]
+        return (entries, sample_rows, Ttot, Rb, Tb,
+                dev + [prev, jnp.asarray(rows)])
 
     # hot_path
     def _sample_unified(self, logits, sample_rows):
         """One batched sampler dispatch for every sampling row — decode
         steps and finishing prefills together (the _prefill_step /
-        fused-scan sampler, so outputs stay bit-identical). Returns the
-        device arrays (tokens, logprobs or None)."""
+        fused-scan sampler, so outputs stay bit-identical). ``logits`` are
+        the sampling rows' alone, in ``sample_rows``' order, as the ragged
+        program's head left them: ``[rows, V]`` at the widest line of rows,
+        which is the width the sampler runs at and its tokens have, so the
+        step after this one takes them by index (``_unread_tokens``) in one
+        program a shape. Returns the device arrays (tokens, logprobs or
+        None)."""
         reqs = [r for r, _, _, _ in sample_rows]
-        Bs = self._bucket(len(sample_rows))
-        pad = Bs - len(sample_rows)
-        idx = np.asarray([i for _, i, _, _ in sample_rows] + [0] * pad,
-                         np.int32)
-        sel = logits[0][jnp.asarray(idx)]                   # [Bs, V]
+        Bs = self._rows_max
+        sel = logits                 # [Bs, V]: the program picked the rows
         temps, ks, tps, mps, seeds, rids, pen, lp, sorts = \
             self._sampling_rows(reqs, Bs)
         self._note_sampler(sorts)
@@ -1815,10 +1968,10 @@ class Engine:
 
     def _pending_counts(self) -> Dict[int, int]:
         """id(req) → number of un-emitted tokens awaiting fetch."""
-        if self._dec is None or self._dec["pending"] is None:
+        unread = self._pending
+        if unread is None:
             return {}
-        rows, *_, valid = self._dec["pending"]
-        return {id(r): v for r, v in zip(rows, valid)}
+        return {id(r): v for r, v in zip(unread.rows, unread.valid)}
 
     def _decode_batch(self) -> List[Request]:
         """Running requests worth dispatching. Rows whose length budget is
@@ -1841,15 +1994,25 @@ class Engine:
             out.append(r)
         return out
 
-    def _emit_pending(self, pending) -> List[StepEvent]:
-        rows, toks_dev, lp_dev, visited_dev, valid = pending
+    def _emit_pending(self, unread: _Unread) -> List[StepEvent]:
+        """Fetch a step's tokens and emit them: the one path by which a
+        fused window's and a unified step's tokens reach the host. A row
+        that a stop token ended meanwhile (it computed one step more, or
+        the rest of its window) drops what is left: its pages, window
+        pages and state slot went back when it finished, after the
+        program that last wrote them was dispatched."""
         with _Phase(self, _SYNC):
-            vals = np.asarray(toks_dev)      # [K, B] — the one host sync
-            lpv = np.asarray(lp_dev) if lp_dev is not None else None
+            # One batched fetch (device_get resolves the leaves in a
+            # single transfer; a None leaf passes through untouched).
+            # lint: allow[jit-hygiene] the one intrinsic emission fetch — sampled tokens must reach the host to stream
+            vals, lpv = jax.device_get((unread.toks, unread.lps))
+        vals = np.atleast_2d(vals)           # [K, B]; a unified step's K is 1
+        if lpv is not None:
+            lpv = np.atleast_2d(lpv)
         events = []
         with _Phase(self, _EMIT):
-            if visited_dev is not None:      # copied since dispatch
-                self.metrics["moe_experts_visited"] += int(visited_dev)
+            if unread.visited is not None:   # copied since dispatch
+                self.metrics["moe_experts_visited"] += int(unread.visited)
                 moe_layers = self.mcfg.num_moe_layers
                 self.metrics["moe_expert_slots"] += (
                     len(vals) * moe_layers * self.mcfg.experts_here)
@@ -1858,27 +2021,36 @@ class Engine:
                 # Of a held range of the experts, the pairs that fall on it
                 # where routing is uniform (what the host can know).
                 self.metrics["moe_routed_rows"] += (
-                    sum(valid) * moe_layers * self.mcfg.experts_per_token
+                    sum(unread.valid) * moe_layers
+                    * self.mcfg.experts_per_token
                     * self.mcfg.experts_here // self.mcfg.num_experts)
-            for i, req in enumerate(rows):
-                for k in range(valid[i]):
+            first = unread.first
+            for i, req in enumerate(unread.rows):
+                for k in range(unread.valid[i]):
                     if req.state != "running":
                         break                # stop token cut the window short
-                    self.metrics["decode_tokens"] += 1
+                    if first is not None and first[i]:
+                        req.t_first = time.perf_counter()
+                    else:
+                        self.metrics["decode_tokens"] += 1
                     lp = (float(lpv[k, i]) if lpv is not None
                           and req.sampling.logprobs else None)
                     events.append(self._emit(req, int(vals[k, i]), lp))
         return events
 
+    def _read_pending(self) -> List[StepEvent]:
+        """Fetch and emit the unread step now, if there is one: the host
+        has then seen every token computed so far."""
+        unread, self._pending = self._pending, None
+        return self._emit_pending(unread) if unread is not None else []
+
     def _drain_decode(self) -> List[StepEvent]:
-        """Fetch + emit the pending decode tokens and discard the device
-        state (forcing a rebuild). Called whenever the decode batch
+        """Fetch + emit the pending tokens and discard the decode window's
+        device state (forcing a rebuild). Called whenever the decode batch
         composition changes, or before preemption releases pages that host
         bookkeeping must observe consistently."""
-        st, self._dec = self._dec, None
-        if st is None or st["pending"] is None:
-            return []
-        return self._emit_pending(st["pending"])
+        self._dec = None
+        return self._read_pending()
 
     # hot_path
     def _decode_window(self) -> int:
@@ -2032,8 +2204,17 @@ class Engine:
         table = np.zeros((B, P), np.int32)
         temps, ks, tps, mps, seeds, rids, pen, lp, sorts = \
             self._sampling_rows(batch, B)
+        # A row that a unified step just advanced has its last token in
+        # that step's unread line: the window takes it from there, and
+        # unified -> decode chains with no read between.
+        take = np.full(B, -1, np.int32)
+        column, prev = self._unread_tokens()
         for i, r in enumerate(batch):
-            tok[i] = r.last_token
+            j = column.get(id(r))
+            if j is None:
+                tok[i] = r.last_token
+            else:
+                take[i] = j
             pos[i] = r.seq_len
             kvl[i] = r.seq_len + 1
             mask[i, 0] = True
@@ -2043,14 +2224,15 @@ class Engine:
         st = {
             "rows": list(batch), "B": B, "pen": pen, "lp": lp,
             "sorts": sorts, "lids": lids,
-            "tok": jnp.asarray(tok), "pos": jnp.asarray(pos),
+            "tok": (_place(jnp.asarray(tok), jnp.asarray(take), prev)
+                    if column else jnp.asarray(tok)),
+            "pos": jnp.asarray(pos),
             "kvl": jnp.asarray(kvl), "mask": jnp.asarray(mask),
             "limit": jnp.asarray(limit),
             "temps": jnp.asarray(temps), "ks": jnp.asarray(ks),
             "tps": jnp.asarray(tps), "mps": jnp.asarray(mps),
             "keys": row_keys(seeds, self._sample_base, rids),
             "table_np": table, "table": jnp.asarray(table),
-            "pending": None,
         }
         if self.state is not None:
             st["slots"] = self._slot_rows(batch, B)
@@ -2151,8 +2333,9 @@ class Engine:
                 valid.append(min(K, req.max_len() - req.seq_len))
                 req.seq_len = min(req.seq_len + K, req.max_len())
 
-            prev, st["pending"] = st["pending"], (list(batch), toks_seq,
-                                                  lp_seq, visited, valid)
+            prev, self._pending = self._pending, _Unread(
+                rows=list(batch), toks=toks_seq, lps=lp_seq, visited=visited,
+                valid=valid, first=None, last=tok)
         if prev is not None:
             events.extend(self._emit_pending(prev))
         return events
@@ -2167,12 +2350,21 @@ class Engine:
         batch = self._decode_batch()
         st = self._dec
         if st is not None and st["rows"] != batch:
-            events.extend(self._drain_decode())
-            st = None
-            batch = self._decode_batch()
+            # A row left the batch (it is at its length, or a stop token or
+            # a cancel ended it) or joined it: the state is built anew, and
+            # takes the rows' tokens from the unread step like any other.
+            st = self._dec = None
         if not batch:
             events.extend(self._drain_decode())
             return None
+        if st is None and self._pending is not None \
+                and self._host_bound(batch):
+            # The state about to be built holds grammar states and output
+            # counts, which the host builds from the tokens it has seen.
+            events.extend(self._read_pending())
+            batch = self._decode_batch()
+            if not batch:
+                return None
 
         # Ensure pages exist for the whole decode window; preempt the
         # youngest requests on exhaustion. Oldest-first so old requests

@@ -508,6 +508,7 @@ class _BatchService:
         # otherwise first compile MID-SERVING, on the join-latency path.
         timed("join_windows", self.engine.warm_join_windows)
         timed("samplers", self.engine.warm_samplers)
+        timed("place", self.engine.warm_place)
         # Arm the jitwatch gate (no-op unless RBG_JITWATCH armed the
         # hooks): everything compiled above is the blessed warmup set;
         # any cataloged program compiling after this is a violation.

@@ -1332,6 +1332,7 @@ def forward_ragged(
     state_slots: Optional[jnp.ndarray] = None,   # [R] int32 slot per row
     window_pages: Optional[tuple] = None,    # window layers: their class
     window_table: Optional[jnp.ndarray] = None,  # [R, P] int32, its lines
+    head_rows: Optional[jnp.ndarray] = None,  # [S] int32 packed offsets
 ):
     """Serving forward over a RAGGED packed batch: prefill chunks and decode
     steps of different rows ride ONE dispatch (tokens packed row-major on the
@@ -1342,7 +1343,10 @@ def forward_ragged(
     Returns (logits [1, T, V] f32, k_pages, v_pages, k_scales, v_scales),
     and the new state after them where ``state`` was given, the window
     class's pools after that where ``window_pages`` were
-    (``forward_paged``'s order)."""
+    (``forward_paged``'s order). With ``head_rows`` the head runs on those
+    packed tokens alone and the logits are ``[1, S, V]``: a step samples at
+    most a token a row, and ``[T, V]`` float32 is the largest array a
+    packed step makes."""
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
     pool = _all_pools(k_pages, v_pages, k_scales, v_scales, state,
                       window_pages)
@@ -1351,6 +1355,8 @@ def forward_ragged(
         PoolAddr(positions, token_mask, kv_lens, page_table, row_ids,
                  max_q_len, state_slots, window_table),
         layers=(0, cfg.num_layers), use_pallas=use_pallas)
+    if head_rows is not None:
+        x = x[:, head_rows]
     return (_head(params, cfg, x), *pool)
 
 

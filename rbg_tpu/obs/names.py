@@ -578,7 +578,9 @@ SPAN_ROUTER_RESHARD = "router.reshard"
 # the ``metrics`` op as ``Engine.metrics`` holds them (docs/observability.md
 # section 2.5 lists each: ``steps_run``, ``unified_steps_run`` and beside it
 # ``unified_rows`` / ``unified_chunk_rows``, the rows of the unified steps
-# that dispatched and those of them that held a chunk).
+# that dispatched and those of them that held a chunk; ``lagged_steps``
+# beside ``device_waited_steps``, the steps dispatched while the step before
+# them was unread).
 SPAN_SERVICE_INTAKE = "service.intake"
 SPAN_SERVICE_DELIVER = "service.deliver"
 SPAN_SERVICE_IDLE = "service.idle"
@@ -654,6 +656,7 @@ PROGRAM_PAGED_FWD = "rbg_paged_fwd"            # Engine._get_fwd
 PROGRAM_FUSED_DECODE = "rbg_fused_decode"      # Engine._get_decode_fn
 PROGRAM_SPEC_VERIFY = "rbg_spec_verify"        # Engine._get_spec_fn
 PROGRAM_SAMPLER = "rbg_sampler"                # Engine._get_sampler
+PROGRAM_PLACE_TOKENS = "rbg_place_tokens"      # engine._place (Engine.warm_place)
 PROGRAM_PD_WINDOW = "rbg_pd_window"            # DecodeWorker._get_window_fn
 PROGRAM_PD_HEAD = "rbg_pd_head"                # DecodeWorker._get_head_fn
 PROGRAM_EMBED_POOLED = "rbg_embed_pooled"      # service._embed_batch
@@ -665,6 +668,7 @@ PROGRAMS = frozenset({
     PROGRAM_FUSED_DECODE,
     PROGRAM_SPEC_VERIFY,
     PROGRAM_SAMPLER,
+    PROGRAM_PLACE_TOKENS,
     PROGRAM_PD_WINDOW,
     PROGRAM_PD_HEAD,
     PROGRAM_EMBED_POOLED,

@@ -89,7 +89,8 @@ def programs(eng, S, T, lora=False, window=None):
         out["rbg_ragged_fwd"] = eng._get_ragged_fn(B, T).lower(
             eng.params, S((1, T), I32), S((1, T), I32), S((1, T), bool),
             S((T,), I32), vec, S((B, P), I32), pool.k_pages, pool.v_pages,
-            *scales, **kw)
+            *scales, S((1, T), I32), S((eng._rows_max,), I32),
+            S((eng._rows_max,), I32), **kw)
     if window is not None:
         D, L = eng.mcfg.hidden_size, eng.mcfg.num_layers
         for lo, hi in ((0, 1), (1, L)):

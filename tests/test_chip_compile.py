@@ -255,6 +255,7 @@ def _compile_unified(chip, eng):
         eng.params, S((1, Tb), I32), S((1, Tb), I32), S((1, Tb), bool),
         S((Tb,), I32), S((Rb,), I32), S((Rb, eng.cfg.max_pages_per_seq), I32),
         eng.cache.k_pages, eng.cache.v_pages, None, None,
+        S((1, Tb), I32), S((eng._rows_max,), I32), S((eng._rows_max,), I32),
         **_state_kw(chip, eng, Rb)).compile()
 
 
@@ -329,13 +330,18 @@ CELL_PROGRAMS = {
     # before the result (two pools' worth of temporaries, 170 MB, and 7.3 %
     # of the cell's device time). Held a whole lane tile wide
     # (``kvcache.rope_pool_width``) it aliases through untouched: 10.5 and
-    # 7.0 MB of temporaries.
+    # 7.0 MB of temporaries. Since PR 48 the unified program's head runs on
+    # the sampling rows alone, so its result is ``[16, V]`` and no longer a
+    # packed line's ``f32[1,1024,129280]`` (529 MB), in whose buffer the
+    # dense dispatch's ``bf16[1024,256,768]`` (403 MB) used to live: that
+    # one intermediate is a temporary now, 405.6 MB in all, and result plus
+    # temporaries fell from 536 MB to 414.
     "joyai": dict(
         file="joyai-llm-flash.json",
         shapes={"blocks/moe_gate": (4, 256, 2048, 768),
                 "v_pages": (5, 8192, 16, 1, 128)},
         walk=("_mla_decode_call", "_block_ragged_mla_call"),
-        temps=(32 * MB, 32 * MB), some_copy=True),
+        temps=(32 * MB, 416 * MB), some_copy=True),
     # all 27 layers (one dense recurrent layer, 19 recurrent and 7 latent
     # expert layers), 16 of 256 experts a layer, 16 rows, pages for the 7
     # latent layers alone and a state slot a row beside them. Each KIND of

@@ -244,10 +244,12 @@ def test_programs_catalog_names_are_stamped_constants():
 @pytest.mark.parametrize("model", ["tiny", "tiny-mla", "tiny-joyai"])
 def test_warm_engine_serves_a_mixed_trace_without_a_compile(watch, model):
     """After ``warm_ragged`` / ``warm_decode`` / ``warm_join_windows`` /
-    ``warm_samplers`` (what ``EngineService.warmup`` runs) a trace of mixed
-    prompt lengths, with arrivals joining a running batch, compiles no
-    cataloged program. On the chip the benchmark reads the same count as
-    ``setup.compiles_in_window``."""
+    ``warm_samplers`` / ``warm_place`` (what ``EngineService.warmup`` runs)
+    a trace of mixed prompt lengths, with arrivals joining a running batch,
+    compiles no cataloged program: not the placement of a window's tokens
+    for the unified step after it either, nor a decode state's built off an
+    unread unified step, at any bucket the joins pass through. On the chip
+    the benchmark reads the same count as ``setup.compiles_in_window``."""
     from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
     watch.arm()
     eng = Engine(EngineConfig(
@@ -256,6 +258,9 @@ def test_warm_engine_serves_a_mixed_trace_without_a_compile(watch, model):
         multi_step=4, use_pallas="never"))
     warmed = (eng.warm_ragged() + eng.warm_decode()
               + eng.warm_join_windows() + eng.warm_samplers())
+    # The placement is one jitted function of the module, not of the
+    # engine: a second engine of the process finds it compiled.
+    assert eng.warm_place() == 5            # buckets 1, 2, 4: 3 + 2
     assert warmed > 0 and watch.warmup_complete() >= warmed
 
     sp = SamplingParams(max_new_tokens=9)
@@ -272,6 +277,10 @@ def test_warm_engine_serves_a_mixed_trace_without_a_compile(watch, model):
     assert all(len(toks) == 9 for toks in done.values())
     assert watch.violations() == []
     assert watch.counters()["rbg_jit_unwarmed_compiles_total"] == 0.0
+    # The trace did chain its steps: all but those that found the engine
+    # with nothing in flight (every row at its length: nothing to dispatch
+    # before the read).
+    assert eng.metrics["lagged_steps"] > eng.metrics["steps_run"] // 2
 
 
 SAMPLING_MIXES = {
@@ -291,7 +300,7 @@ def warmed_engine():
         max_seq_len=128, prefill_chunk=16, enable_radix_cache=False,
         multi_step=4, use_pallas="never"))
     eng.warm_ragged(), eng.warm_decode()
-    eng.warm_join_windows(), eng.warm_samplers()
+    eng.warm_join_windows(), eng.warm_samplers(), eng.warm_place()
     return eng
 
 

@@ -152,7 +152,8 @@ def _lower(eng, program):
         return eng._get_ragged_fn(B, T).lower(
             eng.params, S((1, T), I32), S((1, T), I32), S((1, T), bool),
             S((T,), I32), vec, table, pool.k_pages, pool.v_pages, None, None,
-            **state)
+            S((1, T), I32), S((eng._rows_max,), I32),
+            S((eng._rows_max,), I32), **state)
     worker = DecodeWorker(cfg, params=eng.params)
     return worker._get_window_fn(0, 1, B).lower(
         S((B, 1, eng.mcfg.hidden_size), eng.mcfg.jax_dtype), S((B, 1), I32),

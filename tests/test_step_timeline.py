@@ -372,7 +372,7 @@ def test_a_sync_wait_that_stalled_is_late_and_named_sync(params, monkeypatch):
         def slow_fetch(pending):
             if not state["done"]:
                 state["done"] = True
-                pending = (pending[0], SlowWindow(pending[1])) + pending[2:]
+                pending = pending._replace(toks=SlowWindow(pending.toks))
             return real(pending)
 
         s.engine._emit_pending = slow_fetch
@@ -482,11 +482,13 @@ def test_device_waited_counts_a_dispatch_with_nothing_left_running(
     m = eng.metrics
     while m["decode_steps_run"] < 3:
         eng.step()
-    # Every unified step fetched its own result, and the first decode step
-    # found no window pending: the device waited for each of them.
-    assert m["device_waited_steps"] >= m["unified_steps_run"] + 1
-    pend = eng._dec["pending"]
-    eng._dec["pending"] = (pend[0], FakeWindow(pend[1], ready)) + pend[2:]
+    # The first step of all found nothing pending, so the device waited for
+    # it; every later one was dispatched with the step before it unread,
+    # the decode step after the prompt's unified step too.
+    assert m["device_waited_steps"] >= 1
+    assert m["lagged_steps"] == m["steps_run"] - 1
+    eng._pending = eng._pending._replace(
+        toks=FakeWindow(eng._pending.toks, ready))
     was, steps = m["device_waited_steps"], m["steps_run"]
     eng.step()
     assert m["steps_run"] == steps + 1
@@ -826,5 +828,5 @@ def test_warmup_times_are_on_the_wire(wire):
     m = wire["after"]
     assert m["warmup_s"] > 0
     assert set(m["warmup"]) == {"ragged_s", "waves_s", "decode_s",
-                                "join_windows_s", "samplers_s"}
+                                "join_windows_s", "samplers_s", "place_s"}
     assert sum(m["warmup"].values()) <= m["warmup_s"] + 0.01

@@ -652,6 +652,27 @@ class PoolAddr(NamedTuple):
     # with window layers only), by absolute column like ``page_table``;
     # an entry whose page was given back is 0 and is never attended.
     window_table: Optional[jnp.ndarray] = None
+    # ([R] int32, [R] int32), packed steps only: each row's token count and
+    # its first packed offset (``_row_spans``), read once a step.
+    spans: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
+
+
+def _row_spans(addr: PoolAddr):
+    """What a packed step's rows hold, as its mixers read it
+    (``_pool_attention``, ``_kda_packed``): ``(q_len [R], start [R])``, a
+    row's real tokens in this step and the packed offset of its first
+    (``T``: it has none). A row's tokens lie side by side on the packed
+    axis, so the two say everything; they are read off ``row_ids`` and
+    ``token_mask``, once a step where ``forward_ragged`` left them in
+    ``addr.spans``."""
+    if addr.spans is not None:
+        return addr.spans
+    T, R = addr.row_ids.shape[0], addr.kv_lens.shape[0]
+    I32 = jnp.int32
+    mine = (addr.row_ids == jnp.arange(R, dtype=I32)[:, None]) \
+        & addr.token_mask                                        # [R, T]
+    return (jnp.sum(mine, axis=1, dtype=I32),
+            jnp.min(jnp.where(mine, jnp.arange(T, dtype=I32), T), axis=1))
 
 
 def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
@@ -665,7 +686,30 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
     the layer's ordinal in them (``_kda_attention``, ``_conv_attention``);
     for a window layer (``window``) ``pool`` is the window class's pools
     and ``table`` the layer's own of ``addr.window_table``: the write is
-    the same, the attend keeps to ``cfg.sliding_window``."""
+    the same, the attend keeps to ``cfg.sliding_window``.
+
+    A PACKED step's write is one scatter over the whole pack; its attend
+    is split by what each row holds (``_row_spans``), as ``_kda_packed``
+    splits the delta rule's, for GQA, window and latent layers alike:
+
+    * A row of ONE token (a decoding row; a prompt whose last chunk is one
+      token) takes the decode step's own attend: its query gathered
+      ``[R, 1]``, ``paged_attention`` / ``paged_mla_attention`` over the
+      same ``table`` with ``kv_lens`` zeroed for every other row, which
+      that walk gives one item that attends nothing. ``kv_lens`` of such a
+      row is its token's position + 1, so the decode kernel's causal limit
+      and a window's lower end are the packed form's.
+    * Rows of two tokens or more stay with the ragged attend, they alone:
+      it is handed the positions with -1 at every one-token row's token,
+      padding FOR THE ATTEND ONLY, and padding leads no item there.
+    * A packed token takes the first attend's line if its row holds one
+      token, else the second's (one scatter of ``R`` lines).
+
+    A ragged kernel walks a one-token row at several times the decode
+    kernel's cost (``ops/pallas/ragged_attention_kernel.py``); a step
+    whose rows all hold chunks pays one decode call over ``R`` empty
+    items. On a backend without the kernels both attends are XLA forms
+    over ``paged_attention_xla``."""
     from rbg_tpu.ops.mla_attention import (paged_mla_attention,
                                             ragged_paged_mla_attention)
     from rbg_tpu.ops.paged_attention import paged_attention, write_kv_pages
@@ -678,15 +722,9 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
             return mixer[cfg.attention](cfg, blk, x, pool, table, addr,
                                         use_pallas)
     positions, token_mask, kv_lens, _, row_ids, max_q_len, *_ = addr
-    if row_ids is None:
-        write, attend, attend_mla = (write_kv_pages, paged_attention,
-                                     paged_mla_attention)
-        rows, bound = (), {}
-    else:
-        write, attend, attend_mla = (write_kv_pages_ragged,
-                                     ragged_paged_attention,
-                                     ragged_paged_mla_attention)
-        rows, bound = (row_ids,), {"max_q_len": max_q_len}
+    packed = row_ids is not None
+    write, rows = ((write_kv_pages_ragged, (row_ids,)) if packed
+                   else (write_kv_pages, ()))
     if cfg.mla:
         *q, c, k_pe = _mla_qkv(cfg, blk, x, positions, lora, lora_ids)
         # The rotary key's pool is a whole lane tile wide
@@ -697,18 +735,42 @@ def _pool_attention(cfg: ModelConfig, blk, x, pool, table, addr: PoolAddr,
         k, v = c[:, :, None, :], k_pe[:, :, None, :]
     else:
         q, k, v = _qkv(cfg, blk, x, positions, lora, lora_ids)
+        q = [q]
     kpf, vpf, ksf, vsf = pool = write(*pool[:2], k, v, table, *rows,
                                       positions, token_mask, *pool[2:])
-    where = (kpf, vpf, table, positions, kv_lens, *rows)
     if cfg.mla:
-        return _mla_out(cfg, blk, attend_mla(
-            *q, *where, _mla_scale(cfg), use_pallas=use_pallas, c_scales=ksf,
-            pe_scales=vsf, **bound)), pool
-    if cfg.attention == "window":   # static: another walk, another mask
-        bound["window"] = cfg.sliding_window
-    return _attn_gate(cfg, blk, x, attend(
-        q, *where, use_pallas=use_pallas, k_scales=ksf, v_scales=vsf,
-        **bound)), pool
+        attends, scale = (paged_mla_attention,
+                          ragged_paged_mla_attention), (_mla_scale(cfg),)
+        walk = {"use_pallas": use_pallas, "c_scales": ksf, "pe_scales": vsf}
+    else:
+        attends, scale = (paged_attention, ragged_paged_attention), ()
+        walk = {"use_pallas": use_pallas, "k_scales": ksf, "v_scales": vsf}
+        if cfg.attention == "window":   # static: another walk, another mask
+            walk["window"] = cfg.sliding_window
+
+    def by_row(q, positions, kv_lens):      # a table line a row of queries
+        return attends[0](*q, kpf, vpf, table, positions, kv_lens, *scale,
+                          **walk)
+
+    if not packed:
+        attn = by_row(q, positions, kv_lens)
+    else:
+        T = positions.shape[1]
+        q_len, start = _row_spans(addr)
+        one = q_len == 1
+        first = jnp.minimum(start, T - 1)
+        # where the one-token rows' tokens lie (T, out of range: no such row)
+        lone = jnp.where(one, start, T)
+        attn = attends[1](*q, kpf, vpf, table,    # a table line a token
+                          positions.at[0, lone].set(-1, mode="drop"),
+                          kv_lens, row_ids, *scale, max_q_len=max_q_len,
+                          **walk)
+        o = by_row([a[0, first, None] for a in q], positions[0, first, None],
+                   jnp.where(one, kv_lens, 0))               # [R, 1, h, dv]
+        attn = attn.at[0, lone].set(o[:, 0], mode="drop")
+    if cfg.mla:
+        return _mla_out(cfg, blk, attn), pool
+    return _attn_gate(cfg, blk, x, attn), pool
 
 
 def _row_lines(addr: PoolAddr, T: int):
@@ -864,8 +926,7 @@ def _kda_packed(cfg: ModelConfig, blk, qkv, g, beta, state, layer,
     """The convolution and the recurrence of a packed step (``[1, T]``,
     ``addr.row_ids``), whose cost follows the rows that hold a chunk and
     not the row bucket. A row's tokens lie side by side on the packed
-    axis; how many it has, and where its first lies, is read off
-    ``row_ids`` and ``token_mask``.
+    axis; how many it has, and where its first lies, is ``_row_spans``'.
 
     * A row of ONE token takes the decode step's path
       (``_kda_one_token_rows``): its token is gathered ``[R, 1]`` and
@@ -885,16 +946,13 @@ def _kda_packed(cfg: ModelConfig, blk, qkv, g, beta, state, layer,
     * A row with no token is in neither set: its slot is not touched.
 
     Returns (``o [1, T, H, dk]`` float32, state)."""
-    T, R = qkv.shape[1], addr.kv_lens.shape[0]
+    T = qkv.shape[1]
     C = T if addr.max_q_len is None else min(addr.max_q_len, T)
     I32 = jnp.int32
     h, dk, conv_w = cfg.kda_num_heads, cfg.kda_head_dim, blk["kda_conv"]
     qkv, g, beta = qkv[0], g[0], beta[0]
     slots, n_slots = addr.state_slots, state["s"].shape[1]
-    mine = (addr.row_ids == jnp.arange(R, dtype=I32)[:, None]) \
-        & addr.token_mask                                        # [R, T]
-    q_len = jnp.sum(mine, axis=1, dtype=I32)
-    start = jnp.min(jnp.where(mine, jnp.arange(T, dtype=I32), T), axis=1)
+    q_len, start = _row_spans(addr)
     first = jnp.minimum(start, T - 1)
     fresh = (addr.positions[0, first] == 0) & (q_len > 0)
     layer = jnp.asarray(layer, I32)
@@ -1350,10 +1408,10 @@ def forward_ragged(
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
     pool = _all_pools(k_pages, v_pages, k_scales, v_scales, state,
                       window_pages)
+    addr = PoolAddr(positions, token_mask, kv_lens, page_table, row_ids,
+                    max_q_len, state_slots, window_table)
     x, pool, _ = paged_layers(
-        params, cfg, x, pool,
-        PoolAddr(positions, token_mask, kv_lens, page_table, row_ids,
-                 max_q_len, state_slots, window_table),
+        params, cfg, x, pool, addr._replace(spans=_row_spans(addr)),
         layers=(0, cfg.num_layers), use_pallas=use_pallas)
     if head_rows is not None:
         x = x[:, head_rows]

@@ -131,10 +131,12 @@ def first_live_block(first_token, page: int, n: int = None):
 def find_item(starts_ref, w, n_segments: int):
     """(segment, block of the segment) of work item ``w``: the LAST
     segment whose start is <= w (segments without items share their
-    successor's start and are never found). A fixed-trip bisection over
-    SMEM scalars, so it can run inside an index map. An item past the
-    grid's end, which a pipeline may ask for ahead of time, resolves to
-    the last segment.
+    successor's start and are never found). ``starts_ref`` holds
+    ``n_segments + 1`` scalars, the last the number of items. A fixed-trip
+    search over SMEM scalars, so it can run inside an index map: from
+    segment 0 up by falling powers of two, a step taken while the start
+    there is still <= w. An item past the grid's end, which a pipeline may
+    ask for ahead of time, resolves to the last segment.
 
     Written in ``lax`` primitives, here and in ``page_of_block``: every
     index map of every page of a block traces and lowers this code, in
@@ -142,11 +144,19 @@ def find_item(starts_ref, w, n_segments: int):
     call costs a nested ``jit`` each time (half a minute of set-up).
     Unrolled: as a loop the scalar core pays a branch a trip in every
     index map of every step (`_decode_call` 0.135 -> 0.26 ms on the chip)."""
-    lo, hi = _I32(0), _I32(n_segments)
-    for _ in range(max(n_segments - 1, 1).bit_length()):
-        mid = lax.shift_right_arithmetic(lax.add(lo, hi), _I32(1))
-        left = lax.le(starts_ref[mid], w)
-        lo, hi = lax.select(left, mid, lo), lax.select(left, hi, mid)
+    lo = _I32(0)
+    steps = max(n_segments - 1, 1).bit_length()
+    exact = 1 << steps == n_segments    # a packed axis is a power of two
+    for k in reversed(range(steps)):
+        # Four scalar operations a trip in every map's trace (a bisection
+        # between two bounds takes six).
+        at = lax.add(lo, _I32(1 << k))
+        if exact:
+            lo = lax.select(lax.le(starts_ref[at], w), at, lo)
+        else:       # ``at`` may pass the last segment: it is not taken
+            inside = lax.lt(at, _I32(n_segments))
+            start = starts_ref[lax.min(at, _I32(n_segments))]
+            lo = lax.select(lax.bitwise_and(inside, lax.le(start, w)), at, lo)
     return lo, lax.sub(w, starts_ref[lo])
 
 

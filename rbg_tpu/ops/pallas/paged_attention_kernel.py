@@ -32,8 +32,10 @@ Design (see /opt/skills/guides/pallas_guide.md and ``page_walk.py``):
 
 Reference context: this is the TPU analog of the ragged/paged attention
 kernels the PAPERS.md "Ragged Paged Attention" paper describes. The engine
-uses it for pure-decode batches (T == 1); steps that hold a prefill chunk
-run the block-ragged kernel of ragged_attention_kernel.py.
+uses it for pure-decode batches (T == 1) and for the rows of one token of
+a step that holds a prefill chunk (a unified step: the rows that hold the
+chunks come in at length 0, an empty item each, and run the block-ragged
+kernel of ragged_attention_kernel.py).
 """
 
 from __future__ import annotations

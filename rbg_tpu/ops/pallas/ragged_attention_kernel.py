@@ -36,11 +36,21 @@ pages only (``page_walk.py``):
   (position −1) have limit ≤ 0 → always masked → zero accumulators
   finalize to zero through the denom guard.
 
-Honest cost note: decode rows sharing a tile with a prefill tail attend
-with ``TILE×`` the query rows per page (mostly masked) — the tile trades
-masked MXU lanes (underfilled at small G anyway) for the page-streaming
-win, exactly the RPA paper's trade. Pure-decode batches never reach this
-kernel (the engine's fused multi-step path owns them).
+Honest cost note: a row of ONE token that leads a walk here shares a
+tile of ``Q_TILE`` packed tokens with up to seven other rows, each leading
+its own walk in blocks of 64 slots, and every item attends all ``TILE×G``
+query rows with seven eighths masked, under index maps that search
+(``page_walk.find_item``): several times the decode kernels' time for the
+same pages. The step programs send no such row here. Pure-decode batches
+are the engine's fused multi-step path's, and a unified step's one-token
+rows are handed over as padding: ``models/llama.py::_pool_attention``
+gives this kernel the pack with ``q_position == -1`` at their tokens, the
+pad contract above, so they lead nothing and have no grid step, and the
+decode kernels attend them (``paged_attention_kernel.py``, every other row
+at length 0). What walks here is a prompt's chunk: rows of two tokens or
+more, whose tile does amortise a page over its tokens. The kernels
+themselves take any pack (the tests' and the XLA form's contract): a
+one-token row given at its true position is attended like any row.
 
 Same family of int8 variants as the decode kernel: scales fold
 algebraically into scores/probs, pages feed the MXU as int8. The MLA
@@ -143,21 +153,31 @@ def _tile_limits(row_ids_ref, kv_lens_ref, q_pos_ref, t0, row, tile, group,
     Built from SMEM scalars by ``tile`` selects against a 2-D iota: Mosaic
     has no layout for a rank-1 vector of stacked scalars, nor for the
     shape casts that would broadcast one."""
-    j = jax.lax.broadcasted_iota(jnp.int32, (tile * group, 1), 0)
-    limits = jnp.zeros((tile * group, 1), jnp.int32)
-    for k in range(tile):
-        rk = row_ids_ref[t0 + k]
-        lim_k = jnp.where(
-            rk == row,
-            jnp.minimum(kv_lens_ref[rk], q_pos_ref[t0 + k] + 1), 0)
-        limits = jnp.where(j >= k * group, lim_k, limits)
+    # In ``lax`` primitives, as ``page_walk.find_item`` is and for its
+    # reason: every packed program an engine warms traces these selects
+    # anew, and a ``jnp.where`` is a nested ``jit`` each time.
+    shape, lax, i32 = (tile * group, 1), jax.lax, np.int32
+    j = lax.broadcasted_iota(jnp.int32, shape, 0)
+
+    def column(values):
+        """``values[k]`` (scalars) in the query rows of tile token ``k``."""
+        col = lax.full(shape, 0, jnp.int32)
+        for k, value in enumerate(values):
+            col = lax.select(lax.ge(j, i32(k * group)),
+                             lax.broadcast_in_dim(value, shape, ()), col)
+        return col
+
+    positions = [q_pos_ref[lax.add(t0, i32(k))] for k in range(tile)]
+    limits = []
+    for k, pos in enumerate(positions):
+        rk = row_ids_ref[lax.add(t0, i32(k))]
+        limits.append(lax.select(
+            lax.eq(rk, row), lax.min(kv_lens_ref[rk], lax.add(pos, i32(1))),
+            i32(0)))
     if window is None:
-        return limits, None
-    lowers = jnp.zeros((tile * group, 1), jnp.int32)
-    for k in range(tile):
-        lowers = jnp.where(j >= k * group, q_pos_ref[t0 + k] + 1 - window,
-                           lowers)
-    return limits, lowers
+        return column(limits), None
+    return column(limits), column(
+        [lax.add(pos, i32(1 - window)) for pos in positions])
 
 
 def _block_ragged_kernel(

@@ -31,11 +31,21 @@ Two implementations behind one signature, mirroring ``paged_attention``:
   batch, then gathers the packed tokens back. Cost is therefore ONE
   row-padded dense dispatch — identical KV-gather traffic to the split
   prefill path — never a per-token KV view.
-* ``ragged_paged_attention_pallas`` — streams pages HBM→VMEM per token
-  (ops/pallas/ragged_attention_kernel.py), no padding, no gathered view.
+* ``ragged_paged_attention_pallas`` — streams a row's live pages HBM→VMEM
+  once a query tile (ops/pallas/ragged_attention_kernel.py), no padding,
+  no gathered view.
 
 Quantized (int8 + scales) pools route to the ``_q`` variants, same as the
 decode kernel.
+
+Who calls this with what: the contract above is any pack, and the tests
+hold both forms to it. A unified step program (``models/llama.py::
+_pool_attention``) hands the attend its rows of two tokens or more alone:
+the tokens of its one-token rows go in with ``q_position == -1``, padding
+for this attend only (the write saw their true positions), and are
+attended by ``paged_attention`` over ``[R, 1]`` queries with every other
+row's ``kv_lens`` at 0. The XLA forms here share ``paged_attention_xla``
+either way, so off the chip the split changes no number.
 """
 
 from __future__ import annotations

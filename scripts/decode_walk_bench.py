@@ -17,12 +17,9 @@ block. It fails without a TPU: nothing here is a CPU timing.
 """
 
 import argparse
-import glob
 import json
 import os
-import shutil
 import sys
-import tempfile
 
 sys.path[:0] = [os.getcwd(), os.path.join(os.getcwd(), "benchmark")]
 
@@ -30,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import trace_reduce
+import device_profile
 from rbg_tpu.ops.pallas import page_walk as W
 from rbg_tpu.ops.pallas import paged_attention_kernel as K
 
@@ -77,24 +74,9 @@ def _rows(name, rng):
 
 def _device_us_a_call(call, args):
     """{operation: device us a call} from a profile of TRACED_CALLS."""
-    out = jax.block_until_ready(call(*args))
-    trace_dir = tempfile.mkdtemp(prefix="decode_walk_")
-    try:
-        jax.profiler.start_trace(trace_dir)
-        for _ in range(TRACED_CALLS):
-            out = call(*args)
-        jax.block_until_ready(out)
-        jax.profiler.stop_trace()
-        path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                                recursive=True))[-1]
-        devices, _, _ = trace_reduce.read_xplane(path)
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-    per = {}
-    for events in devices.values():
-        for start, end, op, _ in events:
-            per[op] = per.get(op, 0.0) + (end - start) / TRACED_CALLS / 1e3
-    return per
+    jax.block_until_ready(call(*args))
+    return device_profile.us_a_call(device_profile.device_events(
+        lambda: [call(*args) for _ in range(TRACED_CALLS)]), TRACED_CALLS)
 
 
 def main():

@@ -21,12 +21,9 @@ TPU: nothing here is a CPU timing.
 """
 
 import argparse
-import glob
 import json
 import os
-import shutil
 import sys
-import tempfile
 
 sys.path[:0] = [os.getcwd(), os.path.join(os.getcwd(), "benchmark")]
 
@@ -34,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import device_profile
 from harness import serve, trace_reduce
 from rbg_tpu.models import llama
 
@@ -110,18 +108,13 @@ def _device_us_a_layer(fn, args):
     """{"all" | "proj" | "kernel": device us a layer} from a profile."""
     blks, x, state, *addr = args
     _, state = jax.block_until_ready(fn(blks, x, state, *addr))
-    trace_dir = tempfile.mkdtemp(prefix="kda_packed_")
-    try:
-        jax.profiler.start_trace(trace_dir)
+
+    def work(state=state):          # the state rides from call to call
         for _ in range(TRACED_CALLS):
             out, state = fn(blks, x, state, *addr)
-        jax.block_until_ready(out)
-        jax.profiler.stop_trace()
-        path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                                recursive=True))[-1]
-        devices, _, _ = trace_reduce.read_xplane(path)
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        return out
+
+    devices = device_profile.device_events(work)
     per, ops = {"all": 0.0, "proj": 0.0, "kernel": 0.0}, {}
     a_layer = 1e6 / (TRACED_CALLS * LAYERS)           # seconds -> us a layer
     for events in devices.values():

@@ -23,13 +23,10 @@ a visit. It fails without a TPU: nothing here is a CPU timing.
 import argparse
 import dataclasses
 import functools
-import glob
 import inspect
 import json
 import os
-import shutil
 import sys
-import tempfile
 
 sys.path[:0] = [os.getcwd(), os.path.join(os.getcwd(), "benchmark")]
 
@@ -37,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import device_profile
 from harness import serve, trace_reduce
 from rbg_tpu.models import llama
 
@@ -163,18 +161,8 @@ def case(name, form, seed):
 def _device_us_a_layer(fn, args):
     """(device us a layer, [(operation, us a layer)] longest first)."""
     jax.block_until_ready(fn(*args))
-    trace_dir = tempfile.mkdtemp(prefix="moe_visit_")
-    try:
-        jax.profiler.start_trace(trace_dir)
-        for _ in range(TRACED_CALLS):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        jax.profiler.stop_trace()
-        path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                                recursive=True))[-1]
-        devices, _, _ = trace_reduce.read_xplane(path)
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+    devices = device_profile.device_events(
+        lambda: [fn(*args) for _ in range(TRACED_CALLS)])
     ops = {}
     a_layer = 1e6 / (TRACED_CALLS * LAYERS)           # seconds -> us a layer
     for events in devices.values():

@@ -382,6 +382,106 @@ def test_tile_segments_count_live_blocks_only():
         + [1] + [0] * 7)
 
 
+# ---- one-token rows handed over as padding (the packed step's split) ----
+
+# (q_len, kv_len) a row, in pack order
+_SPLIT_PACKS = {
+    "a chunk among one-token rows": [(1, 40), (Q_TILE + 4, 70), (1, 9),
+                                     (1, 130), (3, 3), (1, 64), (1, 1)],
+    "one-token rows alone": [(1, 5), (1, 64), (1, 65), (1, 130)],
+    "chunks alone": [(5, 5), (Q_TILE + 1, 30), (2, 130)],
+}
+
+
+def _as_padding(q_specs, q_pos):
+    """``q_pos`` with -1 at every one-token row's token, and which tokens
+    those are."""
+    lone = np.repeat([n == 1 for n, _ in q_specs], [n for n, _ in q_specs])
+    return jnp.where(jnp.asarray(lone)[None], -1, q_pos), lone
+
+
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "window"])
+@pytest.mark.parametrize("pack", sorted(_SPLIT_PACKS))
+def test_a_one_token_row_given_as_padding_leads_no_item(pack, window):
+    """A unified step hands the ragged kernels its one-token rows' tokens
+    with position -1 (``models/llama.py::_pool_attention``; the decode
+    kernels attend them): ``_tile_segments`` gives such a token no item,
+    and the grid is the chunk rows' live blocks, tile by tile, plus one
+    for every tile whose first token leads nothing."""
+    from rbg_tpu.ops.pallas.page_walk import pages_per_block
+    from rbg_tpu.ops.pallas.ragged_attention_kernel import (_pad_pack,
+                                                            _tile_segments)
+    page = 8
+    block = pages_per_block(page) * page
+    q_specs = _SPLIT_PACKS[pack]
+    rng = np.random.RandomState(50)
+    q, _, q_pos, lens, rows = _pack(rng, q_specs, P=20, NP=161)
+    given, lone = _as_padding(q_specs, q_pos)
+    _, rows_p, pos_p = _pad_pack(q[0], rows, given[0])
+    lead, starts, *_ = _tile_segments(rows_p, pos_p, lens, page, window)
+    items = np.diff(np.asarray(starts))
+    assert not np.asarray(lead)[:lone.size][lone].any()
+
+    chunks = np.zeros_like(items)       # the chunk rows' live blocks
+    at = 0
+    for n, kv in q_specs:
+        for tile in range(at // Q_TILE, (at + max(n, 1) - 1) // Q_TILE + 1):
+            lo, hi = max(at, tile * Q_TILE), min(at + n, (tile + 1) * Q_TILE)
+            if n > 1:       # its first token there leads up to its last's limit
+                first = 0 if window is None else max(
+                    kv - n + (lo - at) - window + 1, 0) // block
+                chunks[lo] = -(-(kv - n + hi - at) // block) - first
+        at += n
+    heads = np.arange(chunks.size) % Q_TILE == 0
+    idle = heads & (chunks == 0)        # a tile's first token that leads nothing
+    assert items.tolist() == (chunks + idle).tolist()
+    assert int(starts[-1]) == chunks.sum() + idle.sum()
+
+
+@pytest.mark.parametrize("kernel", _RAGGED_KERNELS + ["gqa_window"])
+@pytest.mark.parametrize("pack", sorted(_SPLIT_PACKS))
+def test_ragged_kernels_attend_the_chunk_rows_beside_rows_given_as_padding(
+        kernel, pack):
+    """With the one-token rows' tokens as padding a ragged kernel's output
+    is the XLA reference's at every chunk row's token; theirs are padding's
+    (finite: zeros from an all-pad tile's one item, an even weighing of a
+    neighbour's block where they share its tile), which the step drops."""
+    q_specs = _SPLIT_PACKS[pack]
+    rng = np.random.RandomState(51)
+    page, scales, kw = 8, {}, {}
+    if kernel.startswith("mla"):
+        ql, qp, c, pe, table, q_pos, lens, rows, scale = _mla_pack(
+            rng, q_specs, page=page, NP=161, P=20)
+        if kernel == "mla_q":
+            (c, cs), (pe, pes) = quantize_kv(c), quantize_kv(pe)
+            scales = dict(c_scales=cs, pe_scales=pes)
+        given, lone = _as_padding(q_specs, q_pos)
+        ref = ragged_paged_mla_attention_xla(ql, qp, c, pe, table, q_pos,
+                                             lens, rows, scale, **scales)
+        fn = (ragged_paged_mla_attention_pallas_q if scales
+              else ragged_paged_mla_attention_pallas)
+        got = fn(ql, qp, c, pe, table, given, lens, rows, scale,
+                 *scales.values(), interpret=True)
+    else:
+        k, v = _pool(rng, NP=161, page=page)
+        q, table, q_pos, lens, rows = _pack(rng, q_specs, P=20, NP=161)
+        if kernel == "gqa_q":
+            (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+            scales = dict(k_scales=ks, v_scales=vs)
+        if kernel == "gqa_window":
+            kw = {"window": 24}
+        given, lone = _as_padding(q_specs, q_pos)
+        ref = ragged_paged_attention_xla(q, k, v, table, q_pos, lens, rows,
+                                         **scales, **kw)
+        fn = (ragged_paged_attention_pallas_q if scales
+              else ragged_paged_attention_pallas)
+        got = fn(q, k, v, table, given, lens, rows, *scales.values(),
+                 interpret=True, **kw)
+    got, ref = np.asarray(got)[0], np.asarray(ref)[0]
+    np.testing.assert_allclose(got[~lone], ref[~lone], rtol=1e-4, atol=1e-4)
+    assert np.isfinite(got).all()
+
+
 # ---- the rotary key's pool a whole lane tile wide (PR 33) ----
 
 

@@ -248,8 +248,9 @@ def _state_kw(chip, eng, rows):
     return _on(chip, kw)
 
 
-def _compile_unified(chip, eng):
-    Rb, Tb = eng.cfg.max_batch, eng.cfg.max_batch * eng.cfg.prefill_chunk
+def _compile_unified(chip, eng, rows=None):
+    Rb = rows or eng.cfg.max_batch
+    Tb = Rb * eng.cfg.prefill_chunk
     S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
     return eng._get_ragged_fn(Rb, Tb).lower(
         eng.params, S((1, Tb), I32), S((1, Tb), I32), S((1, Tb), bool),
@@ -274,10 +275,15 @@ def _compile_decode(chip, eng):
         *tail, **_state_kw(chip, eng, B)).compile()
 
 
+@pytest.mark.parametrize("rows", [None, 1])
 def test_unified_step_of_llama3_1b_fits_and_holds_the_kernel(
-        chip, abstract_engine):
-    assert "tpu_custom_call" in _compile_unified(chip,
-                                                 abstract_engine).as_text()
+        chip, abstract_engine, rows):
+    """At the widest row bucket and at the narrowest: a packed program of
+    ONE row holds both walks like any other."""
+    text = _compile_unified(chip, abstract_engine, rows).as_text()
+    assert "tpu_custom_call" in text
+    for walk in ("_decode_call", "_block_ragged_call"):
+        assert walk in text, walk
 
 
 # Half a minute of compile, so outside tier-1; chip_smoke.py's reference
@@ -309,8 +315,11 @@ def _cell_engine(chip, monkeypatch, file):
 
 # cell -> what its two step programs are held to. ``shapes``: parameters
 # (``group/leaf``), pools (``k_pages`` / ``v_pages``) and state arrays
-# (``state/<name>``) as served; ``walk``: the page walk's kernel in (decode,
-# unified); ``temps``: the ceiling on temporaries of (decode, unified); every
+# (``state/<name>``) as served; ``walk``: the page walks' kernels, (the decode
+# step's, the ragged one): a decode step holds the first, a unified step BOTH
+# (its rows of one token take the decode step's walk, the rows that hold a
+# chunk the ragged one); ``temps``: the ceiling on temporaries of
+# (decode, unified); every
 # program holds ``tpu_custom_call``, a decode step alone the experts' walk
 # ``_moe_visit_call`` (PR 43; a unified step dispatches densely), and no
 # program a ``copy`` of the size of a page pool or of a state array. The
@@ -487,7 +496,8 @@ def test_step_programs_of_a_cell_fit_and_copy_no_pool(chip, monkeypatch, cell,
     compiled = (_compile_decode if decode else _compile_unified)(chip, eng)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert want["walk"][0 if decode else 1] in text
+    for walk in want["walk"][:1 if decode else 2]:
+        assert walk in text, walk
     assert ("_moe_visit_call" in text) == decode
     assert compiled.memory_analysis().temp_size_in_bytes < want["temps"][
         0 if decode else 1]

@@ -214,6 +214,8 @@ class Engine:
 
         if self.mcfg.unbuilt_for:
             self._refuse_unbuilt()
+        if self.mcfg.looped_for:
+            self._refuse_looped()
         # A model with window layers keeps a second class of page for
         # them, sized by the rows' windows, with an allocator of its own.
         self.window_allocator: Optional[PageAllocator] = None
@@ -428,6 +430,31 @@ class Engine:
                 f"model {self.mcfg.name!r} {self.mcfg.unbuilt_for}, which "
                 f"do not support {why}")
 
+    def _refuse_looped(self) -> None:
+        """What is not built for a model that runs its layers several
+        times a token, refused by the mechanism that is missing. Such a
+        model is served like any model of one kind of layer (the one walk,
+        one class of page whose leading axis is a pass a layer, prefix
+        reuse, preemption, the host tier, an int8 cache): only what walks
+        layers or lays parameters out beside ``llama.paged_layers`` is
+        refused (LoRA by ``load_lora``)."""
+        cfg, why = self.cfg, None
+        if cfg.speculative != "off":
+            why = ("speculative decoding: no test holds a verify step's "
+                   "accepted and rejected drafts over a cache of several "
+                   "passes to the reference")
+        elif cfg.mode != "unified":
+            why = (f"mode {cfg.mode!r}: a PD bundle is sent and taken in "
+                   f"windows of layers that the stack is walked through "
+                   f"once (engine/pd.py)")
+        elif self.mesh is not None:
+            why = ("a device mesh: the parameters' sharding specs know "
+                   "neither the norms after a sub-layer nor the exit gate")
+        if why:
+            raise ValueError(
+                f"model {self.mcfg.name!r} {self.mcfg.looped_for}, which "
+                f"does not support {why}")
+
     def _slot_rows(self, reqs, B: int):
         """``[B]`` state slots of ``reqs`` in row order, on the device; a
         row of padding names a slot out of range, so its write is
@@ -624,6 +651,11 @@ class Engine:
             raise ValueError("empty adapter")
         if name in self._lora_slots:
             raise ValueError(f"adapter {name!r} already loaded")
+        if self.mcfg.looped_for:
+            raise ValueError(
+                f"model {self.mcfg.name!r} {self.mcfg.looped_for}: LoRA "
+                f"adapters are not supported on it (no test holds an "
+                f"adapter that every pass applies to the reference)")
         if len(self.mcfg.layer_groups) > 1:
             # The [L, n, ...] adapter stack rides one scan over one kind
             # of layer; a dense prefix before expert layers is two.

@@ -206,9 +206,11 @@ class PagedKVCache:
 
 
 def paged_layer_count(cfg: ModelConfig, kind: str = "full") -> int:
-    """Layers that keep pages of the class ``kind``: ``full`` (this
-    config's attention) or ``window``; the recurrent ones keep none."""
-    return cfg.mixer_count(kind)
+    """Entries on the leading axis of the pools of the class ``kind``:
+    ``full`` (this config's attention; a looped model keeps one a pass a
+    layer, ``cfg.cache_layers``) or ``window``; the recurrent layers keep
+    none."""
+    return cfg.cache_layers if kind == "full" else cfg.mixer_count(kind)
 
 
 def window_pages_per_row(cfg: ModelConfig, page_size: int,

@@ -100,6 +100,12 @@ def load_hf_llama(path: str, cfg: ModelConfig) -> dict:
             "(sliding_window: per-kind head counts, wq / wo stacked by "
             "kind) or with scaled or partial rotary settings: no dense "
             "llama-family checkpoint has them — load via orbax instead.")
+    if cfg.loop_steps > 1 or cfg.post_norms or cfg.exit_gate:
+        raise NotImplementedError(
+            "HF import does not map a looped model (model_type ouro: "
+            "total_ut_steps, the two norms after a sub-layer, "
+            "model.early_exit_gate) until a checkpoint's index is in the "
+            "repository to map the names from — load via orbax instead.")
     sd = _hf_state_dict(path)
     dt = cfg.jax_dtype
     L = cfg.num_layers

@@ -184,15 +184,38 @@ class ModelConfig:
     # RMSNorm over each query and key head, with a learned weight, before
     # RoPE (GQA attention only).
     qk_norm: bool = False
+    # A looped stack (Ouro ``total_ut_steps``; arXiv:2510.25741): the layers
+    # run ``loop_steps`` times a token over the SAME weights, the model's
+    # final norm closing every pass (its output enters the next), and a
+    # token's keys and values differ by pass, so pass ``t`` of layer ``l``
+    # keeps a cache entry of its own, ``t * num_layers + l``
+    # (``cache_layers``; ``llama.paged_layers``). ``post_norms``: a second
+    # norm a sub-layer, on the mixer's and on the MLP's OUTPUT before the
+    # residual add (sandwich norms). ``exit_gate``: the parameters hold the
+    # loop's exit gate (``exit_gate: {w [d], b [1]}``), one scalar a token
+    # a pass; at ``early_exit_threshold`` 1, the only value served, no
+    # token leaves before the last pass and the gate is held, not computed:
+    # the two leaves are there for ``num_params`` (2049 of the published
+    # count) and for the checkpoint loader that is to fill them.
+    # ``early_exit_threshold`` is a field only to be refused by name: a
+    # configuration file's ``preset`` reaches the program as
+    # ``ModelConfig(**preset)`` and nowhere else.
+    loop_steps: int = 1
+    post_norms: bool = False
+    exit_gate: bool = False
+    early_exit_threshold: float = 1.0
     # Grouped-query attention's input projections held ``[L, out, in]``
     # (``wq [L, h hd, d]``, ``wk``, ``wv`` likewise), the layout their dots
     # take on the chip: held ``[L, in, out]`` the compiler transposes a
     # layer's slice in every trip, or the whole stack in every step where
     # a kind's loop has several trips (ROADMAP S23 + S14). No option: a
     # model with window layers holds them so, in both its kinds (it takes
-    # no adapters, ``unbuilt_for``), and no constructor takes the field:
-    # ``__post_init__`` reads it off ``sliding_window`` and ``_kind`` hands
-    # it to the full kind's group config.
+    # no adapters, ``unbuilt_for``), and so does a looped model, whose
+    # layer scan stands inside the passes' (held ``[L, in, out]`` its three
+    # stacks were transposed whole in every step, 1.2 GB of temporaries at
+    # 48 layers of 2048; PERF.md, PR 50); no constructor takes the field:
+    # ``__post_init__`` reads it off ``sliding_window`` and ``loop_steps``
+    # and ``_kind`` hands it to a group config.
     proj_out_in: bool = dataclasses.field(default=False, init=False)
     # What a GROUP config's layers mix tokens by: ``full`` (this config's
     # attention), ``window`` (the same under ``window_layer``'s fields,
@@ -254,11 +277,27 @@ class ModelConfig:
                 f"{window_layer['sliding_window']} is not sliding_window "
                 f"{self.sliding_window}: the model's mask and the engine's "
                 f"pages read one window")
-        object.__setattr__(self, "proj_out_in", bool(self.sliding_window))
+        object.__setattr__(self, "proj_out_in", bool(self.sliding_window)
+                           or self.loop_steps > 1)
         if self.attn_gate and self.mla:
             raise ValueError(
                 "attn_gate gates grouped-query attention's output; the "
                 "latent attention (mla) has no gate")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps}: the layers run "
+                             f"at least once")
+        if self.early_exit_threshold != 1.0:
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold}: only 1 "
+                f"is served (every token runs every pass); a depth that "
+                f"differs by row is a scheduler the engine lacks")
+        if self.loop_steps > 1 and (self.by_kind or self.mla
+                                    or self.num_experts):
+            raise ValueError(
+                f"loop_steps {self.loop_steps}: a looped stack is built for "
+                f"layers of one kind on grouped-query attention with a dense "
+                f"MLP (the walk by kind, the latent attention and the "
+                f"experts' counters run a layer once)")
         if self.experts_held is not None:
             lo, hi = self.experts_held
             if not 0 <= lo < hi <= self.num_experts:
@@ -303,6 +342,25 @@ class ModelConfig:
     def mixer_count(self, kind: str) -> int:
         """Layers whose mixer is ``kind``."""
         return self.mixer_kinds.count(kind)
+
+    @property
+    def cache_layers(self) -> int:
+        """Entries on the leading axis of the full class's page pool (and
+        of the contiguous ``KVCache``): one a (pass, attention layer), pass
+        ``t`` of the ``l``-th attention layer at ``t * mixer_count("full")
+        + l``. A model that runs its layers once: its attention layers."""
+        return self.loop_steps * self.mixer_count("full")
+
+    @property
+    def looped_for(self) -> str:
+        """What a looped model has, as the start of a refusal's message;
+        empty where the layers run once. NOT ``unbuilt_for``: such a model
+        is served like any one-kind model, prefix reuse included, and only
+        what walks layers outside ``llama.paged_layers`` refuses it."""
+        if self.loop_steps == 1:
+            return ""
+        return (f"runs its layers {self.loop_steps} times a token (a cache "
+                f"entry a pass a layer, {self.cache_layers})")
 
     @property
     def recurrent_kinds(self) -> Tuple[str, ...]:
@@ -489,7 +547,10 @@ class ModelConfig:
             dict(self.window_layer).get("num_heads", self.num_heads))
         attn_all = sum({"full": attn, "kda": kda, "conv": conv,
                         "window": window}[kind] for kind in self.mixer_kinds)
-        return (v * d + attn_all + self.num_layers * 2 * d + mlp + d + head)
+        norms = 4 if self.post_norms else 2      # a layer's norm weights
+        gate = d + 1 if self.exit_gate else 0
+        return (v * d + attn_all + self.num_layers * norms * d + mlp + d
+                + head + gate)
 
 
 _PRESETS = {
@@ -666,6 +727,17 @@ _PRESETS = {
                       "partial_rotary_factor": 1.0, "rope_scaling": ""},
         layer_types=("full_attention",) + ("sliding_attention",) * 3
         + ("full_attention",) + ("sliding_attention",) * 3,
+    ),
+    # Tiny Ouro-shaped model for tests (the layers of the benchmark's
+    # ouro-2.6b): 2 layers run 3 times a token, a cache entry a pass a
+    # layer (6), plain multi-head attention (4 x 4: a group of ONE query
+    # head), sandwich norms, the exit gate held.
+    "tiny-ouro": ModelConfig(
+        name="tiny-ouro", vocab_size=256, hidden_size=128,
+        intermediate_size=320, num_layers=2, num_heads=4, num_kv_heads=4,
+        head_dim=32, max_seq_len=256, rope_theta=1000000.0,
+        rms_norm_eps=1e-6, dtype="float32",
+        loop_steps=3, post_norms=True, exit_gate=True,
     ),
 }
 

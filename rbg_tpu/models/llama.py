@@ -37,7 +37,8 @@ from rbg_tpu.ops.rope import apply_rope, rotary_tables
 class KVCache:
     """Contiguous KV cache: slot index == absolute position.
 
-    k, v: [num_layers, B, S, KV, head_dim]; length: [B] int32 filled length.
+    k, v: [num_layers, B, S, KV, head_dim] (a looped model: an entry a pass
+    a layer, ``cfg.cache_layers``); length: [B] int32 filled length.
     """
 
     k: jnp.ndarray
@@ -57,7 +58,8 @@ class KVCache:
                              cfg.qk_rope_head_dim), dtype),
                 length=jnp.zeros((batch,), jnp.int32),
             )
-        shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim_)
+        shape = (cfg.cache_layers, batch, max_len, cfg.num_kv_heads,
+                 cfg.head_dim_)
         return KVCache(
             k=jnp.zeros(shape, dtype),
             v=jnp.zeros(shape, dtype),
@@ -87,6 +89,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         params[name] = _init_blocks(g, gkey, n, nrm, s_in, s_out)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = nrm(jax.random.fold_in(key, 99), (d, v), s_in)
+    if cfg.exit_gate:   # held, not computed (``ModelConfig.exit_gate``)
+        params["exit_gate"] = {"w": nrm(jax.random.fold_in(key, 98), (d,),
+                                        s_in),
+                               "b": jnp.zeros((1,), dt)}
     return params
 
 
@@ -101,6 +107,9 @@ def _init_blocks(cfg: ModelConfig, key, L: int, nrm, s_in, s_out) -> dict:
         "attn_norm": jnp.ones((L, d), dt),
         "mlp_norm": jnp.ones((L, d), dt),
     }
+    if cfg.post_norms:      # the norms AFTER the mixer and the MLP
+        blocks.update(attn_post_norm=jnp.ones((L, d), dt),
+                      mlp_post_norm=jnp.ones((L, d), dt))
     if cfg.half == "mlp":
         del blocks["attn_norm"]
     elif cfg.attention == "kda":
@@ -344,22 +353,42 @@ _WO_SCOPES = {"full": "attention", "window": "attention/window",
               "conv": "attention/conv", "kda": "attention/kda/proj"}
 
 
+def _post_norm(cfg: ModelConfig, blk, x, out, norm: str):
+    """A sandwich norm's residual add (``cfg.post_norms``): ``x +
+    RMSNorm(out; blk[norm])``, ``out`` a mixer's or an MLP's output. Under
+    a scope of its own, beside ``attention`` and ``mlp`` and inside
+    neither."""
+    with jax.named_scope("post_norm"):
+        return x + rms_norm(out, blk[norm], cfg.rms_norm_eps)
+
+
 def _post_attention(cfg: ModelConfig, blk, x, attn, lora=None,
                     lora_ids=None, hit_experts=None):
-    """Shared post-attention math: residual → norm → MLP/MoE → residual.
-    With ``hit_experts`` (``_moe_mlp_hit``'s stacks, layer, live rows and
-    kernel policy) the experts are the hit ones only, and their count is
-    returned too."""
+    """Shared post-attention math: residual → norm → MLP/MoE → residual;
+    with ``cfg.post_norms`` each residual adds the sub-layer's output
+    normed once more (``_post_norm``). With ``hit_experts``
+    (``_moe_mlp_hit``'s stacks, layer, live rows and kernel policy) the
+    experts are the hit ones only, and their count is returned too."""
     B, T, _ = x.shape
     with jax.named_scope(_WO_SCOPES[cfg.attention]):
-        x = x + _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
-                           lora_ids)
+        out = _lora_proj(attn.reshape(B, T, -1), blk["wo"], "wo", lora,
+                         lora_ids)
+        if not cfg.post_norms:
+            x = x + out
+    if cfg.post_norms:
+        x = _post_norm(cfg, blk, x, out, "attn_post_norm")
+    visited = None
     with jax.named_scope("moe" if cfg.num_experts else "mlp"):
         xm = rms_norm(x, blk["mlp_norm"], cfg.rms_norm_eps)
         if hit_experts is not None:
             out, visited = _moe_mlp_hit(cfg, blk, xm, *hit_experts)
-            return x + out, visited
-        return x + _mlp(cfg, blk, xm, lora, lora_ids)
+        else:
+            out = _mlp(cfg, blk, xm, lora, lora_ids)
+        if not cfg.post_norms:
+            x = x + out
+    if cfg.post_norms:
+        x = _post_norm(cfg, blk, x, out, "mlp_post_norm")
+    return x if hit_experts is None else (x, visited)
 
 
 def _mlp(cfg: ModelConfig, blk, xm, lora=None, lora_ids=None):
@@ -513,9 +542,12 @@ def _moe_mlp_hit(cfg: ModelConfig, blk, xm, stacks, layer, live,
 
 
 def _head(params, cfg: ModelConfig, x) -> jnp.ndarray:
-    """Shared epilogue: final norm + (tied) LM head, f32 logits."""
+    """Shared epilogue: final norm + (tied) LM head, f32 logits. A looped
+    model's hidden states come normed: its final norm closed the last pass
+    as it closes every pass (``_pass_norm``)."""
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        if cfg.loop_steps == 1:
+            x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         head = params.get("lm_head")
         if head is None:
             head = params["embed"].T
@@ -607,21 +639,46 @@ def forward(
 
     x = params["embed"].astype(cfg.jax_dtype)[tokens]  # [B, T, D]
 
+    # A looped model's passes are unrolled here (this path serves tests and
+    # the tiny loops, not the engine): pass ``t`` over the cache's entries
+    # ``[t L, (t + 1) L)``. One pass: the loop as it always was.
     k_new, v_new = [], []
-    for name, g, lo, hi in cfg.layer_groups:
-        def step(carry, xs, g=g):
-            blk, kc, vc = xs
-            h, kc, vc = _block(g, carry, blk, kc, vc, write_positions,
-                               kv_valid)
-            return h, (kc, vc)
+    for t in range(cfg.loop_steps):
+        base = t * cfg.num_layers
+        for name, g, lo, hi in cfg.layer_groups:
+            def step(carry, xs, g=g):
+                blk, kc, vc = xs
+                h, kc, vc = _block(g, carry, blk, kc, vc, write_positions,
+                                   kv_valid)
+                return h, (kc, vc)
 
-        x, (k, v) = jax.lax.scan(
-            step, x, (params[name], cache.k[lo:hi], cache.v[lo:hi]))
-        k_new.append(k)
-        v_new.append(v)
+            x, (k, v) = jax.lax.scan(
+                step, x, (params[name], cache.k[base + lo:base + hi],
+                          cache.v[base + lo:base + hi]))
+            k_new.append(k)
+            v_new.append(v)
+        if cfg.loop_steps > 1:
+            x = _pass_norm(params, cfg, x)
     logits = _head(params, cfg, x)
     return logits, KVCache(k=jnp.concatenate(k_new), v=jnp.concatenate(v_new),
                            length=new_length)
+
+
+def _pass_norm(params, cfg: ModelConfig, x):
+    """What closes every pass of a looped model: the model's one final
+    norm, whose output enters the next pass (or the head, which then does
+    not norm again)."""
+    with jax.named_scope("pass_norm"):
+        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+
+
+def _pass_addr(addr: "PoolAddr", t, entry_pages: int) -> "PoolAddr":
+    """``addr`` for pass ``t`` of a looped model: the rows' one page table
+    moved to that pass's cache entries, ``entry_pages`` (layers x pages a
+    layer) further on in the flat pool a pass. A page id is a page of every
+    (pass, layer) entry, so the allocator, the table and the prefix cache
+    know nothing of passes."""
+    return addr._replace(page_table=addr.page_table + t * entry_pages)
 
 
 def _no_recurrent(cfg: ModelConfig, what: str) -> None:
@@ -1219,7 +1276,9 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
                  layers: Tuple[int, int], use_pallas: str = "auto", lora=None,
                  lora_ids=None, experts_whole: bool = False,
                  sharded: bool = False):
-    """The one walk over cache-bearing layers: layers ``[lo, hi)`` (static)
+    """The one walk over cache-bearing layers (every pass of them, where
+    ``cfg.loop_steps`` says the stack runs more than once): layers ``[lo,
+    hi)`` (static)
     over the hidden states ``x [B, T, D]`` entering layer ``lo``, writing and
     attending those layers' pages of the FULL pool, the tuple ``(k_pages,
     v_pages, k_scales, v_scales)``, ``[L, NP, page, KV, hd]`` each (scales
@@ -1243,6 +1302,32 @@ def paged_layers(params: dict, cfg: ModelConfig, x, pool, addr: PoolAddr, *,
                 f"was asked for)")
         return _hybrid_layers(params, cfg, x, pool, addr, use_pallas,
                               experts_whole)
+    if cfg.loop_steps > 1:
+        # A looped model: the passes are one scan whose body is THIS walk
+        # over the same stacked weights (the scan's invariants) as a model
+        # that runs its layers once, the pool ``[T x L, NP, ...]`` its
+        # carry beside ``x``, pass ``t`` of layer ``l`` on entry ``t L + l``
+        # (``_pass_addr``), the final norm at the end of the body. A model
+        # of one pass never comes here: its program is what it was.
+        if (lo, hi) != (0, cfg.num_layers):
+            raise NotImplementedError(
+                f"{cfg.name} {cfg.looped_for}: its layers are walked whole, "
+                f"every pass (layers {layers} was asked for)")
+        once = cfg._kind(loop_steps=1)  # (its projections held as cfg's)
+        entry_pages = cfg.num_layers * pool[0].shape[1]
+
+        def one_pass(carry, t):
+            h, pool = carry
+            h, pool, _ = paged_layers(
+                params, once, h, pool, _pass_addr(addr, t, entry_pages),
+                layers=layers, use_pallas=use_pallas, lora=lora,
+                lora_ids=lora_ids, experts_whole=experts_whole,
+                sharded=sharded)
+            return (_pass_norm(params, cfg, h), pool), None
+
+        (x, pool), _ = jax.lax.scan(
+            one_pass, (x, pool), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
+        return x, pool, None
     # The pool rides the layer scan as CARRY over a [L·NP, …] flat view,
     # with each layer addressing its pages as ``layer·NP + page_table``. As
     # a per-layer scan INPUT/OUTPUT (stacked ys) the entire pool would be
@@ -1464,24 +1549,28 @@ def _encode_core(params, cfg, tokens, token_mask, mesh=None, remat=False,
 
     x = params["embed"].astype(cfg.jax_dtype)[tokens]
 
-    for name, g, _, _ in cfg.layer_groups:
-        def body(h, blk, g=g):
-            if use_ring:
-                q, k, vv = _qkv(g, blk, h, positions)
-                attn = ring_attention(q, k, vv, positions, kv_positions, mesh)
-                return _post_attention(g, blk, h,
-                                       _attn_gate(g, blk, h, attn))
-            h, _, _ = _block(g, h, blk, None, None, positions, token_mask)
-            return h
+    for t in range(cfg.loop_steps):     # a looped model's passes, unrolled
+        for name, g, _, _ in cfg.layer_groups:
+            def body(h, blk, g=g):
+                if use_ring:
+                    q, k, vv = _qkv(g, blk, h, positions)
+                    attn = ring_attention(q, k, vv, positions, kv_positions,
+                                          mesh)
+                    return _post_attention(g, blk, h,
+                                           _attn_gate(g, blk, h, attn))
+                h, _, _ = _block(g, h, blk, None, None, positions, token_mask)
+                return h
 
-        if remat:
-            body = jax.checkpoint(body)
+            if remat:
+                body = jax.checkpoint(body)
 
-        def step(h, blk, body=body):
-            return body(h, blk), None
+            def step(h, blk, body=body):
+                return body(h, blk), None
 
-        x, _ = jax.lax.scan(step, x, params[name])
-    if final_norm:
+            x, _ = jax.lax.scan(step, x, params[name])
+        if cfg.loop_steps > 1:
+            x = _pass_norm(params, cfg, x)
+    if final_norm and cfg.loop_steps == 1:
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return x
 

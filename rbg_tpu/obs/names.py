@@ -624,6 +624,12 @@ ATTENTION_INNER_SCOPES = ("kda", "conv", "window")
 # (``models/llama.py::_attn_gate``; ``device.attn_gate_share``), and a KDA
 # layer's projections with its ``wo`` (``device.kda_proj_share``).
 ATTENTION_PART_SCOPES = ("gate", "kda/proj")
+# BESIDE the five above and inside none (they hold no dot): the two norms
+# a model with sandwich norms puts on a sub-layer's output before its
+# residual add (``models/llama.py::_post_norm``), and the final norm that
+# closes every pass of a looped model (``_pass_norm``; a model of one pass
+# norms inside ``lm_head``). ``device.post_norm_share`` reads both.
+NORM_SCOPES = ("post_norm", "pass_norm")
 
 # ---- kernel calls (device time by kernel) ----
 #

@@ -117,6 +117,10 @@ def pipeline_forward_train(params, cfg, tokens, token_mask=None, *, mesh: Mesh,
         raise NotImplementedError(
             f"{cfg.name}: the pipeline stages one stack of one kind of "
             f"layer; this model has {len(cfg.layer_groups)} groups")
+    if cfg.loop_steps > 1:
+        raise NotImplementedError(
+            f"{cfg.name} {cfg.looped_for}: the pipeline stages one stack "
+            f"that a microbatch passes once")
     B, T = tokens.shape
     if token_mask is None:
         token_mask = jnp.ones((B, T), bool)

@@ -3,7 +3,7 @@
 Run on the chip, from the root of a checkout, in one process:
 
     python scripts/correct_readings.py --config benchmark/configs/<c>.json \
-        --weights 1 2 --prompts 11 12 13 [--faults]
+        --weights 1 2 --prompts 11 12 13 [--controls int8 fp8] [--faults]
 
 For each weight seed it builds the program's engine on the benchmark's
 weights (``benchmark/harness/serve.py``'s own preset mapping and reference
@@ -14,8 +14,10 @@ the 75th percentile of |served - reference| log-probability over all
 positions, the worst request's own median):
 
 * ``sound``: the program against the reference;
-* ``int8``: the reference computed in int8 against the reference, on the
-  same served tokens (the precision one step below bfloat16);
+* ``int8`` (and whatever else ``--controls`` names of the reference's
+  ``CONTROLS``): the reference computed in that precision against the
+  reference, on the same served tokens (int8 is the precision one step
+  below bfloat16);
 * with ``--faults``, for a model with recurrent layers, the program with
   its recurrent mixer patched: ``not carried`` (every chunk of a prompt
   starts from a zero state) and ``not zeroed`` (no row starts from zeros,
@@ -25,7 +27,11 @@ positions, the worst request's own median):
   layers' output gate dropped, the delta rule's write strength not scaled,
   attention without positions rotated; YaRN dropped for plain RoPE, the
   whole head rotated, the window not kept, a window's page given back one
-  page early, the window layers served with the full layers' head count.
+  page early, the window layers served with the full layers' head count;
+  for a looped model the last pass dropped, the final norm not applied
+  between passes, every pass reading and writing pass 0's pages, and the
+  norm after the mixer or after the MLP dropped (the last four are no
+  field of a preset: ``model_contract.patched`` patches the model's code).
 
 The serving loop, the faults and ``left_out`` are ``tests/model_contract.py``'s:
 the controls tier-1 holds every tiny configuration to are the ones run here
@@ -73,13 +79,16 @@ def main(argv=None) -> int:
     ap.add_argument("--config", required=True)
     ap.add_argument("--weights", type=int, nargs="+", default=[3000000019])
     ap.add_argument("--prompts", type=int, nargs="+", default=[1, 2, 3])
+    ap.add_argument("--controls", nargs="+", default=["int8"],
+                    help="the reference's lower precisions to read beside "
+                         "the sound program (its CONTROLS)")
     ap.add_argument("--faults", action="store_true")
     args = ap.parse_args(argv)
 
     import jax
     from harness import serve as harness
     from model_contract import (FAULTS, broken_preset, faulty, fitted,
-                                left_out, serve)
+                                left_out, patched, serve)
     from rbg_tpu.engine import Engine, EngineConfig
     from rbg_tpu.models import config as presets
     from rbg_tpu.utils.chipenv import configure_compile_cache
@@ -107,11 +116,14 @@ def main(argv=None) -> int:
             diffs.append(np.abs(np.asarray(got) - ref).tolist())
         return diffs
 
-    def say(kind, w, p, diffs):
+    def say(kind, w, p, diffs, served=None):
         med, p75, worst = numbers(diffs)
-        print(json.dumps({"kind": kind, "weights": w, "prompts": p,
-                          "median": round(med, 5), "p75": round(p75, 5),
-                          "request": round(worst, 5)}), flush=True)
+        line = {"kind": kind, "weights": w, "prompts": p,
+                "median": round(med, 5), "p75": round(p75, 5),
+                "request": round(worst, 5)}
+        if served is not None:      # how independent the positions are
+            line["distinct"] = min(len(set(toks)) for toks, _ in served)
+        print(json.dumps(line), flush=True)
 
     for w in args.weights:
         params = reference.make_params(cfg, w)
@@ -120,9 +132,10 @@ def main(argv=None) -> int:
             alone, rest = sample(cfg, p)
             served = serve(eng, alone, new) + serve(eng, rest, new)
             say("sound", w, p, against_reference(params, alone + rest,
-                                                 served))
-            say("int8", w, p, against_reference(params, alone + rest, served,
-                                                "int8"))
+                                                 served), served)
+            for quant in args.controls:
+                say(quant, w, p, against_reference(params, alone + rest,
+                                                   served, quant))
         del eng
         if not args.faults:
             continue
@@ -130,9 +143,10 @@ def main(argv=None) -> int:
                                       cfg["server"]["page_size"]).items():
             broken = presets._PRESETS[name + "-fault"] = broken_preset(
                 presets._PRESETS[name], name + "-fault", fields)
-            eng = engine(fitted(broken, params), name + "-fault")
-            alone, rest = sample(cfg, args.prompts[0])
-            served = serve(eng, alone, new) + serve(eng, rest, new)
+            with patched(fault):    # a rule that no field of the preset is
+                eng = engine(fitted(broken, params), name + "-fault")
+                alone, rest = sample(cfg, args.prompts[0])
+                served = serve(eng, alone, new) + serve(eng, rest, new)
             say(fault, w, args.prompts[0],
                 against_reference(params, alone + rest, served))
             del eng

@@ -15,6 +15,7 @@ import argparse
 import base64
 import dataclasses
 import functools
+import json
 import os
 import re
 import sys
@@ -80,7 +81,8 @@ def programs(eng, S, T, lora=False, window=None):
             eng.params, S((B, Tq), I32), S((B, Tq), I32), S((B, Tq), bool),
             vec, S((B, P), I32), pool.k_pages, pool.v_pages, *scales, **kw)
     Kq = 5
-    if not eng.mcfg.unbuilt_for:
+    # (nor has a looped model: its engine refuses speculative decoding)
+    if not eng.mcfg.unbuilt_for and not eng.mcfg.looped_for:
         out["rbg_spec_verify"] = eng._get_spec_fn(B, False, la=lora).lower(
             eng.params, S((B, Kq), I32), S((B, Kq), I32), S((B, Kq), bool),
             vec, S((B, P), I32), pool.k_pages, pool.v_pages, *scales, *tail,
@@ -136,7 +138,8 @@ def tiny_cases():
                                ("tiny-kimi-linear", "tiny-kimi-linear", {}),
                                ("tiny-lfm2", "tiny-lfm2", {}),
                                ("tiny-solar-open2", "tiny-solar-open2", {}),
-                               ("tiny-laguna", "tiny-laguna", {})):
+                               ("tiny-laguna", "tiny-laguna", {}),
+                               ("tiny-ouro", "tiny-ouro", {})):
         if model not in presets._PRESETS:   # an older checkout
             continue
         cfg = EngineConfig(model=model, use_pallas="never",
@@ -146,17 +149,20 @@ def tiny_cases():
         if lora:
             _lora_stack(eng)
         window = None
-        # the decode role refuses an int8 pool, a recurrent model and one
-        # with window layers
-        if not extra and not lora and not eng.mcfg.unbuilt_for:
+        # the decode role refuses an int8 pool, a recurrent model, one
+        # with window layers and a looped one
+        if not extra and not lora and not eng.mcfg.unbuilt_for \
+                and not eng.mcfg.looped_for:
             window = DecodeWorker(cfg, params=eng.params)
         yield case, programs(eng, S, 2 * cfg.prefill_chunk, lora, window)
 
 
-def cell_case():
-    """The judged cell's programs, lowered for one chip of a described v5e
-    with the Pallas kernels in (as ``tests/test_chip_compile.py`` does):
-    parameters and pool are shapes, so nothing is allocated."""
+def cell_cases():
+    """The programs of the cells ``mixtral.longgen`` and ``ouro.longgen4``
+    (the second from its configuration file, through the benchmark's own
+    preset mapping), lowered for one chip of a described v5e with the
+    Pallas kernels in (as ``tests/test_chip_compile.py`` does): parameters
+    and pool are shapes, so nothing is allocated."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     topo = topologies.get_topology_desc(platform="tpu",
@@ -169,13 +175,23 @@ def cell_case():
     E.init_params = lambda m, key: on(jax.eval_shape(lambda: init(m, key)))
     E.PagedKVCache.create = staticmethod(
         lambda *a, **kw: on(jax.eval_shape(lambda: create(*a, **kw))))
-    presets._PRESETS["cell"] = dataclasses.replace(
-        get_config("mixtral-8x7b"), name="cell", num_layers=3)
-    eng = Engine(EngineConfig(model="cell", use_pallas="always", **CELL_KW))
+    cells = [("cell-mixtral-3l-v5e", dataclasses.replace(
+        get_config("mixtral-8x7b"), name="cell", num_layers=3), CELL_KW)]
+    sys.path.insert(0, os.path.join(os.getcwd(), "benchmark"))
+    from harness import serve
+    with open("benchmark/configs/ouro-2.6b.json") as f:
+        ouro = json.load(f)
+    cells.append(("cell-ouro-v5e", serve.model_config(ouro, "cell"),
+                  ouro["server"]))
     S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
-    progs = programs(eng, S, CELL_T)
-    E.init_params, E.PagedKVCache.create = init, staticmethod(create)
-    return "cell-mixtral-3l-v5e", progs
+    try:
+        for case, preset, kw in cells:
+            presets._PRESETS["cell"] = preset
+            eng = Engine(EngineConfig(model="cell", use_pallas="always",
+                                      **kw))
+            yield case, programs(eng, S, CELL_T)
+    finally:
+        E.init_params, E.PagedKVCache.create = init, staticmethod(create)
 
 
 def main():
@@ -183,7 +199,7 @@ def main():
     ap.add_argument("--out", required=True)
     args = ap.parse_args()
     os.makedirs(args.out, exist_ok=True)
-    for case, progs in [*tiny_cases(), cell_case()]:
+    for case, progs in [*tiny_cases(), *cell_cases()]:
         for name, text in progs.items():
             path = os.path.join(args.out, f"{case}.{name}.txt")
             with open(path, "w") as f:

@@ -217,7 +217,53 @@ def left_out(m, page_size: int = 16) -> dict:
         if kind.get("num_heads", m.num_heads) != m.num_heads:
             rules["head counts as one"] = dict(
                 window_layer={**kind, "num_heads": m.num_heads})
+    if m.loop_steps > 1:
+        rules["last pass dropped"] = dict(loop_steps=m.loop_steps - 1)
+        rules.update({fault: {} for fault in LOOP_FAULTS})
+    if m.post_norms:
+        rules.update({fault: {} for fault in POST_NORM_FAULTS})
     return rules
+
+
+# The rules of a looped model and of sandwich norms that no field states:
+# each is left out by a patch of ``models/llama.py`` (``patched``), the
+# preset as it is.
+LOOP_FAULTS = ("pass norm between passes dropped",
+               "every pass on pass 0's pages")
+POST_NORM_FAULTS = ("mixer's post norm dropped", "MLP's post norm dropped")
+
+
+@contextlib.contextmanager
+def patched(fault):
+    """``models/llama.py`` with ``fault`` in it, for the faults of
+    ``left_out`` that are no field of the preset; any other fault: the
+    module as it is. ``pass norm between passes dropped``: the final norm
+    is applied once, after the last pass, as in a model that runs its
+    layers once. ``every pass on pass 0's pages``: pass ``t`` of layer ``l``
+    reads and writes entry ``l`` for ``t * L + l``. ``mixer's`` / ``MLP's
+    post norm dropped``: that residual adds the sub-layer's output as it
+    is. (A step program is its engine's own: build the engine inside.)"""
+    real = {name: getattr(llama, name)
+            for name in ("_pass_norm", "_pass_addr", "_post_norm", "_head")}
+    if fault == "pass norm between passes dropped":
+        llama._pass_norm = lambda params, cfg, x: x
+        llama._head = lambda params, cfg, x: real["_head"](
+            params, cfg._kind(loop_steps=1), x)     # which norms, once
+    elif fault == "every pass on pass 0's pages":
+        llama._pass_addr = lambda addr, t, entry_pages: addr
+    elif fault in POST_NORM_FAULTS:
+        which = ("attn_post_norm" if fault.startswith("mixer")
+                 else "mlp_post_norm")
+
+        def post_norm(cfg, blk, x, out, norm):
+            return x + out if norm == which else real["_post_norm"](
+                cfg, blk, x, out, norm)
+        llama._post_norm = post_norm
+    try:
+        yield
+    finally:
+        for name, fn in real.items():
+            setattr(llama, name, fn)
 
 
 def broken_preset(m, name: str, fields: dict):
@@ -297,6 +343,12 @@ def bench(case) -> Bench:
     return load(case)
 
 
+def family(m) -> str:
+    """Which of ``REFUSALS``' families the preset ``m`` is of."""
+    return ("recurrent" if m.recurrent else "window" if m.sliding_window
+            else "looped")
+
+
 def pytest_generate_tests(metafunc):
     """The parameters that depend on the case's preset: the forms it is
     served in (by row AND densely dispatched at once only where no layer is
@@ -310,8 +362,7 @@ def pytest_generate_tests(metafunc):
     if "rule" in metafunc.fixturenames:
         metafunc.parametrize("rule", sorted(left_out(m)))
     if "refused" in metafunc.fixturenames:
-        metafunc.parametrize("refused", sorted(
-            REFUSALS["recurrent" if m.recurrent else "window"]))
+        metafunc.parametrize("refused", sorted(REFUSALS[family(m)]))
     if "state_by" in metafunc.fixturenames:
         metafunc.parametrize("state_by", ["auto", "always"]
                              if m.mixer_count("kda") else ["auto"])
@@ -512,8 +563,9 @@ def test_each_rule_left_out_moves_the_logits_far_past_the_limit(bench, rule):
         bench.preset, name,
         left_out(bench.preset, cfg["server"]["page_size"])[rule])
     prompt, = prompts(cfg, (80,), seed=5)
-    got, = serve(Engine(EngineConfig(model=name, **cfg["server"]),
-                        params=fitted(broken, bench.params)), [prompt], 8)
+    with patched(rule):
+        got, = serve(Engine(EngineConfig(model=name, **cfg["server"]),
+                            params=fitted(broken, bench.params)), [prompt], 8)
     # (rotation moves a toy least, 30 limits: its scores are near uniform)
     assert error(bench, prompt, got) > 10 * cfg["correct"]["limit"]
     # and the sound program on the same prompt is within it
@@ -529,7 +581,7 @@ def test_contiguous_forward_agrees_with_the_reference_and_trains(bench):
     logits, cache = llama.forward(
         bench.params, m, jnp.asarray([toks], jnp.int32),
         llama.KVCache.create(m, 1, 32))
-    assert cache.k.shape[0] == m.num_layers and int(cache.length[0]) == 24
+    assert cache.k.shape[0] == m.cache_layers and int(cache.length[0]) == 24
     lp = jax.nn.log_softmax(logits[0], -1)
     got = lp[jnp.arange(15, 23), jnp.asarray(toks[16:24])]
     assert error(bench, toks[:16], (toks[16:], got)) <= \
@@ -640,6 +692,24 @@ def test_a_cached_prefix_matches_nothing_and_still_agrees(bench):
     assert error(bench, second, got) <= bench.cfg["correct"]["limit"]
 
 
+@where(lambda m: not m.unbuilt_for)
+def test_a_cached_prefix_is_reused_and_still_agrees(bench):
+    """A model of one class of page keeps its prefixes: a prompt that
+    shares another's served tokens takes their pages (every entry of the
+    pool's leading axis lies behind a page id) and prefills the rest."""
+    first, tail = prompts(bench.cfg, (64, 20), seed=4)
+    eng = engine(bench)
+    serve(eng, [first], 4)
+    second = first + tail
+    got, = serve(eng, [second], 6)
+    m = eng.metrics
+    assert m["radix_hit_tokens"] == 64 and m["prefix_skipped"] == 0
+    assert m["prefill_tokens"] == len(first) + len(tail)
+    assert error(bench, second, got) <= bench.cfg["correct"]["limit"]
+    alone, = serve(engine(bench), [second], 6)
+    assert got[0] == alone[0] and rms(got[1], alone[1]) < 1e-4
+
+
 # {what the model has: {what is asked for: (EngineConfig fields, the
 # refusal's message)}}: ``Engine._refuse_unbuilt``'s reasons, by mechanism
 REFUSALS = {
@@ -671,20 +741,31 @@ REFUSALS = {
         "mesh": (dict(mesh=True),
                  "the window class of page has no sharding"),
     },
+    "looped": {
+        "speculative": (dict(speculative="ngram"),
+                        "speculative decoding: no test holds a verify"),
+        "prefill role": (dict(mode="prefill"),
+                         "PD bundle is sent and taken in windows of layers"),
+        "decode role": (dict(mode="decode"),
+                        "PD bundle is sent and taken in windows of layers"),
+        "mesh": (dict(mesh=True),
+                 "sharding specs know neither the norms after a sub-layer"),
+    },
 }
 
 
 def _named(cfg) -> str:
     """How a refusal names what ``cfg`` has, as a pattern."""
-    return re.escape(cfg.unbuilt_for)
+    return re.escape(cfg.unbuilt_for or cfg.looped_for)
 
 
-@pools_alone
+@where(lambda m: m.unbuilt_for or m.looped_for, part="state")
 def test_engine_refuses_what_the_model_does_not_support(case, refused):
-    """``Engine._refuse_unbuilt``'s reasons, by message: each names the
-    mechanism that is missing for what the model has."""
+    """``Engine._refuse_unbuilt``'s and ``_refuse_looped``'s reasons, by
+    message: each names the mechanism that is missing for what the model
+    has."""
     cfg = get_config(case.tiny)
-    kw, match = REFUSALS["recurrent" if cfg.recurrent else "window"][refused]
+    kw, match = REFUSALS[family(cfg)][refused]
     kw, mesh = dict(kw), None
     if kw.pop("mesh", False):
         from jax.sharding import Mesh
@@ -692,7 +773,7 @@ def test_engine_refuses_what_the_model_does_not_support(case, refused):
                     ("dp", "tp"))
     with pytest.raises(ValueError, match=match) as e:
         Engine(EngineConfig(model=case.tiny, **TINY_KW, **kw), mesh=mesh)
-    assert cfg.unbuilt_for in str(e.value)
+    assert re.search(_named(cfg), str(e.value))
 
 
 @pools_alone

@@ -438,6 +438,29 @@ CELL_PROGRAMS = {
                 "window_k": (15, 1185, 16, 8, 128)},
         walk=("_decode_call", "_block_ragged_call"),
         temps=(32 * MB, 192 * MB)),
+    # all 48 layers, run 4 times a token: ONE class of page whose leading
+    # axis is a (pass, layer) entry, 192 over 48 layers of weights, 320
+    # pages of 16 x 16 heads of 128 (8.05 GB in the two pools), 4 rows. The
+    # pools ride TWO nested loops as a carry (the passes' scan, and inside
+    # it the layers' scan over the flat view) and neither loop copies them:
+    # a copy of a pool is 4 GB of temporaries and does not fit. Dense: no
+    # expert kernel in either program. A group of ONE query head a
+    # key/value head in both attention kernels. q, k and v are held ``[L,
+    # out, in]`` (``proj_out_in``): held ``[L, in, out]`` the three stacks
+    # ``bf16[48,2048,2048]`` were transposed whole in every step's entry
+    # computation, hoisted out of the passes' loop (1,210 MB of temporaries
+    # in both programs; 1.9 and 5.4 MB now).
+    "ouro": dict(
+        file="ouro-2.6b.json",
+        shapes={"blocks/wq": (48, 2048, 2048),
+                "blocks/w_gate": (48, 2048, 5632),
+                "blocks/attn_post_norm": (48, 2048),
+                "blocks/mlp_post_norm": (48, 2048),
+                "exit_gate/w": (2048,),
+                "lm_head": (2048, 49152),
+                "k_pages": (192, 320, 16, 16, 128)},
+        walk=("_decode_call", "_block_ragged_call"),
+        temps=(16 * MB, 64 * MB)),
 }
 
 
@@ -498,7 +521,8 @@ def test_step_programs_of_a_cell_fit_and_copy_no_pool(chip, monkeypatch, cell,
     assert "tpu_custom_call" in text
     for walk in want["walk"][:1 if decode else 2]:
         assert walk in text, walk
-    assert ("_moe_visit_call" in text) == decode
+    assert ("_moe_visit_call" in text) == (decode
+                                           and bool(eng.mcfg.num_experts))
     assert compiled.memory_analysis().temp_size_in_bytes < want["temps"][
         0 if decode else 1]
     # A pool under any of its shapes (whole, flat over layers, without its

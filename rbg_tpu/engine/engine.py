@@ -372,7 +372,12 @@ class Engine:
                         # (a prompt's chunk): how often a recurrent
                         # layer's walk over chunk rows engages
                         # (``llama._kda_packed``), from the pack.
-                        "unified_rows": 0, "unified_chunk_rows": 0}
+                        "unified_rows": 0, "unified_chunk_rows": 0,
+                        # A fact of the step programs, not a count of
+                        # steps: the decode walks a step whose kernel
+                        # copies its own pages.
+                        "decode_walk_kernel_copies":
+                            self._decode_walk_kernel_copies()}
         # The step being run: when each phase last began and which one
         # is running (``_Phase``), and what the step first dispatched
         # (``_note_dispatch``).
@@ -2777,6 +2782,28 @@ class Engine:
         return victim
 
     # ---- device dispatch ----
+
+    def _decode_walk_kernel_copies(self) -> int:
+        """How many of a step's decode walks copy their own pages inside
+        the kernel (``ops/pallas/page_walk.kernel_copies``, read off the
+        pools' shapes and dtypes as the step programs hand them over): an
+        entry of the leading axis of each class's K/V pools, where this
+        process runs the kernels at all. 0: every walk takes its pages
+        from the pipeline (packed heads, int8 pools, latents, the XLA
+        forms)."""
+        from rbg_tpu.ops import pallas
+        from rbg_tpu.ops.pallas import page_walk
+        if not pallas.takes_kernel(self.cfg.use_pallas):
+            return 0
+        cache = self.cache
+        classes = [(cache.k_pages, cache.v_pages, cache.k_scales,
+                    cache.v_scales)]
+        if cache.window_k is not None:
+            classes.append((cache.window_k, cache.window_v))
+        return sum(
+            pools[0].shape[0] for pools in classes if page_walk.kernel_copies(
+                [jax.ShapeDtypeStruct(p.shape[1:], p.dtype)
+                 for p in pools if p is not None]))
 
     # bucket_fn
     def _bucket(self, n: int) -> int:

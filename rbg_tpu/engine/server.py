@@ -854,8 +854,10 @@ def serve(args) -> None:
             import traceback
             traceback.print_exc()
             os._exit(1)
-        print(f"engine ready mode={cfg.mode} model={cfg.model} port={port}",
-              flush=True)
+        eng = (server.service or server.decode or server.prefill).engine
+        print(f"engine ready mode={cfg.mode} model={cfg.model} port={port} "
+              "decode_walk_kernel_copies="
+              f"{eng.metrics['decode_walk_kernel_copies']}", flush=True)
 
     threading.Thread(target=init_engine, daemon=True).start()
     print(f"engine listening on 127.0.0.1:{port}", flush=True)

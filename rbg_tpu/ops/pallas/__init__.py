@@ -43,6 +43,13 @@ def kernel(kernel_name: str):
     return getattr(home(kernel_name), kernel_name)
 
 
+def takes_kernel(use_pallas: str) -> bool:
+    """``dispatch_pallas``'s policy alone: whether this process runs the
+    kernels."""
+    return use_pallas == "always" or (use_pallas == "auto"
+                                      and jax.default_backend() == "tpu")
+
+
 def dispatch_pallas(use_pallas: str, kernel_name: str, xla_fn, args, **kw):
     """The ONE kernel-vs-XLA dispatch policy (the page walks, the delta
     rule's decode step and the experts' visits all use it):
@@ -50,7 +57,6 @@ def dispatch_pallas(use_pallas: str, kernel_name: str, xla_fn, args, **kw):
     the XLA path on any other backend, 'never' the XLA path. A kernel
     that cannot be imported is an error, never a reason to run XLA.
     ``kw``: what both forms take by name (a window layer's ``window``)."""
-    if use_pallas == "always" or (use_pallas == "auto"
-                                  and jax.default_backend() == "tpu"):
+    if takes_kernel(use_pallas):
         return kernel(kernel_name)(*args, **kw)
     return xla_fn(*args, **kw)

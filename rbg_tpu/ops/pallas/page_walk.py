@@ -26,7 +26,8 @@ Which (segment, block, physical pages) grid step ``w`` is, two ways:
   evaluates the maps of the step ahead as well). Their segments are a
   step's packed tokens, up to 512 x 64 items, and they are a few per
   cent of a cell's device time.
-* The decode kernels (``paged_attention_kernel.py``) READ A TABLE:
+* The decode kernels (``paged_attention_kernel.py``), where the pipeline
+  brings their pages, READ A TABLE:
   ``walk_items`` resolves every item once a call, in XLA, from the same
   starts, lengths and page table, and an index map is one read of it.
   A block of four pages and two pools has ten index maps; bisecting,
@@ -35,14 +36,37 @@ Which (segment, block, physical pages) grid step ``w`` is, two ways:
   v5e where its 128 KB are 0.16 us of HBM time, 0.81-0.86 us with the
   table (PERF.md, PR 40).
 
-A block's pages are scattered over the pool, so each pool is handed to
-the kernel once per page of a block (``block_specs``): the Pallas
-pipeline fetches the pages side by side, double-buffered as before, and
-``load_blocks`` joins them in VMEM. (Copies issued by the kernel itself
-into one buffer would be the plainer way; Mosaic refuses them for every
-pool whose trailing dims are not whole tiles: hd 64, KV 2, the scales,
-the latents.) A per-step cost is paid once for ``pages_per_block·page``
-slots.
+A block's pages are scattered over the pool, and there are two ways for
+them to arrive, chosen by what the pool's shape and dtype say
+(``kernel_copies``; no flag, field or bucket):
+
+* THE PIPELINE. Each pool is handed to the kernel once per page of a
+  block (``block_specs``): the Pallas pipeline fetches the pages side by
+  side, double-buffered, and ``load_blocks`` joins them in VMEM. Every
+  page is an operand of its own, with its index map, its revisit test,
+  its start and its wait on the scalar core, and since ``walk_items`` a
+  block's time is those: 0.30-0.42 us a grid step and 0.10-0.14 us a page
+  where a page's 64 KB of K and V are 0.08 us of HBM time (PERF.md, PRs
+  40, 46). Every pool can take this path, and the ragged kernels, the
+  latent kernels, the int8 pools with their scales and the pools of
+  packed heads do.
+* THE KERNEL'S OWN COPIES. The pools stay in HBM, the page table and the
+  rows' lengths are the scalar operands, and the kernel walks a row's
+  blocks in a loop of its own: for a block it starts one copy a page a
+  pool into consecutive slots of ONE buffer (``start_block_copies``: the
+  block arrives joined), the block after it, or the next live row's
+  first, before it waits for this one (``wait_block_copies``: one wait a
+  pool) and attends. No grid step a block, no operand a page, no item
+  table and nothing beside the kernel in XLA. Mosaic takes such a copy
+  only where a page's trailing dims ``(KV, hd)`` are whole tiles: bf16
+  pools of KV 8 or 16 and hd 128 (Mixtral's, Solar's GQA layers', both
+  kinds of Laguna's, Ouro's). PR 25 wrote this first and dropped it
+  untimed, because the pools of its day (hd 64, KV 2, scales, latents)
+  were refused; PR 51 brought it back for the decode kernel of the pools
+  that are not.
+
+A per-step cost is paid once for ``pages_per_block·page`` slots either
+way.
 
 The two softmax updates (``gqa_attend``, ``mla_attend``) are the ones the
 grid kernels had, over a block instead of a page: same masks, same
@@ -56,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _I32 = np.int32
@@ -160,13 +185,19 @@ def find_item(starts_ref, w, n_segments: int):
     return lo, lax.sub(w, starts_ref[lo])
 
 
+def _last_live_page(live_tokens, page: int):
+    """The column of a row's line that names its last live page (-1: the
+    row is empty)."""
+    return lax.sub(lax.div(lax.add(live_tokens, _I32(page - 1)), _I32(page)),
+                   _I32(1))
+
+
 def page_of_block(table_ref, row, block, j: int, live_tokens, page: int):
     """Physical page of page ``j`` of ``row``'s block ``block``. Past the
     row's last live page it is that last page again (its slots are masked:
     they lie beyond ``live_tokens``), so no copy follows a table entry
     beyond the live pages, whatever it names."""
-    last = lax.sub(lax.div(lax.add(live_tokens, _I32(page - 1)), _I32(page)),
-                   _I32(1))
+    last = _last_live_page(live_tokens, page)
     p = lax.add(lax.mul(block, _I32(pages_per_block(page))), _I32(j))
     p = lax.clamp(_I32(0), lax.min(p, last), _I32(table_ref.shape[1] - 1))
     return table_ref[row, p]
@@ -247,6 +278,59 @@ def block_specs(pools, page_id, n: int = None):
                 lambda *a, j=j, tail=tail: (page_id(j, *a),) + tail))
             operands.append(pool)
     return specs, operands
+
+
+def kernel_copies(pools) -> bool:
+    """Whether a decode kernel may copy ``pools``' pages itself
+    (``start_block_copies``) or has to take them from the pipeline
+    (``block_specs``): read off the pools, set nowhere. Mosaic slices a
+    page ``[page, KV, hd]`` out of a pool in HBM and lands it at an offset
+    of a VMEM buffer only where the page's trailing dims are whole tiles:
+    bf16 K and V pools of ``KV`` a multiple of 8 and ``hd`` of 128, with no
+    scales beside them (two pools, not four). Heads packed two to a lane
+    tile (``[NP, page, 4, 128]``), int8 pools with their ``[page, KV]``
+    scales and the tests' small heads fall on the pipeline's side."""
+    return len(pools) == 2 and all(
+        p.ndim == 4 and p.dtype == jnp.bfloat16
+        and p.shape[2] % 8 == 0 and p.shape[3] % 128 == 0 for p in pools)
+
+
+def start_block_copies(pools, bufs, sems, table_ref, row, block,
+                       live_tokens, half, n: int):
+    """Start the copies that land block ``block`` of ``row`` in half
+    ``half`` of each pool's buffer: ``n`` a pool, page ``j`` of the block
+    into slots ``j·page ..`` of ``bufs[i] [2, n·page, ...]``, so the block
+    arrives joined. ``pools`` are refs in HBM, ``table_ref`` the page table
+    in SMEM; a pool's copies signal one semaphore, ``sems[i, half]``.
+    ``page_of_block``'s rule: past the row's last live page the last live
+    page again, so no entry beyond it is followed."""
+    page = pools[0].shape[1]
+    last = _last_live_page(live_tokens, page)
+    first = lax.mul(block, _I32(n))
+
+    def start(j, _):
+        p = lax.clamp(_I32(0), lax.min(lax.add(first, j), last),
+                      _I32(table_ref.shape[1] - 1))
+        page_id = table_ref[row, p]
+        slot = pl.multiple_of(lax.mul(j, _I32(page)), page)
+        for i, (pool, buf) in enumerate(zip(pools, bufs)):
+            pltpu.make_async_copy(
+                pool.at[page_id], buf.at[half, pl.ds(slot, page)],
+                sems.at[i, half]).start()
+
+    # Unrolled when lowered, traced once: every step program an engine
+    # warms traces this body (``find_item`` has the price of a ``jnp`` call
+    # and of a loop the scalar core runs).
+    lax.fori_loop(0, n, start, None, unroll=True)
+
+
+def wait_block_copies(bufs, sems, half):
+    """Wait for the block ``start_block_copies`` sent to ``half``: one wait
+    a pool, for the whole half (a DMA semaphore counts bytes, and the
+    pool's ``n`` copies are the half's)."""
+    for i, buf in enumerate(bufs):
+        pltpu.make_async_copy(buf.at[half], buf.at[half],
+                              sems.at[i, half]).wait()
 
 
 def latent_pools(c_pages, pe_pages):

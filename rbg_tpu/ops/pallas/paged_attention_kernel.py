@@ -26,6 +26,14 @@ Design (see /opt/skills/guides/pallas_guide.md and ``page_walk.py``):
 * a block is ``page_walk.decode_pages_per_block`` pages (128 slots), twice
   the ragged kernels': with maps that cost nothing to trace the width is
   the kernel's to choose.
+* bf16 K/V pools whose pages are whole tiles take neither the grid nor
+  the item table (``page_walk.kernel_copies``, read off the pools; PR 51):
+  ``_decode_copies_kernel`` is one program over every row, the pools left
+  in HBM, that walks a row's live blocks in a loop and issues each block's
+  page copies itself into one double buffer, the next block's before this
+  one is attended. Same blocks, same order, same mathematics: its outputs
+  are the pipeline's to the bit. Packed heads, int8 pools and the latent
+  kernels keep the pipeline.
 * GQA via one batched dot per block: [KV, G, hd] × [KV, n·page, hd].
 * A row of length 0 (a free slot of the batch) keeps one step that
   attends nothing and writes zeros.
@@ -44,10 +52,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from rbg_tpu.ops.pallas import page_walk as W
+
+_I32 = np.int32
 
 
 def _page_id(j, w, item_row, item_block, item_page, lens, starts):
@@ -124,6 +136,121 @@ def _decode_kernel(
         out_ref[0] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
+def _decode_copies_kernel(
+    # scalar prefetch
+    table_ref,        # [B, P] int32 (SMEM) — the page table, a line a row
+    kv_lens_ref,      # [B] int32 (SMEM)
+    # operands
+    q_ref,            # [B, KV, G, hd] (VMEM), every row
+    k_hbm, v_hbm,     # [NP, page, KV, hd] (HBM): the pools, whole
+    out_ref,          # [B, KV, G, hd] (VMEM)
+    # scratch
+    k_buf, v_buf,     # [2, n·page, KV, hd]: the block attended, the block
+                      # on its way
+    sems,             # DMA semaphores [2 pools, 2 halves]
+    m_ref, l_ref, acc_ref,    # as ``_decode_kernel``'s
+    head_dim=None,
+    window=None,
+):
+    """``_decode_kernel``'s walk with the copies issued here
+    (``page_walk.start_block_copies``): the rows in a loop, a row's live blocks
+    in a loop inside it, block ``i + 1``'s pages (at a row's end the next
+    live row's first block's) started before block ``i`` is waited for and
+    attended. Same blocks, same order, same ``gqa_attend``."""
+    B = q_ref.shape[0]
+    page = k_hbm.shape[1]
+    n = k_buf.shape[1] // page
+    slots = n * page
+
+    def span(b):
+        """Row ``b``'s length and its walk's first block and end."""
+        kv_len = kv_lens_ref[b]
+        first = _I32(0) if window is None else lax.div(
+            lax.max(lax.sub(kv_len, _I32(window)), _I32(0)), _I32(slots))
+        return kv_len, first, lax.div(lax.add(kv_len, _I32(slots - 1)),
+                                      _I32(slots))
+
+    def start(b, block, kv_len, half):
+        W.start_block_copies((k_hbm, v_hbm), (k_buf, v_buf), sems, table_ref,
+                             b, block, kv_len, half, n)
+
+    def row(b, carry):
+        half, on_its_way = carry
+        kv_len, first, end = span(b)
+        after = lax.min(lax.add(b, _I32(1)), _I32(B - 1))
+        after_len, after_first, after_end = span(after)
+        after_live = lax.bitwise_and(lax.lt(lax.add(b, _I32(1)), _I32(B)),
+                                     lax.lt(after_first, after_end))
+        live = lax.lt(first, end)
+        W.init_softmax(m_ref, l_ref, acc_ref)
+
+        # The call's first live row, or one after an empty row.
+        @pl.when(lax.bitwise_and(live, lax.eq(on_its_way, _I32(0))))
+        def _first():
+            start(b, first, kv_len, half)
+
+        def attend(block, half):
+            ahead = lax.add(block, _I32(1))
+            more = lax.lt(ahead, end)
+            other = lax.sub(_I32(1), half)
+
+            @pl.when(lax.bitwise_or(more, after_live))
+            def _ahead():
+                start(lax.select(more, b, after),
+                      lax.select(more, ahead, after_first),
+                      lax.select(more, kv_len, after_len), other)
+
+            W.wait_block_copies((k_buf, v_buf), sems, half)
+            W.gqa_attend(q_ref[b], k_buf[half], v_buf[half], None, None,
+                         lax.mul(block, _I32(slots)), kv_len, m_ref, l_ref,
+                         acc_ref, head_dim,
+                         None if window is None else kv_len - window)
+            return other
+
+        half = lax.fori_loop(first, end, attend, half)
+        # An empty row attended nothing and writes zeros.
+        out_ref[b] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
+        return half, lax.convert_element_type(
+            lax.bitwise_and(live, after_live), jnp.int32)
+
+    lax.fori_loop(0, B, row, (_I32(0), _I32(0)))
+
+
+def _decode_copies(q, pools, page_table, kv_lens, interpret, head_dim,
+                   window):
+    """``_decode`` for pools the kernel may copy from itself
+    (``page_walk.kernel_copies``): the page table and the lengths are the
+    scalar operands, the pools stay in HBM, and nothing runs beside the
+    kernel in XLA."""
+    B, KV, G, hd = q.shape
+    page = pools[0].shape[1]
+    n = W.decode_pages_per_block(page)
+    whole = pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[whole] + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_specs=whole,
+        scratch_shapes=[
+            pltpu.VMEM((2, n * page, KV, hd), pools[0].dtype),
+            pltpu.VMEM((2, n * page, KV, hd), pools[1].dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_copies_kernel, head_dim=head_dim,
+                          window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_table, kv_lens, q, *pools)
+
+
 def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None,
             window=None):
     """q: [B, KV, G, hd]; pools: k, v pages [NP, page, KV, hd], and for
@@ -131,6 +258,9 @@ def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None,
     ``head_dim``: a head's size where the pool keeps several side by side
     and ``q`` is ``page_walk.pack_queries``'. ``window``: a window layer's
     width (``_walk``)."""
+    if W.kernel_copies(pools):
+        return _decode_copies(q, pools, page_table, kv_lens, interpret,
+                              head_dim, window)
     B, KV, G, hd = q.shape
     page = pools[0].shape[1]
     n = W.decode_pages_per_block(page)

@@ -8,18 +8,22 @@ change's, in one call:
     python scripts/decode_walk_bench.py --slots 256 --out ...   # a sweep
 
 Each family is one jitted call as a step program makes it (``_decode_call``
-at ``lfm2.longgen32``'s and ``mixtral.longgen``'s shapes, ``_mla_decode_call``
-at ``joyai.longgen16``'s) over rows of 128-2048 cached tokens drawn from
-``--seed``. A profile of 50 calls gives the device time of the kernel and
-of the XLA operations beside it (the walk's block counts and, since
-PR 40, its item table) a call, and from the rows' live blocks the time a
-block. It fails without a TPU: nothing here is a CPU timing.
+at ``lfm2.longgen32``'s, ``mixtral.longgen``'s, ``solar-open2.longgen32``'s
+and ``ouro.longgen4``'s shapes and at those of ``laguna-xs2.longgen32``'s
+two kinds of layer, ``_mla_decode_call`` at ``joyai.longgen16``'s) over rows
+of 128-2048 cached tokens (Ouro's 128-1280) drawn from ``--seed``; ``path``
+says who copied the pages (``page_walk.kernel_copies``). A profile of 50
+calls gives the device time of the kernel and of the XLA operations beside
+it (the walk's block counts and, since PR 40, its item table) a call, and
+from the rows' live blocks the time a block. It fails without a TPU:
+nothing here is a CPU timing.
 """
 
 import argparse
 import json
 import os
 import sys
+from typing import NamedTuple, Optional
 
 sys.path[:0] = [os.getcwd(), os.path.join(os.getcwd(), "benchmark")]
 
@@ -32,44 +36,77 @@ from rbg_tpu.ops.pallas import page_walk as W
 from rbg_tpu.ops.pallas import paged_attention_kernel as K
 
 BF16 = jnp.bfloat16
-PAGE, NP = 16, 8192
-# rows, table width, layers of the flat pool, the layer walked
-CELLS = {"lfm2": (32, 256, 10, 3), "joyai": (16, 256, 5, 2),
-         "mixtral": (8, 512, 3, 1)}
+PAGE = 16
+
+
+class Cell(NamedTuple):
+    rows: int
+    width: int                  # of the page table
+    pages: int                  # of one layer of the flat pool
+    layers: int                 # of the flat pool (a few: addresses only)
+    layer: int                  # the one walked
+    gqa: Optional[tuple] = None     # (KV, G, hd) of a whole-tile GQA pool
+    window: Optional[int] = None    # a window layer's width
+
+
+CELLS = {"lfm2": Cell(32, 256, 8192, 10, 3),
+         "joyai": Cell(16, 256, 8192, 5, 2),
+         "mixtral": Cell(8, 512, 8192, 3, 1, (8, 4, 128)),
+         "laguna-window": Cell(32, 256, 8192, 4, 2, (8, 8, 128), 512),
+         "laguna-full": Cell(32, 256, 8192, 5, 2, (8, 6, 128)),
+         "solar": Cell(32, 256, 8192, 2, 1, (8, 8, 128)),
+         "ouro": Cell(4, 80, 320, 24, 13, (16, 1, 128))}
 TRACED_CALLS = 50
 
 
 def _call(name, key):
-    B, _, L, _ = CELLS[name]
-    keys = jax.random.split(key, 4)
-    pool = lambda k, *tail: jax.random.normal(k, (L * NP, PAGE) + tail, BF16)
+    """(the call, the pools whose shapes choose its path)."""
+    cell = CELLS[name]
+    B, keys = cell.rows, jax.random.split(key, 4)
+    pool = lambda k, *tail: jax.random.normal(
+        k, (cell.layers * cell.pages, PAGE) + tail, BF16)
     if name == "joyai":
         ql = jax.random.normal(keys[0], (B, 32, 512), BF16)
         qp = jax.random.normal(keys[1], (B, 32, 64), BF16)
         c, pe = pool(keys[2], 1, 512), pool(keys[3], 1, 128)
-        return lambda t, n: K._mla_decode_call(ql, qp, c, pe, t, n,
-                                               scale=576 ** -0.5)
+        return (lambda t, n: K._mla_decode_call(ql, qp, c, pe, t, n,
+                                                scale=576 ** -0.5),
+                None)           # the latent kernels ask nothing
     if name == "lfm2":      # 8 heads of 64, two a lane tile
         q = W.pack_queries(
             jax.random.normal(keys[0], (B, 8, 4, 64), BF16), 2)
         k, v = pool(keys[1], 4, 128), pool(keys[2], 4, 128)
-        return lambda t, n: K._decode_call(q, k, v, t, n, head_dim=64)
-    q = jax.random.normal(keys[0], (B, 8, 4, 128), BF16)
-    k, v = pool(keys[1], 8, 128), pool(keys[2], 8, 128)
-    return lambda t, n: K._decode_call(q, k, v, t, n)
+        return lambda t, n: K._decode_call(q, k, v, t, n, head_dim=64), (k, v)
+    KV, G, hd = cell.gqa
+    q = jax.random.normal(keys[0], (B, KV, G, hd), BF16)
+    k, v = pool(keys[1], KV, hd), pool(keys[2], KV, hd)
+    return (lambda t, n: K._decode_call(q, k, v, t, n, window=cell.window),
+            (k, v))
 
 
 def _rows(name, rng):
-    """The rows' lengths and their lines of the table: distinct pages of
-    the walked layer, dead entries naming page 0 of the pool."""
-    B, P, _, layer = CELLS[name]
-    lens = rng.integers(128, 2049, size=B).astype(np.int32)
-    table = np.zeros((B, P), np.int32)
-    pages, at = rng.permutation(NP) + layer * NP, 0
+    """The rows' lengths (128-2048, or what the table holds) and their
+    lines of the table: distinct pages of the walked layer, dead entries
+    naming page 0 of the pool (in a window layer the pages wholly below
+    the window too: they were given back)."""
+    cell = CELLS[name]
+    lens = rng.integers(128, min(cell.width * PAGE, 2048) + 1,
+                        size=cell.rows).astype(np.int32)
+    table = np.zeros((cell.rows, cell.width), np.int32)
+    pages, at = rng.permutation(cell.pages) + cell.layer * cell.pages, 0
     for b, live in enumerate(-(-lens // PAGE)):
-        table[b, :live] = pages[at:at + live]
-        at += live
+        below = 0 if cell.window is None else max(
+            lens[b] - cell.window, 0) // PAGE
+        table[b, below:live] = pages[at:at + live - below]
+        at += live - below
     return jnp.asarray(table), jnp.asarray(lens)
+
+
+def _live_blocks(name, lens, slots):
+    """Blocks of ``slots`` the rows' walks attend."""
+    window = CELLS[name].window
+    first = 0 if window is None else np.maximum(lens - window, 0) // slots
+    return int(np.sum(-(-lens // slots) - first))
 
 
 def _device_us_a_call(call, args):
@@ -99,12 +136,15 @@ def main():
               "cells": {}}
     for i, name in enumerate(CELLS):
         rows = _rows(name, np.random.default_rng(args.seed + i))
-        per = _device_us_a_call(
-            _call(name, jax.random.key((args.seed + i) % (1 << 31))), rows)
+        call, pools = _call(name, jax.random.key((args.seed + i) % (1 << 31)))
+        per = _device_us_a_call(call, rows)
         kernel = sum(us for op, us in per.items() if "decode_call" in op)
         beside = sum(us for op, us in per.items() if "decode_call" not in op)
-        blocks = int(np.sum(-(-np.asarray(rows[1]) // slots)))
+        blocks = _live_blocks(name, np.asarray(rows[1]), slots)
+        copies = pools is not None and getattr(
+            W, "kernel_copies", lambda pools: False)(pools)
         result["cells"][name] = {
+            "path": "kernel copies" if copies else "pipeline",
             "blocks": blocks, "kernel_us_a_call": round(kernel, 2),
             "beside_us_a_call": round(beside, 2),
             "operations_beside": len(per) - 1,

@@ -157,17 +157,57 @@ def tiny_cases():
         yield case, programs(eng, S, 2 * cfg.prefill_chunk, lora, window)
 
 
-def cell_cases():
-    """The programs of the cells ``mixtral.longgen`` and ``ouro.longgen4``
-    (the second from its configuration file, through the benchmark's own
-    preset mapping), lowered for one chip of a described v5e with the
-    Pallas kernels in (as ``tests/test_chip_compile.py`` does): parameters
-    and pool are shapes, so nothing is allocated."""
+# Cells lowered from their configuration files: ouro's pools take the
+# decode walk with the kernel's own copies (PR 51), the other three keep the
+# pipeline (packed heads, latents) and their programs must not move.
+CELL_FILES = [("cell-ouro-v5e", "ouro-2.6b"),
+              ("cell-lfm2-v5e", "lfm2-24b-a2b"),
+              ("cell-joyai-v5e", "joyai-llm-flash"),
+              ("cell-kimi-linear-v5e", "kimi-linear-48b-a3b")]
+
+
+@functools.cache
+def _described_chip():
+    """One chip of a described v5e, as a sharding."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    chip = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def kernel_cases():
+    """The four decode kernels alone at pools that keep the pipeline
+    (``page_walk.kernel_copies`` false): LFM2's packed heads, int8 pools
+    with their scales, the latent pools in bf16 and int8."""
+    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=_described_chip())
+    bf, i8, f32 = jnp.bfloat16, jnp.int8, jnp.float32
+    NP, rows, P = 8192, 32, 256
+    tail = (S((rows, P), I32), S((rows,), I32))
+    lat = lambda dt: (S((rows, 32, 512), bf), S((rows, 32, 64), bf),
+                      S((NP, 16, 1, 512), dt), S((NP, 16, 1, 128), dt))
+    yield "kernels", {k: _kernels_without_locations(v.as_text()) for k, v in {
+        "decode_call.packed": K._decode_call.lower(
+            S((rows, 4, 8, 128), bf), *[S((NP, 16, 4, 128), bf)] * 2, *tail,
+            head_dim=64),
+        "decode_call_q": K._decode_call_q.lower(
+            S((rows, 8, 4, 128), bf), *[S((NP, 16, 8, 128), i8)] * 2,
+            *[S((NP, 16, 8), f32)] * 2, *tail),
+        "mla_decode_call": K._mla_decode_call.lower(*lat(bf), *tail,
+                                                    scale=0.1),
+        "mla_decode_call_q": K._mla_decode_call_q.lower(
+            *lat(i8), *[S((NP, 16, 1), f32)] * 2, *tail, scale=0.1),
+    }.items()}
+
+
+def cell_cases():
+    """The programs of the cell ``mixtral.longgen`` and of the cells of
+    ``CELL_FILES`` (those from their configuration files, through the
+    benchmark's own preset mapping), lowered for one chip of a described
+    v5e with the Pallas kernels in (as ``tests/test_chip_compile.py``
+    does): parameters and pool are shapes, so nothing is allocated."""
+    chip = _described_chip()
     on = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=chip),
         tree)
@@ -179,10 +219,14 @@ def cell_cases():
         get_config("mixtral-8x7b"), name="cell", num_layers=3), CELL_KW)]
     sys.path.insert(0, os.path.join(os.getcwd(), "benchmark"))
     from harness import serve
-    with open("benchmark/configs/ouro-2.6b.json") as f:
-        ouro = json.load(f)
-    cells.append(("cell-ouro-v5e", serve.model_config(ouro, "cell"),
-                  ouro["server"]))
+    for case, stem in CELL_FILES:
+        path = f"benchmark/configs/{stem}.json"
+        if not os.path.exists(path):        # an older checkout
+            continue
+        with open(path) as f:
+            served = json.load(f)
+        cells.append((case, serve.model_config(served, "cell"),
+                      served["server"]))
     S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
     try:
         for case, preset, kw in cells:
@@ -199,7 +243,7 @@ def main():
     ap.add_argument("--out", required=True)
     args = ap.parse_args()
     os.makedirs(args.out, exist_ok=True)
-    for case, progs in [*tiny_cases(), *cell_cases()]:
+    for case, progs in [*tiny_cases(), *kernel_cases(), *cell_cases()]:
         for name, text in progs.items():
             path = os.path.join(args.out, f"{case}.{name}.txt")
             with open(path, "w") as f:

@@ -205,7 +205,46 @@ def test_decode_kernel_compiles_at_the_cells_shapes(chip, cell, quantized):
         args = [S((rows, 1, H, hd), BF16), pool, pool, *tail, *scales]
     text = _compiles_with_kernel(fn, *args).as_text()
     n = page_walk.decode_pages_per_block(PAGE)
-    assert f"s32[{n},{rows * (width // n) + 1}]" in text
+    # a bf16 pool of whole-tile pages is walked with the kernel's own
+    # copies (PR 51): no item table; every other pool keeps it
+    copies = not isinstance(heads, int) and page_walk.kernel_copies(args[1:3])
+    assert copies == (cell in ("mixtral.longgen", "solar-open2.longgen32")
+                      and not quantized)
+    assert (f"s32[{n},{rows * (width // n) + 1}]" in text) != copies
+
+
+# What ``page_walk.kernel_copies`` takes (PR 51): (rows, the table's width,
+# the pool's pages over the layers of its class, (kv heads, queries a kv
+# head), a window layer's width).
+CELL_COPIES = {
+    "laguna-xs2.window": (32, 256, 15 * 1185, (8, 8), 512),
+    "laguna-xs2.full": (32, 256, 5 * 8192, (8, 6), None),
+    "mixtral": (8, 512, 3 * 8192, (8, 4), None),
+    "solar-open2.gqa": (32, 256, 2 * 8192, (8, 8), None),
+    "ouro": (4, 80, 192 * 320, (16, 1), None),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(CELL_COPIES))
+def test_decode_kernel_with_its_own_copies_compiles_at_the_cells_shapes(
+        chip, kind):
+    """``_decode_call`` on the path that issues its own page copies
+    (``paged_attention_kernel._decode_copies``): the pools stay in HBM,
+    a page ``[16, KV, 128]`` is sliced out of one and lands at an offset
+    of a VMEM buffer, which Mosaic takes for KV 8 and KV 16 at hd 128;
+    the scalar operands are the page table and the lengths, no item
+    table and no block counts."""
+    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    rows, width, pages, (KV, G), window = CELL_COPIES[kind]
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    pool = S((pages, PAGE, KV, 128), BF16)
+    assert page_walk.kernel_copies((pool, pool))
+    fn = lambda q, k, v, t, n: K._decode_call(q, k, v, t, n, window=window)
+    text = _compiles_with_kernel(
+        fn, S((rows, KV, G, 128), BF16), pool, pool, S((rows, width), I32),
+        S((rows,), I32)).as_text()
+    n = page_walk.decode_pages_per_block(PAGE)
+    assert f"s32[{n},{rows * (width // n) + 1}]" not in text
 
 
 # ---- the engine's own step programs, whole, at chip_smoke's serving size ----
@@ -349,7 +388,7 @@ CELL_PROGRAMS = {
         file="joyai-llm-flash.json",
         shapes={"blocks/moe_gate": (4, 256, 2048, 768),
                 "v_pages": (5, 8192, 16, 1, 128)},
-        walk=("_mla_decode_call", "_block_ragged_mla_call"),
+        walk=("_mla_decode_call", "_block_ragged_mla_call"), copies=0,
         temps=(32 * MB, 416 * MB), some_copy=True),
     # all 27 layers (one dense recurrent layer, 19 recurrent and 7 latent
     # expert layers), 16 of 256 experts a layer, 16 rows, pages for the 7
@@ -369,7 +408,7 @@ CELL_PROGRAMS = {
                 "k_pages": (7, 4096, 16, 1, 512),
                 "state/s": (20, 16, 32, 128, 128),
                 "state/conv": (20, 16, 36864)},
-        walk=("_mla_decode_call", "_block_ragged_mla_call"),
+        walk=("_mla_decode_call", "_block_ragged_mla_call"), copies=0,
         temps=(128 * MB, 128 * MB), some_copy=True),
     # all 40 layers (two dense layers with the gated short convolution, then
     # 10 attention and 28 convolution layers with 8 of 64 experts each), 32
@@ -387,7 +426,7 @@ CELL_PROGRAMS = {
                 "lm_head": None,                        # a tied head
                 "k_pages": (10, 8192, 16, 4, 128),
                 "state/tail": (30, 32, 4096)},
-        walk=("_decode_call", "_block_ragged_call"),
+        walk=("_decode_call", "_block_ragged_call"), copies=0,
         temps=(128 * MB, 128 * MB)),
     # 8 layers in two turns A K K K (A: 64 / 8 heads of 128 without
     # positions, gated; K: 64 delta-rule heads of 128), 20 of 320 experts a
@@ -410,7 +449,7 @@ CELL_PROGRAMS = {
                 "k_pages": (2, 8192, 16, 8, 128),
                 "state/s": (6, 32, 64, 128, 128),
                 "state/conv": (6, 32, 73728)},
-        walk=("_decode_call", "_block_ragged_call"),
+        walk=("_decode_call", "_block_ragged_call"), copies=2,
         temps=(256 * MB, 640 * MB)),
     # 20 layers in five periods F W W W (F: 48 / 8 heads of 128, half of
     # each rotated under YaRN; W: 64 / 8 heads within 512 tokens), a gate a
@@ -436,7 +475,7 @@ CELL_PROGRAMS = {
                 "lm_head": (2048, 12544),
                 "k_pages": (5, 8192, 16, 8, 128),
                 "window_k": (15, 1185, 16, 8, 128)},
-        walk=("_decode_call", "_block_ragged_call"),
+        walk=("_decode_call", "_block_ragged_call"), copies=20,
         temps=(32 * MB, 192 * MB)),
     # all 48 layers, run 4 times a token: ONE class of page whose leading
     # axis is a (pass, layer) entry, 192 over 48 layers of weights, 320
@@ -459,7 +498,7 @@ CELL_PROGRAMS = {
                 "exit_gate/w": (2048,),
                 "lm_head": (2048, 49152),
                 "k_pages": (192, 320, 16, 16, 128)},
-        walk=("_decode_call", "_block_ragged_call"),
+        walk=("_decode_call", "_block_ragged_call"), copies=192,
         temps=(16 * MB, 64 * MB)),
 }
 
@@ -515,6 +554,10 @@ def test_step_programs_of_a_cell_fit_and_copy_no_pool(chip, monkeypatch, cell,
     assert (eng.cfg.max_batch, eng.cfg.max_pages_per_seq) == (
         cfg["server"]["max_batch"],
         cfg["server"]["max_seq_len"] // cfg["server"]["page_size"])
+    # the decode walks a step that copy their own pages (PR 51): read off
+    # the pools, Laguna's two classes, a pass a layer in Ouro, none of the
+    # packed heads or the latents
+    assert eng.metrics["decode_walk_kernel_copies"] == want["copies"]
     decode = program == "decode"
     compiled = (_compile_decode if decode else _compile_unified)(chip, eng)
     text = compiled.as_text()

@@ -54,3 +54,56 @@ def test_a_decoding_row_reads_the_same_beside_a_prefill(interpreted, model,
     assert since["unified_rows"] >= 3 * 6
     assert since["unified_chunk_rows"] == 2
     assert beside == alone
+
+
+def test_kernel_copies_and_the_pipeline_serve_the_same_tokens(
+        interpreted, monkeypatch):
+    """Through the engine, at pools ``page_walk.kernel_copies`` takes (bf16,
+    8 KV heads of 128; the tiny presets' own fall on the pipeline's side),
+    two full and two window layers: decode steps and the one-token rows of
+    unified steps walk with the kernel's own copies in every attention
+    layer of both classes of page (the gauge says so), and the served
+    tokens are those of the same engine with the predicate turned off,
+    the pipeline's."""
+    import dataclasses
+
+    import jax
+
+    from rbg_tpu.models import config as presets
+    from rbg_tpu.models import get_config
+    from rbg_tpu.ops.pallas import page_walk
+
+    base, layers = get_config("tiny-laguna"), 4
+    monkeypatch.setitem(presets._PRESETS, "whole-tiles", dataclasses.replace(
+        base, name="whole-tiles", num_layers=layers, num_heads=8,
+        num_kv_heads=8, head_dim=128, dtype="bfloat16",
+        window_layer={**dict(base.window_layer), "num_heads": 16},
+        layer_types=("full_attention", "sliding_attention") * 2))
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, 256, n).tolist() for n in (37, 5, 18)]
+    late = rng.integers(1, 256, 33).tolist()
+    greedy = SamplingParams(max_new_tokens=6, temperature=0.0)
+
+    def served():
+        jax.clear_caches()      # ``_decode_call`` is traced once a shape
+        eng = Engine(EngineConfig(
+            model="whole-tiles", page_size=16, num_pages=64, max_seq_len=128,
+            max_batch=4, prefill_chunk=16, enable_radix_cache=False,
+            use_pallas="always"))
+        ids = [eng.add_request(p, greedy) for p in prompts]
+        out = {}
+        while eng.has_work():
+            for ev in eng.step():
+                out.setdefault(ev.request_id, []).append(ev.token)
+            if len(out.get(ids[0], ())) == 2 and len(eng.requests) == 3:
+                eng.add_request(late, greedy)       # unified steps follow
+        assert eng.metrics["unified_steps_run"] >= 3
+        return eng.metrics["decode_walk_kernel_copies"], list(out.values())
+
+    copies, tokens = served()
+    assert copies == layers
+    monkeypatch.setattr(page_walk, "kernel_copies", lambda pools: False)
+    piped, tokens_piped = served()
+    jax.clear_caches()
+    assert piped == 0
+    assert tokens == tokens_piped and len(tokens) == 4

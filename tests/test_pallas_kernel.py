@@ -407,3 +407,100 @@ def test_mla_decode_reads_the_same_from_a_padded_rotary_pool(form, quantized):
     narrow, wide = attend(pe), attend(wide)
     assert np.abs(np.asarray(narrow)).max() > 0
     np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))
+
+
+# ---- the decode walk that copies its own pages (PR 51) ----
+#
+# bf16 K/V pools whose pages are whole tiles (``page_walk.kernel_copies``)
+# are walked by a kernel that issues the page copies itself; every other
+# pool keeps the pipeline. Same blocks, same order, same ``gqa_attend``:
+# the two agree to the bit.
+
+from rbg_tpu.ops.pallas import page_walk
+from rbg_tpu.ops.pallas import paged_attention_kernel as decode_kernel
+
+_COPY_PAGE = 16
+_BLOCK = page_walk.decode_pages_per_block(_COPY_PAGE) * _COPY_PAGE   # 128
+
+# id: (KV, G, table width, window, the rows' lengths). Every case holds an
+# empty row; "last" where it ends the call, "between" where live rows
+# follow it.
+_COPY_CASES = {
+    "kv8-g8-table8-one-block": (8, 8, 8, None, [1, 0, _BLOCK, 77, 0]),
+    "kv8-g6-table512-whole-table": (
+        8, 6, 512, None, [_BLOCK + 1, 0, 3 * _BLOCK, 512 * _COPY_PAGE, 1]),
+    "kv16-g1-table32": (16, 1, 32, None,
+                        [_BLOCK, _BLOCK + 1, 0, 300, 32 * _COPY_PAGE, 0]),
+    "kv8-g1-table8": (8, 1, 8, None, [0, 5, _BLOCK]),
+    "kv8-g8-window512-starts-past-block-0": (
+        8, 8, 64, 512, [700, 1000, 0, 513, 40, 1024, 0]),
+    "kv16-g1-window128": (16, 1, 32, 128, [129, 0, 128, 400, 1]),
+}
+
+
+def _copy_case(KV, G, P, window, lens, seed):
+    """Queries, bf16 pools of whole-tile pages whose LAST page is NaN,
+    and a table whose dead entries all name it (a window layer's pages
+    wholly below the window too: they were given back)."""
+    rng = np.random.RandomState(seed)
+    hd, page, B = 128, _COPY_PAGE, len(lens)
+    live = [-(-n // page) for n in lens]
+    NP = sum(live) + 2
+    pool = lambda: jnp.asarray(rng.randn(NP, page, KV, hd),
+                               jnp.bfloat16).at[NP - 1].set(jnp.nan)
+    k, v = pool(), pool()
+    table = np.full((B, P), NP - 1, np.int32)
+    pages, at = rng.permutation(NP - 2) + 1, 0
+    for b, n in enumerate(live):
+        below = 0 if window is None else max(lens[b] - window, 0) // _BLOCK \
+            * (_BLOCK // page)
+        table[b, below:n] = pages[at + below:at + n]
+        at += n
+    q = jnp.asarray(rng.randn(B, KV, G, hd), jnp.bfloat16)
+    return q, (k, v), jnp.asarray(table), jnp.asarray(lens, jnp.int32)
+
+
+@pytest.mark.parametrize("case", sorted(_COPY_CASES))
+def test_decode_walk_with_kernel_copies_equals_the_pipeline_to_the_bit(
+        case, monkeypatch):
+    KV, G, P, window, lens = _COPY_CASES[case]
+    q, pools, table, kv_lens = _copy_case(KV, G, P, window, lens,
+                                          seed=len(case))
+    assert page_walk.kernel_copies(pools)
+    walk = lambda: np.asarray(decode_kernel._decode(
+        q, pools, table, kv_lens, True, None, window), np.float32)
+    copied = walk()
+    monkeypatch.setattr(page_walk, "kernel_copies", lambda pools: False)
+    piped = walk()
+    assert np.isfinite(copied).all()
+    np.testing.assert_array_equal(copied, piped)
+    empty = np.asarray(lens) == 0
+    assert np.all(copied[empty] == 0) and np.abs(copied[~empty]).min() > 0
+    # and both are the XLA form's answer
+    H = KV * G
+    ref = paged_attention_xla(
+        q.reshape(len(lens), 1, H, 128).astype(jnp.float32),
+        *(jnp.nan_to_num(p.astype(jnp.float32)) for p in pools), table,
+        jnp.maximum(kv_lens - 1, 0)[:, None], kv_lens, window=window)
+    np.testing.assert_allclose(
+        copied.reshape(ref.shape)[~empty], np.asarray(ref)[~empty],
+        rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("pools,copies", [
+    ([((64, 16, 8, 128), jnp.bfloat16)] * 2, True),      # mixtral, laguna
+    ([((64, 16, 16, 128), jnp.bfloat16)] * 2, True),     # ouro
+    ([((64, 16, 8, 256), jnp.bfloat16)] * 2, True),
+    ([((64, 16, 4, 128), jnp.bfloat16)] * 2, False),     # lfm2: packed heads
+    ([((64, 16, 8, 64), jnp.bfloat16)] * 2, False),
+    ([((64, 16, 2, 32), jnp.bfloat16)] * 2, False),      # the tiny presets
+    ([((64, 16, 8, 128), jnp.float32)] * 2, False),
+    ([((64, 16, 8, 128), jnp.int8)] * 2
+     + [((64, 16, 8), jnp.float32)] * 2, False),         # int8 and scales
+    ([((64, 16, 512), jnp.bfloat16), ((64, 16, 128), jnp.bfloat16)],
+     False),                                             # latents
+], ids=["kv8", "kv16", "hd256", "packed", "hd64", "tiny", "f32", "int8",
+        "latent"])
+def test_kernel_copies_is_read_off_the_pools(pools, copies):
+    assert page_walk.kernel_copies(
+        [jax.ShapeDtypeStruct(*p) for p in pools]) is copies

@@ -270,7 +270,11 @@ ENGINE_OPS: Dict[str, OpSpec] = {
     # ``Engine.metrics`` holds them (docs/observability.md section 2.5):
     # among them ``steps_run``, ``device_waited_steps`` and, beside it,
     # ``lagged_steps`` (steps dispatched while the step before them was
-    # unread: the one pending read of both step kinds).
+    # unread: the one pending read of both step kinds); the clocks of
+    # the six sub-phases that tile ``engine.pack`` and ``engine.dispatch``
+    # (``t_unified_<sub>_s``, ``t_decode_<sub>_s``), ``uploads``, and the
+    # starved-time bounds ``t_starved_s`` (split into ``_between_s``,
+    # ``_pack_s``, ``_dispatch_s``) and ``t_starved_max_s``.
     OP_METRICS: _spec(OP_METRICS, PLANE_ENGINE, False, {},
                       {"ok": ("metrics", "mode")}),
     OP_SLO: _spec(OP_SLO, PLANE_ENGINE, False, {"window": "float?"},

@@ -64,19 +64,46 @@ class _Phase:
     a cumulative clock in ``Engine.metrics``. A phase entered inside
     another suspends the outer one's clock (a read of the pending step
     inside ``engine.pack`` is sync and emit time, not pack time),
-    so the clocks never overlap and sum to no more than the step."""
+    so the clocks never overlap and sum to no more than the step.
 
-    __slots__ = ("eng", "idx", "ann", "outer", "t0")
+    ``engine.pack`` and ``engine.dispatch`` of a unified and of a fused
+    decode step (``kind``) are tiled by sub-phases: flat, one open at a
+    time, each an annotation inside the phase's and a clock of its own by
+    the kind of step (``SUB_CLOCKS``). The first opens on the phase's
+    entry stamp and the last closes on its exit stamp; ``mark`` closes
+    one and opens the next on ONE reading of the clock. Every stretch that
+    goes to the phase's clock goes to the open sub-phase's too, a stretch
+    cut short by an inner phase included, so the sub-phase clocks sum to
+    the two phases' to float rounding."""
+
+    __slots__ = ("eng", "idx", "ann", "outer", "t0", "subs", "sub",
+                 "sub_ann")
     SPANS = (obs_names.SPAN_ENGINE_ADMIT, obs_names.SPAN_ENGINE_PACK,
              obs_names.SPAN_ENGINE_DISPATCH, obs_names.SPAN_ENGINE_SYNC,
              obs_names.SPAN_ENGINE_EMIT)
     CLOCKS = ("t_admit_s", "t_pack_s", "t_dispatch_s", "t_sync_s",
               "t_emit_s")
+    SUB_SPANS = (obs_names.SPAN_ENGINE_PACK_ROWS,
+                 obs_names.SPAN_ENGINE_PACK_FILL,
+                 obs_names.SPAN_ENGINE_PACK_UPLOAD,
+                 obs_names.SPAN_ENGINE_DISPATCH_CALL,
+                 obs_names.SPAN_ENGINE_DISPATCH_BOOK,
+                 obs_names.SPAN_ENGINE_DISPATCH_SAMPLE)
+    # In ``SUB_SPANS``' order; a decode step has no ``sample``.
+    SUB_CLOCKS = {
+        "unified": ("t_unified_rows_s", "t_unified_fill_s",
+                    "t_unified_upload_s", "t_unified_call_s",
+                    "t_unified_book_s", "t_unified_sample_s"),
+        "decode": ("t_decode_rows_s", "t_decode_fill_s",
+                   "t_decode_upload_s", "t_decode_call_s",
+                   "t_decode_book_s")}
 
-    def __init__(self, eng: "Engine", idx: int):
+    def __init__(self, eng: "Engine", idx: int, kind: Optional[str] = None):
         self.eng = eng
         self.idx = idx
         self.ann = trace.annotation(self.SPANS[idx])
+        self.subs = self.SUB_CLOCKS[kind] if kind is not None else None
+        self.sub = self.sub_ann = None
 
     def __enter__(self):
         eng = self.eng
@@ -86,21 +113,56 @@ class _Phase:
         if outer is not None:
             outer._stop(now)
         eng._phase_now = self
+        if self.idx == _DISPATCH:
+            eng.probe(now)
+        if self.subs is not None:
+            self._open(_ROWS if self.idx == _PACK else _CALL)
         return self
 
     def __exit__(self, *exc):
         now = time.monotonic()
         self._stop(now)
-        outer = self.eng._phase_now = self.outer
+        if self.sub_ann is not None:
+            self.sub_ann.__exit__(*exc)
+        eng = self.eng
+        outer = eng._phase_now = self.outer
         if outer is not None:
             outer.t0 = now
         self.ann.__exit__(*exc)
+        idx = self.idx
+        if idx == _SYNC and eng._pending is None:
+            # The read that left nothing in flight: from here on the
+            # device has nothing of this engine's to run.
+            eng._t_emptied = now
+        if idx != _PACK and idx != _DISPATCH:
+            eng.probe(now)
 
     def _stop(self, now: float) -> None:
-        self.eng.metrics[self.CLOCKS[self.idx]] += now - self.t0
+        took = now - self.t0
+        m = self.eng.metrics
+        m[self.CLOCKS[self.idx]] += took
+        if self.sub is not None:
+            m[self.sub] += took
+
+    def _open(self, sub: int) -> None:
+        self.sub = self.subs[sub]
+        self.sub_ann = trace.annotation(self.SUB_SPANS[sub])
+        self.sub_ann.__enter__()
+
+    def mark(self, sub: int) -> float:
+        """Close the open sub-phase and open ``sub``; the stamp."""
+        now = time.monotonic()
+        self._stop(now)
+        self.t0 = now
+        self.sub_ann.__exit__(None, None, None)
+        self._open(sub)
+        return now
 
 
 _ADMIT, _PACK, _DISPATCH, _SYNC, _EMIT = range(5)
+# The sub-phases of ``engine.pack`` (the first three) and of
+# ``engine.dispatch``, in ``_Phase.SUB_SPANS``' order.
+_ROWS, _FILL, _UPLOAD, _CALL, _BOOK, _SAMPLE = range(6)
 
 
 @dataclasses.dataclass
@@ -377,13 +439,44 @@ class Engine:
                         # steps: the decode walks a step whose kernel
                         # copies its own pages.
                         "decode_walk_kernel_copies":
-                            self._decode_walk_kernel_copies()}
+                            self._decode_walk_kernel_copies(),
+                        # Host-to-device puts of the unified and the
+                        # decode step paths (``_put``).
+                        "uploads": 0,
+                        # How long the device had nothing of this engine's
+                        # to run before a step's program reached it, from
+                        # probes on the loop thread (``probe``,
+                        # ``_note_enqueued``): since the first probe that
+                        # found the step in flight finished, split by
+                        # where the loop was (before the pack, in it, in
+                        # the dispatch), and since the last that found it
+                        # running; the truth lies between the two.
+                        "t_starved_s": 0.0, "t_starved_max_s": 0.0,
+                        "t_starved_between_s": 0.0, "t_starved_pack_s": 0.0,
+                        "t_starved_dispatch_s": 0.0}
+        # What engine.pack and engine.dispatch are made of, by the kind
+        # of step (``_Phase.mark``): cumulative seconds.
+        for clocks in _Phase.SUB_CLOCKS.values():
+            self.metrics.update(dict.fromkeys(clocks, 0.0))
         # The step being run: when each phase last began and which one
         # is running (``_Phase``), and what the step first dispatched
         # (``_note_dispatch``).
         self._marks: List[Optional[float]] = [None] * 5
         self._phase_now: Optional[_Phase] = None
         self._dispatched: Optional[tuple] = None
+        # The step in flight as the probes have seen it (``probe``): which
+        # one, the last probe that found it running and the first that
+        # found it finished; when the last program call of a marked step
+        # returned, when the read that left nothing in flight ended, and
+        # the stamp before which the device idled for want of requests
+        # (the service loop's idle turns), not for the host.
+        self._probed: Optional[_Unread] = None
+        self._t_running: Optional[float] = None
+        self._t_ready: Optional[float] = None
+        self._t_enq: Optional[float] = None
+        self._t_emptied: Optional[float] = None
+        self._starve_floor = 0.0
+        self._out_of_work = False
         # One record per run step, ``(t0, t_pack, t_dispatch, t_sync,
         # t_emit, t_end, kind, rows, q_tokens, bucket, step_num)`` on
         # time.monotonic(); appended by the loop thread, read by the
@@ -461,13 +554,13 @@ class Engine:
                 f"does not support {why}")
 
     def _slot_rows(self, reqs, B: int):
-        """``[B]`` state slots of ``reqs`` in row order, on the device; a
+        """``[B]`` state slots of ``reqs`` in row order, on the host; a
         row of padding names a slot out of range, so its write is
         dropped."""
         slots = np.full(B, self.state.slots, np.int32)
         for i, r in enumerate(reqs):
             slots[i] = r.state_slot
-        return jnp.asarray(slots)
+        return slots
 
     def _state_kw(self, reqs, B: int) -> dict:
         """The keyword arguments by which a step program gets what a model
@@ -477,10 +570,10 @@ class Engine:
         kw = {}
         if self.state is not None:
             kw.update(state=self.state.arrays,
-                      slots=self._slot_rows(reqs, B))
+                      slots=self._put(self._slot_rows(reqs, B)))
         if self.window_allocator is not None:
             kw.update(window=self.cache.window_pages,
-                      wtable=jnp.asarray(self._window_table(reqs, B)))
+                      wtable=self._put(self._window_table(reqs, B)))
         return kw
 
     def _put_pools(self, kp, vp, ksc, vsc, state=None, window=None) -> None:
@@ -1018,6 +1111,93 @@ class Engine:
                 # Held as the step starts: a row that finishes in this
                 # step frees its slot before the step is recorded.
                 self._slots_at_dispatch = self.state.held
+
+    def probe(self, now: float, turn_began: Optional[float] = None) -> None:
+        """Ask whether the step in flight has finished, at a point the
+        loop thread passes anyway (``now``, a stamp it has just taken):
+        the end of ``engine.admit``, ``engine.sync`` and ``engine.emit``,
+        the entry of ``engine.dispatch``, every mark of a sub-phase, and
+        the service loop's ends of ``service.intake`` and
+        ``service.deliver``. Keeps the last stamp at which it was running
+        and the first at which it was not, and asks no more after that:
+        ``is_ready()`` reads no device memory and waits for nothing.
+
+        The probe that ends ``service.intake`` also gives the stamp at
+        which its turn began (``turn_began``): a turn that leaves the
+        engine without work is followed by idle waits, and whatever the
+        device idles until the turn in which work arrives again is no
+        host's fault (``_starve_floor``)."""
+        if turn_began is not None:
+            if not (self.waiting or self.running):
+                self._out_of_work = True
+            elif self._out_of_work:
+                self._out_of_work = False
+                self._starve_floor = turn_began
+        unread = self._pending
+        if unread is not self._probed:
+            self._probed = unread
+            self._t_running = self._t_ready = None
+        if unread is None or self._t_ready is not None:
+            return
+        if unread.toks.is_ready():
+            self._t_ready = now
+        else:
+            self._t_running = now
+
+    def _mark(self, sub: int) -> None:
+        """Close the step's open sub-phase and open ``sub`` (no-op in a
+        phase that has none: the split-path steps), and probe. The mark
+        that closes ``engine.dispatch.call`` is where the step's program
+        has reached the device."""
+        phase = self._phase_now
+        if phase is None or phase.subs is None:
+            return
+        now = phase.mark(sub)
+        self.probe(now)
+        if sub == _BOOK:
+            self._note_enqueued(now)
+
+    def _note_enqueued(self, now: float) -> None:
+        """Account the time the device starved for the step whose program
+        call has just returned (``now``; the probe of this mark has run).
+        The lower bound runs from the first probe that found the step
+        before it finished: the device went idle somewhere before that
+        probe. The upper bound runs from the last probe that found it
+        running, or from the call before this one where none did. With
+        nothing in flight (rows that need the host, the step after a
+        forced read, and a unified step that sampled no row, whose
+        program may in truth still run: the rule of
+        ``device_waited_steps``) the device has idled since the read that
+        emptied ``_pending`` ended, and both bounds take that stamp. No
+        bound reaches back before the call before this one, nor before
+        ``_starve_floor``. The lower bound is split at the stamps at which
+        this step's pack and dispatch began."""
+        m = self.metrics
+        floor = max(self._starve_floor, self._t_enq or 0.0)
+        self._t_enq = now
+        if self._pending is None:
+            lo = hi = self._t_emptied
+        else:
+            lo = self._t_ready
+            hi = self._t_running if self._t_running is not None else floor
+        if hi is None:
+            return
+        m["t_starved_max_s"] += now - max(hi, floor)
+        if lo is None:
+            return
+        lo = max(lo, floor)
+        pack = min(max(self._marks[_PACK], lo), now)
+        dispatch = min(max(self._marks[_DISPATCH], pack), now)
+        m["t_starved_s"] += now - lo
+        m["t_starved_between_s"] += pack - lo
+        m["t_starved_pack_s"] += dispatch - pack
+        m["t_starved_dispatch_s"] += now - dispatch
+
+    def _put(self, a):
+        """A host array put on the device: every put of the unified and
+        the decode step paths is made here, and counted."""
+        self.metrics["uploads"] += 1
+        return jnp.asarray(a)
 
     def _record_step(self, t0: float, t_end: float, ann) -> None:
         """Account one step that dispatched: its wall time by kind, the
@@ -1626,14 +1806,14 @@ class Engine:
         host_bound = self._host_bound(self.running)
         if host_bound:
             events.extend(self._read_pending())
-        with _Phase(self, _PACK):
+        with _Phase(self, _PACK, "unified"):
             packed = self._pack_unified(events)
         if packed is None:
             events.extend(self._read_pending())
             return events
         entries, sample_rows, Ttot, Rb, Tb, dev = packed
 
-        with _Phase(self, _DISPATCH):
+        with _Phase(self, _DISPATCH, "unified"):
             self._note_dispatch("unified", len(entries), Ttot, Rb, Tb)
             self.metrics["unified_rows"] += len(entries)
             self.metrics["unified_chunk_rows"] += sum(
@@ -1647,6 +1827,7 @@ class Engine:
 
             # Host bookkeeping at dispatch: the next pack reads it before
             # this step's tokens are read.
+            self._mark(_BOOK)
             for req, start, end in entries:
                 if end > start:
                     req.prefill_pos = end
@@ -1656,6 +1837,7 @@ class Engine:
                         req.state = "running"
                 else:
                     req.seq_len += 1
+            self._mark(_SAMPLE)
             prev, self._pending = self._pending, None
             if sample_rows:
                 toks, lps = self._sample_unified(logits, sample_rows)
@@ -1670,25 +1852,31 @@ class Engine:
             events.extend(self._read_pending())
         return events
 
-    def _unread_tokens(self):
+    def _unread_columns(self) -> Dict[int, int]:
         """Where rows about to decode find their input tokens while the
-        step before them is unread: ``(column, prev)``. ``column`` maps
-        ``id(request)`` to its entry of ``prev``, that step's ``last`` on
-        the device; a row that is not in it takes the host's
-        ``last_token`` (``_place_tokens``: ``take`` -1). ``prev`` is as
-        wide as the widest line of rows, so that one program a shape
-        serves every step before it (``warm_place`` compiles the
-        widening). No device read."""
+        step before them is unread: ``id(request)`` to its entry of that
+        step's ``last`` (``_unread_tokens``). A row that is not in it takes
+        the host's ``last_token`` (``_place_tokens``: ``take`` -1)."""
         unread = self._pending
         if unread is None:
-            return {}, self._no_tokens
+            return {}
+        return {id(r): j for j, r in enumerate(unread.rows)}
+
+    def _unread_tokens(self):
+        """The unread step's ``last`` on the device, which
+        ``_unread_columns`` indexes: as wide as the widest line of rows,
+        so that one program a shape serves every step before it
+        (``warm_place`` compiles the widening). No device read."""
+        unread = self._pending
+        if unread is None:
+            return self._no_tokens
         prev = unread.last
         n = prev.shape[0]
         if n != self._rows_max:
             wide = np.full(self._rows_max, -1, np.int32)
             wide[:n] = np.arange(n, dtype=np.int32)
-            prev = _place(self._no_tokens, jnp.asarray(wide), prev)
-        return {id(r): j for j, r in enumerate(unread.rows)}, prev
+            prev = _place(self._no_tokens, self._put(wide), prev)
+        return prev
 
     # hot_path
     def _pack_unified(self, events: List[StepEvent]):
@@ -1724,6 +1912,7 @@ class Engine:
                 for r, start, end in entries:
                     self._window_pages_for(r, start, max(end, start + 1))
 
+        self._mark(_FILL)
         P = self.cfg.max_pages_per_seq
         Rb = self._bucket(len(entries))
         Ttot = sum((e - s) if e > s else 1 for _, s, e in entries)
@@ -1732,7 +1921,7 @@ class Engine:
         # Where a decode row's token is still on the device: its entry of
         # ``prev``; -1 elsewhere (``_place_tokens``, inside the program).
         take = np.full((1, Tb), -1, np.int32)
-        column, prev = self._unread_tokens()
+        column = self._unread_columns()
         # Pad tokens carry position -1 — the ragged-pack pad contract
         # (ops/ragged_paged_attention): the XLA fallback's unpack routes
         # them out of its scatter and the kernel skips them outright.
@@ -1772,10 +1961,12 @@ class Engine:
         # ``sample_rows``' order, as wide as the widest line of rows.
         rows = np.zeros(self._rows_max, np.int32)
         rows[:len(sample_rows)] = [i for _, i, _, _ in sample_rows]
-        dev = [jnp.asarray(a) for a in (tok, pos, tmask, row_ids, kvl, table,
-                                        take)]
+        self._mark(_UPLOAD)
+        prev = self._unread_tokens()
+        dev = [self._put(a) for a in (tok, pos, tmask, row_ids, kvl, table,
+                                      take)]
         return (entries, sample_rows, Ttot, Rb, Tb,
-                dev + [prev, jnp.asarray(rows)])
+                dev + [prev, self._put(rows)])
 
     # hot_path
     def _sample_unified(self, logits, sample_rows):
@@ -1797,8 +1988,9 @@ class Engine:
         key_pos = np.zeros(Bs, np.int32)
         for n, (_, _, kpos, _) in enumerate(sample_rows):
             key_pos[n] = kpos
-        keys = step_keys(row_keys(seeds, self._sample_base, rids),
-                         jnp.asarray(key_pos))
+        put = self._put
+        keys = step_keys(row_keys(seeds, self._sample_base, rids, put),
+                         put(key_pos))
         if any(r.gstate is not None for r in reqs):
             # Host-side grammar masks (the unified step host-syncs every
             # token anyway, so tabled and table-less grammars both apply
@@ -1807,15 +1999,14 @@ class Engine:
             for n, req in enumerate(reqs):
                 if req.gstate is not None:
                     gm[n] = self._gmask(req.grammar, req.gstate)
-            sel = jnp.where(jnp.asarray(gm), sel, NEG_INF)
-        args = [sel, keys, jnp.asarray(temps), jnp.asarray(ks),
-                jnp.asarray(tps), jnp.asarray(mps)]
+            sel = jnp.where(put(gm), sel, NEG_INF)
+        args = [sel, keys, put(temps), put(ks), put(tps), put(mps)]
         if pen:
-            pmask, oc_base, rep, pres, freq = self._penalty_rows(reqs, Bs)
-            oc = oc_base
+            pen_rows = self._penalty_rows(reqs, Bs)
+            oc = pen_rows[1]
             for n, req in enumerate(reqs):
                 np.add.at(oc[n], np.asarray(req.output, np.int64), 1)
-            args += [pmask, jnp.asarray(oc), rep, pres, freq]
+            args += [put(a) for a in pen_rows]
         return self._get_sampler(pen, lp)(*args)
 
     # ---- prefill ----
@@ -1910,8 +2101,7 @@ class Engine:
             # First sampled token: output is empty except for pre-preemption
             # tokens folded into the prompt (counted as output by
             # _penalty_rows's oc_base).
-            pmask, oc_base, rep, pres, freq = self._penalty_rows(reqs, Bs)
-            args += [pmask, jnp.asarray(oc_base), rep, pres, freq]
+            args += [jnp.asarray(a) for a in self._penalty_rows(reqs, Bs)]
         toks, lps = self._get_sampler(pen, lp)(*args)
         return toks, lps, reqs
 
@@ -1945,18 +2135,19 @@ class Engine:
             self.metrics["sampler_sort_steps"] += steps
 
     def _lora_rows(self, reqs, B: int):
-        """(lora_ids [B] or None): None when no row uses an adapter —
-        callers compile the adapter-free variant in that case."""
+        """(lora_ids [B] on the host, or None): None when no row uses an
+        adapter — callers compile the adapter-free variant in that case."""
         if self.lora_stack is None or not any(r.lora_idx for r in reqs):
             return None
         ids = np.zeros(B, np.int32)
         for i, r in enumerate(reqs):
             ids[i] = r.lora_idx
-        return jnp.asarray(ids)
+        return ids
 
     def _penalty_rows(self, reqs, B: int):
-        """Host-built penalty state: prompt-seen mask, output-count base,
-        and per-row factors. [B, V] is only materialized when some request
+        """Host-built penalty state, on the host: prompt-seen mask,
+        output-count base, and per-row factors (repetition, presence,
+        frequency). [B, V] is only materialized when some request
         in the batch actually uses penalties (callers compile separate
         variants otherwise). A preempted-and-resumed request carries its
         pre-preemption output inside ``prompt`` — those tokens count as
@@ -1978,8 +2169,7 @@ class Engine:
             rep[n], pres[n], freq[n] = (sp.repetition_penalty,
                                         sp.presence_penalty,
                                         sp.frequency_penalty)
-        return (jnp.asarray(pmask), oc_base, jnp.asarray(rep),
-                jnp.asarray(pres), jnp.asarray(freq))
+        return pmask, oc_base, rep, pres, freq
 
     # hot_path
     def _get_sampler(self, pen: bool, lp: bool):
@@ -2231,6 +2421,10 @@ class Engine:
         return fn
 
     def _build_decode_state(self, batch: List[Request]) -> dict:
+        """The fused window's device state for ``batch``: the host arrays
+        first (``engine.pack.fill``), then their puts
+        (``engine.pack.upload``)."""
+        self._mark(_FILL)
         B = self._bucket(len(batch))
         P = self.cfg.max_pages_per_seq
         tok = np.zeros(B, np.int32)
@@ -2245,7 +2439,7 @@ class Engine:
         # that step's unread line: the window takes it from there, and
         # unified -> decode chains with no read between.
         take = np.full(B, -1, np.int32)
-        column, prev = self._unread_tokens()
+        column = self._unread_columns()
         for i, r in enumerate(batch):
             j = column.get(id(r))
             if j is None:
@@ -2258,37 +2452,23 @@ class Engine:
             limit[i] = r.max_len()
             table[i, :len(r.pages)] = r.pages
         lids = self._lora_rows(batch, B)
-        st = {
-            "rows": list(batch), "B": B, "pen": pen, "lp": lp,
-            "sorts": sorts, "lids": lids,
-            "tok": (_place(jnp.asarray(tok), jnp.asarray(take), prev)
-                    if column else jnp.asarray(tok)),
-            "pos": jnp.asarray(pos),
-            "kvl": jnp.asarray(kvl), "mask": jnp.asarray(mask),
-            "limit": jnp.asarray(limit),
-            "temps": jnp.asarray(temps), "ks": jnp.asarray(ks),
-            "tps": jnp.asarray(tps), "mps": jnp.asarray(mps),
-            "keys": row_keys(seeds, self._sample_base, rids),
-            "table_np": table, "table": jnp.asarray(table),
-        }
+        slots = wtable = pen_rows = None
         if self.state is not None:
-            st["slots"] = self._slot_rows(batch, B)
+            slots = self._slot_rows(batch, B)
         if self.window_allocator is not None:
-            st["wtable"] = jnp.asarray(self._window_table(batch, B))
+            wtable = self._window_table(batch, B)
         if pen:
-            pmask, oc, rep, pres, freq = self._penalty_rows(batch, B)
+            pen_rows = self._penalty_rows(batch, B)
             for i, r in enumerate(batch):
-                np.add.at(oc[i], np.asarray(r.output, np.int64), 1)
-            st.update(pmask=pmask, ocounts=jnp.asarray(oc),
-                      rep=rep, pres=pres, freq=freq)
+                np.add.at(pen_rows[1][i], np.asarray(r.output, np.int64), 1)
         gr_rows = [r for r in batch if r.gstate is not None]
-        st["gr"] = bool(gr_rows)
         if gr_rows:
             # Device-resident grammar decode: per-row table-state ids into
             # the stacked [S, V] tables. A rebuild recovers the device
             # state exactly from req.gstate — host bookkeeping (_emit)
             # advances it token-by-token, and every engine gstate is
-            # whole-token-reachable, so the lookup cannot miss.
+            # whole-token-reachable, so the lookup cannot miss. (A table
+            # met for the first time goes up here, once, and stays.)
             gnext, glegal, offsets = self._device_grammar_tables(
                 [r.grammar for r in gr_rows])
             gstate = np.zeros(B, np.int32)
@@ -2298,9 +2478,33 @@ class Engine:
                     t = self._grammar_table(r.grammar)
                     gstate[i] = offsets[id(r.grammar)] + t.state_ids[r.gstate]
                     gactive[i] = True
-            st.update(gnext=gnext, glegal=glegal,
-                      gstate=jnp.asarray(gstate),
-                      gactive=jnp.asarray(gactive))
+
+        self._mark(_UPLOAD)
+        put = self._put
+        prev = self._unread_tokens()
+        st = {
+            "rows": list(batch), "B": B, "pen": pen, "lp": lp,
+            "sorts": sorts, "gr": bool(gr_rows),
+            "lids": put(lids) if lids is not None else None,
+            "tok": _place(put(tok), put(take), prev) if column else put(tok),
+            "pos": put(pos), "kvl": put(kvl), "mask": put(mask),
+            "limit": put(limit),
+            "temps": put(temps), "ks": put(ks),
+            "tps": put(tps), "mps": put(mps),
+            "keys": row_keys(seeds, self._sample_base, rids, put),
+            "table_np": table, "table": put(table),
+        }
+        if slots is not None:
+            st["slots"] = put(slots)
+        if wtable is not None:
+            st["wtable"] = put(wtable)
+        if pen:
+            pmask, oc, rep, pres, freq = pen_rows
+            st.update(pmask=put(pmask), ocounts=put(oc), rep=put(rep),
+                      pres=put(pres), freq=put(freq))
+        if gr_rows:
+            st.update(gnext=gnext, glegal=glegal, gstate=put(gstate),
+                      gactive=put(gactive))
         return st
 
     def _decode_step(self) -> List[StepEvent]:
@@ -2323,13 +2527,13 @@ class Engine:
     # hot_path
     def _fused_decode_step(self) -> List[StepEvent]:
         events: List[StepEvent] = []
-        with _Phase(self, _PACK):
+        with _Phase(self, _PACK, "decode"):
             packed = self._pack_decode(events)
         if packed is None:
             return events
         st, batch, K = packed
 
-        with _Phase(self, _DISPATCH):
+        with _Phase(self, _DISPATCH, "decode"):
             self._note_dispatch("decode", len(batch), len(batch) * K,
                                 st["B"], st["B"] * K)
             self._note_sampler(st["sorts"], K)
@@ -2360,6 +2564,7 @@ class Engine:
                 # On the host by the time the lagged fetch reads it: a
                 # second blocking read a step would cost 0.3 ms of emit.
                 visited.copy_to_host_async()
+            self._mark(_BOOK)
             st["tok"], st["pos"], st["kvl"] = tok, pos, kvl
             if st["pen"]:
                 st["ocounts"] = oc
@@ -2455,24 +2660,31 @@ class Engine:
         if not batch:
             return None
 
+        moved = False
         if self.window_allocator is not None:
             # A row's line moves once in ``page_size`` steps; the table is
             # laid out anew in the steps in which some row's did.
             with trace.annotation(obs_names.SPAN_KV_WINDOW_RELEASE):
-                moved = [self._window_pages_for(
+                moved = any([self._window_pages_for(
                     r, r.seq_len, min(r.seq_len + K, r.max_len()))
-                    for r in batch]
-            if st is not None and any(moved):
-                st["wtable"] = jnp.asarray(
-                    self._window_table(batch, st["B"]))
+                    for r in batch])
         if st is None:
             st = self._dec = self._build_decode_state(batch)
-        elif pages_changed:
-            for i, r in enumerate(batch):
-                row = st["table_np"][i]
-                row[:len(r.pages)] = r.pages
-                row[len(r.pages):] = 0
-            st["table"] = jnp.asarray(st["table_np"])
+        elif moved or pages_changed:
+            # The patch of a batch that did not change: the lines first,
+            # then their puts.
+            self._mark(_FILL)
+            wtable = self._window_table(batch, st["B"]) if moved else None
+            if pages_changed:
+                for i, r in enumerate(batch):
+                    row = st["table_np"][i]
+                    row[:len(r.pages)] = r.pages
+                    row[len(r.pages):] = 0
+            self._mark(_UPLOAD)
+            if moved:
+                st["wtable"] = self._put(wtable)
+            if pages_changed:
+                st["table"] = self._put(st["table_np"])
         return st, batch, K
 
     # ---- speculative decode (prompt-lookup drafting) ----
@@ -2631,13 +2843,14 @@ class Engine:
                 pmask, oc, rep, pres, freq = self._penalty_rows(batch, B)
                 for i, r in enumerate(batch):
                     np.add.at(oc[i], np.asarray(r.output, np.int64), 1)
-                kw.update(pmask=pmask, ocounts=jnp.asarray(oc), rep=rep,
-                          pres=pres, freq=freq)
+                kw.update(pmask=jnp.asarray(pmask), ocounts=jnp.asarray(oc),
+                          rep=jnp.asarray(rep), pres=jnp.asarray(pres),
+                          freq=jnp.asarray(freq))
             if gr:
                 kw["gmasks"] = jnp.asarray(gmasks)
             lids = self._lora_rows(batch, B)
             if lids is not None:
-                kw.update(lora=self.lora_stack, lids=lids)
+                kw.update(lora=self.lora_stack, lids=jnp.asarray(lids))
         with _Phase(self, _DISPATCH):
             self._note_dispatch("spec", len(batch),
                                 int(mask.sum()), B, B * T)
@@ -2860,7 +3073,7 @@ class Engine:
                 kvl[i] = ln
                 table[i, :len(pg)] = pg
             lids = self._lora_rows(reqs, B) if reqs is not None else None
-            kw = ({"lora": self.lora_stack, "lids": lids}
+            kw = ({"lora": self.lora_stack, "lids": jnp.asarray(lids)}
                   if lids is not None else {})
             kw.update(self._state_kw(reqs or [], B))
             dev = [jnp.asarray(a) for a in (tok, pos, mask, kvl, table)]

@@ -958,9 +958,9 @@ class DecodeWorker:
         args = [lg, keys, jnp.asarray(temps), jnp.asarray(ks),
                 jnp.asarray(tps), jnp.asarray(mps)]
         if pen:
-            pmask, oc, rep, pres, freq = eng._penalty_rows([req], B)
-            np.add.at(oc[0], np.asarray(req.output, np.int64), 1)
-            args += [pmask, jnp.asarray(oc), rep, pres, freq]
+            pen_rows = eng._penalty_rows([req], B)
+            np.add.at(pen_rows[1][0], np.asarray(req.output, np.int64), 1)
+            args += [jnp.asarray(a) for a in pen_rows]
         toks, lps = eng._get_sampler(pen, lp)(*args)
         tok_out = int(np.asarray(toks)[0])
         lp_val = (float(np.asarray(lps)[0])

@@ -35,6 +35,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30  # avoid -inf NaN traps in (masked - masked) style arithmetic
 
@@ -165,20 +166,22 @@ def _row_keys(seed_vals: jnp.ndarray, has_seed: jnp.ndarray,
     return jax.random.wrap_key_data(kd)
 
 
-def row_keys(seeds, fallback_key: jax.Array, ids) -> jax.Array:
+def row_keys(seeds, fallback_key: jax.Array, ids,
+             put=jnp.asarray) -> jax.Array:
     """Build a [B] key array: rows with a seed get ``key(seed)`` (stable,
     user-reproducible); rows without get ``fold_in(fallback, request id)``
     (distinct streams per request, stable across decode-state rebuilds).
     One fused dispatch — this runs on every decode-state rebuild, inside
-    the host scheduling path."""
+    the host scheduling path. ``put`` places the three host lines on the
+    device (the engine's step paths pass the put they count)."""
     # Mask into uint32 — wire seeds are arbitrary ints and NumPy 2.x raises
     # OverflowError on out-of-range conversion (a request must never be able
     # to kill the engine loop thread).
-    seed_vals = jnp.asarray(
+    seed_vals = put(np.asarray(
         [((s if s is not None else 0) & 0xFFFFFFFF) for s in seeds],
-        jnp.uint32)
-    has_seed = jnp.asarray([s is not None for s in seeds])
-    rids = jnp.asarray([int(i) & 0xFFFFFFFF for i in ids], jnp.uint32)
+        np.uint32))
+    has_seed = put(np.asarray([s is not None for s in seeds], bool))
+    rids = put(np.asarray([int(i) & 0xFFFFFFFF for i in ids], np.uint32))
     return _row_keys(seed_vals, has_seed, rids, fallback_key)
 
 

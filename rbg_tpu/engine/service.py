@@ -576,8 +576,6 @@ class _BatchService:
         out["max_queue"] = self.max_queue
         out["estimated_wait_s"] = round(est, 4) if est is not None else None
         out["slo_judged_total"] = self.slo.judged_total()
-        pf = self._prefill_rate
-        out["prefill_tokens_per_s"] = round(pf, 2) if pf is not None else None
         out["early_reject_armed"] = self._early_reject
         return out
 
@@ -702,6 +700,7 @@ class _BatchService:
             with trace.annotation(names.SPAN_SERVICE_INTAKE):
                 self._intake(now)
             t = time.monotonic()
+            eng.probe(t, turn_began=now)
             intake = t - now
             tl["t_intake_s"] += intake
             if eng.has_work():
@@ -711,6 +710,7 @@ class _BatchService:
                 with trace.annotation(names.SPAN_SERVICE_DELIVER):
                     self._deliver(events, t)
                 t_end = time.monotonic()
+                eng.probe(t_end)
                 deliver = t_end - t
                 tl["t_deliver_s"] += deliver
                 sync_s = m["t_sync_s"] - phase0[_SYNC]

@@ -590,6 +590,22 @@ SPAN_ENGINE_PACK = "engine.pack"
 SPAN_ENGINE_DISPATCH = "engine.dispatch"
 SPAN_ENGINE_SYNC = "engine.sync"
 SPAN_ENGINE_EMIT = "engine.emit"
+# The six sub-phases that tile ``engine.pack`` and ``engine.dispatch`` of a
+# unified and of a fused decode step (``engine._Phase.mark``): flat, one
+# open at a time, each with a cumulative clock by the kind of step,
+# ``t_unified_<sub>_s`` / ``t_decode_<sub>_s`` with ``<sub>`` the name's last
+# word (a decode step has no ``sample``: the window samples inside its
+# program). Every mark also asks whether the step in flight has finished
+# (``Engine.probe``), which is what ``t_starved_s`` (with its split
+# ``t_starved_between_s`` / ``t_starved_pack_s`` / ``t_starved_dispatch_s``)
+# and ``t_starved_max_s`` are reckoned from; ``uploads`` counts the
+# host-to-device puts of the two step paths (``Engine._put``).
+SPAN_ENGINE_PACK_ROWS = "engine.pack.rows"
+SPAN_ENGINE_PACK_FILL = "engine.pack.fill"
+SPAN_ENGINE_PACK_UPLOAD = "engine.pack.upload"
+SPAN_ENGINE_DISPATCH_CALL = "engine.dispatch.call"
+SPAN_ENGINE_DISPATCH_BOOK = "engine.dispatch.book"
+SPAN_ENGINE_DISPATCH_SAMPLE = "engine.dispatch.sample"
 # A late turn of the loop (zero length, entered when its record is made)
 # and the relay's socket write of one token frame (a connection thread).
 SPAN_ENGINE_LATE_STEP = "engine.late_step"
@@ -727,6 +743,12 @@ SPANS = frozenset({
     SPAN_ENGINE_DISPATCH,
     SPAN_ENGINE_SYNC,
     SPAN_ENGINE_EMIT,
+    SPAN_ENGINE_PACK_ROWS,
+    SPAN_ENGINE_PACK_FILL,
+    SPAN_ENGINE_PACK_UPLOAD,
+    SPAN_ENGINE_DISPATCH_CALL,
+    SPAN_ENGINE_DISPATCH_BOOK,
+    SPAN_ENGINE_DISPATCH_SAMPLE,
     SPAN_ENGINE_LATE_STEP,
     SPAN_SERVER_RELAY_SEND,
     SPAN_KV_WINDOW_RELEASE,

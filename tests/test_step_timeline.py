@@ -29,8 +29,20 @@ from rbg_tpu.obs import names, trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-ENGINE_CLOCKS = ("t_step_s", "t_admit_s", "t_pack_s", "t_dispatch_s",
-                 "t_sync_s", "t_emit_s", "t_unified_s", "t_decode_s")
+# What engine.pack and engine.dispatch are made of, by the kind of step
+# (a decode step has no ``sample``), and the starved-time clocks.
+SUB_CLOCKS = {
+    "unified": ("t_unified_rows_s", "t_unified_fill_s", "t_unified_upload_s",
+                "t_unified_call_s", "t_unified_book_s", "t_unified_sample_s"),
+    "decode": ("t_decode_rows_s", "t_decode_fill_s", "t_decode_upload_s",
+               "t_decode_call_s", "t_decode_book_s")}
+STARVED_SPLIT = ("t_starved_between_s", "t_starved_pack_s",
+                 "t_starved_dispatch_s")
+STARVED_CLOCKS = ("t_starved_s", "t_starved_max_s") + STARVED_SPLIT
+ENGINE_CLOCKS = (("t_step_s", "t_admit_s", "t_pack_s", "t_dispatch_s",
+                  "t_sync_s", "t_emit_s", "t_unified_s", "t_decode_s")
+                 + SUB_CLOCKS["unified"] + SUB_CLOCKS["decode"]
+                 + STARVED_CLOCKS)
 PHASE_CLOCKS = ("t_admit_s", "t_pack_s", "t_dispatch_s", "t_sync_s",
                 "t_emit_s")
 LOOP_CLOCKS = ("t_loop_s", "t_intake_s", "t_deliver_s", "t_idle_s",
@@ -39,12 +51,13 @@ LOOP_CLOCKS = ("t_loop_s", "t_intake_s", "t_deliver_s", "t_idle_s",
 COUNTS = ("steps", "steps_run", "unified_steps_run", "decode_steps_run",
           "queue_waited", "first_tokens", "kv_live_token_steps",
           "kv_held_slot_steps", "late_steps", "device_waited_steps",
-          "gc_collections", "relay_frames", "relay_tokens")
+          "gc_collections", "relay_frames", "relay_tokens", "uploads")
 # Nothing in a service driven in-process has to move these: no idle turn,
-# no collection, and no relay (a connection thread of the server).
+# no collection, and no relay (a connection thread of the server); and a
+# device that was never seen finished early starved for nothing.
 MAY_STAY_ZERO = ("t_idle_s", "gc_pause_s", "gc_collections",
                  "t_relay_send_s", "relay_lag_s", "relay_frames",
-                 "relay_tokens")
+                 "relay_tokens") + STARVED_CLOCKS
 
 # The eleven metric files this timeline brought to the benchmark, and the
 # eight that put a late step down to a cause.
@@ -58,6 +71,14 @@ NEW_METRICS = (
     "engine.host_offcpu_share", "engine.device_waited_share",
     "relay.lag_mean_ms", "relay.tokens_per_frame", "engine.gc_pause_share",
     "relay.send_mean_ms")
+# The seventeen that say what the loop thread does inside engine.pack and
+# engine.dispatch, and how long the device starved for it.
+NEW_METRICS += tuple(
+    f"engine.{clock[2:-2]}_ms" for kind in ("unified", "decode")
+    for clock in SUB_CLOCKS[kind]) + (
+    "engine.uploads_per_step", "engine.starved_ms_per_step",
+    "engine.starved_max_ms_per_step", "engine.starved_between_ms",
+    "engine.starved_in_pack_ms", "engine.starved_in_dispatch_ms")
 
 # Fields of a late-step record (``Engine.note_late``).
 LATE_FIELDS = ("t0", "t_end", "step_num", "kind", "phase", "phase_wall_s",
@@ -462,13 +483,14 @@ def test_late_ring_is_bounded_and_stop_joins_the_watchdog(params):
 
 
 class FakeWindow:
-    """The pending window's token array with a readiness of our choosing."""
+    """The pending window's token array with a readiness of our choosing
+    (a value, or what a call says each time it is asked)."""
 
     def __init__(self, arr, ready):
         self.arr, self.ready = arr, ready
 
     def is_ready(self):
-        return self.ready
+        return self.ready() if callable(self.ready) else self.ready
 
     def __array__(self, *a, **kw):
         return jax.device_get(self.arr)
@@ -494,6 +516,247 @@ def test_device_waited_counts_a_dispatch_with_nothing_left_running(
     assert m["steps_run"] == steps + 1
     assert m["device_waited_steps"] - was == (1 if ready else 0)
     assert m["device_waited_steps"] <= m["steps_run"]
+
+
+# ---- (3d) what pack and dispatch are made of ---------------------------
+
+
+def run_steps(eng, until):
+    """Step ``eng`` until ``until()``: per step that dispatched, (kind,
+    what each counter moved by)."""
+    m = eng.metrics
+    steps = []
+    while not until():
+        was = dict(m)
+        eng.step()
+        if eng._dispatched is not None:
+            steps.append((eng._dispatched[0],
+                          {k: v - was[k] for k, v in m.items()
+                           if isinstance(v, (int, float))}))
+    return steps
+
+
+@pytest.fixture(scope="module")
+def mixed_run(params):
+    """Unified and decode steps of one engine: a prompt of two chunks,
+    its decode steps, and a second prompt that joins them."""
+    eng = Engine(engine_config(), params=params)
+    eng.add_request(list(range(1, 21)), SamplingParams(max_new_tokens=24))
+    m = eng.metrics
+    steps = run_steps(eng, lambda: m["decode_steps_run"] >= 3)
+    eng.add_request(list(range(30, 50)), SamplingParams(max_new_tokens=6))
+    steps += run_steps(eng, lambda: not eng.has_work())
+    return eng, steps
+
+
+@pytest.mark.parametrize("kind", ["unified", "decode"])
+def test_sub_phase_clocks_tile_pack_and_dispatch(mixed_run, kind):
+    eng, steps = mixed_run
+    mine = [d for k, d in steps if k == kind]
+    assert len(mine) >= 3
+    other = "decode" if kind == "unified" else "unified"
+    for d in mine:
+        subs = sum(d[c] for c in SUB_CLOCKS[kind])
+        assert subs == pytest.approx(d["t_pack_s"] + d["t_dispatch_s"],
+                                     abs=1e-9)
+        # A step's pack and dispatch are its own kind's sub-phases alone,
+        # and each of the two phases had one open throughout.
+        assert all(d[c] == 0 for c in SUB_CLOCKS[other])
+        assert d[f"t_{kind}_rows_s"] > 0 and d[f"t_{kind}_call_s"] > 0
+        assert d[f"t_{kind}_book_s"] > 0
+        assert (d["t_unified_sample_s"] > 0) == (kind == "unified")
+    # The state's build and the pack's lines were filled and put.
+    assert sum(d[f"t_{kind}_fill_s"] for d in mine) > 0
+    assert sum(d[f"t_{kind}_upload_s"] for d in mine) > 0
+
+
+def test_the_eleven_sub_phase_clocks_sum_to_the_two_phases(mixed_run):
+    m = mixed_run[0].metrics
+    eleven = SUB_CLOCKS["unified"] + SUB_CLOCKS["decode"]
+    assert len(eleven) == 11
+    assert sum(m[c] for c in eleven) == pytest.approx(
+        m["t_pack_s"] + m["t_dispatch_s"], abs=1e-9)
+
+
+def test_split_path_steps_get_no_marks(params):
+    eng = Engine(engine_config(ragged="off"), params=params)
+    eng.add_request(list(range(1, 21)), SamplingParams(max_new_tokens=3))
+    while eng.has_work():
+        eng.step()
+    m = eng.metrics
+    # The prompt's chunks ran on the split path: their time stays in the
+    # two phases' clocks; the fused windows after them are marked.
+    assert all(m[c] == 0 for c in SUB_CLOCKS["unified"])
+    assert 0 < sum(m[c] for c in SUB_CLOCKS["decode"]) \
+        < m["t_pack_s"] + m["t_dispatch_s"]
+
+
+# ---- (3e) how long the device starved ------------------------------------
+
+NAP_S = 0.02
+
+
+def engine_before_a_step_of(params, kind):
+    """An engine with a step in flight whose next step is of ``kind``."""
+    eng = Engine(engine_config(), params=params)
+    eng.add_request([3, 1, 4, 1, 5], SamplingParams(max_new_tokens=40))
+    while eng.metrics["decode_steps_run"] < 3:
+        eng.step()
+    if kind == "unified":
+        eng.add_request([2, 7, 1, 8, 2, 8], SamplingParams(max_new_tokens=4))
+    return eng
+
+
+def starved_by_one_step(eng, kind, ready):
+    """What the starved clocks moved by over one step taken ``NAP_S`` after
+    the loop last looked at the step in flight (as it does at the end of
+    ``service.deliver``), whose tokens are ready as ``ready()`` says."""
+    m = eng.metrics
+    eng._pending = eng._pending._replace(
+        toks=FakeWindow(eng._pending.toks, ready))
+    was = {c: m[c] for c in STARVED_CLOCKS}
+    eng.probe(time.monotonic())
+    time.sleep(NAP_S)
+    eng.step()
+    assert eng.step_ring[-1][6] == kind
+    return {c: m[c] - was[c] for c in STARVED_CLOCKS}
+
+
+@pytest.mark.parametrize("kind", ["unified", "decode"])
+def test_a_step_in_flight_seen_finished_starves_the_device_until_the_next(
+        params, kind):
+    eng = engine_before_a_step_of(params, kind)
+    d = starved_by_one_step(eng, kind, True)
+    # Seen finished before the nap: all of the nap is the device's idle
+    # time, and it passed before the pack began.
+    assert d["t_starved_s"] >= NAP_S and d["t_starved_between_s"] >= NAP_S
+    assert d["t_starved_pack_s"] > 0 and d["t_starved_dispatch_s"] > 0
+    assert sum(d[c] for c in STARVED_SPLIT) == pytest.approx(
+        d["t_starved_s"], abs=1e-9)
+    assert d["t_starved_s"] <= d["t_starved_max_s"] + 1e-9
+
+
+@pytest.mark.parametrize("kind", ["unified", "decode"])
+def test_a_step_still_running_at_the_next_call_starves_nothing(params, kind):
+    eng = engine_before_a_step_of(params, kind)
+    d = starved_by_one_step(eng, kind, False)
+    # The probe at the call's return found it running: the program was
+    # queued behind it, whatever the host took.
+    assert all(v == 0 for v in d.values()), d
+
+
+@pytest.mark.parametrize("kind", ["unified", "decode"])
+def test_a_step_that_finished_between_two_probes_moves_the_upper_bound_alone(
+        params, kind):
+    """Running at the entry of engine.dispatch, finished when the program's
+    call returned: the device idled for some of the call or none of it."""
+    eng = engine_before_a_step_of(params, kind)
+    done = []
+    real = eng._put_pools
+    eng._put_pools = lambda *a, **kw: (done.append(1), real(*a, **kw))[1]
+    t_was = eng.metrics[f"t_{kind}_call_s"]
+    d = starved_by_one_step(eng, kind, lambda: bool(done))
+    assert d["t_starved_s"] == 0 and all(d[c] == 0 for c in STARVED_SPLIT)
+    # One probe interval: no more than the call sub-phase.
+    assert 0 < d["t_starved_max_s"] <= (eng.metrics[f"t_{kind}_call_s"]
+                                        - t_was) + 1e-9
+
+
+@pytest.mark.parametrize("kind", ["unified", "decode"])
+def test_starved_parts_sum_to_the_lower_bound_under_the_upper(mixed_run,
+                                                              kind):
+    eng, steps = mixed_run
+    for k, d in steps:
+        if k == kind:
+            assert all(d[c] >= 0 for c in STARVED_CLOCKS), d
+            assert sum(d[c] for c in STARVED_SPLIT) == pytest.approx(
+                d["t_starved_s"], abs=1e-9)
+            assert d["t_starved_s"] <= d["t_starved_max_s"] + 1e-9
+
+
+def test_with_nothing_in_flight_both_bounds_run_from_the_read_that_emptied(
+        params):
+    eng = Engine(engine_config(), params=params)
+    m = eng.metrics
+    eng.add_request([3, 1, 4], SamplingParams(max_new_tokens=3))
+    while eng.has_work():
+        eng.step()
+    # The first step of all had nothing before it: no starved time. The
+    # request's last read emptied the pipeline, and the next request's
+    # first step comes a nap after it.
+    assert eng._pending is None
+    was = {c: m[c] for c in STARVED_CLOCKS}
+    time.sleep(NAP_S)
+    eng.add_request([2, 7, 1], SamplingParams(max_new_tokens=2))
+    eng.step()
+    d = {c: m[c] - was[c] for c in STARVED_CLOCKS}
+    assert d["t_starved_s"] == d["t_starved_max_s"] >= NAP_S
+    assert d["t_starved_between_s"] >= NAP_S
+
+
+def test_a_service_that_idled_adds_none_of_the_idle(params):
+    idle_s = 0.6
+    s = warmed(params)
+    try:
+        before = s.stats()
+        time.sleep(idle_s)
+        s.submit(*REQUEST)
+        wait_for_turn(s)
+        after = s.stats()
+        assert after["t_idle_s"] - before["t_idle_s"] > idle_s / 2
+        assert after["steps_run"] > before["steps_run"]
+        d = {c: after[c] - before[c] for c in STARVED_CLOCKS}
+        assert 0 <= d["t_starved_s"] <= d["t_starved_max_s"] < idle_s / 2, d
+        assert sum(d[c] for c in STARVED_SPLIT) == pytest.approx(
+            d["t_starved_s"], abs=1e-9)
+    finally:
+        s.stop()
+
+
+# ---- (3f) the puts a step makes -------------------------------------------
+
+
+class CountingJnp:
+    """``jax.numpy`` with its ``asarray`` counted."""
+
+    def __init__(self, real):
+        self.real, self.puts = real, 0
+
+    def __getattr__(self, name):
+        return getattr(self.real, name)
+
+    def asarray(self, *a, **kw):
+        self.puts += 1
+        return self.real.asarray(*a, **kw)
+
+
+@pytest.mark.parametrize("kind", ["unified", "decode"])
+def test_uploads_counts_every_put_of_a_step_and_the_helper_makes_them_all(
+        params, kind, monkeypatch):
+    eng = engine_before_a_step_of(params, kind)
+    m = eng.metrics
+    counted = CountingJnp(engine_mod.jnp)
+    monkeypatch.setattr(engine_mod, "jnp", counted)
+    helper = []
+    real = eng._put
+    eng._put = lambda a: (helper.append(a.shape), real(a))[1]
+    per_step = []
+    for _ in range(8 if kind == "decode" else 1):
+        was, puts, made = m["uploads"], counted.puts, len(helper)
+        eng.step()
+        assert eng.step_ring[-1][6] == kind
+        # No put of the step went around the helper, the sampler's key
+        # lines included.
+        assert m["uploads"] - was == len(helper) - made == counted.puts - puts
+        per_step.append(m["uploads"] - was)
+    if kind == "unified":
+        # The seven packed lines, the head's rows, the key positions, the
+        # three lines of the rows' keys and the four sampling lines.
+        assert per_step == [16]
+    else:
+        # A batch that did not change puts its page table when a row took
+        # a page, and nothing else.
+        assert set(per_step) == {0, 1} and per_step.count(0) >= 4
 
 
 # ---- (4) the ring ------------------------------------------------------
@@ -595,12 +858,28 @@ def test_every_phase_annotation_is_cataloged():
             names.SPAN_SERVICE_IDLE, names.SPAN_ENGINE_STEP,
             names.SPAN_ENGINE_LATE_STEP, names.SPAN_SERVER_RELAY_SEND}
     assert phases | loop <= names.SPANS
+    subs = engine_mod._Phase.SUB_SPANS
+    assert subs == (names.SPAN_ENGINE_PACK_ROWS, names.SPAN_ENGINE_PACK_FILL,
+                    names.SPAN_ENGINE_PACK_UPLOAD,
+                    names.SPAN_ENGINE_DISPATCH_CALL,
+                    names.SPAN_ENGINE_DISPATCH_BOOK,
+                    names.SPAN_ENGINE_DISPATCH_SAMPLE)
+    # Each sub-phase is named under its phase, and has a clock a kind of
+    # step that runs it.
+    assert [s.rsplit(".", 1)[0] for s in subs] == (
+        [names.SPAN_ENGINE_PACK] * 3 + [names.SPAN_ENGINE_DISPATCH] * 3)
+    assert engine_mod._Phase.SUB_CLOCKS == SUB_CLOCKS
+    for kind, clocks in SUB_CLOCKS.items():
+        assert [c[len(f"t_{kind}_"):-2] for c in clocks] == [
+            s.rsplit(".", 1)[1] for s in subs[:len(clocks)]]
     assert {n for n in names.SPANS
             if n.startswith("engine.") and n != names.SPAN_ENGINE_OP} \
-        == phases | {names.SPAN_ENGINE_STEP, names.SPAN_ENGINE_LATE_STEP}
+        == phases | set(subs) | {names.SPAN_ENGINE_STEP,
+                                 names.SPAN_ENGINE_LATE_STEP}
     # The host phases a late-step record weighs: all but sync and idle.
     assert service_mod._LATE_PHASES == service_mod._HOST_PHASES + (
         names.SPAN_ENGINE_SYNC,)
+    assert not set(subs) & set(service_mod._LATE_PHASES)
     assert set(service_mod._HOST_PHASES) == (phases | loop) - {
         names.SPAN_ENGINE_SYNC, names.SPAN_SERVICE_IDLE,
         names.SPAN_ENGINE_STEP, names.SPAN_ENGINE_LATE_STEP,

@@ -2999,11 +2999,12 @@ class Engine:
     def _decode_walk_kernel_copies(self) -> int:
         """How many of a step's decode walks copy their own pages inside
         the kernel (``ops/pallas/page_walk.kernel_copies``, read off the
-        pools' shapes and dtypes as the step programs hand them over): an
-        entry of the leading axis of each class's K/V pools, where this
-        process runs the kernels at all. 0: every walk takes its pages
-        from the pipeline (packed heads, int8 pools, latents, the XLA
-        forms)."""
+        pools' shapes and dtypes as the step programs hand them over: a
+        latent model's pair without its singleton axis,
+        ``page_walk.latent_pools``): an entry of the leading axis of each
+        class's pools, where this process runs the kernels at all. 0:
+        every walk takes its pages from the pipeline (int8 pools, float32
+        pools, heads under a lane tile, the XLA forms)."""
         from rbg_tpu.ops import pallas
         from rbg_tpu.ops.pallas import page_walk
         if not pallas.takes_kernel(self.cfg.use_pallas):
@@ -3013,10 +3014,17 @@ class Engine:
                     cache.v_scales)]
         if cache.window_k is not None:
             classes.append((cache.window_k, cache.window_v))
-        return sum(
-            pools[0].shape[0] for pools in classes if page_walk.kernel_copies(
-                [jax.ShapeDtypeStruct(p.shape[1:], p.dtype)
-                 for p in pools if p is not None]))
+
+        def as_handed_over(pools):
+            shapes = [jax.ShapeDtypeStruct(p.shape[1:], p.dtype)
+                      for p in pools if p is not None]
+            if self.mcfg.mla:
+                shapes[:2] = jax.eval_shape(page_walk.latent_pools,
+                                            *shapes[:2])
+            return shapes
+
+        return sum(pools[0].shape[0] for pools in classes
+                   if page_walk.kernel_copies(as_handed_over(pools)))
 
     # bucket_fn
     def _bucket(self, n: int) -> int:

@@ -47,23 +47,32 @@ them to arrive, chosen by what the pool's shape and dtype say
   its start and its wait on the scalar core, and since ``walk_items`` a
   block's time is those: 0.30-0.42 us a grid step and 0.10-0.14 us a page
   where a page's 64 KB of K and V are 0.08 us of HBM time (PERF.md, PRs
-  40, 46). Every pool can take this path, and the ragged kernels, the
-  latent kernels, the int8 pools with their scales and the pools of
-  packed heads do.
-* THE KERNEL'S OWN COPIES. The pools stay in HBM, the page table and the
-  rows' lengths are the scalar operands, and the kernel walks a row's
-  blocks in a loop of its own: for a block it starts one copy a page a
-  pool into consecutive slots of ONE buffer (``start_block_copies``: the
+  40, 46). Every pool can take this path. The ragged kernels and
+  ``_kda_decode_call`` take no other; of the decode kernels' pools it is
+  left with those the other path refuses: int8 pools with their scales
+  (K/V and latent: four pools), float32 pools, and a last dim under a
+  lane tile (heads of 64 unpacked, the tests' small presets). No cell of
+  the benchmark serves one of these.
+* THE KERNEL'S OWN COPIES (``walk_with_copies``, one loop under both
+  decode kernels). The pools stay in HBM, the page table and the rows'
+  lengths are the scalar operands, and the kernel walks a row's blocks
+  in a loop of its own: for a block it starts one copy a page a pool
+  into consecutive slots of ONE buffer (``start_block_copies``: the
   block arrives joined), the block after it, or the next live row's
   first, before it waits for this one (``wait_block_copies``: one wait a
   pool) and attends. No grid step a block, no operand a page, no item
   table and nothing beside the kernel in XLA. Mosaic takes such a copy
-  only where a page's trailing dims ``(KV, hd)`` are whole tiles: bf16
-  pools of KV 8 or 16 and hd 128 (Mixtral's, Solar's GQA layers', both
-  kinds of Laguna's, Ouro's). PR 25 wrote this first and dropped it
-  untimed, because the pools of its day (hd 64, KV 2, scales, latents)
-  were refused; PR 51 brought it back for the decode kernel of the pools
-  that are not.
+  only where a page is whole tiles in the pool and in the buffer, which
+  ``kernel_copies`` reads off the pools: two bf16 pools whose last dim is
+  whole lane tiles, K/V pools of 8 or 16 heads of 128 (Mixtral's, Solar's
+  GQA layers', both kinds of Laguna's, Ouro's), of 4 or 2 (heads of 64
+  packed two to a lane tile: LFM2's ``[NP, 16, 4, 128]``), and the latent
+  pools ``[NP, 16, 512]`` / ``[NP, 16, 128]`` (JoyAI's, Kimi's). PR 25
+  wrote this first and dropped it untimed, because the pools of its day
+  (hd 64, KV 2, scales, the rotary key 64 wide) were refused; PR 51
+  brought it back for the GQA kernel's whole-tile pools (a block 1.4 ->
+  1.05 us), PR 53 for the packed and the latent pools (1.31 -> 0.95 and
+  1.20 -> 0.82 us a block; PERF.md).
 
 A per-step cost is paid once for ``pages_per_block·page`` slots either
 way.
@@ -283,16 +292,31 @@ def block_specs(pools, page_id, n: int = None):
 def kernel_copies(pools) -> bool:
     """Whether a decode kernel may copy ``pools``' pages itself
     (``start_block_copies``) or has to take them from the pipeline
-    (``block_specs``): read off the pools, set nowhere. Mosaic slices a
-    page ``[page, KV, hd]`` out of a pool in HBM and lands it at an offset
-    of a VMEM buffer only where the page's trailing dims are whole tiles:
-    bf16 K and V pools of ``KV`` a multiple of 8 and ``hd`` of 128, with no
-    scales beside them (two pools, not four). Heads packed two to a lane
-    tile (``[NP, page, 4, 128]``), int8 pools with their ``[page, KV]``
-    scales and the tests' small heads fall on the pipeline's side."""
-    return len(pools) == 2 and all(
-        p.ndim == 4 and p.dtype == jnp.bfloat16
-        and p.shape[2] % 8 == 0 and p.shape[3] % 128 == 0 for p in pools)
+    (``block_specs``): read off the pools as a kernel receives them, set
+    nowhere. Mosaic slices a page out of a pool in HBM and lands it in
+    ``page`` of a VMEM buffer's slots only where the slice is whole tiles
+    on both sides (``tests/test_chip_compile.py`` compiles each for a
+    described v5e): two bf16 pools, no scales beside them, whose last dim
+    is a multiple of a lane tile (128) and whose dim before it is
+
+    * for K/V pools ``[NP, page, KV, hd]`` the heads, a dim the page and
+      the buffer share whole: a multiple of 8, or 2 or 4 (a smaller tile;
+      heads of 64 packed two to a lane tile come as ``KV / 2`` heads of
+      128: LFM2's ``[NP, 16, 4, 128]``). 1, 6 or 12 heads are refused;
+    * for the latent pools ``[NP, page, d]`` (``latent_pools``) the page,
+      a slice of the buffer's slots: a multiple of 8.
+
+    float32 pools, int8 pools with their ``[page, KV]`` or ``[page, 1]``
+    scales (four pools) and a last dim under a lane tile (heads of 64
+    unpacked, the tests' small presets) fall on the pipeline's side."""
+
+    def whole_tiles(p):
+        if p.dtype != jnp.bfloat16 or p.ndim not in (3, 4) \
+                or p.shape[-1] % 128:
+            return False
+        return p.shape[-2] % 8 == 0 or (p.ndim == 4 and p.shape[-2] in (2, 4))
+
+    return len(pools) == 2 and all(whole_tiles(p) for p in pools)
 
 
 def start_block_copies(pools, bufs, sems, table_ref, row, block,
@@ -331,6 +355,76 @@ def wait_block_copies(bufs, sems, half):
     for i, buf in enumerate(bufs):
         pltpu.make_async_copy(buf.at[half], buf.at[half],
                               sems.at[i, half]).wait()
+
+
+def walk_with_copies(pools, bufs, sems, table_ref, kv_lens_ref, out_ref,
+                     m_ref, l_ref, acc_ref, attend, window=None):
+    """The decode walk of a kernel that issues its own page copies, one
+    program over every row of ``out_ref [B, ...]``: the rows in a loop, a
+    row's live blocks in a loop inside it, block ``i + 1``'s pages (at a
+    row's end the next live row's first block's) started before block ``i``
+    is waited for and attended, in the two halves of ``bufs`` (a pool's
+    ``[2, n·page, ...]``), one semaphore a pool a half (``sems [pools, 2]``).
+    ``attend(b, half, block, kv_len)`` updates the softmax state from the
+    block that has landed in ``half``; the state is initialised before a
+    row's first block and finalised into ``out_ref[b]`` after its last (an
+    empty row attended nothing and writes zeros). ``window``: a window
+    layer's walk starts at the block that holds token ``kv_len - window``.
+    The blocks and their order are the pipeline's (``live_block_starts``,
+    ``first_live_block``)."""
+    B = out_ref.shape[0]
+    page = pools[0].shape[1]
+    n = bufs[0].shape[1] // page
+    slots = n * page
+
+    def span(b):
+        """Row ``b``'s length and its walk's first block and end."""
+        kv_len = kv_lens_ref[b]
+        first = _I32(0) if window is None else lax.div(
+            lax.max(lax.sub(kv_len, _I32(window)), _I32(0)), _I32(slots))
+        return kv_len, first, lax.div(lax.add(kv_len, _I32(slots - 1)),
+                                      _I32(slots))
+
+    def start(b, block, kv_len, half):
+        start_block_copies(pools, bufs, sems, table_ref, b, block, kv_len,
+                           half, n)
+
+    def row(b, carry):
+        half, on_its_way = carry
+        kv_len, first, end = span(b)
+        after = lax.min(lax.add(b, _I32(1)), _I32(B - 1))
+        after_len, after_first, after_end = span(after)
+        after_live = lax.bitwise_and(lax.lt(lax.add(b, _I32(1)), _I32(B)),
+                                     lax.lt(after_first, after_end))
+        live = lax.lt(first, end)
+        init_softmax(m_ref, l_ref, acc_ref)
+
+        # The call's first live row, or one after an empty row.
+        @pl.when(lax.bitwise_and(live, lax.eq(on_its_way, _I32(0))))
+        def _first():
+            start(b, first, kv_len, half)
+
+        def one_block(block, half):
+            ahead = lax.add(block, _I32(1))
+            more = lax.lt(ahead, end)
+            other = lax.sub(_I32(1), half)
+
+            @pl.when(lax.bitwise_or(more, after_live))
+            def _ahead():
+                start(lax.select(more, b, after),
+                      lax.select(more, ahead, after_first),
+                      lax.select(more, kv_len, after_len), other)
+
+            wait_block_copies(bufs, sems, half)
+            attend(b, half, block, kv_len)
+            return other
+
+        half = lax.fori_loop(first, end, one_block, half)
+        out_ref[b] = finalize_softmax(l_ref, acc_ref, out_ref.dtype)
+        return half, lax.convert_element_type(
+            lax.bitwise_and(live, after_live), jnp.int32)
+
+    lax.fori_loop(0, B, row, (_I32(0), _I32(0)))
 
 
 def latent_pools(c_pages, pe_pages):

@@ -26,14 +26,22 @@ Design (see /opt/skills/guides/pallas_guide.md and ``page_walk.py``):
 * a block is ``page_walk.decode_pages_per_block`` pages (128 slots), twice
   the ragged kernels': with maps that cost nothing to trace the width is
   the kernel's to choose.
-* bf16 K/V pools whose pages are whole tiles take neither the grid nor
-  the item table (``page_walk.kernel_copies``, read off the pools; PR 51):
-  ``_decode_copies_kernel`` is one program over every row, the pools left
-  in HBM, that walks a row's live blocks in a loop and issues each block's
-  page copies itself into one double buffer, the next block's before this
-  one is attended. Same blocks, same order, same mathematics: its outputs
-  are the pipeline's to the bit. Packed heads, int8 pools and the latent
-  kernels keep the pipeline.
+* bf16 pools whose pages are whole tiles take neither the grid nor the
+  item table (``page_walk.kernel_copies``, read off the pools; PRs 51, 53):
+  ``_decode_copies_kernel`` and ``_mla_decode_copies_kernel`` are one
+  program over every row, the pools left in HBM, that walk a row's live
+  blocks in a loop and issue each block's page copies themselves into
+  one double buffer, the next block's before this one is attended
+  (``page_walk.walk_with_copies``, the one loop under both). Same blocks,
+  same order, same mathematics: their outputs are the pipeline's to the
+  bit. Every cell of the benchmark walks this way: K/V pools of 8 or 16
+  heads of 128, LFM2's heads of 64 packed two to a lane tile, JoyAI's and
+  Kimi's latents. THE GRID KERNELS BELOW (``_decode_kernel``,
+  ``_mla_decode_kernel``, ``_walk``) now serve only what that path
+  refuses: int8 pools with their scales, float32 pools, and heads under a
+  lane tile (hd 64 unpacked, the tests' small presets). They stay for
+  those (ROADMAP S2 (b) and the int8 configurations need them), and the
+  tests hold the two paths to the same bits.
 * GQA via one batched dot per block: [KV, G, hd] × [KV, n·page, hd].
 * A row of length 0 (a free slot of the batch) keeps one step that
   attends nothing and writes zeros.
@@ -153,102 +161,53 @@ def _decode_copies_kernel(
     window=None,
 ):
     """``_decode_kernel``'s walk with the copies issued here
-    (``page_walk.start_block_copies``): the rows in a loop, a row's live blocks
-    in a loop inside it, block ``i + 1``'s pages (at a row's end the next
-    live row's first block's) started before block ``i`` is waited for and
-    attended. Same blocks, same order, same ``gqa_attend``."""
-    B = q_ref.shape[0]
-    page = k_hbm.shape[1]
-    n = k_buf.shape[1] // page
-    slots = n * page
+    (``page_walk.walk_with_copies``): same blocks, same order, same
+    ``gqa_attend``."""
+    slots = k_buf.shape[1]
 
-    def span(b):
-        """Row ``b``'s length and its walk's first block and end."""
-        kv_len = kv_lens_ref[b]
-        first = _I32(0) if window is None else lax.div(
-            lax.max(lax.sub(kv_len, _I32(window)), _I32(0)), _I32(slots))
-        return kv_len, first, lax.div(lax.add(kv_len, _I32(slots - 1)),
-                                      _I32(slots))
+    def attend(b, half, block, kv_len):
+        W.gqa_attend(q_ref[b], k_buf[half], v_buf[half], None, None,
+                     lax.mul(block, _I32(slots)), kv_len, m_ref, l_ref,
+                     acc_ref, head_dim,
+                     None if window is None else kv_len - window)
 
-    def start(b, block, kv_len, half):
-        W.start_block_copies((k_hbm, v_hbm), (k_buf, v_buf), sems, table_ref,
-                             b, block, kv_len, half, n)
-
-    def row(b, carry):
-        half, on_its_way = carry
-        kv_len, first, end = span(b)
-        after = lax.min(lax.add(b, _I32(1)), _I32(B - 1))
-        after_len, after_first, after_end = span(after)
-        after_live = lax.bitwise_and(lax.lt(lax.add(b, _I32(1)), _I32(B)),
-                                     lax.lt(after_first, after_end))
-        live = lax.lt(first, end)
-        W.init_softmax(m_ref, l_ref, acc_ref)
-
-        # The call's first live row, or one after an empty row.
-        @pl.when(lax.bitwise_and(live, lax.eq(on_its_way, _I32(0))))
-        def _first():
-            start(b, first, kv_len, half)
-
-        def attend(block, half):
-            ahead = lax.add(block, _I32(1))
-            more = lax.lt(ahead, end)
-            other = lax.sub(_I32(1), half)
-
-            @pl.when(lax.bitwise_or(more, after_live))
-            def _ahead():
-                start(lax.select(more, b, after),
-                      lax.select(more, ahead, after_first),
-                      lax.select(more, kv_len, after_len), other)
-
-            W.wait_block_copies((k_buf, v_buf), sems, half)
-            W.gqa_attend(q_ref[b], k_buf[half], v_buf[half], None, None,
-                         lax.mul(block, _I32(slots)), kv_len, m_ref, l_ref,
-                         acc_ref, head_dim,
-                         None if window is None else kv_len - window)
-            return other
-
-        half = lax.fori_loop(first, end, attend, half)
-        # An empty row attended nothing and writes zeros.
-        out_ref[b] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
-        return half, lax.convert_element_type(
-            lax.bitwise_and(live, after_live), jnp.int32)
-
-    lax.fori_loop(0, B, row, (_I32(0), _I32(0)))
+    W.walk_with_copies((k_hbm, v_hbm), (k_buf, v_buf), sems, table_ref,
+                       kv_lens_ref, out_ref, m_ref, l_ref, acc_ref, attend,
+                       window)
 
 
-def _decode_copies(q, pools, page_table, kv_lens, interpret, head_dim,
-                   window):
-    """``_decode`` for pools the kernel may copy from itself
+def _copies_call(kernel, queries, pools, page_table, kv_lens, scratch,
+                 interpret):
+    """One program over every row for pools a kernel may copy from itself
     (``page_walk.kernel_copies``): the page table and the lengths are the
-    scalar operands, the pools stay in HBM, and nothing runs beside the
-    kernel in XLA."""
-    B, KV, G, hd = q.shape
+    scalar operands, ``queries`` (and the output, the first's shape) are
+    whole in VMEM, the pools stay in HBM with a double buffer a pool and
+    one semaphore a pool a half, and nothing runs beside the kernel in
+    XLA. ``scratch``: the softmax state's shapes."""
     page = pools[0].shape[1]
     n = W.decode_pages_per_block(page)
-    whole = pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0, 0))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda i, *_: (0,) * a.ndim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(1,),
-        in_specs=[whole] + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
-        out_specs=whole,
+        in_specs=[whole(q) for q in queries]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=whole(queries[0]),
         scratch_shapes=[
-            pltpu.VMEM((2, n * page, KV, hd), pools[0].dtype),
-            pltpu.VMEM((2, n * page, KV, hd), pools[1].dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
+            *(pltpu.VMEM((2, n * page) + p.shape[2:], p.dtype)
+              for p in pools),
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            *(pltpu.VMEM(shape, jnp.float32) for shape in scratch),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_decode_copies_kernel, head_dim=head_dim,
-                          window=window),
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(queries[0].shape, queries[0].dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, kv_lens, q, *pools)
+    )(page_table, kv_lens, *queries, *pools)
 
 
 def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None,
@@ -258,10 +217,13 @@ def _decode(q, pools, page_table, kv_lens, interpret, head_dim=None,
     ``head_dim``: a head's size where the pool keeps several side by side
     and ``q`` is ``page_walk.pack_queries``'. ``window``: a window layer's
     width (``_walk``)."""
-    if W.kernel_copies(pools):
-        return _decode_copies(q, pools, page_table, kv_lens, interpret,
-                              head_dim, window)
     B, KV, G, hd = q.shape
+    if W.kernel_copies(pools):
+        return _copies_call(
+            functools.partial(_decode_copies_kernel, head_dim=head_dim,
+                              window=window),
+            (q,), pools, page_table, kv_lens,
+            ((KV, G, 1), (KV, G, 1), (KV, G, hd)), interpret)
     page = pools[0].shape[1]
     n = W.decode_pages_per_block(page)
     walk = _walk(page_table, kv_lens, page, n, window)
@@ -421,6 +383,35 @@ def _mla_decode_kernel(
         out_ref[0] = W.finalize_softmax(l_ref, acc_ref, out_ref.dtype)
 
 
+def _mla_decode_copies_kernel(
+    # scalar prefetch
+    table_ref,        # [B, P] int32 (SMEM) — the page table, a line a row
+    kv_lens_ref,      # [B] int32 (SMEM)
+    # operands
+    ql_ref,           # [B, H, dc] (VMEM), every row
+    qp_ref,           # [B, H, dr]
+    c_hbm, pe_hbm,    # [NP, page, dc], [NP, page, 128] (HBM): the pools
+    out_ref,          # [B, H, dc] (VMEM)
+    # scratch
+    c_buf, pe_buf,    # [2, n·page, dc], [2, n·page, 128]
+    sems,             # DMA semaphores [2 pools, 2 halves]
+    m_ref, l_ref, acc_ref,    # as ``_mla_decode_kernel``'s
+    scale: float,
+):
+    """``_mla_decode_kernel``'s walk with the copies issued here
+    (``page_walk.walk_with_copies``): same blocks, same order, same
+    ``mla_attend``."""
+    slots = c_buf.shape[1]
+
+    def attend(b, half, block, kv_len):
+        W.mla_attend(ql_ref[b], qp_ref[b], c_buf[half], pe_buf[half], None,
+                     None, lax.mul(block, _I32(slots)), kv_len, scale,
+                     m_ref, l_ref, acc_ref)
+
+    W.walk_with_copies((c_hbm, pe_hbm), (c_buf, pe_buf), sems, table_ref,
+                       kv_lens_ref, out_ref, m_ref, l_ref, acc_ref, attend)
+
+
 def _mla_decode(q_lat, q_pe, pools, page_table, kv_lens, scale, interpret):
     """q_lat: [B, H, dc], q_pe: [B, H, dr]; pools: c pages
     [NP, page, 1, dc], pe pages [NP, page, 1, dr rounded up to 128], and
@@ -428,6 +419,11 @@ def _mla_decode(q_lat, q_pe, pools, page_table, kv_lens, scale, interpret):
     Returns the latent attention output [B, H, dc]."""
     pools = W.latent_pools(*pools[:2]) + tuple(pools[2:])
     B, H, dc = q_lat.shape
+    if W.kernel_copies(pools):
+        return _copies_call(
+            functools.partial(_mla_decode_copies_kernel, scale=scale),
+            (q_lat, q_pe), pools, page_table, kv_lens,
+            ((H, 1), (H, 1), (H, dc)), interpret)
     dr = q_pe.shape[-1]
     page = pools[0].shape[1]
     n = W.decode_pages_per_block(page)

@@ -10,9 +10,11 @@ change's, in one call:
 Each family is one jitted call as a step program makes it (``_decode_call``
 at ``lfm2.longgen32``'s, ``mixtral.longgen``'s, ``solar-open2.longgen32``'s
 and ``ouro.longgen4``'s shapes and at those of ``laguna-xs2.longgen32``'s
-two kinds of layer, ``_mla_decode_call`` at ``joyai.longgen16``'s) over rows
-of 128-2048 cached tokens (Ouro's 128-1280) drawn from ``--seed``; ``path``
-says who copied the pages (``page_walk.kernel_copies``). A profile of 50
+two kinds of layer, ``_mla_decode_call`` at ``joyai.longgen16``'s and
+``kimi-linear.longgen16``'s) over rows of 128-2048 cached tokens (Ouro's
+128-1280) drawn from ``--seed``; ``path`` says who copied the pages
+(``page_walk.kernel_copies``, asked about the pools as the kernel receives
+them; ``--cells`` picks families). A profile of 50
 calls gives the device time of the kernel and of the XLA operations beside
 it (the walk's block counts and, since PR 40, its item table) a call, and
 from the rows' live blocks the time a block. It fails without a TPU:
@@ -47,15 +49,18 @@ class Cell(NamedTuple):
     layer: int                  # the one walked
     gqa: Optional[tuple] = None     # (KV, G, hd) of a whole-tile GQA pool
     window: Optional[int] = None    # a window layer's width
+    latent: Optional[int] = None    # heads over the latent pools
 
 
 CELLS = {"lfm2": Cell(32, 256, 8192, 10, 3),
-         "joyai": Cell(16, 256, 8192, 5, 2),
+         "joyai": Cell(16, 256, 8192, 5, 2, latent=32),
          "mixtral": Cell(8, 512, 8192, 3, 1, (8, 4, 128)),
          "laguna-window": Cell(32, 256, 8192, 4, 2, (8, 8, 128), 512),
          "laguna-full": Cell(32, 256, 8192, 5, 2, (8, 6, 128)),
          "solar": Cell(32, 256, 8192, 2, 1, (8, 8, 128)),
-         "ouro": Cell(4, 80, 320, 24, 13, (16, 1, 128))}
+         "ouro": Cell(4, 80, 320, 24, 13, (16, 1, 128)),
+         # (last: a family's rows are drawn from the seed plus its place)
+         "kimi": Cell(16, 256, 4096, 7, 3, latent=32)}
 TRACED_CALLS = 50
 
 
@@ -65,13 +70,13 @@ def _call(name, key):
     B, keys = cell.rows, jax.random.split(key, 4)
     pool = lambda k, *tail: jax.random.normal(
         k, (cell.layers * cell.pages, PAGE) + tail, BF16)
-    if name == "joyai":
-        ql = jax.random.normal(keys[0], (B, 32, 512), BF16)
-        qp = jax.random.normal(keys[1], (B, 32, 64), BF16)
+    if cell.latent:
+        ql = jax.random.normal(keys[0], (B, cell.latent, 512), BF16)
+        qp = jax.random.normal(keys[1], (B, cell.latent, 64), BF16)
         c, pe = pool(keys[2], 1, 512), pool(keys[3], 1, 128)
         return (lambda t, n: K._mla_decode_call(ql, qp, c, pe, t, n,
                                                 scale=576 ** -0.5),
-                None)           # the latent kernels ask nothing
+                jax.eval_shape(W.latent_pools, c, pe))
     if name == "lfm2":      # 8 heads of 64, two a lane tile
         q = W.pack_queries(
             jax.random.normal(keys[0], (B, 8, 4, 64), BF16), 2)
@@ -121,6 +126,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--slots", type=int, default=0,
                     help="a block's slots, for a sweep (0: the tree's own)")
+    ap.add_argument("--cells", default=",".join(CELLS),
+                    help="families, comma-separated (default: all)")
     ap.add_argument("--out", required=True)
     args = ap.parse_args()
     device = jax.devices()[0]
@@ -135,14 +142,15 @@ def main():
     result = {"device": device.device_kind, "slots": slots, "seed": args.seed,
               "cells": {}}
     for i, name in enumerate(CELLS):
+        if name not in args.cells.split(","):
+            continue
         rows = _rows(name, np.random.default_rng(args.seed + i))
         call, pools = _call(name, jax.random.key((args.seed + i) % (1 << 31)))
         per = _device_us_a_call(call, rows)
         kernel = sum(us for op, us in per.items() if "decode_call" in op)
         beside = sum(us for op, us in per.items() if "decode_call" not in op)
         blocks = _live_blocks(name, np.asarray(rows[1]), slots)
-        copies = pools is not None and getattr(
-            W, "kernel_copies", lambda pools: False)(pools)
+        copies = getattr(W, "kernel_copies", lambda pools: False)(pools)
         result["cells"][name] = {
             "path": "kernel copies" if copies else "pipeline",
             "blocks": blocks, "kernel_us_a_call": round(kernel, 2),

@@ -157,13 +157,17 @@ def tiny_cases():
         yield case, programs(eng, S, 2 * cfg.prefill_chunk, lora, window)
 
 
-# Cells lowered from their configuration files: ouro's pools take the
-# decode walk with the kernel's own copies (PR 51), the other three keep the
-# pipeline (packed heads, latents) and their programs must not move.
+# Cells lowered from their configuration files. Ouro's, Solar's and
+# Laguna's pools (and Mixtral's, below) took the decode walk with the
+# kernel's own copies in PR 51 and a change to that walk for other pools
+# must not move their programs; LFM2's packed heads and JoyAI's and Kimi's
+# latents take it since PR 53.
 CELL_FILES = [("cell-ouro-v5e", "ouro-2.6b"),
               ("cell-lfm2-v5e", "lfm2-24b-a2b"),
               ("cell-joyai-v5e", "joyai-llm-flash"),
-              ("cell-kimi-linear-v5e", "kimi-linear-48b-a3b")]
+              ("cell-kimi-linear-v5e", "kimi-linear-48b-a3b"),
+              ("cell-solar-v5e", "solar-open2-250b"),
+              ("cell-laguna-v5e", "laguna-xs2")]
 
 
 @functools.cache
@@ -177,9 +181,10 @@ def _described_chip():
 
 
 def kernel_cases():
-    """The four decode kernels alone at pools that keep the pipeline
-    (``page_walk.kernel_copies`` false): LFM2's packed heads, int8 pools
-    with their scales, the latent pools in bf16 and int8."""
+    """The four decode kernels alone: at LFM2's packed heads and the bf16
+    latent pools, which copy their own pages since PR 53
+    (``page_walk.kernel_copies``), and at int8 pools with their scales,
+    K/V and latent, which keep the pipeline."""
     from rbg_tpu.ops.pallas import paged_attention_kernel as K
     S = functools.partial(jax.ShapeDtypeStruct, sharding=_described_chip())
     bf, i8, f32 = jnp.bfloat16, jnp.int8, jnp.float32

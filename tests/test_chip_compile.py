@@ -206,45 +206,107 @@ def test_decode_kernel_compiles_at_the_cells_shapes(chip, cell, quantized):
     text = _compiles_with_kernel(fn, *args).as_text()
     n = page_walk.decode_pages_per_block(PAGE)
     # a bf16 pool of whole-tile pages is walked with the kernel's own
-    # copies (PR 51): no item table; every other pool keeps it
-    copies = not isinstance(heads, int) and page_walk.kernel_copies(args[1:3])
-    assert copies == (cell in ("mixtral.longgen", "solar-open2.longgen32")
-                      and not quantized)
+    # copies (PRs 51, 53: every cell's): no item table; an int8 pool with
+    # its scales keeps it
+    pools = args[1:3] if not isinstance(heads, int) else jax.eval_shape(
+        page_walk.latent_pools, *args[2:4])
+    copies = page_walk.kernel_copies([*pools, *scales])
+    assert copies == (not quantized)
     assert (f"s32[{n},{rows * (width // n) + 1}]" in text) != copies
 
 
-# What ``page_walk.kernel_copies`` takes (PR 51): (rows, the table's width,
-# the pool's pages over the layers of its class, (kv heads, queries a kv
-# head), a window layer's width).
+# What ``page_walk.kernel_copies`` takes (PRs 51, 53): (rows, the table's
+# width, the pool's pages over the layers of its class, (the pool's heads,
+# queries a head of the pool) or for latents the heads, a window layer's
+# width, a head's size where the pool holds two to a lane tile).
 CELL_COPIES = {
-    "laguna-xs2.window": (32, 256, 15 * 1185, (8, 8), 512),
-    "laguna-xs2.full": (32, 256, 5 * 8192, (8, 6), None),
-    "mixtral": (8, 512, 3 * 8192, (8, 4), None),
-    "solar-open2.gqa": (32, 256, 2 * 8192, (8, 8), None),
-    "ouro": (4, 80, 192 * 320, (16, 1), None),
+    "laguna-xs2.window": (32, 256, 15 * 1185, (8, 8), 512, None),
+    "laguna-xs2.full": (32, 256, 5 * 8192, (8, 6), None, None),
+    "mixtral": (8, 512, 3 * 8192, (8, 4), None, None),
+    "solar-open2.gqa": (32, 256, 2 * 8192, (8, 8), None, None),
+    "ouro": (4, 80, 192 * 320, (16, 1), None, None),
+    "lfm2": (32, 256, 10 * 8192, (4, 8), None, 64),
+    "joyai.latent": (16, 256, 5 * 8192, 32, None, None),
+    "kimi.latent": (16, 256, 7 * 4096, 32, None, None),
 }
+
+
+def _decode_walk(chip, rows, width, pages, page, heads, window=None,
+                 head_dim=None):
+    """(call, arguments, the pools as the kernel receives them) of a decode
+    walk over bf16 pools of ``pages`` pages ``page``: ``[slots, KV, hd]``
+    under ``heads`` queries a head of the pool (``_decode``, what
+    ``_decode_call`` jits), or the latents' ``[slots, dc]`` beside a
+    rotary key's pool a lane tile wide under ``heads`` heads
+    (``_mla_decode``)."""
+    from rbg_tpu.ops.pallas import paged_attention_kernel as K
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
+    tail = (S((rows, width), I32), S((rows,), I32))
+    if len(page) == 2:
+        pools = (S((pages, page[0], 1, page[1]), BF16),
+                 S((pages, page[0], 1, 128), BF16))
+        fn = lambda ql, qp, c, pe, t, n: K._mla_decode(
+            ql, qp, (c, pe), t, n, MLA_SCALE, False)
+        args = (S((rows, heads, page[1]), BF16),
+                S((rows, heads, MLA_DR), BF16), *pools, *tail)
+        return fn, args, jax.eval_shape(page_walk.latent_pools, *pools)
+    pools = (S((pages,) + page, BF16),) * 2
+    fn = lambda q, k, v, t, n: K._decode(q, (k, v), t, n, False, head_dim,
+                                         window)
+    return fn, (S((rows, page[1], heads, page[2]), BF16), *pools, *tail), pools
 
 
 @pytest.mark.parametrize("kind", sorted(CELL_COPIES))
 def test_decode_kernel_with_its_own_copies_compiles_at_the_cells_shapes(
         chip, kind):
-    """``_decode_call`` on the path that issues its own page copies
-    (``paged_attention_kernel._decode_copies``): the pools stay in HBM,
-    a page ``[16, KV, 128]`` is sliced out of one and lands at an offset
-    of a VMEM buffer, which Mosaic takes for KV 8 and KV 16 at hd 128;
-    the scalar operands are the page table and the lengths, no item
-    table and no block counts."""
-    from rbg_tpu.ops.pallas import paged_attention_kernel as K
-    rows, width, pages, (KV, G), window = CELL_COPIES[kind]
-    S = functools.partial(jax.ShapeDtypeStruct, sharding=chip)
-    pool = S((pages, PAGE, KV, 128), BF16)
-    assert page_walk.kernel_copies((pool, pool))
-    fn = lambda q, k, v, t, n: K._decode_call(q, k, v, t, n, window=window)
-    text = _compiles_with_kernel(
-        fn, S((rows, KV, G, 128), BF16), pool, pool, S((rows, width), I32),
-        S((rows,), I32)).as_text()
+    """``_decode_call``'s and ``_mla_decode_call``'s kernels on the path
+    that issues its own page copies (``page_walk.walk_with_copies``): the
+    pools stay in HBM, a page is sliced out of one and lands at an offset
+    of a VMEM buffer, which Mosaic takes for ``[16, KV, 128]`` at KV 8 and
+    16, for LFM2's ``[16, 4, 128]`` (heads of 64 two to a lane tile) and
+    for the latent pools' ``[16, 512]`` / ``[16, 128]``; the scalar
+    operands are the page table and the lengths, no item table and no
+    block counts."""
+    rows, width, pages, heads, window, head_dim = CELL_COPIES[kind]
+    page, heads = ((PAGE, MLA_DC), heads) if isinstance(heads, int) else (
+        (PAGE, heads[0], 128), heads[1])
+    fn, args, pools = _decode_walk(chip, rows, width, pages, page, heads,
+                                   window, head_dim)
+    assert page_walk.kernel_copies(pools)
+    text = _compiles_with_kernel(fn, *args).as_text()
     n = page_walk.decode_pages_per_block(PAGE)
     assert f"s32[{n},{rows * (width // n) + 1}]" not in text
+
+
+# The predicate's borders: (the pools' page ``[page, ...]``, whether
+# ``page_walk.kernel_copies`` takes it). What it takes compiles; what it
+# refuses among bf16 pairs is what Mosaic refuses when the walk is forced
+# onto them (a slice that is no whole number of tiles).
+COPIES_BORDERS = {
+    "packed-kv2": ((16, 2, 128), True),
+    "kv24": ((16, 24, 128), True),
+    "kv8-page8": ((8, 8, 128), True),
+    "kv4-hd256": ((16, 4, 256), True),
+    "latent-page8": ((8, 512), True),
+    "latent-page32-dc256": ((32, 256), True),
+    "packed-kv1": ((16, 1, 128), False),
+    "kv12": ((16, 12, 128), False),
+    "hd64": ((16, 8, 64), False),
+    "latent-page4": ((4, 512), False),
+}
+
+
+@pytest.mark.parametrize("border", sorted(COPIES_BORDERS))
+def test_kernel_copies_takes_what_mosaic_compiles(chip, monkeypatch, border):
+    page, takes = COPIES_BORDERS[border]
+    fn, args, pools = _decode_walk(chip, 8, 64, NP, page, heads=4)
+    assert page_walk.kernel_copies(pools) is takes
+    if takes:
+        _compiles_with_kernel(fn, *args)
+        return
+    monkeypatch.setattr(page_walk, "kernel_copies", lambda pools: True)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(fn).lower(*args).compile()
 
 
 # ---- the engine's own step programs, whole, at chip_smoke's serving size ----
@@ -388,7 +450,7 @@ CELL_PROGRAMS = {
         file="joyai-llm-flash.json",
         shapes={"blocks/moe_gate": (4, 256, 2048, 768),
                 "v_pages": (5, 8192, 16, 1, 128)},
-        walk=("_mla_decode_call", "_block_ragged_mla_call"), copies=0,
+        walk=("_mla_decode_call", "_block_ragged_mla_call"), copies=5,
         temps=(32 * MB, 416 * MB), some_copy=True),
     # all 27 layers (one dense recurrent layer, 19 recurrent and 7 latent
     # expert layers), 16 of 256 experts a layer, 16 rows, pages for the 7
@@ -408,7 +470,7 @@ CELL_PROGRAMS = {
                 "k_pages": (7, 4096, 16, 1, 512),
                 "state/s": (20, 16, 32, 128, 128),
                 "state/conv": (20, 16, 36864)},
-        walk=("_mla_decode_call", "_block_ragged_mla_call"), copies=0,
+        walk=("_mla_decode_call", "_block_ragged_mla_call"), copies=7,
         temps=(128 * MB, 128 * MB), some_copy=True),
     # all 40 layers (two dense layers with the gated short convolution, then
     # 10 attention and 28 convolution layers with 8 of 64 experts each), 32
@@ -426,7 +488,7 @@ CELL_PROGRAMS = {
                 "lm_head": None,                        # a tied head
                 "k_pages": (10, 8192, 16, 4, 128),
                 "state/tail": (30, 32, 4096)},
-        walk=("_decode_call", "_block_ragged_call"), copies=0,
+        walk=("_decode_call", "_block_ragged_call"), copies=10,
         temps=(128 * MB, 128 * MB)),
     # 8 layers in two turns A K K K (A: 64 / 8 heads of 128 without
     # positions, gated; K: 64 delta-rule heads of 128), 20 of 320 experts a
@@ -554,9 +616,9 @@ def test_step_programs_of_a_cell_fit_and_copy_no_pool(chip, monkeypatch, cell,
     assert (eng.cfg.max_batch, eng.cfg.max_pages_per_seq) == (
         cfg["server"]["max_batch"],
         cfg["server"]["max_seq_len"] // cfg["server"]["page_size"])
-    # the decode walks a step that copy their own pages (PR 51): read off
-    # the pools, Laguna's two classes, a pass a layer in Ouro, none of the
-    # packed heads or the latents
+    # the decode walks a step that copy their own pages (PRs 51, 53): read
+    # off the pools, Laguna's two classes, a pass a layer in Ouro, LFM2's
+    # packed heads, the latent layers of JoyAI and Kimi
     assert eng.metrics["decode_walk_kernel_copies"] == want["copies"]
     decode = program == "decode"
     compiled = (_compile_decode if decode else _compile_unified)(chip, eng)

@@ -5,10 +5,16 @@ the same tokens whether or not a prompt is prefilled beside it in unified
 steps.
 """
 
+import dataclasses
+
+import jax
 import numpy as np
 import pytest
 
 from rbg_tpu.engine import Engine, EngineConfig, SamplingParams
+from rbg_tpu.models import config as presets
+from rbg_tpu.models import get_config
+from rbg_tpu.ops.pallas import page_walk
 
 
 @pytest.mark.parametrize("model,use_pallas", [
@@ -56,29 +62,43 @@ def test_a_decoding_row_reads_the_same_beside_a_prefill(interpreted, model,
     assert beside == alone
 
 
-def test_kernel_copies_and_the_pipeline_serve_the_same_tokens(
-        interpreted, monkeypatch):
-    """Through the engine, at pools ``page_walk.kernel_copies`` takes (bf16,
-    8 KV heads of 128; the tiny presets' own fall on the pipeline's side),
-    two full and two window layers: decode steps and the one-token rows of
-    unified steps walk with the kernel's own copies in every attention
-    layer of both classes of page (the gauge says so), and the served
-    tokens are those of the same engine with the predicate turned off,
-    the pipeline's."""
-    import dataclasses
-
-    import jax
-
-    from rbg_tpu.models import config as presets
-    from rbg_tpu.models import get_config
-    from rbg_tpu.ops.pallas import page_walk
-
-    base, layers = get_config("tiny-laguna"), 4
-    monkeypatch.setitem(presets._PRESETS, "whole-tiles", dataclasses.replace(
-        base, name="whole-tiles", num_layers=layers, num_heads=8,
-        num_kv_heads=8, head_dim=128, dtype="bfloat16",
+def _whole_tiles():
+    """bf16, 8 KV heads of 128, two full and two window layers: both
+    classes of page."""
+    base = get_config("tiny-laguna")
+    return dataclasses.replace(
+        base, num_layers=4, num_heads=8, num_kv_heads=8, head_dim=128,
+        dtype="bfloat16",
         window_layer={**dict(base.window_layer), "num_heads": 16},
-        layer_types=("full_attention", "sliding_attention") * 2))
+        layer_types=("full_attention", "sliding_attention") * 2), 4
+
+
+def _packed():
+    """LFM2's pool: bf16, 8 heads of 64 held two to a lane tile,
+    ``[NP, 16, 4, 128]``, in the two attention layers of the hybrid."""
+    return dataclasses.replace(get_config("tiny-lfm2"), num_heads=8,
+                               num_kv_heads=8, dtype="bfloat16"), 2
+
+
+def _latent():
+    """bf16 latents a lane tile wide beside the rotary key's pool,
+    ``[NP, 16, 1, 128]`` each."""
+    return dataclasses.replace(get_config("tiny-mla"), kv_lora_rank=128,
+                               dtype="bfloat16"), 2
+
+
+@pytest.mark.parametrize("preset", [_whole_tiles, _packed, _latent])
+def test_kernel_copies_and_the_pipeline_serve_the_same_tokens(
+        interpreted, monkeypatch, preset):
+    """Through the engine, at pools ``page_walk.kernel_copies`` takes (the
+    tiny presets' own fall on the pipeline's side): decode steps and the
+    one-token rows of unified steps walk with the kernel's own copies in
+    every attention layer of every class of page (the gauge says so), and
+    the served tokens are those of the same engine with the predicate
+    turned off, the pipeline's."""
+    mcfg, walks = preset()
+    monkeypatch.setitem(presets._PRESETS, "copies",
+                        dataclasses.replace(mcfg, name="copies"))
     rng = np.random.default_rng(11)
     prompts = [rng.integers(1, 256, n).tolist() for n in (37, 5, 18)]
     late = rng.integers(1, 256, 33).tolist()
@@ -87,7 +107,7 @@ def test_kernel_copies_and_the_pipeline_serve_the_same_tokens(
     def served():
         jax.clear_caches()      # ``_decode_call`` is traced once a shape
         eng = Engine(EngineConfig(
-            model="whole-tiles", page_size=16, num_pages=64, max_seq_len=128,
+            model="copies", page_size=16, num_pages=64, max_seq_len=128,
             max_batch=4, prefill_chunk=16, enable_radix_cache=False,
             use_pallas="always"))
         ids = [eng.add_request(p, greedy) for p in prompts]
@@ -101,7 +121,7 @@ def test_kernel_copies_and_the_pipeline_serve_the_same_tokens(
         return eng.metrics["decode_walk_kernel_copies"], list(out.values())
 
     copies, tokens = served()
-    assert copies == layers
+    assert copies == walks
     monkeypatch.setattr(page_walk, "kernel_copies", lambda pools: False)
     piped, tokens_piped = served()
     jax.clear_caches()

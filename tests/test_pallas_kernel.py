@@ -409,12 +409,13 @@ def test_mla_decode_reads_the_same_from_a_padded_rotary_pool(form, quantized):
     np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))
 
 
-# ---- the decode walk that copies its own pages (PR 51) ----
+# ---- the decode walk that copies its own pages (PRs 51, 53) ----
 #
-# bf16 K/V pools whose pages are whole tiles (``page_walk.kernel_copies``)
-# are walked by a kernel that issues the page copies itself; every other
-# pool keeps the pipeline. Same blocks, same order, same ``gqa_attend``:
-# the two agree to the bit.
+# bf16 pools whose pages are whole tiles (``page_walk.kernel_copies``: K/V
+# pools of 8 or 16 heads of 128, of heads of 64 packed two to a lane tile,
+# and the latent pools) are walked by a kernel that issues the page copies
+# itself; every other pool keeps the pipeline. Same blocks, same order,
+# same ``gqa_attend`` / ``mla_attend``: the two agree to the bit.
 
 from rbg_tpu.ops.pallas import page_walk
 from rbg_tpu.ops.pallas import paged_attention_kernel as decode_kernel
@@ -422,53 +423,128 @@ from rbg_tpu.ops.pallas import paged_attention_kernel as decode_kernel
 _COPY_PAGE = 16
 _BLOCK = page_walk.decode_pages_per_block(_COPY_PAGE) * _COPY_PAGE   # 128
 
-# id: (KV, G, table width, window, the rows' lengths). Every case holds an
-# empty row; "last" where it ends the call, "between" where live rows
-# follow it.
+# id: (the pool's heads, queries a pool's head, table width, window, the
+# rows' lengths, a head's size where the pool packs two to a lane tile).
+# Every case holds an empty row; "last" where it ends the call, "between"
+# where live rows follow it.
 _COPY_CASES = {
-    "kv8-g8-table8-one-block": (8, 8, 8, None, [1, 0, _BLOCK, 77, 0]),
+    "kv8-g8-table8-one-block": (8, 8, 8, None, [1, 0, _BLOCK, 77, 0], None),
     "kv8-g6-table512-whole-table": (
-        8, 6, 512, None, [_BLOCK + 1, 0, 3 * _BLOCK, 512 * _COPY_PAGE, 1]),
+        8, 6, 512, None, [_BLOCK + 1, 0, 3 * _BLOCK, 512 * _COPY_PAGE, 1],
+        None),
     "kv16-g1-table32": (16, 1, 32, None,
-                        [_BLOCK, _BLOCK + 1, 0, 300, 32 * _COPY_PAGE, 0]),
-    "kv8-g1-table8": (8, 1, 8, None, [0, 5, _BLOCK]),
+                        [_BLOCK, _BLOCK + 1, 0, 300, 32 * _COPY_PAGE, 0],
+                        None),
+    "kv8-g1-table8": (8, 1, 8, None, [0, 5, _BLOCK], None),
     "kv8-g8-window512-starts-past-block-0": (
-        8, 8, 64, 512, [700, 1000, 0, 513, 40, 1024, 0]),
-    "kv16-g1-window128": (16, 1, 32, 128, [129, 0, 128, 400, 1]),
+        8, 8, 64, 512, [700, 1000, 0, 513, 40, 1024, 0], None),
+    "kv16-g1-window128": (16, 1, 32, 128, [129, 0, 128, 400, 1], None),
+    # LFM2's pool: 8 heads of 64 as 4 of 128, four queries a head as eight
+    "packed-kv4-g8-table256-empty-last": (
+        4, 8, 256, None, [_BLOCK, 2047, 1, 0, 130, 0], 64),
+    "packed-kv4-g8-table32-whole-table": (
+        4, 8, 32, None, [0, 32 * _COPY_PAGE, 0, _BLOCK + 1, 77], 64),
+    "packed-kv2-g2-table8-empty-between": (
+        2, 2, 8, None, [5, 0, 0, _BLOCK, 1], 64),
+    "packed-kv4-g4-window128": (4, 4, 32, 128, [129, 0, 128, 400, 0], 64),
 }
 
 
-def _copy_case(KV, G, P, window, lens, seed):
-    """Queries, bf16 pools of whole-tile pages whose LAST page is NaN,
-    and a table whose dead entries all name it (a window layer's pages
-    wholly below the window too: they were given back)."""
-    rng = np.random.RandomState(seed)
-    hd, page, B = 128, _COPY_PAGE, len(lens)
+def _copy_pools(rng, lens, P, window, *tails):
+    """bf16 pools ``[NP, page, *tail]`` whose LAST page is NaN, and a
+    table ``P`` wide over them whose dead entries all name it (a window
+    layer's pages wholly below the window too: they were given back)."""
+    page = _COPY_PAGE
     live = [-(-n // page) for n in lens]
     NP = sum(live) + 2
-    pool = lambda: jnp.asarray(rng.randn(NP, page, KV, hd),
-                               jnp.bfloat16).at[NP - 1].set(jnp.nan)
-    k, v = pool(), pool()
-    table = np.full((B, P), NP - 1, np.int32)
+    pools = tuple(jnp.asarray(rng.randn(NP, page, *tail), jnp.bfloat16)
+                  .at[NP - 1].set(jnp.nan) for tail in tails)
+    table = np.full((len(lens), P), NP - 1, np.int32)
     pages, at = rng.permutation(NP - 2) + 1, 0
     for b, n in enumerate(live):
         below = 0 if window is None else max(lens[b] - window, 0) // _BLOCK \
             * (_BLOCK // page)
         table[b, below:n] = pages[at + below:at + n]
         at += n
-    q = jnp.asarray(rng.randn(B, KV, G, hd), jnp.bfloat16)
-    return q, (k, v), jnp.asarray(table), jnp.asarray(lens, jnp.int32)
+    return pools, jnp.asarray(table), jnp.asarray(lens, jnp.int32)
+
+
+def _copy_case(KV, G, P, window, lens, head_dim, seed):
+    """Queries, K/V pools of whole-tile pages and their table
+    (``_copy_pools``). ``head_dim``: the queries are
+    ``page_walk.pack_queries``' of heads that size."""
+    rng = np.random.RandomState(seed)
+    pools, table, kv_lens = _copy_pools(rng, lens, P, window,
+                                        (KV, 128), (KV, 128))
+    p = 1 if head_dim is None else 128 // head_dim
+    q = page_walk.pack_queries(jnp.asarray(
+        rng.randn(len(lens), KV * p, G // p, 128 // p), jnp.bfloat16), p)
+    return q, pools, table, kv_lens
 
 
 @pytest.mark.parametrize("case", sorted(_COPY_CASES))
 def test_decode_walk_with_kernel_copies_equals_the_pipeline_to_the_bit(
         case, monkeypatch):
-    KV, G, P, window, lens = _COPY_CASES[case]
-    q, pools, table, kv_lens = _copy_case(KV, G, P, window, lens,
+    KV, G, P, window, lens, head_dim = _COPY_CASES[case]
+    q, pools, table, kv_lens = _copy_case(KV, G, P, window, lens, head_dim,
                                           seed=len(case))
     assert page_walk.kernel_copies(pools)
     walk = lambda: np.asarray(decode_kernel._decode(
-        q, pools, table, kv_lens, True, None, window), np.float32)
+        q, pools, table, kv_lens, True, head_dim, window), np.float32)
+    copied = walk()
+    monkeypatch.setattr(page_walk, "kernel_copies", lambda pools: False)
+    piped = walk()
+    assert np.isfinite(copied).all()
+    np.testing.assert_array_equal(copied, piped)
+    empty = np.asarray(lens) == 0
+    p = 1 if head_dim is None else 128 // head_dim
+    own = np.asarray(page_walk.unpack_outputs(jnp.asarray(copied), p))
+    assert np.all(copied[empty] == 0) and np.abs(own[~empty]).min() > 0
+    # and both are the XLA form's answer (which reads a packed pool as the
+    # heads it holds)
+    H, hd = KV * G, 128 // p
+    ref = paged_attention_xla(
+        page_walk.unpack_outputs(q, p).reshape(
+            len(lens), 1, H, hd).astype(jnp.float32),
+        *(jnp.nan_to_num(pool.astype(jnp.float32)) for pool in pools), table,
+        jnp.maximum(kv_lens - 1, 0)[:, None], kv_lens, window=window)
+    np.testing.assert_allclose(
+        own.reshape(ref.shape)[~empty], np.asarray(ref)[~empty],
+        rtol=2e-2, atol=2e-2)
+
+
+# id: (heads, latent width, rotary key's width, table width, the rows'
+# lengths): JoyAI's and Kimi's pools ``[NP, 16, 1, 512]`` / ``[NP, 16, 1,
+# 128]`` and a narrower latent, an empty row last and between.
+_LATENT_COPY_CASES = {
+    "h32-dc512-table256-empty-last": (
+        32, 512, 64, 256, [_BLOCK, 2047, 1, 0, 130, 0]),
+    "h4-dc512-table32-whole-table": (
+        4, 512, 64, 32, [0, 32 * _COPY_PAGE, 0, _BLOCK + 1, 77]),
+    "h8-dc128-dr32-table8-empty-between": (
+        8, 128, 32, 8, [5, 0, 0, _BLOCK, 1]),
+    "h16-dc256-table8-one-block": (16, 256, 64, 8, [1, 0, _BLOCK, 77, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LATENT_COPY_CASES))
+def test_latent_decode_walk_with_kernel_copies_equals_the_pipeline_to_the_bit(
+        case, monkeypatch):
+    """The latent twin: bf16 latent pools whose LAST page is NaN and a
+    table whose dead entries all name it; the kernel that copies its own
+    pages, the pipeline's and the XLA form."""
+    H, dc, dr, P, lens = _LATENT_COPY_CASES[case]
+    rng, B = np.random.RandomState(len(case)), len(lens)
+    (c, pe), table, kv_lens = _copy_pools(rng, lens, P, None, (1, dc),
+                                          (1, 128))
+    # the rotary key's pool a lane tile wide, zero beyond the key
+    pe = pe.at[:-1, ..., dr:].set(0)
+    ql = jnp.asarray(rng.randn(B, H, dc), jnp.bfloat16)
+    qp = jnp.asarray(rng.randn(B, H, dr), jnp.bfloat16)
+    scale = (dc + dr) ** -0.5
+    assert page_walk.kernel_copies(page_walk.latent_pools(c, pe))
+    walk = lambda: np.asarray(decode_kernel._mla_decode(
+        ql, qp, (c, pe), table, kv_lens, scale, True), np.float32)
     copied = walk()
     monkeypatch.setattr(page_walk, "kernel_copies", lambda pools: False)
     piped = walk()
@@ -476,31 +552,36 @@ def test_decode_walk_with_kernel_copies_equals_the_pipeline_to_the_bit(
     np.testing.assert_array_equal(copied, piped)
     empty = np.asarray(lens) == 0
     assert np.all(copied[empty] == 0) and np.abs(copied[~empty]).min() > 0
-    # and both are the XLA form's answer
-    H = KV * G
-    ref = paged_attention_xla(
-        q.reshape(len(lens), 1, H, 128).astype(jnp.float32),
-        *(jnp.nan_to_num(p.astype(jnp.float32)) for p in pools), table,
-        jnp.maximum(kv_lens - 1, 0)[:, None], kv_lens, window=window)
-    np.testing.assert_allclose(
-        copied.reshape(ref.shape)[~empty], np.asarray(ref)[~empty],
-        rtol=2e-2, atol=2e-2)
+    ref = paged_mla_attention_xla(
+        ql[:, None].astype(jnp.float32), qp[:, None].astype(jnp.float32),
+        *(jnp.nan_to_num(a.astype(jnp.float32)) for a in (c, pe)), table,
+        jnp.maximum(kv_lens - 1, 0)[:, None], kv_lens, scale)
+    np.testing.assert_allclose(copied[~empty], np.asarray(ref)[~empty, 0],
+                               rtol=2e-2, atol=2e-2)
 
 
 @pytest.mark.parametrize("pools,copies", [
     ([((64, 16, 8, 128), jnp.bfloat16)] * 2, True),      # mixtral, laguna
     ([((64, 16, 16, 128), jnp.bfloat16)] * 2, True),     # ouro
     ([((64, 16, 8, 256), jnp.bfloat16)] * 2, True),
-    ([((64, 16, 4, 128), jnp.bfloat16)] * 2, False),     # lfm2: packed heads
+    ([((64, 16, 4, 128), jnp.bfloat16)] * 2, True),      # lfm2: packed heads
+    ([((64, 16, 2, 128), jnp.bfloat16)] * 2, True),
+    ([((64, 16, 1, 128), jnp.bfloat16)] * 2, False),     # qwen2's 2 as 1
+    ([((64, 16, 12, 128), jnp.bfloat16)] * 2, False),
     ([((64, 16, 8, 64), jnp.bfloat16)] * 2, False),
     ([((64, 16, 2, 32), jnp.bfloat16)] * 2, False),      # the tiny presets
     ([((64, 16, 8, 128), jnp.float32)] * 2, False),
     ([((64, 16, 8, 128), jnp.int8)] * 2
      + [((64, 16, 8), jnp.float32)] * 2, False),         # int8 and scales
     ([((64, 16, 512), jnp.bfloat16), ((64, 16, 128), jnp.bfloat16)],
-     False),                                             # latents
-], ids=["kv8", "kv16", "hd256", "packed", "hd64", "tiny", "f32", "int8",
-        "latent"])
+     True),                                              # latents
+    ([((64, 16, 512), jnp.bfloat16), ((64, 16, 64), jnp.bfloat16)], False),
+    ([((64, 4, 512), jnp.bfloat16), ((64, 4, 128), jnp.bfloat16)], False),
+    ([((64, 16, 512), jnp.int8), ((64, 16, 128), jnp.int8)]
+     + [((64, 16, 1), jnp.float32)] * 2, False),         # int8 latents
+], ids=["kv8", "kv16", "hd256", "packed", "packed-kv2", "packed-kv1", "kv12",
+        "hd64", "tiny", "f32", "int8", "latent", "latent-key-of-64",
+        "latent-page4", "latent-int8"])
 def test_kernel_copies_is_read_off_the_pools(pools, copies):
     assert page_walk.kernel_copies(
         [jax.ShapeDtypeStruct(*p) for p in pools]) is copies
